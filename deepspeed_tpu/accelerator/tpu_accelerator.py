@@ -35,10 +35,9 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return [d for d in jax.local_devices() if d.platform == "tpu"]
 
     def is_available(self) -> bool:
-        try:
-            return len(self._devices()) > 0
-        except RuntimeError:
-            return False
+        # a backend that fails to initialise raises here: on a TPU host
+        # that is a fault to surface, not "no TPU, carry on on the CPU"
+        return len(self._devices()) > 0
 
     def device_name(self, device_index: Optional[int] = None) -> str:
         if device_index is None:
@@ -79,4 +78,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
                 if dtype == jnp.float32:
                     return flops / 2  # MXU fp32 runs at half bf16 rate
                 return flops
-        return 1e12
+        raise ValueError(
+            f"no published peak FLOP/s for device_kind {kind!r}; add it "
+            f"to _PEAK_FLOPS_BF16 with its source (a utilization against "
+            f"an assumed peak is not a measurement)")

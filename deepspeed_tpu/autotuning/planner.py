@@ -654,11 +654,8 @@ class Planner:
             if k > 0 or cfg.calibrate:
                 cal = self.calibrate()
             else:
-                try:
-                    from ..accelerator import get_accelerator
-                    peak = float(get_accelerator().peak_flops())
-                except Exception:   # noqa: BLE001 — CPU floor
-                    peak = 1e12
+                from ..accelerator import get_accelerator
+                peak = float(get_accelerator().peak_flops())
                 # uncalibrated fallback: accelerator peak x a generic
                 # 0.4 efficiency — ranks, but don't trust absolutes
                 cal = Calibration(flops_per_s=peak * 0.4,

@@ -275,6 +275,12 @@ def paged_attention_kernel(q, k_new, v_new, k_pool, v_pool, block_tables,
                             pltpu.VMEM((hq * sq, 128), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, sq, hq, d), jnp.float32),
+        # all-heads blocks: at 32 heads x 128 a 256-token prefill chunk
+        # needs ~35MB of scoped VMEM (q 2M + f32 out 4M double-buffered,
+        # 8M of m/l scratch, the [hq*sq, blk] score slab) against
+        # Mosaic's 16MB default — same ceiling as ops/pallas/flash_attention
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024),
         interpret=jax.default_backend() != "tpu",
     )(counts, tables, jnp.asarray(pos0, jnp.int32),
       jnp.asarray(true_len, jnp.int32), *operands)

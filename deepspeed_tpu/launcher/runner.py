@@ -183,6 +183,19 @@ def _local_run(args) -> int:
     return subprocess.call(cmd, env=env)
 
 
+def _local_chip_count() -> int:
+    """Local device count, asked of a short-lived child. A chip belongs
+    to one process at a time: if the launcher initialised a JAX backend
+    itself it would hold the chips, and the workers it spawns next would
+    fail or hang waiting for them. The child exits (releasing the
+    chips) before any worker starts."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.local_device_count())"],
+        check=True, capture_output=True, text=True)
+    return max(1, int(out.stdout.split()[-1]))
+
+
 RUNNERS = {
     constants.PDSH_LAUNCHER: PDSHRunner,
     constants.SSH_LAUNCHER: SSHRunner,
@@ -203,9 +216,7 @@ def main(args=None) -> int:
     if resource_pool is None:
         # no hostfile + --force_multi: localhost with ALL its chips (a
         # slots=1 default would shrink TPU_VISIBLE_CHIPS to one chip)
-        from ..accelerator import get_accelerator
-        resource_pool = OrderedDict(
-            localhost=max(1, get_accelerator().device_count()))
+        resource_pool = OrderedDict(localhost=_local_chip_count())
 
     resource_pool = OrderedDict(resource_pool)
     active = parse_resource_filter(resource_pool, args.include, args.exclude)

@@ -222,7 +222,8 @@ class DecoderLM:
         (no leading L dim)."""
         c = self.config
         p = self._maybe_dequant(layer_params, x.dtype)
-        if attn_fn is not None and c.sliding_window is not None:
+        if (attn_fn is not None and c.sliding_window is not None
+                and not getattr(attn_fn, "applies_window", False)):
             from ..utils.logging import warning_once
             warning_once(
                 "sliding_window is set but a custom attn_fn (e.g. the "

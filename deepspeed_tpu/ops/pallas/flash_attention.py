@@ -37,6 +37,7 @@ the same code path is exercised everywhere.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -61,14 +62,8 @@ def _resident_max_seq(d: int) -> int:
 
 # the row-resident kernels hold [S, D] slabs (q/do/dq + temps) in VMEM;
 # Mosaic's default 16MB scoped-vmem ceiling trips at long seq x D=128 —
-# raise it (v5e/v5p have 128MB). CompilerParams was TPUCompilerParams
-# before jax 0.5; on a jaxlib with neither, fall back to the default
-# ceiling (interpret-mode tests don't need it, real-chip long-seq runs
-# on such a jaxlib hit the 16MB limit with a clear Mosaic error).
-_CP_CLS = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-_COMPILER_PARAMS = (_CP_CLS(vmem_limit_bytes=100 * 1024 * 1024)
-                    if _CP_CLS is not None else None)
+# raise it (v5e/v5p have 128MB)
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _interpret() -> bool:
@@ -315,6 +310,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
         # still exercise the kernel at tiny shapes) — use the exact
         # (unfused) path instead
         from ..layers import dot_product_attention, window_bias
+        from ...utils.logging import warning_once
+        warning_once(
+            f"flash attention falling back to the exact unfused form "
+            f"(O(S^2) memory) at seq {s}: the kernel needs a sequence "
+            f"length that is a multiple of 128")
         bias = window_bias(s, window) if window is not None else None
         return dot_product_attention(q, k, v, causal=causal, bias=bias)
     from jax.ad_checkpoint import checkpoint_name
@@ -361,3 +361,45 @@ def flash_attention(q, k, v, *, causal: bool = True,
     o = _flash(to_bh(q), to_bh(k), to_bh(v), causal, window, rep)
     return checkpoint_name(
         o.reshape(b, hq, s, d).transpose(0, 2, 1, 3), "attn_out")
+
+
+def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
+                            window: int | None = None):
+    """attn_fn for a multi-device mesh: :func:`flash_attention` per shard
+    under a shard_map. GSPMD cannot partition a Mosaic custom call
+    ("Mosaic kernels cannot be automatically partitioned" at lowering on
+    the chip), and it only lowers when EVERY mesh axis is manual — so
+    the map is manual over all axes not already manual in an enclosing
+    region (the compiled pipeline's ``pp`` map). Batch is split over
+    ``batch_axes`` and heads over ``tp_axis`` where they divide; any
+    other axis sees replicated inputs (attention is independent per
+    (batch, head), so the per-shard result is exact).
+
+    The returned callable carries ``applies_window = True``: it applies
+    the model's sliding window itself, unlike the sequence-parallel
+    wrappers."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...utils.jax_compat import shard_map
+
+    def attn(q, k, v, *, causal: bool = True, **_kw):
+        active = jax.sharding.get_abstract_mesh()
+        use = active if active.shape else mesh
+        free = [a for a in use.axis_names if a not in use.manual_axes]
+        b_ax = tuple(a for a in batch_axes
+                     if a in free and use.shape[a] > 1)
+        if q.shape[0] % math.prod(use.shape[a] for a in b_ax):
+            b_ax = ()       # uneven batch: replicate, still exact
+        tp = use.shape.get(tp_axis, 1)
+        h_ax = (tp_axis if tp_axis in free and tp > 1
+                and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
+                else None)
+        spec = P(b_ax or None, None, h_ax, None)
+        return shard_map(
+            functools.partial(flash_attention, causal=causal,
+                              window=window),
+            mesh=use, axis_names=set(free), in_specs=(spec, spec, spec),
+            out_specs=spec, check_vma=False)(q, k, v)
+
+    attn.applies_window = True
+    return attn

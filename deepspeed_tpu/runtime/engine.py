@@ -452,11 +452,25 @@ class DeepSpeedEngine:
 
     # ------------------------------------------------------------------
     def _configure_sequence_parallel(self):
-        """Choose the loss fn, wrapping attention for SP when mesh.sp > 1."""
+        """Choose the loss fn: attention wrapped for SP when mesh.sp > 1,
+        flash kernels run per shard when the mesh has >1 device."""
         sp = self.topology.sequence_parallel_size
-        if sp <= 1:
-            return self.module.loss
         import inspect
+        if sp <= 1:
+            c = self.model_config
+            if (self.mesh.size > 1
+                    and getattr(c, "attn_impl", None) == "flash"
+                    and "attn_fn" in inspect.signature(
+                        self.module.loss).parameters):
+                # the flash kernels are Mosaic custom calls, which GSPMD
+                # cannot partition: run them per shard
+                from ..ops.pallas.flash_attention import \
+                    sharded_flash_attention
+                return functools.partial(
+                    self.module.loss, attn_fn=sharded_flash_attention(
+                        self.mesh, self.topology.batch_axes(),
+                        window=c.sliding_window))
+            return self.module.loss
         if "attn_fn" not in inspect.signature(self.module.loss).parameters:
             raise ValueError(
                 "sequence parallelism (mesh.sp > 1) requires the model's "
@@ -1595,8 +1609,8 @@ class DeepSpeedEngine:
         done = getattr(self, "_offloaded_states", set())
         from ..utils.jax_compat import supports_pinned_host
         if not supports_pinned_host():
-            # backend has no pinned_host tier at all (e.g. the 0.4.x CPU
-            # backend): nothing moves, nothing is marked offloaded
+            # backend has no pinned_host tier at all: nothing moves,
+            # nothing is marked offloaded
             logger.warning("offload_states: backend has no pinned_host "
                            "memory; state stays in device memory")
             self._offloaded_states = done

@@ -113,20 +113,15 @@ class PipelinedDecoderLM:
             if c.remat and c.remat_policy != "segments":
                 body = jax.checkpoint(body, prevent_cse=False,
                                       policy=_remat_policy(c.remat_policy))
-            # loss terms ride as [1] vectors, never scalars: jax 0.4.x
-            # shard_map partial-eval gives scalar residuals a {0: axes}
-            # out-name and trips _check_names when differentiating
-            # through the pipeline (scalars forwarded from scan carries
-            # skip _promote_scalar_residuals)
             (h, aux), _ = lax.scan(
-                body, (x, jnp.zeros((1,), jnp.float32)), stage_p)
+                body, (x, jnp.zeros((), jnp.float32)), stage_p)
 
             def loss_branch(h):
                 z = model.unembed(head_p, h)
-                return h, cross_entropy_loss(z, tgt_m).reshape(1)
+                return h, cross_entropy_loss(z, tgt_m)
 
             def pass_branch(h):
-                return h, jnp.zeros((1,), jnp.float32)
+                return h, jnp.zeros((), jnp.float32)
 
             h_out, ce = lax.cond(stage == pp - 1, loss_branch, pass_branch,
                                  h)
@@ -188,12 +183,12 @@ class PipelinedDecoderLM:
 
             act0 = jnp.zeros((mb, S, D), dtype)
             (_, lacc), _ = lax.scan(
-                tick, (act0, jnp.zeros((1,), jnp.float32)), jnp.arange(T))
+                tick, (act0, jnp.zeros((), jnp.float32)), jnp.arange(T))
             # per-stage partial losses stacked on pp and summed OUTSIDE
             # the manual region: a psum here hits an XLA partitioner
             # crash ("Invalid binary instruction opcode copy") on
             # psum-of-masked-select across a partial-manual axis
-            return lacc
+            return lacc[None]
 
         # head params ride a pp-stacked leading dim (an HLO broadcast the
         # partitioner slices per stage — still one copy per device): a
@@ -271,7 +266,7 @@ class PipelinedDecoderLM:
             gsp0 = jax.tree.map(jnp.zeros_like, stage_p)
             ghp0 = jax.tree.map(jnp.zeros_like, head_p)
             zeros_unit = (jnp.zeros((mb, S, D), dtype),
-                          jnp.zeros((1,), jnp.float32))
+                          jnp.zeros((), jnp.float32))
 
             def tick(carry, k):
                 act, cot, ring, gsp, ghp, lacc = carry
@@ -299,7 +294,7 @@ class PipelinedDecoderLM:
                     # every stage's unit loss term feeds the total (CE on
                     # the last stage, MoE router aux on ALL stages) — the
                     # scalar cotangent is 1 everywhere, not just on last
-                    d_loss = jnp.ones((1,), jnp.float32)
+                    d_loss = jnp.ones((), jnp.float32)
                     dsp, dhp, dx = bwd_unit(stage_p, head_p, x_saved,
                                             m_b_c, d_out, d_loss)
                     return (zeros_unit[0], zeros_unit[1], dsp, dhp, dx)
@@ -321,13 +316,13 @@ class PipelinedDecoderLM:
             carry0 = (jnp.zeros((mb, S, D), dtype),
                       jnp.zeros((mb, S, D), dtype),
                       jnp.zeros((depth, mb, S, D), dtype),
-                      gsp0, ghp0, jnp.zeros((1,), jnp.float32))
+                      gsp0, ghp0, jnp.zeros((), jnp.float32))
             (act, cot, ring, gsp, ghp, lacc), _ = lax.scan(
                 tick, carry0, jnp.arange(T))
             # stack per-stage partials on pp; reduced outside the manual
             # region (in-region psum crashes the SPMD partitioner — see
             # _loss_gpipe note)
-            return (lacc,
+            return (lacc[None],
                     jax.tree.map(lambda g: g[None], gsp),
                     jax.tree.map(lambda g: g[None], ghp))
 
@@ -376,7 +371,7 @@ class PipelinedDecoderLM:
                 return (h, aux + a), None
 
             (h, aux), _ = lax.scan(
-                body, (x, jnp.zeros((1,), jnp.float32)), sp)
+                body, (x, jnp.zeros((), jnp.float32)), sp)
             return h, aux
 
         def pipe_body(stage_p, head_p, tok):
@@ -400,9 +395,9 @@ class PipelinedDecoderLM:
             act0 = jnp.zeros((mb, S, D), dtype)
             out0 = jnp.zeros((M, mb, S, D), dtype)
             (_, out, aux), _ = lax.scan(
-                tick, (act0, out0, jnp.zeros((1,), jnp.float32)),
+                tick, (act0, out0, jnp.zeros((), jnp.float32)),
                 jnp.arange(T))
-            return out[None], aux
+            return out[None], aux[None]
 
         pipe = shard_map(
             pipe_body, mesh=self.mesh, axis_names={"pp"},
@@ -521,8 +516,8 @@ class PipelinedSpecStack:
                         if s == pp - 1:
                             return (jnp.zeros(bshape.shape, bshape.dtype),
                                     jnp.asarray(loss_fn(h, lb),
-                                                jnp.float32).reshape(1))
-                        return h, jnp.zeros((1,), jnp.float32)
+                                                jnp.float32).reshape(()))
+                        return h, jnp.zeros((), jnp.float32)
                     return branch
 
                 h_out, l_m = lax.switch(
@@ -533,8 +528,8 @@ class PipelinedSpecStack:
 
             act0 = jnp.zeros(bshape.shape, bshape.dtype)
             (_, lacc), _ = lax.scan(
-                tick, (act0, jnp.zeros((1,), jnp.float32)), jnp.arange(T))
-            return lacc
+                tick, (act0, jnp.zeros((), jnp.float32)), jnp.arange(T))
+            return lacc[None]
 
         params_pp = jax.tree.map(
             lambda l: jnp.broadcast_to(l[None], (pp, *l.shape)), params)

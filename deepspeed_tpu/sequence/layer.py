@@ -25,8 +25,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.layers import dot_product_attention
-from ..utils.jax_compat import (fallback_replicated_axes,
-                                get_abstract_mesh, shard_map)
+from ..utils.jax_compat import shard_map
 
 
 def _seq_all_to_all(x, axis_name: str, *, scatter_idx: int, gather_idx: int):
@@ -42,8 +41,8 @@ def _shard_map_sp(body, mesh, sp_axis, n_args):
     inside other manual regions (e.g. the compiled pipeline): when an
     abstract mesh is already active (inside jit), it is used instead of the
     concrete one so nested shard_maps agree."""
-    active = get_abstract_mesh()
-    use = active if (active is not None and active.shape) else mesh
+    active = jax.sharding.get_abstract_mesh()
+    use = active if active.shape else mesh
     spec = P(*([None] * 1), sp_axis)  # [B, S(sp), H, D]: dim1 manual
     specs = tuple([spec] * n_args)
     return shard_map(body, mesh=use, axis_names={sp_axis},
@@ -74,24 +73,6 @@ class DistributedAttention:
         mesh = self.mesh
         sp = mesh.shape.get(self.sp_axis, 1)
         if sp <= 1:
-            return self.local_attn(q, k, v, causal=causal, **kw)
-        if self.sp_axis in fallback_replicated_axes():
-            # 0.4.x full-manual fallback, nested inside another such
-            # region (e.g. the compiled 1F1B pipeline's shard_map): the
-            # outer map already made EVERY mesh axis manual — including
-            # sp — so a nested shard_map over sp cannot lower ("Axis
-            # ... is also found in manual_axes"; this crashed dryrun B
-            # through PR 8). The guard holds ONLY when every enclosing
-            # fallback frame left sp unmentioned in its specs, i.e. the
-            # inputs here are genuinely replicated along sp — then the
-            # Ulysses all-to-all round trip is the identity up to
-            # layout, and local attention on the full arrays is
-            # bit-identical (redundant compute along sp, the documented
-            # cost of this fallback; see utils/jax_compat.shard_map).
-            # An outer region that actually SHARDS the sequence along
-            # sp keeps the old loud lowering error instead of silently
-            # computing block-diagonal attention. On jax >= 0.5
-            # partial-manual nesting works and this never triggers.
             return self.local_attn(q, k, v, causal=causal, **kw)
 
         nq, nkv = q.shape[2], k.shape[2]

@@ -25,6 +25,7 @@ dispatch.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import re
 from typing import Optional
@@ -52,6 +53,10 @@ _GROUPS_BRACE_RE = re.compile(r"replica_groups=\{(\{[0-9,{} ]*\})\}")
 _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{([0-9,{} ]*)\}")
+# computation header: "%name (params) -> type {" / "ENTRY %main (..) -> .. {"
+_COMPUTATION_RE = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s+\(.*->.*\{\s*$")
+_CUSTOM_CALL_RE = re.compile(r'custom_call_target="([^"]+)"')
 
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "s4": 1, "u2": 1, "u4": 1,
@@ -177,14 +182,27 @@ def analyze_hlo(hlo_text: str, mesh=None,
     qwZ/qgZ payload counts 1 byte/element, so the quantized wire's win
     lands in ``ds_hlo_collective_bytes_total{axis,op}`` without any
     assumed element width. Async ``-start`` ops count once; their
-    ``-done`` halves are ignored."""
+    ``-done`` halves are ignored.
+
+    The TPU compiler emits a reduce-scatter as a ``kCustom`` fusion
+    whose computation is named ``all-reduce-scatter*`` and holds an
+    ``all-reduce`` of the full input followed by a ``dynamic-slice``
+    (seen in the v5e HLO of a ZeRO-3 step, PR 21); such an all-reduce
+    is recorded as the reduce-scatter it implements."""
     axis_table = mesh_axis_groups(mesh)
     records: list[dict] = []
+    computation = ""
     for line in hlo_text.splitlines():
+        header = _COMPUTATION_RE.match(line)
+        if header is not None:
+            computation = header.group("name")
+            continue
         m = _OP_RE.search(line)
         if m is None or "-done" in line.split("=", 1)[0]:
             continue
         hlo_op = m.group("op")
+        fused_rs = (hlo_op == "all-reduce"
+                    and computation.startswith("all-reduce-scatter"))
         out_bytes, out_elements = _shapes_bytes(m.group("shapes"))
         groups = _parse_groups(line)
         axis = None
@@ -216,6 +234,10 @@ def analyze_hlo(hlo_text: str, mesh=None,
         if hlo_op == "reduce-scatter":
             payload = out_bytes * group_size
             elements = out_elements * group_size
+        if fused_rs:
+            # the all-reduce's result IS the full input: already the
+            # reduce-scatter payload convention
+            hlo_op = "reduce-scatter"
         records.append({
             "op": HLO_TO_COMM_OP[hlo_op],
             "hlo_op": hlo_op + ("-start" if m.group("start") else ""),
@@ -228,6 +250,15 @@ def analyze_hlo(hlo_text: str, mesh=None,
             "groups": len(groups) if groups else 1,
         })
     return records
+
+
+def custom_call_targets(hlo_text: str) -> dict[str, int]:
+    """``{custom_call_target: instruction count}`` over optimized HLO
+    text. A Pallas kernel compiled through Mosaic is a
+    ``tpu_custom_call``; in interpret mode it is plain HLO and leaves
+    no custom call — so this is how an executable shows that its
+    kernels were compiled, not interpreted."""
+    return dict(collections.Counter(_CUSTOM_CALL_RE.findall(hlo_text)))
 
 
 def traffic_matrix(records: list[dict], calls: int = 1) -> dict:

@@ -53,8 +53,8 @@ class ExecutableEntry:
     """Ledger row for one compiled executable."""
 
     __slots__ = ("name", "signature", "flops", "bytes_accessed",
-                 "memory", "collectives", "traffic", "calls",
-                 "registered_unix", "register_error")
+                 "memory", "collectives", "traffic", "custom_calls",
+                 "calls", "registered_unix", "register_error")
 
     def __init__(self, name: str, signature: tuple):
         self.name = name
@@ -64,6 +64,9 @@ class ExecutableEntry:
         self.memory: dict = {}
         self.collectives: list[dict] = []
         self.traffic: dict = {}
+        # {custom_call_target: count}; "tpu_custom_call" = a Pallas
+        # kernel compiled through Mosaic (absent in interpret mode)
+        self.custom_calls: dict[str, int] = {}
         self.calls = 0
         self.registered_unix = time.time()
         self.register_error = ""
@@ -97,6 +100,7 @@ class ExecutableEntry:
             "peak_hbm_bytes": self.peak_hbm_bytes,
             "calls": self.calls,
             "collectives": list(self.collectives),
+            "custom_calls": dict(self.custom_calls),
             "register_error": self.register_error,
         }
 
@@ -157,10 +161,12 @@ class ExecutableLedger:
         entry.memory = compiled_memory(compiled)
         if self.hlo_collectives:
             try:
+                hlo = compiled.as_text()
                 entry.collectives = _collectives.analyze_hlo(
-                    compiled.as_text(), mesh=mesh, n_devices=n_devices)
+                    hlo, mesh=mesh, n_devices=n_devices)
                 entry.traffic = _collectives.traffic_matrix(
                     entry.collectives)
+                entry.custom_calls = _collectives.custom_call_targets(hlo)
             except Exception as e:   # noqa: BLE001
                 entry.register_error = (
                     f"hlo: {type(e).__name__}: {e}"[:200])
@@ -330,13 +336,11 @@ def set_ledger(ledger: Optional[ExecutableLedger]) -> None:
 
 def device_peak_flops(configured: float = 0.0) -> float:
     """Per-device peak FLOPs for MFU accounting: the configured value
-    when nonzero, else the accelerator table (1e12 CPU floor — an
-    arbitrary but finite denominator, clearly an estimate on hosts
-    with no published peak)."""
+    when nonzero, else the accelerator table. A TPU ``device_kind``
+    missing from the table raises (accelerator/tpu_accelerator.py); the
+    CPU accelerator's 1e12 is an arbitrary floor for the CPU test rig,
+    not a device peak."""
     if configured and configured > 0:
         return float(configured)
-    try:
-        from ..accelerator import get_accelerator
-        return float(get_accelerator().peak_flops())
-    except Exception:
-        return 1e12
+    from ..accelerator import get_accelerator
+    return float(get_accelerator().peak_flops())

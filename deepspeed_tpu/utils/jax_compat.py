@@ -1,130 +1,30 @@
-"""Compatibility shims over the jax API surface that moved between the
-0.4.x and 0.5+ lines. The repo is written against the current API
-(``jax.shard_map`` with ``axis_names``/``check_vma``,
-``jax.sharding.get_abstract_mesh``); on older jax these fall back to
-``jax.experimental.shard_map`` (``auto``/``check_rep``) so the same
-call sites run on both.
+"""Thin helpers over the jax API surface the repo is written against
+(jax 0.9: ``jax.shard_map`` with ``axis_names``/``check_vma``), plus
+normalisers for what ``Compiled.cost_analysis()`` /
+``memory_analysis()`` return on the backends the repo runs on.
 """
 
 from __future__ import annotations
-
-import threading
 
 import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` when available; otherwise the experimental
-    entry point with ``axis_names`` translated to its complement
-    (``auto``) and ``check_vma`` to ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # No ``auto=``: 0.4.x's partial-manual mode CHECK-fails in the SPMD
-    # partitioner once an auto axis has size > 1 (ManualSubgroup
-    # mismatch, spmd_partitioner.cc:512). Full manual instead — axes
-    # outside ``axis_names`` are simply unmentioned by the specs, so
-    # inputs replicate and compute is redundant along them (correct,
-    # incl. transpose: unmentioned-axis grads verified unscaled on
-    # 0.4.37); the perf cost only exists on this fallback.
-    replicated = frozenset(mesh.axis_names) - _spec_axes(
-        (in_specs, out_specs))
-
-    def traced(*args, **kw):
-        # record, for the duration of the body trace, which axes THIS
-        # fallback frame replicates — nested code (DistributedAttention)
-        # uses it to decide whether a further shard_map over such an
-        # axis may legally collapse to redundant local compute instead
-        # of crashing the 0.4.x lowering (manual-axes collision)
-        frames = _fallback_frames()
-        frames.append(replicated)
-        try:
-            return f(*args, **kw)
-        finally:
-            frames.pop()
-
-    return _shard_map(traced, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-# per-thread: traces may run concurrently (e.g. the async serving
-# worker thread next to the main thread) and one thread's fallback
-# frame must not leak into another's nesting decision
-_FALLBACK_TLS = threading.local()
-
-
-def _fallback_frames() -> list:
-    frames = getattr(_FALLBACK_TLS, "frames", None)
-    if frames is None:
-        frames = _FALLBACK_TLS.frames = []
-    return frames
-
-
-def _spec_axes(specs) -> frozenset:
-    """Mesh axis names mentioned anywhere in a PartitionSpec pytree."""
-    from jax.sharding import PartitionSpec
-    out: set = set()
-
-    def visit(s):
-        if isinstance(s, PartitionSpec):
-            for entry in s:
-                if entry is None:
-                    continue
-                if isinstance(entry, (tuple, list)):
-                    out.update(a for a in entry if a is not None)
-                else:
-                    out.add(entry)
-        elif isinstance(s, (tuple, list)):
-            for e in s:
-                visit(e)
-        elif isinstance(s, dict):
-            for e in s.values():
-                visit(e)
-
-    visit(specs)
-    return frozenset(out)
-
-
-def fallback_replicated_axes() -> frozenset:
-    """Axes guaranteed REPLICATED (unmentioned in the specs, so inputs
-    broadcast and compute is redundant along them) by EVERY enclosing
-    0.4.x full-manual :func:`shard_map` fallback frame. Empty outside
-    the fallback — including on jax >= 0.5, whose partial-manual
-    shard_map nests fine and never pushes a frame. A nested shard_map
-    over one of these axes cannot lower on 0.4.x (its spec'd axes
-    collide with the outer manual set), but because the inputs are
-    replicated along it, running the body's local computation on the
-    full arrays is bit-identical — callers use this to take that exit
-    ONLY when the replication guarantee actually holds. Frames are
-    per-thread: a trace running on another thread never alters this
-    thread's answer."""
-    frames = _fallback_frames()
-    if not frames:
-        return frozenset()
-    out = frames[0]
-    for s in frames[1:]:
-        out = out & s
-    return out
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()``, or None before it existed
-    (callers treat None as "no mesh context active")."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    return getter() if getter is not None else None
+    """``jax.shard_map`` with the repo's defaults (``check_vma`` off;
+    ``axis_names=None`` means every mesh axis is manual)."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """One dict shape for ``Compiled.cost_analysis()`` across backends
-    and jax versions. The raw return is a dict on 0.5+, a
-    LIST-of-one-dict on the 0.4.x line, and ``None``/``[]``/``{}`` on
-    backends (CPU notably) that expose no cost model for a given
-    executable. Callers always get a plain dict with float values —
+    """One dict shape for ``Compiled.cost_analysis()`` across backends.
+    The raw return is a dict, a LIST-of-one-dict on some backends, and
+    ``None``/``[]``/``{}`` on backends (CPU notably) that expose no
+    cost model for a given executable. Callers always get a plain dict with float values —
     possibly empty, never None — so ``.get("flops", 0.0)`` is safe
     everywhere."""
     if isinstance(cost, (list, tuple)):
@@ -182,13 +82,10 @@ def normalize_memory_analysis(mem) -> dict:
 
 
 def supports_pinned_host() -> bool:
-    """Whether the backend exposes a ``pinned_host`` memory tier (the
-    0.4.x CPU backend only has ``unpinned_host``). The single source of
-    truth for offload placement decisions and the placement asserts in
-    tests — False on any probe failure, so callers skip host placement
-    rather than crash constructing a NamedSharding."""
-    try:
-        return any(m.kind == "pinned_host"
-                   for m in jax.devices()[0].addressable_memories())
-    except Exception:
-        return False
+    """Whether the backend exposes a ``pinned_host`` memory tier. The
+    single source of truth for offload placement decisions and the
+    placement asserts in tests. A probe failure propagates: a backend
+    that cannot list its memories is a fault to surface, not a reason
+    to keep "offloaded" state in HBM."""
+    return any(m.kind == "pinned_host"
+               for m in jax.devices()[0].addressable_memories())

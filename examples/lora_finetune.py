@@ -15,9 +15,11 @@ import deepspeed_tpu as ds  # noqa: E402
 from deepspeed_tpu.linear import (LoRAConfig, LoRAModel,  # noqa: E402
                                   QuantizationConfig)
 from deepspeed_tpu.models import GPT2  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     model = LoRAModel(
         GPT2(size="tiny"),
         LoRAConfig(lora_r=8, lora_alpha=16, target_mods=[]),

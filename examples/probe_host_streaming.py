@@ -18,6 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 dev = jax.devices()[0]
 mesh = Mesh([dev], ("x",))
 host = NamedSharding(mesh, P(), memory_kind="pinned_host")

@@ -10,9 +10,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import numpy as np  # noqa: E402
 from deepspeed_tpu.inference.v2 import build_engine  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     engine = build_engine("llama", size="tiny",
                           engine_config={"num_kv_blocks": 128,
                                          "kv_block_size": 64,

@@ -34,9 +34,11 @@ import numpy as np
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import Llama
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--layers", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4)

@@ -1,7 +1,10 @@
 """Train GPT-2 with ZeRO-3 + bf16 on whatever devices are visible.
 
-Run:  python examples/train_gpt2.py  [--steps 50]
-(On a CPU dev box: XLA_FLAGS=--xla_force_host_platform_device_count=8)
+Run:  python examples/train_gpt2.py  [--steps 50] [--size tiny|125m]
+(On a CPU dev box: JAX_PLATFORMS=cpu
+ XLA_FLAGS=--xla_force_host_platform_device_count=8)
+The model and sequence length come from --size alone: the same program
+runs on every backend.
 """
 
 import argparse
@@ -13,6 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import jax  # noqa: E402
 import deepspeed_tpu as ds  # noqa: E402
 from deepspeed_tpu.models import GPT2  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -21,8 +25,10 @@ def main():
     ap.add_argument("--size", default="tiny", choices=["tiny", "125m"])
     args = ap.parse_args()
 
-    on_tpu = jax.devices()[0].platform != "cpu"
-    seq = 1024 if on_tpu and args.size != "tiny" else 64
+    enable_compile_cache()
+    print("devices:", jax.devices())
+    model = GPT2(size=args.size)
+    seq = model.config.max_seq_len      # tiny: 128, 125m: 1024
     batch = 16
 
     config = {
@@ -38,7 +44,6 @@ def main():
         "mesh": {"fsdp": -1},
         "steps_per_print": 5,
     }
-    model = GPT2(size=args.size, max_seq_len=max(seq, 64))
     engine, _, _, _ = ds.initialize(model=model, config=config)
 
     key = jax.random.PRNGKey(0)

@@ -306,10 +306,12 @@ def test_ep_sharded_dispatch_sum_parity(devices8):
                            drop_tokens=False, activation="swiglu")
     disp = EpShardedDispatcher.for_topology(topo)
     assert disp.slow_axes == ("fsdp",) and disp.fast_axes == ("zps",)
+    # jitted, as the engine runs it: eager shard_map dispatches op by op
     with topo.mesh:
-        out, aux = moe_ffn(x, gate_w, experts, k=2, capacity_factor=0.0,
-                           drop_tokens=False, activation="swiglu",
-                           dispatcher=disp)
+        out, aux = jax.jit(lambda x, gate_w, experts: moe_ffn(
+            x, gate_w, experts, k=2, capacity_factor=0.0,
+            drop_tokens=False, activation="swiglu",
+            dispatcher=disp))(x, gate_w, experts)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-6)
@@ -326,7 +328,7 @@ def test_ep_sharded_dispatch_sum_parity(devices8):
         return jnp.sum(o * o), o
 
     with moe_step(3):
-        (v, o8), g = jax.value_and_grad(loss, has_aux=True)(x)
+        (v, o8), g = jax.jit(jax.value_and_grad(loss, has_aux=True))(x)
     assert bool(jnp.all(jnp.isfinite(g)))
     denom = float(jnp.max(jnp.abs(ref))) or 1.0
     assert float(jnp.max(jnp.abs(o8 - ref))) / denom < 0.05

@@ -46,8 +46,9 @@ def test_flash_grads_match_reference(hq, hkv):
     def f_ref(q, k, v):
         return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
 
-    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    # jitted: eager interpret-mode Pallas dispatches op by op (~3x slower)
+    gf = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -4,10 +4,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import Llama
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ga = int(sys.argv[1]) if len(sys.argv) > 1 else 8
 stream_dtype = sys.argv[2] if len(sys.argv) > 2 else "master"

@@ -6,6 +6,9 @@ import numpy as np
 import jax, jax.numpy as jnp
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import Llama, Mixtral
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 moe = Mixtral(hidden_size=1024, num_layers=12, num_heads=8, num_kv_heads=8,
               intermediate_size=2816, num_experts=8, moe_top_k=2,

@@ -8,6 +8,9 @@ import numpy as np
 import jax, jax.numpy as jnp
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import Llama, Mixtral
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 def decode_tps(model, B, P=128, N=64, **kw):
     e = ds.init_inference(model, dtype="bfloat16", max_out_tokens=512, **kw)

@@ -34,6 +34,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from deepspeed_tpu.runtime.domino import DominoTransformerLayer  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 ASYNC_FLAGS = {
     "xla_tpu_enable_async_collective_fusion": "true",
@@ -83,6 +84,7 @@ def compile_counts(rows: int, n_micro: int = 4, d: int = 4096,
 
 
 def main() -> dict:
+    enable_compile_cache()
     small = compile_counts(rows=4096)
     big = compile_counts(rows=32768)
     big_async = compile_counts(rows=32768, opts=ASYNC_FLAGS)
