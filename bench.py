@@ -79,20 +79,19 @@ def _mfu_fields(tps: float, cfg, seq: int) -> dict:
     full-attention figure rides along as mfu_noncausal for
     cross-framework comparison (VERDICT r2 weak #1). With --telemetry,
     the device-truth fields from the executable ledger (ISSUE 5) ride
-    along: compiler-measured MFU and peak HBM of the compiled step."""
+    along: the compiled step's peak HBM and collective payload."""
     peak = peak_flops(jax.devices()[0])
     return {"mfu": round(tps * cfg.flops_per_token(seq) / peak, 4),
             "mfu_noncausal": round(
                 tps * cfg.flops_per_token(seq, causal=False) / peak, 4),
-            **_ledger_truth_fields(peak), **_steptrace_fields()}
+            **_ledger_truth_fields(), **_steptrace_fields()}
 
 
-def _ledger_truth_fields(peak: float) -> dict:
-    """{mfu_hlo, hbm_peak_bytes} from the telemetry executable ledger
-    when it is live (bench --telemetry): MFU from the compiled step's
-    own cost_analysis() FLOPs over the measured span window, and the
-    largest registered executable's compiler-reported peak HBM. Empty
-    when telemetry/ledger are off."""
+def _ledger_truth_fields() -> dict:
+    """{hbm_peak_bytes, wire bytes} from the telemetry executable ledger
+    when it is live (bench --telemetry): the largest registered
+    executable's compiler-reported peak HBM and the HLO-accounted
+    collective payload. Empty when telemetry/ledger are off."""
     from deepspeed_tpu.utils.telemetry_probe import active_telemetry
     mod = active_telemetry()
     led = mod.get_ledger() if mod is not None else None
@@ -102,11 +101,6 @@ def _ledger_truth_fields(peak: float) -> dict:
     peaks = led.peak_hbm_by_name()
     if peaks:
         out["hbm_peak_bytes"] = max(peaks.values())
-    tracer = mod.get_tracer()
-    if tracer is not None:
-        mfu = led.mfu_by_name(tracer.totals_trimmed(), peak)
-        if "compiled_step" in mfu:
-            out["mfu_hlo"] = round(mfu["compiled_step"], 4)
     # per-axis collective payload + observed wire width (ISSUE 8):
     # train-stage artifacts carry the HLO-accounted bytes so the
     # `--gate comms` diff family can watch them across rounds, and the
@@ -3283,7 +3277,7 @@ def main(argv=None):
     if args.telemetry:
         from deepspeed_tpu import telemetry
         # full device-truth stack (ISSUE 5): executable ledger for
-        # mfu_hlo/hbm_peak_bytes stage fields, flight recorder so the
+        # hbm_peak_bytes stage fields, flight recorder so the
         # total-budget watchdog can leave forensics behind
         telemetry.configure(executable_ledger=True,
                             flight_recorder=True,

@@ -9,12 +9,20 @@ Top-level API mirrors ``deepspeed/__init__.py``:
   - ``zero`` — ZeRO sharding utilities
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # imports run before telemetry can be on
+
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
 from . import comm  # noqa: F401
 from .runtime.config import DeepSpeedConfig  # noqa: F401
 from .parallel.mesh import MeshTopology, TopologyConfig, get_topology, set_topology  # noqa: F401
+
+# seconds this package's own imports took (jax included when this is the
+# first import of it): the part of a run's set-up no span can cover
+IMPORT_SECONDS = _time.perf_counter() - _T_IMPORT
 
 
 def initialize(args=None,

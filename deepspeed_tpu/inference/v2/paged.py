@@ -282,6 +282,7 @@ def paged_attention_kernel(q, k_new, v_new, k_pool, v_pool, block_tables,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=jax.default_backend() != "tpu",
+        name="ds_paged_attention",
     )(counts, tables, jnp.asarray(pos0, jnp.int32),
       jnp.asarray(true_len, jnp.int32), *operands)
     return out.astype(q.dtype)

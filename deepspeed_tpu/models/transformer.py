@@ -263,14 +263,19 @@ class DecoderLM:
         if c.remat and c.remat_policy == "segments":
             return self._block_segmented(p, x, attn_fn, positions)
 
-        h = self._norm(x, p["ln1_scale"], p.get("ln1_bias"))
-        q, k, v = self._qkv(p, h, positions)
-        a = attn_fn(q, k, v, causal=True)
+        # device scopes (telemetry/scopes.py DEVICE_SCOPES): HLO metadata a
+        # device trace is read by, no run-time cost
+        with jax.named_scope("ds.attn"):
+            h = self._norm(x, p["ln1_scale"], p.get("ln1_bias"))
+            q, k, v = self._qkv(p, h, positions)
+            attn_out = self._attn_out(p, attn_fn(q, k, v, causal=True))
         if c.parallel_residual:
-            m, aux = self._mlp(p, self._parallel_mlp_input(p, x, h))
-            return x + self._attn_out(p, a) + m, aux
-        x = x + self._attn_out(p, a)
-        return self._mlp_residual(p, x)
+            with jax.named_scope("ds.mlp"):
+                m, aux = self._mlp(p, self._parallel_mlp_input(p, x, h))
+            return x + attn_out + m, aux
+        x = x + attn_out
+        with jax.named_scope("ds.mlp"):
+            return self._mlp_residual(p, x)
 
     def _block_segmented(self, p, x, attn_fn, positions):
         """Segment remat: attention sits OUTSIDE any jax.checkpoint, so
@@ -298,16 +303,21 @@ class DecoderLM:
             q, k, v = self._qkv(p, h, positions)
             return q, k, v, (h if c.parallel_residual else None)
 
-        q, k, v, h = jax.checkpoint(seg_qkv, prevent_cse=False)(p, x)
-        a = attn_fn(q, k, v, causal=True)
+        with jax.named_scope("ds.attn"):
+            q, k, v, h = jax.checkpoint(seg_qkv, prevent_cse=False)(p, x)
+            a = attn_fn(q, k, v, causal=True)
 
         def seg_out(p, x, a, h):
+            with jax.named_scope("ds.attn"):
+                attn_out = self._attn_out(p, a)
             if c.parallel_residual:
-                m, aux = self._mlp(p, self._parallel_mlp_input(p, x, h))
-                return x + self._attn_out(p, a) + m, aux
-            x2 = x + self._attn_out(p, a)
-            x2 = checkpoint_name(x2, "resid_mid")
-            return self._mlp_residual(p, x2)
+                with jax.named_scope("ds.mlp"):
+                    m, aux = self._mlp(
+                        p, self._parallel_mlp_input(p, x, h))
+                return x + attn_out + m, aux
+            x2 = checkpoint_name(x + attn_out, "resid_mid")
+            with jax.named_scope("ds.mlp"):
+                return self._mlp_residual(p, x2)
 
         pol = jax.checkpoint_policies.save_only_these_names(
             "resid_mid", "ffn_pre")
@@ -411,7 +421,8 @@ class DecoderLM:
         x, aux = self._final_hidden(params, tokens, attn_fn=attn_fn,
                                     positions=positions,
                                     act_sharding=act_sharding)
-        logits = self._project_vocab(params, x)
+        with jax.named_scope("ds.loss_head"):
+            logits = self._project_vocab(params, x)
         return (logits, aux) if return_aux else logits
 
     def loss(self, params: PyTree, batch: Any, *,
@@ -425,7 +436,8 @@ class DecoderLM:
         logits, aux = self.apply(params, tokens, attn_fn=attn_fn,
                                  return_aux=True,
                                  act_sharding=act_sharding)
-        ce = L.cross_entropy_loss(logits, targets)
+        with jax.named_scope("ds.loss_head"):
+            ce = L.cross_entropy_loss(logits, targets)
         return ce + self.aux_loss_coef() * aux
 
     def _chunked_loss(self, params: PyTree, tokens, targets, *,
@@ -439,6 +451,14 @@ class DecoderLM:
         c = self.config
         x, aux = self._final_hidden(params, tokens, attn_fn=attn_fn,
                                     act_sharding=act_sharding)
+        with jax.named_scope("ds.loss_head"):
+            ce = self._chunked_ce(params, x, targets)
+        return ce + self.aux_loss_coef() * aux
+
+    def _chunked_ce(self, params: PyTree, x, targets) -> jax.Array:
+        """Mean cross-entropy of final-normed hidden states ``x``, one
+        ``loss_chunk`` slab of logits at a time (see _chunked_loss)."""
+        c = self.config
         W = (params["embed"]["tokens"].T if c.tie_embeddings
              else params["lm_head"])
         b, s, d = x.shape
@@ -475,8 +495,7 @@ class DecoderLM:
         (nll, cnt), _ = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
             (xc, tc))
-        ce = nll / jnp.maximum(cnt, 1)
-        return ce + self.aux_loss_coef() * aux
+        return nll / jnp.maximum(cnt, 1)
 
     def _final_hidden(self, params: PyTree, tokens, *, attn_fn=None,
                       positions=None, act_sharding=None):
@@ -490,7 +509,8 @@ class DecoderLM:
         config that produced 'Involuntary full rematerialization'
         resharding of the embed gradient scatter-add (VERDICT r4 #2)."""
         c = self.config
-        x = self.embed(params, tokens, positions)
+        with jax.named_scope("ds.embed"):
+            x = self.embed(params, tokens, positions)
         if act_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, act_sharding)
 
@@ -508,10 +528,12 @@ class DecoderLM:
             # would re-introduce the flash fwd rerun it exists to avoid
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=_remat_policy(c.remat_policy))
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-        x = self._norm(x, params["final_norm"]["scale"],
-                       params["final_norm"].get("bias"))
+        with jax.named_scope("ds.layers"):
+            (x, aux), _ = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+        with jax.named_scope("ds.loss_head"):
+            x = self._norm(x, params["final_norm"]["scale"],
+                           params["final_norm"].get("bias"))
         return x, aux
 
     def _project_vocab(self, params: PyTree, x: jax.Array) -> jax.Array:

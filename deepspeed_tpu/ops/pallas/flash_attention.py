@@ -90,7 +90,7 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
     grid = (bh, s // bq)
     kernel = functools.partial(_fwd_kernel, sc=sc, bq=bq, bk=bk,
                                nk=s // bk, causal=causal, window=window)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -113,7 +113,12 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
-    )(q, k, v)
+        name="ds_flash_fwd",
+    )
+    # the scope and the kernel's name are all a device trace shows of this
+    # call (telemetry/scopes.py); both are HLO metadata and cost no time
+    with jax.named_scope("ds.flash_fwd"):
+        o, lse = call(q, k, v)
     return o.astype(q.dtype), lse
 
 
@@ -233,7 +238,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
                         memory_space=pltpu.VMEM)
     rowstat = pl.BlockSpec((1, 1, s), lambda b, j: (b, 0, 0),
                            memory_space=pltpu.VMEM)
-    dq, dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, sc=sc, bq=bq, bk=bk,
                           nq=s // bq, causal=causal, window=window),
         grid=(bh, s // bk),
@@ -244,7 +249,12 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
                    jax.ShapeDtypeStruct((bh, s, d), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+        name="ds_flash_bwd",
+    )
+    # opened here, inside the custom_vjp's backward function, so that the
+    # scope survives shard_map and remat
+    with jax.named_scope("ds.flash_bwd"):
+        dq, dk, dv = call(q, k, v, do, lse, delta)
     if rep > 1:
         # per-q-head dk/dv -> per-kv-head (consecutive q heads share kv)
         dk = dk.reshape(bh // rep, rep, s, d).sum(axis=1)

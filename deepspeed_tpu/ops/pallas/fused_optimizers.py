@@ -125,6 +125,7 @@ def fused_adam(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
                 out_specs=[spec, spec, spec],
                 out_shape=[jax.ShapeDtypeStruct(p2.shape, jnp.float32)] * 3,
                 interpret=_interpret(),
+                name="ds_fused_adam",
             )(p2.astype(jnp.float32), g2, m2, v2, hp)
             delta = _unpad(new_p - p2.astype(jnp.float32), n, p.shape, p.dtype)
             return delta, _unpad(new_m, n, p.shape, jnp.float32), \
@@ -195,6 +196,7 @@ def fused_lion(learning_rate, b1=0.9, b2=0.99,
                 out_specs=[spec, spec],
                 out_shape=[jax.ShapeDtypeStruct(p2.shape, jnp.float32)] * 2,
                 interpret=_interpret(),
+                name="ds_fused_lion",
             )(p2.astype(jnp.float32), g2, m2, hp)
             delta = _unpad(new_p - p2.astype(jnp.float32), n, p.shape, p.dtype)
             return delta, _unpad(new_m, n, p.shape, jnp.float32)

@@ -60,6 +60,7 @@ def rms_norm(x, scale, eps: float = 1e-6):
         out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(rows.shape, x.dtype),
+        name="ds_rms_norm",
     )(rows, scale)
     return out.reshape(x.shape)
 
@@ -96,6 +97,7 @@ def layer_norm(x, scale, bias, eps: float = 1e-5):
         out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(rows.shape, x.dtype),
+        name="ds_layer_norm",
     )(rows, scale, bias)
     return out.reshape(x.shape)
 

@@ -214,15 +214,13 @@ class TelemetryConfig(DeepSpeedConfigModel):
     # --- device-truth layer (ISSUE 5), opt-in on top of enabled ------
     # register every observed compiled executable's cost_analysis()/
     # memory_analysis() (FLOPs, HBM) keyed by jit name + shape
-    # signature; feeds the ds_mfu / ds_ledger_* / HBM-headroom gauges.
+    # signature; feeds the ds_ledger_* / HBM-headroom gauges and the
+    # <prefix>.op_scopes.json artifact (HLO instruction -> device scope).
     # Costs ONE extra backend compile per new executable at warmup.
     executable_ledger: bool = False
     # walk each registered executable's HLO for collective ops and
     # attribute payload bytes to mesh axes (requires executable_ledger)
     hlo_collectives: bool = True
-    # device peak FLOPs for the MFU denominator (0 = accelerator
-    # table; CPU uses an arbitrary 1e12 floor)
-    device_peak_flops: float = 0.0
     # per-rank ring buffer of recent dispatch/progress events, dumped
     # on hangs (telemetry/flightrec.py)
     flight_recorder: bool = False

@@ -50,7 +50,8 @@ PyTree = Any
 # telemetry guard (ISSUE 2): sys.modules probe, NOT an import — the
 # disabled path never imports the package or allocates tracer state
 from ..utils.telemetry_probe import (NULL_CM as _NULLCM,  # noqa: E402
-                                     active_telemetry as _telemetry)
+                                     active_telemetry as _telemetry,
+                                     tel_span as _tel_span)
 
 # span-name -> reference _write_monitor label for the wall_clock_breakdown
 # events (reference engine.py:2348: Train/Samples/elapsed_time_ms_*)
@@ -87,33 +88,45 @@ class DeepSpeedEngine:
         self.config = DeepSpeedConfig.from_any(config)
         dist.init_distributed(config=self.config)
 
+        # telemetry (ISSUE 2): explicit opt-in, or implied by
+        # wall_clock_breakdown — the fwd/bwd/step breakdown events are
+        # sourced from span data, so the tracer must be live for them
+        if self.config.telemetry.enabled or self.config.wall_clock_breakdown:
+            from ..utils.telemetry_probe import activate
+            activate(self.config.telemetry)
+        # set-up phases (init/topology, init/state, init/build_step: the
+        # layers under a benchmark's setup_s) are spans like any other:
+        # the probe is their only cost with telemetry off
+
         # --- mesh/topology (reference: _configure_distributed_model) ----
-        mesh_cfg = self.config.mesh
-        zcfg0 = self.config.zero_optimization
-        # ZeRO++ hpZ / MiCS: carve the shard subgroup out of fsdp as the
-        # inner zps axis (see ZeroShardingPlan docstring)
-        zps = mesh_cfg.zps
-        if zcfg0.zero_hpz_partition_size > 1 and zcfg0.mics_shard_size > 1:
-            raise ValueError(
-                "zero_hpz_partition_size and mics_shard_size are mutually "
-                "exclusive sharding modes; set only one")
-        sub = max(zcfg0.zero_hpz_partition_size,
-                  zcfg0.mics_shard_size if zcfg0.mics_shard_size > 1 else 1)
-        if sub > 1 and zps == 1:
-            zps = sub
-            if mesh_cfg.fsdp not in (-1, 1):
-                if mesh_cfg.fsdp % sub != 0:
-                    raise ValueError(
-                        f"mesh.fsdp={mesh_cfg.fsdp} is not divisible by "
-                        f"zero_hpz_partition_size/mics_shard_size={sub}")
-                mesh_cfg = mesh_cfg.model_copy(
-                    update={"fsdp": mesh_cfg.fsdp // sub})
-        self.topology = MeshTopology(TopologyConfig(
-            pp=mesh_cfg.pp, dp=mesh_cfg.dp, fsdp=mesh_cfg.fsdp, zps=zps,
-            ep=mesh_cfg.ep, sp=mesh_cfg.sp, tp=mesh_cfg.tp),
-            dcn=mesh_cfg.dcn)
-        set_topology(self.topology)
-        self.mesh = self.topology.mesh
+        with _tel_span("init/topology"):
+            mesh_cfg = self.config.mesh
+            zcfg0 = self.config.zero_optimization
+            # ZeRO++ hpZ / MiCS: carve the shard subgroup out of fsdp as the
+            # inner zps axis (see ZeroShardingPlan docstring)
+            zps = mesh_cfg.zps
+            if zcfg0.zero_hpz_partition_size > 1 and zcfg0.mics_shard_size > 1:
+                raise ValueError(
+                    "zero_hpz_partition_size and mics_shard_size are mutually "
+                    "exclusive sharding modes; set only one")
+            sub = max(zcfg0.zero_hpz_partition_size,
+                      zcfg0.mics_shard_size
+                      if zcfg0.mics_shard_size > 1 else 1)
+            if sub > 1 and zps == 1:
+                zps = sub
+                if mesh_cfg.fsdp not in (-1, 1):
+                    if mesh_cfg.fsdp % sub != 0:
+                        raise ValueError(
+                            f"mesh.fsdp={mesh_cfg.fsdp} is not divisible by "
+                            f"zero_hpz_partition_size/mics_shard_size={sub}")
+                    mesh_cfg = mesh_cfg.model_copy(
+                        update={"fsdp": mesh_cfg.fsdp // sub})
+            self.topology = MeshTopology(TopologyConfig(
+                pp=mesh_cfg.pp, dp=mesh_cfg.dp, fsdp=mesh_cfg.fsdp, zps=zps,
+                ep=mesh_cfg.ep, sp=mesh_cfg.sp, tp=mesh_cfg.tp),
+                dcn=mesh_cfg.dcn)
+            set_topology(self.topology)
+            self.mesh = self.topology.mesh
 
         # --- batch sizes ------------------------------------------------
         dp = self.topology.data_parallel_size
@@ -208,68 +221,70 @@ class DeepSpeedEngine:
                  if hasattr(self.module, "partition_rules") else [])
 
         # --- state init (reference: zero.Init + _configure_optimizer) ---
-        rng = jax.random.PRNGKey(self.config.seed)
-        if model_parameters is not None:
-            params_host = model_parameters
-            abstract = jax.eval_shape(lambda: params_host)
-        else:
-            abstract = jax.eval_shape(self.module.init, rng)
-        if zcfg.zero_hierarchical_allgather:
-            from .zeropp import hierarchical_allgather_unsupported_reason
-            why = hierarchical_allgather_unsupported_reason(
-                self.mesh, hpz=zcfg.zero_hpz_partition_size > 1,
-                mics=zcfg.mics_shard_size > 1)
-            if why is not None:
-                raise ValueError(why)
-        self.plan = ZeroShardingPlan(
-            self.zero_stage, self.mesh, rules, abstract,
-            offload_optimizer=zcfg.offload_optimizer.device == "cpu",
-            pipeline=self._is_pipeline,
-            hpz=zcfg.zero_hpz_partition_size > 1,
-            mics=zcfg.mics_shard_size > 1)
-        self._build_state_shardings(abstract)
-
-        # NVMe tier keeps master+moments off-device entirely (host RAM /
-        # disk via the native AIO op); cpu tier keeps them as pinned_host
-        # arrays inside the compiled step (see runtime/offload.py)
-        self._nvme_offload = zcfg.offload_optimizer.device == "nvme"
-        self._offload_opt = None
-
-        def _init_state(rng_or_params):
-            if model_parameters is None:
-                params32 = self.module.init(rng_or_params)
+        with _tel_span("init/state"):
+            rng = jax.random.PRNGKey(self.config.seed)
+            if model_parameters is not None:
+                params_host = model_parameters
+                abstract = jax.eval_shape(lambda: params_host)
             else:
-                params32 = rng_or_params
-            params32 = jax.tree.map(lambda x: x.astype(jnp.float32), params32)
-            params = jax.tree.map(
-                lambda x: x.astype(self.compute_dtype), params32)
-            master = (params32 if self._mixed and not self._nvme_offload
-                      else None)
-            opt_state = (() if self._nvme_offload
-                         else self.tx.init(params32))
-            return {"step": jnp.zeros((), jnp.int32),
-                    "params": params,
-                    "master": master,
-                    "opt_state": opt_state,
-                    "loss_scale": init_loss_scale(self.config.fp16)}
+                abstract = jax.eval_shape(self.module.init, rng)
+            if zcfg.zero_hierarchical_allgather:
+                from .zeropp import hierarchical_allgather_unsupported_reason
+                why = hierarchical_allgather_unsupported_reason(
+                    self.mesh, hpz=zcfg.zero_hpz_partition_size > 1,
+                    mics=zcfg.mics_shard_size > 1)
+                if why is not None:
+                    raise ValueError(why)
+            self.plan = ZeroShardingPlan(
+                self.zero_stage, self.mesh, rules, abstract,
+                offload_optimizer=zcfg.offload_optimizer.device == "cpu",
+                pipeline=self._is_pipeline,
+                hpz=zcfg.zero_hpz_partition_size > 1,
+                mics=zcfg.mics_shard_size > 1)
+            self._build_state_shardings(abstract)
 
-        # state sharding tree must mirror the state structure
-        abstract_state = jax.eval_shape(
-            _init_state, rng if model_parameters is None else params_host)
-        self.state_shardings = self._state_sharding_tree(abstract_state)
-        # init in default (device) memory — XLA's SPMD partitioner can't
-        # annotate host placement on constants — then move offloaded trees
-        # to pinned_host with an explicit transfer
-        init_shardings = jax.tree.map(
-            lambda s: (NamedSharding(s.mesh, s.spec)
-                       if s.memory_kind == "pinned_host" else s),
-            self.state_shardings,
-            is_leaf=lambda x: isinstance(x, NamedSharding))
-        init_jit = jax.jit(_init_state, out_shardings=init_shardings)
-        self.state = init_jit(rng if model_parameters is None
-                              else params_host)
-        if self._uses_host_memory:
-            self.state = jax.device_put(self.state, self.state_shardings)
+            # NVMe tier keeps master+moments off-device entirely (host RAM /
+            # disk via the native AIO op); cpu tier keeps them as pinned_host
+            # arrays inside the compiled step (see runtime/offload.py)
+            self._nvme_offload = zcfg.offload_optimizer.device == "nvme"
+            self._offload_opt = None
+
+            def _init_state(rng_or_params):
+                if model_parameters is None:
+                    params32 = self.module.init(rng_or_params)
+                else:
+                    params32 = rng_or_params
+                params32 = jax.tree.map(
+                    lambda x: x.astype(jnp.float32), params32)
+                params = jax.tree.map(
+                    lambda x: x.astype(self.compute_dtype), params32)
+                master = (params32 if self._mixed and not self._nvme_offload
+                          else None)
+                opt_state = (() if self._nvme_offload
+                             else self.tx.init(params32))
+                return {"step": jnp.zeros((), jnp.int32),
+                        "params": params,
+                        "master": master,
+                        "opt_state": opt_state,
+                        "loss_scale": init_loss_scale(self.config.fp16)}
+
+            # state sharding tree must mirror the state structure
+            abstract_state = jax.eval_shape(
+                _init_state, rng if model_parameters is None else params_host)
+            self.state_shardings = self._state_sharding_tree(abstract_state)
+            # init in default (device) memory — XLA's SPMD partitioner
+            # can't annotate host placement on constants — then move
+            # offloaded trees to pinned_host with an explicit transfer
+            init_shardings = jax.tree.map(
+                lambda s: (NamedSharding(s.mesh, s.spec)
+                           if s.memory_kind == "pinned_host" else s),
+                self.state_shardings,
+                is_leaf=lambda x: isinstance(x, NamedSharding))
+            init_jit = jax.jit(_init_state, out_shardings=init_shardings)
+            self.state = init_jit(rng if model_parameters is None
+                                  else params_host)
+            if self._uses_host_memory:
+                self.state = jax.device_put(self.state, self.state_shardings)
 
         # --- sequence parallelism (reference: deepspeed/sequence) -------
         self._loss_fn = self._configure_sequence_parallel()
@@ -326,43 +341,45 @@ class DeepSpeedEngine:
             _nsan.set_numsan(self._numsan)
 
         # --- compiled step ----------------------------------------------
-        def _loss_on_device(params, batch):
-            return self._loss_fn(self._params_to_device(params), batch)
+        with _tel_span("init/build_step"):
+            def _loss_on_device(params, batch):
+                return self._loss_fn(self._params_to_device(params), batch)
 
-        self._loss_fn_dev = _loss_on_device
-        if self.compressor is not None:
-            _tr = self.compressor.transform
+            self._loss_fn_dev = _loss_on_device
+            if self.compressor is not None:
+                _tr = self.compressor.transform
 
-            def _loss_on_device_step(params, batch, step):
-                p = self._params_to_device(params)
-                return self._loss_fn(_tr(p, step), batch)
+                def _loss_on_device_step(params, batch, step):
+                    p = self._params_to_device(params)
+                    return self._loss_fn(_tr(p, step), batch)
 
-            self._loss_fn_dev_step = _loss_on_device_step
-        if self._nvme_offload:
-            from .offload import NVMeOffloadOptimizer
-            self._offload_opt = NVMeOffloadOptimizer(self)
-            self._train_step = self._build_grads_step()
-        else:
-            self._train_step = self._build_train_step()
-        self._eval_loss = jax.jit(
-            self._loss_fn_dev if self.compressor is None
-            else self._loss_fn_dev_step)
-        self._micro_grads_jit = None
-        self._accum_add_jit = None
-        self._apply_grads_jit = None
-        self._grad_stats_jit = None
-        self._accum_grads = None
-        self._micro_count = 0
-        # deferred dp-reduction state for the eager triple (no_sync)
-        self._local_grads_jit = None
-        self._finish_grads_jit = None
-        self._deferred_acc = None
-        self._inside_no_sync = False
+                self._loss_fn_dev_step = _loss_on_device_step
+            if self._nvme_offload:
+                from .offload import NVMeOffloadOptimizer
+                self._offload_opt = NVMeOffloadOptimizer(self)
+                self._train_step = self._build_grads_step()
+            else:
+                self._train_step = self._build_train_step()
+            self._eval_loss = jax.jit(
+                self._loss_fn_dev if self.compressor is None
+                else self._loss_fn_dev_step)
+            self._micro_grads_jit = None
+            self._accum_add_jit = None
+            self._apply_grads_jit = None
+            self._grad_stats_jit = None
+            self._accum_grads = None
+            self._micro_count = 0
+            # deferred dp-reduction state for the eager triple (no_sync)
+            self._local_grads_jit = None
+            self._finish_grads_jit = None
+            self._deferred_acc = None
+            self._inside_no_sync = False
 
         # --- misc engine plumbing ---------------------------------------
         self.global_steps = 0
         self.global_samples = 0
         self.skipped_steps = 0
+        self._dispatched = False    # the first_step span wraps one dispatch
         self.timers = SynchronizedWallClockTimer()
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_batch_size_,
@@ -385,12 +402,6 @@ class DeepSpeedEngine:
                 or self.config.comet.enabled):
             from ..monitor.monitor import MonitorMaster
             self.monitor = MonitorMaster(self.config)
-        # telemetry (ISSUE 2): explicit opt-in, or implied by
-        # wall_clock_breakdown — the fwd/bwd/step breakdown events are
-        # sourced from span data, so the tracer must be live for them
-        if self.config.telemetry.enabled or self.config.wall_clock_breakdown:
-            from ..utils.telemetry_probe import activate
-            activate(self.config.telemetry)
         # runtime sentinels (ISSUE 3): recompile + transfer-guard
         # enforcement on the compiled-step dispatch, opt-in via config
         self._recompile_sentinel = None
@@ -724,40 +735,46 @@ class DeepSpeedEngine:
                     lambda p: jnp.zeros(p.shape, jnp.float32), params)
                 zeros = constrain(zeros, mesh, grad_specs)
                 grads, losses = jax.lax.scan(body, zeros, micro_batches)
-            # unscale + average over GAS (reference scales loss by 1/GAS
-            # before backward, engine.py:2024)
-            inv = 1.0 / (scale * ga)
-            grads = jax.tree.map(lambda g: g * inv, grads)
+            # everything after the gradient is one device scope
+            # (telemetry/scopes.py): HLO metadata, no run-time cost
+            with jax.named_scope("ds.optimizer"):
+                # unscale + average over GAS (reference scales loss by
+                # 1/GAS before backward, engine.py:2024)
+                inv = 1.0 / (scale * ga)
+                grads = jax.tree.map(lambda g: g * inv, grads)
 
-            # overflow check (loss_scaler.grads_finite: the shared
-            # fused reduction; numsan's per-leaf stats extend it below)
-            finite = jnp.array(True)
-            if fp16:
-                finite = grads_finite(grads)
+                # overflow check (loss_scaler.grads_finite: the shared
+                # fused reduction; numsan's per-leaf stats extend it below)
+                finite = jnp.array(True)
+                if fp16:
+                    finite = grads_finite(grads)
 
-            # global grad norm + clip (reference: runtime/utils.py
-            # clip_grad_norm_)
-            sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads))
-            grad_norm = jnp.sqrt(sq)
-            if clip > 0:
-                coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads = jax.tree.map(lambda g: g * coef, grads)
+                # global grad norm + clip (reference: runtime/utils.py
+                # clip_grad_norm_)
+                with jax.named_scope("ds.grad_clip"):
+                    sq = sum(jnp.sum(jnp.square(g))
+                             for g in jax.tree.leaves(grads))
+                    grad_norm = jnp.sqrt(sq)
+                    if clip > 0:
+                        coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+                        grads = jax.tree.map(lambda g: g * coef, grads)
 
-            master = (fetch(state["master"], shardings["master"])
-                      if mixed else params)
-            opt_state = fetch(state["opt_state"], shardings["opt_state"])
-            updates, new_opt = tx.update(grads, opt_state, master)
-            new_master = jax.tree.map(jnp.add, master, updates)
+                master = (fetch(state["master"], shardings["master"])
+                          if mixed else params)
+                opt_state = fetch(state["opt_state"],
+                                  shardings["opt_state"])
+                updates, new_opt = tx.update(grads, opt_state, master)
+                new_master = jax.tree.map(jnp.add, master, updates)
 
-            if fp16:
-                # skip the whole update on overflow
-                sel = lambda new, old: jax.tree.map(  # noqa: E731
-                    lambda n, o: jnp.where(finite, n, o), new, old)
-                new_master = sel(new_master, master)
-                new_opt = sel(new_opt, opt_state)
-            new_params = jax.tree.map(
-                lambda m: m.astype(compute_dtype), new_master)
-            new_params = constrain(new_params, mesh, param_specs)
+                if fp16:
+                    # skip the whole update on overflow
+                    sel = lambda new, old: jax.tree.map(  # noqa: E731
+                        lambda n, o: jnp.where(finite, n, o), new, old)
+                    new_master = sel(new_master, master)
+                    new_opt = sel(new_opt, opt_state)
+                new_params = jax.tree.map(
+                    lambda m: m.astype(compute_dtype), new_master)
+                new_params = constrain(new_params, mesh, param_specs)
 
             ls = state["loss_scale"]
             if fp16:
@@ -952,7 +969,11 @@ class DeepSpeedEngine:
                 # durations track true per-step wall time
                 with (tel.span("compiled_step")
                       if tel is not None else _NULLCM):
-                    with self._dispatch_scope(batch):
+                    # the first dispatch builds (or loads) the executable
+                    with self._dispatch_scope(batch), (
+                            tel.span("first_step")
+                            if tel is not None and not self._dispatched
+                            else _NULLCM):
                         try:
                             self.state, metrics = self._train_step(
                                 self.state, batch)
@@ -965,6 +986,7 @@ class DeepSpeedEngine:
                             self._disable_host_memory(e)
                             self.state, metrics = self._train_step(
                                 self.state, batch)
+            self._dispatched = True
             if st is not None:
                 # both paths dispatch the same ledger-observed
                 # executable; host bookkeeping past this point lands
@@ -981,44 +1003,48 @@ class DeepSpeedEngine:
             else:
                 self.tput_timer.stop(report_speed=False)
         # flushes run OUTSIDE the train_batch span so export/monitor
-        # cost never pollutes the step timing
-        if tel is not None:
-            self._telemetry_boundary(tel, metrics)
-            if jax.process_count() > 1:
-                # per-step straggler cadence (ISSUE 20): step-stride
-                # rate-limited inside (the stride derives only from
-                # cross-rank-identical inputs, so every rank joins the
-                # two tiny host collectives at the same step, roughly
-                # once per straggler_interval_s); the sample feeds both
-                # the skew gauge and the steptrace straggler bucket
-                skew = tel.flightrec.maybe_record_straggler_skew(
-                    tel.get_registry(), self.global_steps,
-                    interval_s=self.config.telemetry.straggler_interval_s)
-                if skew is not None and st is not None:
-                    st.note_straggler(skew)
-        if self.monitor is not None:
-            # reference event set (engine.py:2348 _write_monitor): loss,
-            # lr, and the loss scale when fp16 is live
-            # lr of the step just applied: the optax count only advances
-            # on applied (non-overflow) steps, so read it from the state
-            # rather than global_steps — otherwise the reported lr drifts
-            # ahead of the lr actually used after any skipped step
-            events = [
-                ("Train/Samples/train_loss", float(metrics["loss"]),
-                 self.global_samples),
-                ("Train/Samples/lr",
-                 float(self.lr_schedule(max(self._applied_steps() - 1, 0))),
-                 self.global_samples),
-            ]
-            if self.fp16_enabled:
-                events.append(("Train/Samples/loss_scale",
-                               float(metrics["loss_scale"]),
-                               self.global_samples))
-            self.monitor.write_events(events)
-        if st is not None:
-            # the step window closes AFTER the boundary/monitor work so
-            # flush cost telescopes into dispatch_overhead, not the gap
-            st.step_end()
+        # cost never pollutes the step timing; step_boundary names them, so
+        # that what a device trace shows under no span is the caller's time
+        with (tel.span("step_boundary")
+              if tel is not None else _NULLCM):
+            if tel is not None:
+                self._telemetry_boundary(tel, metrics)
+                if jax.process_count() > 1:
+                    # per-step straggler cadence (ISSUE 20): step-stride
+                    # rate-limited inside (the stride derives only from
+                    # cross-rank-identical inputs, so every rank joins the
+                    # two tiny host collectives at the same step, roughly
+                    # once per straggler_interval_s); the sample feeds both
+                    # the skew gauge and the steptrace straggler bucket
+                    skew = tel.flightrec.maybe_record_straggler_skew(
+                        tel.get_registry(), self.global_steps,
+                        interval_s=self.config.telemetry.straggler_interval_s)
+                    if skew is not None and st is not None:
+                        st.note_straggler(skew)
+            if self.monitor is not None:
+                # reference event set (engine.py:2348 _write_monitor): loss,
+                # lr, and the loss scale when fp16 is live
+                # lr of the step just applied: the optax count only advances
+                # on applied (non-overflow) steps, so read it from the state
+                # rather than global_steps — otherwise the reported lr drifts
+                # ahead of the lr actually used after any skipped step
+                events = [
+                    ("Train/Samples/train_loss", float(metrics["loss"]),
+                     self.global_samples),
+                    ("Train/Samples/lr",
+                     float(self.lr_schedule(
+                         max(self._applied_steps() - 1, 0))),
+                     self.global_samples),
+                ]
+                if self.fp16_enabled:
+                    events.append(("Train/Samples/loss_scale",
+                                   float(metrics["loss_scale"]),
+                                   self.global_samples))
+                self.monitor.write_events(events)
+            if st is not None:
+                # the step window closes AFTER the boundary/monitor work so
+                # flush cost telescopes into dispatch_overhead, not the gap
+                st.step_end()
         return metrics["loss"]
 
     def _dispatch_scope(self, batch):

@@ -36,7 +36,7 @@ from typing import Optional
 from . import (bridges, collectives, flightrec as _flightrec_mod,  # noqa: F401
                fleet as _fleet_mod, health as _health_mod,
                ledger as _ledger_mod, registry as _registry_mod,
-               reqtrace as _reqtrace_mod, spans as _spans_mod,
+               reqtrace as _reqtrace_mod, scopes, spans as _spans_mod,
                steptrace as _steptrace_mod, timeseries as _timeseries_mod)
 from .fleet import FleetScope, get_fleet  # noqa: F401
 from .flightrec import (FlightRecorder, HangWatchdog,  # noqa: F401
@@ -346,6 +346,14 @@ def export_artifacts(out_dir: str, prefix: str = "telemetry",
         with open(path, "w") as f:
             _json.dump(led.snapshot(), f, indent=1, default=str)
         out["ledger"] = path
+        scope_maps = led.op_scopes_by_name()
+        if scope_maps:
+            # the join from a device trace's events (named by HLO
+            # instruction) to the program's ds. scopes (telemetry/scopes.py)
+            path = os.path.join(out_dir, f"{prefix}.op_scopes.json")
+            with open(path, "w") as f:
+                _json.dump(scope_maps, f)
+            out["op_scopes"] = path
     scope = get_fleet()
     if scope is not None:
         # versioned fleet rollup (ISSUE 17); embeds the health snapshot

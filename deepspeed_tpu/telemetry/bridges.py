@@ -5,7 +5,8 @@ Each collector reads one legacy/framework surface and mirrors it into
 Prometheus-style metrics:
 
 - ``install_jax_compile_listener`` — ``jax.monitoring`` duration events
-  (jit trace / lowering / backend compile) -> compile count + seconds.
+  (jit trace / lowering / backend compile / persistent-cache load) ->
+  compile count + seconds by phase.
 - ``collect_memory`` — /proc/self/status VmRSS+VmHWM and PJRT device
   ``memory_stats()`` -> host/device memory gauges.
 - ``collect_comms`` — ``CommsLogger`` per-op call/byte tallies ->
@@ -34,6 +35,7 @@ _JAX_LISTENER_INSTALLED = False
 # it works with telemetry configured OR shut down (the registry mirror
 # below additionally feeds ds_jax_compile_total when active)
 _COMPILE_EVENTS: dict[str, int] = {}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def compile_event_count(phase: str = "backend_compile") -> int:
@@ -57,11 +59,17 @@ def install_jax_compile_listener() -> None:
     import jax
 
     def _on_duration(name: str, dur_s: float, **kw) -> None:
-        if "/compile/" not in name:
+        if name == _CACHE_LOAD_EVENT:
+            # reading an executable back from the persistent cache: part
+            # of (inside) that executable's backend_compile event, so a
+            # warm set-up shows as cache loads and a cold one as compiles
+            phase = "cache_load"
+        elif "/compile/" in name:
+            phase = name.rsplit("/", 1)[-1]
+            if phase.endswith("_duration"):
+                phase = phase[: -len("_duration")]
+        else:
             return
-        phase = name.rsplit("/", 1)[-1]
-        if phase.endswith("_duration"):
-            phase = phase[: -len("_duration")]
         _COMPILE_EVENTS[phase] = _COMPILE_EVENTS.get(phase, 0) + 1
         # the executable ledger tracks process-wide compile time per
         # phase (every newly compiled executable announces itself
@@ -74,9 +82,11 @@ def install_jax_compile_listener() -> None:
             return
         reg.counter("ds_jax_compile_total",
                     "jax compile-path events by phase").inc(phase=phase)
-        reg.counter("ds_jax_compile_seconds_total",
-                    "cumulative seconds in jax compile phases").inc(
-            dur_s, phase=phase)
+        reg.counter("ds_compile_seconds_total",
+                    "cumulative seconds in jax compile phases "
+                    "(jaxpr_trace, jaxpr_to_mlir_module, backend_compile, "
+                    "and cache_load, which lies inside backend_compile)"
+                    ).inc(dur_s, phase=phase)
 
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _JAX_LISTENER_INSTALLED = True
@@ -187,32 +197,18 @@ def collect_serving(reg: MetricsRegistry, serving_metrics: dict,
             dtype=kv_dtype, engine=engine_label)
 
 
-def collect_ledger(reg: MetricsRegistry, peak_flops: float = 0.0) -> None:
-    """Executable-ledger state -> registry (ISSUE 5): per-jit-name MFU
-    from ledger FLOPs x span seconds, peak HBM per executable name,
+def collect_ledger(reg: MetricsRegistry) -> None:
+    """Executable-ledger state -> registry (ISSUE 5): FLOPs dispatched
+    per jit name, peak HBM per executable name,
     HBM headroom against the device limit, and the per-(mesh axis, op)
     HLO collective traffic counters. No-op (zero allocations) when the
     ledger is off."""
     led = _ledger_mod.get_ledger()
     if led is None:
         return
-    from . import spans as _spans_mod
     reg.gauge("ds_ledger_executables",
               "compiled executables registered in the cost ledger"
               ).set(len(led))
-    peak = _ledger_mod.device_peak_flops(peak_flops)
-    tracer = _spans_mod.get_tracer()
-    if tracer is not None:
-        mfu = reg.gauge(
-            "ds_mfu", "model FLOPs utilization per instrumented jit "
-            "name: ledger FLOPs x dispatches / measured span seconds "
-            "/ device peak (steady-state: the warmup span, which "
-            "includes the XLA compile, is trimmed; still a lower "
-            "bound — span time includes host overhead around the "
-            "device work)")
-        for name, value in led.mfu_by_name(tracer.totals_trimmed(),
-                                           peak).items():
-            mfu.set(value, name=name)
     flops_total = reg.counter(
         "ds_ledger_dispatched_flops_total",
         "FLOPs dispatched per jit name (executable FLOPs x calls)")

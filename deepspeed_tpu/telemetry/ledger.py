@@ -30,7 +30,7 @@ import threading
 import time
 from typing import Any, Optional
 
-from . import collectives as _collectives
+from . import collectives as _collectives, scopes as _scopes
 
 
 def _signature(args, kwargs) -> tuple:
@@ -54,7 +54,8 @@ class ExecutableEntry:
 
     __slots__ = ("name", "signature", "flops", "bytes_accessed",
                  "memory", "collectives", "traffic", "custom_calls",
-                 "calls", "registered_unix", "register_error")
+                 "op_scopes", "calls", "registered_unix",
+                 "register_error")
 
     def __init__(self, name: str, signature: tuple):
         self.name = name
@@ -67,6 +68,9 @@ class ExecutableEntry:
         # {custom_call_target: count}; "tpu_custom_call" = a Pallas
         # kernel compiled through Mosaic (absent in interpret mode)
         self.custom_calls: dict[str, int] = {}
+        # {HLO instruction name: device scope path} (scopes.op_scopes):
+        # what a trace event named by its instruction is joined through
+        self.op_scopes: dict[str, str] = {}
         self.calls = 0
         self.registered_unix = time.time()
         self.register_error = ""
@@ -159,17 +163,18 @@ class ExecutableLedger:
         entry.flops = cost.get("flops", 0.0)
         entry.bytes_accessed = cost.get("bytes accessed", 0.0)
         entry.memory = compiled_memory(compiled)
-        if self.hlo_collectives:
-            try:
-                hlo = compiled.as_text()
+        try:
+            hlo = compiled.as_text()
+            entry.op_scopes = _scopes.op_scopes(hlo)
+            if self.hlo_collectives:
                 entry.collectives = _collectives.analyze_hlo(
                     hlo, mesh=mesh, n_devices=n_devices)
                 entry.traffic = _collectives.traffic_matrix(
                     entry.collectives)
                 entry.custom_calls = _collectives.custom_call_targets(hlo)
-            except Exception as e:   # noqa: BLE001
-                entry.register_error = (
-                    f"hlo: {type(e).__name__}: {e}"[:200])
+        except Exception as e:   # noqa: BLE001
+            entry.register_error = (
+                f"hlo: {type(e).__name__}: {e}"[:200])
 
     def on_compile_event(self, phase: str, dur_s: float) -> None:
         with self._lock:
@@ -211,26 +216,6 @@ class ExecutableLedger:
         return _collectives.merge_traffic(
             *(_collectives.traffic_matrix(e.collectives, e.calls)
               for e in self.entries()))
-
-    def mfu_by_name(self, span_totals: dict, peak_flops: float) -> dict:
-        """{name: MFU} joining per-dispatch FLOPs against measured
-        span seconds: ``avg_flops_per_call x span_count / span_seconds
-        / peak``. ``span_totals`` is ``SpanTracer.totals()`` — or
-        ``totals_trimmed()`` for steady-state MFU that excludes the
-        warmup span (whose duration includes the XLA compile). Names
-        absent from the span totals (or zero-duration) are skipped;
-        result values are finite by construction."""
-        if peak_flops <= 0:
-            return {}
-        calls = self.calls_by_name()
-        out = {}
-        for name, flops in self.dispatched_flops().items():
-            tot = span_totals.get(name)
-            if not tot or tot[0] <= 0 or flops <= 0:
-                continue
-            avg = flops / max(calls.get(name, 1), 1)
-            out[name] = avg * tot[1] / tot[0] / peak_flops
-        return out
 
     # -- calibration queries (ISSUE 7: consumed by autotuning) ---------
     def step_seconds_by_name(self, span_totals: dict) -> dict:
@@ -301,6 +286,16 @@ class ExecutableLedger:
             return {}
         return {axis: b / calls for axis, b in totals.items()}
 
+    def op_scopes_by_name(self) -> dict[str, dict[str, str]]:
+        """{entry name: {instruction name: scope path}}; the signatures
+        of one name are merged, the most dispatched last (it wins where
+        two executables share an instruction name)."""
+        out: dict[str, dict[str, str]] = {}
+        for e in sorted(self.entries(), key=lambda e: e.calls):
+            if e.op_scopes:
+                out.setdefault(e.name, {}).update(e.op_scopes)
+        return out
+
     def snapshot(self) -> dict:
         rows = sorted((e.to_dict() for e in self.entries()),
                       key=lambda r: (-r["flops"] * r["calls"],
@@ -332,15 +327,3 @@ def get_ledger() -> Optional[ExecutableLedger]:
 def set_ledger(ledger: Optional[ExecutableLedger]) -> None:
     global _LEDGER
     _LEDGER = ledger
-
-
-def device_peak_flops(configured: float = 0.0) -> float:
-    """Per-device peak FLOPs for MFU accounting: the configured value
-    when nonzero, else the accelerator table. A TPU ``device_kind``
-    missing from the table raises (accelerator/tpu_accelerator.py); the
-    CPU accelerator's 1e12 is an arbitrary floor for the CPU test rig,
-    not a device peak."""
-    if configured and configured > 0:
-        return float(configured)
-    from ..accelerator import get_accelerator
-    return float(get_accelerator().peak_flops())

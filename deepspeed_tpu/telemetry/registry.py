@@ -8,7 +8,7 @@ from a training or serving host.
 
 Naming follows Prometheus conventions: ``_total`` counters,
 ``_seconds``/``_bytes`` units, e.g. ``ds_serving_decoded_tokens_total``,
-``ds_jax_compile_seconds_total{phase="backend_compile"}``. The full
+``ds_compile_seconds_total{phase="backend_compile"}``. The full
 metric table is in docs/observability.md.
 """
 

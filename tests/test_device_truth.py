@@ -242,8 +242,9 @@ def test_train_batch_ledger_mfu_and_hbm(tmp_path, devices8):
     assert sum(row["bytes"] for row in led.traffic().values()) > 0
 
     reg = telemetry.get_registry()
-    mfu = reg.gauge("ds_mfu").value(name="compiled_step")
-    assert math.isfinite(mfu) and mfu > 0
+    dispatched = reg.counter("ds_ledger_dispatched_flops_total").value(
+        name="compiled_step")
+    assert math.isfinite(dispatched) and dispatched > 0
     assert reg.gauge("ds_ledger_peak_hbm_bytes").value(
         name="compiled_step") == step.peak_hbm_bytes
     assert reg.counter("ds_ledger_dispatched_flops_total").value(
@@ -261,7 +262,8 @@ def test_train_batch_ledger_mfu_and_hbm(tmp_path, devices8):
     assert any(r["name"] == "compiled_step" and r["flops"] > 0
                for r in doc["executables"])
     prom = open(paths["prometheus"]).read()
-    assert "ds_mfu" in prom and "ds_ledger_peak_hbm_bytes" in prom
+    assert "ds_ledger_dispatched_flops_total" in prom \
+        and "ds_ledger_peak_hbm_bytes" in prom
 
     # report CLI renders the ledger table
     rpt = _import_report()

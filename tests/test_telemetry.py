@@ -207,7 +207,7 @@ def test_jax_compile_events_captured():
     reg = telemetry.get_registry()
     assert reg.counter("ds_jax_compile_total").value(
         phase="backend_compile") >= 1
-    assert reg.counter("ds_jax_compile_seconds_total").value(
+    assert reg.counter("ds_compile_seconds_total").value(
         phase="backend_compile") > 0
 
 
