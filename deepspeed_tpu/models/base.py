@@ -60,7 +60,9 @@ class ModelConfig:
     # numerics
     param_dtype: Any = None   # set to jnp dtype in __post_init__
     loss_chunk: int = 0       # >0: fused chunked cross-entropy (tokens per
-    #                           chunk) — never materializes [B,S,V] logits
+    #                           chunk): never materializes [B,S,V] logits;
+    #                           under grad each chunk's dX and dW are made
+    #                           in the forward scan, nothing is recomputed
     remat: bool = True
     # jax.checkpoint_policies name; "nothing_saveable" = full recompute
     remat_policy: str = "nothing_saveable"

@@ -21,6 +21,7 @@ on TPU:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -443,12 +444,12 @@ class DecoderLM:
     def _chunked_loss(self, params: PyTree, tokens, targets, *,
                       attn_fn=None, act_sharding=None) -> jax.Array:
         """Fused chunked cross-entropy: the [B, S, V] logits tensor is
-        never materialized — the unembed matmul + logsumexp run per
-        sequence chunk under remat, so peak HBM holds one
-        [B, loss_chunk, V] slab and the backward recomputes it per chunk.
-        The HBM-traffic role of the reference's fused logits kernels
+        never materialized. The unembed matmul, the f32 softmax and, under
+        differentiation, the gradient's two matmuls run per sequence chunk
+        in one scan, so peak HBM holds one [B, loss_chunk, V] slab and
+        nothing is recomputed (see _chunked_cross_entropy). The
+        HBM-traffic role of the reference's fused logits kernels
         (csrc/transformer/inference logits_gather + fused softmax)."""
-        c = self.config
         x, aux = self._final_hidden(params, tokens, attn_fn=attn_fn,
                                     act_sharding=act_sharding)
         with jax.named_scope("ds.loss_head"):
@@ -457,45 +458,23 @@ class DecoderLM:
 
     def _chunked_ce(self, params: PyTree, x, targets) -> jax.Array:
         """Mean cross-entropy of final-normed hidden states ``x``, one
-        ``loss_chunk`` slab of logits at a time (see _chunked_loss)."""
+        ``loss_chunk`` slab of logits at a time
+        (``_chunked_cross_entropy``)."""
         c = self.config
+        # the casts stay outside the custom_vjp, so JAX transposes them
+        # (and the tied embedding's ``.T``) onto the parameters' dtypes
         W = (params["embed"]["tokens"].T if c.tie_embeddings
-             else params["lm_head"])
-        b, s, d = x.shape
+             else params["lm_head"]).astype(x.dtype)
+        bias = params.get("lm_head_b")
+        if bias is not None:
+            bias = bias.astype(jnp.float32)
+        s = x.shape[1]
         chunk = min(c.loss_chunk, s)
         if s % chunk != 0:
             raise ValueError(
                 f"loss_chunk {c.loss_chunk} (effective {chunk}) must "
                 f"divide sequence length {s}")
-        n = s // chunk
-        xc = x.reshape(b, n, chunk, d).swapaxes(0, 1)
-        tc = targets.reshape(b, n, chunk).swapaxes(0, 1)
-
-        bias = params.get("lm_head_b")
-
-        @jax.checkpoint
-        def chunk_nll(x_c, t_c):
-            logits = (x_c @ W.astype(x_c.dtype)).astype(jnp.float32)
-            if bias is not None:
-                logits = logits + bias.astype(jnp.float32)
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            # same masking contract as ops.layers.cross_entropy_loss
-            valid = t_c != -100
-            safe = jnp.where(valid, t_c, 0)
-            tl = jnp.take_along_axis(logits, safe[..., None],
-                                     axis=-1)[..., 0]
-            return jnp.sum(jnp.where(valid, lse - tl, 0.0)), \
-                jnp.sum(valid)
-
-        def body(acc, xs):
-            x_c, t_c = xs
-            nll, cnt = chunk_nll(x_c, t_c)
-            return (acc[0] + nll, acc[1] + cnt), None
-
-        (nll, cnt), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-            (xc, tc))
-        return nll / jnp.maximum(cnt, 1)
+        return _chunked_cross_entropy(x, W, bias, targets, chunk)
 
     def _final_hidden(self, params: PyTree, tokens, *, attn_fn=None,
                       positions=None, act_sharding=None):
@@ -594,3 +573,92 @@ def _unpack_batch(batch):
         return batch["tokens"], batch["targets"]
     tokens, targets = batch
     return tokens, targets
+
+
+# ---------------- chunked cross-entropy ----------------
+def _by_chunk(x, targets, chunk):
+    """[B, S, D] and [B, S] as scan inputs [S/chunk, B, chunk, ...]."""
+    b, s, d = x.shape
+    n = s // chunk
+    return (x.reshape(b, n, chunk, d).swapaxes(0, 1),
+            targets.reshape(b, n, chunk).swapaxes(0, 1))
+
+
+def _valid_count(targets):
+    return jnp.maximum(jnp.sum(targets != -100), 1).astype(jnp.float32)
+
+
+def _chunk_logits(x_c, t_c, W, bias):
+    """One slab: the f32 logits [B, chunk, V] of a chunk, their
+    logsumexp, the mask and clamped targets, and the chunk's summed NLL
+    (same masking contract as ops.layers.cross_entropy_loss)."""
+    logits = (x_c @ W).astype(jnp.float32)
+    if bias is not None:
+        logits = logits + bias
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    valid = t_c != -100
+    safe = jnp.where(valid, t_c, 0)
+    tl = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    return logits, lse, valid, safe, jnp.sum(jnp.where(valid, lse - tl, 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_cross_entropy(x, W, bias, targets, chunk):
+    """Mean cross-entropy of hidden states ``x`` [B, S, D] under the head
+    ``W`` [D, V] (``x``'s dtype) and f32 ``bias`` [V] or None, one
+    [B, chunk, V] slab of f32 logits at a time.
+
+    This body is the primal (eval_batch, anything that asks no gradient):
+    a loss-only scan, one vocabulary matmul a chunk. Under differentiation
+    the forward rule below computes the gradient in the same scan, while
+    the slab is live, so the backward pass holds no vocabulary matmul and
+    no logits are recomputed: three vocabulary matmuls a chunk, where
+    autodiff of this scan under jax.checkpoint ran four."""
+    def body(nll, xs):
+        return nll + _chunk_logits(*xs, W, bias)[-1], None
+
+    nll, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                          _by_chunk(x, targets, chunk))
+    return nll / _valid_count(targets)
+
+
+def _chunked_cross_entropy_fwd(x, W, bias, targets, chunk):
+    """Loss and, per chunk, dlogits = (softmax - onehot) * valid in
+    ``x``'s dtype (as autodiff casts it), dX_c = dlogits @ W^T and
+    dW += x_c^T @ dlogits. dW (and db) accumulate in f32 over the chunks.
+    The 1/count of the mean and the incoming cotangent (the engine's loss
+    scale) are applied together in the backward rule, in f32 before the
+    one rounding to the operands' dtype: dlogits stay in [-1, 1], so fp16
+    keeps the protection loss scaling gives it."""
+    def body(carry, xs):
+        nll, dW, db = carry
+        x_c, t_c = xs
+        logits, lse, valid, safe, nll_c = _chunk_logits(x_c, t_c, W, bias)
+        onehot = safe[..., None] == jnp.arange(logits.shape[-1])
+        dl = jnp.where(valid[..., None],
+                       jnp.exp(logits - lse[..., None]) - onehot, 0.0)
+        if bias is not None:
+            db = db + dl.sum((0, 1))
+        dl = dl.astype(x_c.dtype)
+        dx_c = jnp.einsum("bcv,dv->bcd", dl, W)
+        dW = dW + jnp.einsum("bcd,bcv->dv", x_c, dl,
+                             preferred_element_type=jnp.float32)
+        return (nll + nll_c, dW, db), dx_c
+
+    init = (jnp.zeros((), jnp.float32), jnp.zeros(W.shape, jnp.float32),
+            None if bias is None else jnp.zeros(bias.shape, jnp.float32))
+    (nll, dW, db), dx = jax.lax.scan(body, init,
+                                     _by_chunk(x, targets, chunk))
+    count = _valid_count(targets)
+    return nll / count, (dx.swapaxes(0, 1).reshape(x.shape), dW, db, count)
+
+
+def _chunked_cross_entropy_bwd(chunk, res, g):
+    dx, dW, db, count = res
+    k = g / count
+    return ((dx * k).astype(dx.dtype), (dW * k).astype(dx.dtype),
+            None if db is None else db * k, None)
+
+
+_chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd,
+                              _chunked_cross_entropy_bwd)

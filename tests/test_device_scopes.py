@@ -62,6 +62,14 @@ def step_hlo():
      "bwd:ds.layers/ds.attn/ds.flash_fwd"),
     ("jit(train_step)/transpose(jvp(ds.loss_head))/while/body/dot_general",
      "bwd:ds.loss_head"),
+    # the chunked head's custom_vjp: its forward rule holds the gradient's
+    # matmuls, its backward rule scales what the forward left
+    ("jit(train_step)/jvp(ds.loss_head)/while/body/closed_call/"
+     "bcd,bcv->dv/dot_general", "fwd:ds.loss_head"),
+    ("jit(train_step)/jvp(ds.loss_head)/while/body/closed_call/"
+     "bcv,dv->bcd/dot_general", "fwd:ds.loss_head"),
+    ("jit(train_step)/transpose(jvp(ds.loss_head))/mul",
+     "bwd:ds.loss_head"),
     ("jit(train_step)/ds.optimizer/ds.grad_clip/reduce_sum",
      "ds.optimizer/ds.grad_clip"),
     ("jit(train_step)/jvp(ds.embed)/jit(_take)/gather", "fwd:ds.embed"),
@@ -121,6 +129,21 @@ def test_every_instruction_of_the_train_step_is_scoped_or_counted(step_hlo):
     assert not any(re.match(r"(fwd|bwd):ds\.optimizer", p) for p in paths)
     unscoped = sum(1 for p in got.values() if not p)
     assert 0 < unscoped < len(got)      # parameters at least; counted
+
+
+def test_every_vocab_sized_dot_is_the_loss_heads(step_hlo):
+    """The head's matmuls resolve to ds.loss_head, forward or backward,
+    so unscoped_ms.train cannot take the head's time: x_c @ W and dW
+    carry a vocabulary dimension, dX_c = dlogits @ W^T contracts it."""
+    got = scopes.op_scopes(step_hlo)
+    vocab = str(get_model_class("mistral")(size="tiny").config.vocab_size)
+    dots = {m.group(1): m.group(2).split(",") for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+        r"(?:dot|convolution)\(", step_hlo, re.M)}
+    head = {n for n in dots if got[n].endswith(":ds.loss_head")}
+    assert len(head) == 3, head
+    vocab_sized = {n for n, dims in dots.items() if vocab in dims}
+    assert len(vocab_sized) == 2 and vocab_sized <= head
 
 
 def test_remat_counts_as_backward(step_hlo):
