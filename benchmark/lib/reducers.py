@@ -1,23 +1,59 @@
-"""The fixed set of reducers a per-layer metric file may name. Each takes
-the run's context and the file's ``args`` and returns a number, or None
-when there is nothing to read (the harness then leaves the metric out).
+"""The one table of reducers a per-layer metric file may name, filled by
+``@reducer`` from this module and from every module under
+``benchmark/reducers/`` (a later PR adds a kernel's roofline reducer as a
+new file there; a name defined twice is an error). Each takes the run's
+context and the file's ``args`` and returns a number, or None when there
+is nothing to read (the harness then leaves the metric out).
 
-context keys: ``trace`` (lib.trace.Trace or None), ``peaks``, ``model``
-(HF-keyed numbers as built), ``tokens_per_s``, ``chips``, ``seq_len``,
-``sequences``, ``memory_peak_bytes``.
+context keys: ``trace`` (lib.trace.Trace or None), ``peaks``, ``arch`` (the
+configuration's architecture module), ``model`` (its ``WIDTHS`` keys as
+built), ``tokens_per_s``, ``chips``, ``seq_len``, ``sequences``,
+``memory_peak_bytes``; ``reducers/program.py`` lists those it adds.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib
 import statistics
 
-from . import flops, trace as tr
+from . import files, trace as tr
+
+REDUCERS: dict = {}         # name -> reader(ctx, args)
+
+
+def reducer(fn):
+    """Register ``fn`` under its own name."""
+    old = REDUCERS.get(fn.__name__)
+    if old is not None and old.__module__ != fn.__module__:
+        raise files.BenchmarkFileError(
+            f"reducer {fn.__name__!r} is defined twice: in {old.__module__} "
+            f"and in {fn.__module__}")
+    REDUCERS[fn.__name__] = fn
+    return fn
+
+
+@functools.cache
+def _import_directory() -> None:
+    for stem in files.known_modules("reducers"):
+        importlib.import_module(f"reducers.{stem}")
+
+
+def find(name: str):
+    """The reducer a metric file names; every module under ``reducers/``
+    is imported first, so the table is whole and a duplicate shows."""
+    _import_directory()
+    if name not in REDUCERS:
+        raise files.BenchmarkFileError(
+            f"no reducer named {name!r} (known: {sorted(REDUCERS)})")
+    return REDUCERS[name]
 
 
 def _median_ms(xs):
     return 1e3 * statistics.median(xs) if xs else None
 
 
+@reducer
 def device_op_ms_per_step(ctx, args):
     """Device time of ops matching ``pattern`` inside one run of
     ``module``: median over the complete steps of the trace."""
@@ -30,6 +66,7 @@ def device_op_ms_per_step(ctx, args):
             c, args["pattern"], args.get("exclude"), lines)))))
 
 
+@reducer
 def exposed_op_ms_per_step(ctx, args):
     """Time of ops matching ``pattern`` with no other leaf op running on
     the same chip, inside one run of ``module``: median over steps."""
@@ -47,6 +84,7 @@ def exposed_op_ms_per_step(ctx, args):
     return _median_ms(tr.per_step_seconds(t, args["module"], exposed))
 
 
+@reducer
 def busy_ms_per_step(ctx, args):
     """Union of device op intervals inside one run of ``module``."""
     t = ctx.get("trace")
@@ -56,6 +94,7 @@ def busy_ms_per_step(ctx, args):
         t, args["module"], lambda c: tr.merge(tr._iv(t.ops(c)))))
 
 
+@reducer
 def step_gap_ms_median(ctx, args):
     if ctx.get("trace") is None:
         return None
@@ -63,6 +102,7 @@ def step_gap_ms_median(ctx, args):
     return 1e3 * statistics.median(gaps) if gaps else None
 
 
+@reducer
 def idle_share_pct(ctx, args):
     if ctx.get("trace") is None:
         return None
@@ -72,21 +112,24 @@ def idle_share_pct(ctx, args):
     return 100.0 * (1.0 - tr.busy_seconds(ctx["trace"]) / (hi - lo))
 
 
+@reducer
 def hbm_peak_gib(ctx, args):
     b = ctx.get("memory_peak_bytes")
     return None if not b else b / 2 ** 30
 
 
+@reducer
 def mfu_pct(ctx, args):
     """Required fwd+bwd FLOPs per token x tokens/s of the traced run's
     window, over chips x peak. Recomputation is not counted."""
     if not ctx.get("tokens_per_s"):
         return None
-    need = flops.train_flops_per_token(ctx["model"], ctx["seq_len"])
+    need = ctx["arch"].train_flops_per_token(ctx["model"], ctx["seq_len"])
     return (100.0 * need * ctx["tokens_per_s"]
             / (ctx["chips"] * ctx["peaks"]["bf16_flops_per_s"]))
 
 
+@reducer
 def flash_roofline_pct(ctx, args):
     """Least time the chip could take for the step's flash calls (forward
     and one-pass backward of every layer, this chip's sequences) over
@@ -94,18 +137,13 @@ def flash_roofline_pct(ctx, args):
     ms = device_op_ms_per_step(ctx, args)
     if not ms:
         return None
-    m = ctx["model"]
+    m, arch = ctx["model"], ctx["arch"]
     local = max(1, ctx["sequences"] // ctx["chips"])
     least = 0.0
     for backward in (False, True):
-        cost = flops.flash_call_cost(m, local, ctx["seq_len"],
-                                     backward=backward)
-        least += flops.least_seconds(cost, ctx["peaks"])[0]
+        cost = arch.flash_call_cost(m, local, ctx["seq_len"],
+                                    backward=backward)
+        least += arch.least_seconds(cost, ctx["peaks"])[0]
     least *= m["num_hidden_layers"]
     return 100.0 * (1e3 * least) / ms
 
-
-REDUCERS = {f.__name__: f for f in (
-    device_op_ms_per_step, exposed_op_ms_per_step, busy_ms_per_step,
-    step_gap_ms_median, idle_share_pct, hbm_peak_gib, mfu_pct,
-    flash_roofline_pct)}

@@ -1,10 +1,8 @@
 """Reducers that read what the PROGRAM names (PR 24): its device scopes
 (through the executable ledger's ``<prefix>.op_scopes.json``), its set-up
 spans, its compile seconds by phase, and the host and device clocks of
-one trace. ``benchmark/program_trace.py`` runs them: ``run.py`` reports
-what ``lib/reducers.py`` registers and the cell files list, and neither
-was PR 24's to edit (a ``benchmark`` PR registers ``REDUCERS`` below
-there and appends the metric names to the cells).
+one trace; and two lists a kind may add to a traced run's ``breakdown``
+(``device_scopes``, ``idle_gaps_aligned``).
 
 Every reader returns None where the program has no such scope, span or
 counter (a parent commit from before PR 24 has none), and the metric is
@@ -12,8 +10,9 @@ then left out; none raises for that.
 
 context keys read here, beside those of ``lib.reducers``: ``op_scopes_path``
 (the exported map {ledger entry: {HLO instruction name: scope path}}),
-``program`` (``program_state()`` once nothing more compiles: at the start of the
-window or after it),
+``ledger_entry`` (the kind's entry in that map: its step program),
+``program`` (``lib.telemetry.program_state()`` once nothing more compiles: at
+the start of the window or after it),
 ``program_at_build`` (the same when the engine was built), ``setup_s``,
 ``pre_build_s`` (process start to the call that builds the engine),
 ``step_rows`` (steptrace's rows of the window's steps).
@@ -32,58 +31,10 @@ import json
 import re
 import statistics
 
-from . import trace as tr
+from lib import trace as tr
+from lib.reducers import reducer
 
 _INSTRUCTION = re.compile(r"^%?([^\s=]+)")
-STEP_ENTRY = "compiled_step"        # the train step's ledger entry
-
-
-# -- what the program holds, read by the job -------------------------------
-def program_state() -> dict:
-    """The program's own account of the run so far: ``spans`` {name:
-    [seconds, count]}, ``compile_s`` {phase: seconds} and ``import_s``.
-    Keys are absent where the program has nothing to say (telemetry off,
-    or a program from before these existed)."""
-    import deepspeed_tpu
-    out: dict = {}
-    imp = getattr(deepspeed_tpu, "IMPORT_SECONDS", None)
-    if imp is not None:
-        out["import_s"] = float(imp)
-    from deepspeed_tpu.utils.telemetry_probe import active_telemetry
-    tel = active_telemetry()
-    if tel is None:
-        return out
-    tracer, reg = tel.get_tracer(), tel.get_registry()
-    if tracer is not None:
-        out["spans"] = {k: list(v) for k, v in tracer.totals().items()}
-    if reg is not None:
-        c = reg.counter("ds_compile_seconds_total")
-        phases = {dict(ls).get("phase"): c.value(**dict(ls))
-                  for ls in c.label_sets()}
-        if phases:
-            out["compile_s"] = phases
-    return out
-
-
-def step_rows(n: int) -> list:
-    """steptrace's rows (``STEP_LOG_KEYS``) of the last ``n`` steps."""
-    from deepspeed_tpu.utils.telemetry_probe import active_telemetry
-    tel = active_telemetry()
-    st = tel.get_step_recorder() if tel is not None else None
-    if st is None:
-        return []
-    return [r.log_row() for r in st.completed()[-n:]]
-
-
-def export(out_dir: str, prefix: str) -> dict:
-    """Have the program write its artifacts beside the trace; returns the
-    context keys that point at them."""
-    from deepspeed_tpu.utils.telemetry_probe import active_telemetry
-    tel = active_telemetry()
-    if tel is None:
-        return {}
-    paths = tel.export_artifacts(out_dir, prefix=prefix)
-    return {"op_scopes_path": paths.get("op_scopes")}
 
 
 # -- device scopes -----------------------------------------------------------
@@ -94,7 +45,7 @@ def _scope_map(ctx) -> dict | None:
         if path:
             with open(path) as f:
                 maps = json.load(f)
-        ctx["op_scopes"] = maps.get(STEP_ENTRY)
+        ctx["op_scopes"] = maps.get(ctx.get("ledger_entry"))
     return ctx["op_scopes"]
 
 
@@ -118,6 +69,7 @@ def _matching(ctx, chip, rx):
                     if rx.search(path))
 
 
+@reducer
 def scope_ms_per_step(ctx, args):
     """Device time of the ops under the scopes matching ``pattern`` inside
     one run of ``module``: merged intervals, median over the complete
@@ -139,7 +91,7 @@ def scope_ms_per_step(ctx, args):
     return 1e3 * statistics.median(xs) if xs else None
 
 
-def device_scopes(ctx, n: int = 20) -> list[list]:
+def device_scopes(ctx, n: int = 10) -> list[list]:
     """Seconds of chip 0's leaf device ops by scope path over the traced
     window, [[scope path, seconds]] by decreasing seconds ("" = under no
     scope). Beside ``trace.top_ops``, which has them by op name."""
@@ -210,6 +162,7 @@ def _bracket(ctx, args):
     return ctx["clock_bracket"]
 
 
+@reducer
 def clock_bracket_us(ctx, args):
     """Width of the bracket on the device-minus-host clock offset, in
     microseconds: how exactly an idle gap can be pinned to a host span."""
@@ -232,6 +185,7 @@ def idle_gaps_aligned(ctx, span_pattern: str, n: int = 10) -> list[list]:
     return tr.idle_gaps_by_span(moved, span_pattern, n)
 
 
+@reducer
 def host_span_ms_median(ctx, args):
     """Median length of the host spans matching ``span`` in the trace."""
     t = ctx.get("trace")
@@ -249,10 +203,12 @@ def _compile_total(state: dict, phases) -> float | None:
     return sum(by_phase.get(p, 0.0) for p in phases)
 
 
+@reducer
 def setup_import_s(ctx, args):
     return ctx.get("program", {}).get("import_s")
 
 
+@reducer
 def setup_compile_s(ctx, args):
     """Seconds in jax's compile path between the moment the engine was
     built and the start of the window, over ``phases``: lowering and
@@ -269,79 +225,10 @@ def setup_compile_s(ctx, args):
     return total - (built or 0.0)
 
 
+@reducer
 def setup_init_s(ctx, args):
     """The ``spans`` of engine construction (what compiles inside them
     included)."""
     spans = ctx.get("program", {}).get("spans", {})
     got = [spans[s][0] for s in args["spans"] if s in spans]
     return sum(got) if got else None
-
-
-# -- lines of a traced run that are not metrics ------------------------------
-def _pct(xs, q):
-    xs = sorted(xs)
-    return xs[min(len(xs) - 1, int(q * len(xs)))]
-
-
-def report_lines(ctx, metrics: dict) -> list[str]:
-    out = []
-    got = {k: metrics[k]["value"] for k in (
-        "setup_import_s.train", "setup_init_s.train",
-        "setup_compile_s.train") if k in metrics}
-    if len(got) == 3 and ctx.get("setup_s"):
-        rest = ctx["setup_s"] - sum(got.values())
-        by_phase = ctx.get("program", {}).get("compile_s", {})
-        first = ctx.get("program", {}).get("spans", {}).get(
-            "first_step", [0.0])[0]
-        built = ctx.get("program_at_build", {}).get("compile_s", {})
-        pre = ctx.get("pre_build_s", 0.0)
-        rnd = lambda d: {k: round(v, 3) for k, v in d.items()}  # noqa: E731
-        out.append(
-            f"setup: setup_s={ctx['setup_s']:.3f} = import "
-            f"{got['setup_import_s.train']:.3f} + init "
-            f"{got['setup_init_s.train']:.3f} + compile "
-            f"{got['setup_compile_s.train']:.3f} + the rest {rest:.3f}. "
-            f"The rest: {pre - got['setup_import_s.train']:.3f} before "
-            f"the engine is built and not the package's imports (jax's "
-            f"import, the device runtime's start, the compile cache), and "
-            f"{rest - pre + got['setup_import_s.train']:.3f}"
-            f" after it (the agreement check's and the warm-up's run time, "
-            f"tracing). first_step span {first:.3f}; compile seconds by "
-            f"phase at the window {rnd(by_phase)}, when the engine was "
-            f"built {rnd(built)}")
-    br = ctx.get("clock_bracket")
-    if br:
-        us = lambda x: None if x is None else round(1e6 * x, 1)  # noqa: E731
-        out.append(
-            f"clock: device minus host between {us(br['lower'])} and "
-            f"{us(br['upper'])} us over {br['steps']} steps, midpoint "
-            f"{us(br['midpoint'])} us"
-            + ("" if br["lower"] is not None else
-               " (lower limit dropped: it contradicts the upper one, so "
-               "the caller did not block on each step)"))
-    rows = ctx.get("step_rows") or []
-    if rows:
-        keys = [k for k in rows[0] if k.endswith("_ms")
-                and any(r[k] for r in rows)]
-        series = {k: {"p5": _pct([r[k] for r in rows], 0.05),
-                      "p50": _pct([r[k] for r in rows], 0.50),
-                      "p95": _pct([r[k] for r in rows], 0.95),
-                      "max": max(r[k] for r in rows)} for k in keys}
-        out.append(f"steptrace over {len(rows)} steps (host clock, ms): "
-                   + json.dumps(series))
-        # the program's own split of a step beside the trace's (ROADMAP
-        # queue 3 item 4 decides whether steptrace keeps these two)
-        seen = {k: metrics.get(k, {}).get("value") for k in (
-            "device_step_ms.train", "exposed_collective_ms.train")}
-        out.append(
-            f"steptrace device_compute_ms p50 "
-            f"{series.get('device_compute_ms', {}).get('p50', 0.0)} "
-            f"exposed_comm_ms p50 "
-            f"{series.get('exposed_comm_ms', {}).get('p50', 0.0)} against "
-            f"the trace's {seen}")
-    return out
-
-
-REDUCERS = {f.__name__: f for f in (
-    scope_ms_per_step, clock_bracket_us, host_span_ms_median,
-    setup_import_s, setup_compile_s, setup_init_s)}

@@ -4,14 +4,17 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Loads the cell (``benchmark/cells/<cell>.json``), its configuration and
-its traffic mix by name, builds the system under test through the
-program's normal entry points, checks it against the plain float32
-reference, warms up every shape the traffic uses (all of that is
-``setup_s``), measures for ``--seconds``, and prints one JSON object as
-the LAST line of stdout. ``--trace 0`` reports the cell's end-to-end
-metrics with the program's telemetry off; ``--trace 1`` turns on the
-program's spans, profiles a few seconds of the window and reports the
-cell's per-layer metrics.
+its traffic mix by name, and by the names THEY give the architecture
+module (``architectures/``) and the loop of the traffic kind (``kinds/``);
+builds the system under test through the program's normal entry points,
+checks it against the architecture's plain float32 reference, warms up
+every shape the traffic uses (all of that is ``setup_s``), measures for
+``--seconds``, and prints one JSON object as the LAST line of stdout.
+``--trace 0`` reports the cell's end-to-end metrics with the program's
+telemetry off; ``--trace 1`` turns on the program's spans and its
+executable ledger, profiles a few seconds of the window and reports the
+cell's per-layer metrics, each through the reducer its file names
+(``lib/reducers.py`` and ``reducers/``).
 
 There is no CPU mode: without a TPU whose ``device_kind`` is in
 ``benchmark/lib/peaks.py``, or with fewer chips than the cell asks for,
@@ -36,9 +39,6 @@ for p in (CHECKOUT, HERE):
         sys.path.insert(0, p)
 
 from lib import files               # noqa: E402
-
-# traffic kind -> the module under lib/ that holds its loop
-KINDS = {"train_job": "train_job"}
 
 
 class GateFailure(SystemExit):
@@ -95,13 +95,19 @@ def enable_cache() -> str:
     return cache_dir
 
 
-def layer_metrics(cell: dict, ctx: dict) -> dict:
-    from lib.reducers import REDUCERS
+def layer_readers(cell: dict) -> list:
+    """(name, metric file, reducer) of the cell's per-layer metrics; a
+    reducer that is not found fails here, before any device work."""
+    from lib import reducers
+    specs = [files.load_layer_metric(name) for name in cell["per_layer"]]
+    return [(spec["name"], spec, reducers.find(spec["reducer"]["name"]))
+            for spec in specs]
+
+
+def layer_metrics(readers: list, ctx: dict) -> dict:
     out = {}
-    for name in cell["per_layer"]:
-        spec = files.load_layer_metric(name)
-        red = spec["reducer"]
-        value = REDUCERS[red["name"]](ctx, red.get("args", {}))
+    for name, spec, read in readers:
+        value = read(ctx, spec["reducer"].get("args", {}))
         if value is None:
             print(f"per-layer metric {name}: nothing to read, left out",
                   flush=True)
@@ -122,10 +128,7 @@ def main(argv=None, rig: dict | None = None) -> int:
     cell = files.load_cell(args.workload)
     # the benchmark's own CPU tests shrink the traffic with the model
     cell["traffic_file"].update(rig.get("traffic_overrides", {}))
-    kind = cell["traffic_file"]["kind"]
-    if kind not in KINDS:
-        raise files.BenchmarkFileError(
-            f"traffic kind {kind!r} is not one of {sorted(KINDS)}")
+    readers = layer_readers(cell) if args.trace else []
 
     import jax
     cache_dir = enable_cache()
@@ -141,13 +144,15 @@ def main(argv=None, rig: dict | None = None) -> int:
     if args.trace:
         from deepspeed_tpu import telemetry
         from lib.tracer import Tracer
-        telemetry.configure(profiler_annotations=True)
+        # the ledger walks the step's HLO once, for the map from a trace
+        # event's instruction name to the program's device scope
+        telemetry.configure(profiler_annotations=True,
+                            executable_ledger=True)
         tracer = Tracer(cell["name"],
                         float(cell["traffic_file"]["trace_seconds"]))
 
-    import importlib
-    job = importlib.import_module(f"lib.{KINDS[kind]}")
-    result = job.run(cell, args, rig, tracer=tracer, t_start=T_START)
+    result = cell["job"].run(cell, args, rig, tracer=tracer,
+                             t_start=T_START)
     if tracer is not None:
         tracer.stop()
 
@@ -173,11 +178,17 @@ def main(argv=None, rig: dict | None = None) -> int:
         lo, hi = trace_mod.window(tr)
         device["busy_s"] = trace_mod.busy_seconds(tr)
         device["window_s"] = hi - lo
-        line["metrics"] = layer_metrics(cell, ctx)
+        line["metrics"] = layer_metrics(readers, ctx)
         spans = cell["traffic_file"].get("span_pattern", ".")
         line["breakdown"] = {
             "device_ops": trace_mod.top_ops(tr, 10),
             "idle_gaps": trace_mod.idle_gaps_by_span(tr, spans, 10)}
+        # what the kind itself adds to a traced run, if anything
+        traced = getattr(cell["job"], "traced", None)
+        more = traced(ctx, line["metrics"], spans) if traced else {}
+        line["breakdown"].update(more.get("breakdown", {}))
+        for text in more.get("lines", ()):
+            print(text, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -6,6 +6,7 @@
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -16,8 +17,10 @@ BENCH = os.path.dirname(HERE)
 CHECKOUT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
-from lib import files, flops, peaks, reducers, traffic  # noqa: E402
-from lib import trace as T                               # noqa: E402
+from architectures import mistral                  # noqa: E402
+from architectures import mistral as flops         # noqa: E402
+from lib import files, peaks, reducers, traffic    # noqa: E402
+from lib import trace as T                         # noqa: E402
 
 B = files.benchmark_json()
 CELLS = [w["name"] for w in B["workloads"]]
@@ -92,8 +95,8 @@ def test_recorded_trace_busy_idle_and_steps(recorded):
 
 
 def test_recorded_trace_mosaic_time_and_roofline(recorded):
-    ctx = {"trace": recorded, "model": MISTRAL, "seq_len": 8192,
-           "chips": 1, "sequences": 1, "peaks": peaks.peak("TPU v5 lite")}
+    ctx = {"trace": recorded, "arch": mistral, "model": MISTRAL,
+           "seq_len": 8192, "chips": 1, "sequences": 1, "peaks": peaks.peak("TPU v5 lite")}
     args = {"pattern": MOSAIC, "module": STEP}
     # flash forward + one-pass backward of two layers
     assert abs(reducers.device_op_ms_per_step(ctx, args) - 25.3967) < 1e-3
@@ -189,12 +192,42 @@ def test_benchmark_json_agrees_with_the_files():
             assert set(entry["workloads"]) <= set(spec["cells"])
             # every cell that reports a metric reports the one it moves
             assert spec["moves"] in cell["end_to_end"], (m, w["name"])
-            assert spec["reducer"]["name"] in reducers.REDUCERS
+            assert callable(reducers.find(spec["reducer"]["name"]))
     for m in B["end_to_end"]:
         for wn in m.get("workloads", CELLS):
             assert m["name"] in files.load_cell(wn)["end_to_end"]
     used = {c for w in B["workloads"] for c in [w["config"]]}
     assert used == set(cfgs)
+
+
+def test_every_name_a_file_gives_resolves():
+    """No run needed: each configuration's architecture is a module with
+    the three parts, each traffic mix's kind a module with ``run``, and
+    each metric file (a cell lists it or not) names cells that list it,
+    a reducer of the one table and a ``BENCHMARK.json`` entry."""
+    for path in sorted(os.listdir(os.path.join(BENCH, "configs"))):
+        cfg = files.load_config(path[:-5])
+        arch = files.load_module("architectures", cfg["architecture"], path)
+        assert isinstance(arch.WIDTHS, dict)
+        assert callable(arch.reference) and callable(
+            arch.train_flops_per_token)
+        missing = set(arch.WIDTHS) - set(cfg) - set(arch.OPTIONAL)
+        assert not missing, (path, missing)
+    for path in sorted(os.listdir(os.path.join(BENCH, "traffic"))):
+        kind = files.load_traffic(path[:-5])["kind"]
+        assert callable(files.load_module("kinds", kind, path).run)
+    per_layer = {m["name"]: m for m in B["per_layer"]}
+    for path in sorted(os.listdir(os.path.join(BENCH, "layer_metrics"))):
+        spec = files.load_layer_metric(path[:-5])
+        assert callable(reducers.find(spec["reducer"]["name"]))
+        assert sorted(per_layer[spec["name"]]["workloads"]) == sorted(
+            spec["cells"])
+        for cell in spec["cells"]:
+            assert spec["name"] in files.load_cell(cell)["per_layer"]
+    for stem in files.known_modules("architectures"):
+        with open(os.path.join(BENCH, "architectures", stem + ".py")) as f:
+            assert not re.search(r"^\s*(import|from)\s+deepspeed_tpu",
+                                 f.read(), re.M), stem
 
 
 # ---- every cell, end to end, on the CPU at the tiny preset ----------------
@@ -232,16 +265,68 @@ def test_cell_end_to_end_on_cpu(cell):
 
 
 def test_traced_run_reports_per_layer_metrics_on_cpu():
+    """Control flow only: a CPU trace has no TPU plane, so the device
+    readers return nothing; the host clock and the set-up phases read."""
     line, out = _run_rig("train-s8k-1chip", "1", "3")
+    assert line["correct"] is True and line["failed"] == 0
     assert {"busy_s", "window_s"} <= set(line["device"])
-    assert "breakdown" in line
-    # the host clock and memory_stats' absence read the same on a CPU;
-    # the device trace has no TPU plane, so its readers return nothing
-    assert "mfu.train" in line["metrics"]
-    assert "device_idle.train" not in line["metrics"]
-    assert "flash_ms.train" not in line["metrics"]
-    spec = files.load_cell("train-s8k-1chip")
-    assert set(line["metrics"]) <= set(spec["per_layer"])
+    got = set(line["metrics"])
+    assert {"mfu.train", "setup_import_s.train", "setup_init_s.train",
+            "setup_compile_s.train"} <= got
+    assert not got & {"device_idle.train", "flash_ms.train",
+                      "layers_fwd_ms.train"}
+    assert got <= set(files.load_cell("train-s8k-1chip")["per_layer"])
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps",
+                                      "device_scopes", "idle_gaps_aligned"}
+    assert all(len(v) <= 10 for v in line["breakdown"].values())
+    setup = [ln for ln in out.splitlines() if ln.startswith("setup:")]
+    assert len(setup) == 1
+    parts = sum(line["metrics"][k]["value"] for k in (
+        "setup_import_s.train", "setup_init_s.train",
+        "setup_compile_s.train"))
+    assert 0 < parts < float(setup[0].split("setup_s=")[1].split()[0])
+    assert any(ln.startswith("steptrace over") for ln in out.splitlines())
+
+
+def test_untraced_run_turns_no_telemetry_on():
+    """``--trace 0``: no spans, no executable ledger, no program account."""
+    _, out = _run_rig("train-s8k-1chip", "0", "2")
+    assert not any(ln.startswith(("setup:", "clock:", "steptrace"))
+                   for ln in out.splitlines())
+    code = ("import sys; sys.path[:0] = [%r, %r]; import cpu_rig, run; "
+            "run.main(['--workload', 'train-s8k-1chip', '--seed', '5', "
+            "'--seconds', '1', '--trace', '0'], rig=cpu_rig.RIG); "
+            "assert 'deepspeed_tpu.telemetry' not in sys.modules" % (HERE, BENCH))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=1",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(
+                   CHECKOUT, ".bench_trace", "test_jax_cache"))
+    p = subprocess.run([sys.executable, "-c", code], cwd=CHECKOUT, env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_control_comes_out_not_correct_on_cpu(cell):
+    """``tests/control.py`` at the tiny preset: the reference with fp8
+    operands in the program's place fails the check the program passes
+    (on the chip at the cells' own size: ``PERF.md`` section 2)."""
+    chips = files.load_cell(cell)["chips"]
+    code = ("import sys, json; sys.path[:0] = [%r, %r]; "
+            "import cpu_rig, control; print(json.dumps("
+            "control.control(%r, 3000000019, cpu_rig.RIG)))"
+            % (HERE, BENCH, cell))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(
+                   CHECKOUT, ".bench_trace", "test_jax_cache"))
+    p = subprocess.run([sys.executable, "-c", code], cwd=CHECKOUT, env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    assert got["correct"] is False and got["control"] == "float8_e4m3fn"
+    assert got["rounded_matmuls"] > 0
+    assert got["got"]["logits_err_rms"] > got["limits"]["logits_err_rms"]
 
 
 def test_no_tpu_no_result():

@@ -1,13 +1,11 @@
-"""Tests of the readers PR 24 added (``lib/reducers_program.py``, run by
-``program_trace.py``): device scopes, the clock bracket, set-up phases.
+"""Tests of the readers PR 24 added (``reducers/program.py``): device
+scopes, the clock bracket, set-up phases.
 By hand, with the others:
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -16,8 +14,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 
-from lib import files, peaks, reducers, reducers_program as rp  # noqa: E402
-from lib import trace as T                                      # noqa: E402
+from architectures import mistral                   # noqa: E402
+from kinds import train_job                         # noqa: E402
+from lib import files, peaks, reducers, telemetry   # noqa: E402
+from lib import trace as T                          # noqa: E402
+from reducers import program as rp                  # noqa: E402
 
 STEP = "^jit_train_step"
 SPANS = files.load_traffic("pretrain-s8k")["span_pattern"]
@@ -28,16 +29,16 @@ MISTRAL = dict(hidden_size=4096, head_dim=128, num_attention_heads=32,
 
 def _ctx(fixture, scopes=None):
     return {"trace": T.Trace.from_file(os.path.join(HERE, "data", fixture)),
-            "model": MISTRAL, "seq_len": 8192, "chips": 1, "sequences": 1,
+            "arch": mistral, "model": MISTRAL, "seq_len": 8192, "chips": 1, "sequences": 1,
             "peaks": peaks.peak("TPU v5 lite"), "tokens_per_s": 31672.0,
             "memory_peak_bytes": 10 ** 10,
+            "ledger_entry": train_job.LEDGER_ENTRY,
             "op_scopes_path": scopes and os.path.join(HERE, "data", scopes)}
 
 
 def _metric(ctx, name):
     red = files.load_layer_metric(name)["reducer"]
-    table = {**reducers.REDUCERS, **rp.REDUCERS}
-    return table[red["name"]](ctx, red.get("args", {}))
+    return reducers.find(red["name"])(ctx, red.get("args", {}))
 
 
 @pytest.fixture(scope="module")
@@ -97,7 +98,7 @@ def test_a_program_without_scopes_or_spans_leaves_the_metrics_out(old):
     assert _metric(empty, "clock_bracket_us.train") is None
     assert _metric(empty, "h2d_ms.train") is None
     assert rp.idle_gaps_aligned(empty, SPANS) == []
-    assert rp.report_lines(empty, {}) == []
+    assert train_job.report_lines(empty, {}) == []
 
 
 # ---- device scopes on a trace recorded with them ---------------------------
@@ -200,7 +201,7 @@ def test_a_caller_that_does_not_block_drops_the_lower_limit():
     assert br["lower"] is None and br["midpoint"] is None
     assert rp.clock_bracket_us({"trace": t}, {"module": STEP,
                                               "launch": "^never$"}) is None
-    assert any("did not block" in ln for ln in rp.report_lines(
+    assert any("did not block" in ln for ln in train_job.report_lines(
         {"clock_bracket": br}, {}))
 
 
@@ -225,45 +226,16 @@ def test_setup_phases_partition_the_setup():
     assert got["setup_init_s.train"] == pytest.approx(1.0)
     # after the engine was built; tracing and cache_load are not summed
     assert got["setup_compile_s.train"] == pytest.approx(6.7)
-    metrics = {k: {"value": v} for k, v in got.items()}
-    line = rp.report_lines(ctx, metrics)[0]
-    assert "the rest 11.800" in line and "5.500 before the engine" in line
+    # a later cell's names end otherwise: the line reads them by their stem
+    for ending in (".train", ".moe"):
+        metrics = {k.replace(".train", ending): {"value": v}
+                   for k, v in got.items()}
+        line = train_job.report_lines(ctx, metrics)[0]
+        assert "the rest 11.800" in line and "5.500 before the engine" in line
 
 
 def test_program_state_without_telemetry_is_only_the_import_seconds():
-    state = rp.program_state()
+    state = telemetry.program_state()
     assert set(state) <= {"import_s"}
-    assert rp.step_rows(5) == [] and rp.export("unused", "x") == {}
-
-
-# ---- program_trace.py end to end, on the CPU at the tiny preset ------------
-def test_program_trace_reports_the_cell_and_the_program_metrics_on_cpu():
-    """Control flow only: a CPU trace has no TPU plane, so the device
-    readers return nothing; the set-up phases are host-clock and read."""
-    checkout = os.path.dirname(BENCH)
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=1",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(
-                   checkout, ".bench_trace", "test_jax_cache"))
-    code = (f"import sys; sys.path[:0] = [{HERE!r}, {BENCH!r}]; "
-            "import cpu_rig, program_trace; "
-            "sys.exit(program_trace.main(['--workload', 'train-s8k-1chip', "
-            "'--seed', '3000000019', '--seconds', '3'], rig=cpu_rig.RIG))")
-    p = subprocess.run([sys.executable, "-c", code], cwd=checkout, env=env,
-                       capture_output=True, text=True, timeout=900)
-    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0
-    got = set(line["metrics"])
-    assert {"mfu.train", "setup_import_s.train", "setup_init_s.train",
-            "setup_compile_s.train"} <= got
-    assert "layers_fwd_ms.train" not in got          # no device plane
-    assert set(line["breakdown"]) == {"device_ops", "idle_gaps",
-                                      "device_scopes", "idle_gaps_aligned"}
-    setup = [ln for ln in p.stdout.splitlines() if ln.startswith("setup:")]
-    assert len(setup) == 1
-    parts = sum(line["metrics"][k]["value"] for k in (
-        "setup_import_s.train", "setup_init_s.train",
-        "setup_compile_s.train"))
-    assert 0 < parts < float(setup[0].split("setup_s=")[1].split()[0])
-    assert any(ln.startswith("steptrace over") for ln in p.stdout.splitlines())
+    assert telemetry.step_rows(5) == []
+    assert telemetry.export("unused", "x") == {}

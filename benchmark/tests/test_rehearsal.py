@@ -1,0 +1,212 @@
+"""A second architecture arrives as files: in a temporary copy of
+``benchmark/`` the files under ``tests/rehearsal/`` are ADDED (an
+architecture module for the program's ``mixtral`` ``tiny`` preset, a
+configuration, a cell, two per-layer metrics, a reducer in a new file),
+nothing that was there is edited, and the new cell runs through
+``cpu_rig``. Then what must fail does, naming what is known. By hand:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+"""
+
+import hashlib
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+CHECKOUT = os.path.dirname(BENCH)
+ADDED = os.path.join(HERE, "rehearsal")
+CELL = "train-moe-tiny-1chip"
+
+
+def _hashes(root):
+    out = {}
+    for d, dirs, names in os.walk(root):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for n in names:
+            path = os.path.join(d, n)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    f.read()).hexdigest()
+    return out
+
+
+@pytest.fixture()
+def copy(tmp_path):
+    """A checkout that holds only ``BENCHMARK.json`` and ``benchmark/``
+    (without the rehearsal's own files), then the rehearsal's files added."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "benchmark", ignore=shutil.ignore_patterns(
+        "__pycache__", "rehearsal"))
+    shutil.copy(os.path.join(CHECKOUT, "BENCHMARK.json"), root)
+    before = _hashes(root)
+    shutil.copytree(ADDED, root / "benchmark", dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    after = _hashes(root)
+    assert {k: after[k] for k in before} == before      # added, not edited
+    assert len(after) == len(before) + len(_hashes(ADDED))
+    return root
+
+
+def _rig(root, cell=CELL, trace="0", seconds="2"):
+    """``cpu_rig.py`` of the copy; the program is the real checkout's."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=CHECKOUT,
+               XLA_FLAGS="--xla_force_host_platform_device_count=1",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(
+                   CHECKOUT, ".bench_trace", "test_jax_cache"))
+    return subprocess.run(
+        [sys.executable, str(root / "benchmark" / "tests" / "cpu_rig.py"),
+         cell, trace, seconds], cwd=root, env=env, capture_output=True,
+        text=True, timeout=900)
+
+
+def _edit(path, drop=(), **changes):
+    with open(path) as f:
+        d = json.load(f)
+    d.update(changes)
+    for key in drop:
+        del d[key]
+    with open(path, "w") as f:
+        json.dump(d, f)
+
+
+def test_a_second_architecture_runs_from_added_files_only(copy):
+    before = _hashes(copy)
+    p = _rig(copy, trace="1", seconds="3")
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, p.stdout[-3000:]
+    assert set(line["metrics"]) == {"mfu.moe", "expert_flops_share.moe"}
+    # 2 of 4 experts a token: 2 layers x 2 x 3 x 64 x 128 x 2 FLOPs of
+    # 2 x (that + projections 24576 + router 512 + attention) + head 65536
+    share = line["metrics"]["expert_flops_share.moe"]["value"]
+    assert 50.0 < share < 65.0
+    agree = [ln for ln in p.stdout.splitlines() if ln.startswith("agreement")]
+    assert len(agree) == 1 and "ok=True" in agree[0]
+    # an untraced run reports the end-to-end metrics of the new cell
+    p = _rig(copy)
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    after = _hashes(copy)
+    assert {k: after[k] for k in before
+            if not k.startswith(".bench_trace")} == {
+        k: v for k, v in before.items() if not k.startswith(".bench_trace")}
+
+
+def _write_benchmark_json_entries(root):
+    """``BENCHMARK.json`` of the copy gains what a PR adding these files
+    writes there (README, "Adding things", step 5), read off the files."""
+    def added(kind):
+        for name in sorted(os.listdir(os.path.join(ADDED, kind))):
+            with open(os.path.join(ADDED, kind, name)) as f:
+                yield name[:-5], json.load(f)
+    with open(root / "BENCHMARK.json") as f:
+        b = json.load(f)
+    for name, c in added("configs"):
+        b["configs"].append({
+            "name": name, "source": c["source"][:200],
+            "file": f"benchmark/configs/{name}.json",
+            "reduced": sorted(c["reduced"]), "why": "rehearsal"})
+    for name, c in added("cells"):
+        b["workloads"].append({"name": name, **{k: c[k] for k in (
+            "config", "traffic", "chips", "why")}})
+        for m in b["end_to_end"]:
+            if "workloads" in m and m["name"] in c["end_to_end"]:
+                m["workloads"].append(name)
+    for name, m in added("layer_metrics"):
+        b["per_layer"].append({"name": name, **{k: m[k] for k in (
+            "unit", "better", "source", "layer", "moves")},
+            "workloads": m["cells"]})
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(b, f, indent=1)
+
+
+def test_the_suite_stays_green_with_the_added_files(copy):
+    """The benchmark's own tests, run IN the copy with the added files and
+    their ``BENCHMARK.json`` entries: no test there counts what the
+    harness holds, so a files-only addition edits no test either. The new
+    cell runs end to end, and ``tests/control.py``, which knows no
+    architecture, comes out not correct on it."""
+    _write_benchmark_json_entries(copy)
+    before = _hashes(copy)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=CHECKOUT)
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "benchmark/tests/test_benchmark.py",
+         "benchmark/tests/test_program_reducers.py"],
+        cwd=copy, env=env, capture_output=True, text=True, timeout=1800)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    for must in (f"test_cell_end_to_end_on_cpu[{CELL}]",
+                 f"test_the_control_comes_out_not_correct_on_cpu[{CELL}]"):
+        assert f"{must} PASSED" in p.stdout, (must, p.stdout[-3000:])
+    after = _hashes(copy)
+    assert {k: after.get(k) for k in before
+            if not k.startswith(".bench_trace")} == {
+        k: v for k, v in before.items() if not k.startswith(".bench_trace")}
+
+
+def _unknown_architecture(root):
+    _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
+          architecture="mamba")
+    return "0", ["architecture 'mamba'", "known: ['mistral', 'mixtral']"]
+
+
+def _no_architecture_key(root):
+    _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
+          drop=["architecture"])
+    return "0", ["architecture None", "known: ['mistral', 'mixtral']"]
+
+
+def _unknown_kind(root):
+    _edit(root / "benchmark/traffic/pretrain-s8k.json", kind="serve_open")
+    return "0", ["kind 'serve_open'", "known: ['train_job']"]
+
+
+def _unknown_reducer(root):
+    _edit(root / "benchmark/layer_metrics/mfu.moe.json",
+          reducer={"name": "mfu_of_nothing", "args": {}})
+    return "1", ["no reducer named 'mfu_of_nothing'", "'mfu_pct'",
+                 "'expert_flops_share_pct'", "'scope_ms_per_step'"]
+
+
+def _duplicate_reducer(root):
+    with open(root / "benchmark/reducers/again.py", "w") as f:
+        f.write("from lib.reducers import reducer\n\n\n@reducer\n"
+                "def mfu_pct(ctx, args):\n    return 1.0\n")
+    return "1", ["reducer 'mfu_pct' is defined twice", "lib.reducers",
+                 "reducers.again"]
+
+
+def _drifted_expert_count(root):
+    _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
+          num_local_experts=8)
+    return "0", ["num_local_experts=8", "num_experts=4"]
+
+
+def _missing_demanded_width(root):
+    _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
+          drop=["num_experts_per_tok"])
+    return "0", ["demands 'num_experts_per_tok'"]
+
+
+@pytest.mark.parametrize("breakage", [
+    _unknown_architecture, _no_architecture_key, _unknown_kind,
+    _unknown_reducer, _duplicate_reducer, _drifted_expert_count,
+    _missing_demanded_width], ids=lambda f: f.__name__.strip("_"))
+def test_what_is_not_there_fails_and_names_what_is(copy, breakage):
+    trace, said = breakage(copy)
+    p = _rig(copy, trace=trace)
+    assert p.returncode != 0
+    assert not any(ln.startswith("{") for ln in p.stdout.splitlines())
+    for text in said:
+        assert text in p.stderr, (text, p.stderr[-2000:])
+    if breakage not in (_drifted_expert_count, _missing_demanded_width):
+        # found by name before any device work: no cell line was printed
+        assert "cell train-moe" not in p.stdout
