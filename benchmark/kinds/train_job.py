@@ -70,37 +70,86 @@ def _program_tail_logits(engine, model, tokens):
     return fwd(engine.state["params"], _put(engine, tokens))
 
 
-def errors(got, ref) -> tuple[float, float]:
+def errors(got, ref, counted=None) -> tuple[float, float]:
     """(max, rms) error of ``got`` against ``ref``, each relative to the
     reference's own scale: max|got-ref| / max|ref| and
-    rms(got-ref) / rms(ref). Non-finite output is infinitely wrong."""
+    rms(got-ref) / rms(ref). With ``counted`` (boolean, the tails' shape
+    without the vocabulary axis) both errors and both scales are taken
+    over the counted positions only. Non-finite output is infinitely
+    wrong wherever it is, and so is a mask that counts nothing."""
     import jax.numpy as jnp
     got = jnp.asarray(got, jnp.float32)
     ref = jnp.asarray(ref, jnp.float32)
     if not bool(jnp.all(jnp.isfinite(got))):
         return float("inf"), float("inf")
-    return (float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref))),
-            float(jnp.sqrt(jnp.mean((got - ref) ** 2))
-                  / jnp.sqrt(jnp.mean(ref ** 2))))
+    if counted is None:
+        return (float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref))),
+                float(jnp.sqrt(jnp.mean((got - ref) ** 2))
+                      / jnp.sqrt(jnp.mean(ref ** 2))))
+    if not bool(jnp.any(counted)):
+        return float("inf"), float("inf")
+    keep = jnp.asarray(counted)[..., None]
+    d = jnp.where(keep, got - ref, 0.0)
+    ref = jnp.where(keep, ref, 0.0)
+    # the number of counted positions cancels in the ratio of the means
+    return (float(jnp.max(jnp.abs(d)) / jnp.max(jnp.abs(ref))),
+            float(jnp.sqrt(jnp.sum(d ** 2)) / jnp.sqrt(jnp.sum(ref ** 2))))
 
 
-def agreement(engine, model, arch, batch: np.ndarray) -> dict:
+def reference_of(arch, params, tokens, targets, m: dict):
+    """``arch.reference`` as (loss, tail logits, counted): an architecture
+    whose forward pass takes no discrete decision returns two values and
+    every position counts (``counted`` None)."""
+    loss, tail, *mask = arch.reference(params, tokens, targets, m, TAIL)
+    return loss, tail, (mask[0] if mask else None)
+
+
+def tail_numbers(got_tail, ref_tail, counted) -> dict:
+    """What the tails give the decision: the two logits errors over the
+    counted positions and, where the architecture returned a mask, the
+    share of positions it left out and the number it counted."""
+    err_max, err_rms = errors(got_tail, ref_tail, counted)
+    out = {"logits_err_max": err_max, "logits_err_rms": err_rms}
+    if counted is not None:
+        n = int(np.sum(np.asarray(counted)))
+        out.update(excluded_share=1.0 - n / counted.size,
+                   positions_counted=n)
+    return out
+
+
+# number of the agreement -> the key of ``check`` that limits it
+LIMITS = {"logits_err_max": "logits_err_max",
+          "logits_err_rms": "logits_err_rms", "loss_err": "loss_err",
+          "excluded_share": "excluded_share_max"}
+
+
+def decide(numbers: dict, ref_loss: float, got_loss: float,
+           check: dict) -> bool:
+    """THE decision, for the program (``run``) and for whatever stands in
+    its place (``tests/control.py``): adds ``loss_err`` to ``numbers``
+    and holds every number of ``LIMITS`` that is there to its limit in
+    the configuration's ``check``."""
+    numbers["loss_err"] = abs(got_loss - ref_loss) / abs(ref_loss)
+    return all(numbers[k] <= check[limit]
+               for k, limit in LIMITS.items() if k in numbers)
+
+
+def agreement(engine, model, arch, batch: np.ndarray, check: dict) -> dict:
     """Before the first step: the architecture's reference loss (as the
-    engine defines it) and tail logits on the engine's own float32 master
-    weights, and the program's tail logits. The engine's first-step loss
-    is compared by the caller."""
+    engine defines it), tail logits and mask on the engine's own float32
+    master weights, and the program's tail logits. The engine's
+    first-step loss is compared by the caller."""
     import jax
-    m = modelspec.reference_model(arch, model)
+    m = modelspec.reference_model(arch, model, check)
     tokens, targets = batch[:, :-1], batch[:, 1:]
     master = engine.state["master"] or engine.state["params"]
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_tail = arch.reference(
-            master, _put(engine, tokens), _put(engine, targets), m, TAIL)
+        ref_loss, ref_tail, counted = reference_of(
+            arch, master, _put(engine, tokens), _put(engine, targets), m)
     got_tail = _program_tail_logits(engine, model, tokens)
-    err_max, err_rms = errors(got_tail, ref_tail)
+    numbers = tail_numbers(got_tail, ref_tail, counted)
     del got_tail, ref_tail
-    return {"ref_loss": ref_loss, "logits_err_max": err_max,
-            "logits_err_rms": err_rms}
+    return {"ref_loss": ref_loss, **numbers}
 
 
 def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
@@ -124,14 +173,10 @@ def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
 
     # ---- set-up: agreement with the reference, then warm-up ------------
     tol = cfg_file["check"]
-    agree = agreement(engine, model, arch, pool[0])
+    agree = agreement(engine, model, arch, pool[0], tol)
     first_loss = float(step(0))
     agree["first_step_loss"] = first_loss
-    agree["loss_err"] = abs(first_loss - agree["ref_loss"]) / abs(
-        agree["ref_loss"])
-    agree_ok = (agree["logits_err_max"] <= tol["logits_err_max"]
-                and agree["logits_err_rms"] <= tol["logits_err_rms"]
-                and agree["loss_err"] <= tol["loss_err"])
+    agree_ok = decide(agree, agree["ref_loss"], first_loss, tol)
     print(f"agreement: {agree} tolerances {tol} ok={agree_ok}", flush=True)
     for i in range(1, int(tr["warmup_steps"])):
         step(i)

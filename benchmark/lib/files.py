@@ -51,7 +51,9 @@ def load_module(directory: str, name, what: str):
 def load_cell(name: str) -> dict:
     """The cell with its files, and the code they name: ``arch`` (the
     configuration's ``architecture``) and ``job`` (the traffic's ``kind``).
-    Both are found, or the error raised, before any device work."""
+    Both are found, or the error raised, before any device work; so is a
+    ``check`` key the architecture declares (``CHECK_KEYS``) and the
+    configuration lacks."""
     cell = _load("cells", name)
     cell["name"] = name
     cell["config_file"] = load_config(cell["config"])
@@ -61,6 +63,15 @@ def load_cell(name: str) -> dict:
         f"configuration {cell['config']}")
     cell["job"] = load_module("kinds", cell["traffic_file"].get("kind"),
                               f"traffic mix {cell['traffic']}")
+    demanded = tuple(getattr(cell["arch"], "CHECK_KEYS", ()))
+    lacking = [key for key in demanded
+               if key not in cell["config_file"].get("check", {})]
+    if lacking:
+        raise BenchmarkFileError(
+            f"architecture {cell['config_file']['architecture']!r} demands "
+            f"{lacking} of the 'check' of configuration {cell['config']}, "
+            f"which lacks it (its reference returns a mask; it demands: "
+            f"{list(demanded)})")
     return cell
 
 

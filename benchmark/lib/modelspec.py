@@ -39,8 +39,14 @@ def build_model(cfg_file: dict, arch, rig: dict):
     return model
 
 
-def reference_model(arch, model) -> dict:
+def reference_model(arch, model, check: dict | None = None) -> dict:
     """Published-key numbers of the model AS BUILT (equal to the file's in
-    a real run; the tiny preset's in the benchmark's CPU tests)."""
-    return {key: getattr(model.config, attr)
-            for key, attr in arch.WIDTHS.items()}
+    a real run; the tiny preset's in the benchmark's CPU tests). With the
+    configuration's ``check``, also the keys of it that the architecture
+    declares in ``CHECK_KEYS``: what its reference needs to say which
+    positions it can vouch for."""
+    m = {key: getattr(model.config, attr)
+         for key, attr in arch.WIDTHS.items()}
+    if check is not None:
+        m.update({key: check[key] for key in getattr(arch, "CHECK_KEYS", ())})
+    return m

@@ -129,6 +129,28 @@ def mfu_pct(ctx, args):
             / (ctx["chips"] * ctx["peaks"]["bf16_flops_per_s"]))
 
 
+def least_ms_per_step(ctx, cost: str, per: str) -> float:
+    """Least milliseconds a step's calls of one kernel could take on this
+    chip: the architecture's cost function ``cost`` (called as
+    ``flash_call_cost`` is: the model, this chip's sequences, the sequence
+    length, ``backward=``) for the forward and the backward call, through
+    ``least_seconds``; ``per`` = ``layer`` counts one such pair a layer,
+    ``step`` one a step."""
+    m, arch = ctx["model"], ctx["arch"]
+    local = max(1, ctx["sequences"] // ctx["chips"])
+    least = 0.0
+    for backward in (False, True):
+        call = getattr(arch, cost)(m, local, ctx["seq_len"],
+                                   backward=backward)
+        least += arch.least_seconds(call, ctx["peaks"])[0]
+    if per == "layer":
+        least *= m["num_hidden_layers"]
+    elif per != "step":
+        raise files.BenchmarkFileError(
+            f"per is {per!r}: a kernel runs once a 'layer' or once a 'step'")
+    return 1e3 * least
+
+
 @reducer
 def flash_roofline_pct(ctx, args):
     """Least time the chip could take for the step's flash calls (forward
@@ -137,13 +159,4 @@ def flash_roofline_pct(ctx, args):
     ms = device_op_ms_per_step(ctx, args)
     if not ms:
         return None
-    m, arch = ctx["model"], ctx["arch"]
-    local = max(1, ctx["sequences"] // ctx["chips"])
-    least = 0.0
-    for backward in (False, True):
-        cost = arch.flash_call_cost(m, local, ctx["seq_len"],
-                                    backward=backward)
-        least += arch.least_seconds(cost, ctx["peaks"])[0]
-    least *= m["num_hidden_layers"]
-    return 100.0 * (1e3 * least) / ms
-
+    return 100.0 * least_ms_per_step(ctx, "flash_call_cost", "layer") / ms

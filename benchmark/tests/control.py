@@ -1,18 +1,25 @@
 """The control of ``correct``: the architecture's reference put in the
-program's place and computed one precision below the configurations'
-bfloat16 (``CONTROL``: both operands of every matmul against a weight
-rounded to fp8 e4m3, per-tensor scaled), compared with the float32
-reference exactly as ``kinds/train_job.py`` compares the program. It has to
-come out NOT correct. The benchmark's own runs never run it, and the
-reference knows nothing of it: ``weight_matmuls_in`` rounds from outside,
-so it serves every architecture module as it is.
+program's place with both operands of every matmul against a weight
+rounded to a lower precision, compared with the float32 reference through
+the functions ``kinds/train_job.py`` compares the program with
+(``tail_numbers``, ``decide``). The reference knows nothing of it:
+``weight_matmuls_in`` rounds from outside, so it serves every architecture
+module as it is. The benchmark's own runs never run it.
 
-    chiprun -- python3 benchmark/tests/control.py <cell> <seed> [<seed> ...]
+    chiprun -- python3 benchmark/tests/control.py <cell> [--as <dtype>] <seed> [<seed> ...]
 
-prints one JSON line a seed (a new process each: the engine fills the chip).
-On the CPU ``tests/test_benchmark.py`` runs it at the tiny preset.
+``--as float8_e4m3fn`` (the default, ``CONTROL``: one precision below the
+configurations' bfloat16, per-tensor scaled) has to come out NOT correct.
+``--as bfloat16`` is what a right program looks like through the check,
+and has to come out correct: it shows that a check passes a right program
+before that program exists. Where the architecture returns a mask, the
+line also carries the errors over ALL positions (``all_positions``: the
+reading without the mask). One JSON line a seed (a new process each: the
+engine fills the chip). On the CPU ``tests/test_benchmark.py`` and
+``tests/test_routed_check.py`` run it at the tiny widths.
 """
 
+import argparse
 import contextlib
 import json
 import os
@@ -29,6 +36,9 @@ def _rounded(t, dtype):
     """``t`` rounded to ``dtype``, scaled so that its largest magnitude is
     the type's largest (per-tensor scaling, as fp8 recipes do)."""
     import jax.numpy as jnp
+    if jnp.finfo(dtype).maxexp == jnp.finfo(jnp.float32).maxexp:
+        # bfloat16 has float32's range: rounded as a cast rounds it
+        return t.astype(dtype).astype(jnp.float32)
     scale = float(jnp.finfo(dtype).max) / jnp.max(jnp.abs(t))
     return (t * scale).astype(dtype).astype(jnp.float32) / scale
 
@@ -61,8 +71,10 @@ def weight_matmuls_in(dtype):
         jax.clear_caches()
 
 
-def control(cell_name: str, seed: int, rig: dict) -> dict:
-    import jax
+def staged(cell_name: str, seed: int, rig: dict):
+    """The engine of the cell built from the seed (never stepped), and
+    what a reference is called with: (cell, its ``check`` keys in ``m``,
+    the float32 master weights, tokens, targets)."""
     import run
     from kinds import train_job
     from lib import files, modelspec, traffic
@@ -76,32 +88,65 @@ def control(cell_name: str, seed: int, rig: dict) -> dict:
     batch = traffic.train_batches(cell["traffic_file"], seed,
                                   int(cell["chips"]),
                                   model.config.vocab_size)[0]
-    m = modelspec.reference_model(arch, model)
+    m = modelspec.reference_model(arch, model, cfg["check"])
     master = engine.state["master"] or engine.state["params"]
-    toks = train_job._put(engine, batch[:, :-1])
-    tgts = train_job._put(engine, batch[:, 1:])
+    return (cell, m, master, train_job._put(engine, batch[:, :-1]),
+            train_job._put(engine, batch[:, 1:]))
+
+
+def control(cell_name: str, seed: int, rig: dict,
+            stand_in: str = CONTROL) -> dict:
+    import time
+
+    import jax
+    from kinds import train_job
+    cell, m, master, toks, tgts = staged(cell_name, seed, rig)
+    arch, cfg = cell["arch"], cell["config_file"]
     with jax.default_matmul_precision("highest"):
-        ref_loss, ref_tail = arch.reference(master, toks, tgts, m,
-                                            train_job.TAIL)
-        with weight_matmuls_in(CONTROL) as traced:
-            got_loss, got_tail = arch.reference(master, toks, tgts, m,
-                                                train_job.TAIL)
+        t0 = time.perf_counter()
+        ref_loss, ref_tail, counted = train_job.reference_of(
+            arch, master, toks, tgts, m)
+        jax.block_until_ready(ref_tail)
+        reference_s = time.perf_counter() - t0
+        with weight_matmuls_in(stand_in) as traced:
+            # the mask is the float32 reference's: the stand-in's own is
+            # dropped, as the program's choices never reach the check
+            got_loss, got_tail, _ = train_job.reference_of(
+                arch, master, toks, tgts, m)
     assert traced, "the control rounded no matmul: it is the reference"
-    err_max, err_rms = train_job.errors(got_tail, ref_tail)
-    got = {"logits_err_max": err_max, "logits_err_rms": err_rms,
-           "loss_err": abs(got_loss - ref_loss) / abs(ref_loss)}
-    tol = {k: cfg["check"][k] for k in got}
-    return {"cell": cell_name, "seed": seed, "control": CONTROL,
-            "rounded_matmuls": len(traced), "got": got, "limits": tol,
-            "correct": all(got[k] <= tol[k] for k in got),
-            "device": jax.devices()[0].device_kind}
+    got = train_job.tail_numbers(got_tail, ref_tail, counted)
+    correct = train_job.decide(got, ref_loss, got_loss, cfg["check"])
+    out = {"cell": cell_name, "seed": seed, "control": stand_in,
+           "rounded_matmuls": len(traced), "got": got,
+           "limits": {k: cfg["check"][limit]
+                      for k, limit in train_job.LIMITS.items() if k in got},
+           "correct": correct, "positions": list(tgts.shape),
+           "reference_first_call_s": reference_s,
+           "device": jax.devices()[0].device_kind}
+    if counted is not None:
+        out["all_positions"] = train_job.tail_numbers(got_tail, ref_tail,
+                                                      None)
+    return out
+
+
+def cli(one, script: str, doc: str, default: str) -> None:
+    """``<script> <cell> [--as <dtype>] <seed> [<seed> ...]``: one seed
+    prints ``one(cell, seed, {}, dtype)`` as a JSON line; several run a new
+    process each (the engine fills the chip)."""
+    ap = argparse.ArgumentParser(description=doc.split("\n")[0])
+    ap.add_argument("cell")
+    ap.add_argument("--as", dest="stand_in", default=default,
+                    choices=("bfloat16", CONTROL))
+    ap.add_argument("seeds", type=int, nargs="+")
+    args = ap.parse_args()
+    if len(args.seeds) == 1:
+        print(json.dumps(one(args.cell, args.seeds[0], {}, args.stand_in)),
+              flush=True)
+    else:
+        for seed in args.seeds:
+            subprocess.run([sys.executable, script, args.cell, "--as",
+                            args.stand_in, str(seed)], check=True)
 
 
 if __name__ == "__main__":
-    if len(sys.argv) == 3:
-        print(json.dumps(control(sys.argv[1], int(sys.argv[2]), {})),
-              flush=True)
-    else:
-        for seed in sys.argv[2:]:
-            subprocess.run([sys.executable, __file__, sys.argv[1], seed],
-                           check=True)
+    cli(control, __file__, __doc__, CONTROL)

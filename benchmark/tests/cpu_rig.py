@@ -22,8 +22,14 @@ FAKE_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
 
 # the three keys ``run.main`` and ``lib/modelspec.py`` take from a rig:
 # fake peaks (and with them leave to run off a TPU), the tiny model, the
-# traffic shrunk with it
-RIG = {"peaks": FAKE_PEAKS, "tiny": {"max_seq_len": 128},
+# traffic shrunk with it. The widths are the ``tiny`` presets' own, named
+# here so that a configuration that reaches its widths through
+# ``model_overrides`` is shrunk too and keeps what no preset fixes (the
+# rehearsal's 64 experts top-8)
+RIG = {"peaks": FAKE_PEAKS,
+       "tiny": {"max_seq_len": 128, "hidden_size": 64, "num_heads": 4,
+                "num_kv_heads": 2, "intermediate_size": 128,
+                "vocab_size": 512},
        "traffic_overrides": {"seq_len": 128, "trace_seconds": 1.0}}
 
 

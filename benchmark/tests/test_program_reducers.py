@@ -131,6 +131,31 @@ def test_scoped_parts_telescope_to_the_device_step(scoped):
         "ds_flash_bwd.10 [tpu_custom_call]"
 
 
+def test_a_kernels_roofline_by_scope_reads_the_flash_kernels_share(scoped,
+                                                                   old):
+    """``kernel_roofline_pct`` finds its kernel by the program's scope and
+    its cost by name in the architecture module: for the two flash scopes
+    it reads ``flash_roofline.train``'s value (whose reducer finds them as
+    the trace's only Mosaic calls). A second Pallas kernel under another
+    scope would move that metric and not this one."""
+    args = {"scope": r"ds\.flash_(fwd|bwd)\b", "cost": "flash_call_cost",
+            "per": "layer", "module": STEP}
+    read = reducers.find("kernel_roofline_pct")
+    assert read(scoped, args) == pytest.approx(
+        _metric(scoped, "flash_roofline.train"), rel=1e-9)
+    assert abs(read(scoped, args) - 57.6905) < 1e-3
+    # forward alone against both calls' least time: another number
+    assert read(scoped, {**args, "scope": r"ds\.flash_fwd\b"}) > 100.0
+    # once a step and not once a layer: the least time of one layer
+    assert read(scoped, {**args, "per": "step"}) == pytest.approx(
+        read(scoped, args) / MISTRAL["num_hidden_layers"])
+    with pytest.raises(files.BenchmarkFileError, match="'layer' or"):
+        read(scoped, {**args, "per": "call"})
+    # no such scope, or a program without scopes: nothing to read
+    assert read(scoped, {**args, "scope": r"ds\.moe_gmm\b"}) is None
+    assert read(old, args) is None
+
+
 def test_clock_bracket_and_aligned_gaps_on_the_recorded_trace(scoped):
     width = _metric(scoped, "clock_bracket_us.train")
     br = scoped["clock_bracket"]

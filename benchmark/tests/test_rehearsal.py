@@ -1,9 +1,11 @@
 """A second architecture arrives as files: in a temporary copy of
 ``benchmark/`` the files under ``tests/rehearsal/`` are ADDED (an
-architecture module for the program's ``mixtral`` ``tiny`` preset, a
-configuration, a cell, two per-layer metrics, a reducer in a new file),
-nothing that was there is edited, and the new cell runs through
-``cpu_rig``. Then what must fail does, naming what is known. By hand:
+architecture module for the program's ``mixtral`` class whose reference
+returns a mask, a configuration of its ``tiny`` preset and one at OLMoE's
+router shape, a cell each, a traffic mix, two per-layer metrics, a reducer
+in a new file, the script that reads where routing flips), nothing that
+was there is edited, and the new cells run through ``cpu_rig``. Then what
+must fail does, naming what is known. By hand:
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
@@ -152,6 +154,38 @@ def test_the_suite_stays_green_with_the_added_files(copy):
         k: v for k, v in before.items() if not k.startswith(".bench_trace")}
 
 
+@pytest.mark.parametrize("stand_in,correct", [("bfloat16", True),
+                                              ("float8_e4m3fn", False)])
+def test_the_routed_check_passes_bf16_and_fails_fp8(copy, stand_in, correct):
+    """``control.py`` on the second rehearsal cell (the ``mixtral`` class
+    at the tiny widths with the file's 64 experts top-8, the engine's own
+    weights): the reference in the program's place in bfloat16 is what a
+    right program looks like and comes out correct; in fp8 it does not.
+    On the chip at OLMoE's widths: ``PERF.md`` section 2."""
+    tests = copy / "benchmark" / "tests"
+    code = ("import sys, json; sys.path[:0] = [%r, %r]; "
+            "import cpu_rig, control; print(json.dumps(control.control("
+            "'check-moe-64x8-1chip', 3000000019, cpu_rig.RIG, %r)))"
+            % (str(tests), str(copy / "benchmark"), stand_in))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=CHECKOUT,
+               XLA_FLAGS="--xla_force_host_platform_device_count=1",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(
+                   CHECKOUT, ".bench_trace", "test_jax_cache"))
+    p = subprocess.run([sys.executable, "-c", code], cwd=copy, env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    assert got["control"] == stand_in and got["correct"] is correct, got
+    assert got["rounded_matmuls"] > 0
+    assert set(got["got"]) == {"logits_err_max", "logits_err_rms",
+                               "excluded_share", "positions_counted",
+                               "loss_err"}
+    assert set(got["limits"]) == {"logits_err_max", "logits_err_rms",
+                                  "excluded_share", "loss_err"}
+    assert got["got"]["excluded_share"] <= got["limits"]["excluded_share"]
+    assert set(got["all_positions"]) == {"logits_err_max", "logits_err_rms"}
+
+
 def _unknown_architecture(root):
     _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
           architecture="mamba")
@@ -190,6 +224,17 @@ def _drifted_expert_count(root):
     return "0", ["num_local_experts=8", "num_experts=4"]
 
 
+def _missing_check_key(root):
+    path = root / "benchmark/configs/mixtral-tiny-zero3-1chip.json"
+    with open(path) as f:
+        check = json.load(f)["check"]
+    del check["routing_margin"]
+    _edit(path, check=check)
+    return "0", ["demands ['routing_margin'] of the 'check' of "
+                 "configuration mixtral-tiny-zero3-1chip",
+                 "['routing_margin', 'excluded_share_max']"]
+
+
 def _missing_demanded_width(root):
     _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
           drop=["num_experts_per_tok"])
@@ -198,8 +243,8 @@ def _missing_demanded_width(root):
 
 @pytest.mark.parametrize("breakage", [
     _unknown_architecture, _no_architecture_key, _unknown_kind,
-    _unknown_reducer, _duplicate_reducer, _drifted_expert_count,
-    _missing_demanded_width], ids=lambda f: f.__name__.strip("_"))
+    _unknown_reducer, _duplicate_reducer, _missing_check_key,
+    _drifted_expert_count, _missing_demanded_width], ids=lambda f: f.__name__.strip("_"))
 def test_what_is_not_there_fails_and_names_what_is(copy, breakage):
     trace, said = breakage(copy)
     p = _rig(copy, trace=trace)
