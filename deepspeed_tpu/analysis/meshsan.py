@@ -223,11 +223,17 @@ class MeshSanitizer:
                     "GSPMD reshard moved traffic onto an axis this "
                     "executable never declared", "undeclared-axis"))
                 continue
+            # a permute that is the compiler's own form of a gather or
+            # scatter (collectives.analyze_hlo ``implements``) is held
+            # to that class, which every axis may carry
             if nbytes >= contract.min_bytes \
+                    and not (op == "ppermute" and r.get("implements")) \
                     and not contract.op_declared(axis, op):
+                owner = (f", op_name '{r['op_name']}'"
+                         if r.get("op_name") else "")
                 msgs.append(self._fail(
                     f"executable '{name}': unexpected {op} on axis "
-                    f"'{axis}' ({nbytes} B) — the GSPMD "
+                    f"'{axis}' ({nbytes} B{owner}) — the GSPMD "
                     "silent-reshard signature (a producer/consumer "
                     "spec mismatch makes the partitioner insert an "
                     "exchange no call site asked for)",

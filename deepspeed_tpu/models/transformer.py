@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
+from ..parallel.mesh import constrain_free
 from .base import Model, ModelConfig, Rules
 
 PyTree = Any
@@ -480,26 +481,35 @@ class DecoderLM:
                       positions=None, act_sharding=None):
         """Final-normed hidden states [B, S, D] + router aux loss.
 
-        ``act_sharding`` (a NamedSharding for [B, S, D]) pins the
-        layer-scan carry to one canonical layout. Without it, a
-        sequence-parallel attn_fn (shard_map manual over sp) plus
-        fsdp-sharded stacked weights leaves GSPMD free to flip
-        activation/weight layouts between scan iterations — on the ring
-        config that produced 'Involuntary full rematerialization'
-        resharding of the embed gradient scatter-add (VERDICT r4 #2)."""
+        ``act_sharding`` (a NamedSharding for [B, S, D]; the engine
+        passes ``[B(batch axes), S(sp), D]`` on every mesh of more than
+        one device) pins the layer-scan carry to the ZeRO plan's layout,
+        in the forward and, through the constraint's transpose, in the
+        backward: activations stay on the chip that owns their sequences
+        and each layer's weights are gathered to them. Without it GSPMD
+        lays out ``[B, S, D]`` and ``[B, S, F]`` between two shard_map
+        boundaries as it likes. With fsdp-sharded stacked weights the
+        TPU compile then moved the activations to the weights: on
+        ``fsdp=4`` at Mistral-7B widths it ran the MLP's backward
+        tensor-parallel over ``fsdp``, five 224 MiB all-to-alls a layer
+        (PERF.md, PR 28); on the ring configuration it flipped layouts
+        between scan iterations ('Involuntary full rematerialization' of
+        the embed gradient scatter-add, VERDICT r4 #2). The constraint
+        holds wherever this is traced (``parallel.mesh.constrain_free``:
+        axes manual in an enclosing region are dropped, an uneven batch
+        stays unconstrained)."""
         c = self.config
+        pin = (functools.partial(constrain_free, sharding=act_sharding)
+               if act_sharding is not None else lambda x: x)
         with jax.named_scope("ds.embed"):
             x = self.embed(params, tokens, positions)
-        if act_sharding is not None:
-            x = jax.lax.with_sharding_constraint(x, act_sharding)
+        x = pin(x)
 
         def body(carry, layer_params):
             x, aux = carry
             x, layer_aux = self.block(layer_params, x, attn_fn=attn_fn,
                                       positions=positions)
-            if act_sharding is not None:
-                x = jax.lax.with_sharding_constraint(x, act_sharding)
-            return (x, aux + layer_aux), None
+            return (pin(x), aux + layer_aux), None
 
         if c.remat and c.remat_policy != "segments":
             # "segments" applies selective checkpoints INSIDE block()

@@ -390,12 +390,11 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
     wrappers."""
     from jax.sharding import PartitionSpec as P
 
+    from ...parallel.mesh import active_mesh
     from ...utils.jax_compat import shard_map
 
     def attn(q, k, v, *, causal: bool = True, **_kw):
-        active = jax.sharding.get_abstract_mesh()
-        use = active if active.shape else mesh
-        free = [a for a in use.axis_names if a not in use.manual_axes]
+        use, free = active_mesh(mesh)
         b_ax = tuple(a for a in batch_axes
                      if a in free and use.shape[a] > 1)
         if q.shape[0] % math.prod(use.shape[a] for a in b_ax):

@@ -43,6 +43,40 @@ AXIS_ORDER = ("pp", "dp", "fsdp", "zps", "ep", "sp", "tp")
 BATCH_AXES = ("dp", "fsdp", "zps")
 
 
+def active_mesh(mesh):
+    """``(mesh, free axes)`` as seen from where the caller is traced:
+    inside a ``shard_map`` the active abstract mesh, whose manual axes a
+    nested spec may not name (the compiled pipeline's ``pp`` map, ZeRO++'s
+    fully manual gradient map), else ``mesh`` itself with every axis
+    free."""
+    active = jax.sharding.get_abstract_mesh()
+    use = active if active.shape else mesh
+    return use, tuple(a for a in use.axis_names
+                      if a not in use.manual_axes)
+
+
+def constrain_free(x: jax.Array, sharding: NamedSharding) -> jax.Array:
+    """``with_sharding_constraint(x, sharding)`` that holds wherever the
+    model is traced. Axes that are manual in an enclosing region are
+    dropped from the spec (a ``NamedSharding`` naming them does not lower
+    there), and so are axes of extent 1. A dimension its remaining axes
+    do not divide (an uneven batch) leaves ``x`` unconstrained, as the
+    flash wrapper leaves such a batch replicated; so does a spec with
+    nothing left to say."""
+    use, free = active_mesh(sharding.mesh)
+    spec = []
+    for dim, entry in zip(x.shape, sharding.spec):
+        names = (entry,) if isinstance(entry, str) else tuple(entry or ())
+        keep = tuple(a for a in names if a in free and use.shape[a] > 1)
+        if dim % math.prod(use.shape[a] for a in keep):
+            return x
+        spec.append(keep or None)
+    if not any(spec):
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(use, PartitionSpec(*spec)))
+
+
 def build_device_array(axis_order: Sequence[str], shape: Sequence[int],
                        dcn_sizes: dict, devices: Sequence) -> np.ndarray:
     """Physical-topology-aware device placement (reference:

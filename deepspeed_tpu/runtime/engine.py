@@ -463,26 +463,39 @@ class DeepSpeedEngine:
 
     # ------------------------------------------------------------------
     def _configure_sequence_parallel(self):
-        """Choose the loss fn: attention wrapped for SP when mesh.sp > 1,
-        flash kernels run per shard when the mesh has >1 device."""
+        """Bind the mesh to the model's loss. On every mesh of more than
+        one device the layer scan's carry is pinned to the ZeRO plan's
+        activation layout ``[B(batch axes), S(sp), D]`` (``act_sharding``,
+        where ``loss()`` takes it): GSPMD then gathers each layer's
+        weights to the activations and never moves the activations to
+        the weights. Attention is wrapped for SP when mesh.sp > 1; else
+        the flash kernels run per shard. On one device nothing is bound
+        and the step is the model's own."""
         sp = self.topology.sequence_parallel_size
         import inspect
+        accepts = inspect.signature(self.module.loss).parameters
+        kw = {}
+        if self.mesh.size > 1 and "act_sharding" in accepts:
+            # left free, the carry was resharded behind the plan's back:
+            # all-to-alls in the MLP backward on fsdp=4 (PR 28), per-
+            # iteration reshards under a manual-sp attn_fn (ring config)
+            kw["act_sharding"] = self.topology.sharding(
+                self.topology.batch_axes(), "sp")
         if sp <= 1:
             c = self.model_config
             if (self.mesh.size > 1
                     and getattr(c, "attn_impl", None) == "flash"
-                    and "attn_fn" in inspect.signature(
-                        self.module.loss).parameters):
+                    and "attn_fn" in accepts):
                 # the flash kernels are Mosaic custom calls, which GSPMD
                 # cannot partition: run them per shard
                 from ..ops.pallas.flash_attention import \
                     sharded_flash_attention
-                return functools.partial(
-                    self.module.loss, attn_fn=sharded_flash_attention(
-                        self.mesh, self.topology.batch_axes(),
-                        window=c.sliding_window))
-            return self.module.loss
-        if "attn_fn" not in inspect.signature(self.module.loss).parameters:
+                kw["attn_fn"] = sharded_flash_attention(
+                    self.mesh, self.topology.batch_axes(),
+                    window=c.sliding_window)
+            return (functools.partial(self.module.loss, **kw) if kw
+                    else self.module.loss)
+        if "attn_fn" not in accepts:
             raise ValueError(
                 "sequence parallelism (mesh.sp > 1) requires the model's "
                 "loss() to accept attn_fn (DecoderLM does)")
@@ -496,14 +509,6 @@ class DeepSpeedEngine:
         else:
             raise ValueError(f"unknown sequence_parallel.mode {mode!r}")
         log_dist(f"sequence parallelism: {mode} over sp={sp}")
-        # pin the activation layout [B(batch axes), S(sp), D] through the
-        # layer scan: with a manual-sp attn_fn inside and fsdp-stacked
-        # weights, unconstrained carries let GSPMD reshard per iteration
-        # (ring config's involuntary-full-rematerialization warnings)
-        kw = {}
-        if "act_sharding" in inspect.signature(self.module.loss).parameters:
-            kw["act_sharding"] = self.topology.sharding(
-                self.topology.batch_axes(), "sp")
         return functools.partial(self.module.loss, attn_fn=attn, **kw)
 
     def _flops_per_sample(self):

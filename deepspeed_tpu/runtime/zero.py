@@ -13,6 +13,16 @@ pytrees carry the fsdp mesh axis*:
             the scan-over-layers, overlapping gather with compute — the
             static-schedule version of the prefetch coordinator)  (os+g+pP)
 
+Stage 3's promise (activations stay on the chip that owns their sequences,
+each layer's weights are gathered to them and its gradients reduce-scattered
+from them) holds because the engine pins the layer scan's carry to
+``[B(batch axes), S(sp), D]`` on every mesh of more than one device
+(``engine._configure_sequence_parallel`` -> ``DecoderLM._final_hidden``).
+The plan shards the FFN weights on the FFN dimension (``overlay_axis``
+takes the largest divisible one), and with the carry free GSPMD moved the
+activations to the weights instead: on ``fsdp=4`` the TPU compile ran the
+MLP's backward tensor-parallel, five 224 MiB all-to-alls a layer (PR 28).
+
 The planner computes PartitionSpec trees per stage on top of the model's
 tensor-parallel rules, so ZeRO composes with TP/SP/PP exactly like the
 reference's hybrid topologies (§2.3).

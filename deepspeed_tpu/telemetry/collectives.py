@@ -57,6 +57,10 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{([0-9,{} ]*)\}")
 _COMPUTATION_RE = re.compile(
     r"^\s*(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s+\(.*->.*\{\s*$")
 _CUSTOM_CALL_RE = re.compile(r'custom_call_target="([^"]+)"')
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_RESULT_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_SLICE_OF_RE = re.compile(r"\bslice\(%?([\w.\-]+)\)")
+_PERMUTE_OF_RE = re.compile(r"collective-permute(?:-start)?\(%?([\w.\-]+)")
 
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "s4": 1, "u2": 1, "u4": 1,
@@ -75,15 +79,23 @@ def _dtype_bytes(name: str) -> int:
     return 4
 
 
-def _shapes_bytes(text: str) -> tuple[int, int]:
+def _shapes_bytes(text: str, result_half: bool = False) -> tuple[int, int]:
     """(total bytes, total elements) of every ``dtype[dims]`` shape
     token in ``text`` (handles variadic tuple results). The ratio is
     the instruction's effective wire width — 1.x bytes/element once
     qwZ/qgZ put int8/fp8 payloads (plus fp32 block scales) on the
-    wire, 4.0 for a plain fp32 collective."""
+    wire, 4.0 for a plain fp32 collective. ``result_half``: the tuple
+    of an async ``all-gather-start`` / ``collective-permute-start``
+    carries its operands beside its results (and ``u32[]`` contexts);
+    only the results are payload."""
     total = 0
     elements = 0
-    for dtype, dims in _SHAPE_RE.findall(text):
+    shapes = _SHAPE_RE.findall(text)
+    if result_half:
+        # (operands..., results..., context scalars...): the results
+        arrays = [s for s in shapes if s[1]]
+        shapes = arrays[len(arrays) // 2:]
+    for dtype, dims in shapes:
         n = 1
         for d in dims.split(","):
             if d:
@@ -188,25 +200,57 @@ def analyze_hlo(hlo_text: str, mesh=None,
     whose computation is named ``all-reduce-scatter*`` and holds an
     ``all-reduce`` of the full input followed by a ``dynamic-slice``
     (seen in the v5e HLO of a ZeRO-3 step, PR 21); such an all-reduce
-    is recorded as the reduce-scatter it implements."""
+    is recorded as the reduce-scatter it implements.
+
+    Every record carries the instruction's ``op_name`` metadata (the
+    jaxpr path with its ``ds.`` scopes: the owner of a collective no
+    call site asked for), and a collective-permute that is the TPU
+    compiler's own form of a gather or scatter says so under
+    ``implements`` (seen in the v5e:2x2 HLO of the ZeRO-3 step, PR 28):
+    ``"collective_matmul"`` for a ring step of a windowed einsum (the
+    all-gather or reduce-scatter beside a dot, pipelined with it; the
+    permute keeps the dot's ``op_name``), ``"reduce_scatter"`` for the
+    halo shift after an ``all-reduce-scatter`` fusion whose padded
+    shards (8064 rows for 32000 / 4) are not the plan's. Its ``op``
+    stays ``ppermute``: that is what is on the wire."""
     axis_table = mesh_axis_groups(mesh)
     records: list[dict] = []
     computation = ""
+    scattered: set[str] = set()     # results of all-reduce-scatter fusions
     for line in hlo_text.splitlines():
         header = _COMPUTATION_RE.match(line)
         if header is not None:
             computation = header.group("name")
             continue
         m = _OP_RE.search(line)
-        if m is None or "-done" in line.split("=", 1)[0]:
+        if m is None:
+            # a scattered value, or (once there is one) a slice of one
+            sliced = scattered and _SLICE_OF_RE.search(line)
+            if ("calls=%all-reduce-scatter" in line
+                    or (sliced and sliced.group(1) in scattered)):
+                result = _RESULT_RE.match(line)
+                if result is not None:
+                    scattered.add(result.group(1))
+            continue
+        if "-done" in line.split("=", 1)[0]:
             continue
         hlo_op = m.group("op")
         fused_rs = (hlo_op == "all-reduce"
                     and computation.startswith("all-reduce-scatter"))
-        out_bytes, out_elements = _shapes_bytes(m.group("shapes"))
+        out_bytes, out_elements = _shapes_bytes(
+            m.group("shapes"),
+            result_half=bool(m.group("start")) and hlo_op in (
+                "all-gather", "collective-permute"))
         groups = _parse_groups(line)
         axis = None
+        named = _OP_NAME_RE.search(line)
+        op_name = named.group(1) if named else ""
+        implements = None
         if hlo_op == "collective-permute":
+            if op_name.rsplit("/", 1)[-1] == "dot_general":
+                implements = "collective_matmul"
+            elif _PERMUTE_OF_RE.search(line).group(1) in scattered:
+                implements = "reduce_scatter"
             pm = _PAIRS_RE.search(line)
             pairs = []
             if pm:
@@ -248,6 +292,8 @@ def analyze_hlo(hlo_text: str, mesh=None,
             "group_size": int(group_size),
             "axis": axis or f"n{group_size}",
             "groups": len(groups) if groups else 1,
+            "op_name": op_name,
+            **({"implements": implements} if implements else {}),
         })
     return records
 

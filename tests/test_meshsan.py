@@ -117,6 +117,64 @@ def test_unexpected_all_to_all_is_the_reshard_signature():
         [_rec("tp", op="all_to_all", nbytes=3072)]) == []
 
 
+# recorded from the v5e:2x2 AOT compile of the four-chip cell's ZeRO-3
+# step (Mistral-7B widths, fsdp=4, sequence 8192; PR 28), trimmed: the
+# parent's tensor-parallel re-partitioning of the MLP backward, and the two
+# forms in which the TPU compiler runs the plan's own gathers and scatters
+# as collective-permutes
+_A2A = (
+    '  %all-to-all.11 = bf16[4,1,8192,3584]{2,3,1,0:T(8,128)(2,1)} '
+    'all-to-all(%fusion.789), channel_id=79, replica_groups=[1,4]<=[4], '
+    'dimensions={0}, metadata={op_name="jit(train_step)/transpose(jvp('
+    'ds.layers))/while/body/closed_call/checkpoint/ds.mlp/jit(silu)/mul" '
+    'stack_frame_id=120}')
+_RING = (
+    '  %collective-permute-start.24 = (bf16[4096,1,3584]{2,0,1:T(8,128)'
+    '(2,1)}, bf16[4096,1,3584]{2,0,1:T(8,128)(2,1)}, u32[]{:S(2)}, '
+    'u32[]{:S(2)}) collective-permute-start(%dynamic-slice_bitcast_fusion'
+    '.18), channel_id=71, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, '
+    'metadata={op_name="jit(train_step)/transpose(jvp(ds.layers))/while/'
+    'body/closed_call/checkpoint/ds.mlp/LEAF" stack_frame_id=169}')
+_HALO = '''
+  %fusion.5 = bf16[8064,4096]{1,0:T(8,128)(2,1)} fusion(%fusion.2), kind=kCustom, calls=%all-reduce-scatter, metadata={op_name="jit(train_step)/transpose(jvp(ds.embed))/jit(_take)/scatter-add" stack_frame_id=8}
+  %slice.215 = bf16[192,4096]{1,0:T(8,128)(2,1)} slice(%fusion.5), slice={[7872:8064], [0:4096]}
+  %collective-permute-start.76 = (bf16[192,4096]{1,0:T(8,128)(2,1)}, bf16[192,4096]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%slice.215), channel_id=196, source_target_pairs={{0,1},{1,2},{2,3}}
+  %collective-permute-done.76 = bf16[192,4096]{1,0:T(8,128)(2,1)} collective-permute-done(%collective-permute-start.76)
+'''
+
+
+@pytest.mark.parametrize("hlo,implements,finding", [
+    (_A2A, None, ("all_to_all", f"{224 << 20} B", "ds.mlp/jit(silu)/mul")),
+    (_RING.replace("LEAF", "dot_general"), "collective_matmul", None),
+    (_RING.replace("LEAF", "mul"), None,
+     ("ppermute", f"{4096 * 3584 * 2} B", "ds.mlp/mul")),
+    (_HALO, "reduce_scatter", None),
+], ids=["mlp_backward_all_to_all", "collective_matmul_ring",
+        "bare_permute", "reduce_scatter_halo"])
+def test_zero3_contract_on_recorded_tpu_hlo(hlo, implements, finding,
+                                            devices8):
+    """The detector that would have caught PR 28's fault catches it: the
+    parent's HLO line through the ledger's walk and the seeded ZeRO-3
+    training contract is an ``unexpected-op`` finding naming the axis,
+    the op, its 224 MiB and its owner. The compiler's own ring and halo
+    permutes are held to the gather or scatter they implement; a permute
+    that is neither is still a finding."""
+    from deepspeed_tpu.parallel.mesh import MeshTopology, TopologyConfig
+    from deepspeed_tpu.telemetry.collectives import analyze_hlo
+    topo = MeshTopology(TopologyConfig(fsdp=4), devices=devices8[:4])
+    (rec,) = analyze_hlo(hlo, topo.mesh)
+    assert rec["axis"] == "fsdp" and rec.get("implements") == implements
+    san = MeshSanitizer(mode="warn")
+    san.declare("compiled_step", seed_training_contract(topo.sizes))
+    msgs = san.check_records("compiled_step", [rec])
+    if finding is None:
+        assert msgs == []
+        return
+    (msg,) = msgs
+    assert "unexpected" in msg and "'fsdp'" in msg
+    assert all(part in msg for part in finding)
+
+
 def test_combined_axis_labels_check_by_component():
     """collectives.analyze_hlo labels multi-axis groups "fsdp+zps";
     declared iff every component is."""
