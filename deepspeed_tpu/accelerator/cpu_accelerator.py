@@ -57,7 +57,9 @@ class CPU_Accelerator(DeepSpeedAccelerator):
                     "bytes_limit": 0}
 
     def peak_flops(self, dtype: Any = None, device_index: Optional[int] = None) -> float:
-        return 1e12  # arbitrary floor, matches bench.py's CPU smoke value
+        # arbitrary floor with no measurement behind it (a CPU has no
+        # peak this package measures against; ROADMAP.md queue 3 item 5)
+        return 1e12
 
     def pin_memory(self, array, align_bytes: int = 1):
         return array  # host memory is host memory

@@ -10,7 +10,7 @@ import jax
 from .abstract_accelerator import DeepSpeedAccelerator
 
 # Peak dense bf16 FLOPS per chip by device-kind prefix. Sources: public TPU
-# spec sheets (same numbers bench.py uses for MFU accounting).
+# spec sheets (the benchmark keeps its own table, benchmark/lib/peaks.py).
 _PEAK_FLOPS_BF16 = (
     ("TPU v6 lite", 918e12),   # Trillium
     ("TPU v5 lite", 197e12),   # v5e

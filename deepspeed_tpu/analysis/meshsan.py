@@ -363,8 +363,8 @@ def seed_training_contract(axis_sizes: dict,
     the dispatch transpose legitimately stay full-precision and lower
     to all-to-alls on the SAME (axis, op) buckets, so an aggregate
     ceiling there would flag correct programs — the int8 dispatch-byte
-    claim is audited by the bench's per-op HLO accounting instead
-    (bench.py moe_train, `--gate moe`)."""
+    claim is audited per op from the ledger's HLO accounting instead
+    (``ds_hlo_collective_bytes_total{axis,op}``; tests/test_moe.py)."""
     live = {a for a, n in (axis_sizes or {}).items() if int(n) > 1}
     a2a = {"sp", "ep"} & live
     if quantized_gradients:

@@ -12,7 +12,7 @@ findings:
 
 - **nonfinite-grads**: the engine's train step folds per-leaf
   non-finite counts + max|g| into the same fused reduction that already
-  computes the overflow bit (``_grad_stats``); a bad step raises/warns
+  computes the overflow bit (the engine's ``update`` half); a bad step raises/warns
   with the executable's ledger name (``compiled_step``) and the worst
   leaf's PyTree path — "which executable, which leaf, what kind of
   blow-up" instead of one bit.

@@ -64,7 +64,9 @@ class AutotuningConfig(DeepSpeedConfigModel):
     # fraction to host via zero_optimization.offload_optimizer)
     offload_ratios: list[float] = Field(default_factory=lambda: [0.0])
     # overlap ratios the cost model assumes for collective hiding
-    # (BENCH_r05 measured the domino chunked-overlap at 0.71); extra
+    # (0.71 is the domino chunked-overlap ratio of a CPU run before the
+    # chip: a proxy with no chip measurement behind it, ROADMAP.md queue
+    # 3 item 5); extra
     # values re-score the same trial config under different overlap
     # assumptions, they do not change the emitted config
     overlap_ratios: list[float] = Field(default_factory=lambda: [0.71])
@@ -99,8 +101,8 @@ class AutotuningConfig(DeepSpeedConfigModel):
     # effective FLOPs/s + per-step overhead)
     calibration_steps: int = 3
     # timing windows per measurement; the BEST (min seconds/step)
-    # window is kept — the steady-state convention bench.py uses,
-    # which shields short CPU windows from scheduler jitter
+    # window is kept, which shields short CPU windows from scheduler
+    # jitter
     measure_windows: int = 2
     # run the calibration measurement when no explicit Calibration is
     # passed (False falls back to the accelerator peak-FLOPs table)

@@ -19,8 +19,9 @@ Two models, one calibration source:
   the ledger's HLO collective traffic over the span tracer's measured
   window (``ExecutableLedger.axis_algbw_bounds``), and the overlap
   ratio that decides how much collective time the schedule hides under
-  compute (T3-style: the domino chunked-overlap measurement,
-  BENCH_r05 ratio 0.71, is the honest default).
+  compute (T3-style; the default 0.71 is the domino chunked-overlap
+  ratio of a CPU run before the chip, a proxy with no chip measurement
+  behind it: ROADMAP.md queue 3 item 5).
 
 Everything here is host-only arithmetic (graftlint GL041 contract for
 ``autotuning/``): no jax tracing, no device dispatch — the planner
@@ -35,7 +36,7 @@ import math
 from typing import Any, Optional
 
 ADAM_STATE_BYTES = 16  # fp32 master + 2 fp32 moments per param
-GRAD_BYTES = 4         # grads accumulate in fp32 (engine _build_train_step)
+GRAD_BYTES = 4         # grads accumulate in fp32 (engine _step_parts accumulate)
 
 # per-layer live-activation multiplier by remat policy: how many
 # [micro_batch, seq, hidden]-sized residuals each layer keeps across the

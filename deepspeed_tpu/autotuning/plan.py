@@ -4,8 +4,8 @@ The planner's output is a JSON document — ranked candidates with
 predicted (and, for the measured top-K, observed) step time, per-axis
 collective bytes, the calibration it was scored under, and the chosen
 config diff — plus :meth:`Plan.apply`, which patches a base config
-dict so ``bench.py`` and users consume the planner's decision instead
-of hand-edited configs. The artifact deliberately carries no
+dict so users consume the planner's decision instead of hand-edited
+configs. The artifact deliberately carries no
 timestamps or RNG state: the same inputs produce a byte-identical
 plan (the determinism contract tests assert).
 """

@@ -581,9 +581,8 @@ class Planner:
             tuple[float, float]:
         """Warm up + time ``steps`` train_batch calls on an already-
         built engine, best of ``measure_windows`` windows (min
-        seconds/step — the steady-state convention bench.py uses;
-        short windows on a shared CPU host otherwise ride scheduler
-        jitter): (seconds/step, tokens/s)."""
+        seconds/step; short windows on a shared CPU host otherwise
+        ride scheduler jitter): (seconds/step, tokens/s)."""
         import jax
         batch = self._batch(self.total_batch(cand))
         seq = self._batch_seq_len(batch)

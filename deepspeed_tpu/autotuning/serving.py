@@ -36,9 +36,10 @@ mechanisms the serving loop actually has:
   saturation;
 - the queue-wait term is the M/M/1-shaped ``rho / (1 - rho)`` over the
   candidate's effective service rate, capped by the admission bound
-  (requests past it shed — fast-fail, not silent wait), which is the
-  BENCH_r06 11.2 s queue_wait failure mode this planner exists to
-  close.
+  (requests past it shed — fast-fail, not silent wait): unbounded
+  admission at three times capacity put 11.2 s of queue wait in an
+  open-loop CPU run before the chip, the failure mode this planner
+  exists to close.
 """
 
 from __future__ import annotations

@@ -45,8 +45,7 @@ PyTree = Any
 # serving_metrics() schema: raw counters kept in serving_stats (reset
 # zeroes exactly these); the prefix-cache counters ride alongside via
 # ragged.PREFIX_STAT_KEYS, and derived ratio/occupancy gauges are
-# appended at read time. telemetry.bridges and bench.py consume the
-# same names. The spec_* counters (ISSUE 9) stay zero with speculative
+# appended at read time. telemetry.bridges consumes the same names. The spec_* counters (ISSUE 9) stay zero with speculative
 # decoding off: spec_proposed_tokens/spec_accepted_tokens are the
 # acceptance-rate numerator/denominator, spec_hit_slots counts
 # (row, tick) slots where the prompt-lookup drafter fired at all.

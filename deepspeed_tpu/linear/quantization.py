@@ -126,8 +126,8 @@ def _q_leaf(w, scale_dtype):
 def quantizable_leaf(shape, ndim: int, path: tuple,
                      min_size: int = 1 << 16) -> bool:
     """THE eligibility predicate for weight-only int8 leaves (shared by
-    quantize_dense_params and device-side generators like bench.py's
-    7B builder): layer-stacked matrices (ndim>=3 — per-layer [L, d]
+    quantize_dense_params and device-side weight generators):
+    layer-stacked matrices (ndim>=3 — per-layer [L, d]
     norm/bias VECTORS must never be scaled over the layer axis) or
     top-level 2-D matrices (lm_head), matrix-like trailing dims, and
     big enough to be worth scales."""

@@ -139,7 +139,7 @@ class ModelConfig:
 
     def flops_per_token(self, seq_len: int, causal: bool = True) -> float:
         """Training FLOPs/token (fwd+bwd ~= 6*N_active + attention
-        term), the standard MFU accounting (BASELINE.md §9). For MoE
+        term), the standard MFU accounting. For MoE
         models N is :meth:`num_active_params` — top-k experts per
         token, not the full expert bank.
 

@@ -1,4 +1,4 @@
-"""GPT-2 family (BASELINE.md config 1: GPT-2 125M ZeRO-1)."""
+"""GPT-2 family."""
 
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Llama-2 family (BASELINE.md configs 2/3: 7B ZeRO-2, 70B ZeRO-3)."""
+"""Llama-2 family."""
 
 from __future__ import annotations
 

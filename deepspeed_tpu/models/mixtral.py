@@ -1,4 +1,4 @@
-"""Mixtral family: MoE decoder (BASELINE.md config 5: Mixtral-8x7B EP+ZeRO-3).
+"""Mixtral family: MoE decoder.
 
 A DecoderLM whose FFN is a top-k routed mixture of experts. Expert weights
 are stacked ``[L, E, ...]``: the ``ep`` mesh axis shards E (expert

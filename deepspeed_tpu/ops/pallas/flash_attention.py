@@ -51,9 +51,11 @@ NEG_INF = -1e30
 # the working set scales with s*d: at 32k x 128 that is ~8M bf16 per
 # operand + a 16M f32 dq slab — ~45M total against the raised
 # _COMPILER_PARAMS ceiling (v5e/v5p have 128M). The dispatch gates on
-# s*d (64k at d=64, 32k at d=128, 16k at d=256). Measured at seq 32768
-# x d128 on v5e: 1.38x the stock two-pass kernel's training throughput
-# (bench.py longctx section).
+# s*d (64k at d=64, 32k at d=128, 16k at d=256). The reason for the
+# resident form is one pass over k/v where the stock kernel makes two;
+# a run before this round's records read 1.38x the stock kernel's
+# training throughput at seq 32768 x d128 (not in the ledger: no cell
+# runs that length).
 _RESIDENT_MAX_ELEMS = 32768 * 128
 
 

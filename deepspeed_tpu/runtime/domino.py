@@ -20,8 +20,9 @@ sit between the chunk GEMM fusions in the instruction schedule, but the
 textual TPU HLO exposes no async all-reduce-start/done pairs even with
 the --xla_tpu_enable_async_collective_fusion flag family: whether those
 reduces overlap compute is the TPU runtime's scheduling decision and
-cannot be asserted at the HLO level. Chunking itself is measured free
-(bench.py domino_overlap_ratio ~=1), so enabling Domino never hurts —
+cannot be asserted at the HLO level. Chunking itself cost nothing in a
+CPU run before the chip (chunked over unchunked step time ~=1; not
+measured on the chip), so enabling Domino should never hurt —
 but its overlap benefit should be attributed to XLA, not this module.
 
 ``DominoTransformerLayer`` here is a functional layer usable standalone

@@ -22,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import types
 from typing import Any, Callable, Iterable, Optional
 
 import jax
@@ -354,6 +355,7 @@ class DeepSpeedEngine:
                     return self._loss_fn(_tr(p, step), batch)
 
                 self._loss_fn_dev_step = _loss_on_device_step
+            self._step = self._step_parts()
             if self._nvme_offload:
                 from .offload import NVMeOffloadOptimizer
                 self._offload_opt = NVMeOffloadOptimizer(self)
@@ -366,7 +368,6 @@ class DeepSpeedEngine:
             self._micro_grads_jit = None
             self._accum_add_jit = None
             self._apply_grads_jit = None
-            self._grad_stats_jit = None
             self._accum_grads = None
             self._micro_count = 0
             # deferred dp-reduction state for the eager triple (no_sync)
@@ -676,12 +677,19 @@ class DeepSpeedEngine:
             hierarchical=hier,
             rounding=zcfg.zero_quantized_rounding)
 
-    def _build_train_step(self):
-        ga = self._scan_ga or self.gradient_accumulation_steps_
+    def _step_parts(self):
+        """The training step, defined once: ``micro_loss``, ``accumulate``,
+        ``finish``, ``update`` and ``next_loss_scale``, pure functions
+        closed over the engine's plan. The compiled step is ``update . finish .
+        accumulate`` (:meth:`_build_train_step`); the NVMe tier's program
+        is ``finish . accumulate`` plus the loss scale, its optimizer
+        runs on the host (:meth:`_build_grads_step`); the eager triple
+        runs ``accumulate`` on one micro-batch per ``backward()`` and
+        ``update . finish`` in ``step()``. The shardings are read at
+        trace time: the pinned_host fallback replaces them."""
         clip = self.config.gradient_clipping
         fp16 = self.fp16_enabled
         fp16_cfg = self.config.fp16
-        dynamic = fp16 and fp16_cfg.loss_scale == 0
         mesh = self.mesh
         grad_specs = self.plan.grad_specs
         param_specs = self.plan.param_specs
@@ -689,7 +697,6 @@ class DeepSpeedEngine:
         tx = self.tx
         mixed = self._mixed
         compute_dtype = self.compute_dtype
-        shardings = self.state_shardings
         fetch = fetch_to_device
         compress = (self.compressor.transform
                     if self.compressor is not None else None)
@@ -714,34 +721,39 @@ class DeepSpeedEngine:
 
         grad_fn = self._make_grad_fn(micro_loss)
 
-        def train_step(state, batch):
-            params = fetch(state["params"], shardings["params"])
-            scale = state["loss_scale"].scale
+        def accumulate(params, batch, scale, step, ga):
+            """f32 gradients of ``ga`` micro-batches, summed, on
+            ``grad_specs``, and the micro-batches' losses."""
+            params = fetch(params, self.state_shardings["params"])
 
             def one_micro(micro):
-                (_, loss), grads = grad_fn(params, micro, scale,
-                                           state["step"])
+                (_, loss), grads = grad_fn(params, micro, scale, step)
                 grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
                 return constrain(grads, mesh, grad_specs), loss
 
             if ga == 1:
                 # no accumulation: skip the zeros-init + add pass
                 grads, loss = one_micro(batch)
-                losses = loss[None]
-            else:
-                def body(acc, micro):
-                    grads, loss = one_micro(micro)
-                    return jax.tree.map(jnp.add, acc, grads), loss
+                return grads, loss[None]
 
-                micro_batches = jax.tree.map(
-                    lambda x: x.reshape(ga, x.shape[0] // ga, *x.shape[1:]),
-                    batch)
-                zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                zeros = constrain(zeros, mesh, grad_specs)
-                grads, losses = jax.lax.scan(body, zeros, micro_batches)
-            # everything after the gradient is one device scope
-            # (telemetry/scopes.py): HLO metadata, no run-time cost
+            def body(acc, micro):
+                grads, loss = one_micro(micro)
+                return jax.tree.map(jnp.add, acc, grads), loss
+
+            micro_batches = jax.tree.map(
+                lambda x: x.reshape(ga, x.shape[0] // ga, *x.shape[1:]),
+                batch)
+            zeros = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            zeros = constrain(zeros, mesh, grad_specs)
+            return jax.lax.scan(body, zeros, micro_batches)
+
+        # everything after the gradient is one device scope
+        # (telemetry/scopes.py): HLO metadata, no run-time cost. finish
+        # opens it and update opens it again, so it names the same
+        # operations whichever program holds them
+        def finish(grads, scale, ga):
+            """Unscaled gradients, the overflow bit, the global norm."""
             with jax.named_scope("ds.optimizer"):
                 # unscale + average over GAS (reference scales loss by
                 # 1/GAS before backward, engine.py:2024)
@@ -749,23 +761,43 @@ class DeepSpeedEngine:
                 grads = jax.tree.map(lambda g: g * inv, grads)
 
                 # overflow check (loss_scaler.grads_finite: the shared
-                # fused reduction; numsan's per-leaf stats extend it below)
+                # fused reduction; numsan's per-leaf stats extend it in
+                # update)
                 finite = jnp.array(True)
                 if fp16:
                     finite = grads_finite(grads)
 
-                # global grad norm + clip (reference: runtime/utils.py
+                # global grad norm (reference: runtime/utils.py
                 # clip_grad_norm_)
                 with jax.named_scope("ds.grad_clip"):
                     sq = sum(jnp.sum(jnp.square(g))
                              for g in jax.tree.leaves(grads))
                     grad_norm = jnp.sqrt(sq)
-                    if clip > 0:
+            return grads, finite, grad_norm
+
+        def next_loss_scale(ls, finite):
+            if not fp16:
+                return ls
+            return update_loss_scale(
+                ls, ~finite, dynamic=fp16_cfg.loss_scale == 0,
+                scale_window=fp16_cfg.loss_scale_window,
+                min_scale=fp16_cfg.min_loss_scale,
+                hysteresis=fp16_cfg.hysteresis)
+
+        def update(state, grads, finite, grad_norm, losses=None):
+            """Clip, apply the optimizer unless the step overflowed, and
+            advance the loss scale and the step counter: ``(new state,
+            metrics)``, with the mean loss where ``losses`` are given."""
+            shardings = self.state_shardings
+            with jax.named_scope("ds.optimizer"):
+                if clip > 0:
+                    with jax.named_scope("ds.grad_clip"):
                         coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
                         grads = jax.tree.map(lambda g: g * coef, grads)
 
                 master = (fetch(state["master"], shardings["master"])
-                          if mixed else params)
+                          if mixed
+                          else fetch(state["params"], shardings["params"]))
                 opt_state = fetch(state["opt_state"],
                                   shardings["opt_state"])
                 updates, new_opt = tx.update(grads, opt_state, master)
@@ -781,14 +813,7 @@ class DeepSpeedEngine:
                     lambda m: m.astype(compute_dtype), new_master)
                 new_params = constrain(new_params, mesh, param_specs)
 
-            ls = state["loss_scale"]
-            if fp16:
-                ls = update_loss_scale(
-                    ls, ~finite, dynamic=dynamic,
-                    scale_window=fp16_cfg.loss_scale_window,
-                    min_scale=fp16_cfg.min_loss_scale,
-                    hysteresis=fp16_cfg.hysteresis)
-
+            ls = next_loss_scale(state["loss_scale"], finite)
             step = state["step"] + jnp.where(finite, 1, 0).astype(jnp.int32)
             new_state = {
                 "step": step,
@@ -797,12 +822,9 @@ class DeepSpeedEngine:
                 "opt_state": new_opt,
                 "loss_scale": ls,
             }
-            metrics = {
-                "loss": jnp.mean(losses),
-                "grad_norm": grad_norm,
-                "loss_scale": ls.scale,
-                "overflow": ~finite,
-            }
+            metrics = {} if losses is None else {"loss": jnp.mean(losses)}
+            metrics.update(grad_norm=grad_norm, loss_scale=ls.scale,
+                           overflow=~finite)
             if numsan_stats:
                 gl = jax.tree.leaves(grads)
                 metrics["numsan_nonfinite"] = jnp.stack(
@@ -813,98 +835,79 @@ class DeepSpeedEngine:
                      for g in gl])
             return new_state, metrics
 
+        return types.SimpleNamespace(
+            micro_loss=micro_loss, accumulate=accumulate, finish=finish,
+            update=update, next_loss_scale=next_loss_scale)
+
+    def _build_train_step(self):
+        ga = self._scan_ga or self.gradient_accumulation_steps_
+        parts = self._step
+
+        def train_step(state, batch):
+            scale = state["loss_scale"].scale
+            grads, losses = parts.accumulate(state["params"], batch, scale,
+                                             state["step"], ga)
+            return parts.update(state, *parts.finish(grads, scale, ga),
+                                losses)
+
         return jax.jit(train_step, donate_argnums=(0,),
                        in_shardings=(self.state_shardings, None),
                        out_shardings=(self.state_shardings, None))
 
-    def _build_grads_step(self):
+    def _build_grads_step(self, eager=False):
         """Compiled half of the NVMe-offload step: grads + norm + overflow
-        on device; the optimizer math runs on host (runtime/offload.py)."""
+        + the next loss scale on device; the optimizer math runs on host
+        (runtime/offload.py, :meth:`_offload_apply`). ``eager``: the form
+        ``step()`` runs, on the gradients ``backward()`` accumulated in
+        place of a batch."""
         ga = self.gradient_accumulation_steps_
-        fp16 = self.fp16_enabled
-        fp16_cfg = self.config.fp16
-        dynamic = fp16 and fp16_cfg.loss_scale == 0
-        mesh = self.mesh
-        grad_specs = self.plan.grad_specs
-        loss_fn = self._loss_fn
+        parts = self._step
 
-        compress = (self.compressor.transform
-                    if self.compressor is not None else None)
-
-        def micro_loss(params, batch, scale, step):
-            if compress is not None:
-                params = compress(params, step)
-            with moe_step(step):
-                loss = loss_fn(params, batch)
-            return loss * scale.astype(loss.dtype), loss
-
-        grad_fn = self._make_grad_fn(micro_loss)
+        def finish_step(state, grads, losses=None):
+            scale = state["loss_scale"].scale
+            grads, finite, grad_norm = parts.finish(grads, scale, ga)
+            ls = parts.next_loss_scale(state["loss_scale"], finite)
+            metrics = {} if losses is None else {"loss": jnp.mean(losses)}
+            metrics.update(grad_norm=grad_norm, loss_scale=ls.scale,
+                           overflow=~finite)
+            return grads, ls, metrics
 
         def grads_step(state, batch):
-            params = state["params"]
-            scale = state["loss_scale"].scale
-
-            def body(acc, micro):
-                (_, loss), grads = grad_fn(params, micro, scale,
-                                           state["step"])
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                grads = constrain(grads, mesh, grad_specs)
-                return jax.tree.map(jnp.add, acc, grads), loss
-
-            micro_batches = jax.tree.map(
-                lambda x: x.reshape(ga, x.shape[0] // ga, *x.shape[1:]),
-                batch)
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            zeros = constrain(zeros, mesh, grad_specs)
-            grads, losses = jax.lax.scan(body, zeros, micro_batches)
-            inv = 1.0 / (scale * ga)
-            grads = jax.tree.map(lambda g: g * inv, grads)
-
-            finite = jnp.array(True)
-            if fp16:
-                finite = grads_finite(grads)
-            sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads))
-            grad_norm = jnp.sqrt(sq)
-
-            ls = state["loss_scale"]
-            if fp16:
-                ls = update_loss_scale(
-                    ls, ~finite, dynamic=dynamic,
-                    scale_window=fp16_cfg.loss_scale_window,
-                    min_scale=fp16_cfg.min_loss_scale,
-                    hysteresis=fp16_cfg.hysteresis)
-            metrics = {"loss": jnp.mean(losses), "grad_norm": grad_norm,
-                       "loss_scale": ls.scale, "overflow": ~finite}
-            return grads, ls, metrics
+            return finish_step(state, *parts.accumulate(
+                state["params"], batch, state["loss_scale"].scale,
+                state["step"], ga))
 
         # state is deliberately NOT donated: params/loss_scale must
         # outlive the call (the host-side NVMe optimizer reads them
         # after grads come back)
         # graftlint: disable=GL020
-        return jax.jit(grads_step,
-                       out_shardings=(named_shardings(mesh, grad_specs),
-                                      None, None))
+        return jax.jit(finish_step if eager else grads_step,
+                       donate_argnums=(1,) if eager else (),
+                       out_shardings=(self.grad_shardings, None, None))
+
+    def _offload_apply(self, grads, ls, metrics):
+        """NVMe tier, host half of the step: unscaled device grads ->
+        clip -> native CPU optimizer over host master shards (moments
+        pipelined through the AIO op) -> params back."""
+        self.state["loss_scale"] = ls
+        if bool(metrics["overflow"]):
+            self.skipped_steps += 1
+            return
+        step_before = int(self.state["step"])
+        lr = float(self.lr_schedule(step_before))
+        clip = self.config.gradient_clipping
+        coef = 1.0
+        if clip > 0:
+            coef = min(1.0, clip / (float(metrics["grad_norm"]) + 1e-6))
+        self._offload_opt.step(grads, lr=lr, grad_scale=coef)
+        self.state["params"] = self._offload_opt.updated_params()
+        self.state["step"] = jax.device_put(
+            np.asarray(step_before + 1, np.int32),
+            self.state_shardings["step"])
 
     def _train_batch_offload(self, batch):
-        """NVMe tier: device grads -> native CPU optimizer over host master
-        shards (moments pipelined through the AIO op) -> params back."""
         grads, ls, metrics = self._train_step(self.state, batch)
-        self.state["loss_scale"] = ls
-        if not bool(metrics["overflow"]):
-            step_before = int(self.state["step"])
-            lr = float(self.lr_schedule(step_before))
-            clip = self.config.gradient_clipping
-            coef = 1.0
-            if clip > 0:
-                coef = min(1.0, clip / (float(metrics["grad_norm"]) + 1e-6))
-            self._offload_opt.step(grads, lr=lr, grad_scale=coef)
-            self.state["params"] = self._offload_opt.updated_params()
-            self.state["step"] = jax.device_put(
-                np.asarray(step_before + 1, np.int32),
-                self.state_shardings["step"])
-        else:
-            self.skipped_steps += 1
+        self._offload_apply(grads, ls, metrics)
         return metrics
 
     def _apply_curriculum(self, batch):
@@ -1286,19 +1289,8 @@ class DeepSpeedEngine:
         if self._defer_grads_ok():
             if self._local_grads_jit is None:
                 from .zeropp import local_value_and_grad
-                compress = (self.compressor.transform
-                            if self.compressor is not None else None)
-                loss_fn = self._loss_fn
-
-                def micro_loss(p, batch, scale, step):
-                    if compress is not None:
-                        p = compress(p, step)
-                    with moe_step(step):
-                        l = loss_fn(p, batch)
-                    return l * scale.astype(l.dtype), l
-
                 fn = local_value_and_grad(
-                    micro_loss, self.mesh, self.plan.param_specs,
+                    self._step.micro_loss, self.mesh, self.plan.param_specs,
                     self.topology.batch_axes())
                 if fn is None:          # single replica: nothing to defer
                     self._local_grads_jit = False
@@ -1322,18 +1314,12 @@ class DeepSpeedEngine:
                 self._micro_count += 1
                 return
         if self._micro_grads_jit is None:
-            def micro(params, batch, scale, step):
-                params = self._params_to_device(params)
-
-                def f(p):
-                    if self.compressor is not None:
-                        p = self.compressor.transform(p, step)
-                    return self._loss_fn(p, batch) * scale
-                g = jax.grad(f)(params)
-                g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
-                return constrain(g, self.mesh, self.plan.grad_specs)
+            # the compiled step's gradient half on one micro-batch
+            accumulate = self._step.accumulate
             self._micro_grads_jit = jax.jit(
-                micro, out_shardings=self.grad_shardings)
+                lambda params, batch, scale, step: accumulate(
+                    params, batch, scale, step, 1)[0],
+                out_shardings=self.grad_shardings)
         g = self._micro_grads_jit(self.state["params"], self._pending_batch,
                                   self.state["loss_scale"].scale,
                                   self.state["step"])
@@ -1399,114 +1385,39 @@ class DeepSpeedEngine:
             with (tel.span("grad_reduce")
                   if tel is not None else _NULLCM):
                 self._accum_grads = self._finish_deferred_grads()
-        if self._offload_opt is not None:
-            import math
-            scale = float(self.state["loss_scale"].scale)
-            inv = 1.0 / (scale * self.gradient_accumulation_steps_)
-            # one fused device reduction + one host pull for overflow
-            # check AND grad norm (was a per-leaf bool()/float() sync
-            # loop — graftlint GL004: each leaf cost a blocking round
-            # trip before the host optimizer could even start)
-            if self._grad_stats_jit is None:
-                def _grad_stats(grads):
-                    leaves = jax.tree.leaves(grads)
-                    finite = functools.reduce(
-                        jnp.logical_and,
-                        [jnp.isfinite(g).all() for g in leaves])
-                    sq = sum(jnp.sum(jnp.square(g)) for g in leaves)
-                    return finite, sq
-                self._grad_stats_jit = jax.jit(_grad_stats)
-            finite_dev, sq_dev = self._grad_stats_jit(self._accum_grads)
-            finite_np, sq_np = jax.device_get((finite_dev, sq_dev))
-            finite = bool(finite_np) if self.fp16_enabled else True
-            if self.fp16_enabled:
-                fp16_cfg = self.config.fp16
-                self.state["loss_scale"] = update_loss_scale(
-                    self.state["loss_scale"], jnp.asarray(not finite),
-                    dynamic=fp16_cfg.loss_scale == 0,
-                    scale_window=fp16_cfg.loss_scale_window,
-                    min_scale=fp16_cfg.min_loss_scale,
-                    hysteresis=fp16_cfg.hysteresis)
-            if finite:
-                norm = math.sqrt(float(sq_np)) * inv
-                clip = self.config.gradient_clipping
-                coef = min(1.0, clip / (norm + 1e-6)) if clip > 0 else 1.0
-                step_before = int(self.state["step"])
-                lr = float(self.lr_schedule(step_before))
-                self._offload_opt.step(self._accum_grads, lr=lr,
-                                       grad_scale=inv * coef)
-                self.state["params"] = self._offload_opt.updated_params()
-                self.state["step"] = jax.device_put(
-                    np.asarray(step_before + 1, np.int32),
-                    self.state_shardings["step"])
-            else:
-                self.skipped_steps += 1
-            self._accum_grads = None
-            self._micro_count = 0
-            self.global_steps += 1
-            self.global_samples += self.train_batch_size_
-            return
         if self._apply_grads_jit is None:
             self._apply_grads_jit = self._build_apply_grads()
-        self.state, metrics = self._apply_grads_jit(
-            self.state, self._accum_grads)
+        if self._offload_opt is not None:
+            # one device program + one host pull for the overflow bit
+            # AND the grad norm, then the host optimizer
+            grads, ls, metrics = self._apply_grads_jit(
+                self.state, self._accum_grads)
+            self._offload_apply(grads, ls, metrics)
+        else:
+            self.state, metrics = self._apply_grads_jit(
+                self.state, self._accum_grads)
+            if bool(metrics["overflow"]):
+                self.skipped_steps += 1
         self._accum_grads = None
         self._micro_count = 0
         self.global_steps += 1
         self.global_samples += self.train_batch_size_
-        if bool(metrics["overflow"]):
-            self.skipped_steps += 1
         self._last_metrics = metrics
         if self.global_steps % self.config.steps_per_print == 0:
             self._report({"loss": jnp.nan, **metrics})
 
     def _build_apply_grads(self):
+        """The eager ``step()``'s program: the compiled step's
+        ``update . finish`` on the gradients ``backward()`` accumulated
+        (NVMe tier: ``finish``; the update runs on the host)."""
+        if self._offload_opt is not None:
+            return self._build_grads_step(eager=True)
         ga = self.gradient_accumulation_steps_
-        clip = self.config.gradient_clipping
-        fp16 = self.fp16_enabled
-        fp16_cfg = self.config.fp16
-        dynamic = fp16 and fp16_cfg.loss_scale == 0
-        mixed = self._mixed
+        parts = self._step
 
         def apply_grads(state, grads):
-            scale = state["loss_scale"].scale
-            inv = 1.0 / (scale * ga)
-            grads = jax.tree.map(lambda g: g * inv, grads)
-            finite = jnp.array(True)
-            if fp16:
-                finite = grads_finite(grads)
-            sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads))
-            grad_norm = jnp.sqrt(sq)
-            if clip > 0:
-                coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads = jax.tree.map(lambda g: g * coef, grads)
-            master = state["master"] if mixed else state["params"]
-            updates, new_opt = self.tx.update(grads, state["opt_state"], master)
-            new_master = jax.tree.map(jnp.add, master, updates)
-            if fp16:
-                sel = lambda new, old: jax.tree.map(  # noqa: E731
-                    lambda n, o: jnp.where(finite, n, o), new, old)
-                new_master = sel(new_master, master)
-                new_opt = sel(new_opt, state["opt_state"])
-            new_params = jax.tree.map(
-                lambda m: m.astype(self.compute_dtype), new_master)
-            new_params = constrain(new_params, self.mesh, self.plan.param_specs)
-            ls = state["loss_scale"]
-            if fp16:
-                ls = update_loss_scale(
-                    ls, ~finite, dynamic=dynamic,
-                    scale_window=fp16_cfg.loss_scale_window,
-                    min_scale=fp16_cfg.min_loss_scale,
-                    hysteresis=fp16_cfg.hysteresis)
-            new_state = {
-                "step": state["step"] + jnp.where(finite, 1, 0).astype(jnp.int32),
-                "params": new_params,
-                "master": new_master if mixed else None,
-                "opt_state": new_opt,
-                "loss_scale": ls,
-            }
-            return new_state, {"grad_norm": grad_norm, "overflow": ~finite,
-                               "loss_scale": ls.scale}
+            return parts.update(state, *parts.finish(
+                grads, state["loss_scale"].scale, ga))
 
         return jax.jit(apply_grads, donate_argnums=(0, 1),
                        out_shardings=(self.state_shardings, None))

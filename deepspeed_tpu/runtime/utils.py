@@ -3,7 +3,7 @@ of grad-norm/overflow/alignment helpers used across the engine and ZeRO
 optimizers).
 
 Functional ports over pytrees; all usable inside jit. The engine's
-compiled step inlines the same math (engine.py _build_train_step); these
+compiled step inlines the same math (engine.py _step_parts); these
 standalone versions serve user code and the reference API surface."""
 
 from __future__ import annotations

@@ -19,7 +19,8 @@ class ControllerConfig(DeepSpeedConfigModel):
     cannot set per-minute — the admission bound (shed depth), the
     dispatch-chain depth, and the speculative draft length. Policy:
     queue pressure throttles admission first (fast-fail beats silent
-    aging — the BENCH_r06 11.2 s queue_wait failure); sustained ITL
+    aging: 11.2 s of queue wait in an open-loop CPU run before the
+    chip); sustained ITL
     saturation then steps chain depth down, then drafts off (deep
     chains and long drafts win at low load and kill ITL at
     saturation). Recovery relaxes in reverse order and only after
@@ -74,8 +75,8 @@ class ServingConfig(DeepSpeedConfigModel):
     # admission bound (ISSUE 19): a submit() arriving with this many
     # requests already open is SHED — it fails fast with a
     # RequestFailed("... shed ...") instead of aging in the mailbox
-    # (BENCH_r06: unbounded admission put 11.2 s of queue_wait in an
-    # 11.5 s TTFT p99). Shed requests are counted
+    # (a CPU run before the chip: unbounded admission put 11.2 s of
+    # queue_wait in an 11.5 s TTFT p99). Shed requests are counted
     # (ds_serving_shed_total, reqtrace outcome=shed) — never silently
     # dropped. 0 = off (existing behavior, byte-identical); the
     # controller tightens/relaxes the live bound at runtime.

@@ -18,8 +18,8 @@ it makes is therefore single-threaded with ``step()``), reading:
 and adapting three knobs, in a fixed priority order:
 
 1. **admission** — tighten the live shed depth (fast-fail at the
-   queue). This is the BENCH_r06 fix: at 20 rps the uncontrolled
-   open-loop aged requests 11.2 s in the mailbox before first
+   queue). In a CPU run before the chip, at 20 rps the uncontrolled
+   open loop aged requests 11.2 s in the mailbox before first
    dispatch; shedding keeps queue_wait bounded at the cost of counted,
    fast-failed requests (never silent drops).
 2. **chain depth** — step ``max_inflight_dispatches`` down. Deep

@@ -256,9 +256,9 @@ class AsyncInferenceServer:
         shed_at = self._shed_depth
         if shed_at and self._open >= shed_at:
             # admission control (ISSUE 19): past the bound the request
-            # fails FAST instead of aging in the mailbox (BENCH_r06:
-            # unbounded admission buried an 11.5 s TTFT p99 under
-            # 11.2 s of queue_wait). Counted three ways — handle
+            # fails FAST instead of aging in the mailbox (a CPU run
+            # before the chip: unbounded admission buried an 11.5 s
+            # TTFT p99 under 11.2 s of queue_wait). Counted three ways — handle
             # error, ds_serving_shed_total, reqtrace outcome=shed —
             # never silently dropped.
             uid = next(self._uid) if uid is None else int(uid)

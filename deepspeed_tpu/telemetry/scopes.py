@@ -51,8 +51,8 @@ DEVICE_SCOPES = (
     "ds.flash_fwd",    # ops/pallas/flash_attention.py _flash_fwd
     "ds.flash_bwd",    # ops/pallas/flash_attention.py _flash_bwd
     "ds.loss_head",    # models/transformer.py: final norm, head, loss
-    "ds.optimizer",    # runtime/engine.py train_step, after the gradient
-    "ds.grad_clip",    # runtime/engine.py train_step: grad norm and clipping
+    "ds.optimizer",    # runtime/engine.py _step_parts: finish and update
+    "ds.grad_clip",    # the same: the grad norm (finish), the clip (update)
 )
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these

@@ -27,7 +27,7 @@ def _telemetry_window_s(started_unix: float) -> float:
     bandwidth, breaking the lower-bound claim. In that case the caller
     gets 0.0 and the bandwidth columns render ``-`` (call
     ``CommsLogger.reset()`` alongside ``telemetry.clear()`` to re-pair
-    them, as ``bench.py --telemetry`` does between stages)."""
+    them)."""
     mod = active_telemetry()
     if mod is None:
         return 0.0

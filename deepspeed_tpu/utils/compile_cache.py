@@ -1,6 +1,6 @@
 """One place that decides where JAX keeps its persistent compile cache.
 
-Entry-point scripts (``chip_smoke.py``, ``bench.py``, ``tools/``,
+Entry-point scripts (``chip_smoke.py``, ``benchmark/run.py``, ``tools/``,
 ``examples/``) call :func:`enable_compile_cache` before their first jit.
 Library code never does: a library that picks a cache directory for its
 caller cannot be overruled by the environment.

@@ -7,9 +7,10 @@ config below is exactly the reference's `offload_param`/`offload_optimizer`
 JSON — the streamed engine is selected automatically on a single chip.
 
 Throughput is PCIe-bound by design (the whole optimizer state crosses
-the host link every step); this is the capability tier — see bench.py's
-`llama7b` section for measured numbers, and `save_16bit_model` for the
-bridge onto a sharded multi-chip run once a pod is available.
+the host link every step); this is the capability tier, not measured
+on the chip by the benchmark (`ROADMAP.md` queue 1 item 9) — see
+`save_16bit_model` for the bridge onto a sharded multi-chip run once a
+pod is available.
 
 Two knobs worth knowing:
 - ``--ga N`` gradient accumulation: the master+moments stream is paid

@@ -82,14 +82,16 @@ _SLOW = {
     ("test_zeropp.py", "test_engine_hierarchical_quantized_parity"),
     ("test_zeropp.py", "test_hierarchical_qgz_sum_matches_psum_scatter"),
     ("test_comm.py", "test_all_to_all_quant_reduce_odd_sizes"),
-    # nvme offload tier (AIO file I/O heavy); cpu-tier offload stays
+    # nvme offload tier (AIO file I/O heavy); cpu-tier offload stays,
+    # and test_nvme_offload_matches_baseline, which holds the NVMe
+    # tier's two drivers to the compiled step (ISSUE 29)
     ("test_offload.py", "test_nvme_offload_checkpoint_roundtrip"),
-    ("test_offload.py", "test_nvme_offload_matches_baseline"),
     ("test_offload.py", "test_nvme_offload_universal_conversion"),
     ("test_offload.py", "test_nvme_offload_with_pipeline"),
     ("test_engine.py", "test_checkpoint_roundtrip"),
+    # test_forward_backward_step_compat stays tier-1: it holds the
+    # drivers of the one step definition to one another (ISSUE 29)
     ("test_engine.py", "test_no_sync_triple_matches_train_batch"),
-    ("test_engine.py", "test_forward_backward_step_compat"),
     ("test_checkpoint.py", "test_universal_checkpoint_roundtrip"),
     ("test_checkpoint.py", "test_async_checkpoint_engine"),
     ("test_checkpoint.py",

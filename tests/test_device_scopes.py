@@ -163,6 +163,34 @@ def test_scope_list_equals_the_scopes_found(step_hlo):
     assert set(scopes.TOP_SCOPES) <= set(scopes.DEVICE_SCOPES)
 
 
+def test_the_eager_apply_program_carries_the_optimizer_scopes():
+    """``step()`` of the forward/backward/step triple runs the compiled
+    step's ``update . finish`` (engine._step_parts), so the same scopes
+    name the same operations there: everything traced from the unscale
+    to the new parameters is under ds.optimizer, the norm and the clip
+    under ds.grad_clip. What is left outside is the next loss scale, the
+    step counter and the metrics: scalars. fp16, so that the overflow
+    bit and the skip are in the program."""
+    engine, _ = _tiny_engine({"bf16": {"enabled": False},
+                              "fp16": {"enabled": True}})
+    grads = jax.tree.map(
+        lambda p, s: jax.ShapeDtypeStruct(p.shape, jnp.float32, sharding=s),
+        engine.state["params"], engine.grad_shardings)
+    apply = engine._build_apply_grads()
+    hlo = apply.lower(engine.state, grads).compile().as_text()
+    got = scopes.op_scopes(hlo)
+    assert set(got.values()) == {"", "ds.optimizer",
+                                 "ds.optimizer/ds.grad_clip"}
+    # instructions the trace made (they carry an op_name; a parameter's
+    # is its argument's name)
+    traced = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* (?!parameter)"
+        r".*op_name=", hlo, re.M)
+    assert len(traced) > 100
+    outside = [(n, dims) for n, dims in traced if not got[n]]
+    assert outside and all(dims == "" for _, dims in outside), outside
+
+
 # ---- kernel names ---------------------------------------------------------
 def _pallas_names(jaxpr, out):
     for eqn in jaxpr.eqns:

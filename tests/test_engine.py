@@ -84,26 +84,132 @@ def test_fp16_loss_scaling_and_overflow(devices8):
     assert float(engine.state["loss_scale"].scale) < s1  # backed off
 
 
-def test_forward_backward_step_compat(devices8):
-    """The micro-batch triple must match train_batch numerics."""
-    cfg = base_config(zero_optimization={"stage": 1})
-    e1, _, _, _ = ds.initialize(model=GPT2(size="tiny"), config=cfg)
-    e2, _, _, _ = ds.initialize(model=GPT2(size="tiny"), config=cfg)
+def eager_batch(engine, batch, micros=2):
+    """One train_batch's worth of data through forward/backward/step;
+    the mean of the micro-batches' losses, which is train_batch's."""
+    n = batch[0].shape[0] // micros
+    losses = []
+    for i in range(micros):
+        micro = jax.tree.map(lambda x: x[i * n:(i + 1) * n], batch)
+        losses.append(engine.forward(micro))
+        engine.backward(losses[-1])
+    assert engine.is_gradient_accumulation_boundary()
+    engine.step()
+    return float(np.mean([float(l) for l in losses]))
 
-    batch = make_batch(jax.random.PRNGKey(0))
-    l1 = e1.train_batch(batch)
 
-    # same data split into 2 micro-batches of 4
-    for i in range(2):
-        micro = jax.tree.map(lambda x: x[i * 8:(i + 1) * 8], batch)
-        loss = e2.forward(micro)
-        e2.backward(loss)
-    assert e2.is_gradient_accumulation_boundary()
-    e2.step()
-    p1 = e1.state["params"]["embed"]["tokens"]
-    p2 = e2.state["params"]["embed"]["tokens"]
-    np.testing.assert_allclose(np.asarray(p1), np.asarray(p2),
-                               rtol=2e-5, atol=2e-5)
+def _weights(engine):
+    """The float32 weights the optimizer updates, as one host vector."""
+    tree = (engine.state["master"] if engine.state["master"] is not None
+            else engine.state["params"])
+    return np.concatenate([np.asarray(x, np.float32).ravel()
+                           for x in jax.tree.leaves(tree)])
+
+
+def assert_same_training(e1, e2, start, tol):
+    """Both engines took the same optimizer steps from ``start``: the
+    step counter, the loss scale, and the update itself (relative L2
+    error of the whole weight change; a missing unscale, clip, GAS
+    average or step is an error of order 1)."""
+    assert int(e1.state["step"]) == int(e2.state["step"])
+    assert (float(e1.state["loss_scale"].scale)
+            == float(e2.state["loss_scale"].scale))
+    d1, d2 = _weights(e1) - start, _weights(e2) - start
+    assert np.linalg.norm(d1) > 0
+    assert np.linalg.norm(d1 - d2) <= tol * np.linalg.norm(d1)
+
+
+class Boosted:
+    """GPT-2 tiny whose loss is multiplied by the mean of the batch's
+    third field, so that a batch can overflow fp16 gradients on demand
+    (a loss scale chosen to sit at the threshold would not overflow in
+    the same step under two reduction orders)."""
+
+    def __init__(self):
+        self.model = GPT2(size="tiny")
+        self.config = self.model.config
+        self.init = self.model.init
+        self.partition_rules = self.model.partition_rules
+
+    def loss(self, params, batch):
+        tokens, targets, boost = batch
+        return self.model.loss(params, (tokens, targets)) * jnp.mean(boost)
+
+
+def boosted_batch(k, boost=1.0):
+    tokens, targets = make_batch(jax.random.PRNGKey(k))
+    return tokens, targets, jnp.full((tokens.shape[0],), boost, jnp.float32)
+
+
+PRECISIONS = {
+    "float32": ({}, 1e-5),
+    "bf16": ({"bf16": {"enabled": True}}, 2e-2),
+    "fp16": ({"fp16": {"enabled": True, "initial_scale_power": 8,
+                       "hysteresis": 1, "loss_scale_window": 100}}, 1e-2),
+}
+# SGD: the update is linear in the clipped, unscaled gradient, where
+# Adam's normalisation would hide a wrong scale or clip
+SGD = {"type": "SGD", "params": {"lr": 0.1, "momentum": 0.9}}
+
+
+@pytest.mark.parametrize("precision", list(PRECISIONS))
+@pytest.mark.parametrize("stage", [1, 3])
+def test_forward_backward_step_compat(stage, precision, devices8):
+    """train_batch and the micro-batch triple compose the same three
+    functions (engine._step_parts): after the same micro-batches they
+    leave the same weights, step count and loss scale. Stage 1 takes the
+    deferred-reduction eager path, stage 3 the per-micro one. Under fp16
+    the second of the three batches overflows: both skip it."""
+    over, tol = PRECISIONS[precision]
+    cfg = base_config(zero_optimization={"stage": stage}, optimizer=SGD,
+                      **over)
+    e1, _, _, _ = ds.initialize(model=Boosted(), config=cfg)
+    e2, _, _, _ = ds.initialize(model=Boosted(), config=cfg)
+    start = _weights(e1)
+    fp16 = precision == "fp16"
+    for k in range(3):
+        batch = boosted_batch(k, 1e8 if fp16 and k == 1 else 1.0)
+        e1.train_batch(batch)
+        eager_batch(e2, batch)
+        assert int(e1.state["step"]) == int(e2.state["step"]), k
+    assert_same_training(e1, e2, start, tol)
+    assert int(e1.state["step"]) == (2 if fp16 else 3)
+    assert e2.skipped_steps == e1.overflow_steps == int(fp16)
+    if fp16:
+        assert float(e1.state["loss_scale"].scale) == 2.0 ** 7
+
+
+@pytest.mark.parametrize("stage", [1, 3])
+def test_eager_micro_gradient_binds_the_moe_step(stage, devices8):
+    """backward() traces the loss inside ``moe_step(state step)`` on both
+    eager paths (deferred at stage 1, per-micro at stage 3), as the
+    compiled step does: ``moe/dispatch.py current_step()`` is the state's
+    step there, not the 0 of an unbound trace, so the MoE int8 wire's
+    stochastic rounding gets a new seed each step. Seen through a loss
+    that is multiplied by ``1 + current_step()``."""
+    from deepspeed_tpu.moe.dispatch import current_step
+
+    class StepScaled(Boosted):
+        def loss(self, params, batch):
+            return self.model.loss(params, batch) * (
+                1.0 + current_step().astype(jnp.float32))
+
+    engine, _, _, _ = ds.initialize(
+        model=StepScaled(), config=base_config(
+            zero_optimization={"stage": stage}))
+    micro = jax.tree.map(lambda x: x[:8], make_batch(jax.random.PRNGKey(0)))
+
+    def micro_gradient():
+        engine.optimizer.zero_grad()
+        engine.backward(engine.forward(micro))
+        acc = engine._deferred_acc if stage == 1 else engine._accum_grads
+        return np.asarray(jax.tree.leaves(acc)[0])
+
+    at_step_0 = micro_gradient()
+    engine.state["step"] = engine.state["step"] + 3
+    at_step_3 = micro_gradient()
+    assert np.abs(at_step_0).max() > 0
+    np.testing.assert_allclose(at_step_3, 4.0 * at_step_0, rtol=1e-5)
 
 
 def test_no_sync_triple_matches_train_batch(devices8):

@@ -2,12 +2,13 @@
 tests/unit/runtime/zero/test_zero_offload*.py and swap_tensor tests —
 offloaded runs must track the in-HBM trajectory)."""
 
+import jax
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import GPT2
-from test_engine import base_config, make_batch, run_steps
+from test_engine import base_config, eager_batch, make_batch, run_steps
 
 
 def _engine(zero_over=None, **cfg_over):
@@ -106,19 +107,29 @@ def test_param_offload_cpu(devices8):
     assert losses[-1] < losses[0]
 
 
-def test_nvme_offload_matches_baseline(tmp_path, devices8):
+@pytest.mark.parametrize("driver", ["train_batch", "triple"])
+def test_nvme_offload_matches_baseline(driver, tmp_path, devices8):
     """nvme tier: native CPU-Adam over host master, moments through the
-    AIO op; trajectory must match the compiled AdamW path."""
+    AIO op; driven by train_batch or by forward/backward/step, the
+    trajectory must match the compiled AdamW path."""
     ref = _engine()
     off = _engine({"offload_optimizer": {"device": "nvme",
                                          "nvme_path": str(tmp_path)}})
     assert off.state["master"] is None          # no fp32 master in HBM
     assert off.state["opt_state"] == ()         # no moments in HBM
     l_ref = run_steps(ref, n=3)
-    l_off = run_steps(off, n=3)
+    if driver == "train_batch":
+        l_off = run_steps(off, n=3)
+    else:
+        l_off = [eager_batch(off, make_batch(jax.random.PRNGKey(0)))
+                 for _ in range(3)]
     # different XLA programs round grads differently; Adam amplifies
     # near-eps grads, so trajectories agree only to ~1e-3 in bf16
     np.testing.assert_allclose(l_off, l_ref, rtol=2e-3, atol=2e-3)
+    assert int(off.state["step"]) == int(ref.state["step"]) == 3
+    assert off.global_steps == 3 and off.skipped_steps == 0
+    np.testing.assert_allclose(off.get_global_grad_norm(),
+                               ref.get_global_grad_norm(), rtol=2e-2)
     # moments landed on disk (per-engine scratch subdir under nvme_path)
     swaps = list(tmp_path.glob("engine_*/rank0_*_exp_avg.bin"))
     assert swaps, "no moment files written to nvme_path"

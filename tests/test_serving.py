@@ -8,8 +8,6 @@ import asyncio
 import importlib.util
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -292,23 +290,3 @@ def test_serving_regression_gate(tmp_path):
                                "preempt_stall_p99_ms": 5.5}))
     assert tr.main(["--diff", str(pca), str(cok),
                     "--gate", "serving"]) == 0
-
-
-def test_bench_default_invocation_always_exits_zero(devices8):
-    """ISSUE 6 satellite (BENCH_r05 rc=124 / parsed:null): `python
-    bench.py` with NO arguments must apply the global --total-budget-s
-    default, skip whatever the budget cannot cover, print exactly one
-    parseable JSON line on stdout and exit 0."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["DS_BENCH_TOTAL_BUDGET_S"] = "1"    # expire instantly: every
-    env["JAX_PLATFORMS"] = "cpu"            # stage skips, JSON still out
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo,
-                          env=env, capture_output=True, text=True,
-                          timeout=240)
-    assert proc.returncode == 0, (proc.returncode, proc.stderr[-800:])
-    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, lines
-    rec = json.loads(lines[-1])
-    assert "metric" in rec and "value" in rec
-    assert "skipped" in rec or "interrupted" in rec

@@ -1,5 +1,5 @@
 """SLO-driven serving control plane (ISSUE 19): fake-clock feedback-
-controller state machine, admission shedding (the BENCH_r06 fix),
+controller state machine, admission shedding,
 offline serving planner determinism/crossovers/roundtrip, the new
 serving gate rows, and the controller-armed load-step end-to-end."""
 

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Render an autotuning plan artifact (ISSUE 7) as a ranked table.
 
-The plan JSON comes from ``Plan.save()`` — ``bench.py`` writes one at
-``artifacts/autotune_plan.json`` during the ``autotune`` stage, and
-``Planner.plan()`` callers can write their own. Shows every ranked
+The plan JSON comes from ``Plan.save()`` (``artifacts/autotune_plan.json``
+is one; ``Planner.plan()`` callers write their own). Shows every ranked
 candidate with its predicted (and, for the measured top-K, observed)
 step time, the compiler-reported AOT peak HBM next to the memory
 model's prediction, per-axis collective payload, and the chosen

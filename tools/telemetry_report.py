@@ -443,11 +443,10 @@ _GATES = {
         # chain-boundary gap, preemption stall); decode_active scales
         # with tokens generated, so gating it would flag longer
         # outputs as regressions.
-        # serving control plane (ISSUE 19, bench serve_openloop
-        # load-step phase + serve_autotune stage): goodput under the
+        # serving control plane (ISSUE 19): goodput under the
         # declared SLOs with the shed/controller armed must not
         # shrink, the controlled queue-wait p99 must not creep back up
-        # (the BENCH_r06 failure), and the offline plan must keep
+        # (unbounded admission's failure), and the offline plan must keep
         # beating the hand-tuned baseline it was ranked against. The
         # deliberately-saturated control arms (uncontrolled_*,
         # baseline_/plan_ ttft/itl points) are excluded below.
