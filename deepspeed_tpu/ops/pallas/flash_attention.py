@@ -23,6 +23,28 @@ Design notes (why this beats the stock two-pass kernel at model shapes):
 - **bf16 MXU operands** with f32 accumulation (`preferred_element_type`);
   p/ds are cast back to the input dtype before their dots (upcasting
   operands to f32 would halve the MXU rate).
+- **Tile classes**: with bq == bk a score tile's class depends only on
+  its block distance d = q block - kv block (`tile_bands`): *skipped*
+  (no live pair: above the causal diagonal, or wholly past the window's
+  far edge; the sweep's trip count leaves it out), *masked* (cut by the
+  diagonal, d == 0, or by the window's far edge) and *unmasked* (every
+  pair live). Each sweep is two or three `fori_loop` ranges over one
+  body parametrised at trace time, so an unmasked tile carries no mask
+  code at all (no iota, compare or select). At seq 8192, block 512,
+  window 4096 a head runs 108 tiles: 24 masked, 84 unmasked, 28 skipped
+  under the diagonal (gauge `ds_flash_tiles`). `where(True, s, NEG_INF)`
+  is `s`, so the result is bit-equal to masking every tile. On a v5e the
+  mask is a small part of a tile (4% of the forward, 1% of the backward:
+  the vector unit has slots to spare); what a tile waits for is the
+  serial chain matmul, row statistics, exp, matmul, and its relayouts.
+- **No lane-by-lane relayouts**: the row statistics live as columns
+  ([bq, 1]) in the forward and are stored lane-dense ([1, S]); the
+  forward turns them once a q block through the transpose unit, and the
+  backward holds its tile transposed ([bk, bq]) so that lse and delta
+  broadcast as the rows they are, p and ds are already the left operands
+  of the dv and dk matmuls, and one in-loop transpose (for dq) is left
+  of two. `jnp.dot(a, b.T)` needs no rewriting: Mosaic lowers it to a
+  matmul that latches b transposed.
 
 Layout: wrapper takes [B, S, H, D] (model convention), kernels run on
 [B*H, S, D]. The log-sum-exp is carried as [BH, 1, S] so every block
@@ -46,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128          # a vector register's minor dimension
 
 # k/v (fwd) and q/do/dq (bwd) are VMEM-resident per (batch*head) row, so
 # the working set scales with s*d: at 32k x 128 that is ~8M bf16 per
@@ -81,6 +104,90 @@ def _block(s: int) -> int:
     return s
 
 
+# ------------------------------------------------------------ tile classes
+TILE_KINDS = ("masked", "unmasked", "skipped")
+
+
+def tile_bands(s: int, b: int, window: int | None):
+    """``(edge, dead)``: the class of a [b, b] score tile from its block
+    distance ``d = q block - kv block`` (its pairs' ``row - col`` span
+    ``d*b - (b-1) .. d*b + (b-1)``). Skipped (no live pair) iff
+    ``causal and d < 0`` or ``d >= dead``; masked (cut) iff ``causal and
+    d == 0`` (the diagonal) or ``edge <= d < dead`` (the window's far
+    edge: some ``row - col >= window``); every other tile is wholly live.
+    Without a window both are ``s // b``, past every tile."""
+    if window is None:
+        return s // b, s // b
+    return window // b, (window - 2) // b + 2
+
+
+def tile_kind(d: int, edge: int, dead: int, causal: bool) -> str:
+    if (causal and d < 0) or d >= dead:
+        return "skipped"
+    if (causal and d == 0) or d >= edge:
+        return "masked"
+    return "unmasked"
+
+
+def tile_counts(s: int, b: int, window: int | None, causal: bool) -> dict:
+    """Tiles of each kind that one (batch x head) row's sweep meets; the
+    triangle above a causal diagonal was never swept and is not counted,
+    so ``skipped`` is what the window saves."""
+    n = s // b
+    edge, dead = tile_bands(s, b, window)
+    out = dict.fromkeys(TILE_KINDS, 0)
+    for d in range(0 if causal else 1 - n, n):
+        out[tile_kind(d, edge, dead, causal)] += n - abs(d)
+    return out
+
+
+def _fwd_ranges(i, edge: int, dead: int):
+    """kv sweep of q block ``i`` (causal): ``[lo, a)`` is cut by the
+    window's far edge, ``[a, i)`` is wholly live, tile ``i`` is on the
+    diagonal."""
+    lo = jnp.maximum(0, i - dead + 1)
+    return lo, jnp.clip(i - edge + 1, lo, i)
+
+
+def _bwd_ranges(j, n: int, edge: int, dead: int):
+    """q sweep of kv block ``j`` (causal): tile ``j`` is on the diagonal,
+    ``(j, c)`` is wholly live, ``[c, hi)`` is cut by the window's far
+    edge."""
+    hi = jnp.minimum(n, j + dead)
+    return jnp.clip(j + edge, j + 1, hi), hi
+
+
+def _cut(s, off, window, q_axis: int):
+    """Scores of a tile the mask cuts. Queries run along ``q_axis`` of the
+    tile, keys along the other; ``off`` is the first query's position less
+    the first key's, so ``rel`` is each pair's ``query - key``, live in
+    ``[0, window)``. The iotas are left to the compiler: the vector unit
+    has slots to spare in these loops and vector stores have none, so a
+    position array built once per invocation and kept in VMEM measured
+    slower than building it a tile."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)) + off
+    live = rel >= 0
+    if window is not None:
+        live &= rel < window
+    return jnp.where(live, s, NEG_INF)
+
+
+def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool):
+    """Trace time, host only: how often the maskless body engages is a
+    function of shapes, so it is counted where the kernel is built."""
+    from ...utils.telemetry_probe import active_telemetry
+    tel = active_telemetry()
+    reg = tel.get_registry() if tel is not None else None
+    if reg is None:
+        return
+    g = reg.gauge("ds_flash_tiles",
+                  "score tiles a (batch x head) row of the flash kernel "
+                  "last built runs masked / unmasked / skips by the window")
+    for kind, n in tile_counts(s, b, window, causal).items():
+        g.set(n, kernel=kernel, kind=kind)
+
+
 # ---------------------------------------------------------------- forward
 def _flash_fwd(q, k, v, *, causal: bool, sc: float,
                window: int | None = None, rep: int = 1):
@@ -90,6 +197,7 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
     bh, s, d = q.shape
     bq = bk = _block(s)
     grid = (bh, s // bq)
+    _gauge_tiles("fwd", s, bq, window, causal)
     kernel = functools.partial(_fwd_kernel, sc=sc, bq=bq, bk=bk,
                                nk=s // bk, causal=causal, window=window)
     call = pl.pallas_call(
@@ -128,24 +236,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
                 causal, window):
     """Online-softmax forward: q block vs the VMEM-resident k/v row.
     ``window`` (Mistral SWA): query r sees keys in (r - window, r] — the
-    kv sweep starts at the window's first live block and the in-block
-    mask drops the tail."""
+    kv sweep starts at the window's first live block, and only the tiles
+    the diagonal or the window's far edge cuts carry the mask."""
     i = pl.program_id(1)
     q = q_ref[0]
     d = q.shape[-1]
 
-    def body(j, carry):
+    def body(j, carry, masked):
         o_acc, m, l = carry
         kj = k_ref[0, pl.ds(j * bk, bk), :]
         vj = v_ref[0, pl.ds(j * bk, bk), :]
         s = jnp.dot(q, kj.T, preferred_element_type=jnp.float32) * sc
-        if causal or window is not None:
-            qi = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            ki = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-            live = qi >= ki if causal else (qi == qi)
-            if window is not None:
-                live &= qi - ki < window
-            s = jnp.where(live, s, NEG_INF)
+        if masked:
+            s = _cut(s, (i - j) * bq, window, q_axis=0)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -154,75 +257,94 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
                                        preferred_element_type=jnp.float32)
         return o_acc, m_new, l
 
-    # causal: q block i attends kv blocks [0, i] (bq == bk); a window
-    # additionally floors the sweep at its first live block
-    hi = (i + 1) if causal else nk
-    lo = (jnp.maximum(0, (i * bq - window + 1) // bk)
-          if window is not None else 0)
-    o_acc, m, l = jax.lax.fori_loop(
-        lo, hi, body,
-        (jnp.zeros((bq, d), jnp.float32),
-         jnp.full((bq, 1), NEG_INF, jnp.float32),
-         jnp.zeros((bq, 1), jnp.float32)))
+    cut = functools.partial(body, masked=True)
+    whole = functools.partial(body, masked=False)
+    carry = (jnp.zeros((bq, d), jnp.float32),
+             jnp.full((bq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32))
+    if causal:
+        # q block i attends kv blocks [lo, i] (bq == bk), in that order
+        edge, dead = tile_bands(nk * bk, bk, window)
+        lo, a = _fwd_ranges(i, edge, dead)
+        if edge < nk:           # else the window reaches past every tile
+            carry = jax.lax.fori_loop(lo, a, cut, carry)
+        carry = jax.lax.fori_loop(a, i, whole, carry)
+        carry = cut(i, carry)
+    else:
+        carry = jax.lax.fori_loop(0, nk, whole, carry)
+    o_acc, m, l = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = o_acc / l
-    lse_ref[0, 0, :] = (m + jnp.log(l))[:, 0]
+    # the statistics are a column ([bq, 1], a row a sublane) and the output
+    # is lane-dense ([1, bq]): through the transpose unit, not lane by lane
+    lse = jnp.broadcast_to(m + jnp.log(l), (bq, _LANES))
+    lse_ref[0] = lse.T[:1]
 
 
 # ---------------------------------------------------------------- backward
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, *, sc, bq, bk, nq, causal,
                       window):
-    # dk/dv are emitted PER Q-HEAD (summed over the GQA group outside —
-    # cheap XLA reduce); k/v rows are indexed b // rep by the caller
     """One-pass backward: kv block j vs the VMEM-resident q/do row. dq
     accumulates into the full-[S, D] VMEM-resident output slab (index map
     depends only on the bh grid axis; the sequential grid makes the
     accumulation race-free)."""
+    # dk/dv are emitted PER Q-HEAD (summed over the GQA group outside —
+    # cheap XLA reduce); k/v rows are indexed b // rep by the caller
     j = pl.program_id(1)
     k = k_ref[0]
     v = v_ref[0]
     d = k.shape[-1]
+    nt = (((1,), (1,)), ((), ()))       # a @ b.T: contract the minor dims
 
     @pl.when(j == 0)
     def _():
         dq_ref[:] = jnp.zeros_like(dq_ref)
 
-    def body(i, carry):
+    def body(i, carry, masked):
+        # the tile is held TRANSPOSED ([bk, bq]: keys by queries): lse and
+        # delta broadcast as the lane-dense rows they are stored as, p and
+        # ds are already the left operands dv and dk need, and only dq
+        # contracts over the tile's major dimension (one in-loop transpose
+        # where the [bq, bk] form has two, plus two relayouts of a row
+        # into a column); same products, same order of accumulation
         dk_acc, dv_acc = carry
         rows = (0, pl.ds(i * bq, bq), slice(None))
-        qi_ = q_ref[rows]
+        qi = q_ref[rows]
         doi = do_ref[rows]
-        lse = lse_ref[0, 0, pl.ds(i * bq, bq)][:, None]       # [bq, 1]
-        delta = delta_ref[0, 0, pl.ds(i * bq, bq)][:, None]
-        s = jnp.dot(qi_, k.T, preferred_element_type=jnp.float32) * sc
-        if causal or window is not None:
-            qi = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            ki = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-            live = qi >= ki if causal else (qi == qi)
-            if window is not None:
-                live &= qi - ki < window
-            s = jnp.where(live, s, NEG_INF)
-        p = jnp.exp(s - lse).astype(k.dtype)
-        dv_acc += jnp.dot(p.T, doi, preferred_element_type=jnp.float32)
-        dp = jnp.dot(doi, v.T, preferred_element_type=jnp.float32)
-        ds = (p.astype(jnp.float32) * (dp - delta)).astype(k.dtype)
-        dk_acc += jnp.dot(ds.T, qi_,
-                          preferred_element_type=jnp.float32) * sc
-        dq_ref[rows] += jnp.dot(ds, k,
+        lse = lse_ref[0, :, pl.ds(i * bq, bq)]                # [1, bq]
+        delta = delta_ref[0, :, pl.ds(i * bq, bq)]
+        s = jax.lax.dot_general(k, qi, nt,
                                 preferred_element_type=jnp.float32) * sc
+        if masked:
+            s = _cut(s, (i - j) * bq, window, q_axis=1)
+        p = jnp.exp(s - lse).astype(k.dtype)
+        dv_acc += jnp.dot(p, doi, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, doi, nt,
+                                 preferred_element_type=jnp.float32)
+        ds = (p.astype(jnp.float32) * (dp - delta)).astype(k.dtype)
+        dk_acc += jnp.dot(ds, qi, preferred_element_type=jnp.float32) * sc
+        dq_ref[rows] += jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * sc
         return dk_acc, dv_acc
 
-    # causal: kv block j is attended by q blocks [j, nq) (bq == bk); a
-    # window additionally caps the sweep at its last live block
-    lo = j if causal else 0
-    hi = (jnp.minimum(nq, (j * bk + bk - 1 + window - 1) // bq + 1)
-          if window is not None else nq)
-    dk_acc, dv_acc = jax.lax.fori_loop(
-        lo, hi, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0] = dk_acc
-    dv_ref[0] = dv_acc
+    cut = functools.partial(body, masked=True)
+    whole = functools.partial(body, masked=False)
+    carry = (jnp.zeros((bk, d), jnp.float32),
+             jnp.zeros((bk, d), jnp.float32))
+    if causal:
+        # kv block j is attended by q blocks [j, hi) (bq == bk), in that
+        # order
+        edge, dead = tile_bands(nq * bq, bq, window)
+        c, hi = _bwd_ranges(j, nq, edge, dead)
+        carry = cut(j, carry)
+        carry = jax.lax.fori_loop(j + 1, c, whole, carry)
+        if edge < nq:           # else the window reaches past every tile
+            carry = jax.lax.fori_loop(c, hi, cut, carry)
+    else:
+        carry = jax.lax.fori_loop(0, nq, whole, carry)
+    dk_ref[0], dv_ref[0] = carry
 
 
 def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
@@ -231,6 +353,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
     bq = bk = _block(s)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, s)
+    _gauge_tiles("bwd", s, bq, window, causal)
 
     rowfull = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0),
                            memory_space=pltpu.VMEM)

@@ -1,3 +1,5 @@
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -5,6 +7,9 @@ import pytest
 
 from deepspeed_tpu.ops.layers import dot_product_attention
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+# the package exports the function under the module's name
+fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 
 
 @pytest.mark.parametrize("s,hq,hkv,d", [(128, 4, 4, 32), (256, 4, 2, 64)])
@@ -78,6 +83,133 @@ def test_flash_unaligned_seq_falls_back_exact():
     out = flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def _live_pairs(s, window, causal):
+    """The real mask, pair by pair: [s, s] bool."""
+    rel = np.arange(s)[:, None] - np.arange(s)[None, :]
+    live = rel >= 0 if causal else np.ones((s, s), bool)
+    return live & (rel < window) if window is not None else live
+
+
+@pytest.mark.parametrize("s,b,window,causal", [
+    (640, 128, 256, True), (640, 128, 384, True),      # multiples of b
+    (640, 128, 300, True), (640, 128, 129, True),      # two far-edge tiles
+    (640, 128, 128, True), (640, 128, 127, True), (640, 128, 100, True),
+    (640, 128, 2, True), (640, 128, 1, True),          # no live tile
+    (640, 128, 640, True), (640, 128, 639, True), (640, 128, 5000, True),
+    (640, 128, None, True), (640, 128, None, False),
+    (1024, 512, 700, True), (1024, 256, 512, True), (128, 128, 64, True),
+])
+def test_flash_tile_classes_match_the_mask(s, b, window, causal):
+    """A tile is skipped iff no pair of it is live, unmasked iff all are,
+    masked otherwise; and the ranges the kernels sweep are exactly the
+    tiles that are not skipped, each under the body of its class."""
+    n = s // b
+    edge, dead = fa.tile_bands(s, b, window)
+    live = _live_pairs(s, window, causal).reshape(n, b, n, b)
+    want = {}
+    for i in range(n):
+        for j in range(n):
+            t = live[i, :, j, :]
+            want[i, j] = ("skipped" if not t.any() else
+                          "unmasked" if t.all() else "masked")
+            assert fa.tile_kind(i - j, edge, dead, causal) == want[i, j], \
+                (i, j)
+    counts = fa.tile_counts(s, b, window, causal)
+    swept = [k for (i, j), k in want.items() if not causal or j <= i]
+    assert counts == {k: swept.count(k) for k in fa.TILE_KINDS}
+    if not causal:
+        return          # one unmasked loop over every tile
+    for x in range(n):
+        lo, a = (int(r) for r in fa._fwd_ranges(x, edge, dead))
+        c, hi = (int(r) for r in fa._bwd_ranges(x, n, edge, dead))
+        assert 0 <= lo <= a <= x and x + 1 <= c <= hi <= n
+        fwd = ({j: "masked" for j in range(lo, a)}
+               | {j: "unmasked" for j in range(a, x)} | {x: "masked"})
+        bwd = ({x: "masked"} | {i: "unmasked" for i in range(x + 1, c)}
+               | {i: "masked" for i in range(c, hi)})
+        assert fwd == {j: want[x, j] for j in range(n)
+                       if want[x, j] != "skipped"}
+        assert bwd == {i: want[i, x] for i in range(n)
+                       if want[i, x] != "skipped"}
+        # the loops the kernels leave out at trace time are empty
+        if edge >= n:
+            assert lo == a and c == hi
+
+
+@pytest.mark.parametrize("window,hq,hkv,dtype", [
+    (300, 4, 4, jnp.float32), (300, 4, 1, jnp.float32),
+    (256, 4, 4, jnp.float32), (256, 4, 1, jnp.float32),
+    (100, 4, 4, jnp.float32), (100, 4, 1, jnp.float32),
+    (640, 4, 4, jnp.float32), (640, 4, 1, jnp.float32),
+    (None, 4, 4, jnp.float32), (None, 4, 1, jnp.float32),
+    (300, 4, 1, jnp.bfloat16),
+])
+def test_flash_all_tile_classes_match_reference(window, hq, hkv, dtype):
+    """Forward and all three gradients against the exact masked form at
+    five 128-blocks: windows 300 and 256 reach masked (diagonal and far
+    edge), unmasked and skipped tiles in one call, 100 leaves no unmasked
+    tile, 640 and None no far edge."""
+    from deepspeed_tpu.ops.layers import window_bias
+    s, d = 640, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, s, hq, d), dtype)
+    k = jax.random.normal(ks[1], (1, s, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (1, s, hkv, d), dtype)
+    bias = window_bias(s, window) if window is not None else None
+
+    def both(attn):
+        def f(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))(q, k, v)
+
+    (_, o1), g1 = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window))
+    (_, o2), g2 = both(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True, bias=bias))
+    fwd_tol, bwd_tol = ((2e-5, 2e-4) if dtype == jnp.float32
+                        else (3e-2, 1.5e-1))
+    assert o1.dtype == dtype
+    np.testing.assert_allclose(np.asarray(o1, np.float32),
+                               np.asarray(o2, np.float32),
+                               atol=fwd_tol, rtol=fwd_tol)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape and a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=bwd_tol, rtol=bwd_tol)
+
+
+@pytest.mark.parametrize("window,want", [
+    (4096, {"masked": 24, "unmasked": 84, "skipped": 28}),
+    (None, {"masked": 16, "unmasked": 120, "skipped": 0}),
+])
+def test_flash_tiles_gauge(window, want):
+    """``ds_flash_tiles`` is set where the kernels are built (trace time,
+    so an abstract evaluation is enough), at the cells' shape."""
+    from deepspeed_tpu import telemetry
+    assert fa.tile_counts(8192, 512, window, True) == want
+    x = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32)))(q)
+
+    telemetry.shutdown()
+    telemetry.configure()
+    try:
+        reg = telemetry.get_registry()
+        assert reg.get("ds_flash_tiles") is None
+        jax.eval_shape(grad, x, x, x)
+        g = reg.get("ds_flash_tiles")
+        for kernel in ("fwd", "bwd"):
+            assert {k: g.value(kernel=kernel, kind=k)
+                    for k in fa.TILE_KINDS} == want
+    finally:
+        telemetry.shutdown()
 
 
 def test_fused_adam_with_schedule_matches_optax():
