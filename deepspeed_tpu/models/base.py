@@ -57,6 +57,35 @@ class ModelConfig:
     capacity_factor: float = 1.25
     min_capacity: int = 4
     router_aux_loss_coef: float = 0.01
+    moe_router_activation: str = "softmax"  # softmax | sigmoid (bias-
+    #                                 corrected selection, Kimi-Linear)
+    routed_scaling_factor: float = 1.0
+    moe_intermediate_size: int = 0  # expert width where it differs from
+    #                                 the dense FFN's (0 = the same)
+    moe_held_experts: int = 0       # experts HELD here of num_experts, the
+    #                                 router's width (0 = all): one chip's
+    #                                 share under expert parallelism (the
+    #                                 first of them)
+    # a stack of more than one kind of layer (models/kimi_linear.py):
+    # 1-based layer numbers as the published config gives them; both empty
+    # = one kind of layer (every other family)
+    kda_layers: tuple = ()          # KDA linear attention (ops/kda.py)
+    full_attn_layers: tuple = ()    # latent attention (MLA)
+    first_k_dense_replace: int = 0  # leading layers whose FFN is dense
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 128        # width of the low-rank decay / output
+    #                                 gate maps (the head width)
+    kda_head_groups: int = 1        # run the KDA heads in this many groups,
+    #                                 one after the other (ops/kda.py): the
+    #                                 chunked form's operands live a group
+    #                                 at a time
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_use_nope: bool = False      # no rotation on either part of q, k
     # numerics
     param_dtype: Any = None   # set to jnp dtype in __post_init__
     loss_chunk: int = 0       # >0: fused chunked cross-entropy (tokens per
@@ -74,6 +103,8 @@ class ModelConfig:
             self.param_dtype = jnp.float32
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
+        self.kda_layers = tuple(self.kda_layers)
+        self.full_attn_layers = tuple(self.full_attn_layers)
 
     @property
     def head_dim(self) -> int:
@@ -85,9 +116,88 @@ class ModelConfig:
         for init / forward / num_params (GPT-J splits them)."""
         return self.use_bias if self.mlp_bias is None else self.mlp_bias
 
+    # ---- a stack of kinds (kda_layers / full_attn_layers) --------------
+    @property
+    def linear_attn_config(self) -> dict:
+        """The published ``linear_attn_config`` group of the model as
+        built (lists, as JSON has them)."""
+        return {"full_attn_layers": list(self.full_attn_layers),
+                "head_dim": self.kda_head_dim,
+                "kda_layers": list(self.kda_layers),
+                "num_heads": self.kda_num_heads,
+                "short_conv_kernel_size": self.kda_conv_size}
+
+    def layer_kinds(self) -> list[tuple[str, str]] | None:
+        """(token mixer, channel mixer) of each layer, ``kda`` | ``mla``
+        and ``dense`` | ``moe``; None for a model of one kind of layer."""
+        if not (self.kda_layers or self.full_attn_layers):
+            return None
+        kinds = []
+        for n in range(1, self.num_layers + 1):
+            if (n in self.kda_layers) == (n in self.full_attn_layers):
+                raise ValueError(
+                    f"layer {n} is in both or neither of kda_layers "
+                    f"{self.kda_layers} and full_attn_layers "
+                    f"{self.full_attn_layers}")
+            kinds.append(("kda" if n in self.kda_layers else "mla",
+                          "dense" if n <= self.first_k_dense_replace
+                          or self.num_experts <= 0 else "moe"))
+        return kinds
+
+    def _kind_params(self) -> dict:
+        """Parameters of each kind of mixer, as models/kimi_linear.py
+        builds them; ``expert`` is ONE routed expert, ``moe`` everything
+        of a routed layer but its routed experts."""
+        d = self.hidden_size
+        h, dk, r = self.kda_num_heads, self.kda_head_dim, self.kda_gate_rank
+        inner = h * dk
+        kda = (3 * d * inner + 3 * self.kda_conv_size * inner   # q, k, v
+               + d * r + r * inner + inner + h                   # decay
+               + d * h                                           # beta
+               + d * r + r * inner + inner                       # out gate
+               + dk + inner * d)                                 # norm, wo
+        nh = self.num_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        mla = (d * nh * qk + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+               + self.kv_lora_rank
+               + self.kv_lora_rank * nh * (self.qk_nope_head_dim
+                                           + self.v_head_dim)
+               + nh * self.v_head_dim * d)
+        fe = self.moe_intermediate_size or self.intermediate_size
+        return {"kda": kda, "mla": mla,
+                "dense": 3 * d * self.intermediate_size,
+                "expert": 3 * d * fe,
+                "moe": (d * self.num_experts + self.num_experts
+                        + 3 * d * fe * self.moe_num_shared_experts)}
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts held here: all of them unless told a share."""
+        return self.moe_held_experts or self.num_experts
+
+    def _stack_params(self, kinds, active: bool) -> int:
+        """Embedding, head, norms and the layers of a stack of kinds.
+        ``active``: a token's routed experts count as the ``moe_top_k``
+        it is routed to times the share of the experts held here (what
+        this chip computes for it, under a balanced router)."""
+        per = self._kind_params()
+        d, v = self.hidden_size, self.vocab_size
+        routed = (self.moe_top_k * self.held_experts / self.num_experts
+                  if active else self.held_experts) if self.num_experts \
+            else 0
+        n = v * d + (0 if self.tie_embeddings else v * d) + d
+        for mixer, channel in kinds:
+            n += per[mixer] + 2 * d
+            n += (per["dense"] if channel == "dense"
+                  else per["moe"] + routed * per["expert"])
+        return int(n)
+
     def num_params(self) -> int:
         """Analytic parameter count (embedding + layers + final norm),
-        matching the trees DecoderLM.init builds exactly."""
+        matching the trees the model's ``init`` builds exactly."""
+        kinds = self.layer_kinds()
+        if kinds is not None:
+            return self._stack_params(kinds, active=False)
         d, f, v, L = (self.hidden_size, self.intermediate_size,
                       self.vocab_size, self.num_layers)
         nh_d = self.num_heads * self.head_dim
@@ -129,6 +239,9 @@ class ModelConfig:
         router projection and any shared experts always run). This is
         the MFU denominator — counting parked experts would credit the
         model with FLOPs it never executed."""
+        kinds = self.layer_kinds()
+        if kinds is not None:
+            return self._stack_params(kinds, active=True)
         n = self.num_params()
         if self.num_experts <= 0:
             return n
@@ -163,6 +276,20 @@ class ModelConfig:
                 ctx = (s + 1) / 2
         else:
             ctx = s
+        kinds = self.layer_kinds()
+        if kinds is not None:
+            # latent attention multiplies a key of qk width and a value
+            # of v width a visible pair (2 matmuls, x3 for training); a
+            # KDA head reads, corrects and writes its [dk, dv] state once
+            # a token (3 products of 2 dk dv FLOPs, x3 for training)
+            mla = 6 * self.num_heads * ctx * (
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+                + self.v_head_dim)
+            kda = 18 * self.kda_num_heads * self.kda_head_dim ** 2
+            # the embedding is a gather, not a matmul
+            n -= self.vocab_size * self.hidden_size
+            return 6 * n + sum(kda if mixer == "kda" else mla
+                               for mixer, _ in kinds)
         attn_flops = 12 * self.num_layers * self.hidden_size * ctx
         return 6 * n + attn_flops
 

@@ -1,0 +1,444 @@
+"""Kimi-Linear family: a stack of more than one kind of layer.
+
+``Kimi-Linear-48B-A3B`` (moonshotai, ``config.json``): 27 pre-norm layers
+whose token mixer is KDA linear attention (``ops/kda.py``) in three of
+four and latent attention without positions (MLA, NoPE) in the fourth,
+and whose channel mixer is a dense SwiGLU in the first layer and 256
+sigmoid-routed experts of width 1024 (top 8, one shared expert) after it.
+
+    x <- x + Mix_l(rmsnorm(x));  x <- x + Ch_l(rmsnorm(x))
+
+**KDA** (H heads of dk = dv = 128)::
+
+    q = l2norm(silu(conv4(x Wq))) / sqrt(dk);  k = l2norm(silu(conv4(x Wk)))
+    v = silu(conv4(x Wv));   beta = sigmoid(x Wb)            per head
+    g = -exp(A_log[h]) * softplus((x Wf1) Wf2 + dt_bias)     per head, channel
+    o = chunk_kda(q, k, v, g, beta)
+    y = (rmsnorm_head(o) * sigmoid((x Wg1) Wg2 + b_g)) Wo
+
+``conv4`` is a causal depthwise convolution of width 4 along the sequence.
+**MLA** (``mla_use_nope``: neither part of q or k is rotated)::
+
+    q = x Wq  as H x (nope + rope);   [c, k_pe] = x Wkva  (kv_lora + rope)
+    [k_nope, v] = rmsnorm(c) Wkvb  as H x (nope + v);  k_h = [k_nope_h, k_pe]
+    y = softmax_causal(q k^T / sqrt(nope + rope)) v  Wo
+
+In training the latent is expanded and the layer runs as H-head attention
+with a key of 192 and a value of 128 through the flash kernels.
+**Routed layers** are ``moe.sharded_moe.moe_ffn_held``: a float32 sigmoid
+router over all experts with a selection bias, renormalised top-k times
+``routed_scaling_factor``, the experts HELD here (the first
+``moe_held_experts``: one chip's share under expert parallelism) through
+the dropless grouped dispatch, plus the shared expert. There is no
+auxiliary loss: the router is bias-corrected. ``optimizer_frozen`` keeps
+the optimizer off the bias; ``loss(with_stats=True)`` also returns every
+routed layer's load by expert, and ``after_step`` (the engine calls it
+with the updated weights) moves each bias against its expert's load.
+
+**The stack.** Parameters are stacked by kind: ``layers`` holds ``lead``
+(the leading dense layers, unrolled), ``period`` (the layers of ONE period
+of the pattern, each stacked over the whole periods, run under one
+``lax.scan``) and ``tail`` (what does not fill a period, unrolled); a
+layer's kind is read from the keys it holds.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ..ops import layers as L
+from .base import ModelConfig, register_model
+from .transformer import (DecoderLM, _dense_init, _remat_policy,
+                          _unpack_batch)
+
+_PUBLISHED = dict(
+    hidden_size=2304, intermediate_size=9216, num_heads=32, num_kv_heads=32,
+    num_layers=27, vocab_size=163840, max_seq_len=16384,
+    kda_layers=tuple(n for n in range(1, 27) if n % 4),
+    full_attn_layers=(4, 8, 12, 16, 20, 24, 27), first_k_dense_replace=1,
+    kda_num_heads=32, kda_head_dim=128, kda_conv_size=4, kda_gate_rank=128,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, mla_use_nope=True, num_experts=256, moe_top_k=8,
+    moe_num_shared_experts=1, moe_intermediate_size=1024,
+    routed_scaling_factor=2.446)
+
+
+def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
+    presets = {
+        "tiny": dict(hidden_size=64, intermediate_size=128, num_heads=4,
+                     num_kv_heads=4, num_layers=5, vocab_size=512,
+                     max_seq_len=128, kda_layers=(1, 2, 3, 5),
+                     full_attn_layers=(4,), first_k_dense_replace=1,
+                     kda_num_heads=4, kda_head_dim=16, kda_conv_size=4,
+                     kda_gate_rank=16, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, mla_use_nope=True,
+                     # the published router: the agreement check's mask
+                     # depends on the share of experts near the boundary
+                     num_experts=256, moe_top_k=8, moe_num_shared_experts=1,
+                     moe_intermediate_size=32, routed_scaling_factor=2.446),
+        "48b-a3b": _PUBLISHED,
+    }
+    base = dict(norm_type="rmsnorm", activation="swiglu",
+                position_embedding="none", use_bias=False,
+                tie_embeddings=False, norm_eps=1e-5,
+                moe_router_activation="sigmoid", moe_norm_topk=True,
+                router_aux_loss_coef=0.0)
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def stack_plan(kinds: list, lead: int) -> tuple[int, int, int]:
+    """(layers a period, whole periods, layers left over) of the kinds
+    after the ``lead`` leading layers: the period whose whole repeats (two
+    at least) cover most layers, the shortest such; what follows them is
+    left over, and so is everything where nothing repeats."""
+    rest = kinds[lead:]
+    best = (0, 0)
+    for p in range(1, len(rest) // 2 + 1):
+        n = 1
+        while rest[n * p:(n + 1) * p] == rest[:p]:
+            n += 1
+        if n >= 2 and n * p > best[0] * best[1]:
+            best = (p, n)
+    p, n = best
+    return p, n, len(rest) - p * n
+
+
+@register_model("kimi_linear")
+class KimiLinear(DecoderLM):
+    def __init__(self, config: ModelConfig | None = None,
+                 size: str | None = None, **overrides):
+        if config is not None and (size is not None or overrides):
+            raise ValueError(
+                "pass either an explicit config or size/overrides, not both")
+        config = config or kimi_linear_config(size or "48b-a3b", **overrides)
+        kinds = config.layer_kinds()
+        if kinds is None:
+            raise ValueError("KimiLinear needs kda_layers / full_attn_layers")
+        if not config.mla_use_nope:
+            raise NotImplementedError(
+                "KimiLinear's latent attention is NoPE (mla_use_nope)")
+        if config.moe_router_activation != "sigmoid":
+            raise ValueError("KimiLinear's router is sigmoid")
+        if config.tie_embeddings:
+            raise ValueError("KimiLinear's head is untied")
+        if config.held_experts > config.num_experts:
+            raise ValueError(
+                f"{config.held_experts} experts held of the router's "
+                f"{config.num_experts}")
+        super().__init__(config)
+        self.kinds = kinds
+        self.lead = min(config.first_k_dense_replace, len(kinds))
+        self.period, self.repeats, self.left = stack_plan(kinds, self.lead)
+
+    def optimizer_frozen(self) -> str:
+        """Leaves the optimizer leaves alone (the engine zeroes their
+        updates): the router's selection bias moves by ``after_step``."""
+        return r"router_bias$"
+
+    def after_step(self, params, stats):
+        """The trainer's half of the bias-corrected router, run by the
+        engine on the step's updated weights: every routed layer's
+        selection bias moves by ``BIAS_UPDATE_RATE`` against its
+        experts' load in the step (``balance_bias``). ``stats`` is what
+        ``loss(with_stats=True)`` returned beside the loss, summed over
+        the step's micro-batches. Returns (params, metrics): the rows
+        routed to the experts held here and the rows they computed
+        (equal, or rows were dropped), over ``moe_held_calls`` routed
+        layers of ``moe_held_experts`` each."""
+        from ..moe.sharded_moe import balance_bias
+        c = self.config
+        layers = {g: dict(slots) for g, slots in params["layers"].items()}
+        rows = done = calls = 0
+        for group, slots in stats.items():
+            for slot, counts in slots.items():
+                p = layers[group][slot]
+                moe = dict(p["moe"])
+                moe["router_bias"] = balance_bias(moe["router_bias"],
+                                                  counts["load"])
+                layers[group][slot] = {**p, "moe": moe}
+                rows += jnp.sum(counts["load"][..., :c.held_experts])
+                done += jnp.sum(counts["done"])
+                calls += counts["done"].size
+        metrics = {"moe_held_rows": rows, "moe_held_done": done,
+                   "moe_held_calls": jnp.int32(calls),
+                   "moe_held_experts": jnp.int32(c.held_experts)}
+        return {**params, "layers": layers}, metrics
+
+    # ---------------- init ----------------
+    def _init_layer(self, key, kind, lead_shape=()):
+        c = self.config
+        dt = c.param_dtype
+        d = c.hidden_size
+        std = 0.02
+        resid_std = std / (2 * c.num_layers) ** 0.5
+        ks = iter(jax.random.split(key, 24))
+
+        def w(shape, scale=std):
+            return _dense_init(next(ks), (*lead_shape, *shape), scale, dt)
+
+        def ones(shape):
+            return jnp.ones((*lead_shape, *shape), dt)
+
+        p = {"ln1_scale": ones((d,)), "ln2_scale": ones((d,))}
+        mixer, channel = kind
+        if mixer == "kda":
+            h, dk, r = c.kda_num_heads, c.kda_head_dim, c.kda_gate_rank
+            inner = h * dk
+            conv = lambda: jax.random.uniform(  # noqa: E731
+                next(ks), (*lead_shape, c.kda_conv_size, inner),
+                minval=-0.5, maxval=0.5).astype(dt)
+            # decay init: A = U(1, 16); dt = exp(U(log 1e-3, log 0.1)),
+            # dt_bias its inverse softplus
+            step = jnp.exp(jax.random.uniform(
+                next(ks), (*lead_shape, inner),
+                minval=np.log(1e-3), maxval=np.log(0.1)))
+            p["kda"] = {
+                "wq": w((d, inner)), "wk": w((d, inner)), "wv": w((d, inner)),
+                "conv_q": conv(), "conv_k": conv(), "conv_v": conv(),
+                "w_f1": w((d, r)), "w_f2": w((r, inner)),
+                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+                "A_log": jnp.log(jax.random.uniform(
+                    next(ks), (*lead_shape, h), minval=1.0,
+                    maxval=16.0)).astype(dt),
+                "w_b": w((d, h)),
+                "w_g1": w((d, r)), "w_g2": w((r, inner)),
+                "b_g": jnp.zeros((*lead_shape, inner), dt),
+                "o_norm": ones((dk,)),
+                "wo": w((inner, d), resid_std),
+            }
+        else:
+            nh = c.num_heads
+            qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+            p["mla"] = {
+                "wq": w((d, nh * qk)),
+                "w_kva": w((d, c.kv_lora_rank + c.qk_rope_head_dim)),
+                "kv_norm": ones((c.kv_lora_rank,)),
+                "w_kvb": w((c.kv_lora_rank,
+                            nh * (c.qk_nope_head_dim + c.v_head_dim))),
+                "wo": w((nh * c.v_head_dim, d), resid_std),
+            }
+        if channel == "dense":
+            f = c.intermediate_size
+            p["mlp"] = {"w_gate": w((d, f)), "w_up": w((d, f)),
+                        "w_down": w((f, d), resid_std)}
+        else:
+            f = c.moe_intermediate_size or c.intermediate_size
+            e = c.held_experts
+            fs = f * c.moe_num_shared_experts
+            p["moe"] = {
+                # logits of unit variance at any width (0.0208 at the
+                # published 2304): the scores' spread, and with it the
+                # share of experts near the top-k boundary, is the same
+                # at the tiny preset as at the real one
+                "router": w((d, c.num_experts), d ** -0.5),
+                # drawn, so that selection (scores + bias) and weighting
+                # (scores) differ, small beside the scores' spread (0.2)
+                # so that the load stays balanced; after_step moves it
+                "router_bias": w((c.num_experts,), 0.01),
+                "experts": {"w_gate": w((e, d, f)), "w_up": w((e, d, f)),
+                            "w_down": w((e, f, d), resid_std)},
+            }
+            if fs:
+                p["moe"]["shared"] = {
+                    "w_gate": w((d, fs)), "w_up": w((d, fs)),
+                    "w_down": w((fs, d), resid_std)}
+        return p
+
+    def init(self, rng: jax.Array):
+        c = self.config
+        dt = c.param_dtype
+        d, v = c.hidden_size, c.vocab_size
+        keys = jax.random.split(rng, 4)
+        lk = iter(jax.random.split(keys[0], len(self.kinds)))
+        at = self.lead + self.period * self.repeats
+        layers = {
+            "lead": {str(i): self._init_layer(next(lk), self.kinds[i])
+                     for i in range(self.lead)},
+            "period": {str(j): self._init_layer(
+                next(lk), self.kinds[self.lead + j], (self.repeats,))
+                for j in range(self.period if self.repeats else 0)},
+            "tail": {str(i): self._init_layer(next(lk), self.kinds[at + i])
+                     for i in range(self.left)},
+        }
+        return {
+            "embed": {"tokens": _dense_init(keys[1], (v, d), 0.02, dt)},
+            "layers": layers,
+            "final_norm": {"scale": jnp.ones((d,), dt)},
+            "lm_head": _dense_init(keys[2], (d, v), 0.02, dt),
+        }
+
+    # ---------------- the mixers ----------------
+    def _kda(self, p, h):
+        from ..ops.kda import chunk_kda
+        c = self.config
+        b, s, _ = h.shape
+        nh, dk = c.kda_num_heads, c.kda_head_dim
+        f32 = jnp.float32
+
+        def conv(x, w):     # causal depthwise, width kda_conv_size
+            n = w.shape[0]
+            xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
+            return sum(xp[:, i:i + s] * w[i] for i in range(n))
+
+        def l2norm(x):
+            x = x.astype(f32)
+            return x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+        heads = lambda x: x.reshape(b, s, nh, dk)  # noqa: E731
+        q = heads(L.silu(conv(h @ p["wq"], p["conv_q"])))
+        k = heads(L.silu(conv(h @ p["wk"], p["conv_k"])))
+        v = heads(L.silu(conv(h @ p["wv"], p["conv_v"])))
+        q = (l2norm(q) * dk ** -0.5).astype(h.dtype)
+        k = l2norm(k).astype(h.dtype)
+        beta = jax.nn.sigmoid((h @ p["w_b"]).astype(f32))
+        decay = ((h @ p["w_f1"]) @ p["w_f2"]).astype(f32) \
+            + p["dt_bias"].astype(f32)
+        g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
+            * heads(jax.nn.softplus(decay))
+        o = chunk_kda(q, k, v, g, beta, head_groups=c.kda_head_groups)
+        gate = jax.nn.sigmoid(
+            ((h @ p["w_g1"]) @ p["w_g2"]).astype(f32)
+            + p["b_g"].astype(f32))
+        o = L.rms_norm(o, p["o_norm"], c.norm_eps).reshape(b, s, nh * dk)
+        return (o.astype(f32) * gate).astype(h.dtype) @ p["wo"]
+
+    def _mla(self, p, h, attn_fn):
+        c = self.config
+        b, s, _ = h.shape
+        nh, nope, rope, dv = (c.num_heads, c.qk_nope_head_dim,
+                              c.qk_rope_head_dim, c.v_head_dim)
+        q = (h @ p["wq"]).reshape(b, s, nh, nope + rope)
+        kva = h @ p["w_kva"]
+        latent = L.rms_norm(kva[..., :c.kv_lora_rank], p["kv_norm"],
+                            c.norm_eps)
+        k_pe = kva[..., c.kv_lora_rank:]
+        kv = (latent @ p["w_kvb"]).reshape(b, s, nh, nope + dv)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe[:, :, None, :], (b, s, nh, rope))],
+            axis=-1)
+        a = attn_fn(q, k, kv[..., nope:], causal=True)
+        return a.reshape(b, s, nh * dv) @ p["wo"]
+
+    def _routed(self, p, h):
+        from ..moe.sharded_moe import moe_ffn_held
+        c = self.config
+        return moe_ffn_held(
+            h, p["router"], p["router_bias"], p["experts"], p.get("shared"),
+            k=c.moe_top_k, renormalise=c.moe_norm_topk,
+            scaling=c.routed_scaling_factor)
+
+    # ---------------- one layer, the stack ----------------
+    def _mix(self, p, x, attn_fn):
+        h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
+        if "kda" in p:
+            with jax.named_scope("ds.kda"):
+                return x + self._kda(p["kda"], h)
+        with jax.named_scope("ds.mla"):
+            return x + self._mla(p["mla"], h, attn_fn)
+
+    def _channel(self, p, x):
+        """(x, counts): a routed layer's counts, nothing of a dense one."""
+        h = L.rms_norm(x, p["ln2_scale"], self.config.norm_eps)
+        if "mlp" in p:
+            with jax.named_scope("ds.mlp"):
+                return x + self._mlp(p["mlp"], h)[0], {}
+        y, counts = self._routed(p["moe"], h)
+        return x + y, counts
+
+    def _layer(self, p, x, attn_fn, scanned: bool):
+        """One layer of the kind its keys name, as (x, counts); rematted
+        whole. An unrolled layer's checkpoint has to prevent CSE, or XLA
+        merges the recomputation with the forward pass and keeps every
+        intermediate alive; under the scan the loop boundary does that."""
+        c = self.config
+        layer = lambda p, x: self._channel(  # noqa: E731
+            p, self._mix(p, x, attn_fn))
+        if not c.remat:
+            return layer(p, x)
+        return jax.checkpoint(layer, prevent_cse=not scanned,
+                              policy=_remat_policy(c.remat_policy))(p, x)
+
+    def _layer_stack(self, layers, x, pin, *, attn_fn, positions):
+        """(x, stats): ``stats[group][slot]`` are the counts of each
+        routed layer (``moe_ffn_held``), a ``period`` slot's stacked over
+        the repeats as its parameters are."""
+        if attn_fn is None:
+            if self.config.attn_impl == "flash":
+                from ..ops.pallas.flash_attention import flash_attention
+                attn_fn = flash_attention
+            else:
+                attn_fn = L.dot_product_attention
+        stats = {"lead": {}, "period": {}, "tail": {}}
+
+        def unrolled(group, n, x):
+            for i in range(n):
+                x, stats[group][str(i)] = self._layer(
+                    layers[group][str(i)], x, attn_fn, False)
+                x = pin(x)
+            return x
+
+        x = unrolled("lead", self.lead, x)
+        if self.repeats:
+            def body(x, slots):
+                counts = {}
+                for j in range(self.period):
+                    x, counts[str(j)] = self._layer(
+                        slots[str(j)], x, attn_fn, True)
+                    x = pin(x)
+                return x, counts
+
+            x, stats["period"] = jax.lax.scan(body, x, layers["period"])
+        x = unrolled("tail", self.left, x)
+        return x, {g: {k: v for k, v in slots.items() if v}
+                   for g, slots in stats.items()}
+
+    def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
+             with_stats: bool = False):
+        """Mean cross-entropy (no auxiliary term); ``with_stats`` also
+        returns the routed layers' counts, for ``after_step``."""
+        tokens, targets = _unpack_batch(batch)
+        x, stats = self._final_hidden(params, tokens, attn_fn=attn_fn,
+                                      act_sharding=act_sharding)
+        with jax.named_scope("ds.loss_head"):
+            if self.config.loss_chunk > 0:
+                ce = self._chunked_ce(params, x, targets)
+            else:
+                ce = L.cross_entropy_loss(
+                    self._project_vocab(params, x), targets)
+        return (ce, stats) if with_stats else ce
+
+    # the serving and pipeline paths assume one kind of layer and a KV cache
+    def _one_kind_only(self, *a, **kw):
+        raise NotImplementedError(
+            "KimiLinear runs through apply/loss only: a latent cache and "
+            "recurrent KDA state are not in inference/, and a stack of "
+            "kinds has no single block()")
+
+    block = block_decode = decode = init_cache = _one_kind_only
+
+    # ---------------- sharding ----------------
+    def partition_rules(self):
+        """Tensor-parallel rules by head / FFN / expert dimension; the
+        leading axis of a ``period`` stack is the scan's and stays
+        whole."""
+        def both(pattern, *spec):
+            return [(rf"layers/period/.*{pattern}", P(None, *spec)),
+                    (rf"layers/(lead|tail)/.*{pattern}", P(*spec))]
+
+        rules = [(r"embed/tokens", P("tp", None))]
+        for pattern, spec in [
+                (r"(kda|mla)/(wq|wk|wv|w_f2|w_g2|w_kvb)$", (None, "tp")),
+                (r"(kda|mla)/wo$", ("tp", None)),
+                (r"experts/(w_up|w_gate)$", ("ep", None, "tp")),
+                (r"experts/w_down$", ("ep", "tp", None)),
+                (r"(mlp|shared)/(w_up|w_gate)$", (None, "tp")),
+                (r"(mlp|shared)/w_down$", ("tp", None))]:
+            rules += both(pattern, *spec)
+        return rules + [(r"lm_head$", P(None, "tp"))]

@@ -498,12 +498,26 @@ class DecoderLM:
         holds wherever this is traced (``parallel.mesh.constrain_free``:
         axes manual in an enclosing region are dropped, an uneven batch
         stays unconstrained)."""
-        c = self.config
         pin = (functools.partial(constrain_free, sharding=act_sharding)
                if act_sharding is not None else lambda x: x)
         with jax.named_scope("ds.embed"):
             x = self.embed(params, tokens, positions)
         x = pin(x)
+
+        with jax.named_scope("ds.layers"):
+            x, aux = self._layer_stack(params["layers"], x, pin,
+                                       attn_fn=attn_fn, positions=positions)
+        with jax.named_scope("ds.loss_head"):
+            x = self._norm(x, params["final_norm"]["scale"],
+                           params["final_norm"].get("bias"))
+        return x, aux
+
+    def _layer_stack(self, layers: PyTree, x, pin, *, attn_fn, positions):
+        """The layers between embedding and final norm: here ONE kind of
+        layer, stacked ``[L, ...]`` under one scan. A family whose stack
+        holds several kinds overrides this (models/kimi_linear.py).
+        Returns (x, summed router aux loss)."""
+        c = self.config
 
         def body(carry, layer_params):
             x, aux = carry
@@ -517,12 +531,8 @@ class DecoderLM:
             # would re-introduce the flash fwd rerun it exists to avoid
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=_remat_policy(c.remat_policy))
-        with jax.named_scope("ds.layers"):
-            (x, aux), _ = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-        with jax.named_scope("ds.loss_head"):
-            x = self._norm(x, params["final_norm"]["scale"],
-                           params["final_norm"].get("bias"))
+        (x, aux), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), layers)
         return x, aux
 
     def _project_vocab(self, params: PyTree, x: jax.Array) -> jax.Array:

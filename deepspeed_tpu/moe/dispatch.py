@@ -204,3 +204,34 @@ def publish_router_metrics(metrics: dict) -> None:
     load = metrics["expert_load"]
     jax.debug.callback(_emit, metrics["drop_fraction"], jnp.min(load),
                        jnp.max(load))
+
+
+def record_held_expert_counts(reg, counts: dict) -> None:
+    """One finished step's ``moe_*`` metrics (``models/kimi_linear.py``
+    ``after_step``: device scalars the step returns, traced or not) into
+    the telemetry registry, on the host: the rows (token, choice) routed
+    to the experts held here, the routed-layer calls, the rows routed but
+    not computed (0, or the dispatch dropped tokens).
+    ``ds_moe_held_rows_total / (ds_moe_held_calls_total x experts held)``
+    is the mean tokens a held expert a layer call; the two ``_step_``
+    gauges keep the least and the most that mean was in one step."""
+    rows, done = float(counts["moe_held_rows"]), float(counts["moe_held_done"])
+    calls, held = float(counts["moe_held_calls"]), float(
+        counts["moe_held_experts"])
+    reg.counter("ds_moe_held_rows_total",
+                "rows (token, choice) routed to held experts").inc(rows)
+    reg.counter("ds_moe_held_calls_total",
+                "held-expert layer calls").inc(calls)
+    reg.counter("ds_moe_dropped_rows_total",
+                "rows routed to a held expert and not computed").inc(
+                    rows - done)
+    reg.gauge("ds_moe_held_experts",
+              "experts held by this chip's share of a routed layer").set(held)
+    per = rows / (calls * held)
+    low = reg.gauge("ds_moe_held_tokens_step_min",
+                    "least mean tokens a held expert a call, of any step")
+    high = reg.gauge("ds_moe_held_tokens_step_max",
+                     "most mean tokens a held expert a call, of any step")
+    first = reg.counter("ds_moe_held_calls_total").value() == calls
+    low.set(per if first else min(low.value(), per))
+    high.set(per if first else max(high.value(), per))

@@ -248,3 +248,228 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: dict, *,
         h = constrain(h)
     out = jnp.einsum("nec,ecd->nd", combine, h)
     return out.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# A held share of sigmoid-routed experts, dropless, with a backward
+# ---------------------------------------------------------------------------
+def sigmoid_top_k(logits: jax.Array, bias: jax.Array, k: int, *,
+                  renormalise: bool = True, scaling: float = 1.0):
+    """Bias-corrected sigmoid routing (DeepSeek-V3 / Kimi-Linear, one
+    group). ``logits`` [N, E] float32. ``scores = sigmoid(logits)``; the
+    top ``k`` of ``scores + bias`` are chosen (the correction ``bias``
+    takes part in the SELECTION only and gets no gradient); the weights
+    are the chosen experts' own scores, divided by their sum (+1e-20) if
+    ``renormalise``, times ``scaling``. Returns (experts chosen [N, k]
+    int32, weights [N, k] float32, selection scores [N, E])."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    select = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    _, idx = lax.top_k(select, k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling, select
+
+
+def _held_layout(idx, first: int, n_held: int, block: int):
+    """Rows (token, choice) routed to the experts held here, sorted by
+    expert and cut into blocks of ``block`` rows that each lie in ONE
+    expert's run. Returns ``order`` [N*k + block] (row ids by expert, the
+    rows of absent experts last, padded), ``counts`` and ``starts`` [E_h]
+    of each held expert's run in it, and ``ends`` [E_h]: the number of
+    blocks up to and with the expert's own (the last is the total)."""
+    n, k = idx.shape
+    local = idx - first
+    held = (local >= 0) & (local < n_held)
+    e_flat = jnp.where(held, local, n_held).reshape(-1)
+    order = jnp.argsort(e_flat, stable=True).astype(jnp.int32)
+    counts = jnp.bincount(e_flat, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    ends = jnp.cumsum((counts + block - 1) // block)
+    order = jnp.concatenate([order, jnp.zeros((block,), jnp.int32)])
+    return order, counts, starts, ends
+
+
+def _block_rows(b, layout, k: int, block: int):
+    """Block ``b`` of the layout: its expert, its row ids [block], their
+    tokens, and which of the rows are the expert's (the last block of a
+    run is part empty)."""
+    order, counts, starts, ends = layout
+    e = jnp.sum(b >= ends).astype(jnp.int32)
+    at = (b - ends[e]) * block + (counts[e] + block - 1) // block * block
+    rows = lax.dynamic_slice(order, (starts[e] + at,), (block,))
+    valid = jnp.arange(block) < counts[e] - at
+    return e, rows, rows // k, valid
+
+
+def _swiglu_rows(xg, w_gate, w_up):
+    gate = xg @ w_gate
+    up = xg @ w_up
+    return gate, up, jax.nn.silu(gate) * up
+
+
+def _held_fwd_loop(x, idx, weights, experts, first, block):
+    n, d = x.shape
+    k = idx.shape[1]
+    n_held = experts["w_up"].shape[0]
+    layout = _held_layout(idx, first, n_held, block)
+    w_flat = weights.reshape(-1)
+
+    def body(b, carry):
+        out, done = carry
+        e, rows, tokens, valid = _block_rows(b, layout, k, block)
+        xg = jnp.where(valid[:, None], x[tokens], 0)
+        _, _, h = _swiglu_rows(xg, experts["w_gate"][e], experts["w_up"][e])
+        y = (h @ experts["w_down"][e]).astype(jnp.float32)
+        scale = jnp.where(valid, w_flat[rows], 0.0)
+        return (out.at[tokens].add(y * scale[:, None]),
+                done + jnp.sum(valid))
+
+    out, done = lax.fori_loop(
+        0, layout[-1][-1], body,
+        (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32)))
+    return out.astype(x.dtype), done
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def held_experts_ffn(x, idx, weights, experts, first, block):
+    """The part of a routed layer's result that the experts HELD here
+    give: ``sum_j weights[n, j] * E_{idx[n, j]}(x_n)`` over the choices
+    ``j`` whose expert lies in ``[first, first + E_h)`` (``experts``:
+    SwiGLU weights ``[E_h, ...]``). Dropless: the rows routed here are
+    sorted by expert and swept in blocks of ``block`` rows, as many
+    blocks as this batch's routing needs (a loop whose trip count is
+    data), each block one gather, three matmuls against ONE expert's
+    weights and one scatter-add; no capacity, no [N, E, C] table. The
+    work is that of the rows routed here, however skewed the router;
+    nothing has the size of the worst case but the row index.
+
+    x [N, D]; idx [N, k] int32 over ALL experts; weights [N, k] float32.
+    Returns (out [N, D], rows computed): fewer than the rows routed to
+    the held experts only if rows were dropped."""
+    with jax.named_scope("ds.moe_experts"):
+        return _held_fwd_loop(x, idx, weights, experts, first, block)
+
+
+def _held_fwd_rule(x, idx, weights, experts, first, block):
+    with jax.named_scope("ds.moe_experts"):
+        out = _held_fwd_loop(x, idx, weights, experts, first, block)
+    return out, (x, idx, weights, experts)
+
+
+def _held_bwd_rule(first, block, res, cts):
+    """One more sweep over the same blocks: the expert's two input
+    matmuls are run again (nothing of the forward sweep is kept but its
+    inputs), then the six of the backward."""
+    x, idx, weights, experts = res
+    dout = cts[0]
+    n, d = x.shape
+    k = idx.shape[1]
+    n_held = experts["w_up"].shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("ds.moe_experts"):
+        layout = _held_layout(idx, first, n_held, block)
+        w_flat = weights.reshape(-1)
+
+        def body(b, carry):
+            dx, dw, dg, du, dd = carry
+            e, rows, tokens, valid = _block_rows(b, layout, k, block)
+            xg = jnp.where(valid[:, None], x[tokens], 0)
+            gate, up, h = _swiglu_rows(xg, experts["w_gate"][e],
+                                       experts["w_up"][e])
+            y = h @ experts["w_down"][e]
+            dout_g = jnp.where(valid[:, None], dout[tokens], 0)
+            dw = dw.at[rows].add(jnp.where(valid, jnp.sum(
+                dout_g.astype(f32) * y.astype(f32), axis=-1), 0.0))
+            dy = (dout_g.astype(f32)
+                  * jnp.where(valid, w_flat[rows], 0.0)[:, None]
+                  ).astype(x.dtype)
+            dh = dy @ experts["w_down"][e].T
+            sg = jax.nn.sigmoid(gate.astype(f32))
+            d_up = (dh * (gate.astype(f32) * sg)).astype(x.dtype)
+            d_gate = (dh * up * (sg * (1 + gate.astype(f32) * (1 - sg)))
+                      ).astype(x.dtype)
+            dxg = (d_gate @ experts["w_gate"][e].T
+                   + d_up @ experts["w_up"][e].T)
+            acc = lambda t, a, b_: t.at[e].add(  # noqa: E731
+                jnp.matmul(a.T, b_, preferred_element_type=f32))
+            return (dx.at[tokens].add(dxg.astype(f32)), dw,
+                    acc(dg, xg, d_gate), acc(du, xg, d_up), acc(dd, h, dy))
+
+        zeros = lambda w: jnp.zeros(w.shape, f32)  # noqa: E731
+        dx, dw, dg, du, dd = lax.fori_loop(
+            0, layout[-1][-1], body,
+            (jnp.zeros((n, d), f32), jnp.zeros((n * k + block,), f32),
+             zeros(experts["w_gate"]), zeros(experts["w_up"]),
+             zeros(experts["w_down"])))
+    d_experts = {"w_gate": dg.astype(experts["w_gate"].dtype),
+                 "w_up": du.astype(experts["w_up"].dtype),
+                 "w_down": dd.astype(experts["w_down"].dtype)}
+    return (dx.astype(x.dtype), None,
+            dw[:n * k].reshape(n, k).astype(weights.dtype), d_experts)
+
+
+held_experts_ffn.defvjp(_held_fwd_rule, _held_bwd_rule)
+
+
+def moe_ffn_held(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+                 experts: dict, shared: dict | None, *, k: int,
+                 first_expert: int = 0, renormalise: bool = True,
+                 scaling: float = 1.0, block: int | None = None):
+    """A sigmoid-routed expert layer that is told which experts it holds
+    (one chip's share under expert parallelism, without its exchange):
+    routes every token over ALL ``router_w.shape[-1]`` experts, computes
+    what the ``experts["w_up"].shape[0]`` experts from ``first_expert``
+    on give for the tokens routed to them (:func:`held_experts_ffn`) and
+    adds the always-on ``shared`` expert. A token routed only to absent
+    experts gets the shared expert alone; what the absent experts would
+    have added is left out. ``block`` (rows a block of the dispatch)
+    defaults to twice what a balanced router sends one expert, in 128s
+    between 128 and 1024: a padded capacity of two, as trainers pad for
+    static shapes. An expert at or under it takes ONE block, so below it
+    the sweep's time does not follow the load (the held-expert roofline,
+    which counts the rows that ran, shows the padding); an expert over it
+    takes more blocks, and nothing is dropped.
+
+    Returns (out [B, S, D], counts): ``counts["load"]`` [E] int32, the
+    rows (token, choice) routed to EACH of the router's experts (what the
+    trainer's bias update balances, :func:`balance_bias`; its slice
+    ``[first_expert, +E_h)`` is what this share was sent), and
+    ``counts["done"]``, the rows this share computed: less than that
+    slice's sum only if rows were dropped."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    if block is None:
+        even = b * s * k / router_w.shape[-1]
+        block = min(1024, max(128, 128 * math.ceil(2 * even / 128)))
+    with jax.named_scope("ds.moe_router"):
+        logits = jnp.matmul(xt, router_w,
+                            preferred_element_type=jnp.float32)
+        idx, weights, _ = sigmoid_top_k(logits, router_bias, k,
+                                        renormalise=renormalise,
+                                        scaling=scaling)
+        load = jnp.bincount(idx.reshape(-1),
+                            length=router_w.shape[-1]).astype(jnp.int32)
+    out, done = held_experts_ffn(xt, idx, weights, experts,
+                                 int(first_expert), int(block))
+    if shared is not None:
+        with jax.named_scope("ds.moe_shared"):
+            _, _, h = _swiglu_rows(xt, shared["w_gate"], shared["w_up"])
+            out = out + h @ shared["w_down"]
+    return out.reshape(b, s, d), {"load": load, "done": done}
+
+
+BIAS_UPDATE_RATE = 0.001    # DeepSeek-V3's; the sigmoid-routed families follow it
+
+
+def balance_bias(bias: jax.Array, load: jax.Array,
+                 rate: float = BIAS_UPDATE_RATE) -> jax.Array:
+    """The trainer's update of a bias-corrected router's selection bias
+    (auxiliary-loss-free balancing, DeepSeek-V3 section 2.1.2): after a
+    step, an expert that got more than the mean load has its bias lowered
+    by ``rate``, one that got less has it raised. ``bias`` [..., E];
+    ``load`` [..., E], the rows routed to each expert in the step."""
+    load = load.astype(jnp.float32)
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - load).astype(bias.dtype)

@@ -195,6 +195,7 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
     [B*Hkv, S, D]; the kv index maps divide the q-head grid index by
     ``rep`` instead of materializing repeated k/v."""
     bh, s, d = q.shape
+    dv = v.shape[-1]        # the value width may differ from the key's
     bq = bk = _block(s)
     grid = (bh, s // bq)
     _gauge_tiles("fwd", s, bq, window, causal)
@@ -208,17 +209,17 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0),
+            pl.BlockSpec((1, s, dv), lambda b, i: (b // rep, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0),
+            pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, s, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
@@ -240,7 +241,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
     the diagonal or the window's far edge cuts carry the mask."""
     i = pl.program_id(1)
     q = q_ref[0]
-    d = q.shape[-1]
+    d = v_ref.shape[-1]
 
     def body(j, carry, masked):
         o_acc, m, l = carry
@@ -294,7 +295,6 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     j = pl.program_id(1)
     k = k_ref[0]
     v = v_ref[0]
-    d = k.shape[-1]
     nt = (((1,), (1,)), ((), ()))       # a @ b.T: contract the minor dims
 
     @pl.when(j == 0)
@@ -331,8 +331,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     cut = functools.partial(body, masked=True)
     whole = functools.partial(body, masked=False)
-    carry = (jnp.zeros((bk, d), jnp.float32),
-             jnp.zeros((bk, d), jnp.float32))
+    carry = (jnp.zeros(k.shape, jnp.float32),
+             jnp.zeros(v.shape, jnp.float32))
     if causal:
         # kv block j is attended by q blocks [j, hi) (bq == bk), in that
         # order
@@ -355,23 +355,27 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
                     axis=-1).reshape(bh, 1, s)
     _gauge_tiles("bwd", s, bq, window, causal)
 
-    rowfull = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0),
-                           memory_space=pltpu.VMEM)
-    kin = pl.BlockSpec((1, bk, d), lambda b, j: (b // rep, j, 0),
-                       memory_space=pltpu.VMEM)
-    kout = pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0),
-                        memory_space=pltpu.VMEM)
+    # q, k and their gradients at the key width ``d``; v, do and dv at the
+    # value width (latent attention: 192 beside 128; equal elsewhere)
+    dv = v.shape[-1]
+    rowfull = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, s, w), lambda b, j: (b, 0, 0), memory_space=pltpu.VMEM)
+    kin = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bk, w), lambda b, j: (b // rep, j, 0), memory_space=pltpu.VMEM)
+    kout = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bk, w), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM)
     rowstat = pl.BlockSpec((1, 1, s), lambda b, j: (b, 0, 0),
                            memory_space=pltpu.VMEM)
     call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, sc=sc, bq=bq, bk=bk,
                           nq=s // bq, causal=causal, window=window),
         grid=(bh, s // bk),
-        in_specs=[rowfull, kin, kin, rowfull, rowstat, rowstat],
-        out_specs=[rowfull, kout, kout],
+        in_specs=[rowfull(d), kin(d), kin(dv), rowfull(dv), rowstat,
+                  rowstat],
+        out_specs=[rowfull(d), kout(d), kout(dv)],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
                    jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, s, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, s, dv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
         name="ds_flash_bwd",
@@ -379,12 +383,12 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
     # opened here, inside the custom_vjp's backward function, so that the
     # scope survives shard_map and remat
     with jax.named_scope("ds.flash_bwd"):
-        dq, dk, dv = call(q, k, v, do, lse, delta)
+        dq, dk, dv_ = call(q, k, v, do, lse, delta)
     if rep > 1:
         # per-q-head dk/dv -> per-kv-head (consecutive q heads share kv)
         dk = dk.reshape(bh // rep, rep, s, d).sum(axis=1)
-        dv = dv.reshape(bh // rep, rep, s, d).sum(axis=1)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        dv_ = dv_.reshape(bh // rep, rep, s, dv).sum(axis=1)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv_.astype(v.dtype)
 
 
 # ---------------------------------------------------------------- public
@@ -430,6 +434,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """
     b, s, hq, d = q.shape
     hkv = k.shape[2]
+    dv = v.shape[-1]        # latent attention: a value narrower than the key
     if window is not None and not causal:
         raise ValueError("window requires causal=True (Mistral SWA)")
     if hq % hkv != 0:
@@ -454,13 +459,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return dot_product_attention(q, k, v, causal=causal, bias=bias)
     from jax.ad_checkpoint import checkpoint_name
     bhsd = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    if jax.default_backend() == "tpu" and s > _resident_max_seq(d):
+    if jax.default_backend() == "tpu" and s > _resident_max_seq(max(d, dv)):
         if rep > 1:
             # fallback paths take per-q-head kv (dot_product_attention
             # repeats internally; the stock kernel needs equal heads)
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        if d % 8 != 0 or window is not None:
+        if d % 8 != 0 or window is not None or dv != d:
             # the stock kernel needs 8-aligned head dims and supports no
             # window, and the resident kernel's VMEM budget is sized for
             # s <= _resident_max_seq(d) — use the exact masked form
@@ -472,7 +477,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 + ("sliding windows are only fused up to seq "
                    f"{_resident_max_seq(d)} at head_dim {d}"
                    if window is not None
-                   else f"head_dim {d} is not 8-aligned"))
+                   else f"head_dim {d} is not 8-aligned" if d % 8
+                   else f"the stock kernel has one width for key and "
+                        f"value, not {d} and {dv}"))
             bias = window_bias(s, window) if window is not None else None
             return dot_product_attention(q, k, v, causal=causal,
                                          bias=bias)
@@ -492,10 +499,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # kv rows at q_head_idx // rep, so repeated k/v are never
     # materialized — and the custom-VJP residuals (what remat stores per
     # layer) hold the UNREPEATED k/v
-    to_bh = lambda x: bhsd(x).reshape(-1, s, d)  # noqa: E731
+    to_bh = lambda x: bhsd(x).reshape(-1, s, x.shape[-1])  # noqa: E731
     o = _flash(to_bh(q), to_bh(k), to_bh(v), causal, window, rep)
     return checkpoint_name(
-        o.reshape(b, hq, s, d).transpose(0, 2, 1, 3), "attn_out")
+        o.reshape(b, hq, s, dv).transpose(0, 2, 1, 3), "attn_out")
 
 
 def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",

@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import inspect
 import os
+import re
 import types
 from typing import Any, Callable, Iterable, Optional
 
@@ -34,7 +36,7 @@ from .. import comm as dist
 from ..models.base import ModelConfig
 from ..moe.dispatch import moe_step
 from ..parallel.mesh import MeshTopology, TopologyConfig, set_topology
-from ..parallel.partition import constrain, named_shardings
+from ..parallel.partition import _path_str, constrain, named_shardings
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
                            STEP_GLOBAL_TIMER, SynchronizedWallClockTimer,
@@ -473,7 +475,6 @@ class DeepSpeedEngine:
         the flash kernels run per shard. On one device nothing is bound
         and the step is the model's own."""
         sp = self.topology.sequence_parallel_size
-        import inspect
         accepts = inspect.signature(self.module.loss).parameters
         kw = {}
         if self.mesh.size > 1 and "act_sharding" in accepts:
@@ -705,8 +706,22 @@ class DeepSpeedEngine:
         # grads the step already holds; absent (byte-identical
         # executable) when the sanitizer is off
         numsan_stats = self._numsan is not None
+        frozen = (re.compile(self.module.optimizer_frozen())
+                  if hasattr(self.module, "optimizer_frozen") else None)
+        # a model whose loss can return statistics of the step beside it
+        # (``with_stats``: a bias-corrected router's load by expert) gets
+        # them back in ``after_step`` with the updated weights; the ZeRO++
+        # explicit collectives and the eager triple carry the loss alone
+        zcfg = self.config.zero_optimization
+        with_stats = (
+            "with_stats" in inspect.signature(self.module.loss).parameters
+            and not (zcfg.zero_quantized_weights
+                     or zcfg.zero_quantized_gradients
+                     or zcfg.zero_hierarchical_allgather))
 
-        def micro_loss(params, batch, scale, step):
+        def micro_loss(params, batch, scale, step, stats=False):
+            """(scaled loss, aux): aux is the loss, or with ``stats``
+            (loss, the model's statistics)."""
             if compress is not None:
                 # QAT/pruning transform under grad: quantization rounds with
                 # an STE, pruning masks gate the gradient too (reference
@@ -716,25 +731,31 @@ class DeepSpeedEngine:
             # to this (traced) step; try/finally keeps a failed trace
             # from leaking the tracer into the contextvar
             with moe_step(step):
-                loss = loss_fn(params, batch)
-            return loss * scale.astype(loss.dtype), loss
+                aux = (loss_fn(params, batch, with_stats=True) if stats
+                       else loss_fn(params, batch))
+            loss = aux[0] if stats else aux
+            return loss * scale.astype(loss.dtype), aux
 
-        grad_fn = self._make_grad_fn(micro_loss)
+        grad_fn = (jax.value_and_grad(
+            functools.partial(micro_loss, stats=True), has_aux=True)
+            if with_stats else self._make_grad_fn(micro_loss))
 
         def accumulate(params, batch, scale, step, ga):
             """f32 gradients of ``ga`` micro-batches, summed, on
-            ``grad_specs``, and the micro-batches' losses."""
+            ``grad_specs``, the micro-batches' losses and the model's
+            statistics of them, summed (None: it has none)."""
             params = fetch(params, self.state_shardings["params"])
 
             def one_micro(micro):
-                (_, loss), grads = grad_fn(params, micro, scale, step)
+                (_, aux), grads = grad_fn(params, micro, scale, step)
                 grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                return constrain(grads, mesh, grad_specs), loss
+                return (constrain(grads, mesh, grad_specs),
+                        aux if with_stats else (aux, None))
 
             if ga == 1:
                 # no accumulation: skip the zeros-init + add pass
-                grads, loss = one_micro(batch)
-                return grads, loss[None]
+                grads, (loss, stats) = one_micro(batch)
+                return grads, loss[None], stats
 
             def body(acc, micro):
                 grads, loss = one_micro(micro)
@@ -746,7 +767,8 @@ class DeepSpeedEngine:
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             zeros = constrain(zeros, mesh, grad_specs)
-            return jax.lax.scan(body, zeros, micro_batches)
+            grads, (losses, stats) = jax.lax.scan(body, zeros, micro_batches)
+            return grads, losses, jax.tree.map(lambda x: x.sum(0), stats)
 
         # everything after the gradient is one device scope
         # (telemetry/scopes.py): HLO metadata, no run-time cost. finish
@@ -784,10 +806,12 @@ class DeepSpeedEngine:
                 min_scale=fp16_cfg.min_loss_scale,
                 hysteresis=fp16_cfg.hysteresis)
 
-        def update(state, grads, finite, grad_norm, losses=None):
+        def update(state, grads, finite, grad_norm, losses=None, stats=None):
             """Clip, apply the optimizer unless the step overflowed, and
             advance the loss scale and the step counter: ``(new state,
-            metrics)``, with the mean loss where ``losses`` are given."""
+            metrics)``, with the mean loss where ``losses`` are given.
+            With the model's ``stats`` of the step, its ``after_step``
+            has the updated weights and adds to the metrics."""
             shardings = self.state_shardings
             with jax.named_scope("ds.optimizer"):
                 if clip > 0:
@@ -801,7 +825,17 @@ class DeepSpeedEngine:
                 opt_state = fetch(state["opt_state"],
                                   shardings["opt_state"])
                 updates, new_opt = tx.update(grads, opt_state, master)
+                if frozen is not None:
+                    # leaves the model keeps from the optimizer (a
+                    # router's selection bias): no decay either
+                    updates = jax.tree_util.tree_map_with_path(
+                        lambda path, u: jnp.zeros_like(u) if frozen.search(
+                            _path_str(path)) else u, updates)
                 new_master = jax.tree.map(jnp.add, master, updates)
+                model_metrics = {}
+                if stats is not None:
+                    new_master, model_metrics = self.module.after_step(
+                        new_master, stats)
 
                 if fp16:
                     # skip the whole update on overflow
@@ -824,7 +858,7 @@ class DeepSpeedEngine:
             }
             metrics = {} if losses is None else {"loss": jnp.mean(losses)}
             metrics.update(grad_norm=grad_norm, loss_scale=ls.scale,
-                           overflow=~finite)
+                           overflow=~finite, **model_metrics)
             if numsan_stats:
                 gl = jax.tree.leaves(grads)
                 metrics["numsan_nonfinite"] = jnp.stack(
@@ -845,10 +879,10 @@ class DeepSpeedEngine:
 
         def train_step(state, batch):
             scale = state["loss_scale"].scale
-            grads, losses = parts.accumulate(state["params"], batch, scale,
-                                             state["step"], ga)
+            grads, losses, stats = parts.accumulate(
+                state["params"], batch, scale, state["step"], ga)
             return parts.update(state, *parts.finish(grads, scale, ga),
-                                losses)
+                                losses, stats)
 
         return jax.jit(train_step, donate_argnums=(0,),
                        in_shardings=(self.state_shardings, None),
@@ -875,7 +909,7 @@ class DeepSpeedEngine:
         def grads_step(state, batch):
             return finish_step(state, *parts.accumulate(
                 state["params"], batch, state["loss_scale"].scale,
-                state["step"], ga))
+                state["step"], ga)[:2])
 
         # state is deliberately NOT donated: params/loss_scale must
         # outlive the call (the host-side NVMe optimizer reads them
@@ -1017,6 +1051,8 @@ class DeepSpeedEngine:
               if tel is not None else _NULLCM):
             if tel is not None:
                 self._telemetry_boundary(tel, metrics)
+                if "moe_held_rows" in metrics:
+                    self._held_counts_feed(tel, metrics)
                 if jax.process_count() > 1:
                     # per-step straggler cadence (ISSUE 20): step-stride
                     # rate-limited inside (the stride derives only from
@@ -1100,6 +1136,19 @@ class DeepSpeedEngine:
             return max(0, self.global_steps - int(self.state["step"]))
         except Exception:
             return self.skipped_steps
+
+    def _held_counts_feed(self, tel, metrics):
+        """A routed model's counts of the step (its ``after_step``
+        metrics, device scalars) into the registry, as ``_numsan_feed``
+        reads its own: this step's are queued and the PREVIOUS step's,
+        which the donated state has already materialised, are read, so
+        nothing waits on the device. The registry is one step behind."""
+        pending = getattr(self, "_held_counts_pending", None)
+        self._held_counts_pending = metrics
+        reg = tel.get_registry()
+        if pending is not None and reg is not None:
+            from ..moe.dispatch import record_held_expert_counts
+            record_held_expert_counts(reg, pending)
 
     # --- numsan (ISSUE 18) --------------------------------------------
     def _numsan_feed(self, metrics):

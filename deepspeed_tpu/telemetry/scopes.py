@@ -54,6 +54,19 @@ DEVICE_SCOPES = (
     "ds.optimizer",    # runtime/engine.py _step_parts: finish and update
     "ds.grad_clip",    # the same: the grad norm (finish), the clip (update)
 )
+# what a stack of several kinds of layer opens inside ds.layers in place of
+# ds.attn (models/kimi_linear.py); ``tests/test_kimi_linear.py`` holds this
+# list equal to what that model's step carries
+KIND_SCOPES = (
+    "ds.kda",          # models/kimi_linear.py _mix: KDA's projections,
+    #                    convolutions, gates, norm and output matmul
+    "ds.kda_scan",     # ops/kda.py chunk_kda: the chunked delta rule
+    "ds.mla",          # models/kimi_linear.py _mix: latent attention
+    #                    (ds.flash_fwd / ds.flash_bwd inside it)
+    "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router
+    "ds.moe_experts",  # moe/sharded_moe.py held_experts_ffn, fwd and bwd
+    "ds.moe_shared",   # moe/sharded_moe.py moe_ffn_held: the shared expert
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")
