@@ -273,8 +273,7 @@ class KimiLinear(DecoderLM):
         }
 
     # ---------------- the mixers ----------------
-    def _kda(self, p, h):
-        from ..ops.kda import chunk_kda
+    def _kda(self, p, h, kda_fn):
         c = self.config
         b, s, _ = h.shape
         nh, dk = c.kda_num_heads, c.kda_head_dim
@@ -301,7 +300,7 @@ class KimiLinear(DecoderLM):
             + p["dt_bias"].astype(f32)
         g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
             * heads(jax.nn.softplus(decay))
-        o = chunk_kda(q, k, v, g, beta, head_groups=c.kda_head_groups)
+        o = kda_fn(q, k, v, g, beta, head_groups=c.kda_head_groups)
         gate = jax.nn.sigmoid(
             ((h @ p["w_g1"]) @ p["w_g2"]).astype(f32)
             + p["b_g"].astype(f32))
@@ -335,11 +334,11 @@ class KimiLinear(DecoderLM):
             scaling=c.routed_scaling_factor)
 
     # ---------------- one layer, the stack ----------------
-    def _mix(self, p, x, attn_fn):
+    def _mix(self, p, x, attn_fn, kda_fn):
         h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
         if "kda" in p:
             with jax.named_scope("ds.kda"):
-                return x + self._kda(p["kda"], h)
+                return x + self._kda(p["kda"], h, kda_fn)
         with jax.named_scope("ds.mla"):
             return x + self._mla(p["mla"], h, attn_fn)
 
@@ -352,35 +351,40 @@ class KimiLinear(DecoderLM):
         y, counts = self._routed(p["moe"], h)
         return x + y, counts
 
-    def _layer(self, p, x, attn_fn, scanned: bool):
+    def _layer(self, p, x, mixers, scanned: bool):
         """One layer of the kind its keys name, as (x, counts); rematted
         whole. An unrolled layer's checkpoint has to prevent CSE, or XLA
         merges the recomputation with the forward pass and keeps every
         intermediate alive; under the scan the loop boundary does that."""
         c = self.config
         layer = lambda p, x: self._channel(  # noqa: E731
-            p, self._mix(p, x, attn_fn))
+            p, self._mix(p, x, *mixers))
         if not c.remat:
             return layer(p, x)
         return jax.checkpoint(layer, prevent_cse=not scanned,
                               policy=_remat_policy(c.remat_policy))(p, x)
 
-    def _layer_stack(self, layers, x, pin, *, attn_fn, positions):
+    def _layer_stack(self, layers, x, pin, *, attn_fn, positions,
+                     act_sharding=None):
         """(x, stats): ``stats[group][slot]`` are the counts of each
         routed layer (``moe_ffn_held``), a ``period`` slot's stacked over
-        the repeats as its parameters are."""
+        the repeats as its parameters are. On a mesh of more than one
+        device (``act_sharding``) the KDA kernels run per shard."""
+        from ..ops.kda import chunk_kda, sharded_chunk_kda
         if attn_fn is None:
             if self.config.attn_impl == "flash":
                 from ..ops.pallas.flash_attention import flash_attention
                 attn_fn = flash_attention
             else:
                 attn_fn = L.dot_product_attention
+        mixers = (attn_fn, chunk_kda if act_sharding is None
+                  else sharded_chunk_kda(act_sharding))
         stats = {"lead": {}, "period": {}, "tail": {}}
 
         def unrolled(group, n, x):
             for i in range(n):
                 x, stats[group][str(i)] = self._layer(
-                    layers[group][str(i)], x, attn_fn, False)
+                    layers[group][str(i)], x, mixers, False)
                 x = pin(x)
             return x
 
@@ -390,7 +394,7 @@ class KimiLinear(DecoderLM):
                 counts = {}
                 for j in range(self.period):
                     x, counts[str(j)] = self._layer(
-                        slots[str(j)], x, attn_fn, True)
+                        slots[str(j)], x, mixers, True)
                     x = pin(x)
                 return x, counts
 

@@ -506,16 +506,20 @@ class DecoderLM:
 
         with jax.named_scope("ds.layers"):
             x, aux = self._layer_stack(params["layers"], x, pin,
-                                       attn_fn=attn_fn, positions=positions)
+                                       attn_fn=attn_fn, positions=positions,
+                                       act_sharding=act_sharding)
         with jax.named_scope("ds.loss_head"):
             x = self._norm(x, params["final_norm"]["scale"],
                            params["final_norm"].get("bias"))
         return x, aux
 
-    def _layer_stack(self, layers: PyTree, x, pin, *, attn_fn, positions):
+    def _layer_stack(self, layers: PyTree, x, pin, *, attn_fn, positions,
+                     act_sharding=None):
         """The layers between embedding and final norm: here ONE kind of
         layer, stacked ``[L, ...]`` under one scan. A family whose stack
-        holds several kinds overrides this (models/kimi_linear.py).
+        holds several kinds overrides this (models/kimi_linear.py; its
+        mixer's kernels run per shard of ``act_sharding``, which this one
+        needs only as ``pin``).
         Returns (x, summed router aux loss)."""
         c = self.config
 

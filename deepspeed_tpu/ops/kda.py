@@ -16,10 +16,19 @@ chunk with incoming state ``S`` and ``G_r = sum_{i<=r} g_i``::
     o_r  = S^T (q_r * exp(G_r)) + sum_{j<=r} beta_j <q_r * exp(G_r - G_j), k_j> u_j
     S'   = Diag(exp(G_C)) S + sum_j (k_j * exp(G_C - G_j)) beta_j u_j^T
 
-Everything that does not need ``S`` (both score matrices, the inverse,
-``T V`` and ``T (K * exp(G))``) is computed for all chunks at once, as
-batched matmuls; one ``lax.scan`` over the chunks then carries ``S``
-through three small matmuls a chunk. The backward is autodiff.
+Everything that does not need ``S`` (both score matrices, the inverse
+``T = (I + A)^-1``, ``T V``, ``T (K * exp(G))`` and the decay products) is
+computed for all chunks at once, as batched matmuls in ``jax.numpy``
+under autodiff: the preparation. What needs ``S`` is serial in the
+chunks and runs in a Pallas kernel pair under one ``custom_vjp``
+(``ops/pallas/kda.py`` ``kda_recurrence``): the forward carries ``S`` in
+VMEM across the chunks and writes ``o`` alone; the backward rebuilds the
+states by segments of ``SEG`` chunks from float32 segment checkpoints
+and carries ``dS`` in VMEM, so no state history and no per-chunk
+residual of the recurrence reaches HBM. (Until PR 32 this was a
+``lax.scan`` with an autodiff backward that stacked a state a chunk.)
+On the chip the preparation is five sixths of the mixer's time and the
+kernels one twentieth (``PERF.md`` section 5).
 
 ``exp(G_i - G_j) <= 1``, but ``exp(G_i) * exp(-G_j)`` overflows float32
 where a channel decays fast (a log-decay of -1.6 a token is -100 over a
@@ -39,8 +48,13 @@ by the finite Neumann product ``(I - A)(I + A^2)(I + A^4)...``
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+
+from .pallas.kda import kda_recurrence
 
 CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
 SUB = 8         # rows a sub-block of the score matrices
@@ -135,9 +149,12 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
 
     The heads (they are independent) run in ``head_groups`` groups, one
     after the other under ``lax.map``, each under its own
-    ``jax.checkpoint``: the chunk-wise operands and the backward's
-    residuals (a dozen arrays of ``[B, S, H, 128]`` float32) live for one
-    group at a time, and a group's forward is run again in its backward."""
+    ``jax.checkpoint``: the chunk-wise operands and the residuals of the
+    preparation's backward (a dozen arrays of ``[B, S, H, 128]`` float32)
+    live for one group at a time. A group's backward runs its
+    preparation again, then the forward kernel's checkpoint form and the
+    backward kernel; the forward kernel itself is not run again (its
+    ``o`` is dead in the rerun)."""
     h = q.shape[2]
     if h % head_groups:
         raise ValueError(f"chunk_kda: {h} heads in {head_groups} groups")
@@ -153,6 +170,37 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
         o = jax.lax.map(one, tuple(split(x) for x in (q, k, v, g, beta)))
     o = jnp.moveaxis(o, 0, 2)                       # [B, S, G, H/G, dv]
     return o.reshape(*o.shape[:2], h, o.shape[-1])
+
+
+def sharded_chunk_kda(act_sharding):
+    """``chunk_kda`` for a multi-device mesh: per shard of the batch under
+    a shard_map, because GSPMD cannot partition the kernels' Mosaic calls
+    (as ``ops.pallas.flash_attention.sharded_flash_attention``, which see).
+    ``act_sharding`` is the layout the model's activations are pinned to,
+    ``[B(batch axes), S, D]``; the batch is split over its batch axes where
+    they divide it, every other axis sees replicated inputs (heads and
+    sequences are independent, so the per-shard result is exact)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import active_mesh
+    from ..utils.jax_compat import shard_map
+
+    entry = act_sharding.spec[0] if len(act_sharding.spec) else None
+    batch_axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+
+    def kda(q, k, v, g, beta, **kw):
+        use, free = active_mesh(act_sharding.mesh)
+        b_ax = tuple(a for a in batch_axes
+                     if a in free and use.shape[a] > 1)
+        if q.shape[0] % math.prod(use.shape[a] for a in b_ax):
+            b_ax = ()       # uneven batch: replicate, still exact
+        wide, flat = (P(b_ax or None, *[None] * n) for n in (3, 2))
+        return shard_map(
+            functools.partial(chunk_kda, **kw), mesh=use,
+            axis_names=set(free), in_specs=(wide,) * 4 + (flat,),
+            out_specs=wide, check_vma=False)(q, k, v, g, beta)
+
+    return kda
 
 
 def _chunk_kda(q, k, v, g, beta, *, chunk):
@@ -188,19 +236,7 @@ def _chunk_kda(q, k, v, g, beta, *, chunk):
         w = mm(t, k * decay)                            # T (K e^G)
         q_in = (q * decay).astype(dt)
         k_out = (kb * jnp.exp(tail - G)).astype(dt)
-        xs = tuple(jnp.moveaxis(x, 2, 0) for x in (
-            u_v, w.astype(dt), q_in, a_qk.astype(dt), k_out,
-            jnp.exp(jnp.minimum(tail[..., 0, :], 0.0))))
-
-        def step(state, xs):
-            u_v, w, q_in, a_qk, k_out, shrink = xs
-            u = u_v - mm(w, state)
-            o = mm(q_in, state) + mm(a_qk, u)
-            state = state * shrink[..., None] + mm(
-                jnp.swapaxes(k_out, -1, -2), u)
-            return state, o.astype(out_dt)
-
-        _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32), xs)
-    # [N, B, H, C, dv] -> [B, S, H, dv]
-    o = jnp.moveaxis(o, 0, 2)                           # [B, H, N, C, dv]
+        shrink = jnp.exp(jnp.minimum(tail[..., 0, :], 0.0))
+        o = kda_recurrence(u_v, w.astype(dt), q_in, a_qk.astype(dt), k_out,
+                           shrink, out_dtype=out_dt)    # [B, H, N, C, dv]
     return jnp.moveaxis(o, 1, 3).reshape(b, s, h, dv)

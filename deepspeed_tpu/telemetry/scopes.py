@@ -60,7 +60,11 @@ DEVICE_SCOPES = (
 KIND_SCOPES = (
     "ds.kda",          # models/kimi_linear.py _mix: KDA's projections,
     #                    convolutions, gates, norm and output matmul
-    "ds.kda_scan",     # ops/kda.py chunk_kda: the chunked delta rule
+    "ds.kda_scan",     # ops/kda.py chunk_kda: the chunked delta rule (the
+    #                    preparation and the kernels; the recurrence's
+    #                    backward opens it again, outside the forward's)
+    "ds.kda_fwd",      # ops/pallas/kda.py _forward: ds_kda_fwd, either form
+    "ds.kda_bwd",      # ops/pallas/kda.py _backward: ds_kda_bwd
     "ds.mla",          # models/kimi_linear.py _mix: latent attention
     #                    (ds.flash_fwd / ds.flash_bwd inside it)
     "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router

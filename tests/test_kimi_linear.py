@@ -20,7 +20,9 @@ from deepspeed_tpu.models.kimi_linear import stack_plan
 from deepspeed_tpu.moe.sharded_moe import (BIAS_UPDATE_RATE, balance_bias,
                                            held_experts_ffn, moe_ffn_held,
                                            sigmoid_top_k)
+from deepspeed_tpu.ops import kda as kda_ops
 from deepspeed_tpu.ops.kda import chunk_kda, recurrent_kda
+from deepspeed_tpu.ops.pallas import kda as kda_kernels
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.telemetry import scopes
 
@@ -172,26 +174,34 @@ def test_chunked_kda_matches_the_recurrence_forward_and_backward(groups):
         _close(g, r, 2e-5, f"d{name}")
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("per_token", [6.0, 12.0, 20.0])
 def test_chunked_kda_stays_finite_where_a_channel_forgets_in_one_token(
-        per_token):
+        per_token, dtype):
     """Past a log-decay of -5.5 a token a 16-row block's own columns
     overflowed float32 and a training run on the chip went NaN (PR 31):
-    8 rows, a clamped exponent and an exact diagonal hold any decay."""
+    8 rows, a clamped exponent and an exact diagonal hold any decay, and
+    the kernels (PR 32) get their operands from those; in bfloat16 as the
+    cell runs them, too."""
     args = _kda_inputs(b=1, s=128, h=2)
     g = args[3].at[..., 0].set(-per_token).at[..., 1].set(-per_token / 2)
     args[3] = g
+    tol_o, tol_g = 1e-5, 2e-5
+    if dtype == "bfloat16":
+        args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
+    f32 = lambda f: lambda *a: f(*a).astype(jnp.float32)  # noqa: E731
     want = recurrent_kda(*args)
-    got = chunk_kda(*args)
+    got = f32(chunk_kda)(*args)
     assert bool(jnp.all(jnp.isfinite(got)))
-    _close(got, want, 1e-5, "forward")
-    grads = jax.grad(lambda *a: jnp.sum(chunk_kda(*a)),
+    _close(got, want, tol_o, "forward")
+    grads = jax.grad(lambda *a: jnp.sum(f32(chunk_kda)(*a)),
                      argnums=(0, 1, 2, 3, 4))(*args)
     want_g = jax.grad(lambda *a: jnp.sum(recurrent_kda(*a)),
                       argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("qkvgb", grads, want_g):
         assert bool(jnp.all(jnp.isfinite(a))), name
-        _close(a, b, 2e-5, f"d{name}")
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol_g,
+               f"d{name}")
 
 
 def test_chunked_kda_agrees_with_the_benchmarks_recurrence_and_its_shape():
@@ -199,6 +209,148 @@ def test_chunked_kda_agrees_with_the_benchmarks_recurrence_and_its_shape():
     _close(chunk_kda(*args), arch.kda_recurrence(*args), 1e-5)
     with pytest.raises(ValueError, match="multiple of the chunk"):
         chunk_kda(*[a[:, :100] for a in args])
+
+
+# ---- KDA: the kernel pair (interpret mode) ---------------------------------
+def _as_bf16(args):
+    """q, k, v rounded to bfloat16 as the model hands them in; g and beta
+    stay float32."""
+    return [a.astype(jnp.bfloat16) for a in args[:3]] + list(args[3:])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seq", [192, 256])
+@pytest.mark.parametrize("groups", [1, 2])
+def test_kda_kernels_match_the_recurrence(groups, seq, dtype, monkeypatch):
+    """Outputs and all five gradients through ``ds_kda_fwd`` /
+    ``ds_kda_bwd`` with segments of 2 chunks: 4 chunks are two whole
+    segments, 3 are padded with one that leaves the state alone."""
+    monkeypatch.setattr(kda_kernels, "SEG", 2)
+    args = _kda_inputs(s=seq, h=4)
+    tol_o, tol_g = 1e-5, 2e-5
+    if dtype == "bfloat16":
+        args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
+    want = recurrent_kda(*args)
+    got = chunk_kda(*args, head_groups=groups)
+    assert got.dtype == args[2].dtype
+    _close(got.astype(jnp.float32), want, tol_o, "forward")
+    w = jnp.asarray(np.random.default_rng(1).normal(size=want.shape),
+                    jnp.float32)
+    grad = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    for name, g, r in zip("qkvgb", grad(
+            lambda *a: chunk_kda(*a, head_groups=groups)),
+            grad(recurrent_kda)):
+        assert g.dtype == r.dtype
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        _close(g.astype(jnp.float32), r.astype(jnp.float32), tol_g,
+               f"d{name}")
+
+
+def _old_step_recurrence(u_v, w, q_in, a_qk, k_out, shrink, out_dtype):
+    """The ``lax.scan`` form ``_chunk_kda`` held before the kernels (PR 31),
+    on flat heads [BH, N, C, .]: the reference of the six cotangents."""
+    dt = w.dtype
+    mm = lambda x, y: jnp.matmul(  # noqa: E731
+        x.astype(dt), y.astype(dt), preferred_element_type=jnp.float32)
+
+    def step(state, xs):
+        u_v, w, q_in, a_qk, k_out, shrink = xs
+        u = u_v - mm(w, state)
+        o = mm(q_in, state) + mm(a_qk, u)
+        state = state * shrink[..., None] + mm(
+            jnp.swapaxes(k_out, -1, -2), u)
+        return state, o.astype(out_dtype)
+
+    xs = tuple(jnp.swapaxes(x, 0, 1)
+               for x in (u_v, w, q_in, a_qk, k_out, shrink))
+    init = jnp.zeros((w.shape[0], w.shape[-1], u_v.shape[-1]), jnp.float32)
+    _, o = jax.lax.scan(step, init, xs)
+    return jnp.swapaxes(o, 0, 1)
+
+
+@pytest.mark.parametrize("chunks", [4, 5])
+@pytest.mark.parametrize("heads_a_step", [1, 2])
+def test_kda_backward_kernel_alone_matches_the_old_steps_vjp(
+        heads_a_step, chunks, monkeypatch):
+    """``ds_kda_bwd`` on segment checkpoints of ``ds_kda_fwd``'s second
+    form against ``jax.vjp`` of the scan step, cotangent by cotangent; the
+    checkpoints are the states the scan carries into each segment."""
+    monkeypatch.setattr(kda_kernels, "SEG", 2)
+    monkeypatch.setattr(kda_kernels, "HEADS", heads_a_step)
+    bh, c, dk, dv = 4, 16, 32, 16
+    rng = np.random.default_rng(chunks)
+    rn = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    ops = (rn(bh, chunks, c, dv), 0.3 * rn(bh, chunks, c, dk),
+           rn(bh, chunks, c, dk), 0.3 * rn(bh, chunks, c, c),
+           0.3 * rn(bh, chunks, c, dk),
+           jnp.asarray(rng.uniform(0.2, 1.0, (bh, chunks, dk)), jnp.float32))
+    do = rn(bh, chunks, c, dv)
+    want_o, pull = jax.vjp(
+        lambda *x: _old_step_recurrence(*x, jnp.float32), *ops)
+    _close(kda_kernels._forward(ops, jnp.float32, states=False), want_o,
+           1e-5, "o")
+    ck = kda_kernels._forward(ops, jnp.float32, states=True)
+    assert ck.shape == (bh, -(-chunks // 2), dv, dk)
+    assert not np.asarray(ck[:, 0]).any()       # S = 0 before chunk 0
+    state = jnp.zeros((bh, dk, dv))
+    for n in range(2):                          # the state into segment 1
+        u = ops[0][:, n] - ops[1][:, n] @ state
+        state = state * ops[5][:, n][..., None] + jnp.swapaxes(
+            ops[4][:, n], -1, -2) @ u
+    _close(jnp.swapaxes(ck[:, 1], -1, -2), state, 1e-5, "checkpoint 1")
+    got = kda_kernels._backward(ops, ck, do)
+    for name, g, r, x in zip(("du_v", "dw", "dq_in", "da_qk", "dk_out",
+                              "dshrink"), got, pull(do), ops):
+        assert g.shape == x.shape and g.dtype == x.dtype, name
+        _close(g, r, 2e-5, name)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr``, through its sub-jaxprs (scan, map,
+    remat, custom_vjp) but not into a Pallas kernel's body, whose values
+    are VMEM."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _walk_eqns(sub)
+
+
+def test_no_state_history_reaches_hbm_only_the_segment_checkpoints():
+    """``jax.vjp(chunk_kda)``, forward and backward in one jaxpr: nothing
+    shaped [..., dk, dv] (or transposed) outside the kernels is larger than
+    the segment checkpoints, where the scan's autodiff stacked a state a
+    chunk (``SEG`` times as much)."""
+    # a value width that no chunk or row block of the scores (8 to 64) has
+    b, s, h, dk, dv = 1, 64 * 2 * kda_kernels.SEG, 2, 32, 20
+    args = _kda_inputs(b=b, s=s, h=h, dk=dk, dv=dv)
+
+    def both(*a):
+        o, pull = jax.vjp(lambda *x: chunk_kda(*x, head_groups=2), *a)
+        return pull(jnp.ones_like(o))
+
+    avals = [v.aval for e in _walk_eqns(jax.make_jaxpr(both)(*args).jaxpr)
+             for v in e.outvars]
+    states = [a for a in avals if getattr(a, "shape", ())[-2:]
+              in ((dk, dv), (dv, dk)) and len(a.shape) >= 3]
+    checkpoints = b * (h // 2) * 2 * dk * dv        # a group's: 2 segments
+    assert states and max(int(np.prod(a.shape)) for a in states) \
+        == checkpoints
+    assert all(a.dtype == jnp.float32 for a in states)
+    # and the scan is gone: a group runs one kernel and no loop
+    names = [e.primitive.name for e in _walk_eqns(jax.make_jaxpr(
+        lambda *a: kda_ops._chunk_kda(*a, chunk=64))(*args).jaxpr)]
+    assert "scan" not in names and "while" not in names
+    assert names.count("pallas_call") == 1
+
+
+def test_kda_kernels_refuse_on_the_chip_what_mosaic_cannot_tile(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="multiples of 128"):
+        kda_kernels._check_chip_shapes(64, 32, 128)
+    kda_kernels._check_chip_shapes(64, 128, 256)
 
 
 # ---- MLA: the flash path (key 24, value 16) against plain softmax ----------
@@ -492,6 +644,14 @@ def test_step_scopes_are_the_lists(kimi_engine):
     for scope in ("ds.kda_scan", "ds.flash_bwd", "ds.moe_experts"):
         assert any(p.startswith("bwd:ds.layers") and scope in p
                    for p in paths), scope
+    # the KDA kernels: the forward under fwd: and, run again by remat,
+    # under bwd:; its checkpoint form and the backward kernel under bwd:;
+    # every one of them inside ds.kda_scan, which kda_ms.kda reads
+    kernels = {p for p in paths if re.search(r"ds\.kda_(fwd|bwd)\b", p)}
+    assert all("ds.kda/ds.kda_scan/" in p for p in kernels), kernels
+    assert {p.split(":")[0] for p in kernels if "ds.kda_fwd" in p} \
+        == {"fwd", "bwd"}
+    assert {p.split(":")[0] for p in kernels if "ds.kda_bwd" in p} == {"bwd"}
 
 
 # ---- the one-kind scan is the parent's program -----------------------------

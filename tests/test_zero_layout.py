@@ -190,3 +190,66 @@ CASES = {
 @pytest.mark.parametrize("case", list(CASES))
 def test_layer_scan_carry_is_pinned_to_the_batch_axes(case, monkeypatch):
     CASES[case](monkeypatch)
+
+
+def test_kda_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
+    """The KDA kernel pair (PR 32) at the cell's widths, compiled by Mosaic
+    for one described v5e chip, and ``chunk_kda``'s gradient on ``v5e:2x2``
+    with the batch over ``fsdp``: GSPMD cannot partition a Mosaic call, so
+    on a mesh the model runs the kernels per shard
+    (``ops.kda.sharded_chunk_kda``). Here, and not beside the other KDA
+    tests, because one process a run may describe a topology."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.ops import kda
+    from deepspeed_tpu.ops.pallas import kda as kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    # the kernels alone: a head group of the cell (8 heads x 256 chunks)
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    bh, n, c, d = 8, 256, 64, 128
+    ops = (sd((bh, n, c, d), f32), sd((bh, n, c, d), bf),
+           sd((bh, n, c, d), bf), sd((bh, n, c, c), bf),
+           sd((bh, n, c, d), bf), sd((bh, n, d), f32))
+    ck = sd((bh, n // kernels.SEG, d, d), f32)
+    for name, fn, args in (
+            ("ds_kda_fwd", lambda *o: kernels._forward(o, bf, states=False),
+             ops),
+            ("ds_kda_fwd", lambda *o: kernels._forward(o, bf, states=True),
+             ops),
+            ("ds_kda_bwd", lambda *a: kernels._backward(a[:6], a[6], a[7]),
+             (*ops, ck, sd((bh, n, c, d), bf)))):
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+        assert re.search(rf"%{name}[.\w]* = .*custom-call", hlo), name
+    with pytest.raises(ValueError, match="multiples of 128"):
+        jax.jit(lambda *o: kernels._forward(o, bf, states=False)).lower(
+            *(sd(x.shape[:-1] + (64,), x.dtype) for x in ops))
+
+    # the whole chunk_kda, forward and backward, a sequence a chip
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    act = mt.sharding(mt.batch_axes(), "sp")
+    row = lambda *s, dt=bf: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=NamedSharding(
+            mt.mesh, P(mt.batch_axes(), *[None] * (len(s) - 1))))
+    b, s, h = 4, 2048, 4
+    args = (row(b, s, h, d), row(b, s, h, d), row(b, s, h, d),
+            row(b, s, h, d, dt=f32), row(b, s, h, dt=f32))
+    loss = lambda fn: lambda *a: jnp.sum(  # noqa: E731
+        fn(*a, head_groups=2).astype(f32))
+    hlo = jax.jit(jax.grad(loss(kda.sharded_chunk_kda(act)),
+                           argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    assert len(re.findall(r"%ds_kda_fwd[.\w]* = ", hlo)) >= 1
+    assert len(re.findall(r"%ds_kda_bwd[.\w]* = ", hlo)) >= 1
+    assert "all-to-all" not in hlo and "all-gather" not in hlo
+    with pytest.raises(Exception, match="[Mm]osaic"):
+        jax.jit(jax.grad(loss(kda.chunk_kda))).lower(*args).compile()

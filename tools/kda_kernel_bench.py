@@ -1,0 +1,197 @@
+"""The two KDA kernels alone, on the chip: time and results of this
+checkout's ``ops/pallas/kda.py`` against the ``lax.scan`` form it replaced.
+
+    chiprun -- python tools/kda_kernel_bench.py [HEADS ...]
+
+Sizes a change to the kernels before a cell is run (PR 32). Shapes are
+the cell's (one sequence of 16384, heads of 128, chunks of 64) at each
+HEADS (default 8, a head group of the cell, and 32, a layer). A line a
+head count:
+
+- ``kernel_ms``: the forward kernel, its checkpoint form and the backward
+  kernel, each the mean duration of the ``tpu_custom_call`` events of a
+  profiler trace of 10 calls;
+- ``scan_ms``: the recurrence in the parent's form (``scan_recurrence``
+  below, a copy: ``jax.lax.scan`` over the chunks, autodiff backward) on
+  the same operands, forward alone and forward with backward, device busy
+  time a call;
+- ``chunk_kda_ms`` (at 8 heads): ``jax.grad`` of one head group of
+  ``ops.kda.chunk_kda`` (the preparation under its ``jax.checkpoint``, so
+  with its rerun) with the kernels and with the scan in their place:
+  device busy time a call, the part inside ``while`` ops (the scan's two
+  loops) and inside the kernels; the rest is the preparation;
+- ``err``: the kernels' ``o`` and six cotangents against the scan's,
+  largest difference over the largest value.
+
+A device number, so only on a TPU. Not the yardstick: what a user feels
+is ``benchmark/run.py``.
+"""
+
+from __future__ import annotations
+
+import glob
+import json
+import os
+import re
+import shutil
+import sys
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+SEQ, D, CHUNK, CALLS = 16384, 128, 64, 10
+TRACE_DIR = os.path.join(ROOT, ".bench_trace", "kda_kernel_bench")
+
+
+def scan_recurrence(u_v, w, q_in, a_qk, k_out, shrink, *, out_dtype):
+    """``_chunk_kda``'s recurrence as it stood before PR 32: operands
+    [B, H, N, C, .], one scan step a chunk, the state [B, H, dk, dv]."""
+    import jax
+    import jax.numpy as jnp
+    dt = w.dtype
+    mm = lambda x, y: jnp.matmul(  # noqa: E731
+        x.astype(dt), y.astype(dt), preferred_element_type=jnp.float32)
+
+    def step(state, xs):
+        u_v, w, q_in, a_qk, k_out, shrink = xs
+        u = u_v - mm(w, state)
+        o = mm(q_in, state) + mm(a_qk, u)
+        state = state * shrink[..., None] + mm(
+            jnp.swapaxes(k_out, -1, -2), u)
+        return state, o.astype(out_dtype)
+
+    b, h, _, _, dk = w.shape
+    xs = tuple(jnp.moveaxis(x, 2, 0)
+               for x in (u_v, w, q_in, a_qk, k_out, shrink))
+    _, o = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, u_v.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def inputs(heads: int, seed: int = 32):
+    """q, k, v (bf16), g, beta (float32) [1, SEQ, heads, D] as the model's
+    mixer makes them: unit keys, decays from 1e-3 to 1.6 a token."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    shape = (1, SEQ, heads, D)
+    unit = lambda x: x / np.sqrt((x ** 2).sum(-1, keepdims=True) + 1e-6)  # noqa: E731
+    q = unit(rng.normal(size=shape)) / np.sqrt(D)
+    k = unit(rng.normal(size=shape))
+    v = rng.normal(size=shape)
+    g = -np.exp(rng.uniform(-7, 0.5, size=shape))
+    beta = 1 / (1 + np.exp(-rng.normal(size=shape[:3])))
+    return ([jnp.asarray(x, jnp.bfloat16) for x in (q, k, v)]
+            + [jnp.asarray(x, jnp.float32) for x in (g, beta)])
+
+
+def traced(jax, fn, args):
+    """[(name, start, end)] of device 0's ops over CALLS calls of fn."""
+    for _ in range(2):
+        jax.block_until_ready(fn(*args))
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    jax.profiler.start_trace(TRACE_DIR)
+    for _ in range(CALLS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    newest = sorted(glob.glob(os.path.join(
+        TRACE_DIR, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in jax.profiler.ProfileData.from_file(newest).planes
+            if plane.name.startswith("/device:TPU:0")
+            for line in plane.lines if line.name == "XLA Ops"
+            for e in line.events]
+
+
+def busy_ms(events, pattern: str = "") -> float:
+    """Union of the matching events' intervals, ms a call."""
+    rx, total, end = re.compile(pattern), 0, 0
+    for _, a, b in sorted((e for e in events if rx.search(e[0])),
+                          key=lambda e: e[1]):
+        total += max(b, end) - max(a, end)
+        end = max(b, end)
+    return 1e-6 * total / CALLS
+
+
+def kernel_ms(events) -> float:
+    ds = [b - a for name, a, b in events if "tpu_custom_call" in name]
+    return 1e-6 * sum(ds) / max(len(ds), 1)
+
+
+def rel_err(a, b) -> float:
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.abs(a - b).max() / (np.abs(b).max() + 1e-30))
+
+
+def main(argv) -> int:
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops import kda
+    from deepspeed_tpu.ops.pallas import kda as kernels
+    kernel_recurrence = kda.kda_recurrence
+    for heads in [int(a) for a in argv] or [8, 32]:
+        args = inputs(heads)
+        got = []
+
+        def grab(*ops, out_dtype):
+            got.append(ops)
+            return jnp.zeros(ops[0].shape, out_dtype)
+
+        kda.kda_recurrence = grab
+        ops = jax.jit(lambda *a: (kda._chunk_kda(*a, chunk=CHUNK),
+                                  got[-1])[1])(*args)
+        do = jnp.asarray(np.random.default_rng(1).normal(
+            size=ops[0].shape), jnp.bfloat16)
+        flat = tuple(x.reshape(-1, *x.shape[2:]) for x in ops)
+        bf = jnp.bfloat16
+        fwd = jax.jit(lambda *o: kernels._forward(o, bf, states=False))
+        states = jax.jit(lambda *o: kernels._forward(o, bf, states=True))
+        bwd = jax.jit(lambda *a: kernels._backward(a[:6], a[6], a[7]))
+        ck = states(*flat)
+        vjp = lambda f: jax.jit(lambda *a: (  # noqa: E731
+            lambda o, pull: (o, *pull(a[6])))(
+                *jax.vjp(lambda *x: f(*x, out_dtype=bf), *a[:6])))
+        scan_fwd = jax.jit(lambda *o: scan_recurrence(*o, out_dtype=bf))
+        line = {"heads": heads, "seg": kernels.SEG,
+                "heads_a_step": kernels.HEADS,
+                "kernel_ms": {
+                    "fwd": kernel_ms(traced(jax, fwd, flat)),
+                    "states": kernel_ms(traced(jax, states, flat)),
+                    "bwd": kernel_ms(traced(
+                        jax, bwd, (*flat, ck, do.reshape(flat[0].shape))))},
+                "scan_ms": {
+                    "fwd": busy_ms(traced(jax, scan_fwd, ops)),
+                    "fwd_bwd": busy_ms(traced(jax, vjp(scan_recurrence),
+                                              (*ops, do)))},
+                "err": dict(zip(
+                    ("o", "du_v", "dw", "dq_in", "da_qk", "dk_out",
+                     "dshrink"),
+                    map(rel_err, vjp(kernel_recurrence)(*ops, do),
+                        vjp(scan_recurrence)(*ops, do))))}
+        del ops, flat, ck, do
+        if heads == 8:      # a head group of the cell, without lax.map
+            wgt = jnp.asarray(np.random.default_rng(2).normal(
+                size=args[2].shape), jnp.bfloat16)
+            whole = {}
+            for name, f in (("kernels", kernel_recurrence),
+                            ("scan", scan_recurrence)):
+                kda.kda_recurrence = f
+                # a new function a form: jax.checkpoint keeps its trace
+                group = jax.checkpoint(
+                    lambda *a: kda._chunk_kda(*a, chunk=CHUNK))
+                grad = jax.jit(jax.grad(
+                    lambda *a: jnp.sum(group(*a).astype(jnp.float32) * wgt),
+                    argnums=(0, 1, 2, 3, 4)))
+                ev = traced(jax, grad, args)
+                whole[name] = {"busy": busy_ms(ev),
+                               "while": busy_ms(ev, r"^%?while"),
+                               "kernels": busy_ms(ev, "tpu_custom_call")}
+            kda.kda_recurrence = kernel_recurrence
+            line["chunk_kda_ms"] = whole
+        print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
