@@ -5,6 +5,7 @@ from .falcon import Falcon, falcon_config  # noqa: F401
 from .gpt2 import GPT2, gpt2_config  # noqa: F401
 from .gptj import GPTJ, gptj_config  # noqa: F401
 from .gptneox import GPTNeoX, gptneox_config  # noqa: F401
+from .granite_hybrid import GraniteHybrid, granite_hybrid_config  # noqa: F401
 from .internlm import InternLM, internlm_config  # noqa: F401
 from .kimi_linear import KimiLinear, kimi_linear_config  # noqa: F401
 from .llama import Llama, llama_config  # noqa: F401

@@ -35,7 +35,9 @@ class ModelConfig:
     # architecture switches
     norm_type: str = "layernorm"        # layernorm | rmsnorm
     activation: str = "gelu"            # gelu | relu | swiglu
-    position_embedding: str = "learned"  # learned | rope | alibi (Bloom)
+    position_embedding: str = "learned"  # learned | rope | alibi (Bloom);
+    #                                      anything else ("none", "nope"):
+    #                                      the model has no positions
     use_bias: bool = True
     attn_qkv_bias: bool = False     # qkv biases even when use_bias=False
     #                                 (Qwen-style)
@@ -86,6 +88,26 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     mla_use_nope: bool = False      # no rotation on either part of q, k
+    # Mamba-2 state-space layers among attention layers
+    # (models/granite_hybrid.py, ops/ssd.py); key names as published
+    layer_types: tuple | list = ()  # "mamba" | "attention" a layer; empty =
+    #                                 one kind of layer
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1         # groups of heads sharing B and C
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_expand: int = 2           # n_heads * d_head = expand * hidden_size
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # the four muP multipliers of the Granite families, read by
+    # models/granite_hybrid.py alone: 1 (None) where a model has none
+    embedding_multiplier: float = 1.0   # x0 = embed[tokens] * this
+    residual_multiplier: float = 1.0    # x + this * sublayer(norm(x))
+    logits_scaling: float = 1.0         # logits / this
+    attention_multiplier: float | None = None   # the softmax scale; None =
+    #                                             head_dim ** -0.5
     # numerics
     param_dtype: Any = None   # set to jnp dtype in __post_init__
     loss_chunk: int = 0       # >0: fused chunked cross-entropy (tokens per
@@ -105,6 +127,7 @@ class ModelConfig:
             self.num_kv_heads = self.num_heads
         self.kda_layers = tuple(self.kda_layers)
         self.full_attn_layers = tuple(self.full_attn_layers)
+        self.layer_types = list(self.layer_types)   # as JSON has it
 
     @property
     def head_dim(self) -> int:
@@ -170,6 +193,22 @@ class ModelConfig:
                 "moe": (d * self.num_experts + self.num_experts
                         + 3 * d * fe * self.moe_num_shared_experts)}
 
+    # ---- Mamba-2 among attention layers (layer_types) ------------------
+    def _hybrid_params(self) -> int:
+        """Embedding, head, norms and the layers of ``layer_types``, as
+        models/granite_hybrid.py builds them (every layer with a SwiGLU)."""
+        d, v, h = self.hidden_size, self.vocab_size, self.mamba_n_heads
+        inner = h * self.mamba_d_head
+        conv = inner + 2 * self.mamba_n_groups * self.mamba_d_state
+        per = {"mamba": (d * (inner + conv + h)          # z | xBC | dt
+                         + (self.mamba_d_conv + self.mamba_conv_bias) * conv
+                         + 3 * h + inner + inner * d),   # A, D, dt_bias, norm
+               "attention": 2 * d * self.head_dim * (self.num_heads
+                                                     + self.num_kv_heads)}
+        n = v * d * (1 if self.tie_embeddings else 2) + d
+        return n + sum(per[t] + 3 * d * self.intermediate_size + 2 * d
+                       for t in self.layer_types)
+
     @property
     def held_experts(self) -> int:
         """Routed experts held here: all of them unless told a share."""
@@ -195,6 +234,8 @@ class ModelConfig:
     def num_params(self) -> int:
         """Analytic parameter count (embedding + layers + final norm),
         matching the trees the model's ``init`` builds exactly."""
+        if self.layer_types:
+            return self._hybrid_params()
         kinds = self.layer_kinds()
         if kinds is not None:
             return self._stack_params(kinds, active=False)
@@ -276,6 +317,19 @@ class ModelConfig:
                 ctx = (s + 1) / 2
         else:
             ctx = s
+        if self.layer_types:
+            # an attention layer multiplies a key and a value of head_dim
+            # a visible pair; a Mamba head writes and reads its [P, N]
+            # state once a token (2 products of 2 P N FLOPs); x3 training.
+            # A tied table is the head's matmul; an untied one's gather is
+            # not a matmul
+            attn = 12 * self.num_heads * self.head_dim * ctx
+            ssd = 12 * self.mamba_n_heads * self.mamba_d_head \
+                * self.mamba_d_state
+            if not self.tie_embeddings:
+                n -= self.vocab_size * self.hidden_size
+            return 6 * n + sum(ssd if t == "mamba" else attn
+                               for t in self.layer_types)
         kinds = self.layer_kinds()
         if kinds is not None:
             # latent attention multiplies a key of qk width and a value

@@ -35,11 +35,9 @@ the optimizer off the bias; ``loss(with_stats=True)`` also returns every
 routed layer's load by expert, and ``after_step`` (the engine calls it
 with the updated weights) moves each bias against its expert's load.
 
-**The stack.** Parameters are stacked by kind: ``layers`` holds ``lead``
-(the leading dense layers, unrolled), ``period`` (the layers of ONE period
-of the pattern, each stacked over the whole periods, run under one
-``lax.scan``) and ``tail`` (what does not fill a period, unrolled); a
-layer's kind is read from the keys it holds.
+**The stack** is ``models/stack.py``'s: parameters stacked by kind
+(``lead`` the leading dense layers, ``period``, ``tail``), a layer's kind
+read from the keys it holds.
 """
 
 from __future__ import annotations
@@ -51,8 +49,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import ModelConfig, register_model
-from .transformer import (DecoderLM, _dense_init, _remat_policy,
-                          _unpack_batch)
+from .stack import StackOfKinds
+from .transformer import _dense_init
 
 _PUBLISHED = dict(
     hidden_size=2304, intermediate_size=9216, num_heads=32, num_kv_heads=32,
@@ -91,25 +89,8 @@ def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
     return ModelConfig(**base)
 
 
-def stack_plan(kinds: list, lead: int) -> tuple[int, int, int]:
-    """(layers a period, whole periods, layers left over) of the kinds
-    after the ``lead`` leading layers: the period whose whole repeats (two
-    at least) cover most layers, the shortest such; what follows them is
-    left over, and so is everything where nothing repeats."""
-    rest = kinds[lead:]
-    best = (0, 0)
-    for p in range(1, len(rest) // 2 + 1):
-        n = 1
-        while rest[n * p:(n + 1) * p] == rest[:p]:
-            n += 1
-        if n >= 2 and n * p > best[0] * best[1]:
-            best = (p, n)
-    p, n = best
-    return p, n, len(rest) - p * n
-
-
 @register_model("kimi_linear")
-class KimiLinear(DecoderLM):
+class KimiLinear(StackOfKinds):
     def __init__(self, config: ModelConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
@@ -130,10 +111,7 @@ class KimiLinear(DecoderLM):
             raise ValueError(
                 f"{config.held_experts} experts held of the router's "
                 f"{config.num_experts}")
-        super().__init__(config)
-        self.kinds = kinds
-        self.lead = min(config.first_k_dense_replace, len(kinds))
-        self.period, self.repeats, self.left = stack_plan(kinds, self.lead)
+        super().__init__(config, kinds, lead=config.first_k_dense_replace)
 
     def optimizer_frozen(self) -> str:
         """Leaves the optimizer leaves alone (the engine zeroes their
@@ -254,20 +232,9 @@ class KimiLinear(DecoderLM):
         dt = c.param_dtype
         d, v = c.hidden_size, c.vocab_size
         keys = jax.random.split(rng, 4)
-        lk = iter(jax.random.split(keys[0], len(self.kinds)))
-        at = self.lead + self.period * self.repeats
-        layers = {
-            "lead": {str(i): self._init_layer(next(lk), self.kinds[i])
-                     for i in range(self.lead)},
-            "period": {str(j): self._init_layer(
-                next(lk), self.kinds[self.lead + j], (self.repeats,))
-                for j in range(self.period if self.repeats else 0)},
-            "tail": {str(i): self._init_layer(next(lk), self.kinds[at + i])
-                     for i in range(self.left)},
-        }
         return {
             "embed": {"tokens": _dense_init(keys[1], (v, d), 0.02, dt)},
-            "layers": layers,
+            "layers": self._init_layers(keys[0]),
             "final_norm": {"scale": jnp.ones((d,), dt)},
             "lm_head": _dense_init(keys[2], (d, v), 0.02, dt),
         }
@@ -279,20 +246,15 @@ class KimiLinear(DecoderLM):
         nh, dk = c.kda_num_heads, c.kda_head_dim
         f32 = jnp.float32
 
-        def conv(x, w):     # causal depthwise, width kda_conv_size
-            n = w.shape[0]
-            xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-            return sum(xp[:, i:i + s] * w[i] for i in range(n))
-
         def l2norm(x):
             x = x.astype(f32)
             return x * jax.lax.rsqrt(
                 jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
         heads = lambda x: x.reshape(b, s, nh, dk)  # noqa: E731
-        q = heads(L.silu(conv(h @ p["wq"], p["conv_q"])))
-        k = heads(L.silu(conv(h @ p["wk"], p["conv_k"])))
-        v = heads(L.silu(conv(h @ p["wv"], p["conv_v"])))
+        q = heads(L.silu(L.causal_conv(h @ p["wq"], p["conv_q"])))
+        k = heads(L.silu(L.causal_conv(h @ p["wk"], p["conv_k"])))
+        v = heads(L.silu(L.causal_conv(h @ p["wv"], p["conv_v"])))
         q = (l2norm(q) * dk ** -0.5).astype(h.dtype)
         k = l2norm(k).astype(h.dtype)
         beta = jax.nn.sigmoid((h @ p["w_b"]).astype(f32))
@@ -351,81 +313,23 @@ class KimiLinear(DecoderLM):
         y, counts = self._routed(p["moe"], h)
         return x + y, counts
 
-    def _layer(self, p, x, mixers, scanned: bool):
-        """One layer of the kind its keys name, as (x, counts); rematted
-        whole. An unrolled layer's checkpoint has to prevent CSE, or XLA
-        merges the recomputation with the forward pass and keeps every
-        intermediate alive; under the scan the loop boundary does that."""
-        c = self.config
-        layer = lambda p, x: self._channel(  # noqa: E731
-            p, self._mix(p, x, *mixers))
-        if not c.remat:
-            return layer(p, x)
-        return jax.checkpoint(layer, prevent_cse=not scanned,
-                              policy=_remat_policy(c.remat_policy))(p, x)
+    def _one_layer(self, p, x, mixers):
+        return self._channel(p, self._mix(p, x, *mixers))
 
-    def _layer_stack(self, layers, x, pin, *, attn_fn, positions,
-                     act_sharding=None):
-        """(x, stats): ``stats[group][slot]`` are the counts of each
-        routed layer (``moe_ffn_held``), a ``period`` slot's stacked over
-        the repeats as its parameters are. On a mesh of more than one
-        device (``act_sharding``) the KDA kernels run per shard."""
+    def _mixers(self, attn_fn, act_sharding):
+        """(attention, KDA): on a mesh of more than one device the KDA
+        kernels run per shard of ``act_sharding``."""
         from ..ops.kda import chunk_kda, sharded_chunk_kda
-        if attn_fn is None:
-            if self.config.attn_impl == "flash":
-                from ..ops.pallas.flash_attention import flash_attention
-                attn_fn = flash_attention
-            else:
-                attn_fn = L.dot_product_attention
-        mixers = (attn_fn, chunk_kda if act_sharding is None
-                  else sharded_chunk_kda(act_sharding))
-        stats = {"lead": {}, "period": {}, "tail": {}}
-
-        def unrolled(group, n, x):
-            for i in range(n):
-                x, stats[group][str(i)] = self._layer(
-                    layers[group][str(i)], x, mixers, False)
-                x = pin(x)
-            return x
-
-        x = unrolled("lead", self.lead, x)
-        if self.repeats:
-            def body(x, slots):
-                counts = {}
-                for j in range(self.period):
-                    x, counts[str(j)] = self._layer(
-                        slots[str(j)], x, mixers, True)
-                    x = pin(x)
-                return x, counts
-
-            x, stats["period"] = jax.lax.scan(body, x, layers["period"])
-        x = unrolled("tail", self.left, x)
-        return x, {g: {k: v for k, v in slots.items() if v}
-                   for g, slots in stats.items()}
+        return (attn_fn, chunk_kda if act_sharding is None
+                else sharded_chunk_kda(act_sharding))
 
     def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
              with_stats: bool = False):
         """Mean cross-entropy (no auxiliary term); ``with_stats`` also
         returns the routed layers' counts, for ``after_step``."""
-        tokens, targets = _unpack_batch(batch)
-        x, stats = self._final_hidden(params, tokens, attn_fn=attn_fn,
-                                      act_sharding=act_sharding)
-        with jax.named_scope("ds.loss_head"):
-            if self.config.loss_chunk > 0:
-                ce = self._chunked_ce(params, x, targets)
-            else:
-                ce = L.cross_entropy_loss(
-                    self._project_vocab(params, x), targets)
+        ce, stats = self._loss_and_stats(params, batch, attn_fn=attn_fn,
+                                         act_sharding=act_sharding)
         return (ce, stats) if with_stats else ce
-
-    # the serving and pipeline paths assume one kind of layer and a KV cache
-    def _one_kind_only(self, *a, **kw):
-        raise NotImplementedError(
-            "KimiLinear runs through apply/loss only: a latent cache and "
-            "recurrent KDA state are not in inference/, and a stack of "
-            "kinds has no single block()")
-
-    block = block_decode = decode = init_cache = _one_kind_only
 
     # ---------------- sharding ----------------
     def partition_rules(self):

@@ -1,0 +1,140 @@
+"""A stack of more than one kind of layer: what the families that have one
+share (``models/kimi_linear.py``: KDA and latent attention over dense and
+routed channels; ``models/granite_hybrid.py``: Mamba-2 and grouped-query
+attention).
+
+Parameters are stacked by kind: ``layers`` holds ``lead`` (the leading
+layers, unrolled), ``period`` (the layers of ONE period of the pattern,
+each stacked over the whole periods, run under one ``lax.scan``) and
+``tail`` (what does not fill a period, unrolled); a layer's kind is read
+from the keys it holds. Every layer is rematted whole. A family gives its
+``kinds`` (one hashable a layer) and three methods: ``_init_layer(key,
+kind, lead_shape)``, ``_mixers(attn_fn, act_sharding)`` (what its layers
+call to mix tokens, with the kernels imported there and not at import)
+and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``.
+"""
+
+from __future__ import annotations
+
+import jax
+
+from ..ops import layers as L
+from .transformer import DecoderLM, _remat_policy, _unpack_batch
+
+
+def stack_plan(kinds: list, lead: int) -> tuple[int, int, int]:
+    """(layers a period, whole periods, layers left over) of the kinds
+    after the ``lead`` leading layers: the period whose whole repeats (two
+    at least) cover most layers, the shortest such; what follows them is
+    left over, and so is everything where nothing repeats."""
+    rest = kinds[lead:]
+    best = (0, 0)
+    for p in range(1, len(rest) // 2 + 1):
+        n = 1
+        while rest[n * p:(n + 1) * p] == rest[:p]:
+            n += 1
+        if n >= 2 and n * p > best[0] * best[1]:
+            best = (p, n)
+    p, n = best
+    return p, n, len(rest) - p * n
+
+
+class StackOfKinds(DecoderLM):
+    def __init__(self, config, kinds: list, lead: int = 0):
+        super().__init__(config)
+        self.kinds = kinds
+        self.lead = min(lead, len(kinds))
+        self.period, self.repeats, self.left = stack_plan(kinds, self.lead)
+
+    def _init_layers(self, key):
+        """``layers`` by group, a key a layer in the order they run."""
+        lk = iter(jax.random.split(key, len(self.kinds)))
+        at = self.lead + self.period * self.repeats
+        return {
+            "lead": {str(i): self._init_layer(next(lk), self.kinds[i])
+                     for i in range(self.lead)},
+            "period": {str(j): self._init_layer(
+                next(lk), self.kinds[self.lead + j], (self.repeats,))
+                for j in range(self.period if self.repeats else 0)},
+            "tail": {str(i): self._init_layer(next(lk), self.kinds[at + i])
+                     for i in range(self.left)},
+        }
+
+    def _attn(self, attn_fn):
+        """The attention a layer of the stack calls: full causal."""
+        if attn_fn is not None:
+            return attn_fn
+        if self.config.attn_impl == "flash":
+            from ..ops.pallas.flash_attention import flash_attention
+            return flash_attention
+        return L.dot_product_attention
+
+    def _layer(self, p, x, mixers, scanned: bool):
+        """One layer of the kind its keys name, as (x, counts); rematted
+        whole. An unrolled layer's checkpoint has to prevent CSE, or XLA
+        merges the recomputation with the forward pass and keeps every
+        intermediate alive; under the scan the loop boundary does that."""
+        c = self.config
+        layer = lambda p, x: self._one_layer(p, x, mixers)  # noqa: E731
+        if not c.remat:
+            return layer(p, x)
+        return jax.checkpoint(layer, prevent_cse=not scanned,
+                              policy=_remat_policy(c.remat_policy))(p, x)
+
+    def _layer_stack(self, layers, x, pin, *, attn_fn, positions,
+                     act_sharding=None):
+        """(x, stats): ``stats[group][slot]`` are the counts of each layer
+        that counts anything (a routed one), a ``period`` slot's stacked
+        over the repeats as its parameters are. On a mesh of more than one
+        device (``act_sharding``) a family's kernels run per shard."""
+        mixers = self._mixers(self._attn(attn_fn), act_sharding)
+        stats = {"lead": {}, "period": {}, "tail": {}}
+
+        def unrolled(group, n, x):
+            for i in range(n):
+                x, stats[group][str(i)] = self._layer(
+                    layers[group][str(i)], x, mixers, False)
+                x = pin(x)
+            return x
+
+        x = unrolled("lead", self.lead, x)
+        if self.repeats:
+            def body(x, slots):
+                counts = {}
+                for j in range(self.period):
+                    x, counts[str(j)] = self._layer(
+                        slots[str(j)], x, mixers, True)
+                    x = pin(x)
+                return x, counts
+
+            x, stats["period"] = jax.lax.scan(body, x, layers["period"])
+        x = unrolled("tail", self.left, x)
+        return x, {g: {k: v for k, v in slots.items() if v}
+                   for g, slots in stats.items()}
+
+    def _loss_and_stats(self, params, batch, *, attn_fn=None,
+                        act_sharding=None):
+        """(mean cross-entropy, no auxiliary term; the layers' counts)."""
+        tokens, targets = _unpack_batch(batch)
+        x, stats = self._final_hidden(params, tokens, attn_fn=attn_fn,
+                                      act_sharding=act_sharding)
+        with jax.named_scope("ds.loss_head"):
+            if self.config.loss_chunk > 0:
+                ce = self._chunked_ce(params, x, targets)
+            else:
+                ce = L.cross_entropy_loss(
+                    self._project_vocab(params, x), targets)
+        return ce, stats
+
+    def loss(self, params, batch, *, attn_fn=None, act_sharding=None):
+        return self._loss_and_stats(params, batch, attn_fn=attn_fn,
+                                    act_sharding=act_sharding)[0]
+
+    # the serving and pipeline paths assume one kind of layer and a KV cache
+    def _one_kind_only(self, *a, **kw):
+        raise NotImplementedError(
+            f"{type(self).__name__} runs through apply/loss only: latent "
+            f"caches and recurrent state are not in inference/, and a "
+            f"stack of kinds has no single block()")
+
+    block = block_decode = decode = init_cache = _one_kind_only

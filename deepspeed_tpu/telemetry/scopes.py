@@ -71,6 +71,16 @@ KIND_SCOPES = (
     "ds.moe_experts",  # moe/sharded_moe.py held_experts_ffn, fwd and bwd
     "ds.moe_shared",   # moe/sharded_moe.py moe_ffn_held: the shared expert
 )
+# what a stack of Mamba-2 and attention layers opens inside ds.layers beside
+# ds.attn and ds.mlp (models/granite_hybrid.py); ``tests/
+# test_granite_hybrid.py`` holds this list equal to what that model's step
+# carries
+SSM_SCOPES = (
+    "ds.mamba",        # models/granite_hybrid.py _one_layer: the Mamba-2
+    #                    mixer (norm, projections, convolution, gate, norm)
+    "ds.ssd",          # ops/ssd.py chunk_ssd: the chunked state-space scan,
+    #                    forward, remat's reruns and backward
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

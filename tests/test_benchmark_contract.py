@@ -99,7 +99,8 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
     assert modules == {"^jit_train_step"}
     named = set(re.findall(r"ds\.[a-z_]+", text))
     assert named and named <= (set(scopes.DEVICE_SCOPES)
-                               | set(scopes.KIND_SCOPES))
+                               | set(scopes.KIND_SCOPES)
+                               | set(scopes.SSM_SCOPES))
 
     telemetry.shutdown()
     try:

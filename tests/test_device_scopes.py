@@ -106,6 +106,38 @@ ENTRY %main (x: f32[4]) -> f32[4] {
     assert got["x"] == ""
 
 
+def test_an_unnamed_instruction_nothing_holds_stays_under_no_scope():
+    """What the compiler puts into the entry computation for a layer that
+    is unrolled there (relayout and asynchronous copies with no metadata,
+    or an argument's name: 4.8 ms a step in the Granite cell, PR 34) is
+    counted under no scope, and ``unscoped_ms.*`` says how much it is:
+    naming it after its user is a ``tracing`` PR's to decide, for every
+    cell at once."""
+    hlo = """
+%fused (q: f32[4]) -> f32[4] {
+  %q = f32[4] parameter(0)
+  ROOT %neg.1 = f32[4] negate(%q), metadata={op_name="jit(f)/ds.optimizer/neg"}
+}
+
+ENTRY %main (x: f32[4], w: f32[4]) -> (f32[4], f32[4]) {
+  %x = f32[4] parameter(0)
+  %w = f32[4] parameter(1), metadata={op_name="state['w']"}
+  %copy.7 = f32[4] copy(%w), metadata={op_name="state['w']"}
+  %copy-start.1 = (f32[4], f32[4], u32[]) copy-start(%copy.7)
+  %copy-done.1 = f32[4] copy-done(%copy-start.1)
+  %mul.2 = f32[4] multiply(%x, %copy-done.1), metadata={op_name="jit(f)/jvp(ds.layers)/ds.mamba/mul"}
+  %fusion.2 = f32[4] fusion(%mul.2), kind=kLoop, calls=%fused
+  %copy.9 = f32[4] copy(%fusion.2)
+  ROOT %tuple.1 = (f32[4], f32[4]) tuple(%copy.9, %mul.2)
+}
+"""
+    got = scopes.op_scopes(hlo)
+    for name in ("copy.7", "copy-start.1", "copy-done.1", "copy.9"):
+        assert got[name] == "", name
+    assert got["mul.2"] == "fwd:ds.layers/ds.mamba"
+    assert got["fusion.2"] == "ds.optimizer"    # a fusion has its root's
+
+
 # ---- the compiled train step at the tiny preset ----------------------------
 def test_every_instruction_of_the_train_step_is_scoped_or_counted(step_hlo):
     got = scopes.op_scopes(step_hlo)

@@ -16,7 +16,7 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.models import KimiLinear, Mistral
-from deepspeed_tpu.models.kimi_linear import stack_plan
+from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.moe.sharded_moe import (BIAS_UPDATE_RATE, balance_bias,
                                            held_experts_ffn, moe_ffn_held,
                                            sigmoid_top_k)
