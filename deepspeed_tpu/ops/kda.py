@@ -18,32 +18,34 @@ chunk with incoming state ``S`` and ``G_r = sum_{i<=r} g_i``::
 
 Everything that does not need ``S`` (both score matrices, the inverse
 ``T = (I + A)^-1``, ``T V``, ``T (K * exp(G))`` and the decay products) is
-computed for all chunks at once, as batched matmuls in ``jax.numpy``
-under autodiff: the preparation. What needs ``S`` is serial in the
-chunks and runs in a Pallas kernel pair under one ``custom_vjp``
-(``ops/pallas/kda.py`` ``kda_recurrence``): the forward carries ``S`` in
-VMEM across the chunks and writes ``o`` alone; the backward rebuilds the
-states by segments of ``SEG`` chunks from float32 segment checkpoints
-and carries ``dS`` in VMEM, so no state history and no per-chunk
-residual of the recurrence reaches HBM. (Until PR 32 this was a
-``lax.scan`` with an autodiff backward that stacked a state a chunk.)
-On the chip the preparation is five sixths of the mixer's time and the
-kernels one twentieth (``PERF.md`` section 5).
+the PREPARATION: a Pallas kernel pair under one ``custom_vjp``
+(``ops/pallas/kda.py`` ``kda_prepare``) builds it chunk by chunk in VMEM
+from q, k, v, g and beta, which it reads once in the model's layout, and
+writes six operands; its backward rebuilds a chunk's forward from the
+same five inputs, its only residuals. What needs ``S`` is serial in the
+chunks and runs in a second kernel pair under its own ``custom_vjp``
+(``kda_recurrence``): the forward carries ``S`` in VMEM across the chunks
+and writes ``o`` alone; the backward rebuilds the states by segments of
+``SEG`` chunks from float32 segment checkpoints and carries ``dS`` in
+VMEM. No score matrix, inverse, state history or per-chunk residual
+reaches HBM; the six operands cross it once each way. (Until PR 32 the
+recurrence was a ``lax.scan`` under autodiff, until PR 35 the preparation
+``jax.numpy`` under autodiff: ``tests/helpers/kda_reference.py`` keeps
+that form as the kernels' reference.)
+
+A layer of the cell runs the preparation's forward three times (the
+forward, the layer's remat, the head group's own checkpoint) and its
+backward once; ``ds_kda_fwd`` twice, its checkpoint form once and
+``ds_kda_bwd`` once (``PERF.md`` section 5 has their times).
 
 ``exp(G_i - G_j) <= 1``, but ``exp(G_i) * exp(-G_j)`` overflows float32
 where a channel decays fast (a log-decay of -1.6 a token is -100 over a
 chunk). So the score matrices are built by row blocks of ``SUB`` rows,
-each factored about the block's own first row ``G_f``: rows carry
-``exp(G_i - G_f) <= 1``, earlier columns ``exp(G_f - G_j) <= 1``, and
-the block's own columns at most ``exp((SUB - 1) |g|)``, held to
-``exp(CLAMP)`` (``_scores``; the published kernel factors by sub-blocks
-too). On the chip a training run drifted past the overflow of 16-row
-blocks (|g| > 5.5 a token) within 30 steps and its loss went NaN (PR 31):
-hence 8 rows, the clamp and the exact diagonal. The inverse of the unit lower triangle is exact block
-elimination (``_inverse_unit_lower``): the ``SUB x SUB`` diagonal blocks
-by the finite Neumann product ``(I - A)(I + A^2)(I + A^4)...``
-(``A^SUB = 0``), the blocks below them by a second one of order
-``CHUNK / SUB``, all as [CHUNK, CHUNK] matmuls.
+each factored about the block's own first row, with the exponent of a
+block's own columns held to ``CLAMP`` and an exact diagonal, and the
+inverse of the unit lower triangle is exact block elimination in two
+finite Neumann products: ``ops/pallas/kda.py`` says how, and why 8 rows
+(a training run on the chip went NaN at 16: PR 31).
 """
 
 from __future__ import annotations
@@ -54,11 +56,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .pallas.kda import kda_recurrence
-
-CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
-SUB = 8         # rows a sub-block of the score matrices
-CLAMP = 60.0    # largest exponent a sub-block's own columns may carry
+from .pallas.kda import CHUNK, kda_prepare, kda_recurrence
 
 
 def recurrent_kda(q, k, v, g, beta):
@@ -82,65 +80,6 @@ def recurrent_kda(q, k, v, g, beta):
     return o.swapaxes(0, 1)
 
 
-def _scores(q, k, kb, beta, G, dt):
-    """(a_kk, a_qk) [..., C, C]: ``sum_c x_ic kb_jc exp(G_ic - G_jc)`` for
-    x = k below the diagonal and for x = q on and below it, 0 elsewhere;
-    by row blocks of SUB rows so that no factor overflows. The factors are
-    formed in float32 and multiplied in ``dt``. A block's own columns
-    carry ``exp(G_f - G_j)``, which grows with the decay: it is held to
-    ``exp(CLAMP)``, so a channel that decays by more than CLAMP within SUB
-    rows (|g| > 8.5 a token: it forgets in one) loses its already
-    negligible terms off the diagonal and nothing is ever infinite; the
-    diagonal needs no decay and is exact."""
-    c = k.shape[-2]
-    out = []
-    for r0 in range(0, c, SUB):
-        r1 = r0 + SUB
-        ref = G[..., r0:r0 + 1, :]
-        shrink = jnp.exp(G[..., r0:r1, :] - ref)
-        left = jnp.concatenate([k[..., r0:r1, :] * shrink,
-                                q[..., r0:r1, :] * shrink], axis=-2)
-        right = kb[..., :r1, :] * jnp.exp(
-            jnp.minimum(ref - G[..., :r1, :], CLAMP))
-        s = jnp.einsum("...ik,...jk->...ij", left.astype(dt),
-                       right.astype(dt), preferred_element_type=jnp.float32)
-        out.append(jnp.pad(s, [(0, 0)] * (s.ndim - 1) + [(0, c - r1)]))
-    ii = jnp.arange(c)
-    a_kk = jnp.concatenate([s[..., :SUB, :] for s in out], axis=-2)
-    a_qk = jnp.concatenate([s[..., SUB:, :] for s in out], axis=-2)
-    own = beta * jnp.sum(q * k, axis=-1)            # beta_i <q_i, k_i>
-    a_qk = jnp.where(ii[:, None] == ii[None, :], own[..., None], a_qk)
-    return (jnp.where(ii[:, None] > ii[None, :], a_kk, 0.0),
-            jnp.where(ii[:, None] >= ii[None, :], a_qk, 0.0))
-
-
-def _neumann(x, order: int):
-    """(I + x)^-1 = (I - x)(I + x^2)(I + x^4)... for ``x^order = 0``."""
-    mm = lambda a, b: jnp.matmul(  # noqa: E731
-        a, b, precision=jax.lax.Precision.HIGHEST)
-    inv = jnp.eye(x.shape[-1], dtype=x.dtype) - x
-    while order > 2:
-        x = mm(x, x)
-        inv = inv + mm(inv, x)
-        order //= 2
-    return inv
-
-
-def _inverse_unit_lower(a):
-    """(I + a)^-1 for strictly lower triangular ``a`` [..., C, C], float32.
-    Exact, in two finite Neumann products of [C, C] matmuls: with ``d`` the
-    SUB x SUB blocks on the diagonal and ``low`` the rest,
-    ``I + a = (I + d)(I + (I + d)^-1 low)``; ``d^SUB = 0`` and the second
-    factor's strictly block-lower part is nilpotent of order C / SUB."""
-    c = a.shape[-1]
-    blk = jnp.arange(c) // SUB
-    d = jnp.where(blk[:, None] == blk[None, :], a, 0.0)
-    t = _neumann(d, SUB)
-    hi = jax.lax.Precision.HIGHEST
-    m = jnp.matmul(t, a - d, precision=hi)
-    return jnp.matmul(_neumann(m, c // SUB), t, precision=hi)
-
-
 def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
     """The chunked form; arguments as ``recurrent_kda``. The matmuls run in
     ``q``'s dtype with float32 accumulation, the decays, the score
@@ -149,12 +88,14 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
 
     The heads (they are independent) run in ``head_groups`` groups, one
     after the other under ``lax.map``, each under its own
-    ``jax.checkpoint``: the chunk-wise operands and the residuals of the
-    preparation's backward (a dozen arrays of ``[B, S, H, 128]`` float32)
-    live for one group at a time. A group's backward runs its
-    preparation again, then the forward kernel's checkpoint form and the
-    backward kernel; the forward kernel itself is not run again (its
-    ``o`` is dead in the rerun)."""
+    ``jax.checkpoint``: the six operands (the residuals of the
+    recurrence's ``custom_vjp``, 184 MB a group of 8 heads at 16384 tokens)
+    live for one group at a time. A group's backward runs its preparation
+    again, then the recurrence's checkpoint form and the two backward
+    kernels; ``ds_kda_fwd`` itself is not run again (its ``o`` is dead in
+    the rerun). Without that checkpoint the engine's train step of the
+    Kimi cell peaks at 14.19 GiB where the parent's peaked at 14.00
+    (AOT, PR 35); with it at 13.50."""
     h = q.shape[2]
     if h % head_groups:
         raise ValueError(f"chunk_kda: {h} heads in {head_groups} groups")
@@ -204,39 +145,8 @@ def sharded_chunk_kda(act_sharding):
 
 
 def _chunk_kda(q, k, v, g, beta, *, chunk):
-    f32 = jnp.float32
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    if s % chunk or chunk % SUB:
-        raise ValueError(
-            f"chunk_kda: sequence {s} must be a multiple of the chunk "
-            f"{chunk}, and the chunk of {SUB}")
-    n = s // chunk
-    dt, out_dt = q.dtype, v.dtype
-
-    def by_chunk(x):        # [B, S, H, ...] -> [B, H, N, C, ...]
-        x = x.reshape(b, n, chunk, h, *x.shape[3:])
-        return jnp.moveaxis(x, 3, 1)
-
+    b, s, h, _ = q.shape
     with jax.named_scope("ds.kda_scan"):
-        q, k, v, g = (by_chunk(x.astype(f32)) for x in (q, k, v, g))
-        beta = by_chunk(beta.astype(f32))               # [B, H, N, C]
-        G = jnp.cumsum(g, axis=-2)
-        kb = k * beta[..., None]
-        # A_ij = beta_j <k_i e^{G_i - G_j}, k_j> (j < i); the same with q
-        # and the diagonal for the outputs
-        a_kk, a_qk = _scores(q, k, kb, beta, G, dt)
-        t = _inverse_unit_lower(a_kk)
-        # a log-decay is never positive: the clamp only says so
-        decay = jnp.exp(jnp.minimum(G, 0.0))
-        tail = G[..., -1:, :]                           # G_C
-        mm = lambda x, y: jnp.matmul(  # noqa: E731
-            x.astype(dt), y.astype(dt), preferred_element_type=f32)
-        u_v = mm(t, v)                                  # T V
-        w = mm(t, k * decay)                            # T (K e^G)
-        q_in = (q * decay).astype(dt)
-        k_out = (kb * jnp.exp(tail - G)).astype(dt)
-        shrink = jnp.exp(jnp.minimum(tail[..., 0, :], 0.0))
-        o = kda_recurrence(u_v, w.astype(dt), q_in, a_qk.astype(dt), k_out,
-                           shrink, out_dtype=out_dt)    # [B, H, N, C, dv]
-    return jnp.moveaxis(o, 1, 3).reshape(b, s, h, dv)
+        o = kda_recurrence(*kda_prepare(q, k, v, g, beta, chunk=chunk),
+                           out_dtype=v.dtype)           # [B, H, N, C, dv]
+    return jnp.moveaxis(o, 1, 3).reshape(b, s, h, v.shape[-1])

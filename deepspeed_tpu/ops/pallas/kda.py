@@ -1,10 +1,33 @@
-"""The chunk recurrence of Kimi Delta Attention as a Pallas kernel pair.
+"""Chunked Kimi Delta Attention as two Pallas kernel pairs: the
+preparation of a chunk, and the recurrence over the chunks.
 
-``ops/kda.py`` prepares, for every chunk of ``C`` tokens at once, what
-does not need the state: ``u_v = T V``, ``w = T (K e^G)``, ``q_in = q
-e^G``, ``a_qk``, ``k_out = k beta e^(G_C - G)`` and ``shrink = e^(G_C)``.
-What is left is serial in the chunks: with the float32 state ``S``
-[dk, dv] of one head, ``S = 0`` before the first chunk::
+**The preparation** (``kda_prepare``, one ``jax.custom_vjp`` whose only
+residuals are its five inputs) makes, chunk by chunk of ``C`` tokens and
+head by head, what does not need the state: with ``G`` the chunk's running
+sum of the log-decays ``g``, the score matrices ``a_kk`` (strictly lower)
+and ``a_qk`` (lower, exact diagonal), ``T = (I + a_kk)^-1``, and from them
+``u_v = T V``, ``w = T (K e^G)``, ``q_in = q e^G``, ``a_qk``, ``k_out = k
+beta e^(G_C - G)`` and ``shrink = e^(G_C)``.
+
+- **Forward** (``ds_kda_prep_fwd``): grid (batch, blocks of ``PREP_HEADS``
+  heads, blocks of ``NCK`` chunks), every axis parallel. q, k, v (the
+  matmuls' dtype) and g (float32) arrive through BlockSpecs of the model's
+  own [B, S, H d] layout, beta as rows; a grid step loops over its chunks
+  and builds everything of ``_Chunk`` in VMEM and registers. Matmul
+  operands are in the inputs' dtype with float32 accumulation; ``G``, the
+  decays and the inverse are float32 (products at ``HIGHEST``: six bf16
+  passes). The inverse is ten dependent [C, C] products, each waiting on
+  the one before: the heads of a grid step take them IN STEP
+  (``_inverse_unit_lower``), which is what fills the MXU.
+- **Backward** (``ds_kda_prep_bwd``): the same grid and blocks, plus the
+  six cotangents ``ds_kda_bwd`` makes; rebuilds the chunk's forward, then
+  ``dT = du_v V^T + dw (K e^G)^T``, ``da_kk = -T^T dT T^T`` kept strictly
+  lower, the score blocks' cotangents through the same factoring (a
+  clamped factor passes no gradient), the decay products, and the running
+  sum's transpose; writes dq, dk, dv, dg in the model's layout and dbeta.
+
+**The recurrence.** What is left is serial in the chunks: with the float32
+state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
 
     u  = u_v - w S
     o  = q_in S + a_qk u
@@ -32,7 +55,7 @@ What is left is serial in the chunks: with the float32 state ``S``
   Under a ``jax.checkpoint`` that reruns the forward rule for its
   residuals (``chunk_kda`` puts one around each group of heads) the rule's
   ``o`` is dead and the forward kernel is not run again: the rerun costs
-  the preparation and the checkpoint form only.
+  the preparation's forward and the checkpoint form only.
 
 The kernels hold the state TRANSPOSED (``St`` [dv, dk]): ``shrink`` then
 scales lanes and broadcasts as the row it is stored as, ``dshrink`` is a
@@ -60,8 +83,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-SEG = 16        # chunks a segment: a grid step, and a checkpoint's spacing
-HEADS = 4       # heads a grid step: independent chains for the MXU
+CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
+SUB = 8         # rows a sub-block of the score matrices
+CLAMP = 60.0    # largest exponent a sub-block's own columns may carry
+NCK = 8         # chunks a grid step of the preparation
+PREP_HEADS = 4  # heads a grid step of the preparation: their inverses in step
+SEG = 16        # chunks a segment: a grid step of the recurrence, and a
+#                 checkpoint's spacing
+HEADS = 4       # heads a grid step of the recurrence: independent chains
 
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
@@ -300,6 +329,369 @@ def _backward(ops, ck, do):
     return tuple(x[:, :n] for x in grads)
 
 
+# ------------------------------------------------------------ preparation
+# Everything of a chunk that does not need the state, from q, k, v, g and
+# beta: the module docstring's first half.
+_HI = jax.lax.Precision.HIGHEST
+_PREP_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _hdot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product at float32's precision (six bf16 passes)."""
+    return jax.lax.dot_general(a, b, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _half_at_tie(x):
+    """d min(x, 0) / dx as ``jnp.minimum`` has it: 1 below, 1/2 at 0."""
+    return jnp.where(x < 0, 1.0, jnp.where(x == 0, 0.5, 0.0))
+
+
+def _neumann(xs, eye, order: int):
+    """(I + x)^-1 = (I - x)(I + x^2)(I + x^4)... for ``x^order = 0``, of
+    each x of the list, in step."""
+    invs = [eye - x for x in xs]
+    while order > 2:
+        xs = [_hdot(x, x) for x in xs]
+        invs = [inv + _hdot(inv, x) for inv, x in zip(invs, xs)]
+        order //= 2
+    return invs
+
+
+def _inverse_unit_lower(mats, eye, same_block):
+    """(I + a)^-1 for each strictly lower triangular ``a`` [C, C] of the
+    list, float32, exact, in two finite Neumann products: with d the SUB x
+    SUB blocks on the diagonal and low the rest, I + a = (I + d)(I + (I +
+    d)^-1 low); d^SUB = 0 and the second factor's strictly block-lower
+    part is nilpotent of order C / SUB. A product waits a few hundred
+    cycles for the one before it, so the chunks of a list advance IN STEP:
+    each step's products are independent and fill the wait."""
+    ds = [jnp.where(same_block, a, 0.0) for a in mats]
+    ts = _neumann(ds, eye, SUB)
+    ms = [_hdot(t, a - d) for t, a, d in zip(ts, mats, ds)]
+    return [_hdot(n, t) for n, t in zip(
+        _neumann(ms, eye, eye.shape[0] // SUB), ts)]
+
+
+class _Chunk:
+    """What both kernels build of one chunk of one head, in VMEM and
+    registers: q, k [C, dk] in the matmuls' dtype ``dt``, v [C, dv], g
+    [C, dk] float32, beta [1, C] float32 (a ROW: it scales the score
+    matrices' columns; ``beta_col`` is the same numbers down a column).
+
+    The score matrices are built by row blocks of ``SUB`` rows, each
+    factored about the block's own first row ``G_f``: rows carry
+    ``exp(G_i - G_f) <= 1``, earlier columns ``exp(G_f - G_j) <= 1`` and
+    the block's own columns at most ``exp((SUB - 1) |g|)``, held to
+    ``exp(CLAMP)``; the diagonal needs no decay and is exact. ``blocks``
+    keeps each block's factors for the backward."""
+
+    def __init__(self, q, k, v, g, beta):
+        f32 = jnp.float32
+        self.dt = dt = q.dtype
+        self.c, self.dk = c, dk = k.shape
+        self.v, self.beta = v.astype(dt), beta
+        self.qf, self.kf = qf, kf = q.astype(f32), k.astype(f32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        self.low, self.diag = rows > cols, rows == cols
+        self.eye = jnp.where(self.diag, 1.0, 0.0).astype(f32)
+        shift = SUB.bit_length() - 1
+        self.same_block = (jax.lax.shift_right_logical(rows, shift)
+                           == jax.lax.shift_right_logical(cols, shift))
+        # the running sum of g within the chunk, exact in six passes (a
+        # one is one bf16 piece, g three)
+        self.upto = jnp.where(rows >= cols, 1.0, 0.0).astype(f32)
+        self.G = G = _hdot(self.upto, g)
+        self.blocks, kk, qk = [], [], []
+        for r0 in range(0, c, SUB):
+            r1 = r0 + SUB
+            n = min(c, -(-r1 // 16) * 16)   # whole bf16 tiles of columns
+            ref = G[r0:r0 + 1]
+            shrink = jnp.exp(G[r0:r1] - ref)
+            left = jnp.concatenate(
+                [kf[r0:r1] * shrink, qf[r0:r1] * shrink], axis=0).astype(dt)
+            grow = jnp.exp(jnp.minimum(ref - G[:n], CLAMP))
+            right = (kf[:n] * grow).astype(dt)
+            if n < c:
+                right = jnp.concatenate(
+                    [right, jnp.zeros((c - n, dk), dt)], axis=0)
+            s = _dot(left, right, _NT)                      # [2 SUB, C]
+            kk.append(s[:SUB])
+            qk.append(s[SUB:])
+            self.blocks.append((r0, n, shrink, left, grow, right))
+        # before beta scales their columns
+        self.s_kk = jnp.concatenate(kk, axis=0)
+        self.s_qk = jnp.concatenate(qk, axis=0)
+        self.own = jnp.sum(qf * kf, axis=1, keepdims=True)  # <q_i, k_i>
+        self.a_kk = jnp.where(self.low, self.s_kk, 0.0) * beta
+        self.a_qk = jnp.where(
+            self.low, self.s_qk, jnp.where(self.diag, self.own, 0.0)) * beta
+        # a log-decay is never positive: the clamp only says so
+        self.decay = jnp.exp(jnp.minimum(G, 0.0))
+        self.tail = G[c - 1:c]                              # G_C
+        self.kd = (kf * self.decay).astype(dt)              # K e^G
+        self.beta_col = jnp.sum(jnp.where(self.diag, beta, 0.0), axis=1,
+                                keepdims=True)
+        self.fade = jnp.exp(self.tail - G)                  # e^(G_C - G)
+        self.shrink = jnp.exp(jnp.minimum(self.tail, 0.0))
+
+    @staticmethod
+    def invert(chunks):
+        """T = (I + a_kk)^-1 of each chunk of the list."""
+        one = chunks[0]
+        for chunk, t in zip(chunks, _inverse_unit_lower(
+                [x.a_kk for x in chunks], one.eye, one.same_block)):
+            chunk.t, chunk.tb = t, t.astype(chunk.dt)
+
+    def operands(self):
+        """u_v, w, q_in, a_qk, k_out, shrink as ``kda_recurrence`` takes
+        them."""
+        dt, dv = self.dt, self.v.shape[1]
+        uw = _dot(self.tb, jnp.concatenate([self.v, self.kd], axis=1))
+        return (uw[:, :dv], uw[:, dv:].astype(dt),
+                (self.qf * self.decay).astype(dt), self.a_qk.astype(dt),
+                (self.kf * self.beta_col * self.fade).astype(dt),
+                self.shrink)
+
+    @staticmethod
+    def gradients(chunks, cts):
+        """(dq, dk, dv, dg [C, .], dbeta [1, C]), all float32, of each
+        chunk of the list from its six cotangents (du_v, dw, dq_in, da_qk,
+        dk_out, dshrink); the float32 products in step, as ``invert``."""
+        dt = chunks[0].dt
+        # u_v = T V, w = T (K e^G)
+        duws = [jnp.concatenate([ct[0].astype(dt), ct[1].astype(dt)], axis=1)
+                for ct in cts]
+        d_ts = [_dot(duw, jnp.concatenate([x.v, x.kd], axis=1), _NT)
+                for x, duw in zip(chunks, duws)]
+        dvks = [_dot(x.tb, duw, _TN) for x, duw in zip(chunks, duws)]
+        # T = (I + a_kk)^-1: da_kk = -T^T dT T^T, strictly lower
+        d_as = [_hdot(d_t, x.t, _NT) for x, d_t in zip(chunks, d_ts)]
+        d_as = [jnp.where(x.low, -_hdot(x.t, d_a, _TN), 0.0)
+                for x, d_a in zip(chunks, d_as)]
+        parts = [x._scores_and_decays(d_a, dvk, *ct[2:])
+                 for x, d_a, dvk, ct in zip(chunks, d_as, dvks, cts)]
+        # the running sum's transpose: a reversed sum within the chunk
+        return [(dq, dk, d_v, _hdot(x.upto, d_g, _TN), dbeta)
+                for x, (dq, dk, d_v, d_g, dbeta) in zip(chunks, parts)]
+
+    def _scores_and_decays(self, d_a, dvk, dq_in, da_qk, dk_out, dshrink):
+        """dq, dk, dv, dG, dbeta from da_kk and the other cotangents."""
+        f32 = jnp.float32
+        dt, c, dv = self.dt, self.c, self.v.shape[1]
+        qf, kf, G, beta = self.qf, self.kf, self.G, self.beta
+        d_v, dkd = dvk[:, :dv], dvk[:, dv:]
+        da = da_qk.astype(f32)
+        da_low = jnp.where(self.low, da, 0.0)
+        da_own = jnp.where(self.diag, da, 0.0)
+        dbeta = jnp.sum(d_a * self.s_kk + da_low * self.s_qk
+                        + da_own * self.own, axis=0, keepdims=True)
+        ds_kk, ds_qk = d_a * beta, da_low * beta
+        d_own = jnp.sum(da_own * beta, axis=1, keepdims=True)
+        # per row block of SUB rows
+        nb = c // SUB
+        block = lambda x, b: x[b * SUB:(b + 1) * SUB]  # noqa: E731
+        dq = [block(d_own, b) * block(kf, b) for b in range(nb)]
+        dk = [block(d_own, b) * block(qf, b) for b in range(nb)]
+        d_g = [None] * nb                                   # dG
+        first = jax.lax.broadcasted_iota(
+            jnp.int32, (SUB, self.dk), 0) == 0
+        for b, (r0, n, shrink, left, grow, right) in enumerate(self.blocks):
+            r1 = r0 + SUB
+            ds = jnp.concatenate([ds_kk[r0:r1], ds_qk[r0:r1]],
+                                 axis=0).astype(dt)          # [2 SUB, C]
+            dleft = _dot(ds, right)                          # [2 SUB, dk]
+            dright = _dot(ds, left, _TN)[:n]                 # [n, dk]
+            dlk, dlq = dleft[:SUB] * shrink, dleft[SUB:] * shrink
+            dk[b] = dk[b] + dlk
+            dq[b] = dq[b] + dlq
+            lean = dlk * kf[r0:r1] + dlq * qf[r0:r1]        # d(G_i - G_f)
+            p = dright * grow
+            # a clamped factor passes no gradient
+            pull = jnp.where(G[r0:r0 + 1] - G[:n] < CLAMP, p * kf[:n], 0.0)
+            dref = (jnp.sum(pull, axis=0, keepdims=True)
+                    - jnp.sum(lean, axis=0, keepdims=True))
+            lean = lean + jnp.where(first, dref, 0.0)
+            for j in range(n // SUB):
+                dk[j] = dk[j] + block(p, j)
+                mine = -block(pull, j) + (lean if j == b else 0.0)
+                d_g[j] = mine if d_g[j] is None else d_g[j] + mine
+        dq, dk, d_g = (jnp.concatenate(x, axis=0) for x in (dq, dk, d_g))
+        # the decay products
+        dqi, dko = dq_in.astype(f32), dk_out.astype(f32)
+        ddecay = dkd * kf + dqi * qf
+        dq = dq + dqi * self.decay
+        dkb = dko * self.fade                                # d(k beta)
+        dk = dk + dkd * self.decay + dkb * self.beta_col
+        dbeta = dbeta + jnp.sum(
+            jnp.where(self.diag,
+                      jnp.sum(dkb * kf, axis=1, keepdims=True), 0.0),
+            axis=0, keepdims=True)
+        dfade = dkb * kf * self.beta_col                     # d(G_C - G)
+        dtail = (jnp.sum(dfade, axis=0, keepdims=True)
+                 + dshrink * self.shrink * _half_at_tie(self.tail))
+        last = jax.lax.broadcasted_iota(
+            jnp.int32, (c, self.dk), 0) == c - 1
+        d_g = (d_g + ddecay * self.decay * _half_at_tie(G) - dfade
+               + jnp.where(last, dtail, 0.0))
+        return dq, dk, d_v, d_g, dbeta
+
+
+def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv):
+    """(the rows of chunk ``i`` in the inputs' blocks, that chunk of each
+    head of the grid step, inverted)."""
+    rows = pl.ds(pl.multiple_of(i * c, c), c)
+    chunks = [_Chunk(q_ref[0, rows, h * dk:(h + 1) * dk],
+                     k_ref[0, rows, h * dk:(h + 1) * dk],
+                     v_ref[0, rows, h * dv:(h + 1) * dv],
+                     g_ref[0, rows, h * dk:(h + 1) * dk].astype(jnp.float32),
+                     b_ref[h, i].astype(jnp.float32)) for h in range(heads)]
+    _Chunk.invert(chunks)
+    return rows, chunks
+
+
+def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
+                     qi_ref, a_ref, ko_ref, sh_ref, *, heads, nck, c, dk,
+                     dv):
+    """``nck`` chunks of ``heads`` heads: the inputs' blocks are [1,
+    nck C, heads d] of the model's [B, S, H d], beta's [heads, nck, 1,
+    C]; the operands' [heads, nck, C, .]."""
+    def chunk(i, carry):
+        _, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c,
+                            dk, dv)
+        for h, chunk in enumerate(chunks):
+            for ref, x in zip((uv_ref, w_ref, qi_ref, a_ref, ko_ref,
+                               sh_ref), chunk.operands()):
+                ref[h, i] = x.astype(ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, nck, chunk, 0)
+
+
+def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
+                     dqi_ref, da_ref, dko_ref, dsh_ref, dq_ref, dk_ref,
+                     dv_ref, dg_ref, db_ref, *, heads, nck, c, dk, dv):
+    """The same blocks; rebuilds each chunk's forward, then its backward."""
+    def chunk(i, carry):
+        rows, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads,
+                               c, dk, dv)
+        cts = [tuple(ref[h, i] for ref in (duv_ref, dw_ref, dqi_ref, da_ref,
+                                           dko_ref, dsh_ref))
+               for h in range(heads)]
+        for h, grads in enumerate(_Chunk.gradients(chunks, cts)):
+            for ref, x, d in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads,
+                                 (dk, dk, dv, dk)):
+                ref[0, rows, h * d:(h + 1) * d] = x.astype(ref.dtype)
+            db_ref[h, i] = grads[4].astype(db_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, nck, chunk, 0)
+
+
+def _prep_geometry(q, v, chunk):
+    b, s, h, dk = q.shape
+    n = s // chunk
+    nck = next(d for d in range(min(NCK, n), 0, -1) if n % d == 0)
+    heads = next(d for d in range(min(PREP_HEADS, h), 0, -1) if h % d == 0)
+    return b, n, h, dk, v.shape[-1], nck, heads
+
+
+def _prep_specs(b, n, h, c, dk, dv, nck, heads):
+    """(the five inputs' specs, the six operands' specs and shapes): a
+    grid step (batch, head block, chunk block)."""
+    hb = h // heads
+    wide = lambda d: pl.BlockSpec(  # noqa: E731
+        (1, nck * c, heads * d), lambda i, j, l: (i, l, j),
+        memory_space=pltpu.VMEM)
+    flat = lambda *d: pl.BlockSpec(  # noqa: E731
+        (heads, nck, *d), lambda i, j, l: (i * hb + j, l, 0, 0),
+        memory_space=pltpu.VMEM)
+    ins = [wide(dk), wide(dk), wide(dv), wide(dk), flat(1, c)]
+    ops = [flat(c, dv), flat(c, dk), flat(c, dk), flat(c, c), flat(c, dk),
+           flat(1, dk)]
+    shapes = [(b * h, n, *spec.block_shape[2:]) for spec in ops]
+    return ins, ops, shapes
+
+
+def _prep_inputs(q, k, v, g, beta, n, c):
+    """[B, S, H, d] as [B, S, H d] (no copy); beta [B, S, H] as [B H, N,
+    1, C] (one small transpose)."""
+    b, s, h = beta.shape
+    wide = lambda x: x.reshape(b, s, -1)  # noqa: E731
+    rows = jnp.moveaxis(beta, 2, 1).reshape(b * h, n, 1, c)
+    return wide(q), wide(k), wide(v), wide(g), rows
+
+
+def _prepare_forward(q, k, v, g, beta, chunk):
+    b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
+    _check_chip_shapes(chunk, dk, dv)
+    ins, ops, shapes = _prep_specs(b, n, h, chunk, dk, dv, nck, heads)
+    f32, dt = jnp.float32, q.dtype
+    out_shape = [jax.ShapeDtypeStruct(s, d) for s, d in zip(
+        shapes, (f32, dt, dt, dt, dt, f32))]
+    args = _prep_inputs(q, k, v, g, beta, n, chunk)
+    call = pl.pallas_call(
+        functools.partial(_prep_fwd_kernel, heads=heads, nck=nck, c=chunk,
+                          dk=dk, dv=dv),
+        grid=(b, h // heads, n // nck),
+        in_specs=ins, out_specs=ops, out_shape=out_shape,
+        compiler_params=_PREP_PARAMS,
+        cost_estimate=_prep_cost(b * h * n, chunk, dk, dv, args, out_shape,
+                                 backward=False),
+        interpret=_interpret(),
+        name="ds_kda_prep_fwd",
+    )
+    with jax.named_scope("ds.kda_prep_fwd"):
+        u_v, w, q_in, a_qk, k_out, shrink = call(*args)
+    return u_v, w, q_in, a_qk, k_out, shrink.reshape(b * h, n, dk)
+
+
+def _prepare_backward(q, k, v, g, beta, cts, chunk):
+    b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
+    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads)
+    args = _prep_inputs(q, k, v, g, beta, n, chunk)
+    *mats, dshrink = cts
+    cts = (*mats, dshrink.reshape(b * h, n, 1, dk))
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args]
+    call = pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, heads=heads, nck=nck, c=chunk,
+                          dk=dk, dv=dv),
+        grid=(b, h // heads, n // nck),
+        in_specs=ins + ops, out_specs=ins, out_shape=out_shape,
+        compiler_params=_PREP_PARAMS,
+        cost_estimate=_prep_cost(b * h * n, chunk, dk, dv, (*args, *cts),
+                                 out_shape, backward=True),
+        interpret=_interpret(),
+        name="ds_kda_prep_bwd",
+    )
+    with jax.named_scope("ds.kda_prep_bwd"):
+        dq, dk_, dv_, dg, dbeta = call(*args, *cts)
+    dbeta = jnp.moveaxis(dbeta.reshape(b, h, n * chunk), 1, 2)
+    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+            dg.reshape(g.shape), dbeta)
+
+
+def _prep_cost(chunks, c, dk, dv, ins, outs, *, backward: bool):
+    """Matmul passes as bf16 FLOPs (a float32 product is six), the
+    exponentials, and every operand's one trip."""
+    square = 2 * c * c * c
+    flops = (6 * 10 * square + 2 * c * c * dk       # the inverse; G
+             + 2 * 2 * c * c * dk                   # the score blocks
+             + 2 * c * c * (dk + dv))               # T [V, K e^G]
+    if backward:
+        flops += (6 * 2 * square + 6 * 2 * c * c * dk
+                  + 2 * 2 * c * c * (dk + dv) + 2 * 4 * c * c * dk)
+    nbytes = sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
+                 for x in (*ins, *outs))
+    return pl.CostEstimate(
+        flops=int(chunks * flops), bytes_accessed=int(nbytes),
+        transcendentals=int(chunks * (2 if backward else 1) * c * dk
+                            * (c // SUB + 5) // 2))
+
+
 # ---------------------------------------------------------------- public
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _recurrence(*ops_and_dtype):
@@ -332,3 +724,36 @@ def kda_recurrence(u_v, w, q_in, a_qk, k_out, shrink, *, out_dtype):
     o = _recurrence(*(flat(x) for x in (u_v, w, q_in, a_qk, k_out, shrink)),
                     jnp.dtype(out_dtype))
     return o.reshape(b, h, *o.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _prepare(q, k, v, g, beta, chunk):
+    return _prepare_forward(q, k, v, g, beta, chunk)
+
+
+def _prepare_fwd(q, k, v, g, beta, chunk):
+    return _prepare_forward(q, k, v, g, beta, chunk), (q, k, v, g, beta)
+
+
+def _prepare_bwd(chunk, inputs, cts):
+    # opened here, as _recurrence_bwd opens it
+    with jax.named_scope("ds.kda_scan"):
+        return _prepare_backward(*inputs, cts, chunk)
+
+
+_prepare.defvjp(_prepare_fwd, _prepare_bwd)
+
+
+def kda_prepare(q, k, v, g, beta, *, chunk: int):
+    """The six operands of ``kda_recurrence``, each [B, H, N, C, .]
+    (``shrink`` [B, H, N, dk]), from q, k [B, S, H, dk], v [B, S, H, dv]
+    (the matmuls run in ``q``'s dtype), g [B, S, H, dk] and beta [B, S, H]
+    (float32 in the kernels). ``S`` must be a multiple of ``chunk`` and
+    ``chunk`` of ``SUB``."""
+    b, s, h, _ = q.shape
+    if s % chunk or chunk % SUB:
+        raise ValueError(
+            f"chunk_kda: sequence {s} must be a multiple of the chunk "
+            f"{chunk}, and the chunk of {SUB}")
+    return tuple(x.reshape(b, h, *x.shape[1:])
+                 for x in _prepare(q, k, v, g, beta, chunk))

@@ -61,8 +61,10 @@ KIND_SCOPES = (
     "ds.kda",          # models/kimi_linear.py _mix: KDA's projections,
     #                    convolutions, gates, norm and output matmul
     "ds.kda_scan",     # ops/kda.py chunk_kda: the chunked delta rule (the
-    #                    preparation and the kernels; the recurrence's
-    #                    backward opens it again, outside the forward's)
+    #                    four kernels and XLA's copies round them; the two
+    #                    backward rules open it again, outside the forward's)
+    "ds.kda_prep_fwd",  # ops/pallas/kda.py _prepare_forward: ds_kda_prep_fwd
+    "ds.kda_prep_bwd",  # ops/pallas/kda.py _prepare_backward: ds_kda_prep_bwd
     "ds.kda_fwd",      # ops/pallas/kda.py _forward: ds_kda_fwd, either form
     "ds.kda_bwd",      # ops/pallas/kda.py _backward: ds_kda_bwd
     "ds.mla",          # models/kimi_linear.py _mix: latent attention

@@ -4,6 +4,7 @@ at tiny sizes against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/kimi_linear.py``, which imports nothing from
 the program). A CPU run shows results and counts, never a time."""
 
+import gc
 import pathlib
 import re
 import sys
@@ -32,12 +33,27 @@ if str(BENCH) not in sys.path:
 from architectures import kimi_linear as arch  # noqa: E402
 from lib import modelspec  # noqa: E402
 
+from helpers import kda_reference  # noqa: E402  (tests/helpers)
+
 
 @pytest.fixture(autouse=True)
 def _telemetry_isolation():
     telemetry.shutdown()
     yield
     telemetry.shutdown()
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs(request):
+    """The KDA tests run the kernels eagerly in interpret mode: a call
+    compiles some hundred small programs that no cache ever finds again,
+    each a few memory mappings, and a test worker that has run this file
+    passed the kernel's 65530 mappings a process and died in XLA's
+    compiler (PR 35). Dropping JAX's caches after a test returns them."""
+    yield
+    if "kimi_engine" not in request.fixturenames:   # its step stays compiled
+        jax.clear_caches()
+        gc.collect()
 
 
 def _close(got, want, tol, what=""):
@@ -77,8 +93,9 @@ def test_loss_and_gradients_match_the_float32_reference(variant):
     with jax.default_matmul_precision("highest"):
         want, want_g = jax.value_and_grad(_ref_loss)(params, tokens,
                                                      targets, m)
-        got, got_g = jax.value_and_grad(model.loss)(params,
-                                                    (tokens, targets))
+        # jitted: eager, every interpreted kernel call compiles alone
+        got, got_g = jax.jit(jax.value_and_grad(model.loss))(
+            params, (tokens, targets))
     assert abs(float(got) - float(want)) <= 2e-5 * float(want)
     flat_w = jax.tree_util.tree_leaves_with_path(want_g)
     flat_g = jax.tree_util.tree_leaves_with_path(got_g)
@@ -101,10 +118,11 @@ def test_reference_logits_match_apply_and_every_position_counts():
                                                 "excluded_share_max": 1.0})
     with jax.default_matmul_precision("highest"):
         loss, tail, counted = arch.reference(params, tokens, targets, m, 32)
-        got = model.apply(params, tokens)[:, -32:]
+        got = jax.jit(model.apply)(params, tokens)[:, -32:]
     assert bool(jnp.all(counted)) and counted.shape == (1, 32)
     _close(got, tail, 1e-4, "tail logits")
-    assert abs(loss - float(model.loss(params, (tokens, targets)))) < 1e-4
+    assert abs(loss - float(jax.jit(model.loss)(
+        params, (tokens, targets)))) < 1e-4
     # a margin leaves out the positions whose held experts sit near the
     # boundary, and only those
     m["routing_margin"] = 0.05
@@ -134,7 +152,7 @@ def test_the_cells_loss_limit_catches_a_planted_fault(fault):
         want = float(_ref_loss(params, tokens, targets, m))
         if fault == "targets_off_by_one":
             targets = jnp.roll(targets, 1, axis=1)
-        got = float(model.loss(params, (tokens, targets)))
+        got = float(jax.jit(model.loss)(params, (tokens, targets)))
     if fault == "a_chunk_left_out_of_the_count":
         got *= targets.size / (targets.size - model.config.loss_chunk)
     numbers = {}
@@ -248,6 +266,77 @@ def test_kda_kernels_match_the_recurrence(groups, seq, dtype, monkeypatch):
                f"d{name}")
 
 
+
+# ---- KDA: the preparation's kernel pair (interpret mode) -------------------
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [3, 5])
+@pytest.mark.parametrize("heads_a_step", [1, 2])
+def test_kda_preparation_kernels_match_the_jax_numpy_preparation(
+        heads_a_step, chunks, dtype, monkeypatch):
+    """``ds_kda_prep_fwd`` and ``ds_kda_prep_bwd`` against the preparation
+    as ``ops/kda.py`` held it in ``jax.numpy`` (``helpers/kda_reference``)
+    and its autodiff: the six operands, and the five input gradients from
+    six random cotangents. 3 chunks are one grid step of 3, 5 are five
+    steps of 1 (a grid step takes a divisor of the chunk count)."""
+    monkeypatch.setattr(kda_kernels, "PREP_HEADS", heads_a_step)
+    monkeypatch.setattr(kda_kernels, "NCK", 4)
+    args = _kda_inputs(s=64 * chunks, h=2)
+    tol_o, tol_g = 1e-5, 2e-5
+    if dtype == "bfloat16":
+        args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
+    b, _, h, dk = args[0].shape
+    geometry = kda_kernels._prep_geometry(args[0], args[2], 64)
+    assert geometry[-2:] == ({3: 3, 5: 1}[chunks], heads_a_step)
+    rng = np.random.default_rng(chunks)
+    cts = tuple(jnp.asarray(rng.normal(size=x.shape), x.dtype)
+                for x in jax.eval_shape(
+                    lambda *a: kda_reference.prepare(*a, chunk=64), *args))
+    # jitted: eager, every interpreted kernel call compiles alone
+    both = lambda f: jax.jit(lambda *a: (  # noqa: E731
+        lambda out, pull: (out, pull(cts)))(
+            *jax.vjp(lambda *x: f(*x, chunk=64), *a)))(*args)
+    want, want_g = both(kda_reference.prepare)
+    got, got_g = both(kda_kernels.kda_prepare)
+    names = ("u_v", "w", "q_in", "a_qk", "k_out", "shrink")
+    for name, x, y in zip(names, got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype, name
+        assert bool(jnp.all(jnp.isfinite(x))), name
+        _close(x.astype(jnp.float32), y.astype(jnp.float32), tol_o, name)
+    for name, x, y, a in zip("qkvgb", got_g, want_g, args):
+        assert x.shape == a.shape and x.dtype == a.dtype, name
+        assert bool(jnp.all(jnp.isfinite(x))), name
+        _close(x.astype(jnp.float32), y.astype(jnp.float32), tol_g,
+               f"d{name}")
+
+
+def test_no_score_matrix_or_inverse_reaches_hbm_and_the_residuals_are_few():
+    """``jax.vjp`` of one head group, forward and backward in one jaxpr:
+    outside the kernels nothing is a float32 [.., 64, 64] array (the score
+    matrices and the inverse live in VMEM; ``a_qk`` and its cotangent are
+    in the matmuls' dtype), and the two ``custom_vjp``s keep the five
+    inputs and the six operands, nothing else (the segment checkpoints
+    are made in the backward)."""
+    args = _as_bf16(_kda_inputs(b=1, s=64 * 4, h=2, dk=32, dv=20))
+    group = lambda *a: kda_ops._chunk_kda(*a, chunk=64)  # noqa: E731
+
+    def both(*a):
+        o, pull = jax.vjp(group, *a)
+        return pull(jnp.ones_like(o))
+
+    eqns = list(_walk_eqns(jax.make_jaxpr(both)(*args).jaxpr))
+    square = [v.aval for e in eqns for v in e.outvars
+              if getattr(v.aval, "shape", ())[-2:] == (64, 64)]
+    assert square and all(a.dtype == jnp.bfloat16 for a in square), square
+    calls = [e.params["name"] for e in eqns
+             if e.primitive.name == "pallas_call"]
+    assert sorted(calls) == ["ds_kda_bwd", "ds_kda_fwd", "ds_kda_fwd",
+                             "ds_kda_prep_bwd", "ds_kda_prep_fwd"], calls
+    _, pull = jax.vjp(group, *args)
+    kept = sorted((x.size, str(x.dtype)) for x in jax.tree.leaves(pull))
+    ops = kda_kernels.kda_prepare(*args, chunk=64)
+    assert kept == sorted((x.size, str(x.dtype)) for x in (*args, *ops))
+
+
 def _old_step_recurrence(u_v, w, q_in, a_qk, k_out, shrink, out_dtype):
     """The ``lax.scan`` form ``_chunk_kda`` held before the kernels (PR 31),
     on flat heads [BH, N, C, .]: the reference of the six cotangents."""
@@ -343,7 +432,7 @@ def test_no_state_history_reaches_hbm_only_the_segment_checkpoints():
     names = [e.primitive.name for e in _walk_eqns(jax.make_jaxpr(
         lambda *a: kda_ops._chunk_kda(*a, chunk=64))(*args).jaxpr)]
     assert "scan" not in names and "while" not in names
-    assert names.count("pallas_call") == 1
+    assert names.count("pallas_call") == 2      # the preparation, the scan
 
 
 def test_kda_kernels_refuse_on_the_chip_what_mosaic_cannot_tile(monkeypatch):
@@ -644,14 +733,17 @@ def test_step_scopes_are_the_lists(kimi_engine):
     for scope in ("ds.kda_scan", "ds.flash_bwd", "ds.moe_experts"):
         assert any(p.startswith("bwd:ds.layers") and scope in p
                    for p in paths), scope
-    # the KDA kernels: the forward under fwd: and, run again by remat,
-    # under bwd:; its checkpoint form and the backward kernel under bwd:;
-    # every one of them inside ds.kda_scan, which kda_ms.kda reads
-    kernels = {p for p in paths if re.search(r"ds\.kda_(fwd|bwd)\b", p)}
+    # the KDA kernels: the two forwards under fwd: and, run again by remat
+    # and by the head group's checkpoint, under bwd:; the recurrence's
+    # checkpoint form and the two backward kernels under bwd:; every one
+    # of them inside ds.kda_scan, which kda_ms.kda reads
+    kernels = {p for p in paths
+               if re.search(r"ds\.kda_(prep_)?(fwd|bwd)\b", p)}
     assert all("ds.kda/ds.kda_scan/" in p for p in kernels), kernels
-    assert {p.split(":")[0] for p in kernels if "ds.kda_fwd" in p} \
-        == {"fwd", "bwd"}
-    assert {p.split(":")[0] for p in kernels if "ds.kda_bwd" in p} == {"bwd"}
+    sides = lambda name: {p.split(":")[0] for p in kernels  # noqa: E731
+                          if p.endswith("/" + name)}
+    assert sides("ds.kda_fwd") == sides("ds.kda_prep_fwd") == {"fwd", "bwd"}
+    assert sides("ds.kda_bwd") == sides("ds.kda_prep_bwd") == {"bwd"}
 
 
 # ---- the one-kind scan is the parent's program -----------------------------
@@ -724,3 +816,37 @@ def test_mistral_step_is_the_parents_program(monkeypatch, model_kw):
                                                  **model_kw)
     assert mlir_now == mlir_parent
     assert hlo_now == hlo_parent
+
+
+@pytest.mark.parametrize("family", ["mistral", "granite_hybrid"])
+def test_the_other_architectures_steps_run_nothing_of_kda(monkeypatch,
+                                                          family):
+    """PR 35 changed ``ops/kda.py`` and ``ops/pallas/kda.py`` alone (and a
+    list in ``telemetry/scopes.py``): Mistral's and Granite's lowered train
+    steps are the same text with every entry point of the two files made
+    to raise, so they are the parent's."""
+    from deepspeed_tpu.models.base import get_model_class
+
+    def step_text():
+        model = get_model_class(family)(size="tiny")
+        engine, *_ = ds.initialize(model=model, config={
+            "train_batch_size": 8, "bf16": {"enabled": True},
+            "zero_optimization": {"stage": 3},
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
+            "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
+            "steps_per_print": 10 ** 9})
+        tok = np.zeros((8, model.config.max_seq_len), np.int32)
+        return engine._train_step.lower(
+            engine.state, engine._put_batch((tok, tok))).as_text()
+
+    def refuse(*a, **kw):
+        raise AssertionError("KDA code on another architecture's path")
+
+    now = step_text()
+    for module, names in ((kda_ops, ("chunk_kda", "sharded_chunk_kda",
+                                     "_chunk_kda", "recurrent_kda")),
+                          (kda_kernels, ("kda_prepare", "kda_recurrence",
+                                         "_Chunk", "_forward", "_backward"))):
+        for name in names:
+            monkeypatch.setattr(module, name, refuse)
+    assert "loc(" not in now and step_text() == now

@@ -248,8 +248,52 @@ def test_kda_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
     hlo = jax.jit(jax.grad(loss(kda.sharded_chunk_kda(act)),
                            argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile().as_text()
-    assert len(re.findall(r"%ds_kda_fwd[.\w]* = ", hlo)) >= 1
-    assert len(re.findall(r"%ds_kda_bwd[.\w]* = ", hlo)) >= 1
+    for kernel in ("ds_kda_prep_fwd", "ds_kda_prep_bwd", "ds_kda_fwd",
+                   "ds_kda_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
     assert "all-to-all" not in hlo and "all-gather" not in hlo
     with pytest.raises(Exception, match="[Mm]osaic"):
         jax.jit(jax.grad(loss(kda.chunk_kda))).lower(*args).compile()
+
+
+def test_kda_preparation_kernels_compile_for_v5e(monkeypatch):
+    """The preparation's kernel pair (PR 35) at the cell's widths (one
+    sequence of 16384, a head group of 8 heads of 128, bf16 with float32
+    decays), compiled by Mosaic for one described v5e chip: the float32
+    products of the inverse, the transposed products of the backward and
+    the blocks of the model's [B, S, H d] layout are what interpret mode
+    cannot refuse. Per shard on ``v5e:2x2`` they compile in the test above."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.ops.pallas import kda as kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    b, s, h, d, c = 1, 16384, 8, 128, kernels.CHUNK
+    n = s // c
+    wide = (b, s, h, d)
+    ins = (sd(wide, bf), sd(wide, bf), sd(wide, bf), sd(wide, f32),
+           sd(wide[:3], f32))
+    cts = (sd((b * h, n, c, d), f32), sd((b * h, n, c, d), bf),
+           sd((b * h, n, c, d), bf), sd((b * h, n, c, c), bf),
+           sd((b * h, n, c, d), bf), sd((b * h, n, d), f32))
+    fwd = jax.jit(lambda *a: kernels._prepare_forward(*a, c))
+    bwd = jax.jit(lambda *a: kernels._prepare_backward(*a[:5], a[5:], c))
+    for name, fn, args in (("ds_kda_prep_fwd", fwd, ins),
+                           ("ds_kda_prep_bwd", bwd, (*ins, *cts))):
+        compiled = fn.lower(*args).compile()
+        assert re.search(rf"%{name}[.\w]* = .*custom-call",
+                         compiled.as_text()), name
+    assert [x.shape for x in jax.eval_shape(fwd, *ins)] == [
+        x.shape for x in cts]
+    with pytest.raises(ValueError, match="multiples of 128"):
+        fwd.lower(*(sd(x.shape[:3] + (64,) * (len(x.shape) - 3), x.dtype)
+                    for x in ins))

@@ -1,27 +1,38 @@
-"""The two KDA kernels alone, on the chip: time and results of this
-checkout's ``ops/pallas/kda.py`` against the ``lax.scan`` form it replaced.
+"""The four KDA kernels alone, on the chip: time and results of this
+checkout's ``ops/pallas/kda.py`` against the forms they replaced.
 
     chiprun -- python tools/kda_kernel_bench.py [HEADS ...]
 
-Sizes a change to the kernels before a cell is run (PR 32). Shapes are
-the cell's (one sequence of 16384, heads of 128, chunks of 64) at each
+Sizes a change to the kernels before a cell is run (PRs 32, 35). Shapes
+are the cell's (one sequence of 16384, heads of 128, chunks of 64) at each
 HEADS (default 8, a head group of the cell, and 32, a layer). A line a
 head count:
 
-- ``kernel_ms``: the forward kernel, its checkpoint form and the backward
-  kernel, each the mean duration of the ``tpu_custom_call`` events of a
-  profiler trace of 10 calls;
-- ``scan_ms``: the recurrence in the parent's form (``scan_recurrence``
-  below, a copy: ``jax.lax.scan`` over the chunks, autodiff backward) on
-  the same operands, forward alone and forward with backward, device busy
-  time a call;
+- ``kernel_ms``: the recurrence's forward kernel, its checkpoint form and
+  its backward kernel, each the mean duration of the ``tpu_custom_call``
+  events of a profiler trace of 10 calls;
+- ``prep_ms``: the preparation's forward and backward kernel the same way
+  (``fwd``, ``bwd``) and the device busy time of the call they sit in
+  (``fwd_busy``, ``bwd_busy``: with XLA's copies of the inputs into the
+  [B, S, H d] tiles the kernels read); ``jnp_fwd`` / ``jnp_fwd_bwd``: the
+  ``jax.numpy`` preparation they replaced (``tests/helpers/
+  kda_reference.py``) and its autodiff, device busy time a call;
+- ``scan_ms``: the recurrence in PR 31's form (``scan_recurrence`` below,
+  a copy: ``jax.lax.scan`` over the chunks, autodiff backward) on the same
+  operands, forward alone and forward with backward, device busy time;
 - ``chunk_kda_ms`` (at 8 heads): ``jax.grad`` of one head group of
-  ``ops.kda.chunk_kda`` (the preparation under its ``jax.checkpoint``, so
-  with its rerun) with the kernels and with the scan in their place:
-  device busy time a call, the part inside ``while`` ops (the scan's two
-  loops) and inside the kernels; the rest is the preparation;
-- ``err``: the kernels' ``o`` and six cotangents against the scan's,
-  largest difference over the largest value.
+  ``ops.kda.chunk_kda`` under its ``jax.checkpoint`` (so the backward with
+  the preparation's rerun; the loss is linear in ``o``, so the first
+  forward is dead) with the kernels and with the ``jax.numpy`` preparation
+  in their place: device busy time a call, each kernel's part, the rest;
+- ``layer_ms`` (at 32 heads): ``jax.grad`` of a layer's ``chunk_kda`` (four
+  groups under ``lax.map``, each under its checkpoint, the whole under the
+  layer's remat; the loss is quadratic, but its first forward is still
+  dead: two of a step's three forward runs and the backward), split the
+  same way: ``rest`` is what ``lax.map`` and XLA's copies add;
+- ``err`` / ``prep_err``: the recurrence's ``o`` and six cotangents against
+  the scan's, the preparation's six operands and five gradients against
+  the ``jax.numpy`` form's: largest difference over the largest value.
 
 A device number, so only on a TPU. Not the yardstick: what a user feels
 is ``benchmark/run.py``.
@@ -40,6 +51,7 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+sys.path.insert(1, os.path.join(ROOT, "tests"))   # helpers/kda_reference.py
 SEQ, D, CHUNK, CALLS = 16384, 128, 64, 10
 TRACE_DIR = os.path.join(ROOT, ".bench_trace", "kda_kernel_bench")
 
@@ -119,6 +131,16 @@ def kernel_ms(events) -> float:
     return 1e-6 * sum(ds) / max(len(ds), 1)
 
 
+def by_kernel(events) -> dict:
+    """Device busy time a call, each KDA kernel's part and the rest."""
+    out = {"busy": busy_ms(events)}
+    for k in ("ds_kda_prep_fwd", "ds_kda_prep_bwd", "ds_kda_fwd",
+              "ds_kda_bwd"):
+        out[k] = busy_ms(events, rf"^%?{k}[.\d]* = ")
+    out["rest"] = 2 * out["busy"] - sum(out.values())
+    return out
+
+
 def rel_err(a, b) -> float:
     a, b = (np.asarray(x, np.float32) for x in (a, b))
     return float(np.abs(a - b).max() / (np.abs(b).max() + 1e-30))
@@ -129,37 +151,51 @@ def main(argv) -> int:
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kda
     from deepspeed_tpu.ops.pallas import kda as kernels
-    kernel_recurrence = kda.kda_recurrence
+    from helpers import kda_reference
+    bf = jnp.bfloat16
+    kernel_prepare = kda.kda_prepare
     for heads in [int(a) for a in argv] or [8, 32]:
         args = inputs(heads)
-        got = []
-
-        def grab(*ops, out_dtype):
-            got.append(ops)
-            return jnp.zeros(ops[0].shape, out_dtype)
-
-        kda.kda_recurrence = grab
-        ops = jax.jit(lambda *a: (kda._chunk_kda(*a, chunk=CHUNK),
-                                  got[-1])[1])(*args)
-        do = jnp.asarray(np.random.default_rng(1).normal(
-            size=ops[0].shape), jnp.bfloat16)
+        ops = jax.jit(lambda *a: kernel_prepare(*a, chunk=CHUNK))(*args)
+        rng = np.random.default_rng(1)
+        do = jnp.asarray(rng.normal(size=ops[0].shape), bf)
         flat = tuple(x.reshape(-1, *x.shape[2:]) for x in ops)
-        bf = jnp.bfloat16
+        # cotangents of the six operands, as ds_kda_bwd would hand them
+        cts = tuple(jnp.asarray(rng.normal(size=x.shape), x.dtype)
+                    for x in flat)
         fwd = jax.jit(lambda *o: kernels._forward(o, bf, states=False))
         states = jax.jit(lambda *o: kernels._forward(o, bf, states=True))
         bwd = jax.jit(lambda *a: kernels._backward(a[:6], a[6], a[7]))
+        prep_fwd = jax.jit(lambda *a: kernels._prepare_forward(*a, CHUNK))
+        prep_bwd = jax.jit(
+            lambda *a: kernels._prepare_backward(*a[:5], a[5:], CHUNK))
+        ref_fwd = jax.jit(lambda *a: kda_reference.prepare(*a, chunk=CHUNK))
+        pull = lambda f: jax.jit(lambda *a: jax.vjp(  # noqa: E731
+            lambda *x: tuple(y.reshape(-1, *y.shape[2:])
+                             for y in f(*x, chunk=CHUNK)),
+            *a[:5])[1](tuple(a[5:])))
         ck = states(*flat)
         vjp = lambda f: jax.jit(lambda *a: (  # noqa: E731
             lambda o, pull: (o, *pull(a[6])))(
                 *jax.vjp(lambda *x: f(*x, out_dtype=bf), *a[:6])))
         scan_fwd = jax.jit(lambda *o: scan_recurrence(*o, out_dtype=bf))
+        ev_pf = traced(jax, prep_fwd, args)
+        ev_pb = traced(jax, prep_bwd, (*args, *cts))
         line = {"heads": heads, "seg": kernels.SEG,
                 "heads_a_step": kernels.HEADS,
+                "prep_chunks_a_step": kernels.NCK,
+                "prep_heads_a_step": kernels.PREP_HEADS,
                 "kernel_ms": {
                     "fwd": kernel_ms(traced(jax, fwd, flat)),
                     "states": kernel_ms(traced(jax, states, flat)),
                     "bwd": kernel_ms(traced(
                         jax, bwd, (*flat, ck, do.reshape(flat[0].shape))))},
+                "prep_ms": {
+                    "fwd": kernel_ms(ev_pf), "fwd_busy": busy_ms(ev_pf),
+                    "bwd": kernel_ms(ev_pb), "bwd_busy": busy_ms(ev_pb),
+                    "jnp_fwd": busy_ms(traced(jax, ref_fwd, args)),
+                    "jnp_fwd_bwd": busy_ms(traced(
+                        jax, pull(kda_reference.prepare), (*args, *cts)))},
                 "scan_ms": {
                     "fwd": busy_ms(traced(jax, scan_fwd, ops)),
                     "fwd_bwd": busy_ms(traced(jax, vjp(scan_recurrence),
@@ -167,16 +203,23 @@ def main(argv) -> int:
                 "err": dict(zip(
                     ("o", "du_v", "dw", "dq_in", "da_qk", "dk_out",
                      "dshrink"),
-                    map(rel_err, vjp(kernel_recurrence)(*ops, do),
-                        vjp(scan_recurrence)(*ops, do))))}
-        del ops, flat, ck, do
+                    map(rel_err, vjp(kda.kda_recurrence)(*ops, do),
+                        vjp(scan_recurrence)(*ops, do)))),
+                "prep_err": dict(zip(
+                    ("u_v", "w", "q_in", "a_qk", "k_out", "shrink",
+                     "dq", "dk", "dv", "dg", "dbeta"),
+                    map(rel_err,
+                        (*ops, *pull(kernel_prepare)(*args, *cts)),
+                        (*ref_fwd(*args),
+                         *pull(kda_reference.prepare)(*args, *cts)))))}
+        del ops, flat, ck, do, cts
         if heads == 8:      # a head group of the cell, without lax.map
             wgt = jnp.asarray(np.random.default_rng(2).normal(
-                size=args[2].shape), jnp.bfloat16)
+                size=args[2].shape), bf)
             whole = {}
-            for name, f in (("kernels", kernel_recurrence),
-                            ("scan", scan_recurrence)):
-                kda.kda_recurrence = f
+            for name, f in (("kernels", kernel_prepare),
+                            ("jnp_prepare", kda_reference.prepare)):
+                kda.kda_prepare = f
                 # a new function a form: jax.checkpoint keeps its trace
                 group = jax.checkpoint(
                     lambda *a: kda._chunk_kda(*a, chunk=CHUNK))
@@ -184,11 +227,17 @@ def main(argv) -> int:
                     lambda *a: jnp.sum(group(*a).astype(jnp.float32) * wgt),
                     argnums=(0, 1, 2, 3, 4)))
                 ev = traced(jax, grad, args)
-                whole[name] = {"busy": busy_ms(ev),
-                               "while": busy_ms(ev, r"^%?while"),
-                               "kernels": busy_ms(ev, "tpu_custom_call")}
-            kda.kda_recurrence = kernel_recurrence
+                whole[name] = by_kernel(ev)
+            kda.kda_prepare = kernel_prepare
             line["chunk_kda_ms"] = whole
+        if heads == 32:     # a layer of the cell: four groups under lax.map
+            layer = jax.checkpoint(
+                lambda *a: kda.chunk_kda(*a, head_groups=4))
+            grad = jax.jit(jax.grad(
+                lambda *a: 0.5 * jnp.sum(
+                    layer(*a).astype(jnp.float32) ** 2),
+                argnums=(0, 1, 2, 3, 4)))
+            line["layer_ms"] = by_kernel(traced(jax, grad, args))
         print(json.dumps(line), flush=True)
     return 0
 
