@@ -187,19 +187,23 @@ class GraniteHybrid(StackOfKinds):
         f32 = jnp.float32
         proj = h @ p["w_in"]
         z = proj[..., :inner]
-        xbc = L.silu(L.causal_conv(proj[..., inner:2 * inner + 2 * g * n],
-                                   p["conv_w"], p.get("conv_b")))
-        dt = jax.nn.softplus(proj[..., 2 * inner + 2 * g * n:].astype(f32)
-                             + p["dt_bias"].astype(f32))
-        x = xbc[..., :inner].reshape(b, s, nh, hd)
-        B = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
-        C = xbc[..., inner + g * n:].reshape(b, s, g, n)
+        xbc = L.causal_conv(proj[..., inner:2 * inner + 2 * g * n],
+                            p["conv_w"], p.get("conv_b"))
+        with jax.named_scope("ds.mix_pre"):
+            xbc = L.silu(xbc)
+            dt = jax.nn.softplus(
+                proj[..., 2 * inner + 2 * g * n:].astype(f32)
+                + p["dt_bias"].astype(f32))
+            x = xbc[..., :inner].reshape(b, s, nh, hd)
+            B = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
+            C = xbc[..., inner + g * n:].reshape(b, s, g, n)
         y = ssd_fn(x, dt, -jnp.exp(p["A_log"].astype(f32)), B, C,
                    chunk=min(c.mamba_chunk_size, s))
-        y = y.astype(f32) + x.astype(f32) * p["D"].astype(f32)[:, None]
-        y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(f32))
-        return L.rms_norm(y, p["norm"], c.norm_eps).astype(h.dtype) \
-            @ p["w_out"]
+        with jax.named_scope("ds.mix_post"):
+            y = y.astype(f32) + x.astype(f32) * p["D"].astype(f32)[:, None]
+            y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(f32))
+            y = L.rms_norm(y, p["norm"], c.norm_eps).astype(h.dtype)
+        return y @ p["w_out"]
 
     def _attention(self, p, h, attn_fn):
         c = self.config

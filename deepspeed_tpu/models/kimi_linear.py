@@ -252,22 +252,37 @@ class KimiLinear(StackOfKinds):
                 jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
         heads = lambda x: x.reshape(b, s, nh, dk)  # noqa: E731
-        q = heads(L.silu(L.causal_conv(h @ p["wq"], p["conv_q"])))
-        k = heads(L.silu(L.causal_conv(h @ p["wk"], p["conv_k"])))
-        v = heads(L.silu(L.causal_conv(h @ p["wv"], p["conv_v"])))
-        q = (l2norm(q) * dk ** -0.5).astype(h.dtype)
-        k = l2norm(k).astype(h.dtype)
-        beta = jax.nn.sigmoid((h @ p["w_b"]).astype(f32))
-        decay = ((h @ p["w_f1"]) @ p["w_f2"]).astype(f32) \
-            + p["dt_bias"].astype(f32)
-        g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
-            * heads(jax.nn.softplus(decay))
+        # the projections carry ds.kda alone (what kind "matmul" finds);
+        # causal_conv opens ds.conv; what else lies before the scan is
+        # ds.mix_pre, what lies after it ds.mix_post
+        pre = lambda: jax.named_scope("ds.mix_pre")  # noqa: E731
+
+        def short(w, conv):
+            y = L.causal_conv(h @ p[w], p[conv])
+            with pre():
+                return heads(L.silu(y))
+
+        q, k, v = (short("wq", "conv_q"), short("wk", "conv_k"),
+                   short("wv", "conv_v"))
+        with pre():
+            q = (l2norm(q) * dk ** -0.5).astype(h.dtype)
+            k = l2norm(k).astype(h.dtype)
+        beta = h @ p["w_b"]
+        with pre():
+            beta = jax.nn.sigmoid(beta.astype(f32))
+        decay = (h @ p["w_f1"]) @ p["w_f2"]
+        with pre():
+            decay = decay.astype(f32) + p["dt_bias"].astype(f32)
+            g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
+                * heads(jax.nn.softplus(decay))
         o = kda_fn(q, k, v, g, beta, head_groups=c.kda_head_groups)
-        gate = jax.nn.sigmoid(
-            ((h @ p["w_g1"]) @ p["w_g2"]).astype(f32)
-            + p["b_g"].astype(f32))
-        o = L.rms_norm(o, p["o_norm"], c.norm_eps).reshape(b, s, nh * dk)
-        return (o.astype(f32) * gate).astype(h.dtype) @ p["wo"]
+        gate = (h @ p["w_g1"]) @ p["w_g2"]
+        with jax.named_scope("ds.mix_post"):
+            gate = jax.nn.sigmoid(gate.astype(f32) + p["b_g"].astype(f32))
+            o = L.rms_norm(o, p["o_norm"], c.norm_eps).reshape(
+                b, s, nh * dk)
+            o = (o.astype(f32) * gate).astype(h.dtype)
+        return o @ p["wo"]
 
     def _mla(self, p, h, attn_fn):
         c = self.config
@@ -297,11 +312,10 @@ class KimiLinear(StackOfKinds):
 
     # ---------------- one layer, the stack ----------------
     def _mix(self, p, x, attn_fn, kda_fn):
-        h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
-        if "kda" in p:
-            with jax.named_scope("ds.kda"):
+        with jax.named_scope("ds.kda" if "kda" in p else "ds.mla"):
+            h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
+            if "kda" in p:
                 return x + self._kda(p["kda"], h, kda_fn)
-        with jax.named_scope("ds.mla"):
             return x + self._mla(p["mla"], h, attn_fn)
 
     def _channel(self, p, x):

@@ -51,9 +51,10 @@ def causal_conv(x, w, bias=None):
     None; y_t = sum_i w[i] x_{t-(n-1)+i} (+ bias), zeros before the
     start."""
     n, s = w.shape[0], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-    y = sum(xp[:, i:i + s] * w[i] for i in range(n))
-    return y if bias is None else y + bias
+    with jax.named_scope("ds.conv"):
+        xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
+        y = sum(xp[:, i:i + s] * w[i] for i in range(n))
+        return y if bias is None else y + bias
 
 
 def rotary_embedding(seq_len: int, head_dim: int, theta: float = 10000.0,

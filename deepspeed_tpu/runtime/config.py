@@ -215,7 +215,8 @@ class TelemetryConfig(DeepSpeedConfigModel):
     # register every observed compiled executable's cost_analysis()/
     # memory_analysis() (FLOPs, HBM) keyed by jit name + shape
     # signature; feeds the ds_ledger_* / HBM-headroom gauges and the
-    # <prefix>.op_scopes.json artifact (HLO instruction -> device scope).
+    # <prefix>.op_scopes.json / .op_work.json artifacts (HLO instruction
+    # -> device scope; -> kind of work and bytes at its boundary).
     # Costs ONE extra backend compile per new executable at warmup.
     executable_ledger: bool = False
     # walk each registered executable's HLO for collective ops and

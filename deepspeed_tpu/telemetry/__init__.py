@@ -354,6 +354,14 @@ def export_artifacts(out_dir: str, prefix: str = "telemetry",
             with open(path, "w") as f:
                 _json.dump(scope_maps, f)
             out["op_scopes"] = path
+        work_maps = led.op_work_by_name()
+        if work_maps:
+            # beside each instruction's scope, what kind of work it is
+            # and the bytes at its boundary (scopes.op_work)
+            path = os.path.join(out_dir, f"{prefix}.op_work.json")
+            with open(path, "w") as f:
+                _json.dump(work_maps, f)
+            out["op_work"] = path
     scope = get_fleet()
     if scope is not None:
         # versioned fleet rollup (ISSUE 17); embeds the health snapshot

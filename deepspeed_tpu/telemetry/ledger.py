@@ -12,13 +12,15 @@ by jax per signature, so this costs ONE extra backend compile per new
 executable during warmup and a dict lookup afterwards), records the
 normalized cost/memory analysis, and — when a mesh is given — walks
 the optimized HLO for the collective traffic matrix
-(:mod:`.collectives`).
+(:mod:`.collectives`). One more walk of the same text gives every
+instruction its device scope, its kind of work and the bytes at its
+boundary (:mod:`.scopes`): the join from a device trace's events to what
+the program names.
 
-Ledger entry names deliberately match the span names of the same call
-sites (``compiled_step``, ``v2/dispatch``, ``v2/fused_dispatch``):
-``mfu_by_name()`` joins dispatched FLOPs against the span tracer's
-measured seconds to produce live MFU — a lower bound, since the span
-window includes host time around the device work.
+Ledger entry names match the span names of the same call sites
+(``compiled_step``, ``v2/dispatch``, ``v2/fused_dispatch``):
+``step_seconds_by_name()`` joins dispatch counts against the span
+tracer's measured seconds.
 
 Everything here is host-only API (graftlint GL041): nothing may be
 called from jit-reachable code.
@@ -54,7 +56,7 @@ class ExecutableEntry:
 
     __slots__ = ("name", "signature", "flops", "bytes_accessed",
                  "memory", "collectives", "traffic", "custom_calls",
-                 "op_scopes", "calls", "registered_unix",
+                 "op_work", "calls", "registered_unix",
                  "register_error")
 
     def __init__(self, name: str, signature: tuple):
@@ -68,9 +70,11 @@ class ExecutableEntry:
         # {custom_call_target: count}; "tpu_custom_call" = a Pallas
         # kernel compiled through Mosaic (absent in interpret mode)
         self.custom_calls: dict[str, int] = {}
-        # {HLO instruction name: device scope path} (scopes.op_scopes):
-        # what a trace event named by its instruction is joined through
-        self.op_scopes: dict[str, str] = {}
+        # {HLO instruction name: {"scope", "kind", "bytes", "mixed"}}
+        # (scopes.op_work): what a trace event named by its instruction
+        # is joined through to the program's device scope, the kind of
+        # work it is and the bytes at its boundary
+        self.op_work: dict[str, dict] = {}
         self.calls = 0
         self.registered_unix = time.time()
         self.register_error = ""
@@ -165,7 +169,7 @@ class ExecutableLedger:
         entry.memory = compiled_memory(compiled)
         try:
             hlo = compiled.as_text()
-            entry.op_scopes = _scopes.op_scopes(hlo)
+            entry.op_work = _scopes.op_work(hlo)
             if self.hlo_collectives:
                 entry.collectives = _collectives.analyze_hlo(
                     hlo, mesh=mesh, n_devices=n_devices)
@@ -286,15 +290,22 @@ class ExecutableLedger:
             return {}
         return {axis: b / calls for axis, b in totals.items()}
 
-    def op_scopes_by_name(self) -> dict[str, dict[str, str]]:
-        """{entry name: {instruction name: scope path}}; the signatures
-        of one name are merged, the most dispatched last (it wins where
-        two executables share an instruction name)."""
-        out: dict[str, dict[str, str]] = {}
+    def op_work_by_name(self) -> dict[str, dict[str, dict]]:
+        """{entry name: {instruction name: {"scope", "kind", "bytes",
+        "mixed"}}}; the signatures of one name are merged, the most
+        dispatched last (it wins where two executables share an
+        instruction name)."""
+        out: dict[str, dict[str, dict]] = {}
         for e in sorted(self.entries(), key=lambda e: e.calls):
-            if e.op_scopes:
-                out.setdefault(e.name, {}).update(e.op_scopes)
+            if e.op_work:
+                out.setdefault(e.name, {}).update(e.op_work)
         return out
+
+    def op_scopes_by_name(self) -> dict[str, dict[str, str]]:
+        """{entry name: {instruction name: scope path}}, of
+        ``op_work_by_name``."""
+        return {name: {op: w["scope"] for op, w in rows.items()}
+                for name, rows in self.op_work_by_name().items()}
 
     def snapshot(self) -> dict:
         rows = sorted((e.to_dict() for e in self.entries()),

@@ -30,6 +30,43 @@ instruction was made by differentiation::
   a ``while``, ``call`` or ``conditional``, takes the scope of the
   instruction that holds it.
 
+The same walk says what KIND of work each instruction is and how many
+bytes stand at its boundary (``op_work``): a scope says whose the time is,
+and blurs where XLA fuses across two scopes; the kind is read from the
+instruction itself and does not. Exactly one of ``KINDS``:
+
+- ``matmul``: a ``dot`` or ``convolution`` (what the TPU lowers a ``dot``
+  to), or a fusion that holds one (its elementwise epilogue counts with
+  it);
+- ``kernel``: a ``custom-call`` to ``tpu_custom_call`` (a Mosaic kernel);
+- ``collective``: ``all-gather``, ``reduce-scatter``, ``all-reduce``,
+  ``all-to-all``, ``collective-permute`` and the like, and their
+  ``-start`` / ``-done`` halves;
+- ``move``: an op that changes where or how data lies and computes
+  nothing (``copy``, ``transpose``, ``slice``, ``dynamic-update-slice``,
+  ``concatenate``, ``pad``, ``broadcast``, ``bitcast``, ``gather``, a
+  ``scatter`` that assigns, ...), and a fusion that holds only such;
+- ``elementwise``: every other instruction that does arithmetic (a loop
+  or input fusion with no dot, a ``reduce``, a ``select``, a ``convert``);
+- ``control``: ``while``, ``call``, ``conditional``, ``tuple``,
+  ``get-tuple-element``, ``parameter``, ``constant``: holders and
+  bookkeeping, never a leaf with time of its own;
+- ``other``: an opcode the tables below do not know. Never silently one
+  of the above: the tests hold it to nothing in the steps they compile,
+  and whoever meets one extends the table.
+
+``bytes`` is the size of the instruction's result plus its operands, from
+the shapes in the text (a tuple: the sum of its parts); for a fusion it
+is what crosses its boundary. Two things are counted by what they touch
+and not whole: an operand that the instruction, or the fusion's
+computation, only slices (``slice``, ``dynamic-slice``: the projection of
+which a fusion reads one part, the stacked buffer of which a loop reads
+one layer) counts the slices; a ``dynamic-update-slice`` at the root,
+which XLA runs in place, counts the update read and written and not the
+buffer twice. It is still an UPPER bound on the HBM traffic: an operand
+or result the compiler holds in VMEM, or aliases some other way, is
+counted.
+
 Host-only text analysis, one walk per executable (telemetry/ledger.py).
 The scopes are opened where the work is defined, by name, because the
 model and the kernels never import this package (the zero-import
@@ -39,6 +76,7 @@ contract); ``tests/test_device_scopes.py`` holds the two lists equal.
 from __future__ import annotations
 
 import re
+from typing import Optional
 
 from .collectives import _COMPUTATION_RE
 
@@ -83,18 +121,94 @@ SSM_SCOPES = (
     "ds.ssd",          # ops/ssd.py chunk_ssd: the chunked state-space scan,
     #                    forward, remat's reruns and backward
 )
+# the parts of a recurrent mixer round its scan, opened inside ds.kda and
+# ds.mamba alike; both model tests add this list to what they expect. The
+# projections need no scope: inside the mixer and outside the scan they
+# are what kind "matmul" finds
+MIXER_SCOPES = (
+    "ds.conv",         # ops/layers.py causal_conv: the short convolution
+    #                    (KDA's three, Mamba-2's one)
+    "ds.mix_pre",      # between the input projections and the scan, without
+    #                    the convolution. models/kimi_linear.py _kda: silu,
+    #                    the l2 norms and the scale, beta, the decay's
+    #                    softplus and g; models/granite_hybrid.py _mamba:
+    #                    silu, dt's softplus, the split into x, B, C
+    "ds.mix_post",     # between the scan and the output projection. _kda:
+    #                    the gate's sigmoid, o_norm, the product; _mamba:
+    #                    D x, the silu(z) gate, the gated norm
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")
 
 _SCOPE_RE = re.compile(r"ds\.[A-Za-z0-9_]+")
 _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
-_INSTRUCTION_RE = re.compile(r"^\s*(?P<root>ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=")
+_INSTRUCTION_RE = re.compile(r"^\s*(?P<root>ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*")
 _CALLED_RE = re.compile(
     r"\b(?:body|condition|to_apply|calls|true_computation|"
     r"false_computation)=%?([\w.\-]+)")
 _CALLED_LIST_RE = re.compile(
     r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
+_PAREN_RE = re.compile(r"[()]")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_HALF_RE = re.compile(r"-(?:start|update|done)$")
+_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
+_SHAPE_RE = re.compile(r"\b([a-z]+\d+\w*|pred)\[([\d,<=]*)\]")
+
+KINDS = ("matmul", "kernel", "collective", "move", "elementwise", "control",
+         "other")
+# the opcodes of each kind; one in none of them is "other". An
+# asynchronous half is looked up without its -start / -update / -done
+_OPCODES = {
+    "matmul": ("dot", "convolution"),
+    "collective": (
+        "all-gather", "reduce-scatter", "all-reduce", "all-to-all",
+        "collective-permute", "collective-broadcast", "ragged-all-to-all",
+        "send", "recv"),
+    "move": (
+        "copy", "transpose", "slice", "dynamic-slice",
+        "dynamic-update-slice", "concatenate", "pad", "broadcast",
+        "reshape", "dynamic-reshape", "bitcast", "bitcast-convert",
+        "gather", "reverse"),
+    "control": (
+        "while", "call", "conditional", "tuple", "get-tuple-element",
+        "parameter", "constant", "after-all", "partition-id", "replica-id",
+        "opt-barrier", "add-dependency", "domain", "get-dimension-size"),
+    "elementwise": (
+        "add", "subtract", "multiply", "divide", "remainder", "power",
+        "maximum", "minimum", "abs", "negate", "sign", "floor", "ceil",
+        "round-nearest-afz", "round-nearest-even", "exponential",
+        "exponential-minus-one", "log", "log-plus-one", "sqrt", "rsqrt",
+        "cbrt", "tanh", "tan", "sine", "cosine", "atan2", "logistic", "erf",
+        "is-finite", "not", "and", "or", "xor", "shift-left",
+        "shift-right-arithmetic", "shift-right-logical", "popcnt",
+        "count-leading-zeros", "compare", "select", "clamp", "convert",
+        "reduce-precision", "stochastic-convert", "real", "imag", "complex",
+        "reduce", "reduce-window", "select-and-scatter", "map", "sort",
+        "topk", "iota", "rng", "rng-bit-generator",
+        "rng-get-and-update-state", "cholesky", "triangular-solve", "fft"),
+}
+_KIND_OF = {code: k for k, codes in _OPCODES.items() for code in codes}
+# custom calls by target: a Mosaic kernel; what the TPU compiler puts in
+# for itself (met in the four cells' steps): the halves of a fused
+# collective, a buffer reserved, a promise about a gather's indices,
+# slices laid end to end, indices packed; and the CPU's top-k (a sort)
+_CUSTOM_CALLS = {"tpu_custom_call": "kernel",
+                 "AsyncCollectiveStart": "collective",
+                 "AsyncCollectiveDone": "collective",
+                 "AllocateBuffer": "control",
+                 "AssumeGatherIndicesInBound": "control",
+                 "ConcatBitcast": "move",
+                 "GatherScatterIndicesBitpacked": "elementwise",
+                 "TopK": "elementwise"}
+# what a fusion (or the computation an async pair wraps) is, from the
+# kinds it holds: the first of these that is there; "control" alone is a
+# fusion of nothing but parameters and constants, which moves them
+_HELD_ORDER = ("other", "kernel", "matmul", "collective", "elementwise",
+               "move")
+_SLICES = ("slice", "dynamic-slice")
+_BYTES = {"pred": 1.0}        # bytes an element; filled as dtypes are met
 
 
 def scope_of(op_name: str) -> str:
@@ -133,38 +247,108 @@ def _join(outer: str, inner: str) -> str:
     return (direction + ":" if direction else "") + "/".join(path)
 
 
-def op_scopes(hlo_text: str) -> dict[str, str]:
-    """{instruction name: scope path} over every instruction of every
-    computation of an optimized HLO module (``Compiled.as_text()``).
-    Names carry no ``%``; "" means the instruction is under no scope."""
+def _shape_bytes(text: str) -> int:
+    """Bytes of every array shape in ``text`` (``bf16[1,8192]{...}``; a
+    tuple is the sum of its parts; layouts and memory spaces are passed
+    over; a dynamic bound counts in full)."""
+    total = 0.0
+    for dtype, dims in _SHAPE_RE.findall(text):
+        width = _BYTES.get(dtype)
+        if width is None:           # f32, bf16, s4, f8e4m3fn, c64: the bits
+            bits = "".join(ch for ch in dtype.split("e")[0] if ch.isdigit())
+            width = _BYTES[dtype] = int(bits) / 8
+        n = 1
+        for d in dims.replace("<=", "").split(","):
+            if d:
+                n *= int(d)
+        total += n * width
+    return int(total)
+
+
+def _group_end(line: str, start: int) -> int:
+    """Index after the parenthesis group that opens at ``line[start]``."""
+    depth = 0
+    for m in _PAREN_RE.finditer(line, start):
+        depth += 1 if m.group() == "(" else -1
+        if depth == 0:
+            return m.end()
+    return len(line)
+
+
+def op_work(hlo_text: str) -> dict[str, dict]:
+    """{instruction name: {"scope", "kind", "bytes", "mixed"}} over every
+    instruction of every computation of an optimized HLO module
+    (``Compiled.as_text()``): the scope path as ``op_scopes`` gives it,
+    the kind of work (one of ``KINDS``), the bytes at the instruction's
+    boundary and, for a fusion, whether it fused instructions of another
+    scope path than its root's (the module docstring has all four). Names
+    carry no ``%``."""
     own: dict[str, str] = {}            # instruction -> its own path
     where: dict[str, str] = {}          # instruction -> its computation
     roots: dict[str, str] = {}          # computation -> ROOT instruction
     caller: dict[str, str] = {}         # computation -> calling instruction
-    fusion_body: dict[str, str] = {}    # fusion instruction -> computation
+    body: dict[str, str] = {}           # fusion or async op -> computation
+    members: dict[str, list] = {}       # computation -> its instructions
+    opcode: dict[str, str] = {}
+    result: dict[str, int] = {}         # instruction -> bytes of its result
+    operands: dict[str, list] = {}      # instruction -> operand names
+    target: dict[str, str] = {}         # custom-call -> its target
+    number: dict[str, int] = {}         # parameter -> which one it is
     comp = None
     for line in hlo_text.splitlines():
         m = _COMPUTATION_RE.match(line)
         if m:
             comp = m.group("name")
+            members[comp] = []
             continue
         m = _INSTRUCTION_RE.match(line)
         if m is None or comp is None:
             continue
         name = m.group("name")
         where[name] = comp
+        members[comp].append(name)
         if m.group("root"):
             roots[comp] = name
-        op = _OP_NAME_RE.search(line)
+        # "<result shape> <opcode>(<operands>), <attributes>"
+        at = m.end()
+        shape_end = (_group_end(line, at) if line.startswith("(", at)
+                     else line.find(" ", at))
+        code = _OPCODE_RE.match(line, shape_end)
+        if code is None:
+            opcode[name], rest = "", line
+            result[name], operands[name] = 0, []
+        else:
+            args_end = _group_end(line, code.end() - 1)
+            args = line[code.end():args_end - 1]
+            rest = line[args_end:]
+            # both halves of an asynchronous pair are what they wrap
+            opcode[name] = _HALF_RE.sub("", code.group(1))
+            result[name] = _shape_bytes(line[at:shape_end])
+            operands[name] = _OPERAND_RE.findall(args)
+            if opcode[name] == "parameter":
+                number[name] = int(args) if args.isdigit() else 0
+        op = _OP_NAME_RE.search(rest)
         own[name] = scope_of(op.group(1)) if op else ""
-        called = _CALLED_RE.findall(line)
-        for group in _CALLED_LIST_RE.findall(line):
+        called = _CALLED_RE.findall(rest)
+        for group in _CALLED_LIST_RE.findall(rest):
             called += [c.strip().lstrip("%") for c in group.split(",")
                        if c.strip()]
         for c in called:
             caller.setdefault(c, name)
-        if " fusion(" in line and called:
-            fusion_body[name] = called[0]
+        if called and opcode[name] in ("fusion", "async"):
+            body[name] = called[0]
+        elif opcode[name] == "scatter" and called:
+            body[name] = called[-1]     # to_apply: how updates combine
+        elif opcode[name] == "custom-call":
+            t = _TARGET_RE.search(rest)
+            target[name] = t.group(1) if t else ""
+
+    def named(name: str) -> str:
+        """The instruction's own path; a fusion without one has its
+        root's."""
+        if not own[name] and opcode[name] == "fusion" and name in body:
+            return own.get(roots.get(body[name]), "")
+        return own[name]
 
     resolved: dict[str, str] = {}
 
@@ -172,15 +356,93 @@ def op_scopes(hlo_text: str) -> dict[str, str]:
         if name in resolved:
             return resolved[name]
         resolved[name] = ""             # a cycle cannot occur; be safe
-        path = own[name]
-        if not path and name in fusion_body:
-            root = roots.get(fusion_body[name])
-            path = own.get(root, "") if root else ""
+        path = named(name)
         holder = caller.get(where[name])
         if holder is not None and holder in own:
             path = _join(resolve(holder), path)
         resolved[name] = path
         return path
 
-    return {name: resolve(name) for name in own}
+    kinds: dict[str, str] = {}
 
+    def held(name: str) -> str:
+        """What the computation ``name`` wraps is, from what it holds."""
+        have = {kind(i) for i in members.get(body[name], ())}
+        return next((k for k in _HELD_ORDER if k in have), "move")
+
+    def kind(name: str) -> str:
+        if name in kinds:
+            return kinds[name]
+        kinds[name] = "other"           # a cycle cannot occur; be safe
+        base = opcode[name]
+        if base == "async" and name not in body:
+            first = operands[name][:1]  # the -done of an async-start
+            k = kind(first[0]) if first and first[0] in opcode else "other"
+        elif base in ("fusion", "async"):
+            k = held(name) if name in body else "other"
+        elif base == "scatter":
+            k = "move" if held(name) == "move" else "elementwise"
+        elif base == "custom-call":
+            k = _CUSTOM_CALLS.get(target.get(name, ""), "other")
+        else:
+            k = _KIND_OF.get(base, "other")
+        kinds[name] = k
+        return k
+
+    def mixed(name: str) -> bool:
+        if opcode[name] != "fusion" or name not in body:
+            return False
+        mine = named(name)
+        return any(own[i] and own[i] != mine
+                   for i in members.get(body[name], ()))
+
+    def crossing(comp: str) -> tuple[dict, Optional[int]]:
+        """What a fused computation brings across its boundary in part:
+        ({parameter number: bytes read of it} for a parameter that is only
+        sliced, or updated in place; the bytes written where the root
+        updates a slice of a parameter in place, else None)."""
+        users: dict[str, list] = {}
+        for i in members.get(comp, ()):
+            for k, o in enumerate(operands[i]):
+                users.setdefault(o, []).append((i, k))
+        reads = {number[p]: min(result[p], sum(result[u] for u, _ in us))
+                 for p, us in users.items()
+                 if p in number and all(opcode[u] in _SLICES and k == 0
+                                        for u, k in us)}
+        written = None
+        root = roots.get(comp)
+        if root and opcode[root] == "dynamic-update-slice":
+            into, update = operands[root][:2]
+            while opcode.get(into) == "bitcast" and len(users[into]) == 1:
+                into = operands[into][0]
+            if into in number and len(users[into]) == 1:
+                reads[number[into]] = 0
+                written = result[update]
+        return reads, written
+
+    def size(name: str) -> int:
+        known = [result.get(o, 0) for o in operands[name]]
+        out = result[name]
+        if opcode[name] in _SLICES and known:       # reads what it yields
+            known[0] = min(known[0], out)
+        elif opcode[name] == "dynamic-update-slice" and len(known) > 1:
+            known[0], out = 0, known[1]             # in place
+        elif opcode[name] == "fusion" and name in body:
+            reads, written = crossing(body[name])
+            for i, n in reads.items():
+                if i < len(known):
+                    known[i] = min(known[i], n)
+            if written is not None:
+                out = written
+        return out + sum(known)
+
+    return {name: {"scope": resolve(name), "kind": kind(name),
+                   "bytes": size(name), "mixed": mixed(name)}
+            for name in own}
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """{instruction name: scope path} over every instruction of every
+    computation of an optimized HLO module (``Compiled.as_text()``).
+    Names carry no ``%``; "" means the instruction is under no scope."""
+    return {name: w["scope"] for name, w in op_work(hlo_text).items()}

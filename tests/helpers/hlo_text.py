@@ -1,0 +1,40 @@
+"""An optimized HLO module's text without what only says where the code
+stood and what it was called: every ``metadata={...}`` and the four
+tables of file names, function names, locations and stack frames at the
+head. What is left is the program; two such texts are equal or the
+program moved (ISSUE 36: a ``jax.named_scope`` is metadata and nothing
+else)."""
+
+import re
+
+_TABLE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+    r"(?:.+\n)*\n?", re.M)
+
+
+def program_only(hlo: str) -> str:
+    """Instructions and computations are numbered anew in their order of
+    appearance: the compiler's ``.1147`` suffixes count what the unoptimized
+    module held (an inner jitted function lowered once a name stack), not
+    what the program is."""
+    text = _TABLE.sub("", re.sub(r",? ?metadata=\{[^{}]*\}", "", hlo))
+    ids: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: ids.setdefault(m.group(), f"%{len(ids)}"), text)
+
+
+def bare_step(engine, batch, ds_config, monkeypatch) -> tuple[str, str]:
+    """(the engine's compiled step, the same step built again with every
+    ``jax.named_scope`` a null context), both as ``program_only``."""
+    import contextlib
+
+    import jax
+
+    import deepspeed_tpu as ds
+    text = lambda e: program_only(e._train_step.lower(  # noqa: E731
+        e.state, e._put_batch(batch)).compile().as_text())
+    named = text(engine)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, *_ = ds.initialize(model=engine.module, config=dict(ds_config))
+    return named, text(bare)

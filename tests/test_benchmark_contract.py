@@ -100,7 +100,8 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
     named = set(re.findall(r"ds\.[a-z_]+", text))
     assert named and named <= (set(scopes.DEVICE_SCOPES)
                                | set(scopes.KIND_SCOPES)
-                               | set(scopes.SSM_SCOPES))
+                               | set(scopes.SSM_SCOPES)
+                               | set(scopes.MIXER_SCOPES))
 
     telemetry.shutdown()
     try:
@@ -128,3 +129,129 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
             e.name for e in telemetry.get_ledger().entries()}
     finally:
         telemetry.shutdown()
+
+
+# ---- the kind of work of each device op (ISSUE 36) -------------------------
+# reducers/work.py and the twenty metric definitions that read it. They are
+# data beside a by-hand reader (benchmark/tests/work_split.py) and not yet
+# files under layer_metrics/: run.py takes a cell's metrics from the
+# ``per_layer`` list of cells/<cell>.json, which only a ``benchmark`` PR may
+# edit (PERF.md section 7).
+WORK_METRICS = json.loads((BENCH / "tests" / "work_metrics.json").read_text())
+
+
+def _bench_on_path():
+    import sys
+    if str(BENCH) not in sys.path:
+        sys.path.insert(0, str(BENCH))
+
+
+@pytest.mark.parametrize("name", sorted(WORK_METRICS))
+def test_a_work_metric_is_a_metric_file_in_all_but_place(name):
+    """The keys of a metric file, a unit and a layer its neighbours use,
+    cells the contract has, a reducer of ``reducers/work.py``, and no
+    ``ds.`` name that is not a scope the program opens; a name the
+    contract does not have yet."""
+    _bench_on_path()
+    from deepspeed_tpu.telemetry import scopes
+    from lib import reducers
+    spec = WORK_METRICS[name]
+    assert set(spec) == {"layer", "unit", "better", "source", "moves",
+                         "cells", "what", "reducer"}
+    assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", name)
+    assert name not in {m["name"] for m in CONTRACT["per_layer"]}
+    assert spec["layer"] in {m["layer"] for m in CONTRACT["per_layer"]}
+    assert spec["unit"] == ("GiB" if "_gib." in name else "ms")
+    assert (spec["better"], spec["source"], spec["moves"]) == (
+        "lower", "device_trace", "train_tokens_per_s")
+    cells = {w["name"] for w in CONTRACT["workloads"]}
+    assert spec["cells"] and set(spec["cells"]) <= cells
+    args = spec["reducer"]["args"]
+    assert spec["reducer"]["name"] == (
+        "work_gib_per_step" if spec["unit"] == "GiB" else "work_ms_per_step")
+    assert callable(reducers.find(spec["reducer"]["name"]))
+    assert args["module"] == "^jit_train_step"
+    assert set(args) <= {"pattern", "exclude", "kinds", "module"}
+    assert set(args.get("kinds", ())) <= set(scopes.KINDS)
+    opened = (set(scopes.DEVICE_SCOPES) | set(scopes.KIND_SCOPES)
+              | set(scopes.SSM_SCOPES) | set(scopes.MIXER_SCOPES))
+    for text in (spec["what"], args["pattern"], args.get("exclude", "")):
+        plain = text.replace("\\b", "").replace("\\", "")   # a regex's
+        assert set(re.findall(r"ds\.[a-z_]+", plain)) <= opened, text
+
+
+def _synthetic_work(tmp_path, with_file=True):
+    """Two steps of one chip: a ``while`` that holds a matmul fusion and a
+    copy (twice a step), a loop fusion after it, a Mosaic kernel of
+    another scope; and the map the program would have exported."""
+    _bench_on_path()
+    from lib import trace as tr
+    ops, modules = [], []
+    for step in (0.0, 1.0, 2.0):        # the last run is cut off
+        modules.append(("jit_train_step(1)", step, step + 0.9))
+        ops += [("%while.1 = (s32[]) while(...)", step + 0.1, step + 0.5),
+                ("%fusion.1 = f32[8] fusion(...)", step + 0.10, step + 0.20),
+                ("%copy.1 = f32[8] copy(...)", step + 0.20, step + 0.25),
+                ("%fusion.1 = f32[8] fusion(...)", step + 0.30, step + 0.40),
+                ("%copy.1 = f32[8] copy(...)", step + 0.40, step + 0.45),
+                ("%fusion.2 = f32[8] fusion(...)", step + 0.5, step + 0.7),
+                ("%closed_call.1 = f32[8] custom-call(...)", step + 0.7,
+                 step + 0.8)]
+    trace = tr.Trace({0: {tr.OPS_LINE: ops, tr.MODULES_LINE: modules}}, {})
+    kda = "fwd:ds.layers/ds.kda"
+    rows = {"while.1": {"scope": kda, "kind": "control",
+                        "bytes": 10 ** 12, "mixed": False},
+            "fusion.1": {"scope": kda, "kind": "matmul",
+                         "bytes": 2 ** 30, "mixed": True},
+            "copy.1": {"scope": kda, "kind": "move",
+                       "bytes": 2 ** 29, "mixed": False},
+            "fusion.2": {"scope": kda + "/ds.mix_pre", "kind": "elementwise",
+                         "bytes": 2 ** 28, "mixed": False},
+            "closed_call.1": {"scope": kda + "/ds.kda_scan/ds.kda_fwd",
+                              "kind": "kernel", "bytes": 2 ** 27,
+                              "mixed": False}}
+    scopes_path = tmp_path / "t.op_scopes.json"
+    scopes_path.write_text(json.dumps(
+        {"compiled_step": {k: v["scope"] for k, v in rows.items()}}))
+    if with_file:
+        (tmp_path / "t.op_work.json").write_text(
+            json.dumps({"compiled_step": rows}))
+    return {"trace": trace, "ledger_entry": "compiled_step",
+            "op_scopes_path": str(scopes_path)}
+
+
+@pytest.mark.parametrize("args,ms,gib", [
+    # every kind: the while lends nothing of its own (its gaps, 0.05 s
+    # twice a step, belong to no leaf), and its 10**12 bytes are not read
+    ({"pattern": r"ds\.kda\b"}, 600.0, 2 * 1.0 + 2 * 0.5 + 0.25 + 0.125),
+    ({"pattern": r"ds\.kda\b", "exclude": r"ds\.kda_scan\b"},
+     500.0, 2 * 1.0 + 2 * 0.5 + 0.25),
+    ({"pattern": r"ds\.kda\b", "exclude": r"ds\.kda_scan\b",
+      "kinds": ["matmul"]}, 200.0, 2.0),    # a leaf in a loop: per event
+    ({"pattern": r"ds\.kda\b", "kinds": ["move"]}, 100.0, 1.0),
+    ({"pattern": r"ds\.kda\b", "kinds": ["elementwise", "move"]},
+     300.0, 1.25),
+    ({"pattern": r"ds\.kda\b.*ds\.mix_pre\b"}, 200.0, 0.25),
+    ({"pattern": "", "kinds": ["kernel"]}, 100.0, 0.125),
+    ({"pattern": r"ds\.mamba\b"}, 0.0, 0.0),
+])
+def test_work_reducers_on_a_synthetic_trace(tmp_path, args, ms, gib):
+    _bench_on_path()
+    from lib import reducers
+    ctx = _synthetic_work(tmp_path)
+    args = dict(args, module="^jit_train_step")
+    assert reducers.find("work_ms_per_step")(ctx, args) == pytest.approx(ms)
+    assert reducers.find("work_gib_per_step")(ctx, args) == gib
+
+
+def test_work_reducers_read_nothing_where_the_program_wrote_no_file(
+        tmp_path):
+    """The parent: ``op_scopes.json`` is there, ``op_work.json`` is not;
+    the metric is left out, nothing raises."""
+    _bench_on_path()
+    from lib import reducers
+    args = {"pattern": "", "module": "^jit_train_step"}
+    for ctx in (_synthetic_work(tmp_path, with_file=False),
+                {"trace": None}, {"trace": None, "op_scopes_path": None}):
+        assert reducers.find("work_ms_per_step")(ctx, args) is None
+        assert reducers.find("work_gib_per_step")(ctx, args) is None

@@ -329,3 +329,204 @@ def test_op_scopes_exported_and_mfu_gauge_gone(tmp_path):
     assert "ds_" + "mfu" not in prom
     assert "ds_ledger_dispatched_flops_total" in prom
     assert "deepspeed_tpu.telemetry.scopes" in sys.modules
+
+
+# ---- the kind of work and the bytes of each instruction (ISSUE 36) --------
+# an optimized module by hand, as the TPU compiler prints one: a row of
+# the table of kinds each
+_WORK_HLO = """
+%fused_dot (p0: bf16[8,16], p1: bf16[16,32], p2: f32[32]) -> f32[8,32] {
+  %p0 = bf16[8,16]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[16,32]{1,0:T(8,128)(2,1)} parameter(1)
+  %p2 = f32[32]{0:T(256)} parameter(2)
+  %convolution.1 = f32[8,32]{1,0:T(8,128)} convolution(%p0, %p1), dim_labels=bf_io->bf, metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/dot_general"}
+  %broadcast.1 = f32[8,32]{1,0:T(8,128)} broadcast(%p2), dimensions={1}
+  ROOT %add.1 = f32[8,32]{1,0:T(8,128)} add(%convolution.1, %broadcast.1), metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/ds.mix_pre/add"}
+}
+
+%fused_loop (q0: f32[8,32]) -> bf16[8,32] {
+  %q0 = f32[8,32]{1,0:T(8,128)} parameter(0)
+  %exp.1 = f32[8,32]{1,0:T(8,128)} exponential(%q0), metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/ds.mix_pre/exp"}
+  ROOT %convert.1 = bf16[8,32]{1,0:T(8,128)(2,1)} convert(%exp.1), metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/ds.mix_pre/convert"}
+}
+
+%fused_transpose (r0: bf16[8,32]) -> bf16[32,8] {
+  %r0 = bf16[8,32]{1,0:T(8,128)(2,1)} parameter(0)
+  %bitcast.1 = bf16[8,32]{1,0:T(8,128)(2,1)} bitcast(%r0)
+  ROOT %transpose.1 = bf16[32,8]{1,0:T(8,128)(2,1)} transpose(%bitcast.1), dimensions={1,0}
+}
+
+%fused_dus (s0: bf16[4,32,8], s1: bf16[32,8], s2: s32[]) -> bf16[4,32,8] {
+  %s0 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %s1 = bf16[32,8]{1,0:T(8,128)(2,1)} parameter(1)
+  %s2 = s32[]{:T(128)} parameter(2)
+  %constant.9 = s32[]{:T(128)} constant(0)
+  %bitcast.2 = bf16[1,32,8]{2,1,0:T(8,128)(2,1)} bitcast(%s1)
+  ROOT %dynamic-update-slice.1 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%s0, %bitcast.2, %s2, %constant.9, %constant.9)
+}
+
+%fused_sliced (t0: bf16[64,32], t1: bf16[16,32]) -> bf16[16,32] {
+  %t0 = bf16[64,32]{1,0:T(8,128)(2,1)} parameter(0)
+  %t1 = bf16[16,32]{1,0:T(8,128)(2,1)} parameter(1)
+  %slice.9 = bf16[16,32]{1,0:T(8,128)(2,1)} slice(%t0), slice={[16:32], [0:32]}
+  ROOT %add.9 = bf16[16,32]{1,0:T(8,128)(2,1)} add(%slice.9, %t1)
+}
+
+%cond (c: (s32[], bf16[4,32,8])) -> pred[] {
+  %c = (s32[]{:T(128)}, bf16[4,32,8]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i.1 = s32[]{:T(128)} get-tuple-element(%c), index=0
+  %constant.4 = s32[]{:T(128)} constant(4)
+  ROOT %lt.1 = pred[]{:T(512)} compare(%i.1, %constant.4), direction=LT
+}
+
+%body (b: (s32[], bf16[4,32,8])) -> (s32[], bf16[4,32,8]) {
+  %b = (s32[]{:T(128)}, bf16[4,32,8]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i.2 = s32[]{:T(128)} get-tuple-element(%b), index=0
+  %x.2 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} get-tuple-element(%b), index=1
+  %copy.2 = bf16[4,32,8]{2,0,1:T(8,128)(2,1)} copy(%x.2)
+  %closed_call.1 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} custom-call(%copy.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/jvp(ds.layers)/while/body/ds.kda/ds.kda_scan/ds.kda_fwd/pallas_call"}
+  %constant.1 = s32[]{:T(128)} constant(1)
+  %next.1 = s32[]{:T(128)} add(%i.2, %constant.1)
+  ROOT %tuple.2 = (s32[]{:T(128)}, bf16[4,32,8]{2,1,0:T(8,128)(2,1)}) tuple(%next.1, %closed_call.1)
+}
+
+ENTRY %main (x: bf16[8,16], w: bf16[16,32], b: f32[32], buf: bf16[4,32,8]) -> (bf16[4,32,8], bf16[32,32]) {
+  %x = bf16[8,16]{1,0:T(8,128)(2,1)} parameter(0)
+  %w = bf16[16,32]{1,0:T(8,128)(2,1)} parameter(1)
+  %b = f32[32]{0:T(256)} parameter(2)
+  %buf = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} parameter(3)
+  %fusion.1 = f32[8,32]{1,0:T(8,128)} fusion(%x, %w, %b), kind=kOutput, calls=%fused_dot, metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/ds.mix_pre/add"}
+  %fusion.2 = bf16[8,32]{1,0:T(8,128)(2,1)} fusion(%fusion.1), kind=kLoop, calls=%fused_loop, metadata={op_name="jit(f)/jvp(ds.layers)/ds.kda/ds.mix_pre/convert"}
+  %copy.1 = bf16[8,32]{0,1:T(8,128)(2,1)} copy(%fusion.2)
+  %fusion.3 = bf16[32,8]{1,0:T(8,128)(2,1)} fusion(%copy.1), kind=kLoop, calls=%fused_transpose
+  %constant.2 = s32[]{:T(128)} constant(2)
+  %fusion.4 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} fusion(%buf, %fusion.3, %constant.2), kind=kLoop, calls=%fused_dus
+  %all-gather-start.1 = (bf16[16,32]{1,0:T(8,128)(2,1)}, bf16[64,32]{1,0:T(8,128)(2,1)}) all-gather-start(%w), dimensions={0}, metadata={op_name="jit(f)/jvp(ds.layers)/ds.mlp/all_gather"}
+  %all-gather-done.1 = bf16[64,32]{1,0:T(8,128)(2,1)} all-gather-done(%all-gather-start.1)
+  %slice.1 = bf16[32,32]{1,0:T(8,128)(2,1)} slice(%all-gather-done.1), slice={[0:32], [0:32]}
+  %fusion.5 = bf16[16,32]{1,0:T(8,128)(2,1)} fusion(%all-gather-done.1, %w), kind=kLoop, calls=%fused_sliced
+  %dynamic-update-slice.2 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%buf, %fusion.3, %constant.2, %constant.2, %constant.2)
+  %constant.3 = s32[]{:T(128)} constant(0)
+  %tuple.1 = (s32[]{:T(128)}, bf16[4,32,8]{2,1,0:T(8,128)(2,1)}) tuple(%constant.3, %fusion.4)
+  %while.1 = (s32[]{:T(128)}, bf16[4,32,8]{2,1,0:T(8,128)(2,1)}) while(%tuple.1), condition=%cond, body=%body, metadata={op_name="jit(f)/jvp(ds.layers)/while"}
+  %gte.1 = bf16[4,32,8]{2,1,0:T(8,128)(2,1)} get-tuple-element(%while.1), index=1
+  %frobnicate.1 = bf16[32,32]{1,0:T(8,128)(2,1)} frobnicate(%slice.1)
+  ROOT %tuple.3 = (bf16[4,32,8]{2,1,0:T(8,128)(2,1)}, bf16[32,32]{1,0:T(8,128)(2,1)}) tuple(%gte.1, %frobnicate.1)
+}
+"""
+
+
+@pytest.mark.parametrize("name,kind,nbytes,mixed", [
+    # a dot fusion with its epilogue: 8x16 + 16x32 in bf16, the bias and
+    # the result in float32; it fused ds.kda's dot under ds.mix_pre's root
+    ("fusion.1", "matmul", 256 + 1024 + 128 + 1024, True),
+    ("fusion.2", "elementwise", 1024 + 512, False),     # a loop fusion
+    ("copy.1", "move", 512 + 512, False),
+    ("fusion.3", "move", 512 + 512, False),             # transpose only
+    # a dynamic-update-slice fusion runs in place: the update read and
+    # written and the index, not the buffer twice
+    ("fusion.4", "move", 512 + 512 + 4, False),
+    ("closed_call.1", "kernel", 2048 + 2048, False),
+    ("all-gather-start.1", "collective", 1024 + 4096 + 1024, False),
+    ("all-gather-done.1", "collective", 4096 + 1024 + 4096, False),
+    ("slice.1", "move", 2048 + 2048, False),            # what it reads
+    # a fusion that only slices an operand reads the slice of it
+    ("fusion.5", "elementwise", 1024 + 1024 + 1024, False),
+    ("dynamic-update-slice.2", "move", 512 + 512 + 3 * 4, False),
+    ("while.1", "control", 2 * (4 + 2048), False),
+    ("copy.2", "move", 2048 + 2048, False),              # a leaf in the body
+    ("tuple.1", "control", 4 + 2048 + 4 + 2048, False),
+    ("x", "control", 256, False),
+    ("convolution.1", "matmul", 1024 + 256 + 1024, False),
+    ("lt.1", "elementwise", 1 + 4 + 4, False),
+    ("frobnicate.1", "other", 2048 + 2048, False),       # not in the table
+])
+def test_the_kind_bytes_and_mix_of_each_instruction(name, kind, nbytes,
+                                                    mixed):
+    row = scopes.op_work(_WORK_HLO)[name]
+    assert (row["kind"], row["bytes"], row["mixed"]) == (kind, nbytes, mixed)
+    assert row["kind"] in scopes.KINDS
+
+
+def test_op_work_carries_the_scopes_op_scopes_returns():
+    work = scopes.op_work(_WORK_HLO)
+    got = scopes.op_scopes(_WORK_HLO)
+    assert list(got) == list(work)              # the same walk, in order
+    assert got == {name: row["scope"] for name, row in work.items()}
+    assert got["fusion.1"] == "fwd:ds.layers/ds.kda/ds.mix_pre"
+    assert got["copy.2"] == "fwd:ds.layers"     # its holder's
+    assert got["closed_call.1"] == \
+        "fwd:ds.layers/ds.kda/ds.kda_scan/ds.kda_fwd"
+    assert got["all-gather-done.1"] == ""
+    assert set(work["fusion.1"]) == {"scope", "kind", "bytes", "mixed"}
+
+
+def test_a_scatter_is_a_move_only_if_it_assigns():
+    hlo = """
+%assign (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  ROOT %b = f32[] parameter(1)
+}
+
+%plus (c: f32[], d: f32[]) -> f32[] {
+  %c = f32[] parameter(0)
+  %d = f32[] parameter(1)
+  ROOT %add.3 = f32[] add(%c, %d)
+}
+
+%wrapped (e: f32[8,4]) -> f32[2,4] {
+  %e = f32[8,4]{1,0} parameter(0)
+  ROOT %slice.5 = f32[2,4]{1,0} slice(%e), slice={[0:2], [0:4]}
+}
+
+ENTRY %main (t: f32[8,4], i: s32[2,1], u: f32[2,4]) -> f32[8,4] {
+  %t = f32[8,4]{1,0} parameter(0)
+  %i = s32[2,1]{1,0} parameter(1)
+  %u = f32[2,4]{1,0} parameter(2)
+  %scatter.1 = f32[8,4]{1,0} scatter(%t, %i, %u), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%assign
+  %slice-start.1 = ((f32[8,4]{1,0}), f32[2,4]{1,0}, s32[]) async-start(%scatter.1), calls=%wrapped
+  %slice-done.1 = f32[2,4]{1,0} async-done(%slice-start.1)
+  %buffer.1 = f32[8,4]{1,0} custom-call(), custom_call_target="AllocateBuffer"
+  ROOT %scatter.2 = f32[8,4]{1,0} scatter(%scatter.1, %i, %slice-done.1), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%plus
+}
+"""
+    work = scopes.op_work(hlo)
+    assert work["scatter.1"]["kind"] == "move"
+    assert work["scatter.2"]["kind"] == "elementwise"
+    # an asynchronous pair is what it wraps, both halves
+    assert work["slice-start.1"]["kind"] == "move"
+    assert work["slice-done.1"]["kind"] == "move"
+    assert work["buffer.1"]["kind"] == "control"
+    assert work["scatter.1"]["bytes"] == 128 + 128 + 8 + 32
+
+
+def test_no_instruction_of_the_train_step_is_of_an_unknown_kind(step_hlo):
+    work = scopes.op_work(step_hlo)
+    assert {row["kind"] for row in work.values()} <= set(scopes.KINDS)
+    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
+    assert not unknown, unknown
+    kinds = {row["kind"] for row in work.values()}
+    assert {"matmul", "elementwise", "move", "control",
+            "collective"} <= kinds
+    assert all(row["bytes"] >= 0 for row in work.values())
+
+
+def test_op_work_is_exported_beside_op_scopes(tmp_path):
+    import json
+    engine, batch = _tiny_engine(
+        {"telemetry": {"enabled": True, "executable_ledger": True}})
+    engine.train_batch(batch).block_until_ready()
+    paths = telemetry.export_artifacts(str(tmp_path), prefix="t")
+    assert paths["op_work"] == str(tmp_path / "t.op_work.json")
+    with open(paths["op_work"]) as f:
+        work = json.load(f)["compiled_step"]
+    with open(paths["op_scopes"]) as f:
+        text = f.read()
+    # op_scopes.json is what it was: the same names in the same order
+    # with the same paths, in the same bytes
+    led = telemetry.get_ledger()
+    assert text == json.dumps(led.op_scopes_by_name())
+    assert json.loads(text)["compiled_step"] == {
+        name: row["scope"] for name, row in work.items()}
+    assert work == led.op_work_by_name()["compiled_step"]
+    assert not any(row["kind"] == "other" for row in work.values())

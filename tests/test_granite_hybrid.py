@@ -33,6 +33,8 @@ from architectures import granite_hybrid as arch  # noqa: E402
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
 
+from helpers import hlo_text  # noqa: E402  (tests/helpers)
+
 CONFIG = json.loads(
     (BENCH / "configs" / "granite-4.0-h-micro-zero3-1chip.json").read_text())
 
@@ -316,7 +318,8 @@ def test_step_scopes_are_the_lists(granite_engine):
     found = set()
     for op_name in re.findall(r'op_name="([^"]*)"', hlo):
         found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
-    assert found == set(scopes.DEVICE_SCOPES) | set(scopes.SSM_SCOPES)
+    assert found == (set(scopes.DEVICE_SCOPES) | set(scopes.SSM_SCOPES)
+                     | set(scopes.MIXER_SCOPES))
     by_op = scopes.op_scopes(hlo)
     paths = {p for p in by_op.values() if p}
     for scope in ("ds.mamba/ds.ssd", "ds.attn/ds.flash_fwd", "ds.mlp"):
@@ -330,6 +333,38 @@ def test_step_scopes_are_the_lists(granite_engine):
     # names by the innermost scope alone)
     scan = [p for p in by_op.values() if "ds.ssd" in p]
     assert sum("ds.mamba" in p for p in scan) > 0.99 * len(scan)
+
+
+def test_the_mixer_parts_lie_inside_ds_mamba_and_no_kind_is_unknown(
+        granite_engine):
+    """ISSUE 36: the convolution and what lies before and after the scan
+    are named inside ds.mamba, straight under it in both directions and
+    never inside the attention layer or the FFN (the compiler moves an
+    instruction or two of them into the scan's loop, whose path then
+    holds theirs); the table of kinds knows every instruction of the
+    step."""
+    engine, batch = granite_engine
+    hlo = engine._train_step.lower(
+        engine.state, engine._put_batch(batch)).compile().as_text()
+    work = scopes.op_work(hlo)
+    paths = {row["scope"] for row in work.values()}
+    for part in scopes.MIXER_SCOPES:
+        mine = {p for p in paths if re.search(rf"{re.escape(part)}\b", p)}
+        assert {f"{d}:ds.layers/ds.mamba/{part}"
+                for d in ("fwd", "bwd")} <= mine, (part, mine)
+        assert all("ds.layers/ds.mamba/" in p and "ds.attn" not in p
+                   and "ds.mlp" not in p for p in mine), (part, mine)
+    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
+    assert not unknown, unknown
+
+
+def test_the_named_scopes_are_metadata_and_nothing_else(granite_engine,
+                                                        monkeypatch):
+    """The step compiled with every ``jax.named_scope`` a null context is
+    the same optimized program once ``metadata={...}`` is taken out."""
+    named, bare = hlo_text.bare_step(*granite_engine, _DS_CONFIG, monkeypatch)
+    assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
+    assert bare == named
 
 
 # ---- the stack's first caller is the program it was ------------------------

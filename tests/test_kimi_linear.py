@@ -33,7 +33,7 @@ if str(BENCH) not in sys.path:
 from architectures import kimi_linear as arch  # noqa: E402
 from lib import modelspec  # noqa: E402
 
-from helpers import kda_reference  # noqa: E402  (tests/helpers)
+from helpers import hlo_text, kda_reference  # noqa: E402  (tests/helpers)
 
 
 @pytest.fixture(autouse=True)
@@ -723,7 +723,7 @@ def test_step_scopes_are_the_lists(kimi_engine):
     for op_name in re.findall(r'op_name="([^"]*)"', hlo):
         found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
     assert found == (set(scopes.DEVICE_SCOPES) - {"ds.attn"}
-                     | set(scopes.KIND_SCOPES))
+                     | set(scopes.KIND_SCOPES) | set(scopes.MIXER_SCOPES))
     got = scopes.op_scopes(hlo)
     paths = {p for p in got.values() if p}
     for scope in ("ds.kda/ds.kda_scan", "ds.mla/ds.flash_fwd",
@@ -744,6 +744,41 @@ def test_step_scopes_are_the_lists(kimi_engine):
                           if p.endswith("/" + name)}
     assert sides("ds.kda_fwd") == sides("ds.kda_prep_fwd") == {"fwd", "bwd"}
     assert sides("ds.kda_bwd") == sides("ds.kda_prep_bwd") == {"bwd"}
+
+
+def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
+        kimi_engine):
+    """ISSUE 36: the convolution and what lies before and after the scan
+    are named inside ds.kda, straight under it in both directions and
+    never inside the MLA layer; the layer's pre-norm counts with its
+    mixer; and the table of kinds knows every instruction of the step."""
+    engine, batch = kimi_engine
+    hlo = engine._train_step.lower(
+        engine.state, engine._put_batch(batch)).compile().as_text()
+    work = scopes.op_work(hlo)
+    paths = {row["scope"] for row in work.values()}
+    for part in scopes.MIXER_SCOPES:
+        mine = {p for p in paths if re.search(rf"{re.escape(part)}\b", p)}
+        assert {f"{d}:ds.layers/ds.kda/{part}"
+                for d in ("fwd", "bwd")} <= mine, (part, mine)
+        assert all("ds.layers/ds.kda/" in p and "ds.mla" not in p
+                   for p in mine), (part, mine)
+    # the layer's pre-norm is the one rsqrt straight under ds.kda (the l2
+    # norms are ds.mix_pre's, o_norm is ds.mix_post's)
+    norms = {row["scope"] for name, row in work.items()
+             if name.startswith("rsqrt")}
+    assert {"fwd:ds.layers/ds.kda", "bwd:ds.layers/ds.kda"} <= norms, norms
+    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
+    assert not unknown, unknown
+
+
+def test_the_named_scopes_are_metadata_and_nothing_else(kimi_engine,
+                                                        monkeypatch):
+    """The step compiled with every ``jax.named_scope`` a null context is
+    the same optimized program once ``metadata={...}`` is taken out."""
+    named, bare = hlo_text.bare_step(*kimi_engine, _DS_CONFIG, monkeypatch)
+    assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
+    assert bare == named
 
 
 # ---- the one-kind scan is the parent's program -----------------------------
