@@ -218,8 +218,11 @@ class GraniteHybrid(StackOfKinds):
         return attn_fn(q, k, v, causal=True).reshape(b, s, nh * hd) @ p["wo"]
 
     def _mixers(self, attn_fn, act_sharding):
-        from ..ops.ssd import chunk_ssd
-        return attn_fn, chunk_ssd
+        """The scan's kernels run per shard of ``act_sharding`` where the
+        mesh has more than one device."""
+        from ..ops.ssd import chunk_ssd, sharded_chunk_ssd
+        return (attn_fn, chunk_ssd if act_sharding is None
+                else sharded_chunk_ssd(act_sharding))
 
     def _residual(self, x, y):
         """x + residual_multiplier * y, in float32 and rounded once (0.22

@@ -16,24 +16,43 @@ inside a chunk with incoming state ``S`` and ``a_r = sum_{i<=r} dt_i A``::
 
 The first sum is a masked [Q, Q] matrix a head and chunk times the chunk's
 inputs, the sum in ``S'`` one [P, Q] x [Q, N] product a chunk; only
-``S' = exp(a_Q) S + ...`` is serial, and runs as one ``lax.scan`` over the
-chunks. Every exponent is a difference ``a_i - a_j`` with ``i >= j`` (or
-``a_r`` itself): never positive, taken BEFORE the exponential, so nothing
+``S' = exp(a_Q) S + ...`` is serial in the chunks. Every exponent is a
+difference ``a_i - a_j`` with ``i >= j`` (or ``a_r`` itself): never
+positive, taken BEFORE the exponential, so nothing
 overflows however fast a head decays (``dt A`` of -1.6 a token is -410 over
 a chunk of 256; a product ``exp(a_i) exp(-a_j)`` would be inf x 0). The
 decays, their cumulative sums and the carried state are float32; the
-matmuls run in ``x``'s dtype with float32 accumulation. The backward is
-autodiff's. ``D x`` and the output gate are the caller's.
+matmuls run in ``x``'s dtype with float32 accumulation. ``D x`` and the
+output gate are the caller's.
 
-All of it is ``jax.numpy``: the masked decay matrix [B, H, S/Q, Q, Q] goes
-through HBM in float32 (537 MB a layer at 64 heads x 8192 tokens), which
-is what a kernel would keep in VMEM (``PERF.md`` section 7).
+It runs as a Pallas kernel pair under one ``jax.custom_vjp``
+(``ops/pallas/ssd.py`` ``ssd_scan``, PR 37). The forward reads x, dt, B, C
+once in the model's own layout, makes a chunk's running sum ``a`` for every
+head, ``C B^T`` once a group and each head's masked decay blocks in VMEM,
+carries the float32 state in VMEM across the chunks and writes ``y``
+alone. The backward takes the chunks last to first from the state each
+chunk started from (the forward kernel's second form: float32, 67 MB a
+layer at the cell's shape, alive only inside the backward), carries ``dS``
+in VMEM and writes dx, ddt, dB, dC and dA's share. Nothing of size [Q, Q]
+reaches HBM and the residuals are the five inputs. A rematted layer runs
+``ds_ssd_fwd`` twice (its ``y`` feeds the gate and the gated norm, whose
+backward needs it again), the second form once and ``ds_ssd_bwd`` once
+(``PERF.md`` section 5 has their times). Until PR 37 all of it was
+``jax.numpy`` under autodiff, the decay matrix [B, H, S/Q, Q, Q] through
+HBM in float32 (537 MB a layer at 64 heads x 8192 tokens):
+``tests/helpers/ssd_reference.py`` keeps that form as the kernels'
+reference.
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+
+from .pallas.ssd import ssd_scan
 
 CHUNK = 256     # tokens a chunk (``mamba_chunk_size`` as published)
 
@@ -65,58 +84,42 @@ def chunk_ssd(x, dt, A, B, C, *, chunk: int = CHUNK):
     [B, S, H, P] in ``x``'s dtype. ``S`` must be a multiple of ``chunk``.
     All heads at once and no checkpoint of its own: the caller's remat of
     the layer is the only rerun."""
-    f32 = jnp.float32
-    b, s, h, p = x.shape
-    g, n = B.shape[2:]
+    s, h = x.shape[1:3]
+    g = B.shape[2]
     if s % chunk:
         raise ValueError(f"chunk_ssd: sequence {s} must be a multiple of "
                          f"the chunk {chunk}")
     if h % g:
         raise ValueError(f"chunk_ssd: {h} heads in {g} groups of B and C")
-    c, r = s // chunk, h // g
-    mm = x.dtype
     with jax.named_scope("ds.ssd"):
-        dt = dt.astype(f32).reshape(b, c, chunk, g, r)
-        a = jnp.cumsum(dt * A.astype(f32).reshape(g, r), axis=2)
-        xd = (x.astype(f32).reshape(b, c, chunk, g, r, p)
-              * dt[..., None]).astype(mm)           # dt_j x_j
-        B = B.reshape(b, c, chunk, g, n).astype(mm)
-        C = C.reshape(b, c, chunk, g, n).astype(mm)
-        a = jnp.moveaxis(a, 2, -1)                  # [b, c, g, r, Q]
-        # within a chunk: <C_i, B_j> exp(a_i - a_j) for j <= i
-        ii = jnp.arange(chunk)
-        diff = a[..., :, None] - a[..., None, :]
-        decay = jnp.exp(jnp.where(ii[:, None] >= ii[None, :], diff,
-                                  -jnp.inf))        # [b, c, g, r, Q, Q]
-        cb = jnp.einsum("bcign,bcjgn->bcgij", C, B,
-                        preferred_element_type=f32)
-        m = (cb[:, :, :, None] * decay).astype(mm)
-        y = jnp.einsum("bcgrij,bcjgrp->bcigrp", m, xd,
-                       preferred_element_type=f32)
-        # each chunk's own contribution to the state at its end
-        last = a[..., -1]                           # a_Q  [b, c, g, r]
-        to_end = jnp.exp(last[..., None] - a)       # exp(a_Q - a_j) <= 1
-        # from the rounded xd, not its float32 form: kept live for this, the
-        # float32 array cost 1.7 ms a step on the chip (PR 34)
-        xe = (xd.astype(f32)
-              * jnp.moveaxis(to_end, -1, 2)[..., None]).astype(mm)
-        own = jnp.einsum("bcjgrp,bcjgn->bcgrpn", xe, B,
-                         preferred_element_type=f32)
+        return ssd_scan(x, dt, A, B, C, chunk=chunk)
 
-        # the recurrence over the chunks: the state each chunk starts from
-        # (a running sum of log-decays is never positive: the clamps only
-        # say so)
-        def step(state, xs):
-            own_c, shrink = xs
-            return state * shrink[..., None, None] + own_c, state
 
-        _, start = jax.lax.scan(
-            step, jnp.zeros((b, g, r, p, n), f32),
-            (jnp.moveaxis(own, 1, 0),
-             jnp.moveaxis(jnp.exp(jnp.minimum(last, 0.0)), 1, 0)))
-        start = jnp.moveaxis(start, 0, 1)           # [b, c, g, r, p, n]
-        carried = jnp.einsum("bcign,bcgrpn->bcigrp", C, start.astype(mm),
-                             preferred_element_type=f32)
-        y = y + carried * jnp.moveaxis(
-            jnp.exp(jnp.minimum(a, 0.0)), -1, 2)[..., None]
-    return y.reshape(b, s, h, p).astype(x.dtype)
+def sharded_chunk_ssd(act_sharding):
+    """``chunk_ssd`` for a multi-device mesh: per shard of the batch under
+    a shard_map, because GSPMD cannot partition the kernels' Mosaic calls
+    (the twin of ``ops.kda.sharded_chunk_kda``, which see). The batch is
+    split over ``act_sharding``'s batch axes where they divide it, every
+    other axis sees replicated inputs (heads and sequences are
+    independent, so the per-shard result is exact)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import active_mesh
+    from ..utils.jax_compat import shard_map
+
+    entry = act_sharding.spec[0] if len(act_sharding.spec) else None
+    batch_axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+
+    def ssd(x, dt, A, B, C, **kw):
+        use, free = active_mesh(act_sharding.mesh)
+        b_ax = tuple(a for a in batch_axes
+                     if a in free and use.shape[a] > 1)
+        if x.shape[0] % math.prod(use.shape[a] for a in b_ax):
+            b_ax = ()       # uneven batch: replicate, still exact
+        wide, flat = (P(b_ax or None, *[None] * n) for n in (3, 2))
+        return shard_map(
+            functools.partial(chunk_ssd, **kw), mesh=use,
+            axis_names=set(free), in_specs=(wide, flat, P(), wide, wide),
+            out_specs=wide, check_vma=False)(x, dt, A, B, C)
+
+    return ssd

@@ -118,8 +118,11 @@ KIND_SCOPES = (
 SSM_SCOPES = (
     "ds.mamba",        # models/granite_hybrid.py _one_layer: the Mamba-2
     #                    mixer (norm, projections, convolution, gate, norm)
-    "ds.ssd",          # ops/ssd.py chunk_ssd: the chunked state-space scan,
-    #                    forward, remat's reruns and backward
+    "ds.ssd",          # ops/ssd.py chunk_ssd: the chunked state-space scan
+    #                    (the kernels and XLA's copies round them; the
+    #                    backward rule opens it again, outside the forward's)
+    "ds.ssd_fwd",      # ops/pallas/ssd.py _forward: ds_ssd_fwd, either form
+    "ds.ssd_bwd",      # ops/pallas/ssd.py _backward: ds_ssd_bwd
 )
 # the parts of a recurrent mixer round its scan, opened inside ds.kda and
 # ds.mamba alike; both model tests add this list to what they expect. The

@@ -297,3 +297,63 @@ def test_kda_preparation_kernels_compile_for_v5e(monkeypatch):
     with pytest.raises(ValueError, match="multiples of 128"):
         fwd.lower(*(sd(x.shape[:3] + (64,) * (len(x.shape) - 3), x.dtype)
                     for x in ins))
+
+
+def test_ssd_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
+    """The Mamba-2 scan's kernel pair (PR 37) at the cell's widths (one
+    sequence of 8192, 64 heads of 64 in one group, state 128, chunk 256;
+    and Nemotron-H's 8 groups), forward and the rematted layer's backward,
+    compiled by Mosaic for one described v5e chip: the row-form running
+    sums, the one-hot products that spread them, the transposed products
+    and the blocks of the model's own layouts are what interpret mode
+    cannot refuse. Then ``sharded_chunk_ssd`` on ``v5e:2x2`` with the batch
+    over ``fsdp``, where the bare call cannot be partitioned."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.ops import ssd
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shapes(b, s, h, p, g, n, place):
+        sd = lambda *dims, dt=bf: jax.ShapeDtypeStruct(  # noqa: E731
+            dims, dt, sharding=place(len(dims)))
+        return (sd(b, s, h, p), sd(b, s, h, dt=f32), sd(h, dt=f32),
+                sd(b, s, g, n), sd(b, s, g, n))
+
+    def grad(fn, chunk):
+        layer = jax.checkpoint(lambda *a: fn(*a, chunk=chunk))
+        return jax.jit(jax.grad(
+            lambda *a: 0.5 * jnp.sum(layer(*a).astype(f32) ** 2),
+            argnums=(0, 1, 2, 3, 4)))
+
+    for h, g in ((64, 1), (128, 8)):
+        args = shapes(1, 8192 * 64 // h, h, 64, g, 128, lambda _: one)
+        hlo = grad(ssd.chunk_ssd, 256).lower(*args).compile().as_text()
+        for kernel, calls in (("ds_ssd_fwd", 2), ("ds_ssd_bwd", 1)):
+            found = re.findall(rf"%{kernel}[.\w]* = .*custom-call", hlo)
+            assert len(found) == calls, (h, g, kernel, len(found))
+    with pytest.raises(ValueError, match="not 1 x 64, 128 and 256"):
+        jax.jit(lambda *a: ssd.chunk_ssd(*a, chunk=256)).lower(
+            *shapes(1, 1024, 8, 64, 8, 128, lambda _: one))
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    act = mt.sharding(mt.batch_axes(), "sp")
+    rows = lambda n: NamedSharding(  # noqa: E731
+        mt.mesh, P(*([mt.batch_axes()] + [None] * (n - 1)) if n > 1
+                   else P()))
+    args = shapes(4, 1024, 8, 64, 1, 128, rows)
+    hlo = grad(ssd.sharded_chunk_ssd(act), 256).lower(
+        *args).compile().as_text()
+    for kernel in ("ds_ssd_fwd", "ds_ssd_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo and "all-gather" not in hlo
+    with pytest.raises(Exception, match="[Mm]osaic"):
+        grad(ssd.chunk_ssd, 256).lower(*args).compile()
