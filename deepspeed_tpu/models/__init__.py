@@ -9,6 +9,7 @@ from .granite_hybrid import GraniteHybrid, granite_hybrid_config  # noqa: F401
 from .internlm import InternLM, internlm_config  # noqa: F401
 from .kimi_linear import KimiLinear, kimi_linear_config  # noqa: F401
 from .llama import Llama, llama_config  # noqa: F401
+from .mellum import Mellum, mellum_config  # noqa: F401
 from .mistral import Mistral, mistral_config  # noqa: F401
 from .mixtral import Mixtral, mixtral_config  # noqa: F401
 from .opt import OPT, opt_config  # noqa: F401

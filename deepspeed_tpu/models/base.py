@@ -36,8 +36,11 @@ class ModelConfig:
     norm_type: str = "layernorm"        # layernorm | rmsnorm
     activation: str = "gelu"            # gelu | relu | swiglu
     position_embedding: str = "learned"  # learned | rope | alibi (Bloom);
-    #                                      anything else ("none", "nope"):
-    #                                      the model has no positions
+    #                                      anything else: DecoderLM adds no
+    #                                      positions ("none", "nope": the
+    #                                      model has none; "rope_by_kind":
+    #                                      models/mellum.py rotates by its
+    #                                      rope_parameters)
     use_bias: bool = True
     attn_qkv_bias: bool = False     # qkv biases even when use_bias=False
     #                                 (Qwen-style)
@@ -50,6 +53,9 @@ class ModelConfig:
     embed_layernorm: bool = False   # Bloom: LayerNorm after word embed
     rotary_pct: float = 1.0         # partial rotary (GPT-NeoX/Phi-2)
     sliding_window: int | None = None  # Mistral windowed attention
+    attn_head_dim: int = 0          # the width of an attention head where it
+    #                                 is published apart from the hidden
+    #                                 size (0: hidden_size // num_heads)
     # MoE (0 experts = dense; reference: deepspeed/moe)
     num_experts: int = 0
     moe_num_shared_experts: int = 0  # Qwen2-MoE always-on experts
@@ -90,8 +96,17 @@ class ModelConfig:
     mla_use_nope: bool = False      # no rotation on either part of q, k
     # Mamba-2 state-space layers among attention layers
     # (models/granite_hybrid.py, ops/ssd.py); key names as published
-    layer_types: tuple | list = ()  # "mamba" | "attention" a layer; empty =
-    #                                 one kind of layer
+    layer_types: tuple | list = ()  # "mamba" | "attention" a layer
+    #                                 (models/granite_hybrid.py), or
+    #                                 "sliding_attention" | "full_attention"
+    #                                 (models/mellum.py: sliding_window holds
+    #                                 for the first kind alone); empty = one
+    #                                 kind of layer
+    rope_parameters: dict = dataclasses.field(default_factory=dict)
+    #                                 a rotary table a kind of layer_types,
+    #                                 as published: {kind: {rope_type,
+    #                                 rope_theta, ...}} (ops/layers.py
+    #                                 rotary_embedding); empty = rope_theta
     mamba_n_heads: int = 0
     mamba_d_head: int = 64
     mamba_d_state: int = 128
@@ -131,7 +146,7 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
     @property
     def effective_mlp_bias(self) -> bool:
@@ -214,6 +229,27 @@ class ModelConfig:
         """Routed experts held here: all of them unless told a share."""
         return self.moe_held_experts or self.num_experts
 
+    # ---- window and full attention layers, each routed (layer_types) ---
+    @property
+    def window_stack(self) -> bool:
+        return "sliding_attention" in self.layer_types \
+            or "full_attention" in self.layer_types
+
+    def _window_stack_params(self, active: bool) -> int:
+        """Embedding, untied head, norms and the layers of a window-and-
+        full stack, as models/mellum.py builds them: grouped-query
+        attention at ``head_dim`` and a router over ``num_experts`` in
+        front of the ``held_experts`` held here. ``active``: a token's
+        routed experts count as its ``moe_top_k`` times the share held."""
+        d, v, hd = self.hidden_size, self.vocab_size, self.head_dim
+        attn = 2 * d * hd * (self.num_heads + self.num_kv_heads)
+        routed = (self.moe_top_k * self.held_experts / self.num_experts
+                  if active else self.held_experts)
+        layer = (attn + 2 * d + d * self.num_experts
+                 + routed * 3 * d * self.moe_intermediate_size)
+        n = v * d * (1 if self.tie_embeddings else 2) + d
+        return int(n + len(self.layer_types) * layer)
+
     def _stack_params(self, kinds, active: bool) -> int:
         """Embedding, head, norms and the layers of a stack of kinds.
         ``active``: a token's routed experts count as the ``moe_top_k``
@@ -234,6 +270,8 @@ class ModelConfig:
     def num_params(self) -> int:
         """Analytic parameter count (embedding + layers + final norm),
         matching the trees the model's ``init`` builds exactly."""
+        if self.window_stack:
+            return self._window_stack_params(active=False)
         if self.layer_types:
             return self._hybrid_params()
         kinds = self.layer_kinds()
@@ -280,6 +318,8 @@ class ModelConfig:
         router projection and any shared experts always run). This is
         the MFU denominator — counting parked experts would credit the
         model with FLOPs it never executed."""
+        if self.window_stack:
+            return self._window_stack_params(active=True)
         kinds = self.layer_kinds()
         if kinds is not None:
             return self._stack_params(kinds, active=True)
@@ -317,6 +357,16 @@ class ModelConfig:
                 ctx = (s + 1) / 2
         else:
             ctx = s
+        if self.window_stack:
+            # a visible pair multiplies a key and a value of head_dim a
+            # head (x3 training); the window bounds the first kind alone.
+            # The embedding is a gather, not a matmul
+            pair = 12 * self.num_heads * self.head_dim
+            full = (s + 1) / 2 if causal else s
+            n -= self.vocab_size * self.hidden_size
+            return 6 * n + pair * sum(
+                ctx if t == "sliding_attention" else full
+                for t in self.layer_types)
         if self.layer_types:
             # an attention layer multiplies a key and a value of head_dim
             # a visible pair; a Mamba head writes and reads its [P, N]

@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import ModelConfig, register_model
-from .stack import StackOfKinds
+from .stack import RoutedStackOfKinds
 from .transformer import _dense_init
 
 _PUBLISHED = dict(
@@ -90,7 +90,7 @@ def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
 
 
 @register_model("kimi_linear")
-class KimiLinear(StackOfKinds):
+class KimiLinear(RoutedStackOfKinds):
     def __init__(self, config: ModelConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
@@ -129,22 +129,16 @@ class KimiLinear(StackOfKinds):
         (equal, or rows were dropped), over ``moe_held_calls`` routed
         layers of ``moe_held_experts`` each."""
         from ..moe.sharded_moe import balance_bias
-        c = self.config
         layers = {g: dict(slots) for g, slots in params["layers"].items()}
-        rows = done = calls = 0
-        for group, slots in stats.items():
-            for slot, counts in slots.items():
-                p = layers[group][slot]
-                moe = dict(p["moe"])
-                moe["router_bias"] = balance_bias(moe["router_bias"],
-                                                  counts["load"])
-                layers[group][slot] = {**p, "moe": moe}
-                rows += jnp.sum(counts["load"][..., :c.held_experts])
-                done += jnp.sum(counts["done"])
-                calls += counts["done"].size
-        metrics = {"moe_held_rows": rows, "moe_held_done": done,
-                   "moe_held_calls": jnp.int32(calls),
-                   "moe_held_experts": jnp.int32(c.held_experts)}
+
+        def move_bias(group, slot, counts):
+            p = layers[group][slot]
+            moe = dict(p["moe"])
+            moe["router_bias"] = balance_bias(moe["router_bias"],
+                                              counts["load"])
+            layers[group][slot] = {**p, "moe": moe}
+
+        metrics = self._held_metrics(stats, each=move_bias)
         return {**params, "layers": layers}, metrics
 
     # ---------------- init ----------------
@@ -336,14 +330,6 @@ class KimiLinear(StackOfKinds):
         from ..ops.kda import chunk_kda, sharded_chunk_kda
         return (attn_fn, chunk_kda if act_sharding is None
                 else sharded_chunk_kda(act_sharding))
-
-    def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
-             with_stats: bool = False):
-        """Mean cross-entropy (no auxiliary term); ``with_stats`` also
-        returns the routed layers' counts, for ``after_step``."""
-        ce, stats = self._loss_and_stats(params, batch, attn_fn=attn_fn,
-                                         act_sharding=act_sharding)
-        return (ce, stats) if with_stats else ce
 
     # ---------------- sharding ----------------
     def partition_rules(self):

@@ -1,0 +1,278 @@
+"""Mellum 2 family: window and full attention layers mixed, every layer
+routed.
+
+``Mellum2-12B-A2.5B-Instruct`` (JetBrains, ``config.json``, ``model_type``
+``mellum``): 28 pre-norm layers of hidden 2304 whose ``layer_types`` are
+three ``sliding_attention`` to one ``full_attention``, each followed by 64
+softmax-routed experts of width 896 (top 8, renormalised, no shared
+expert)::
+
+    x <- x + Attn_t(rmsnorm(x)) Wo;   x <- x + Routed(rmsnorm(x))
+    logits = rmsnorm(x_L) W_head                         (untied head)
+
+**Attention** (both kinds): 32 query heads and 4 key/value heads of
+``head_dim`` 128 (NOT hidden / heads: ``attn_head_dim``), no bias, rotary
+on the whole head, softmax at ``head_dim ** -0.5``, causal. A kind has its
+own window and its own rotary table (``rope_parameters``, one section a
+kind; ``ops/layers.py`` ``rotary_embedding``):
+
+- ``sliding_attention``: the last ``sliding_window`` (1024) positions, the
+  row's own among them; plain rotary at ``rope_theta`` 500,000.
+- ``full_attention``: every earlier position; YaRN (factor 16 over an
+  original context of 8192) with its ``attention_factor`` on cos and sin.
+
+**Routed layer** (``moe.sharded_moe.moe_ffn_held`` with the ``softmax``
+router): float32 softmax over all ``num_experts`` logits, the top
+``moe_top_k``, their probabilities divided by their sum, and the experts
+HELD here (the first ``moe_held_experts``: one chip's share under expert
+parallelism) through the dropless dispatch. No selection bias, no scaling,
+no shared expert, and no auxiliary term: the published config has no
+coefficient for one, so ``after_step`` changes no weight and only hands
+the engine the layers' counts (``RoutedStackOfKinds._held_metrics``).
+
+Not in the published config and not built: a q/k norm, a multi-token
+prediction head. ``intermediate_size`` is unused (every layer is sparse).
+
+**The stack** is ``models/stack.py``'s: the three window layers of a
+period under one scan where periods repeat, a layer's kind read from the
+key its attention weights lie under (``swa`` | ``full``). Serving and the
+pipeline are not here (``StackOfKinds._one_kind_only``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..ops import layers as L
+from .base import ModelConfig, register_model
+from .stack import RoutedStackOfKinds
+from .transformer import _dense_init
+
+_KINDS = {"sliding_attention": "swa", "full_attention": "full"}
+_ROPE = {
+    "full_attention": {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192, "beta_fast": 32,
+        "beta_slow": 1, "attention_factor": 1.2772588722239782},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+}
+_PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+_PUBLISHED = dict(
+    hidden_size=2304, intermediate_size=7168, num_heads=32, num_kv_heads=4,
+    attn_head_dim=128, num_layers=28, vocab_size=98304, max_seq_len=131072,
+    layer_types=_PERIOD * 7, sliding_window=1024, rope_parameters=_ROPE,
+    num_experts=64, moe_top_k=8, moe_intermediate_size=896)
+
+
+def mellum_config(size: str = "12b-a2.5b", **overrides) -> ModelConfig:
+    presets = {
+        # a head of 32 on a hidden size of 64 (not 64 / 4), a window a
+        # quarter of the sequence, YaRN at the published factor over an
+        # original context of 64 (low 0, high 5 of 16 pairs), and the
+        # published router
+        "tiny": dict(hidden_size=64, intermediate_size=128, num_heads=4,
+                     num_kv_heads=2, attn_head_dim=32, num_layers=4,
+                     vocab_size=512, max_seq_len=128, layer_types=_PERIOD,
+                     sliding_window=32,
+                     rope_parameters={
+                         "full_attention": {
+                             "rope_type": "yarn", "rope_theta": 10000,
+                             "factor": 16,
+                             "original_max_position_embeddings": 64,
+                             "beta_fast": 8, "beta_slow": 1,
+                             "attention_factor": 1.2772588722239782},
+                         "sliding_attention": {"rope_type": "default",
+                                               "rope_theta": 10000}},
+                     num_experts=64, moe_top_k=8, moe_intermediate_size=32),
+        "12b-a2.5b": _PUBLISHED,
+    }
+    base = dict(norm_type="rmsnorm", activation="swiglu",
+                # a table a kind, built here: DecoderLM builds its one
+                # table for "rope" alone
+                position_embedding="rope_by_kind", use_bias=False,
+                tie_embeddings=False, norm_eps=1e-6,
+                moe_router_activation="softmax", moe_norm_topk=True,
+                router_aux_loss_coef=0.0)
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+@register_model("mellum")
+class Mellum(RoutedStackOfKinds):
+    def __init__(self, config: ModelConfig | None = None,
+                 size: str | None = None, **overrides):
+        if config is not None and (size is not None or overrides):
+            raise ValueError(
+                "pass either an explicit config or size/overrides, not both")
+        c = config or mellum_config(size or "12b-a2.5b", **overrides)
+        if len(c.layer_types) != c.num_layers or set(c.layer_types) - set(
+                _KINDS):
+            raise ValueError(
+                f"Mellum needs {c.num_layers} layer_types of "
+                f"{sorted(_KINDS)}, not {c.layer_types}")
+        if set(c.layer_types) - set(c.rope_parameters):
+            raise ValueError(
+                f"rope_parameters has no section for "
+                f"{sorted(set(c.layer_types) - set(c.rope_parameters))}")
+        if "sliding_attention" in c.layer_types and not c.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if (c.moe_router_activation != "softmax" or c.tie_embeddings
+                or c.moe_num_shared_experts or c.use_bias
+                or c.num_experts <= 0):
+            raise NotImplementedError(
+                "Mellum has a softmax router over its experts, no shared "
+                "expert, no bias and an untied head")
+        if c.held_experts > c.num_experts:
+            raise ValueError(
+                f"{c.held_experts} experts held of the router's "
+                f"{c.num_experts}")
+        super().__init__(c, list(c.layer_types))
+        self._ropes = {
+            _KINDS[t]: L.rotary_embedding(c.max_seq_len, c.head_dim,
+                                          c.rope_theta,
+                                          scaling=c.rope_parameters[t])
+            for t in sorted(set(c.layer_types))}
+
+    def after_step(self, params, stats):
+        """No weight moves after the optimizer's update (no selection bias
+        to balance); the routed layers' counts of the step become the
+        ``moe_held_*`` metrics the engine feeds the registry from."""
+        return params, self._held_metrics(stats)
+
+    # ---------------- init ----------------
+    def _init_layer(self, key, kind, lead_shape=()):
+        c = self.config
+        dt = c.param_dtype
+        d, f, hd = c.hidden_size, c.moe_intermediate_size, c.head_dim
+        nh, nkv, e = c.num_heads, c.num_kv_heads, c.held_experts
+        std = 0.02
+        resid_std = std / (2 * c.num_layers) ** 0.5
+        ks = iter(jax.random.split(key, 8))
+
+        def w(shape, scale=std):
+            return _dense_init(next(ks), (*lead_shape, *shape), scale, dt)
+
+        def ones(shape):
+            return jnp.ones((*lead_shape, *shape), dt)
+
+        return {
+            "ln1_scale": ones((d,)), "ln2_scale": ones((d,)),
+            # scores of deviation 8, not 0.9: attention that selects, as
+            # trained heads do (see init)
+            _KINDS[kind]: {"wq": w((d, nh * hd), 3 * std),
+                           "wk": w((d, nkv * hd), 3 * std),
+                           "wv": w((d, nkv * hd)),
+                           "wo": w((nh * hd, d), resid_std)},
+            "moe": {
+                # logits of unit variance at any width, as the other
+                # routed family draws them: the share of positions near
+                # the top-k boundary is the same at the tiny preset
+                "router": w((d, c.num_experts), d ** -0.5),
+                "experts": {"w_gate": w((e, d, f)), "w_up": w((e, d, f)),
+                            "w_down": w((e, f, d), resid_std)}},
+        }
+
+    def init(self, rng: jax.Array):
+        """Seeded weights under which a router sees what it sees in a
+        trained model: its own token. At normal(0, 0.02) throughout, a
+        layer's output is several times its input's embedding and the
+        scores are near level, so attention hands every position the same
+        mean of a thousand random values, the next layer leans on it, and
+        from the second layer on every token picks nearly the same experts
+        (largest load 7 of a possible 8 times the even load). So the
+        embedding rows are normal(0, 1) and the query and key projections
+        normal(0, 0.06); the rest is normal(0, 0.02) with the residual
+        outputs at 0.02 / sqrt(2 layers). Loads then read within a quarter
+        of even, and the layers still make 60% of the logits."""
+        c = self.config
+        dt = c.param_dtype
+        d, v = c.hidden_size, c.vocab_size
+        keys = jax.random.split(rng, 3)
+        return {
+            "embed": {"tokens": _dense_init(keys[1], (v, d), 1.0, dt)},
+            "layers": self._init_layers(keys[0]),
+            "final_norm": {"scale": jnp.ones((d,), dt)},
+            "lm_head": _dense_init(keys[2], (d, v), 0.02, dt),
+        }
+
+    # ---------------- one layer, the stack ----------------
+    def _attention(self, p, h, kind, attn):
+        c = self.config
+        b, s, _ = h.shape
+        nh, nkv, hd = c.num_heads, c.num_kv_heads, c.head_dim
+        q = (h @ p["wq"]).reshape(b, s, nh, hd)
+        k = (h @ p["wk"]).reshape(b, s, nkv, hd)
+        v = (h @ p["wv"]).reshape(b, s, nkv, hd)
+        with jax.named_scope("ds.rope"):
+            cos, sin = self._ropes[kind]
+            q = L.apply_rotary(q, cos, sin)
+            k = L.apply_rotary(k, cos, sin)
+        return attn(q, k, v).reshape(b, s, nh * hd) @ p["wo"]
+
+    def _one_layer(self, p, x, mixers):
+        from ..moe import sharded_moe
+        c = self.config
+        kind = "swa" if "swa" in p else "full"
+        with jax.named_scope(f"ds.attn_{kind}"):
+            h = L.rms_norm(x, p["ln1_scale"], c.norm_eps)
+            x = x + self._attention(p[kind], h, kind, mixers[kind])
+        h = L.rms_norm(x, p["ln2_scale"], c.norm_eps)
+        moe = p["moe"]
+        # a share without its peers leaves the routing alone in the
+        # backward (``moe_ffn_held``): the whole layer trains its router
+        y, counts = sharded_moe.moe_ffn_held(
+            h, moe["router"], None, moe["experts"], None, k=c.moe_top_k,
+            renormalise=c.moe_norm_topk, router="softmax",
+            router_grad=c.held_experts == c.num_experts)
+        # the blocks the dispatch swept, from the load and its own rule
+        block = sharded_moe.held_block(h.shape[0] * h.shape[1], c.moe_top_k,
+                                       c.num_experts)
+        blocks = jnp.sum(-(-counts["load"][:c.held_experts] // block))
+        return x + y, {**counts, "blocks": blocks,
+                       "block": jnp.int32(block)}
+
+    def _mixers(self, attn_fn, act_sharding):
+        """{kind: attention of that kind's window}. ``attn_fn`` is the
+        stack's (``_attn``): the flash kernels, plain attention, or what
+        the engine bound for a mesh, which has to take a window a call
+        (``sharded_flash_attention`` does; the sequence-parallel wrappers
+        apply none, and a window layer cannot run full-causal)."""
+        c = self.config
+        if attn_fn is L.dot_product_attention:
+            def plain(q, k, v, window):
+                bias = (None if window is None
+                        else L.window_bias(q.shape[1], window))
+                return attn_fn(q, k, v, causal=True, bias=bias)
+            of = lambda w: functools.partial(plain, window=w)  # noqa: E731
+        else:
+            from ..ops.pallas.flash_attention import flash_attention
+            if attn_fn is not flash_attention and not getattr(
+                    attn_fn, "applies_window", False):
+                raise NotImplementedError(
+                    "Mellum's window layers need an attention that applies "
+                    "a window a call: the sequence-parallel wrappers do not")
+            of = lambda w: functools.partial(  # noqa: E731
+                attn_fn, causal=True, window=w)
+        return {"swa": of(c.sliding_window), "full": of(None)}
+
+    # ---------------- sharding ----------------
+    def partition_rules(self):
+        """Tensor-parallel rules by head / expert dimension; the leading
+        axis of a ``period`` stack is the scan's and stays whole."""
+        def both(pattern, *spec):
+            return [(rf"layers/period/.*{pattern}", P(None, *spec)),
+                    (rf"layers/(lead|tail)/.*{pattern}", P(*spec))]
+
+        rules = [(r"embed/tokens", P("tp", None))]
+        for pattern, spec in [
+                (r"(swa|full)/(wq|wk|wv)$", (None, "tp")),
+                (r"(swa|full)/wo$", ("tp", None)),
+                (r"experts/(w_up|w_gate)$", ("ep", None, "tp")),
+                (r"experts/w_down$", ("ep", "tp", None))]:
+            rules += both(pattern, *spec)
+        return rules + [(r"lm_head$", P(None, "tp"))]

@@ -17,6 +17,7 @@ and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from ..ops import layers as L
 from .transformer import DecoderLM, _remat_policy, _unpack_batch
@@ -138,3 +139,58 @@ class StackOfKinds(DecoderLM):
             f"stack of kinds has no single block()")
 
     block = block_decode = decode = init_cache = _one_kind_only
+
+
+class RoutedStackOfKinds(StackOfKinds):
+    """A stack of kinds with routed layers that hold a share of their
+    experts (``moe.sharded_moe.moe_ffn_held``): the step hands the layers'
+    counts back. ``loss(with_stats=True)`` returns them beside the loss,
+    and the engine calls the family's ``after_step(params, stats)`` with
+    the step's updated weights and takes ``(params, metrics)`` from it;
+    ``_held_metrics`` makes the metrics every such family returns."""
+
+    def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
+             with_stats: bool = False):
+        """Mean cross-entropy (no auxiliary term); ``with_stats`` also
+        returns the routed layers' counts, for ``after_step``."""
+        ce, stats = self._loss_and_stats(params, batch, attn_fn=attn_fn,
+                                         act_sharding=act_sharding)
+        return (ce, stats) if with_stats else ce
+
+    def _held_metrics(self, stats, each=None) -> dict:
+        """What an ``after_step`` returns of the routed layers' counts of
+        one step (``stats``: ``loss(with_stats=True)``'s, summed over the
+        micro-batches), as device scalars the engine feeds
+        ``moe.dispatch.record_held_expert_counts`` with: the rows routed
+        to the experts held here (the first ``held_experts``) and the rows
+        they computed (equal, or rows were dropped), over
+        ``moe_held_calls`` routed layers of ``moe_held_experts`` each.
+        Where a family's layers count the blocks the dispatch swept
+        (``counts["blocks"]`` of ``counts["block"]`` rows each, from the
+        load and ``held_block``) also those, the rows of one, and the
+        step's largest and smallest load of ANY of the router's experts
+        in one layer. ``each(group, slot, counts)`` runs first for every
+        routed layer (a bias-corrected router's update)."""
+        held = self.config.held_experts
+        rows = done = blocks = calls = 0
+        block, tops, leasts = None, [], []
+        for group, slots in stats.items():
+            for slot, counts in slots.items():
+                if each is not None:
+                    each(group, slot, counts)
+                rows += jnp.sum(counts["load"][..., :held])
+                done += jnp.sum(counts["done"])
+                calls += counts["done"].size
+                if "blocks" in counts:
+                    blocks += jnp.sum(counts["blocks"])
+                    block = jnp.max(counts["block"])
+                    tops.append(jnp.max(counts["load"]))
+                    leasts.append(jnp.min(counts["load"]))
+        metrics = {"moe_held_rows": rows, "moe_held_done": done,
+                   "moe_held_calls": jnp.int32(calls),
+                   "moe_held_experts": jnp.int32(held)}
+        if tops:
+            metrics.update(moe_held_blocks=blocks, moe_held_block=block,
+                           moe_load_max=jnp.max(jnp.stack(tops)),
+                           moe_load_min=jnp.min(jnp.stack(leasts)))
+        return metrics

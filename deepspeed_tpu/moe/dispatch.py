@@ -207,17 +207,30 @@ def publish_router_metrics(metrics: dict) -> None:
 
 
 def record_held_expert_counts(reg, counts: dict) -> None:
-    """One finished step's ``moe_*`` metrics (``models/kimi_linear.py``
-    ``after_step``: device scalars the step returns, traced or not) into
-    the telemetry registry, on the host: the rows (token, choice) routed
-    to the experts held here, the routed-layer calls, the rows routed but
-    not computed (0, or the dispatch dropped tokens).
+    """One finished step's ``moe_*`` metrics (``models/stack.py``
+    ``_held_metrics``, returned by a routed family's ``after_step``: device
+    scalars the step returns, traced or not) into the telemetry registry,
+    on the host: the rows (token, choice) routed to the experts held here,
+    the routed-layer calls, the rows routed but not computed (0, or the
+    dispatch dropped tokens).
     ``ds_moe_held_rows_total / (ds_moe_held_calls_total x experts held)``
     is the mean tokens a held expert a layer call; the two ``_step_``
-    gauges keep the least and the most that mean was in one step."""
+    gauges keep the least and the most that mean was in one step. Where
+    the step counts its blocks: ``ds_moe_held_blocks_total`` (blocks the
+    dispatch swept) and ``ds_moe_held_block_rows`` (rows of one), so the
+    share of swept rows that is padding is ``1 - rows / (blocks x block
+    rows)``; and the largest and smallest rows ANY of the router's experts
+    was sent in one layer of one step (``ds_moe_load_step_max`` /
+    ``_min``)."""
     rows, done = float(counts["moe_held_rows"]), float(counts["moe_held_done"])
     calls, held = float(counts["moe_held_calls"]), float(
         counts["moe_held_experts"])
+    first = reg.get("ds_moe_held_calls_total") is None
+
+    def extreme(name, text, value, pick):
+        g = reg.gauge(name, text)
+        g.set(value if first else pick(g.value(), value))
+
     reg.counter("ds_moe_held_rows_total",
                 "rows (token, choice) routed to held experts").inc(rows)
     reg.counter("ds_moe_held_calls_total",
@@ -228,10 +241,20 @@ def record_held_expert_counts(reg, counts: dict) -> None:
     reg.gauge("ds_moe_held_experts",
               "experts held by this chip's share of a routed layer").set(held)
     per = rows / (calls * held)
-    low = reg.gauge("ds_moe_held_tokens_step_min",
-                    "least mean tokens a held expert a call, of any step")
-    high = reg.gauge("ds_moe_held_tokens_step_max",
-                     "most mean tokens a held expert a call, of any step")
-    first = reg.counter("ds_moe_held_calls_total").value() == calls
-    low.set(per if first else min(low.value(), per))
-    high.set(per if first else max(high.value(), per))
+    extreme("ds_moe_held_tokens_step_min",
+            "least mean tokens a held expert a call, of any step", per, min)
+    extreme("ds_moe_held_tokens_step_max",
+            "most mean tokens a held expert a call, of any step", per, max)
+    if "moe_held_blocks" in counts:
+        reg.counter("ds_moe_held_blocks_total",
+                    "blocks the held dispatch swept").inc(
+                        float(counts["moe_held_blocks"]))
+        reg.gauge("ds_moe_held_block_rows",
+                  "rows a block of the held dispatch").set(
+                      float(counts["moe_held_block"]))
+        extreme("ds_moe_load_step_max",
+                "most rows any expert of the router was sent in one layer "
+                "of any step", float(counts["moe_load_max"]), max)
+        extreme("ds_moe_load_step_min",
+                "least rows any expert of the router was sent in one layer "
+                "of any step", float(counts["moe_load_min"]), min)

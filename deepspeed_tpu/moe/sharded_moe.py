@@ -32,6 +32,19 @@ def compute_capacity(num_tokens: int, num_experts: int, k: int,
     return max(cap, min_capacity)
 
 
+def softmax_top_k(logits: jax.Array, k: int, *, renormalise: bool = True):
+    """Softmax routing, once for every path that routes by it: float32
+    ``probs = softmax(logits)`` over all experts, the ``k`` largest, their
+    probabilities divided by their sum if ``renormalise`` (and k > 1).
+    Returns (experts chosen [N, k], weights [N, k] float32, probs
+    [N, E])."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    topk_probs, topk_idx = lax.top_k(probs, k)          # [N, k]
+    if renormalise and k > 1:
+        topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+    return topk_idx, topk_probs, probs
+
+
 def top_k_gating(logits: jax.Array, k: int, capacity_factor: float = 1.0,
                  min_capacity: int = 4, normalize_topk: bool = True,
                  drop_tokens: bool = True):
@@ -49,11 +62,8 @@ def top_k_gating(logits: jax.Array, k: int, capacity_factor: float = 1.0,
         # capacity here silently one-hots overflow positions past the
         # table into zero rows (they were "kept" but never dispatched)
         capacity = max(n, min_capacity)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    topk_probs, topk_idx = lax.top_k(probs, k)          # [N, k]
-    if normalize_topk and k > 1:
-        topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+    topk_idx, topk_probs, probs = softmax_top_k(logits, k,
+                                                renormalise=normalize_topk)
 
     # slot-major positions: all slot-0 assignments get capacity positions
     # first (matches reference top2gating's second-expert offset logic)
@@ -154,11 +164,8 @@ def moe_ffn_grouped(x: jax.Array, gate_w: jax.Array, experts: dict, *,
     e = gate_w.shape[-1]
     xt = x.reshape(n, d)
     logits = xt @ gate_w                                   # [N, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topk_probs, topk_idx = lax.top_k(probs, k)             # [N, k]
-    if normalize_topk and k > 1:
-        topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1,
-                                          keepdims=True)
+    topk_idx, topk_probs, probs = softmax_top_k(logits, k,
+                                                renormalise=normalize_topk)
 
     e_flat = topk_idx.reshape(-1)                          # [N*k]
     order = jnp.argsort(e_flat)                            # sorted rows
@@ -251,7 +258,7 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, experts: dict, *,
 
 
 # ---------------------------------------------------------------------------
-# A held share of sigmoid-routed experts, dropless, with a backward
+# A held share of routed experts, dropless, with a backward
 # ---------------------------------------------------------------------------
 def sigmoid_top_k(logits: jax.Array, bias: jax.Array, k: int, *,
                   renormalise: bool = True, scaling: float = 1.0):
@@ -413,24 +420,62 @@ def _held_bwd_rule(first, block, res, cts):
 held_experts_ffn.defvjp(_held_fwd_rule, _held_bwd_rule)
 
 
-def moe_ffn_held(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
-                 experts: dict, shared: dict | None, *, k: int,
-                 first_expert: int = 0, renormalise: bool = True,
-                 scaling: float = 1.0, block: int | None = None):
-    """A sigmoid-routed expert layer that is told which experts it holds
-    (one chip's share under expert parallelism, without its exchange):
-    routes every token over ALL ``router_w.shape[-1]`` experts, computes
-    what the ``experts["w_up"].shape[0]`` experts from ``first_expert``
-    on give for the tokens routed to them (:func:`held_experts_ffn`) and
-    adds the always-on ``shared`` expert. A token routed only to absent
-    experts gets the shared expert alone; what the absent experts would
-    have added is left out. ``block`` (rows a block of the dispatch)
-    defaults to twice what a balanced router sends one expert, in 128s
-    between 128 and 1024: a padded capacity of two, as trainers pad for
-    static shapes. An expert at or under it takes ONE block, so below it
-    the sweep's time does not follow the load (the held-expert roofline,
-    which counts the rows that ran, shows the padding); an expert over it
-    takes more blocks, and nothing is dropped.
+_BLOCK_MAX = 1024       # rows: the largest block of the dispatch
+
+
+def held_block(tokens: int, k: int, n_experts: int) -> int:
+    """Rows a block of the held dispatch, from the shape alone (``even``:
+    the rows a balanced router sends one expert), in 128s between 128 and
+    ``_BLOCK_MAX``. An expert takes ``ceil(load / block)`` blocks, so the
+    swept rows step at every multiple of the block.
+
+    - ``even`` under a block: twice ``even``, a padded capacity of two as
+      trainers pad for static shapes. An expert at or under it takes ONE
+      block, so below it the sweep's time does not follow the load (the
+      held-expert roofline, which counts the rows that ran, shows the
+      padding); an expert over it takes more blocks.
+    - ``even`` of a whole block or more: every expert takes several
+      blocks. Twice ``even`` would be capped at ``_BLOCK_MAX``, and where
+      the block divides ``even`` a balanced expert sits ON a step: half
+      the experts take one block more than the other half, which of them
+      by the seed. So: the block that leaves ``even`` farthest, in blocks,
+      from a multiple of it, the larger of equals (2048 -> 768: three
+      blocks from 1537 to 2304 rows, a ninth of them padding)."""
+    even = tokens * k / n_experts
+    if even < _BLOCK_MAX:
+        return min(_BLOCK_MAX, max(128, 128 * math.ceil(2 * even / 128)))
+
+    def off_a_step(block):
+        return min(even % block, block - even % block) / block
+
+    return max(range(128, _BLOCK_MAX + 1, 128),
+               key=lambda block: (off_a_step(block), block))
+
+
+def moe_ffn_held(x: jax.Array, router_w: jax.Array,
+                 router_bias: jax.Array | None, experts: dict,
+                 shared: dict | None, *, k: int, first_expert: int = 0,
+                 renormalise: bool = True, scaling: float = 1.0,
+                 block: int | None = None, router: str = "sigmoid",
+                 router_grad: bool = True):
+    """A routed expert layer that is told which experts it holds (one
+    chip's share under expert parallelism, without its exchange): routes
+    every token over ALL ``router_w.shape[-1]`` experts, computes what the
+    ``experts["w_up"].shape[0]`` experts from ``first_expert`` on give for
+    the tokens routed to them (:func:`held_experts_ffn`) and adds the
+    always-on ``shared`` expert where there is one. ``router``:
+    ``sigmoid`` (:func:`sigmoid_top_k`, with its selection bias
+    ``router_bias``) or ``softmax`` (:func:`softmax_top_k`, no bias); the
+    weights are float32 either way, times ``scaling``. A token routed only
+    to absent experts gets the shared expert alone (nothing without one);
+    what the absent experts would have added is left out. ``block`` (rows
+    a block of the dispatch) defaults to :func:`held_block` of the shape;
+    nothing is dropped at any block. ``router_grad`` False takes the
+    weights as given in the backward: the gradient of a token's weights is
+    a sum over ALL the experts it chose, and a share sees only the terms
+    of the experts it holds, which pull every token towards them (they
+    alone answer); a share whose peers' terms are not summed in leaves
+    the routing alone.
 
     Returns (out [B, S, D], counts): ``counts["load"]`` [E] int32, the
     rows (token, choice) routed to EACH of the router's experts (what the
@@ -440,17 +485,31 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     slice's sum only if rows were dropped."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
+    n_experts = router_w.shape[-1]
     if block is None:
-        even = b * s * k / router_w.shape[-1]
-        block = min(1024, max(128, 128 * math.ceil(2 * even / 128)))
+        block = held_block(b * s, k, n_experts)
     with jax.named_scope("ds.moe_router"):
         logits = jnp.matmul(xt, router_w,
                             preferred_element_type=jnp.float32)
-        idx, weights, _ = sigmoid_top_k(logits, router_bias, k,
-                                        renormalise=renormalise,
-                                        scaling=scaling)
+        if router == "sigmoid":
+            idx, weights, _ = sigmoid_top_k(logits, router_bias, k,
+                                            renormalise=renormalise,
+                                            scaling=scaling)
+        elif router == "softmax" and router_bias is None:
+            idx, weights, _ = softmax_top_k(logits, k,
+                                            renormalise=renormalise)
+            idx = idx.astype(jnp.int32)
+            if scaling != 1.0:
+                weights = weights * scaling
+        else:
+            raise ValueError(
+                f"router {router!r}, selection bias "
+                f"{'given' if router_bias is not None else 'None'}: "
+                f"'sigmoid' takes a bias, 'softmax' takes none")
+        if not router_grad:
+            weights = lax.stop_gradient(weights)
         load = jnp.bincount(idx.reshape(-1),
-                            length=router_w.shape[-1]).astype(jnp.int32)
+                            length=n_experts).astype(jnp.int32)
     out, done = held_experts_ffn(xt, idx, weights, experts,
                                  int(first_expert), int(block))
     if shared is not None:

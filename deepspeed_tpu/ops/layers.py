@@ -11,6 +11,8 @@ Everything here is shape-static and jit-safe.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,13 +59,58 @@ def causal_conv(x, w, bias=None):
         return y if bias is None else y + bias
 
 
+def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0, **_kw):
+    """YaRN's frequencies (Peng et al. 2023, "NTK-by-parts"), and the
+    ramp's two ends: pair ``i`` of ``head_dim // 2`` turns
+    ``original_max_position_embeddings / (2 pi theta^(2i/head_dim))``
+    times over the original context. ``c(n)`` is the (real) pair that
+    turns ``n`` times; pairs up to ``low = floor(c(beta_fast))`` keep
+    their frequency, pairs from ``high = ceil(c(beta_slow))`` on have it
+    divided by ``factor``, and between them it is blended linearly.
+    Returns (inv_freq [head_dim // 2] float64, low, high)."""
+    base = theta ** (-np.arange(0, head_dim, 2) / head_dim)
+
+    def pair_turning(n):
+        return (head_dim * math.log(
+            original_max_position_embeddings / (n * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return base * (1 - ramp) + base / factor * ramp, low, high
+
+
 def rotary_embedding(seq_len: int, head_dim: int, theta: float = 10000.0,
-                     dtype=jnp.float32):
-    """Precompute RoPE cos/sin tables [seq, head_dim//2]."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+                     dtype=jnp.float32, *, scaling: dict | None = None):
+    """Precompute RoPE cos/sin tables [seq, head_dim//2]. ``scaling``: one
+    section of a published ``rope_parameters`` (``rope_type`` ``default``
+    or ``yarn``; its ``rope_theta`` wins over ``theta``). A YaRN table
+    is multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` where
+    the section gives none), so q k^T carries its square. Another
+    ``rope_type`` (linear, dynamic NTK, llama3, longrope) is not built."""
+    scaling = dict(scaling or {})
+    theta = float(scaling.get("rope_theta", theta))
+    kind = scaling.get("rope_type", "default")
+    scale = 1.0
+    if kind == "yarn":
+        inv_freq, _, _ = yarn_inv_freq(head_dim, theta, **scaling)
+        scale = scaling.get("attention_factor")
+        if scale is None:
+            scale = 0.1 * math.log(scaling["factor"]) + 1.0
+    elif kind == "default":
+        inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    else:
+        raise NotImplementedError(
+            f"rope_type {kind!r}: only 'default' and 'yarn' tables are "
+            f"built")
     t = np.arange(seq_len)
     freqs = np.outer(t, inv_freq)
-    return jnp.asarray(np.cos(freqs), dtype), jnp.asarray(np.sin(freqs), dtype)
+    return (jnp.asarray(np.cos(freqs) * scale, dtype),
+            jnp.asarray(np.sin(freqs) * scale, dtype))
 
 
 def apply_rotary(x, cos, sin, positions=None):

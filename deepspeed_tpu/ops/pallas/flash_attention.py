@@ -519,13 +519,14 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
 
     The returned callable carries ``applies_window = True``: it applies
     the model's sliding window itself, unlike the sequence-parallel
-    wrappers."""
+    wrappers; a call may give another ``window`` (None: none), as a stack
+    whose layers differ in it does (models/mellum.py)."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import active_mesh
     from ...utils.jax_compat import shard_map
 
-    def attn(q, k, v, *, causal: bool = True, **_kw):
+    def attn(q, k, v, *, causal: bool = True, window=window, **_kw):
         use, free = active_mesh(mesh)
         b_ax = tuple(a for a in batch_axes
                      if a in free and use.shape[a] > 1)

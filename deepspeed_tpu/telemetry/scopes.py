@@ -140,6 +140,19 @@ MIXER_SCOPES = (
     #                    the gate's sigmoid, o_norm, the product; _mamba:
     #                    D x, the silu(z) gate, the gated norm
 )
+# what a stack of window and full attention layers, each routed, opens
+# inside ds.layers in place of ds.attn (models/mellum.py), beside
+# ds.moe_router and ds.moe_experts of KIND_SCOPES; ``tests/test_mellum.py``
+# holds this list equal to what that model's step carries. The kernels'
+# ds.flash_fwd / ds.flash_bwd lie inside the scope of their layer's kind in
+# the forward, in remat's rerun and in the backward rule, so one kind's
+# kernel time is read by ``ds\.attn_swa\b.*ds\.flash_``
+WINDOW_SCOPES = (
+    "ds.attn_swa",     # models/mellum.py _one_layer: a sliding_attention
+    #                    layer's norm, projections, rotary, kernel, wo
+    "ds.attn_full",    # the same of a full_attention layer
+    "ds.rope",         # models/mellum.py _attention: the rotation of q, k
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

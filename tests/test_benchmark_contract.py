@@ -48,6 +48,79 @@ def test_every_cell_has_its_files(workload):
         assert set(cell[key]) == declared, key
 
 
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+_UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
+_SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+_KEYS = {
+    "configs": ({"name", "source", "file", "reduced", "why"}, set()),
+    "workloads": ({"name", "config", "traffic", "chips", "why"}, set()),
+    "end_to_end": ({"name", "unit", "better", "bound", "source"},
+                   {"workloads"}),
+    "per_layer": ({"name", "unit", "better", "source", "layer", "moves"},
+                  {"workloads"}),
+}
+
+
+def _is_a_line(text):
+    """1 to 200 printable characters on one line: what the driver asks
+    of a ``why``, a ``layer``, a ``source`` and each word of ``command``
+    (PR 38 was refused for a ``why`` of 201)."""
+    return (isinstance(text, str) and 1 <= len(text) <= 200
+            and text.isprintable() and text.isascii())
+
+
+@pytest.mark.parametrize("group", sorted(_KEYS))
+def test_the_contract_keeps_the_drivers_form(group):
+    """The rules of form the driver holds ``BENCHMARK.json`` to before
+    any run, so that a fault of form fails here and not there."""
+    needed, allowed = _KEYS[group]
+    names = [entry["name"] for entry in CONTRACT[group]]
+    assert len(names) == len(set(names))
+    assert 1 <= len(names) <= (128 if group == "per_layer" else 24)
+    cells = {w["name"] for w in CONTRACT["workloads"]}
+    for entry in CONTRACT[group]:
+        where = (group, entry["name"])
+        assert needed <= set(entry) <= needed | allowed, where
+        assert _NAME.fullmatch(entry["name"]), where
+        for key in ("why", "layer"):
+            assert key not in entry or _is_a_line(entry[key]), where + (key,)
+        if "unit" in entry:
+            assert _UNIT.fullmatch(entry["unit"]), where
+            assert entry["better"] in ("lower", "higher"), where
+            assert entry["source"] in _SOURCES, where
+            assert set(entry.get("workloads", cells)) <= cells, where
+    if group == "configs":
+        for entry in CONTRACT[group]:
+            assert _is_a_line(entry["source"]), entry["name"]
+            assert len(entry["reduced"]) <= 16
+            assert all(_NAME.fullmatch(k) for k in entry["reduced"])
+            assert entry["file"].startswith(
+                tuple(p + "/" for p in CONTRACT["paths"]))
+        files = [entry["file"] for entry in CONTRACT[group]]
+        assert len(files) == len(set(files))
+    if group == "workloads":
+        configs = {c["name"] for c in CONTRACT["configs"]}
+        pairs = [(w["config"], w["traffic"]) for w in CONTRACT[group]]
+        assert len(pairs) == len(set(pairs))
+        for w in CONTRACT[group]:
+            assert w["config"] in configs and w["chips"] in (1, 4)
+            assert _NAME.fullmatch(w["traffic"]), w["name"]
+        four = sum(w["chips"] == 4 for w in CONTRACT[group])
+        assert four <= max(1, len(pairs) // 4)
+    if group == "end_to_end":
+        assert all(m["source"] in ("host_clock", "device_trace")
+                   for m in CONTRACT[group])
+    if group == "per_layer":
+        metrics = [m["name"] for key in ("end_to_end", "per_layer")
+                   for m in CONTRACT[key]]
+        assert len(metrics) == len(set(metrics))
+        assert all(_is_a_line(word) for word in CONTRACT["command"])
+        assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
+        runs = 2 + 14 * len(cells)
+        seconds = CONTRACT["run_seconds"]
+        assert runs * (seconds + 60) + 180 * len(cells) + 1200 <= 43200
+
+
 def test_every_per_layer_metric_has_its_file():
     assert len(CONTRACT["per_layer"]) >= 21
     for m in CONTRACT["per_layer"]:
@@ -101,7 +174,8 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
     assert named and named <= (set(scopes.DEVICE_SCOPES)
                                | set(scopes.KIND_SCOPES)
                                | set(scopes.SSM_SCOPES)
-                               | set(scopes.MIXER_SCOPES))
+                               | set(scopes.MIXER_SCOPES)
+                               | set(scopes.WINDOW_SCOPES))
 
     telemetry.shutdown()
     try:
