@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas import grouped_matmul
+
 
 def compute_capacity(num_tokens: int, num_experts: int, k: int,
                      capacity_factor: float, min_capacity: int = 4) -> int:
@@ -278,36 +280,30 @@ def sigmoid_top_k(logits: jax.Array, bias: jax.Array, k: int, *,
     return idx.astype(jnp.int32), w * scaling, select
 
 
-def _held_layout(idx, first: int, n_held: int, block: int):
+def _held_layout(idx, weights, first: int, n_held: int, tile: int):
     """Rows (token, choice) routed to the experts held here, sorted by
-    expert and cut into blocks of ``block`` rows that each lie in ONE
-    expert's run. Returns ``order`` [N*k + block] (row ids by expert, the
+    expert and cut into row tiles of ``tile`` rows that each lie in ONE
+    expert's run. Returns ``order`` [N*k + tile] (row ids by expert, the
     rows of absent experts last, padded), ``counts`` and ``starts`` [E_h]
-    of each held expert's run in it, and ``ends`` [E_h]: the number of
-    blocks up to and with the expert's own (the last is the total)."""
+    of each held expert's run in it, ``ends`` [E_h]: the number of tiles
+    up to and with the expert's own (the last is the total), and
+    ``weights`` [N, k] in ``order``'s order (they ride the sort: as a
+    gather by ``order`` they cost a millisecond a sweep)."""
     n, k = idx.shape
     local = idx - first
     held = (local >= 0) & (local < n_held)
     e_flat = jnp.where(held, local, n_held).reshape(-1)
-    order = jnp.argsort(e_flat, stable=True).astype(jnp.int32)
-    counts = jnp.bincount(e_flat, length=n_held + 1)[:n_held].astype(
-        jnp.int32)
+    _, order, by_order = lax.sort(
+        (e_flat, jnp.arange(n * k, dtype=jnp.int32), weights.reshape(-1)),
+        num_keys=1, is_stable=True)
+    # a bincount is a scatter-add: a millisecond of its own on the chip
+    counts = jnp.sum(e_flat[:, None] == jnp.arange(n_held)[None, :],
+                     axis=0, dtype=jnp.int32)
     starts = jnp.cumsum(counts) - counts
-    ends = jnp.cumsum((counts + block - 1) // block)
-    order = jnp.concatenate([order, jnp.zeros((block,), jnp.int32)])
-    return order, counts, starts, ends
-
-
-def _block_rows(b, layout, k: int, block: int):
-    """Block ``b`` of the layout: its expert, its row ids [block], their
-    tokens, and which of the rows are the expert's (the last block of a
-    run is part empty)."""
-    order, counts, starts, ends = layout
-    e = jnp.sum(b >= ends).astype(jnp.int32)
-    at = (b - ends[e]) * block + (counts[e] + block - 1) // block * block
-    rows = lax.dynamic_slice(order, (starts[e] + at,), (block,))
-    valid = jnp.arange(block) < counts[e] - at
-    return e, rows, rows // k, valid
+    ends = jnp.cumsum((counts + tile - 1) // tile)
+    pad = lambda v: jnp.concatenate(  # noqa: E731
+        [v, jnp.zeros((tile,), v.dtype)])
+    return pad(order), counts, starts, ends, pad(by_order)
 
 
 def _swiglu_rows(xg, w_gate, w_up):
@@ -316,105 +312,153 @@ def _swiglu_rows(xg, w_gate, w_up):
     return gate, up, jax.nn.silu(gate) * up
 
 
-def _held_fwd_loop(x, idx, weights, experts, first, block):
-    n, d = x.shape
+def _held_tiles(layout, tile: int, m: int):
+    """Every row tile the layout can hold (the rows' ``N * k / tile`` and
+    one part-empty tile an expert, in whole chunks of ``m`` tiles), laid
+    end to end: where each tile's rows begin in the layout's ``order`` [t]
+    and the kernels' tables, a chunk's tiles after another's
+    (``grouped_matmul.tile_tables``: a run's last tile is part empty, and
+    the tiles past the layout's last wholly)."""
+    order, counts, starts, ends, _ = layout
+    held = counts.shape[0]
+    tiles = -(-(order.shape[0] // tile + held) // m) * m
+    t = jnp.arange(tiles)
+    e = jnp.minimum(jnp.sum(t[:, None] >= ends[None, :], axis=1), held - 1)
+    at = (t - ends[e]) * tile + (counts[e] + tile - 1) // tile * tile
+    live = jnp.where(t < ends[-1], jnp.clip(counts[e] - at, 0, tile), 0)
+    return (jnp.where(live > 0, starts[e] + at, 0),
+            grouped_matmul.tile_tables(e.astype(jnp.int32), live, at > 0, m))
+
+
+def _add_rows(acc, index, rows, valid):
+    """``acc[index[r]] += rows[r]`` over a chunk's valid rows, ONE add a
+    chunk. XLA sorts the indices, brings the rows into that order and,
+    past some 8k rows, walks ``acc`` once through VMEM: milliseconds A
+    CALL for a float32 [16384, 2304] whatever the rows (``PERF.md``
+    section 6, PR 41, has the chip's figures). An add a block of one
+    expert's rows pays that walk for a sixteenth of the rows, told or not
+    that its indices are sorted and distinct: most of what the block loop
+    this replaced cost. The rows that are no expert's get an index past
+    ``acc``'s end and are dropped: their tile may not have run."""
+    return acc.at[jnp.where(valid, index, acc.shape[0])].add(
+        rows.astype(acc.dtype), mode="drop")
+
+
+def _held_sweep(idx, weights, first, n_held, block, chunk, body, carry):
+    """``body(rows, tokens, valid, scale, tables, tile, carry)`` over the
+    chunks of the rows routed to the held experts (``scale`` [C, 1]: a
+    row's routing weight, 0 where it is no expert's), ``chunk`` rows in
+    whole row tiles each: as many chunks as this batch's routing needs (a
+    loop whose trip count is data; ``held_chunk``: ONE where no more rows
+    are held than a balanced router sends). An expert's run is padded to
+    ROW TILES here (``grouped_matmul.row_tile``: it divides the block, the
+    unit the callers count the padding by), so that a chunk holds no tile
+    without a live row but its last ones."""
     k = idx.shape[1]
+    tile = grouped_matmul.row_tile(block)
+    m = -(-(chunk or n_held * block) // tile)
+    layout = _held_layout(idx, weights, first, n_held, tile)
+    run, tables = _held_tiles(layout, tile, m)
+
+    def one(c, carry):
+        # the chunk's row ids and routing weights: a slice of the layout
+        # a tile, cut here for the chunk's tiles and not before the loop
+        # for every tile the layout can hold (PERF.md section 6, PR 41)
+        part = lambda a: lax.dynamic_slice_in_dim(a, c * m, m)  # noqa: E731
+        cut = lambda v: jax.vmap(  # noqa: E731
+            lambda s: lax.dynamic_slice(v, (s,), (tile,)))(part(run))
+        mine = tuple(part(t) for t in tables)
+        valid = (jnp.arange(tile)[None, :] < mine[2][:, None]).reshape(-1)
+        rows = cut(layout[0]).reshape(-1)
+        scale = jnp.where(valid, cut(layout[4]).reshape(-1), 0.0)
+        return body(rows, rows // k, valid, scale[:, None], mine, tile,
+                    carry)
+
+    return lax.fori_loop(0, (layout[3][-1] + m - 1) // m, one, carry)
+
+
+def _held_forward(x, idx, weights, experts, first, block, chunk):
+    n, d = x.shape
     n_held = experts["w_up"].shape[0]
-    layout = _held_layout(idx, first, n_held, block)
-    w_flat = weights.reshape(-1)
 
-    def body(b, carry):
+    def body(rows, tokens, valid, scale, tables, tile, carry):
         out, done = carry
-        e, rows, tokens, valid = _block_rows(b, layout, k, block)
-        xg = jnp.where(valid[:, None], x[tokens], 0)
-        _, _, h = _swiglu_rows(xg, experts["w_gate"][e], experts["w_up"][e])
-        y = (h @ experts["w_down"][e]).astype(jnp.float32)
-        scale = jnp.where(valid, w_flat[rows], 0.0)
-        return (out.at[tokens].add(y * scale[:, None]),
-                done + jnp.sum(valid))
+        y = grouped_matmul.forward(x[tokens], scale, tables[:3], experts,
+                                   tile)
+        return _add_rows(out, tokens, y, valid), done + jnp.sum(valid)
 
-    out, done = lax.fori_loop(
-        0, layout[-1][-1], body,
+    out, done = _held_sweep(
+        idx, weights, first, n_held, block, chunk, body,
         (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32)))
     return out.astype(x.dtype), done
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def held_experts_ffn(x, idx, weights, experts, first, block):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def held_experts_ffn(x, idx, weights, experts, first, block,
+                     router_grad=True, chunk=None):
     """The part of a routed layer's result that the experts HELD here
     give: ``sum_j weights[n, j] * E_{idx[n, j]}(x_n)`` over the choices
     ``j`` whose expert lies in ``[first, first + E_h)`` (``experts``:
     SwiGLU weights ``[E_h, ...]``). Dropless: the rows routed here are
-    sorted by expert and swept in blocks of ``block`` rows, as many
-    blocks as this batch's routing needs (a loop whose trip count is
-    data), each block one gather, three matmuls against ONE expert's
-    weights and one scatter-add; no capacity, no [N, E, C] table. The
-    work is that of the rows routed here, however skewed the router;
-    nothing has the size of the worst case but the row index.
+    sorted by expert, each expert's run padded to row tiles (the largest
+    multiple of 128 up to 256 that divides ``block``, the unit the callers
+    count the padding by), and swept ``chunk`` rows at a time
+    (``held_chunk`` of the shape; left out, a block an expert held), as
+    many chunks as this batch's routing needs: the chunk's rows are
+    gathered in expert order once, a grouped-matmul kernel
+    (``ops/pallas/grouped_matmul.py``) runs the three matmuls tile by tile
+    with an expert's weights held in VMEM across its tiles, and the
+    weighted rows are added to their tokens once; no capacity, no
+    [N, E, C] table. The work is that of the rows routed here, however
+    skewed the router; nothing has the size of the worst case but the row
+    index. The backward is one more sweep whose kernel makes ``gate`` and
+    ``up`` again (nothing of the forward is kept but its inputs);
+    ``router_grad`` False says the routing weights get no gradient
+    (zeros), and the kernel leaves that product out.
 
     x [N, D]; idx [N, k] int32 over ALL experts; weights [N, k] float32.
     Returns (out [N, D], rows computed): fewer than the rows routed to
     the held experts only if rows were dropped."""
     with jax.named_scope("ds.moe_experts"):
-        return _held_fwd_loop(x, idx, weights, experts, first, block)
+        return _held_forward(x, idx, weights, experts, first, block, chunk)
 
 
-def _held_fwd_rule(x, idx, weights, experts, first, block):
+def _held_fwd_rule(x, idx, weights, experts, first, block, router_grad,
+                   chunk):
     with jax.named_scope("ds.moe_experts"):
-        out = _held_fwd_loop(x, idx, weights, experts, first, block)
+        out = _held_forward(x, idx, weights, experts, first, block, chunk)
     return out, (x, idx, weights, experts)
 
 
-def _held_bwd_rule(first, block, res, cts):
-    """One more sweep over the same blocks: the expert's two input
-    matmuls are run again (nothing of the forward sweep is kept but its
-    inputs), then the six of the backward."""
+def _held_bwd_rule(first, block, router_grad, chunk, res, cts):
     x, idx, weights, experts = res
     dout = cts[0]
     n, d = x.shape
     k = idx.shape[1]
-    n_held = experts["w_up"].shape[0]
     f32 = jnp.float32
+    names = ("w_gate", "w_up", "w_down")
+    # opened here: a custom_vjp's backward function is traced outside the
+    # scope its forward was called under
     with jax.named_scope("ds.moe_experts"):
-        layout = _held_layout(idx, first, n_held, block)
-        w_flat = weights.reshape(-1)
+        def body(rows, tokens, valid, scale, tables, tile, carry):
+            dx, dw, sums = carry
+            dxs, dwt, sums = grouped_matmul.backward(
+                x[tokens], dout[tokens], scale, tables, experts, sums, tile,
+                router_grad)
+            dx = _add_rows(dx, tokens, dxs, valid)
+            if router_grad:
+                dw = _add_rows(dw, rows, dwt[:, 0], valid)
+            return dx, dw, sums
 
-        def body(b, carry):
-            dx, dw, dg, du, dd = carry
-            e, rows, tokens, valid = _block_rows(b, layout, k, block)
-            xg = jnp.where(valid[:, None], x[tokens], 0)
-            gate, up, h = _swiglu_rows(xg, experts["w_gate"][e],
-                                       experts["w_up"][e])
-            y = h @ experts["w_down"][e]
-            dout_g = jnp.where(valid[:, None], dout[tokens], 0)
-            dw = dw.at[rows].add(jnp.where(valid, jnp.sum(
-                dout_g.astype(f32) * y.astype(f32), axis=-1), 0.0))
-            dy = (dout_g.astype(f32)
-                  * jnp.where(valid, w_flat[rows], 0.0)[:, None]
-                  ).astype(x.dtype)
-            dh = dy @ experts["w_down"][e].T
-            sg = jax.nn.sigmoid(gate.astype(f32))
-            d_up = (dh * (gate.astype(f32) * sg)).astype(x.dtype)
-            d_gate = (dh * up * (sg * (1 + gate.astype(f32) * (1 - sg)))
-                      ).astype(x.dtype)
-            dxg = (d_gate @ experts["w_gate"][e].T
-                   + d_up @ experts["w_up"][e].T)
-            acc = lambda t, a, b_: t.at[e].add(  # noqa: E731
-                jnp.matmul(a.T, b_, preferred_element_type=f32))
-            return (dx.at[tokens].add(dxg.astype(f32)), dw,
-                    acc(dg, xg, d_gate), acc(du, xg, d_up), acc(dd, h, dy))
-
-        zeros = lambda w: jnp.zeros(w.shape, f32)  # noqa: E731
-        dx, dw, dg, du, dd = lax.fori_loop(
-            0, layout[-1][-1], body,
-            (jnp.zeros((n, d), f32), jnp.zeros((n * k + block,), f32),
-             zeros(experts["w_gate"]), zeros(experts["w_up"]),
-             zeros(experts["w_down"])))
-    d_experts = {"w_gate": dg.astype(experts["w_gate"].dtype),
-                 "w_up": du.astype(experts["w_up"].dtype),
-                 "w_down": dd.astype(experts["w_down"].dtype)}
+        n_held = experts["w_up"].shape[0]
+        dx, dw, sums = _held_sweep(
+            idx, weights, first, n_held, block, chunk, body,
+            (jnp.zeros((n, d), f32), jnp.zeros((n * k,), f32),
+             [jnp.zeros(experts[name].shape, f32) for name in names]))
+    d_experts = {name: s.astype(experts[name].dtype)
+                 for name, s in zip(names, sums)}
     return (dx.astype(x.dtype), None,
-            dw[:n * k].reshape(n, k).astype(weights.dtype), d_experts)
+            dw.reshape(n, k).astype(weights.dtype), d_experts)
 
 
 held_experts_ffn.defvjp(_held_fwd_rule, _held_bwd_rule)
@@ -450,6 +494,29 @@ def held_block(tokens: int, k: int, n_experts: int) -> int:
 
     return max(range(128, _BLOCK_MAX + 1, 128),
                key=lambda block: (off_a_step(block), block))
+
+
+def held_chunk(tokens: int, k: int, n_experts: int, n_held: int,
+               block: int) -> int:
+    """Rows a chunk of the held sweep, from the shape alone: what a
+    balanced router sends the held experts and one row tile (the block's)
+    an expert for the part-empty ends of their runs (the Mellum cell:
+    32,768 + 16 x 256
+    = 36,864; the Kimi cell: 4096 + 8 x 256 = 6144). The runs are padded
+    to row tiles, so a chunk of that size holds ANY split of the even
+    total between the held experts, however skewed. A chunk costs its
+    gathers and its adds to tokens by the rows it HOLDS, live or not
+    (``_add_rows``: a walk of the float32 [N, D] carry a call), and its
+    kernels by the row tiles that hold a live row: so one chunk a sweep
+    is what a share that is sent its even total should pay, and no more
+    rows than that. A share that is sent MORE than its even total (and
+    the ends of its runs) takes a second chunk: its gathers and adds
+    again for the tiles left over (``PERF.md`` section 6, PR 41,
+    ``mellum_over`` of ``tools/moe_kernel_bench.py``: 5.4 + 7.5 ms a
+    layer at the Mellum shape), and no more memory: the loop's body holds
+    one chunk's temporaries whatever its trips."""
+    return (math.ceil(n_held * tokens * k / n_experts)
+            + n_held * grouped_matmul.row_tile(block))
 
 
 def moe_ffn_held(x: jax.Array, router_w: jax.Array,
@@ -510,8 +577,10 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
             weights = lax.stop_gradient(weights)
         load = jnp.bincount(idx.reshape(-1),
                             length=n_experts).astype(jnp.int32)
-    out, done = held_experts_ffn(xt, idx, weights, experts,
-                                 int(first_expert), int(block))
+    n_held = experts["w_up"].shape[0]
+    out, done = held_experts_ffn(
+        xt, idx, weights, experts, int(first_expert), int(block),
+        bool(router_grad), held_chunk(b * s, k, n_experts, n_held, block))
     if shared is not None:
         with jax.named_scope("ds.moe_shared"):
             _, _, h = _swiglu_rows(xt, shared["w_gate"], shared["w_up"])

@@ -1,0 +1,237 @@
+"""The held experts' SwiGLU as a grouped matmul: a Pallas kernel pair over
+rows that lie in EXPERT ORDER (``moe/sharded_moe.py`` ``held_experts_ffn``
+gathers them so, a chunk at a time, and is the one caller).
+
+A chunk is ``m`` row tiles (``row_tile`` rows each), each tile inside ONE
+expert's run (a run's last tile part empty, and the chunk's last tiles
+wholly where the rows end before it); a kernel's grid walks the chunk's
+tiles and reads, per tile, four numbers from scalar-prefetched tables
+(``tile_tables``): its expert, how many of its rows are live, the tile
+whose blocks it names, and what to do with the expert's ``dW`` before it.
+Consecutive tiles of one expert name the same weight blocks, so the
+pipeline fetches an expert's ``w_gate``, ``w_up``, ``w_down`` ONCE and they
+stay in VMEM while its tiles pass. A tile with no live row names the
+blocks of the last live tile before it and runs nothing: no fetch, no
+product, no store.
+
+``ds_moe_gmm_fwd`` (``forward``): ``gate`` and ``up`` in float32 from the
+bf16 rows, ``h = silu(gate) * up`` rounded once to the rows' dtype, the
+down projection in float32, times the row's routing weight (0 past an
+expert's count), all in VMEM: ``h`` never goes to HBM, and the rows leave
+in float32, as the caller's add to tokens takes them.
+
+``ds_moe_gmm_bwd`` (``backward``): the same walk. ``gate`` and ``up`` are
+made again; ``dh = (dy @ w_down^T) * weight`` in float32; the row's
+``dy . y`` (the routing weight's gradient, only where ``router_grad``) is
+``sum(dy @ w_down^T * h)``, so ``y`` is not made again; ``dx`` a tile; the
+expert's three ``dW`` are summed in float32 IN their output blocks, which
+stay in VMEM across the expert's tiles and go to HBM once when the expert
+changes. The outputs alias the float32 carries the caller's loop holds: an
+expert the chunk does not reach keeps what it had, and one that began in
+an earlier chunk takes its sums up from the carry by one copy.
+
+**One trace a shape** (``ssd._bind``). Operands in the rows' dtype,
+float32 accumulation. On the chip the widths are multiples of 128 and the
+tile of 8 (16 in bf16); interpret mode (any other backend, the tests)
+takes any shape.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .ssd import _bind, _interpret, _nbytes
+
+ROW_TILE = 256      # rows a grid step, at most
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+_ZERO, _CARRY = 1, 2                # a tile's ``init``: the dW before it
+
+
+def row_tile(block: int) -> int:
+    """Rows a grid step: the largest multiple of 128 up to ``ROW_TILE``
+    that divides the block, else the block."""
+    return next((t for t in range(ROW_TILE, 0, -128) if block % t == 0),
+                block)
+
+
+def tile_tables(expert, live, begun, m: int):
+    """The kernels' tables [tiles] (int32) from the layout's row tiles
+    (whole chunks of ``m``): the ``expert`` held (any value where ``live``
+    is 0), the ``live`` rows of the tile, and whether the expert's run had
+    ``begun`` before it. Returns (expert of the tile, the tile OF ITS
+    CHUNK whose blocks it names, its live rows, init: ``_ZERO`` at an
+    expert's first tile, ``_CARRY`` at a later one that opens a chunk,
+    else 0)."""
+    i32 = jnp.int32
+    n = expert.shape[0]
+    t = jnp.tile(jnp.arange(m, dtype=i32), n // m)
+    src = jax.lax.cummax(jnp.where(live > 0, t, 0).reshape(-1, m),
+                         axis=1).reshape(-1)
+    of_tile = expert[jnp.arange(n, dtype=i32) - t + src]
+    opens = ((t == 0) | ~begun) & (live > 0)
+    init = jnp.where(opens, jnp.where(begun, _CARRY, _ZERO), 0)
+    return (of_tile.astype(i32), src.astype(i32), live.astype(i32),
+            init.astype(i32))
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _params(resident_bytes: int, tile: int):
+    # what stays in VMEM (weights and dW blocks, two buffers each) and
+    # room for the row tiles and the float32 temporaries
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=int(min(
+            resident_bytes + (16 << 20) + tile * (64 << 10), 126 << 20)))
+
+
+def _rows_spec(tile, width):
+    return pl.BlockSpec((tile, width), lambda t, e, src, *_: (src[t], 0))
+
+
+def _expert_spec(shape):
+    return pl.BlockSpec((1, *shape), lambda t, e, *_: (e[t], 0, 0))
+
+
+# ---------------------------------------------------------------- forward
+def _fwd_kernel(e_ref, src_ref, live_ref, xs_ref, scale_ref, wg_ref, wu_ref,
+                wd_ref, y_ref):
+    del e_ref, src_ref
+
+    @pl.when(live_ref[pl.program_id(0)] > 0)
+    def _():
+        x = xs_ref[...]
+        gate = _dot(x, wg_ref[0])
+        h = (jax.nn.silu(gate) * _dot(x, wu_ref[0])).astype(x.dtype)
+        y_ref[...] = (_dot(h, wd_ref[0]) * scale_ref[...]).astype(
+            y_ref.dtype)
+
+
+def forward(xs, scale, tables, experts, tile: int):
+    """``scale * E(xs)`` [C, D] float32 for a chunk's rows
+    ``xs`` [C, D] in expert order, ``scale`` [C, 1] float32 (the routing
+    weight; 0 where the row is not the expert's), ``tables`` =
+    ``tile_tables``' first three, ``experts`` the held SwiGLU weights
+    ``[E_h, ...]``. The rows of a tile that did not run are not written."""
+    c, d = xs.shape
+    w = [experts[n] for n in ("w_gate", "w_up", "w_down")]
+    f = w[0].shape[-1]
+    call = pl.pallas_call(
+        _fwd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(c // tile,),
+            in_specs=[_rows_spec(tile, d), _rows_spec(tile, 1),
+                      _expert_spec((d, f)), _expert_spec((d, f)),
+                      _expert_spec((f, d))],
+            out_specs=_rows_spec(tile, d)),
+        out_shape=jax.ShapeDtypeStruct((c, d), jnp.float32),
+        compiler_params=_params(2 * _nbytes(*w) // w[0].shape[0], tile),
+        cost_estimate=pl.CostEstimate(
+            flops=int(6 * c * d * f), transcendentals=int(c * f),
+            bytes_accessed=int(3 * _nbytes(xs) + _nbytes(scale, *w))),
+        interpret=_interpret(),
+        name="ds_moe_gmm_fwd",
+    )
+    # the scope and the kernel's name are all a device trace shows of this
+    # call (telemetry/scopes.py)
+    return _bind(call, "ds.moe_gmm_fwd", ("fwd", tile), *tables, xs, scale,
+                 *w)[0]
+
+
+# --------------------------------------------------------------- backward
+def _bwd_kernel(e_ref, src_ref, live_ref, init_ref, xs_ref, dys_ref,
+                scale_ref, wg_ref, wu_ref, wd_ref, cg_ref, cu_ref, cd_ref,
+                dxs_ref, dwt_ref, dg_ref, du_ref, dd_ref, sem, *,
+                router_grad: bool):
+    del src_ref
+    t = pl.program_id(0)
+    sums = ((cg_ref, dg_ref), (cu_ref, du_ref), (cd_ref, dd_ref))
+
+    @pl.when(init_ref[t] == _ZERO)
+    def _():
+        for _, ref in sums:
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    @pl.when(init_ref[t] == _CARRY)
+    def _():
+        copies = [pltpu.make_async_copy(carry.at[pl.ds(e_ref[t], 1)], ref,
+                                        sem.at[i])
+                  for i, (carry, ref) in enumerate(sums)]
+        for copy in copies:
+            copy.start()
+        for copy in copies:
+            copy.wait()
+
+    @pl.when(live_ref[t] > 0)
+    def _():
+        x, dy, scale = xs_ref[...], dys_ref[...], scale_ref[...]
+        gate, up = _dot(x, wg_ref[0]), _dot(x, wu_ref[0])
+        sg = jax.nn.sigmoid(gate)
+        act = gate * sg
+        h = act * up
+        dh = _dot(dy, wd_ref[0], _NT)
+        if router_grad:
+            dwt_ref[...] = jnp.sum(dh * h, axis=1, keepdims=True)
+        dh = dh * scale
+        d_up = (dh * act).astype(x.dtype)
+        d_gate = (dh * up * (sg * (1 + gate * (1 - sg)))).astype(x.dtype)
+        dxs_ref[...] = (_dot(d_gate, wg_ref[0], _NT)
+                        + _dot(d_up, wu_ref[0], _NT)).astype(dxs_ref.dtype)
+        dg_ref[0] += _dot(x, d_gate, _TN)
+        du_ref[0] += _dot(x, d_up, _TN)
+        dd_ref[0] += _dot((h * scale).astype(x.dtype), dy, _TN)
+
+
+def backward(xs, dys, scale, tables, experts, sums, tile: int,
+             router_grad: bool):
+    """The chunk's part of the backward: (``dxs`` [C, D] float32 (the
+    add to tokens takes float32 rows: a kernel's own sums, not rounded on
+    the way), ``dwt`` [C, 1] float32: the row's ``dy . E(xs)`` where
+    ``router_grad``, else not written, and the three float32 ``dW`` sums
+    ``[E_h, ...]``: ``sums`` with this chunk's rows added). ``dys`` [C, D]
+    is the result's cotangent by row; the rest as ``forward``, ``tables``
+    all four."""
+    c, d = xs.shape
+    names = ("w_gate", "w_up", "w_down")
+    w = [experts[n] for n in names]
+    held, _, f = w[0].shape
+    carry = pl.BlockSpec(memory_space=pl.ANY)
+    call = pl.pallas_call(
+        functools.partial(_bwd_kernel, router_grad=router_grad),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(c // tile,),
+            in_specs=[_rows_spec(tile, d), _rows_spec(tile, d),
+                      _rows_spec(tile, 1), _expert_spec((d, f)),
+                      _expert_spec((d, f)), _expert_spec((f, d)),
+                      carry, carry, carry],
+            out_specs=[_rows_spec(tile, d), _rows_spec(tile, 1),
+                       _expert_spec((d, f)), _expert_spec((d, f)),
+                       _expert_spec((f, d))],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((3,))]),
+        out_shape=[jax.ShapeDtypeStruct((c, d), jnp.float32),
+                   jax.ShapeDtypeStruct((c, 1), jnp.float32),
+                   *(jax.ShapeDtypeStruct(s.shape, s.dtype) for s in sums)],
+        input_output_aliases={10: 2, 11: 3, 12: 4},
+        compiler_params=_params(
+            2 * (_nbytes(*w) + _nbytes(*sums)) // held, tile),
+        cost_estimate=pl.CostEstimate(
+            flops=int(16 * c * d * f), transcendentals=int(c * f),
+            bytes_accessed=int(4 * _nbytes(xs) + _nbytes(scale, *w)
+                               + 2 * _nbytes(*sums))),
+        interpret=_interpret(),
+        name="ds_moe_gmm_bwd",
+    )
+    dxs, dwt, *sums = _bind(call, "ds.moe_gmm_bwd",
+                            ("bwd", tile, router_grad), *tables, xs, dys,
+                            scale, *w, *sums)
+    return dxs, dwt, sums

@@ -1,0 +1,185 @@
+"""The held experts' grouped-matmul kernel pair (``ops/pallas/
+grouped_matmul.py``, PR 41) under ``moe.sharded_moe.held_experts_ffn``
+against the ``jax.numpy`` block loop it replaced (``tests/helpers/
+held_reference.py``): interpret mode, jitted, tiny widths that keep the
+lane rule. The row tile is a quarter of the block here and a chunk four
+blocks' rows (a block an expert held, what ``held_experts_ffn`` takes
+where it is told none; one case runs other chunks), so that a run ends
+in a part-empty tile and crosses chunks as at the cells' sizes. ``tests/test_kimi_linear.py`` and
+``tests/test_mellum.py`` hold the same function to a dense sum over
+experts; its compile for the chip is in ``tests/test_zero_layout.py``."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.moe.sharded_moe import held_chunk, held_experts_ffn
+from deepspeed_tpu.ops.pallas import grouped_matmul
+
+from helpers import held_reference  # noqa: E402  (tests/helpers)
+
+TOKENS, TOP_K, EXPERTS, HELD, BLOCK, TILE = 320, 2, 8, 4, 64, 16
+
+
+@pytest.fixture(autouse=True)
+def _small_tiles(monkeypatch):
+    monkeypatch.setattr(grouped_matmul, "row_tile", lambda block: TILE)
+
+
+def _routing(load: str, rng):
+    """idx [TOKENS, TOP_K] over EXPERTS, of which the first HELD are held."""
+    away = lambda n: HELD + np.stack([  # noqa: E731
+        rng.permutation(EXPERTS - HELD)[:TOP_K] for _ in range(n)])
+    idx = np.stack([rng.permutation(EXPERTS)[:TOP_K]
+                    for _ in range(TOKENS)])
+    if load == "one_expert":        # 320 rows: five blocks, two chunks
+        idx = away(TOKENS)
+        idx[:, 0] = 1
+    elif load == "an_expert_with_none":
+        idx = np.where(idx == 2, EXPERTS - 1 - (idx[:, ::-1] == EXPERTS - 1),
+                       idx)
+    elif load in ("one_over_a_tile", "one_under_a_tile"):
+        idx = away(TOKENS)
+        idx[:TILE + (1 if load == "one_over_a_tile" else -1), 1] = 3
+        idx[BLOCK:2 * BLOCK + 2 * TILE, 0] = 0      # a block and two tiles
+    elif load == "absent_only":
+        idx = away(TOKENS)
+    assert all(len(set(row)) == TOP_K for row in idx), load
+    return idx.astype(np.int32)
+
+
+def _inputs(load, d, f, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    normal = lambda *s: jnp.asarray(  # noqa: E731
+        rng.standard_normal(s, dtype=np.float32), dtype)
+    experts = {"w_gate": normal(HELD, d, f) / d ** 0.5,
+               "w_up": normal(HELD, d, f) / d ** 0.5,
+               "w_down": normal(HELD, f, d) / f ** 0.5}
+    return (normal(TOKENS, d), jnp.asarray(_routing(load, rng)),
+            jnp.asarray(rng.uniform(0.1, 1.0, (TOKENS, TOP_K)), jnp.float32),
+            experts, normal(TOKENS, d))
+
+
+def _all(fn, *static):
+    """(out, rows computed, dx, d weights, the three dW) in one program."""
+    def run(x, idx, w, experts, ct):
+        (out, done), back = jax.vjp(
+            lambda x, w, e: fn(x, idx, w, e, 0, BLOCK, *static),
+            x, w, experts)
+        dx, dw, de = back((ct, jnp.zeros((), jax.dtypes.float0)))
+        return out, done, dx, dw, de["w_gate"], de["w_up"], de["w_down"]
+    return jax.jit(run)
+
+
+def _err(got, want):
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    return float(np.abs(got - want).max() / (np.abs(want).max() + 1e-30))
+
+
+NAMES = ("out", "done", "dx", "dweights", "dw_gate", "dw_up", "dw_down")
+LOADS = ("balanced", "one_expert", "an_expert_with_none",
+         "one_over_a_tile", "one_under_a_tile", "absent_only")
+
+
+@pytest.mark.parametrize("width", [384, 256], ids=["f384", "f256"])
+@pytest.mark.parametrize("load", LOADS)
+def test_kernel_pair_matches_the_block_loop(load, width):
+    """Forward and every gradient (x, the three weights, the routing
+    weights) at float32, where both forms are exact to rounding; ``done``
+    is the rows routed to the held experts, whatever the skew."""
+    args = _inputs(load, 128, width, jnp.float32)
+    got = _all(held_experts_ffn, True)(*args)
+    want = _all(held_reference.held_experts_ffn)(*args)
+    rows = int(np.sum(np.asarray(args[1]) < HELD))
+    assert int(got[1]) == int(want[1]) == rows
+    for name, g, w in zip(NAMES, got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert _err(g, w) < 2e-5, (name, _err(g, w))
+    if load == "absent_only":
+        assert not any(np.asarray(g).any() for g in got), load
+
+
+@pytest.mark.parametrize("chunk", [TILE, 3 * TILE + 1, 24 * TILE])
+@pytest.mark.parametrize("load", ["balanced", "one_expert"])
+def test_any_chunk_gives_what_the_loop_gives(load, chunk):
+    """The rows a chunk are the sweep's own business: one tile a chunk
+    (every run crosses chunks), four (a chunk opens inside a run and
+    holds several experts; the rows asked for round up to whole tiles),
+    24 (one chunk, part empty, whatever the load)."""
+    args = _inputs(load, 128, 256, jnp.float32)
+    got = _all(held_experts_ffn, True, chunk)(*args)
+    want = _all(held_reference.held_experts_ffn)(*args)
+    assert int(got[1]) == int(want[1])
+    for name, g, w in zip(NAMES, got, want):
+        assert _err(g, w) < 2e-5, (name, _err(g, w))
+
+
+def test_the_chunk_rule_by_shape(monkeypatch):
+    """A chunk is what a balanced router sends the held experts and a
+    row tile an expert: any split of that total fits."""
+    monkeypatch.undo()      # the kernels' own row tile: 256 in both cells
+    assert held_chunk(16384, 8, 64, 16, 768) == 32768 + 16 * 256 == 36864
+    assert held_chunk(16384, 8, 256, 8, 1024) == 4096 + 8 * 256 == 6144
+    assert held_chunk(256, 8, 256, 8, 128) == 64 + 8 * 128
+    rng = np.random.default_rng(0)
+    for _ in range(50):     # any split of the even total, in whole tiles
+        cuts = np.sort(rng.integers(0, 32769, 15))
+        loads = np.diff(np.concatenate([[0], cuts, [32768]]))
+        assert np.sum(-(-loads // 256)) * 256 <= 36864
+
+
+def test_bf16_rounds_no_worse_than_the_loop():
+    """At bf16 the kernels keep ``gate``, ``up`` and the sums in float32
+    where the loop rounds each matmul's result: against the float32 loop
+    the kernels' error is within the bf16 loop's own (times 1.5 of room)."""
+    args32 = _inputs("balanced", 128, 384, jnp.float32)
+    cast = lambda t: jax.tree.map(  # noqa: E731
+        lambda v: v.astype(jnp.bfloat16) if v.dtype == jnp.float32
+        and v.ndim > 1 and v.shape != (TOKENS, TOP_K) else v, t)
+    args16 = cast(args32)
+    exact = _all(held_reference.held_experts_ffn)(*args32)
+    loop = _all(held_reference.held_experts_ffn)(*args16)
+    got = _all(held_experts_ffn, True)(*args16)
+    for name, e, l, g in zip(NAMES, exact, loop, got):
+        if name != "done":
+            assert g.dtype == l.dtype, name
+            assert _err(g, e) < max(1.5 * _err(l, e), 1e-3), (
+                name, _err(g, e), _err(l, e))
+
+
+def test_without_router_grad_the_weights_get_zeros_and_the_rest_stays():
+    args = _inputs("balanced", 128, 256, jnp.float32)
+    on = _all(held_experts_ffn, True)(*args)
+    off = _all(held_experts_ffn, False)(*args)
+    for name, a, b in zip(NAMES, on, off):
+        if name == "dweights":
+            assert not np.asarray(b).any()
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+def test_tile_tables_name_one_expert_a_tile_and_skip_the_dead():
+    """Chunks of four tiles of 16 rows: expert 2 with 81 rows (six tiles,
+    the last of one row), expert 3 with 5, then nothing: a dead tile
+    names the last live tile of ITS chunk; ``init`` zeroes an expert's dW
+    at its first tile and takes it from the carry where a chunk opens
+    inside a run."""
+    expert = jnp.asarray([2] * 6 + [3] + [0] * 5, jnp.int32)
+    live = jnp.asarray([16] * 5 + [1, 5] + [0] * 5, jnp.int32)
+    begun = jnp.asarray([False] + [True] * 5 + [False] * 6)
+    of, src, rows, init = (np.asarray(t) for t in grouped_matmul.tile_tables(
+        expert, live, begun, 4))
+    assert rows.tolist() == [16] * 5 + [1, 5] + [0] * 5
+    assert src.tolist() == [0, 1, 2, 3] + [0, 1, 2, 2] + [0] * 4
+    assert of.tolist() == [2] * 6 + [3, 3] + [0] * 4
+    assert init.tolist() == [1, 0, 0, 0] + [2, 0, 1, 0] + [0] * 4
+
+
+@pytest.mark.parametrize("block, tile", [(768, 256), (1024, 256), (128, 128),
+                                         (256, 256), (16, 16), (640, 128)])
+def test_row_tile_divides_the_block(block, tile, monkeypatch):
+    monkeypatch.undo()
+    assert grouped_matmul.row_tile(block) == tile and block % tile == 0

@@ -302,7 +302,7 @@ def test_no_row_is_dropped_under_a_skewed_softmax_router(skew):
                     for e in range(16))
         assert _err(out, dense) < 1e-5
     # the sweep's own trip count is the blocks the layer counts from the load
-    ends = _held_layout(idx, 0, 16, 16)[-1]
+    ends = _held_layout(idx, w, 0, 16, 16)[3]
     sent = jnp.bincount(idx.reshape(-1), length=E)[:16]
     assert int(ends[-1]) == int(jnp.sum((sent + 15) // 16))
     if skew == "all_to_one_held_expert":
@@ -544,7 +544,8 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
         found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
     assert found == (set(scopes.DEVICE_SCOPES) - {"ds.attn", "ds.mlp"}
                      | set(scopes.WINDOW_SCOPES)
-                     | {"ds.moe_router", "ds.moe_experts"})
+                     | {"ds.moe_router", "ds.moe_experts", "ds.moe_gmm_fwd",
+                        "ds.moe_gmm_bwd"})
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values() if row["scope"]}
     for kind in ("swa", "full"):
@@ -561,6 +562,13 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
         assert {d for d in ("fwd", "bwd") if any(
             p.startswith(d + ":ds.layers") and scope in p
             for p in paths)} == {"fwd", "bwd"}, scope
+    # the grouped-matmul kernels (interpreted here) inside the scope
+    # moe_ms.mellum reads. Remat's rerun holds no forward sweep: the
+    # backward rule keeps the inputs alone and nothing else of the layer
+    # reads the sweep's result, so the compiler drops it
+    for want in ("fwd:ds.layers/ds.moe_experts/ds.moe_gmm_fwd",
+                 "bwd:ds.layers/ds.moe_experts/ds.moe_gmm_bwd"):
+        assert want in paths, want
     # what the cell's attn_ms.mellum reads: the layer less its kernels
     rx = re.compile(r"ds\.attn_(swa|full)\b(?!.*ds\.flash_)")
     assert any(rx.search(p) for p in paths)
@@ -574,13 +582,16 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
 # routed layer behind a KDA and an MLA mixer; a Mamba and an attention layer);
 # beside each the sha256 of its lowered train step and the sum of its seeded
 # master weights' magnitudes AT THE PARENT (commit 2d920a0, this file's
-# `_step_text` run on that checkout)
+# `_step_text` run on that checkout). `kimi_linear`'s hash is PR 41's: its
+# routed layers sweep their held experts through the grouped-matmul
+# kernels since (`ops/pallas/grouped_matmul.py`), which no `mistral` or
+# `granite_hybrid` step holds; its weights are still the parent's
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "4d22e45c748e5f52d6cfc3cac5ecff6fa9cdabe7b097995fd7ac513478a725d1",
+        "a3322f24c9bdc762d05a79b8ae941beb38ca39b95cb9c24354fae5a0467886e1",
         7191.956369750438),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",

@@ -357,3 +357,50 @@ def test_ssd_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
     assert "all-to-all" not in hlo and "all-gather" not in hlo
     with pytest.raises(Exception, match="[Mm]osaic"):
         grad(ssd.chunk_ssd, 256).lower(*args).compile()
+
+
+def test_grouped_matmul_kernels_compile_for_v5e(monkeypatch):
+    """The held experts' kernel pair (PR 41) under ``held_experts_ffn`` at
+    the two cells' widths (16384 tokens, top 8, hidden 2304: 16 experts of
+    896 in chunks of 36,864 rows; 8 of 1024 in chunks of 6144, the routing
+    weights' gradient on), forward, remat's rerun and
+    backward, compiled by Mosaic for one described v5e chip: an expert's
+    weights and its float32 ``dW`` blocks held in VMEM at two buffers each
+    (97 MB of the chip's 128 at 1024 wide), the transposed products and
+    the copy from the aliased carry are what interpret mode cannot
+    refuse."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.moe import sharded_moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    n, k, d = 16384, 8, 2304
+    for experts, held, f, router_grad in ((64, 16, 896, False),
+                                          (256, 8, 1024, True)):
+        block = sharded_moe.held_block(n, k, experts)
+        chunk = sharded_moe.held_chunk(n, k, experts, held, block)
+        assert (block, chunk) == {64: (768, 36864), 256: (1024, 6144)}[
+            experts]
+        layer = jax.checkpoint(lambda x, idx, w, ex: (
+            sharded_moe.held_experts_ffn(x, idx, w, ex, 0, block,
+                                         router_grad, chunk)[0]))
+        grad = jax.jit(jax.grad(
+            lambda x, w, ex, idx: 0.5 * jnp.sum(
+                layer(x, idx, w, ex).astype(f32) ** 2), argnums=(0, 1, 2)))
+        hlo = grad.lower(
+            sd((n, d), bf), sd((n, k), f32),
+            {"w_gate": sd((held, d, f), bf), "w_up": sd((held, d, f), bf),
+             "w_down": sd((held, f, d), bf)},
+            sd((n, k), jnp.int32)).compile().as_text()
+        for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd"):
+            assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), (
+                experts, kernel)

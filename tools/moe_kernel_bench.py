@@ -1,0 +1,175 @@
+"""The held experts' sweep alone, on the chip: time and results of this
+checkout's ``moe/sharded_moe.py`` ``held_experts_ffn`` (the grouped-matmul
+kernels of ``ops/pallas/grouped_matmul.py`` and what XLA does round them)
+against the ``jax.numpy`` block loop it replaced
+(``tests/helpers/held_reference.py``).
+
+    chiprun -- python tools/moe_kernel_bench.py [ROW_TILE ...]
+
+Sizes a change to the kernels before a cell is run (PR 41). The shapes are
+the two cells' (16384 tokens, top 8, hidden 2304): ``mellum`` holds 16 of
+64 experts of 896 at a balanced 2048 rows each (ONE chunk of 36,864 rows),
+``mellum_over`` the same with eight of them at 2688: 5120 rows over the
+share's even total, so a second chunk (what that costs: ``held_chunk``);
+``kimi`` holds 8 of 256 of 1024 at 288 rows each, ``kimi_skew`` the same
+with one expert at 1163 (a chunk of 6144 rows holds either). A line a
+shape and ROW_TILE (default
+128, 256, 512: the largest multiple of 128 up to it that divides the
+block is the kernels' row tile), each number the device busy time of one
+call in ms, from a profiler trace of 10 calls:
+
+- ``fwd``: one layer's forward sweep, ``busy`` and the kernel's part;
+- ``grad``: ``jax.grad`` of a rematted layer's sweep (a loss linear in
+  the result, so the backward sweep alone, as in a train step: the
+  backward rule keeps nothing but the inputs and the compiler drops the
+  rerun's forward sweep), ``busy``, each kernel's part and the ``rest``
+  (the sort, the gathers, the adds to tokens, the loop);
+- ``ops``: the longest device ops of ``grad`` outside the kernels;
+- ``router_grad``: whether the routing weights' gradient is made;
+- with the default row tile also ``jnp``: the same two of the block loop,
+  and ``err``: the result and the gradients against the loop's, largest
+  difference over the largest value.
+
+A device number, so only on a TPU. Not the yardstick: what a user feels
+is ``benchmark/run.py``.
+"""
+
+from __future__ import annotations
+
+import collections
+import json
+import os
+import sys
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(1, os.path.join(ROOT, "tests"))   # helpers/held_reference.py
+sys.path.insert(2, os.path.join(ROOT, "tools"))
+# the trace of CALLS calls and its reductions: one definition for the tools
+from kda_kernel_bench import CALLS, busy_ms, rel_err, traced  # noqa: E402
+
+TOKENS, TOP_K, HIDDEN = 16384, 8, 2304
+KERNELS = ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd")
+# name: (experts, held, expert width, rows of expert 0, router_grad)
+SHAPES = {"mellum": (64, 16, 896, None, False),
+          "mellum_over": (64, 16, 896, 2688, False),
+          "kimi": (256, 8, 1024, None, True),
+          "kimi_skew": (256, 8, 1024, 1163, True)}
+
+
+def routing(experts: int, held: int, skew):
+    """idx [TOKENS, TOP_K]: ``mellum`` sends every expert its even 2048
+    rows, with ``skew`` the first eight that many; the Kimi shapes send
+    the held experts 9/16 of their even 512 (the cell reads 247-294),
+    with ``skew`` expert 0 that many, from tokens that had not chosen
+    it."""
+    n, j = np.arange(TOKENS)[:, None], np.arange(TOP_K)[None, :]
+    idx = (n + experts // TOP_K * j) % experts
+    if experts > 64:
+        away = held + (n + (experts // TOP_K - 1) * j) % (experts - held)
+        sent = n // (experts // TOP_K) % 16 < 9
+        idx = np.where(sent, idx, away)
+        if skew:
+            more = np.flatnonzero(~sent[:, 0])[:skew - 288]
+            idx[more, 0] = 0
+    elif skew:      # a choice of an absent expert to one more held one
+        more = np.arange(8 * (skew - 2048))
+        idx[more] = np.where(idx[more] == more[:, None] % 8 + held,
+                             (more[:, None] % 8 + 1) % 8, idx[more])
+    return idx.astype(np.int32)
+
+
+def inputs(name: str, seed: int = 41):
+    import jax.numpy as jnp
+    experts, held, width, skew, _ = SHAPES[name]
+    rng = np.random.default_rng(seed)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    normal = lambda *s: rng.standard_normal(s, dtype=np.float32)  # noqa: E731
+    ex = {"w_gate": normal(held, HIDDEN, width) / HIDDEN ** 0.5,
+          "w_up": normal(held, HIDDEN, width) / HIDDEN ** 0.5,
+          "w_down": normal(held, width, HIDDEN) / width ** 0.5}
+    return (jnp.asarray(normal(TOKENS, HIDDEN), bf),
+            jnp.asarray(routing(experts, held, skew)),
+            jnp.asarray(rng.uniform(0.05, 0.3, (TOKENS, TOP_K)), f32),
+            {k: jnp.asarray(v, bf) for k, v in ex.items()},
+            jnp.asarray(normal(TOKENS, HIDDEN), bf))
+
+
+def split(events) -> dict:
+    out = {"busy": busy_ms(events)}
+    for k in KERNELS:
+        out[k] = busy_ms(events, rf"^%?{k}[.\d]* = ")
+    out["rest"] = 2 * out["busy"] - sum(out.values())
+    return {k: round(v, 3) for k, v in out.items()}
+
+
+def longest(events, n: int = 6) -> dict:
+    """The n leaf ops outside the kernels with the most time, ms a call."""
+    total = collections.Counter()
+    for name, a, b in events:
+        op = name.split(" = ")[0].lstrip("%")
+        if not op.startswith(("while", "ds_moe_gmm", "conditional")):
+            total[op] += b - a
+    return {op: round(1e-6 * ns / CALLS, 3) for op, ns in total.most_common(n)}
+
+
+def main(argv) -> int:
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+    from helpers import held_reference
+    tiles = [int(a) for a in argv] or [128, 256, 512]
+    f32 = jnp.float32
+
+    def forms(fn, block, *more):     # more: router_grad, rows a chunk
+        def layer(x, idx, w, ex):
+            return fn(x, idx, w, ex, 0, block, *more)[0]
+
+        def loss(x, w, ex, idx, ct):
+            return jnp.sum(jax.checkpoint(layer)(x, idx, w, ex).astype(f32)
+                           * ct.astype(f32))
+        return jax.jit(layer), jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+    for name, (experts, held, width, _, router_grad) in SHAPES.items():
+        x, idx, w, ex, ct = inputs(name)
+        block = sharded_moe.held_block(TOKENS, TOP_K, experts)
+        chunk = sharded_moe.held_chunk(TOKENS, TOP_K, experts, held, block)
+        rows = np.bincount(np.asarray(idx).ravel(),
+                           minlength=experts)[:held]
+        default = grouped_matmul.ROW_TILE
+        for tile in tiles:
+            grouped_matmul.ROW_TILE = tile
+            fwd, grad = forms(sharded_moe.held_experts_ffn, block,
+                              router_grad, chunk)
+            ev = traced(jax, grad, (x, w, ex, idx, ct))
+            line = {"shape": name, "block": block, "chunk": chunk,
+                    "row_tile": grouped_matmul.row_tile(block),
+                    "rows": [int(rows.min()), int(rows.max())],
+                    "router_grad": router_grad,
+                    "fwd": split(traced(jax, fwd, (x, idx, w, ex))),
+                    "grad": split(ev), "ops": longest(ev)}
+            if tile == default:
+                ref_fwd, ref_grad = forms(held_reference.held_experts_ffn,
+                                          block)
+                line["jnp"] = {
+                    "fwd": round(busy_ms(traced(
+                        jax, ref_fwd, (x, idx, w, ex))), 3),
+                    "grad": round(busy_ms(traced(
+                        jax, ref_grad, (x, w, ex, idx, ct))), 3)}
+                got = (fwd(x, idx, w, ex), *grad(x, w, ex, idx, ct))
+                want = (ref_fwd(x, idx, w, ex),
+                        *ref_grad(x, w, ex, idx, ct))
+                if not router_grad:     # the kernel leaves that product out
+                    got, want = (got[:2] + got[3:]), (want[:2] + want[3:])
+                line["err"] = [round(rel_err(a, b), 5) for a, b in zip(
+                    jax.tree.leaves(got), jax.tree.leaves(want))]
+            print(json.dumps(line), flush=True)
+        grouped_matmul.ROW_TILE = default
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
