@@ -13,6 +13,7 @@ from .mellum import Mellum, mellum_config  # noqa: F401
 from .mistral import Mistral, mistral_config  # noqa: F401
 from .mixtral import Mixtral, mixtral_config  # noqa: F401
 from .opt import OPT, opt_config  # noqa: F401
+from .ouro import Ouro, ouro_config  # noqa: F401
 from .phi import Phi, Phi3, phi3_config, phi_config  # noqa: F401
 from .qwen import (Qwen, Qwen2, Qwen2MoE, qwen2_config,  # noqa: F401
                    qwen2_moe_config, qwen_config)

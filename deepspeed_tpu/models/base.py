@@ -123,6 +123,17 @@ class ModelConfig:
     logits_scaling: float = 1.0         # logits / this
     attention_multiplier: float | None = None   # the softmax scale; None =
     #                                             head_dim ** -0.5
+    # a looped stack (models/ouro.py): the num_layers layers run
+    # total_ut_steps times a forward pass on the SAME weights, the final
+    # norm after every pass, and every pass is an exit
+    total_ut_steps: int = 1
+    sandwich_norm: bool = False     # a second norm on each sublayer's
+    #                                 OUTPUT, before the residual add
+    exit_gate: bool = False         # a d -> 1 gate on every exit's state:
+    #                                 the loss is the expected loss under
+    #                                 the exit distribution the gates give
+    exit_entropy_beta: float = 0.0  # ... less this x that distribution's
+    #                                 entropy, a position
     # numerics
     param_dtype: Any = None   # set to jnp dtype in __post_init__
     loss_chunk: int = 0       # >0: fused chunked cross-entropy (tokens per
@@ -291,6 +302,8 @@ class ModelConfig:
                 mlp += 3 * d * f * self.moe_num_shared_experts + d
         n_norms = (1 if self.parallel_residual
                    and not self.parallel_dual_norm else 2)
+        if self.sandwich_norm:
+            n_norms += 2
         mlp_bias = self.effective_mlp_bias
         per_layer = attn + mlp + n_norms * d  # + ln scales
         if self.use_bias or self.attn_qkv_bias:
@@ -310,7 +323,8 @@ class ModelConfig:
             embed += 2 * d
         pos = self.max_seq_len * d if self.position_embedding == "learned" else 0
         final_norm = d + (d if self.norm_type == "layernorm" else 0)
-        return embed + pos + L * per_layer + final_norm
+        gate = d + 1 if self.exit_gate else 0
+        return embed + pos + L * per_layer + final_norm + gate
 
     def num_active_params(self) -> int:
         """Parameters a token actually computes with: dense models run
@@ -395,7 +409,12 @@ class ModelConfig:
             return 6 * n + sum(kda if mixer == "kda" else mla
                                for mixer, _ in kinds)
         attn_flops = 12 * self.num_layers * self.hidden_size * ctx
-        return 6 * n + attn_flops
+        # a looped stack runs everything but the embedding's gather once a
+        # pass (the layers, the final norm, the head and the gate)
+        again = (self.total_ut_steps - 1) * (
+            n - (0 if self.tie_embeddings
+                 else self.vocab_size * self.hidden_size))
+        return 6 * (n + again) + self.total_ut_steps * attn_flops
 
 
 class Model(Protocol):

@@ -157,11 +157,18 @@ class RoutedStackOfKinds(StackOfKinds):
                                          act_sharding=act_sharding)
         return (ce, stats) if with_stats else ce
 
+    @staticmethod
+    def record_step_metrics(reg, metrics: dict) -> None:
+        """How the engine records what ``after_step`` returned, one step
+        behind: the routed families' recorder."""
+        from ..moe.dispatch import record_held_expert_counts
+        record_held_expert_counts(reg, metrics)
+
     def _held_metrics(self, stats, each=None) -> dict:
         """What an ``after_step`` returns of the routed layers' counts of
         one step (``stats``: ``loss(with_stats=True)``'s, summed over the
         micro-batches), as device scalars the engine feeds
-        ``moe.dispatch.record_held_expert_counts`` with: the rows routed
+        ``record_step_metrics`` with: the rows routed
         to the experts held here (the first ``held_experts``) and the rows
         they computed (equal, or rows were dropped), over
         ``moe_held_calls`` routed layers of ``moe_held_experts`` each.

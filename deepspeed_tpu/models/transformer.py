@@ -457,10 +457,12 @@ class DecoderLM:
             ce = self._chunked_ce(params, x, targets)
         return ce + self.aux_loss_coef() * aux
 
-    def _chunked_ce(self, params: PyTree, x, targets) -> jax.Array:
+    def _chunked_ce(self, params: PyTree, x, targets, weights=None):
         """Mean cross-entropy of final-normed hidden states ``x``, one
         ``loss_chunk`` slab of logits at a time
-        (``_chunked_cross_entropy``)."""
+        (``_chunked_cross_entropy``). With float32 ``weights`` a row
+        ``[B, S]``: ``(sum(weights * nll) / count, the rows' nll)``
+        (``_chunked_weighted_cross_entropy``)."""
         c = self.config
         # the casts stay outside the custom_vjp, so JAX transposes them
         # (and the tied embedding's ``.T``) onto the parameters' dtypes
@@ -475,6 +477,9 @@ class DecoderLM:
             raise ValueError(
                 f"loss_chunk {c.loss_chunk} (effective {chunk}) must "
                 f"divide sequence length {s}")
+        if weights is not None:
+            return _chunked_weighted_cross_entropy(x, W, bias, targets,
+                                                   weights, chunk)
         return _chunked_cross_entropy(x, W, bias, targets, chunk)
 
     def _final_hidden(self, params: PyTree, tokens, *, attn_fn=None,
@@ -612,10 +617,11 @@ def _valid_count(targets):
     return jnp.maximum(jnp.sum(targets != -100), 1).astype(jnp.float32)
 
 
-def _chunk_logits(x_c, t_c, W, bias):
+def _chunk_logits(x_c, t_c, W, bias, rows: bool = False):
     """One slab: the f32 logits [B, chunk, V] of a chunk, their
     logsumexp, the mask and clamped targets, and the chunk's summed NLL
-    (same masking contract as ops.layers.cross_entropy_loss)."""
+    (same masking contract as ops.layers.cross_entropy_loss); with
+    ``rows`` the NLL a row [B, chunk] instead, 0 where masked."""
     logits = (x_c @ W).astype(jnp.float32)
     if bias is not None:
         logits = logits + bias
@@ -623,7 +629,8 @@ def _chunk_logits(x_c, t_c, W, bias):
     valid = t_c != -100
     safe = jnp.where(valid, t_c, 0)
     tl = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-    return logits, lse, valid, safe, jnp.sum(jnp.where(valid, lse - tl, 0.0))
+    nll = jnp.where(valid, lse - tl, 0.0)
+    return logits, lse, valid, safe, nll if rows else jnp.sum(nll)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -686,3 +693,81 @@ def _chunked_cross_entropy_bwd(chunk, res, g):
 
 _chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd,
                               _chunked_cross_entropy_bwd)
+
+
+# ---------------- the same, each row with a weight of its own ----------------
+def _rows_by_chunk(rows, chunk):
+    """[B, S] (weights in, NLL rows out) as the scan's [S/chunk, B, chunk],
+    and back."""
+    b, s = rows.shape
+    return rows.reshape(b, s // chunk, chunk).swapaxes(0, 1)
+
+
+def _rows_from_chunks(rows):
+    n, b, chunk = rows.shape
+    return rows.swapaxes(0, 1).reshape(b, n * chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunked_weighted_cross_entropy(x, W, bias, targets, weights, chunk):
+    """``(sum(weights * nll) / count, nll)``: the cross-entropy of
+    ``_chunked_cross_entropy`` with a float32 weight a row ``[B, S]`` that
+    is itself differentiated (a looped stack's exit distribution over its
+    passes' rows, stacked along ``B``: models/ouro.py), and beside it the
+    rows' own NLL ``[B, S]`` float32, for statistics: NO gradient flows
+    through the second result (the caller stops it).
+
+    One call over every pass's rows keeps ONE float32 ``dW`` from forward
+    to backward where a call a pass would keep one each. Under
+    differentiation the forward rule makes ``dx`` and ``dW`` in the same
+    scan with the weights on the slab's ``dlogits`` (in f32, before the
+    one rounding), and keeps the rows' NLL for the weights' cotangent,
+    ``nll / count``."""
+    def body(_, xs):
+        x_c, t_c = xs
+        return None, _chunk_logits(x_c, t_c, W, bias, rows=True)[-1]
+
+    _, nll = jax.lax.scan(body, None, _by_chunk(x, targets, chunk))
+    nll = _rows_from_chunks(nll)
+    return jnp.sum(weights * nll) / _valid_count(targets), nll
+
+
+def _chunked_weighted_cross_entropy_fwd(x, W, bias, targets, weights, chunk):
+    def body(carry, xs):
+        dW, db = carry
+        x_c, t_c, w_c = xs
+        logits, lse, valid, safe, nll_c = _chunk_logits(x_c, t_c, W, bias,
+                                                        rows=True)
+        onehot = safe[..., None] == jnp.arange(logits.shape[-1])
+        dl = jnp.where(
+            valid[..., None],
+            (jnp.exp(logits - lse[..., None]) - onehot) * w_c[..., None],
+            0.0)
+        if bias is not None:
+            db = db + dl.sum((0, 1))
+        dl = dl.astype(x_c.dtype)
+        dx_c = jnp.einsum("bcv,dv->bcd", dl, W)
+        dW = dW + jnp.einsum("bcd,bcv->dv", x_c, dl,
+                             preferred_element_type=jnp.float32)
+        return (dW, db), (dx_c, nll_c)
+
+    init = (jnp.zeros(W.shape, jnp.float32),
+            None if bias is None else jnp.zeros(bias.shape, jnp.float32))
+    (dW, db), (dx, nll) = jax.lax.scan(
+        body, init, (*_by_chunk(x, targets, chunk),
+                     _rows_by_chunk(weights, chunk)))
+    nll = _rows_from_chunks(nll)
+    count = _valid_count(targets)
+    return ((jnp.sum(weights * nll) / count, nll),
+            (dx.swapaxes(0, 1).reshape(x.shape), dW, db, count, nll))
+
+
+def _chunked_weighted_cross_entropy_bwd(chunk, res, g):
+    dx, dW, db, count, nll = res
+    k = g[0] / count        # g[1], the rows' own cotangent, is not taken
+    return ((dx * k).astype(dx.dtype), (dW * k).astype(dx.dtype),
+            None if db is None else db * k, None, nll * k)
+
+
+_chunked_weighted_cross_entropy.defvjp(_chunked_weighted_cross_entropy_fwd,
+                                       _chunked_weighted_cross_entropy_bwd)

@@ -330,6 +330,8 @@ class DeepSpeedEngine:
         # pipeline never gains a sync.
         self._numsan = None
         self._numsan_pending = None
+        self._model_metrics_recorder = None     # set by _step_parts
+        self._model_metrics_pending = None
         self._numsan_leaf_paths = None
         ns_cfg = self.config.numsan
         if ns_cfg.enabled or os.environ.get("DS_NUMSAN", "") \
@@ -718,6 +720,12 @@ class DeepSpeedEngine:
             and not (zcfg.zero_quantized_weights
                      or zcfg.zero_quantized_gradients
                      or zcfg.zero_hierarchical_allgather))
+        # ... and says itself how the metrics its ``after_step`` returns
+        # are recorded (``record_step_metrics(registry, metrics)``): the
+        # engine feeds it one step behind and knows no family's names
+        self._model_metrics_recorder = (
+            getattr(self.module, "record_step_metrics", None)
+            if with_stats else None)
 
         def micro_loss(params, batch, scale, step, stats=False):
             """(scaled loss, aux): aux is the loss, or with ``stats``
@@ -1051,8 +1059,8 @@ class DeepSpeedEngine:
               if tel is not None else _NULLCM):
             if tel is not None:
                 self._telemetry_boundary(tel, metrics)
-                if "moe_held_rows" in metrics:
-                    self._held_counts_feed(tel, metrics)
+                if self._model_metrics_recorder is not None:
+                    self._model_metrics_feed(tel, metrics)
                 if jax.process_count() > 1:
                     # per-step straggler cadence (ISSUE 20): step-stride
                     # rate-limited inside (the stride derives only from
@@ -1137,18 +1145,19 @@ class DeepSpeedEngine:
         except Exception:
             return self.skipped_steps
 
-    def _held_counts_feed(self, tel, metrics):
-        """A routed model's counts of the step (its ``after_step``
-        metrics, device scalars) into the registry, as ``_numsan_feed``
-        reads its own: this step's are queued and the PREVIOUS step's,
-        which the donated state has already materialised, are read, so
-        nothing waits on the device. The registry is one step behind."""
-        pending = getattr(self, "_held_counts_pending", None)
-        self._held_counts_pending = metrics
+    def _model_metrics_feed(self, tel, metrics):
+        """The model's own metrics of the step (what its ``after_step``
+        returned, device scalars: a routed family's held-expert counts, a
+        looped stack's exit statistics) into the registry through the
+        model's ``record_step_metrics``, as ``_numsan_feed`` reads its
+        own: this step's are queued and the PREVIOUS step's, which the
+        donated state has already materialised, are read, so nothing
+        waits on the device. The registry is one step behind."""
+        pending, self._model_metrics_pending = (
+            self._model_metrics_pending, metrics)
         reg = tel.get_registry()
         if pending is not None and reg is not None:
-            from ..moe.dispatch import record_held_expert_counts
-            record_held_expert_counts(reg, pending)
+            self._model_metrics_recorder(reg, pending)
 
     # --- numsan (ISSUE 18) --------------------------------------------
     def _numsan_feed(self, metrics):

@@ -67,6 +67,12 @@ class PipelinedDecoderLM:
         self.num_stages = num_stages
         self.num_microbatches = num_microbatches
         self.schedule = schedule
+        if getattr(model.config, "total_ut_steps", 1) > 1:
+            raise NotImplementedError(
+                f"{type(model).__name__} is a looped stack: its hidden "
+                f"state would go round the ring of stages total_ut_steps = "
+                f"{model.config.total_ut_steps} times a forward pass, and "
+                f"the schedules here make one trip")
         L = model.config.num_layers
         if L % num_stages != 0:
             raise ValueError(

@@ -157,6 +157,20 @@ WINDOW_SCOPES = (
     "ds.attn_full",    # the same of a full_attention layer
     "ds.rope",         # models/mellum.py _attention: the rotation of q, k
 )
+# what a looped stack opens beside ds.attn and ds.mlp (models/ouro.py);
+# ``tests/test_ouro.py`` holds this list equal to what that model's step
+# carries. The sublayers' output norms lie inside ds.attn / ds.mlp, the T
+# exits' head inside ds.loss_head
+LOOP_SCOPES = (
+    "ds.loop",         # models/ouro.py _exit_states, inside ds.layers: one
+    #                    pass's body (the scan over the layers and the
+    #                    final norm), so that the loop's own carry, the
+    #                    stacking of the exits and the sum of the passes'
+    #                    gradients are what ds.layers holds outside it
+    "ds.exit_gate",    # models/ouro.py loss, inside ds.loss_head: the
+    #                    gate, the exit distribution, its entropy, the
+    #                    mixing of the exits' losses and the statistics
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

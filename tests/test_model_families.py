@@ -9,9 +9,9 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import (GPT2, OPT, Bloom, Falcon, GPTJ, GPTNeoX,
-                                  InternLM, Llama, Mistral, Mixtral, Phi,
-                                  Phi3, Qwen, Qwen2, Qwen2MoE,
-                                  get_model_class)
+                                  GraniteHybrid, InternLM, Llama, Mellum,
+                                  Mistral, Mixtral, Ouro, Phi, Phi3, Qwen,
+                                  Qwen2, Qwen2MoE, get_model_class)
 
 FAMILIES = [GPT2, Llama, Mistral, Mixtral, Falcon, OPT, Phi, Phi3, Qwen,
             Qwen2, Qwen2MoE, Bloom, GPTJ, GPTNeoX, InternLM]
@@ -43,10 +43,37 @@ def test_family_init_loss_decode(cls):
     assert int(cache["index"]) == 16
 
 
+# families that train and refuse to decode: a stack of kinds has no single
+# block(), a looped stack would need a cache slot a pass and layer
+TRAIN_ONLY = [GraniteHybrid, Mellum, Ouro]
+
+
+@pytest.mark.parametrize("cls", TRAIN_ONLY)
+def test_train_only_family_init_loss_rules_and_refusal(cls):
+    """The tiny preset initializes, its analytic parameter count is the
+    tree's, the partition rules lay every leaf out (tests/test_ouro.py
+    holds a looped stack's to a rule for EVERY leaf, its two output norms a
+    layer and its gate among them), the loss is finite, and the serving
+    entry points refuse."""
+    from deepspeed_tpu.parallel.partition import match_rules
+    model = tiny(cls)
+    params = model.init(jax.random.PRNGKey(0))
+    n_actual = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    assert model.config.num_params() == n_actual, cls.__name__
+    match_rules(model.partition_rules(), params)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 512)
+    loss = model.loss(params, (tokens[:, :-1], tokens[:, 1:]))
+    assert jnp.isfinite(loss)
+    assert model.apply(params, tokens[:, :-1]).shape == (2, 128, 512)
+    for entry in (model.decode, model.init_cache):
+        with pytest.raises(NotImplementedError):
+            entry()
+
+
 def test_registry_covers_reference_families():
     for name in ("gpt2", "llama", "mistral", "mixtral", "falcon", "opt",
                  "phi", "phi3", "qwen", "qwen2", "qwen2_moe", "bloom",
-                 "gptj", "gptneox", "internlm", "bert"):
+                 "gptj", "gptneox", "internlm", "bert", "ouro"):
         assert get_model_class(name) is not None
 
 
