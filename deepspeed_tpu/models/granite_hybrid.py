@@ -14,7 +14,8 @@ hidden 2048, ``layer_types`` nine ``mamba`` to one ``attention``::
 **mamba** (Mamba-2: H heads of P, state N, G groups, ``ops/ssd.py``)::
 
     [z | xBC | dt] = h W_in          widths H P | H P + 2 G N | H
-    [x | B | C] = silu(conv4(xBC) + b_conv)          causal, depthwise
+    [x | B | C] = silu(conv4(xBC) + b_conv)          causal, depthwise:
+                                          ops.layers.short_conv, one pass
     dt = softplus(dt + dt_bias);   A = -exp(A_log)           per head
     y = chunk_ssd(x, dt, A, B, C) + D x
     out = (rmsnorm(y * silu(z)) * w) W_out       the norm over all H P
@@ -178,7 +179,7 @@ class GraniteHybrid(StackOfKinds):
             params, x * (1.0 / self.config.logits_scaling), targets)
 
     # ---------------- the mixers ----------------
-    def _mamba(self, p, h, ssd_fn):
+    def _mamba(self, p, h, ssd_fn, conv_fn):
         c = self.config
         b, s, _ = h.shape
         nh, hd, g, n = (c.mamba_n_heads, c.mamba_d_head, c.mamba_n_groups,
@@ -187,10 +188,10 @@ class GraniteHybrid(StackOfKinds):
         f32 = jnp.float32
         proj = h @ p["w_in"]
         z = proj[..., :inner]
-        xbc = L.causal_conv(proj[..., inner:2 * inner + 2 * g * n],
-                            p["conv_w"], p.get("conv_b"))
+        # the convolution and the SiLU: one pass (scope ds.conv)
+        xbc = conv_fn(proj[..., inner:2 * inner + 2 * g * n], p["conv_w"],
+                      p.get("conv_b"))
         with jax.named_scope("ds.mix_pre"):
-            xbc = L.silu(xbc)
             dt = jax.nn.softplus(
                 proj[..., 2 * inner + 2 * g * n:].astype(f32)
                 + p["dt_bias"].astype(f32))
@@ -218,11 +219,14 @@ class GraniteHybrid(StackOfKinds):
         return attn_fn(q, k, v, causal=True).reshape(b, s, nh * hd) @ p["wo"]
 
     def _mixers(self, attn_fn, act_sharding):
-        """The scan's kernels run per shard of ``act_sharding`` where the
+        """(attention, scan, short convolution): the scan's and the
+        convolution's kernels run per shard of ``act_sharding`` where the
         mesh has more than one device."""
         from ..ops.ssd import chunk_ssd, sharded_chunk_ssd
-        return (attn_fn, chunk_ssd if act_sharding is None
-                else sharded_chunk_ssd(act_sharding))
+        if act_sharding is None:
+            return attn_fn, chunk_ssd, L.short_conv
+        return (attn_fn, sharded_chunk_ssd(act_sharding),
+                L.sharded_short_conv(act_sharding))
 
     def _residual(self, x, y):
         """x + residual_multiplier * y, in float32 and rounded once (0.22
@@ -233,11 +237,12 @@ class GraniteHybrid(StackOfKinds):
 
     def _one_layer(self, p, x, mixers):
         c = self.config
-        attn_fn, ssd_fn = mixers
+        attn_fn, ssd_fn, conv_fn = mixers
         if "mamba" in p:
             with jax.named_scope("ds.mamba"):
                 h = L.rms_norm(x, p["ln1_scale"], c.norm_eps)
-                x = self._residual(x, self._mamba(p["mamba"], h, ssd_fn))
+                x = self._residual(
+                    x, self._mamba(p["mamba"], h, ssd_fn, conv_fn))
         else:
             with jax.named_scope("ds.attn"):
                 h = L.rms_norm(x, p["ln1_scale"], c.norm_eps)

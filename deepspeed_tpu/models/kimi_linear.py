@@ -16,7 +16,8 @@ sigmoid-routed experts of width 1024 (top 8, one shared expert) after it.
     o = chunk_kda(q, k, v, g, beta)
     y = (rmsnorm_head(o) * sigmoid((x Wg1) Wg2 + b_g)) Wo
 
-``conv4`` is a causal depthwise convolution of width 4 along the sequence.
+``conv4`` is a causal depthwise convolution of width 4 along the sequence;
+with the SiLU and the l2 norm it is one pass of ``ops.layers.short_conv``.
 **MLA** (``mla_use_nope``: neither part of q or k is rotated)::
 
     q = x Wq  as H x (nope + rope);   [c, k_pe] = x Wkva  (kv_lora + rope)
@@ -234,33 +235,24 @@ class KimiLinear(RoutedStackOfKinds):
         }
 
     # ---------------- the mixers ----------------
-    def _kda(self, p, h, kda_fn):
+    def _kda(self, p, h, kda_fn, conv_fn):
+        """One KDA mixer on the normed ``h``. The projections carry ds.kda
+        alone (what kind "matmul" finds). q, k and v are each ONE pass of
+        ``conv_fn`` (``ops.layers.short_conv``, scope ds.conv) over their
+        projection: the convolution, SiLU and, for q and k, the head's l2
+        norm, written in the [B, S, H d] layout the scan's kernels read.
+        What else lies before the scan (beta, the decay and ``g``) is
+        ds.mix_pre, what lies after it ds.mix_post."""
         c = self.config
         b, s, _ = h.shape
         nh, dk = c.kda_num_heads, c.kda_head_dim
         f32 = jnp.float32
-
-        def l2norm(x):
-            x = x.astype(f32)
-            return x * jax.lax.rsqrt(
-                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-
         heads = lambda x: x.reshape(b, s, nh, dk)  # noqa: E731
-        # the projections carry ds.kda alone (what kind "matmul" finds);
-        # causal_conv opens ds.conv; what else lies before the scan is
-        # ds.mix_pre, what lies after it ds.mix_post
         pre = lambda: jax.named_scope("ds.mix_pre")  # noqa: E731
-
-        def short(w, conv):
-            y = L.causal_conv(h @ p[w], p[conv])
-            with pre():
-                return heads(L.silu(y))
-
-        q, k, v = (short("wq", "conv_q"), short("wk", "conv_k"),
-                   short("wv", "conv_v"))
-        with pre():
-            q = (l2norm(q) * dk ** -0.5).astype(h.dtype)
-            k = l2norm(k).astype(h.dtype)
+        q = heads(conv_fn(h @ p["wq"], p["conv_q"], norm_width=dk,
+                          norm_scale=dk ** -0.5))
+        k = heads(conv_fn(h @ p["wk"], p["conv_k"], norm_width=dk))
+        v = heads(conv_fn(h @ p["wv"], p["conv_v"]))
         beta = h @ p["w_b"]
         with pre():
             beta = jax.nn.sigmoid(beta.astype(f32))
@@ -305,11 +297,11 @@ class KimiLinear(RoutedStackOfKinds):
             scaling=c.routed_scaling_factor)
 
     # ---------------- one layer, the stack ----------------
-    def _mix(self, p, x, attn_fn, kda_fn):
+    def _mix(self, p, x, attn_fn, kda_fn, conv_fn):
         with jax.named_scope("ds.kda" if "kda" in p else "ds.mla"):
             h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
             if "kda" in p:
-                return x + self._kda(p["kda"], h, kda_fn)
+                return x + self._kda(p["kda"], h, kda_fn, conv_fn)
             return x + self._mla(p["mla"], h, attn_fn)
 
     def _channel(self, p, x):
@@ -325,11 +317,14 @@ class KimiLinear(RoutedStackOfKinds):
         return self._channel(p, self._mix(p, x, *mixers))
 
     def _mixers(self, attn_fn, act_sharding):
-        """(attention, KDA): on a mesh of more than one device the KDA
-        kernels run per shard of ``act_sharding``."""
+        """(attention, KDA, short convolution): on a mesh of more than one
+        device the KDA and the convolution's kernels run per shard of
+        ``act_sharding``."""
         from ..ops.kda import chunk_kda, sharded_chunk_kda
-        return (attn_fn, chunk_kda if act_sharding is None
-                else sharded_chunk_kda(act_sharding))
+        if act_sharding is None:
+            return attn_fn, chunk_kda, L.short_conv
+        return (attn_fn, sharded_chunk_kda(act_sharding),
+                L.sharded_short_conv(act_sharding))
 
     # ---------------- sharding ----------------
     def partition_rules(self):

@@ -50,9 +50,6 @@ finite Neumann products: ``ops/pallas/kda.py`` says how, and why 8 rows
 
 from __future__ import annotations
 
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
 
@@ -116,32 +113,10 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
 def sharded_chunk_kda(act_sharding):
     """``chunk_kda`` for a multi-device mesh: per shard of the batch under
     a shard_map, because GSPMD cannot partition the kernels' Mosaic calls
-    (as ``ops.pallas.flash_attention.sharded_flash_attention``, which see).
-    ``act_sharding`` is the layout the model's activations are pinned to,
-    ``[B(batch axes), S, D]``; the batch is split over its batch axes where
-    they divide it, every other axis sees replicated inputs (heads and
-    sequences are independent, so the per-shard result is exact)."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import active_mesh
-    from ..utils.jax_compat import shard_map
-
-    entry = act_sharding.spec[0] if len(act_sharding.spec) else None
-    batch_axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
-
-    def kda(q, k, v, g, beta, **kw):
-        use, free = active_mesh(act_sharding.mesh)
-        b_ax = tuple(a for a in batch_axes
-                     if a in free and use.shape[a] > 1)
-        if q.shape[0] % math.prod(use.shape[a] for a in b_ax):
-            b_ax = ()       # uneven batch: replicate, still exact
-        wide, flat = (P(b_ax or None, *[None] * n) for n in (3, 2))
-        return shard_map(
-            functools.partial(chunk_kda, **kw), mesh=use,
-            axis_names=set(free), in_specs=(wide,) * 4 + (flat,),
-            out_specs=wide, check_vma=False)(q, k, v, g, beta)
-
-    return kda
+    (``parallel.mesh.per_batch_shard``, which see: heads and sequences are
+    independent, so the per-shard result is exact)."""
+    from ..parallel.mesh import per_batch_shard
+    return per_batch_shard(chunk_kda, act_sharding, (True,) * 5)
 
 
 def _chunk_kda(q, k, v, g, beta, *, chunk):

@@ -4,7 +4,9 @@ These are the XLA-fused equivalents of the reference's fused CUDA kernels
 (``csrc/transformer/*_kernels.cu``: gelu/layernorm/softmax/transform). On
 TPU, XLA fuses these elementwise/norm ops into surrounding matmuls; Pallas
 variants (deepspeed_tpu/ops/pallas/) replace the ones XLA can't fuse well
-(flash attention, quantized collectives, fused optimizers).
+(flash attention, quantized collectives, fused optimizers); ``short_conv``
+(the recurrent mixers' convolution, SiLU and l2 norm in one pass) is the
+thin caller of such a pair, as ``ops/ssd.py`` is of its own.
 
 Everything here is shape-static and jit-safe.
 """
@@ -47,16 +49,42 @@ def silu(x):
     return jax.nn.silu(x)
 
 
-def causal_conv(x, w, bias=None):
-    """Causal depthwise convolution along the sequence (the short
-    convolution of KDA and Mamba-2): x [B, S, C], w [n, C], bias [C] or
-    None; y_t = sum_i w[i] x_{t-(n-1)+i} (+ bias), zeros before the
-    start."""
-    n, s = w.shape[0], x.shape[1]
-    with jax.named_scope("ds.conv"):
-        xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-        y = sum(xp[:, i:i + s] * w[i] for i in range(n))
-        return y if bias is None else y + bias
+def short_conv(x, w, bias=None, *, norm_width: int | None = None,
+               norm_scale: float = 1.0):
+    """The short convolution of KDA and Mamba-2 with what follows it
+    elementwise, one pass over a projection's output: x [B, S, C], taps
+    w [n, C], bias [C] or None;
+
+        u_t = sum_i w[i] x_{t-(n-1)+i} (+ bias), zeros before the start
+        y = silu(u), and where ``norm_width`` is given
+        y = y * rsqrt(sum(y^2) + 1e-6) * norm_scale
+
+    over each run of ``norm_width`` channels (a head's l2 norm). Float32
+    inside, rounded once to ``x``'s dtype. A Pallas kernel pair under one
+    ``custom_vjp`` (``ops/pallas/short_conv.py``; scope ``ds.conv``);
+    ``tests/helpers/short_conv_reference.py`` keeps the ``jax.numpy``
+    form the mixers ran until PR 43."""
+    from .pallas.short_conv import short_conv as kernels
+    return kernels(x, w, bias, norm_width=norm_width, norm_scale=norm_scale)
+
+
+def sharded_short_conv(act_sharding):
+    """``short_conv`` for a multi-device mesh: per shard of the batch
+    under a shard_map, because GSPMD cannot partition the kernels' Mosaic
+    calls (``parallel.mesh.per_batch_shard``, which see: channels are
+    independent and ``act_sharding`` never splits the sequence, so the
+    per-shard result is exact; the taps' and the bias's gradients are
+    summed over the shards)."""
+    from ..parallel.mesh import per_batch_shard
+    per_shard = per_batch_shard(short_conv, act_sharding,
+                                (True, False, False))
+
+    def conv(x, w, bias=None, **kw):
+        if bias is None:
+            bias = jnp.zeros(x.shape[2:], w.dtype)
+        return per_shard(x, w, bias, **kw)
+
+    return conv
 
 
 def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,

@@ -46,9 +46,6 @@ reference.
 
 from __future__ import annotations
 
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
 
@@ -98,28 +95,9 @@ def chunk_ssd(x, dt, A, B, C, *, chunk: int = CHUNK):
 def sharded_chunk_ssd(act_sharding):
     """``chunk_ssd`` for a multi-device mesh: per shard of the batch under
     a shard_map, because GSPMD cannot partition the kernels' Mosaic calls
-    (the twin of ``ops.kda.sharded_chunk_kda``, which see). The batch is
-    split over ``act_sharding``'s batch axes where they divide it, every
-    other axis sees replicated inputs (heads and sequences are
-    independent, so the per-shard result is exact)."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import active_mesh
-    from ..utils.jax_compat import shard_map
-
-    entry = act_sharding.spec[0] if len(act_sharding.spec) else None
-    batch_axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
-
-    def ssd(x, dt, A, B, C, **kw):
-        use, free = active_mesh(act_sharding.mesh)
-        b_ax = tuple(a for a in batch_axes
-                     if a in free and use.shape[a] > 1)
-        if x.shape[0] % math.prod(use.shape[a] for a in b_ax):
-            b_ax = ()       # uneven batch: replicate, still exact
-        wide, flat = (P(b_ax or None, *[None] * n) for n in (3, 2))
-        return shard_map(
-            functools.partial(chunk_ssd, **kw), mesh=use,
-            axis_names=set(free), in_specs=(wide, flat, P(), wide, wide),
-            out_specs=wide, check_vma=False)(x, dt, A, B, C)
-
-    return ssd
+    (``parallel.mesh.per_batch_shard``, which see: heads and sequences are
+    independent, so the per-shard result is exact). ``A`` [H] is
+    replicated."""
+    from ..parallel.mesh import per_batch_shard
+    return per_batch_shard(chunk_ssd, act_sharding,
+                           (True, True, False, True, True))

@@ -55,6 +55,41 @@ def active_mesh(mesh):
                       if a not in use.manual_axes)
 
 
+def per_batch_shard(fn, act_sharding: NamedSharding, batched):
+    """``fn`` per shard of the batch under a shard_map, for a function that
+    holds Mosaic kernels (GSPMD cannot partition their calls; as
+    ``ops.pallas.flash_attention.sharded_flash_attention``, which see).
+    ``act_sharding`` is the layout the model's activations are pinned to,
+    ``[B(batch axes), S, D]``; ``batched[i]`` says whether argument ``i``
+    (and the result, like argument 0) leads with the batch. The batch is
+    split over the batch axes where they divide it, every other axis and
+    argument is replicated: exact for a function whose batch rows are
+    independent and whose other dimensions ``act_sharding`` never splits.
+    Keywords reach ``fn`` as they are."""
+    import functools
+
+    from ..utils.jax_compat import shard_map
+
+    entry = act_sharding.spec[0] if len(act_sharding.spec) else None
+    batch_axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+
+    def per_shard(*args, **kw):
+        use, free = active_mesh(act_sharding.mesh)
+        b_ax = tuple(a for a in batch_axes
+                     if a in free and use.shape[a] > 1)
+        if args[0].shape[0] % math.prod(use.shape[a] for a in b_ax):
+            b_ax = ()       # uneven batch: replicate, still exact
+        rows = lambda x: PartitionSpec(  # noqa: E731
+            b_ax or None, *[None] * (x.ndim - 1))
+        return shard_map(
+            functools.partial(fn, **kw), mesh=use, axis_names=set(free),
+            in_specs=tuple(rows(x) if lead else PartitionSpec()
+                           for x, lead in zip(args, batched, strict=True)),
+            out_specs=rows(args[0]), check_vma=False)(*args)
+
+    return per_shard
+
+
 def constrain_free(x: jax.Array, sharding: NamedSharding) -> jax.Array:
     """``with_sharding_constraint(x, sharding)`` that holds wherever the
     model is traced. Axes that are manual in an enclosing region are

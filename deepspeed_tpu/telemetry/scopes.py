@@ -133,13 +133,17 @@ SSM_SCOPES = (
 # projections need no scope: inside the mixer and outside the scan they
 # are what kind "matmul" finds
 MIXER_SCOPES = (
-    "ds.conv",         # ops/layers.py causal_conv: the short convolution
-    #                    (KDA's three, Mamba-2's one)
+    "ds.conv",         # ops/layers.py short_conv: the short convolution
+    #                    with its SiLU and, for KDA's q and k, the head's
+    #                    l2 norm (KDA's three, Mamba-2's one): the kernels
+    #                    ds_short_conv_fwd / ds_short_conv_bwd of
+    #                    ops/pallas/short_conv.py and nothing else (the
+    #                    backward rule opens the scope itself)
     "ds.mix_pre",      # between the input projections and the scan, without
-    #                    the convolution. models/kimi_linear.py _kda: silu,
-    #                    the l2 norms and the scale, beta, the decay's
-    #                    softplus and g; models/granite_hybrid.py _mamba:
-    #                    silu, dt's softplus, the split into x, B, C
+    #                    the convolution's pass. models/kimi_linear.py _kda:
+    #                    beta, the decay's softplus and g;
+    #                    models/granite_hybrid.py _mamba: dt's softplus,
+    #                    the split into x, B, C
     "ds.mix_post",     # between the scan and the output projection. _kda:
     #                    the gate's sigmoid, o_norm, the product; _mamba:
     #                    D x, the silu(z) gate, the gated norm

@@ -38,3 +38,25 @@ def bare_step(engine, batch, ds_config, monkeypatch) -> tuple[str, str]:
                         lambda name: contextlib.nullcontext())
     bare, *_ = ds.initialize(model=engine.module, config=dict(ds_config))
     return named, text(bare)
+
+
+def assert_conv_scope_is_the_kernels(hlo: str, mixer: str, others) -> None:
+    """ISSUE 43: in a compiled step, scope ``ds.conv`` holds the short
+    convolution's two kernels and nothing else (interpreted on a CPU, so
+    every instruction of a kernel's body carries its name): the forward
+    under fwd: and, run again by remat, under bwd:, the backward under
+    bwd:; inside ``mixer`` (``ds.kda`` | ``ds.mamba``) but for the few per
+    cent of a kernel's constants that its one trace a shape names by
+    ``ds.conv`` alone, and never inside one of the ``others``."""
+    conv = [m.group(1) for m in re.finditer(
+        r'op_name="([^"]*ds\.conv[^"]*)"', hlo)]
+    sides = {(k, "bwd" if "transpose(" in name else "fwd")
+             for name in conv
+             for k in re.findall(r"ds_short_conv_(?:fwd|bwd)", name)[:1]
+             if mixer in name}
+    assert all("ds_short_conv_" in name for name in conv)
+    assert sides == {("ds_short_conv_fwd", "fwd"),
+                     ("ds_short_conv_fwd", "bwd"),
+                     ("ds_short_conv_bwd", "bwd")}
+    assert sum(f"/{mixer}/" in name for name in conv) > 0.9 * len(conv)
+    assert not any(o in name for name in conv for o in others)

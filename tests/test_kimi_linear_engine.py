@@ -129,7 +129,9 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
     """ISSUE 36: the convolution and what lies before and after the scan
     are named inside ds.kda, straight under it in both directions and
     never inside the MLA layer; the layer's pre-norm counts with its
-    mixer; and the table of kinds knows every instruction of the step."""
+    mixer; and the table of kinds knows every instruction of the step.
+    ISSUE 43: the convolution is a kernel pair that holds the SiLU and
+    the l2 norms too."""
     engine, batch = kimi_engine
     hlo = engine._train_step.lower(
         engine.state, engine._put_batch(batch)).compile().as_text()
@@ -139,10 +141,19 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
         mine = {p for p in paths if re.search(rf"{re.escape(part)}\b", p)}
         assert {f"{d}:ds.layers/ds.kda/{part}"
                 for d in ("fwd", "bwd")} <= mine, (part, mine)
+        if part == "ds.conv":   # below: the interpreted kernels' constants
+            continue
         assert all("ds.layers/ds.kda/" in p and "ds.mla" not in p
                    for p in mine), (part, mine)
-    # the layer's pre-norm is the one rsqrt straight under ds.kda (the l2
-    # norms are ds.mix_pre's, o_norm is ds.mix_post's)
+    hlo_text.assert_conv_scope_is_the_kernels(hlo, "ds.kda", ("ds.mla",))
+    # q, k and v leave ds.conv as [B, S, H d] for the scan: ds.mix_pre
+    # holds no bf16 op of their [., ., H, d] any more (g is float32)
+    c = engine.module.config
+    heads = rf"= bf16\[\d+,\d+,{c.kda_num_heads},{c.kda_head_dim}\]"
+    assert not [line for line in hlo.splitlines()
+                if re.search(heads, line) and "ds.mix_pre" in line]
+    # the layer's pre-norm is the one rsqrt straight under ds.kda (the
+    # head's l2 norms are the kernels', o_norm is ds.mix_post's)
     norms = {row["scope"] for name, row in work.items()
              if name.startswith("rsqrt")}
     assert {"fwd:ds.layers/ds.kda", "bwd:ds.layers/ds.kda"} <= norms, norms

@@ -582,21 +582,23 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
 # routed layer behind a KDA and an MLA mixer; a Mamba and an attention layer);
 # beside each the sha256 of its lowered train step and the sum of its seeded
 # master weights' magnitudes AT THE PARENT (commit 2d920a0, this file's
-# `_step_text` run on that checkout). `kimi_linear`'s hash is PR 41's: its
+# `_step_text` run on that checkout). `kimi_linear`'s and `granite_hybrid`'s
+# hashes are PR 43's: their mixers' short convolution, SiLU and l2 norms
+# are the kernel pair of `ops/pallas/short_conv.py` since (and Kimi's
 # routed layers sweep their held experts through the grouped-matmul
-# kernels since (`ops/pallas/grouped_matmul.py`), which no `mistral` or
-# `granite_hybrid` step holds; its weights are still the parent's
+# kernels since PR 41), which no `mistral` step holds: `mistral`'s hash is
+# still that parent's, and all three families' weights are
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "a3322f24c9bdc762d05a79b8ae941beb38ca39b95cb9c24354fae5a0467886e1",
+        "36467dd593e4e3e218ab9c85f5a0a3774c4b19c69fc752381bf70f1f4a76b040",
         7191.956369750438),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
         loss_chunk=64),
-        "fe5073033da487c73a03d2b3633742b7e25c250f6a642ace8f2805c2dd5ccf5a",
+        "b940718f5df138fc1d1327ba1f78afa25b97108cc9eebb2d64d64d336ae38512",
         2422.812915172007),
     "mistral": (Mistral, dict(attn_impl="flash", loss_chunk=64,
                               remat_policy="segments", sliding_window=64),

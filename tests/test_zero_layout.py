@@ -404,3 +404,104 @@ def test_grouped_matmul_kernels_compile_for_v5e(monkeypatch):
         for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd"):
             assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), (
                 experts, kernel)
+
+
+def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
+        monkeypatch):
+    """The short convolution's kernel pair (PR 43) at the two cells' widths
+    ([1, 16384, 4096] with the norm of 128 and without; [1, 8192, 4352]
+    with a bias), forward and the rematted gradient, compiled by Mosaic for
+    one described v5e chip: the rolls along the rows, the folded sums of
+    ``dw`` and the products with ones are what interpret mode cannot
+    refuse. Then a whole KDA mixer at the cell's widths and four head
+    groups: q, k and v leave their kernels in the layout the scan's
+    kernels read, so between them lies ONE bf16 copy a tensor each way
+    (the split into head groups, its merge in the backward) and none under
+    ds.mix_pre, where the parent relaid each out in float32 and bf16.
+    Then ``sharded_short_conv`` on ``v5e:2x2`` with the batch over
+    ``fsdp``, where the bare call cannot be partitioned."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.models.kimi_linear import (KimiLinear,
+                                                  kimi_linear_config)
+    from deepspeed_tpu.ops import layers as L
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, bf, sharding=one)
+
+    def grad(fn, n=3, **kw):
+        layer = jax.checkpoint(lambda *a: fn(*a, **kw))
+        return jax.jit(jax.grad(
+            lambda *a: 0.5 * jnp.sum(layer(*a).astype(f32) ** 2),
+            argnums=tuple(range(n))))
+
+    def calls(hlo, kernel):
+        return len(re.findall(rf"%{kernel}[.\w]* = .*custom-call", hlo))
+
+    for s, c, bias, kw in (
+            (16384, 4096, False, dict(norm_width=128, norm_scale=128 ** -0.5)),
+            (16384, 4096, False, {}), (8192, 4352, True, {})):
+        args = (sd(1, s, c), sd(4, c)) + ((sd(c),) if bias else ())
+        hlo = grad(L.short_conv, len(args), **kw).lower(
+            *args).compile().as_text()
+        # the first forward is dead under this loss: remat's rerun is it
+        assert (calls(hlo, "ds_short_conv_fwd"),
+                calls(hlo, "ds_short_conv_bwd")) == (1, 1), (s, c, kw)
+    with pytest.raises(ValueError, match="multiple of 128, not 4160"):
+        jax.jit(L.short_conv).lower(sd(1, 8192, 4160), sd(4, 4160))
+
+    # a KDA mixer of the cell, forward, remat's rerun and backward
+    model = KimiLinear(config=kimi_linear_config(
+        "48b-a3b", kda_head_groups=4, param_dtype=bf))
+    p = jax.eval_shape(lambda k: model._init_layer(k, ("kda", "dense")),
+                       jax.random.PRNGKey(0))["kda"]
+    p = jax.tree.map(lambda x: sd(*x.shape), p)
+    _, kda_fn, conv_fn = model._mixers(None, None)
+
+    def mixer(p, h):
+        with jax.named_scope("ds.kda"):
+            return model._kda(p, h, kda_fn, conv_fn)
+
+    hlo = jax.jit(jax.grad(lambda p, h: jnp.sum(
+        jax.checkpoint(mixer)(p, h).astype(f32) ** 2), argnums=(0, 1))).lower(
+            p, sd(1, 16384, 2304)).compile().as_text()
+    assert (calls(hlo, "ds_short_conv_fwd"),
+            calls(hlo, "ds_short_conv_bwd")) == (6, 3)
+    moved = {"fwd": [], "bwd": [], "mix_pre": []}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*%[\w.\-]+ = (bf16\[[\d,]+\])\S* "
+                     r"(copy|transpose)\(.*op_name=\"([^\"]*)\"", line)
+        if m and m.group(1) == "bf16[1,16384,4,8,128]":
+            path = m.group(3)
+            moved["mix_pre" if "ds.mix_pre" in path else
+                  "bwd" if "transpose(jvp" in path.split(";")[0]
+                  and "rematted" not in path else "fwd"].append(path)
+    # q, k, v split into the head groups: forward and remat's rerun; their
+    # cotangents merged again
+    assert (len(moved["fwd"]), len(moved["bwd"]), moved["mix_pre"]) == (
+        6, 3, []), moved
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    act = mt.sharding(mt.batch_axes(), "sp")
+    rows = NamedSharding(mt.mesh, P(mt.batch_axes(), None, None))
+    args = (jax.ShapeDtypeStruct((4, 2048, 512), bf, sharding=rows),
+            jax.ShapeDtypeStruct((4, 512), bf,
+                                 sharding=NamedSharding(mt.mesh, P())),
+            jax.ShapeDtypeStruct((512,), bf,
+                                 sharding=NamedSharding(mt.mesh, P())))
+    hlo = grad(L.sharded_short_conv(act), norm_width=128).lower(
+        *args).compile().as_text()
+    for kernel in ("ds_short_conv_fwd", "ds_short_conv_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo and "all-gather" not in hlo
+    with pytest.raises(Exception, match="[Mm]osaic"):
+        grad(L.short_conv, norm_width=128).lower(*args).compile()
