@@ -21,9 +21,10 @@ Everything that does not need ``S`` (both score matrices, the inverse
 the PREPARATION: a Pallas kernel pair under one ``custom_vjp``
 (``ops/pallas/kda.py`` ``kda_prepare``) builds it chunk by chunk in VMEM
 from q, k, v, g and beta, which it reads once in the model's layout, and
-writes six operands; its backward rebuilds a chunk's forward from the
-same five inputs, its only residuals. What needs ``S`` is serial in the
-chunks and runs in a second kernel pair under its own ``custom_vjp``
+writes six operands (the inverse's float32 products two heads to a
+product 128 lanes wide, PR 44); its backward rebuilds a chunk's forward
+from the same five inputs, its only residuals. What needs ``S`` is serial
+in the chunks and runs in a second kernel pair under its own ``custom_vjp``
 (``kda_recurrence``): the forward carries ``S`` in VMEM across the chunks
 and writes ``o`` alone; the backward rebuilds the states by segments of
 ``SEG`` chunks from float32 segment checkpoints and carries ``dS`` in
@@ -91,8 +92,9 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
     again, then the recurrence's checkpoint form and the two backward
     kernels; ``ds_kda_fwd`` itself is not run again (its ``o`` is dead in
     the rerun). Without that checkpoint the engine's train step of the
-    Kimi cell peaks at 14.19 GiB where the parent's peaked at 14.00
-    (AOT, PR 35); with it at 13.50."""
+    Kimi cell peaks at 13.19 GiB, with it at 12.62 (AOT for one v5e chip,
+    PR 44; 14.19 and 13.50 in PR 35, before the short convolution's
+    kernels)."""
     h = q.shape[2]
     if h % head_groups:
         raise ValueError(f"chunk_kda: {h} heads in {head_groups} groups")

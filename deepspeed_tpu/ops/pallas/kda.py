@@ -17,12 +17,22 @@ beta e^(G_C - G)`` and ``shrink = e^(G_C)``.
   operands are in the inputs' dtype with float32 accumulation; ``G``, the
   decays and the inverse are float32 (products at ``HIGHEST``: six bf16
   passes). The inverse is ten dependent [C, C] products, each waiting on
-  the one before: the heads of a grid step take them IN STEP
-  (``_inverse_unit_lower``), which is what fills the MXU.
+  the one before. Two heads take a product TOGETHER (``_pdot``: ``[x1 |
+  x2]`` [C, 2 C] against ``y1``, ``y2`` on the diagonal of a [2 C, 2 C]
+  operand, a latch that fills the MXU's width and depth and half the rows
+  streamed; the zero blocks add exact zeros, so a head's numbers are what
+  they are alone), and the pairs of a grid step take them IN STEP
+  (``_inverse_unit_lower``), which is what fills the wait. Pairing is read
+  from the shape: at least two heads a grid step and ``2 C <= 128``; a
+  lone head goes through the same functions as a list of one [C, C].
 - **Backward** (``ds_kda_prep_bwd``): the same grid and blocks, plus the
   six cotangents ``ds_kda_bwd`` makes; rebuilds the chunk's forward, then
   ``dT = du_v V^T + dw (K e^G)^T``, ``da_kk = -T^T dT T^T`` kept strictly
-  lower, the score blocks' cotangents through the same factoring (a
+  lower (its two float32 products by pairs too: ``[T1^T | T2^T]``, the pair
+  transposed and its heads put side by side again, takes ``dT T^T`` from
+  the right, each factor on the side it has alone, so the sums are the
+  lone product's to the last bit), the score blocks' cotangents through
+  the same factoring (a
   clamped factor passes no gradient), the decay products, and the running
   sum's transpose; writes dq, dk, dv, dg in the model's layout and dbeta.
 
@@ -83,11 +93,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ssd import _bind
+
 CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
 SUB = 8         # rows a sub-block of the score matrices
 CLAMP = 60.0    # largest exponent a sub-block's own columns may carry
 NCK = 8         # chunks a grid step of the preparation
-PREP_HEADS = 4  # heads a grid step of the preparation: their inverses in step
+PREP_HEADS = 8  # heads a grid step of the preparation: four pairs in step
 SEG = 16        # chunks a segment: a grid step of the recurrence, and a
 #                 checkpoint's spacing
 HEADS = 4       # heads a grid step of the recurrence: independent chains
@@ -349,30 +361,87 @@ def _half_at_tie(x):
     return jnp.where(x < 0, 1.0, jnp.where(x == 0, 0.5, 0.0))
 
 
+def _side_by_side(mats):
+    """The list's [C, C] arrays two to a [C, 2 C] array ``[x1 | x2]``
+    while that fits a vreg's 128 lanes; an odd one out stays alone."""
+    c = mats[0].shape[0]
+    paired = len(mats) // 2 * 2 if 2 * c <= _LANES else 0
+    return [jnp.concatenate(mats[i:i + 2], axis=1)
+            for i in range(0, paired, 2)] + list(mats[paired:])
+
+
+def _apart(xs):
+    """The [C, C] arrays of a list of ``[x1 | x2 ...]``."""
+    return [x[:, i:i + x.shape[0]]
+            for x in xs for i in range(0, x.shape[1], x.shape[0])]
+
+
+def _transposed(x):
+    """``[x1^T | x2^T]`` of ``[x1 | x2]``: transposed, the heads lie one
+    over the other; side by side again."""
+    c = x.shape[0]
+    return jnp.concatenate(
+        [x.T[i:i + c] for i in range(0, x.shape[1], c)], axis=1)
+
+
+def _unit_masks(a):
+    """(I, the mask of the SUB x SUB blocks on the diagonal) in the shape
+    of ``a`` [C, C] or ``[a1 | a2]``: a column counts within its head."""
+    c, w = a.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, w), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, w), 1)
+    cols = jnp.where(cols >= c, cols - c, cols)
+    shift = SUB.bit_length() - 1
+    return (jnp.where(rows == cols, 1.0, 0.0).astype(jnp.float32),
+            jax.lax.shift_right_logical(rows, shift)
+            == jax.lax.shift_right_logical(cols, shift))
+
+
+def _pdot(x, y, *dims):
+    """``[x1 y1 | x2 y2]`` of ``[x1 | x2]`` and ``[y1 | y2]`` [C, 2 C] as
+    ONE float32 product against ``y1`` and ``y2`` on the diagonal of a
+    [2 C, 2 C] operand: a latch that fills the MXU's 128 x 128 where two
+    [C, C] latches fill a quarter each, half the rows streamed. The zero
+    blocks add exact zeros to a float32 sum, so a head's numbers are those
+    of the product taken alone, which is what a lone [C, C] gets."""
+    c, w = y.shape
+    if w > c:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
+        y = jnp.where((rows >= c) == (cols >= c),
+                      jnp.concatenate([y, y], axis=0), 0.0)
+    return _hdot(x, y, *dims)
+
+
 def _neumann(xs, eye, order: int):
     """(I + x)^-1 = (I - x)(I + x^2)(I + x^4)... for ``x^order = 0``, of
-    each x of the list, in step."""
-    invs = [eye - x for x in xs]
+    each x of the list (a head, or two side by side), in step."""
+    invs = [e - x for e, x in zip(eye, xs)]
     while order > 2:
-        xs = [_hdot(x, x) for x in xs]
-        invs = [inv + _hdot(inv, x) for inv, x in zip(invs, xs)]
+        xs = [_pdot(x, x) for x in xs]
+        invs = [inv + _pdot(inv, x) for inv, x in zip(invs, xs)]
         order //= 2
     return invs
 
 
-def _inverse_unit_lower(mats, eye, same_block):
+def _inverse_unit_lower(mats):
     """(I + a)^-1 for each strictly lower triangular ``a`` [C, C] of the
     list, float32, exact, in two finite Neumann products: with d the SUB x
     SUB blocks on the diagonal and low the rest, I + a = (I + d)(I + (I +
     d)^-1 low); d^SUB = 0 and the second factor's strictly block-lower
-    part is nilpotent of order C / SUB. A product waits a few hundred
-    cycles for the one before it, so the chunks of a list advance IN STEP:
-    each step's products are independent and fill the wait."""
-    ds = [jnp.where(same_block, a, 0.0) for a in mats]
+    part is nilpotent of order C / SUB. Ten dependent products. The heads
+    take them two to a product (``_side_by_side``, ``_pdot``: half the
+    passes through the MXU), and the result comes back so, ``[T1 | T2]``.
+    A product waits a few hundred cycles for the one before it, so the
+    pairs of a list advance IN STEP: each step's products are independent
+    and fill the wait."""
+    mats = _side_by_side(mats)
+    eye, same_block = zip(*(_unit_masks(a) for a in mats))
+    ds = [jnp.where(same, a, 0.0) for same, a in zip(same_block, mats)]
     ts = _neumann(ds, eye, SUB)
-    ms = [_hdot(t, a - d) for t, a, d in zip(ts, mats, ds)]
-    return [_hdot(n, t) for n, t in zip(
-        _neumann(ms, eye, eye.shape[0] // SUB), ts)]
+    ms = [_pdot(t, a - d) for t, a, d in zip(ts, mats, ds)]
+    return [_pdot(n, t) for n, t in zip(
+        _neumann(ms, eye, mats[0].shape[0] // SUB), ts)]
 
 
 class _Chunk:
@@ -397,10 +466,6 @@ class _Chunk:
         rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
         self.low, self.diag = rows > cols, rows == cols
-        self.eye = jnp.where(self.diag, 1.0, 0.0).astype(f32)
-        shift = SUB.bit_length() - 1
-        self.same_block = (jax.lax.shift_right_logical(rows, shift)
-                           == jax.lax.shift_right_logical(cols, shift))
         # the running sum of g within the chunk, exact in six passes (a
         # one is one bf16 piece, g three)
         self.upto = jnp.where(rows >= cols, 1.0, 0.0).astype(f32)
@@ -440,11 +505,13 @@ class _Chunk:
 
     @staticmethod
     def invert(chunks):
-        """T = (I + a_kk)^-1 of each chunk of the list."""
-        one = chunks[0]
-        for chunk, t in zip(chunks, _inverse_unit_lower(
-                [x.a_kk for x in chunks], one.eye, one.same_block)):
-            chunk.t, chunk.tb = t, t.astype(chunk.dt)
+        """T = (I + a_kk)^-1 of each chunk of the list: side by side as
+        ``_inverse_unit_lower`` returns them (the backward's float32
+        products take them so), and apart in the matmuls' dtype (``tb``)."""
+        ts = _inverse_unit_lower([x.a_kk for x in chunks])
+        for chunk, t in zip(chunks, _apart(ts)):
+            chunk.tb = t.astype(chunk.dt)
+        return ts
 
     def operands(self):
         """u_v, w, q_in, a_qk, k_out, shrink as ``kda_recurrence`` takes
@@ -457,21 +524,25 @@ class _Chunk:
                 self.shrink)
 
     @staticmethod
-    def gradients(chunks, cts):
+    def gradients(chunks, ts, cts):
         """(dq, dk, dv, dg [C, .], dbeta [1, C]), all float32, of each
-        chunk of the list from its six cotangents (du_v, dw, dq_in, da_qk,
-        dk_out, dshrink); the float32 products in step, as ``invert``."""
+        chunk of the list from ``invert``'s ``ts`` and its six cotangents
+        (du_v, dw, dq_in, da_qk, dk_out, dshrink); the float32 products
+        two heads to a product and in step, as the inverse's."""
         dt = chunks[0].dt
         # u_v = T V, w = T (K e^G)
         duws = [jnp.concatenate([ct[0].astype(dt), ct[1].astype(dt)], axis=1)
                 for ct in cts]
-        d_ts = [_dot(duw, jnp.concatenate([x.v, x.kd], axis=1), _NT)
-                for x, duw in zip(chunks, duws)]
+        d_ts = _side_by_side(
+            [_dot(duw, jnp.concatenate([x.v, x.kd], axis=1), _NT)
+             for x, duw in zip(chunks, duws)])
         dvks = [_dot(x.tb, duw, _TN) for x, duw in zip(chunks, duws)]
-        # T = (I + a_kk)^-1: da_kk = -T^T dT T^T, strictly lower
-        d_as = [_hdot(d_t, x.t, _NT) for x, d_t in zip(chunks, d_ts)]
-        d_as = [jnp.where(x.low, -_hdot(x.t, d_a, _TN), 0.0)
-                for x, d_a in zip(chunks, d_as)]
+        # T = (I + a_kk)^-1: da_kk = -T^T (dT T^T), strictly lower; a pair
+        # takes its own head's factor from the right
+        d_as = [_pdot(d_t, t, _NT) for t, d_t in zip(ts, d_ts)]
+        d_as = [_pdot(_transposed(t), d_a) for t, d_a in zip(ts, d_as)]
+        d_as = [jnp.where(x.low, -d_a, 0.0)
+                for x, d_a in zip(chunks, _apart(d_as))]
         parts = [x._scores_and_decays(d_a, dvk, *ct[2:])
                  for x, d_a, dvk, ct in zip(chunks, d_as, dvks, cts)]
         # the running sum's transpose: a reversed sum within the chunk
@@ -542,15 +613,14 @@ class _Chunk:
 
 def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv):
     """(the rows of chunk ``i`` in the inputs' blocks, that chunk of each
-    head of the grid step, inverted)."""
+    head of the grid step, their inverses side by side)."""
     rows = pl.ds(pl.multiple_of(i * c, c), c)
     chunks = [_Chunk(q_ref[0, rows, h * dk:(h + 1) * dk],
                      k_ref[0, rows, h * dk:(h + 1) * dk],
                      v_ref[0, rows, h * dv:(h + 1) * dv],
                      g_ref[0, rows, h * dk:(h + 1) * dk].astype(jnp.float32),
                      b_ref[h, i].astype(jnp.float32)) for h in range(heads)]
-    _Chunk.invert(chunks)
-    return rows, chunks
+    return rows, chunks, _Chunk.invert(chunks)
 
 
 def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
@@ -560,8 +630,8 @@ def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
     nck C, heads d] of the model's [B, S, H d], beta's [heads, nck, 1,
     C]; the operands' [heads, nck, C, .]."""
     def chunk(i, carry):
-        _, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c,
-                            dk, dv)
+        _, chunks, _ = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads,
+                               c, dk, dv)
         for h, chunk in enumerate(chunks):
             for ref, x in zip((uv_ref, w_ref, qi_ref, a_ref, ko_ref,
                                sh_ref), chunk.operands()):
@@ -576,12 +646,12 @@ def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
                      dv_ref, dg_ref, db_ref, *, heads, nck, c, dk, dv):
     """The same blocks; rebuilds each chunk's forward, then its backward."""
     def chunk(i, carry):
-        rows, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads,
-                               c, dk, dv)
+        rows, chunks, ts = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i,
+                                   heads, c, dk, dv)
         cts = [tuple(ref[h, i] for ref in (duv_ref, dw_ref, dqi_ref, da_ref,
                                            dko_ref, dsh_ref))
                for h in range(heads)]
-        for h, grads in enumerate(_Chunk.gradients(chunks, cts)):
+        for h, grads in enumerate(_Chunk.gradients(chunks, ts, cts)):
             for ref, x, d in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads,
                                  (dk, dk, dv, dk)):
                 ref[0, rows, h * d:(h + 1) * d] = x.astype(ref.dtype)
@@ -644,8 +714,8 @@ def _prepare_forward(q, k, v, g, beta, chunk):
         interpret=_interpret(),
         name="ds_kda_prep_fwd",
     )
-    with jax.named_scope("ds.kda_prep_fwd"):
-        u_v, w, q_in, a_qk, k_out, shrink = call(*args)
+    u_v, w, q_in, a_qk, k_out, shrink = _bind(
+        call, "ds.kda_prep_fwd", ("kda_prep_fwd", chunk, nck, heads), *args)
     return u_v, w, q_in, a_qk, k_out, shrink.reshape(b * h, n, dk)
 
 
@@ -667,8 +737,9 @@ def _prepare_backward(q, k, v, g, beta, cts, chunk):
         interpret=_interpret(),
         name="ds_kda_prep_bwd",
     )
-    with jax.named_scope("ds.kda_prep_bwd"):
-        dq, dk_, dv_, dg, dbeta = call(*args, *cts)
+    dq, dk_, dv_, dg, dbeta = _bind(
+        call, "ds.kda_prep_bwd", ("kda_prep_bwd", chunk, nck, heads), *args,
+        *cts)
     dbeta = jnp.moveaxis(dbeta.reshape(b, h, n * chunk), 1, 2)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
             dg.reshape(g.shape), dbeta)
@@ -676,7 +747,10 @@ def _prepare_backward(q, k, v, g, beta, cts, chunk):
 
 def _prep_cost(chunks, c, dk, dv, ins, outs, *, backward: bool):
     """Matmul passes as bf16 FLOPs (a float32 product is six), the
-    exponentials, and every operand's one trip."""
+    exponentials, and every operand's one trip. The FLOPs are the
+    mathematics': a [C, C] product counts ``2 C^3`` a pass whether it runs
+    alone or beside another head's, where the pair takes half the passes
+    through the MXU (and multiplies as many zeros)."""
     square = 2 * c * c * c
     flops = (6 * 10 * square + 2 * c * c * dk       # the inverse; G
              + 2 * 2 * c * c * dk                   # the score blocks

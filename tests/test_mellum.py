@@ -582,18 +582,20 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
 # routed layer behind a KDA and an MLA mixer; a Mamba and an attention layer);
 # beside each the sha256 of its lowered train step and the sum of its seeded
 # master weights' magnitudes AT THE PARENT (commit 2d920a0, this file's
-# `_step_text` run on that checkout). `kimi_linear`'s and `granite_hybrid`'s
-# hashes are PR 43's: their mixers' short convolution, SiLU and l2 norms
-# are the kernel pair of `ops/pallas/short_conv.py` since (and Kimi's
-# routed layers sweep their held experts through the grouped-matmul
-# kernels since PR 41), which no `mistral` step holds: `mistral`'s hash is
-# still that parent's, and all three families' weights are
+# `_step_text` run on that checkout). `granite_hybrid`'s hash is PR 43's:
+# the mixers' short convolution, SiLU and l2 norms are the kernel pair of
+# `ops/pallas/short_conv.py` since. `kimi_linear`'s is PR 44's: its KDA
+# preparation takes the inverse's float32 products two heads to a product
+# (`ops/pallas/kda.py` `_pdot`; its routed layers sweep their held experts
+# through the grouped-matmul kernels since PR 41). No `mistral` step holds
+# either: `mistral`'s hash is still that parent's, and all three families'
+# weights are
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "36467dd593e4e3e218ab9c85f5a0a3774c4b19c69fc752381bf70f1f4a76b040",
+        "8a8e03b8d412e8bc25fc8d4ae9b4f228d011094564c5c0c1efc8417495c5cc4c",
         7191.956369750438),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",

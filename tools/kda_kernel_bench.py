@@ -14,7 +14,11 @@ head count:
 - ``prep_ms``: the preparation's forward and backward kernel the same way
   (``fwd``, ``bwd``) and the device busy time of the call they sit in
   (``fwd_busy``, ``bwd_busy``: with XLA's copies of the inputs into the
-  [B, S, H d] tiles the kernels read); ``jnp_fwd`` / ``jnp_fwd_bwd``: the
+  [B, S, H d] tiles the kernels read); ``fwd_no_inverse``: the forward
+  kernel with ``_inverse_unit_lower`` left out (``T = a_kk``: wrong
+  operands, the same blocks and stores), so ``inverse`` = ``fwd`` less it
+  is the inverse's own time, the heads' placement side by side and their
+  taking apart in it (PR 44); ``jnp_fwd`` / ``jnp_fwd_bwd``: the
   ``jax.numpy`` preparation they replaced (``tests/helpers/
   kda_reference.py``) and its autodiff, device busy time a call;
 - ``scan_ms``: the recurrence in PR 31's form (``scan_recurrence`` below,
@@ -150,7 +154,7 @@ def main(argv) -> int:
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kda
-    from deepspeed_tpu.ops.pallas import kda as kernels
+    from deepspeed_tpu.ops.pallas import kda as kernels, ssd
     from helpers import kda_reference
     bf = jnp.bfloat16
     kernel_prepare = kda.kda_prepare
@@ -180,7 +184,17 @@ def main(argv) -> int:
                 *jax.vjp(lambda *x: f(*x, out_dtype=bf), *a[:6])))
         scan_fwd = jax.jit(lambda *o: scan_recurrence(*o, out_dtype=bf))
         ev_pf = traced(jax, prep_fwd, args)
+        prep_fwd_ms = kernel_ms(ev_pf)
         ev_pb = traced(jax, prep_bwd, (*args, *cts))
+        # a new jit and no kept trace (ssd._bind keeps one a shape): the
+        # kernel is traced again, without its inverse
+        inverse = kernels._inverse_unit_lower
+        kernels._inverse_unit_lower = lambda mats: mats
+        ssd._TRACED.clear()
+        no_inverse = kernel_ms(traced(jax, jax.jit(
+            lambda *a: kernels._prepare_forward(*a, CHUNK)), args))
+        kernels._inverse_unit_lower = inverse
+        ssd._TRACED.clear()
         line = {"heads": heads, "seg": kernels.SEG,
                 "heads_a_step": kernels.HEADS,
                 "prep_chunks_a_step": kernels.NCK,
@@ -191,7 +205,9 @@ def main(argv) -> int:
                     "bwd": kernel_ms(traced(
                         jax, bwd, (*flat, ck, do.reshape(flat[0].shape))))},
                 "prep_ms": {
-                    "fwd": kernel_ms(ev_pf), "fwd_busy": busy_ms(ev_pf),
+                    "fwd": prep_fwd_ms, "fwd_busy": busy_ms(ev_pf),
+                    "fwd_no_inverse": no_inverse,
+                    "inverse": prep_fwd_ms - no_inverse,
                     "bwd": kernel_ms(ev_pb), "bwd_busy": busy_ms(ev_pb),
                     "jnp_fwd": busy_ms(traced(jax, ref_fwd, args)),
                     "jnp_fwd_bwd": busy_ms(traced(
