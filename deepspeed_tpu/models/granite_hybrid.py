@@ -34,15 +34,71 @@ state has no place in ``inference/`` yet).
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
-from .base import ModelConfig, register_model
-from .stack import StackOfKinds
+from .base import mean_context, register_model
+from .stack import StackConfig, StackOfKinds
 from .transformer import _dense_init
+
+
+@dataclasses.dataclass
+class GraniteHybridConfig(StackConfig):
+    # key names as published
+    layer_types: tuple | list = ()  # "mamba" | "attention" a layer
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1         # groups of heads sharing B and C
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_expand: int = 2           # n_heads * d_head = expand * hidden_size
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # the four muP multipliers: 1 (None) where a model has none
+    embedding_multiplier: float = 1.0   # x0 = embed[tokens] * this
+    residual_multiplier: float = 1.0    # x + this * sublayer(norm(x))
+    logits_scaling: float = 1.0         # logits / this
+    attention_multiplier: float | None = None   # the softmax scale; None =
+    #                                             head_dim ** -0.5
+
+    def __post_init__(self):
+        super().__post_init__()
+        self.layer_types = list(self.layer_types)   # as JSON has it
+
+    def layer_kinds(self) -> list[str]:
+        return list(self.layer_types)
+
+    def _layer_params(self, kind) -> int:
+        """As ``GraniteHybrid._init_layer`` builds a layer: the mixer, a
+        SwiGLU and two norms."""
+        d, h = self.hidden_size, self.mamba_n_heads
+        if kind == "mamba":
+            inner = h * self.mamba_d_head
+            conv = inner + 2 * self.mamba_n_groups * self.mamba_d_state
+            mixer = (d * (inner + conv + h)          # z | xBC | dt
+                     + (self.mamba_d_conv + self.mamba_conv_bias) * conv
+                     + 3 * h + inner + inner * d)    # A, D, dt_bias, norm
+        else:
+            mixer = 2 * d * self.head_dim * (self.num_heads
+                                             + self.num_kv_heads)
+        return mixer + 3 * d * self.intermediate_size + 2 * d
+
+    def _layer_mixer_flops(self, kind, seq_len, causal) -> float:
+        """An attention layer multiplies a key and a value of head_dim a
+        visible pair; a Mamba head writes and reads its [P, N] state once
+        a token (2 products of 2 P N FLOPs); x3 training."""
+        if kind == "mamba":
+            return 12 * self.mamba_n_heads * self.mamba_d_head \
+                * self.mamba_d_state
+        return 12 * self.num_heads * self.head_dim * mean_context(
+            seq_len, causal)
+
 
 _PERIOD = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
 _PUBLISHED = dict(
@@ -56,7 +112,7 @@ _PUBLISHED = dict(
 
 
 def granite_hybrid_config(size: str = "4.0-h-micro",
-                          **overrides) -> ModelConfig:
+                          **overrides) -> GraniteHybridConfig:
     presets = {
         "tiny": dict(hidden_size=64, intermediate_size=128, num_heads=4,
                      num_kv_heads=2, num_layers=5, vocab_size=512,
@@ -75,12 +131,12 @@ def granite_hybrid_config(size: str = "4.0-h-micro",
                 tie_embeddings=True, norm_eps=1e-5)
     base.update(presets[size])
     base.update(overrides)
-    return ModelConfig(**base)
+    return GraniteHybridConfig(**base)
 
 
 @register_model("granite_hybrid")
 class GraniteHybrid(StackOfKinds):
-    def __init__(self, config: ModelConfig | None = None,
+    def __init__(self, config: GraniteHybridConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
             raise ValueError(
@@ -101,7 +157,7 @@ class GraniteHybrid(StackOfKinds):
             raise NotImplementedError(
                 "GraniteHybrid has no projection bias, no experts and a "
                 "tied head")
-        super().__init__(c, list(c.layer_types))
+        super().__init__(c)
 
     # ---------------- init ----------------
     def _init_layer(self, key, kind, lead_shape=()):

@@ -43,15 +43,118 @@ read from the keys it holds.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
-from .base import ModelConfig, register_model
-from .stack import RoutedStackOfKinds
+from .base import mean_context, register_model
+from .stack import RoutedStackConfig, RoutedStackOfKinds
 from .transformer import _dense_init
+
+
+@dataclasses.dataclass
+class KimiLinearConfig(RoutedStackConfig):
+    # 1-based layer numbers as the published config gives them
+    kda_layers: tuple = ()          # KDA linear attention (ops/kda.py)
+    full_attn_layers: tuple = ()    # latent attention (MLA)
+    first_k_dense_replace: int = 0  # leading layers whose FFN is dense
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 128        # width of the low-rank decay / output
+    #                                 gate maps (the head width)
+    kda_head_groups: int = 1        # run the KDA heads in this many groups,
+    #                                 one after the other (ops/kda.py): the
+    #                                 chunked form's operands live a group
+    #                                 at a time
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_use_nope: bool = False      # no rotation on either part of q, k
+
+    def __post_init__(self):
+        super().__post_init__()
+        self.kda_layers = tuple(self.kda_layers)
+        self.full_attn_layers = tuple(self.full_attn_layers)
+
+    @property
+    def linear_attn_config(self) -> dict:
+        """The published ``linear_attn_config`` group of the model as
+        built (lists, as JSON has them)."""
+        return {"full_attn_layers": list(self.full_attn_layers),
+                "head_dim": self.kda_head_dim,
+                "kda_layers": list(self.kda_layers),
+                "num_heads": self.kda_num_heads,
+                "short_conv_kernel_size": self.kda_conv_size}
+
+    def layer_kinds(self) -> list[tuple[str, str]]:
+        """(token mixer, channel mixer) of each layer, ``kda`` | ``mla``
+        and ``dense`` | ``moe``."""
+        kinds = []
+        for n in range(1, self.num_layers + 1):
+            if (n in self.kda_layers) == (n in self.full_attn_layers):
+                raise ValueError(
+                    f"layer {n} is in both or neither of kda_layers "
+                    f"{self.kda_layers} and full_attn_layers "
+                    f"{self.full_attn_layers}")
+            kinds.append(("kda" if n in self.kda_layers else "mla",
+                          "dense" if n <= self.first_k_dense_replace
+                          or self.num_experts <= 0 else "moe"))
+        return kinds
+
+    def lead_layers(self) -> int:
+        return self.first_k_dense_replace
+
+    def _kind_params(self) -> dict:
+        """Parameters of each kind of mixer, as ``KimiLinear._init_layer``
+        builds them; ``expert`` is ONE routed expert, ``moe`` everything
+        of a routed layer but its routed experts."""
+        d = self.hidden_size
+        h, dk, r = self.kda_num_heads, self.kda_head_dim, self.kda_gate_rank
+        inner = h * dk
+        kda = (3 * d * inner + 3 * self.kda_conv_size * inner   # q, k, v
+               + d * r + r * inner + inner + h                   # decay
+               + d * h                                           # beta
+               + d * r + r * inner + inner                       # out gate
+               + dk + inner * d)                                 # norm, wo
+        nh = self.num_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        mla = (d * nh * qk + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+               + self.kv_lora_rank
+               + self.kv_lora_rank * nh * (self.qk_nope_head_dim
+                                           + self.v_head_dim)
+               + nh * self.v_head_dim * d)
+        expert = self._expert_params()
+        return {"kda": kda, "mla": mla,
+                "dense": 3 * d * self.intermediate_size,
+                "expert": expert,
+                "moe": (d * self.num_experts + self.num_experts
+                        + expert * self.moe_num_shared_experts)}
+
+    def _layer_params(self, kind) -> int:
+        per = self._kind_params()
+        mixer, channel = kind
+        return (per[mixer] + 2 * self.hidden_size + per[channel]
+                + (self._held_params() if channel == "moe" else 0))
+
+    def _layer_idle_params(self, kind) -> float:
+        return self._idle_held_params() if kind[1] == "moe" else 0
+
+    def _layer_mixer_flops(self, kind, seq_len, causal) -> float:
+        """Latent attention multiplies a key of qk width and a value of v
+        width a visible pair (2 matmuls, x3 for training); a KDA head
+        reads, corrects and writes its [dk, dv] state once a token (3
+        products of 2 dk dv FLOPs, x3 for training)."""
+        if kind[0] == "kda":
+            return 18 * self.kda_num_heads * self.kda_head_dim ** 2
+        return 6 * self.num_heads * mean_context(seq_len, causal) * (
+            self.qk_nope_head_dim + self.qk_rope_head_dim + self.v_head_dim)
+
 
 _PUBLISHED = dict(
     hidden_size=2304, intermediate_size=9216, num_heads=32, num_kv_heads=32,
@@ -65,7 +168,8 @@ _PUBLISHED = dict(
     routed_scaling_factor=2.446)
 
 
-def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
+def kimi_linear_config(size: str = "48b-a3b",
+                       **overrides) -> KimiLinearConfig:
     presets = {
         "tiny": dict(hidden_size=64, intermediate_size=128, num_heads=4,
                      num_kv_heads=4, num_layers=5, vocab_size=512,
@@ -87,20 +191,17 @@ def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
                 router_aux_loss_coef=0.0)
     base.update(presets[size])
     base.update(overrides)
-    return ModelConfig(**base)
+    return KimiLinearConfig(**base)
 
 
 @register_model("kimi_linear")
 class KimiLinear(RoutedStackOfKinds):
-    def __init__(self, config: ModelConfig | None = None,
+    def __init__(self, config: KimiLinearConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
             raise ValueError(
                 "pass either an explicit config or size/overrides, not both")
         config = config or kimi_linear_config(size or "48b-a3b", **overrides)
-        kinds = config.layer_kinds()
-        if kinds is None:
-            raise ValueError("KimiLinear needs kda_layers / full_attn_layers")
         if not config.mla_use_nope:
             raise NotImplementedError(
                 "KimiLinear's latent attention is NoPE (mla_use_nope)")
@@ -112,7 +213,7 @@ class KimiLinear(RoutedStackOfKinds):
             raise ValueError(
                 f"{config.held_experts} experts held of the router's "
                 f"{config.num_experts}")
-        super().__init__(config, kinds, lead=config.first_k_dense_replace)
+        super().__init__(config)
 
     def optimizer_frozen(self) -> str:
         """Leaves the optimizer leaves alone (the engine zeroes their

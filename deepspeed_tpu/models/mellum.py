@@ -41,6 +41,7 @@ pipeline are not here (``StackOfKinds._one_kind_only``).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -48,8 +49,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
-from .base import ModelConfig, register_model
-from .stack import RoutedStackOfKinds
+from .base import mean_context, register_model
+from .stack import RoutedStackConfig, RoutedStackOfKinds
 from .transformer import _dense_init
 
 _KINDS = {"sliding_attention": "swa", "full_attention": "full"}
@@ -68,7 +69,44 @@ _PUBLISHED = dict(
     num_experts=64, moe_top_k=8, moe_intermediate_size=896)
 
 
-def mellum_config(size: str = "12b-a2.5b", **overrides) -> ModelConfig:
+@dataclasses.dataclass
+class MellumConfig(RoutedStackConfig):
+    # key names as published
+    layer_types: tuple | list = ()  # "sliding_attention" (sliding_window
+    #                                 holds for this kind alone) |
+    #                                 "full_attention", a layer
+    rope_parameters: dict = dataclasses.field(default_factory=dict)
+    #                                 a rotary table a kind of layer_types:
+    #                                 {kind: {rope_type, rope_theta, ...}}
+    #                                 (ops/layers.py rotary_embedding)
+
+    def __post_init__(self):
+        super().__post_init__()
+        self.layer_types = list(self.layer_types)   # as JSON has it
+
+    def layer_kinds(self) -> list[str]:
+        return list(self.layer_types)
+
+    def _layer_params(self, kind) -> int:
+        """As ``Mellum._init_layer`` builds a layer of either kind:
+        grouped-query attention at ``head_dim``, two norms, the router
+        over ``num_experts`` and the experts held here."""
+        d = self.hidden_size
+        return (2 * d * self.head_dim * (self.num_heads + self.num_kv_heads)
+                + 2 * d + d * self.num_experts + self._held_params())
+
+    def _layer_idle_params(self, kind) -> float:
+        return self._idle_held_params()
+
+    def _layer_mixer_flops(self, kind, seq_len, causal) -> float:
+        """A visible pair multiplies a key and a value of head_dim a head
+        (x3 training); the window bounds the first kind alone."""
+        window = self.sliding_window if kind == "sliding_attention" else None
+        return 12 * self.num_heads * self.head_dim * mean_context(
+            seq_len, causal, window)
+
+
+def mellum_config(size: str = "12b-a2.5b", **overrides) -> MellumConfig:
     presets = {
         # a head of 32 on a hidden size of 64 (not 64 / 4), a window a
         # quarter of the sequence, YaRN at the published factor over an
@@ -91,20 +129,21 @@ def mellum_config(size: str = "12b-a2.5b", **overrides) -> ModelConfig:
         "12b-a2.5b": _PUBLISHED,
     }
     base = dict(norm_type="rmsnorm", activation="swiglu",
-                # a table a kind, built here: DecoderLM builds its one
-                # table for "rope" alone
+                # a table a kind, built here by rope_parameters: DecoderLM
+                # builds its one table for "rope" alone and adds no
+                # positions for a name it does not know
                 position_embedding="rope_by_kind", use_bias=False,
                 tie_embeddings=False, norm_eps=1e-6,
                 moe_router_activation="softmax", moe_norm_topk=True,
                 router_aux_loss_coef=0.0)
     base.update(presets[size])
     base.update(overrides)
-    return ModelConfig(**base)
+    return MellumConfig(**base)
 
 
 @register_model("mellum")
 class Mellum(RoutedStackOfKinds):
-    def __init__(self, config: ModelConfig | None = None,
+    def __init__(self, config: MellumConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
             raise ValueError(
@@ -131,7 +170,7 @@ class Mellum(RoutedStackOfKinds):
             raise ValueError(
                 f"{c.held_experts} experts held of the router's "
                 f"{c.num_experts}")
-        super().__init__(c, list(c.layer_types))
+        super().__init__(c)
         self._ropes = {
             _KINDS[t]: L.rotary_embedding(c.max_seq_len, c.head_dim,
                                           c.rope_theta,

@@ -48,6 +48,7 @@ refuse, and with them every engine that runs a model a layer at a time
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -61,7 +62,40 @@ from .transformer import (DecoderLM, _chunk_logits, _dense_init,
                           _remat_policy, _unpack_batch, _valid_count)
 
 
-def ouro_config(size: str = "2.6b", **overrides) -> ModelConfig:
+@dataclasses.dataclass
+class OuroConfig(ModelConfig):
+    # the num_layers layers run total_ut_steps times a forward pass on the
+    # SAME weights, the final norm after every pass, and every pass is an
+    # exit
+    total_ut_steps: int = 1
+    sandwich_norm: bool = False     # a second norm on each sublayer's
+    #                                 OUTPUT, before the residual add
+    exit_gate: bool = False         # a d -> 1 gate on every exit's state:
+    #                                 the loss is the expected loss under
+    #                                 the exit distribution the gates give
+    exit_entropy_beta: float = 0.0  # ... less this x that distribution's
+    #                                 entropy, a position
+
+    def num_params(self) -> int:
+        """``DecoderLM``'s, the two output norms a layer and the gate."""
+        d = self.hidden_size
+        return (super().num_params()
+                + (2 * d * self.num_layers if self.sandwich_norm else 0)
+                + (d + 1 if self.exit_gate else 0))
+
+    def _matmul_params(self) -> int:
+        """Everything but the embedding's gather runs once a pass (the
+        layers, the final norm, the head and the gate)."""
+        n = self.num_active_params()
+        again = n - (0 if self.tie_embeddings
+                     else self.vocab_size * self.hidden_size)
+        return n + (self.total_ut_steps - 1) * again
+
+    def _mixer_flops(self, seq_len: int, causal: bool) -> float:
+        return self.total_ut_steps * super()._mixer_flops(seq_len, causal)
+
+
+def ouro_config(size: str = "2.6b", **overrides) -> OuroConfig:
     presets = {
         "tiny": dict(hidden_size=64, num_layers=2, num_heads=4,
                      num_kv_heads=4, intermediate_size=128, vocab_size=512,
@@ -77,12 +111,12 @@ def ouro_config(size: str = "2.6b", **overrides) -> ModelConfig:
                 sandwich_norm=True, exit_gate=True, exit_entropy_beta=0.1)
     base.update(presets[size])
     base.update(overrides)
-    return ModelConfig(**base)
+    return OuroConfig(**base)
 
 
 @register_model("ouro")
 class Ouro(DecoderLM):
-    def __init__(self, config: ModelConfig | None = None,
+    def __init__(self, config: OuroConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
             raise ValueError(

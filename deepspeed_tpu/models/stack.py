@@ -1,26 +1,121 @@
 """A stack of more than one kind of layer: what the families that have one
 share (``models/kimi_linear.py``: KDA and latent attention over dense and
 routed channels; ``models/granite_hybrid.py``: Mamba-2 and grouped-query
-attention).
+attention; ``models/mellum.py``: window and full attention, each routed).
 
-Parameters are stacked by kind: ``layers`` holds ``lead`` (the leading
-layers, unrolled), ``period`` (the layers of ONE period of the pattern,
-each stacked over the whole periods, run under one ``lax.scan``) and
-``tail`` (what does not fill a period, unrolled); a layer's kind is read
-from the keys it holds. Every layer is rematted whole. A family gives its
-``kinds`` (one hashable a layer) and three methods: ``_init_layer(key,
-kind, lead_shape)``, ``_mixers(attn_fn, act_sharding)`` (what its layers
-call to mix tokens, with the kernels imported there and not at import)
-and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``.
+**The config** (``StackConfig``, ``RoutedStackConfig``): a family's own
+dataclass holds its fields, the kinds of its layers and three small tables
+a kind; the parameter and FLOP counts are ONE walk over the kinds, here,
+with the held share of a routed layer in the one place the routed
+families share.
+
+**The model** (``StackOfKinds``, ``RoutedStackOfKinds``): parameters are
+stacked by kind: ``layers`` holds ``lead`` (the leading layers, unrolled),
+``period`` (the layers of ONE period of the pattern, each stacked over the
+whole periods, run under one ``lax.scan``) and ``tail`` (what does not
+fill a period, unrolled); a layer's kind is read from the keys it holds.
+Every layer is rematted whole. The kinds (one hashable a layer) and the
+leading layers are the config's; a family gives three methods:
+``_init_layer(key, kind, lead_shape)``, ``_mixers(attn_fn, act_sharding)``
+(what its layers call to mix tokens, with the kernels imported there and
+not at import) and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import layers as L
+from .base import ModelConfig
 from .transformer import DecoderLM, _remat_policy, _unpack_batch
+
+
+@dataclasses.dataclass
+class StackConfig(ModelConfig):
+    """The config of a stack of kinds. A family's subclass holds its own
+    fields and says, a kind of layer: ``layer_kinds()`` (one hashable a
+    layer, in the order they run), ``_layer_params(kind)`` (a layer's
+    parameters, norms and all), ``_layer_idle_params(kind)`` (the part of
+    them a token does not run) and ``_layer_mixer_flops(kind, seq_len,
+    causal)`` (what its token mixer adds to the 6 N, a token). The counts
+    are one walk over the kinds: embedding, head and final norm, plus for
+    each layer its kind's."""
+
+    def layer_kinds(self) -> list:
+        raise NotImplementedError
+
+    def lead_layers(self) -> int:
+        """Leading layers kept out of the repeating pattern."""
+        return 0
+
+    def _layer_params(self, kind) -> int:
+        raise NotImplementedError
+
+    def _layer_idle_params(self, kind) -> float:
+        return 0
+
+    def _layer_mixer_flops(self, kind, seq_len: int, causal: bool) -> float:
+        raise NotImplementedError
+
+    def num_params(self) -> int:
+        """As the family's ``init`` builds the trees, exactly: an RMSNorm
+        stack without biases or learned positions."""
+        table = self.vocab_size * self.hidden_size
+        return (table * (1 if self.tie_embeddings else 2) + self.hidden_size
+                + sum(self._layer_params(k) for k in self.layer_kinds()))
+
+    def num_active_params(self) -> int:
+        return int(self.num_params() - sum(
+            self._layer_idle_params(k) for k in self.layer_kinds()))
+
+    def _matmul_params(self) -> float:
+        """A tied table is the head's matmul; an untied one's gather is
+        not a matmul."""
+        return self.num_active_params() - (
+            0 if self.tie_embeddings else self.vocab_size * self.hidden_size)
+
+    def _mixer_flops(self, seq_len: int, causal: bool) -> float:
+        return sum(self._layer_mixer_flops(k, seq_len, causal)
+                   for k in self.layer_kinds())
+
+
+@dataclasses.dataclass
+class RoutedStackConfig(StackConfig):
+    """... with routed layers (``moe.sharded_moe.moe_ffn_held``): a router
+    over ``num_experts`` in front of the experts HELD here."""
+    moe_router_activation: str = "softmax"  # softmax | sigmoid (bias-
+    #                                 corrected selection)
+    routed_scaling_factor: float = 1.0
+    moe_intermediate_size: int = 0  # expert width where it differs from
+    #                                 the dense FFN's (0 = the same)
+    moe_held_experts: int = 0       # experts HELD here of num_experts, the
+    #                                 router's width (0 = all): one chip's
+    #                                 share under expert parallelism (the
+    #                                 first of them)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts held here: all of them unless told a share."""
+        return self.moe_held_experts or self.num_experts
+
+    def _expert_params(self) -> int:
+        """ONE routed expert, a SwiGLU."""
+        return 3 * self.hidden_size * (self.moe_intermediate_size
+                                       or self.intermediate_size)
+
+    def _held_params(self) -> int:
+        """A routed layer's experts held here."""
+        return self.held_experts * self._expert_params()
+
+    def _idle_held_params(self) -> float:
+        """... and the part of them a token does not run: it runs the
+        ``moe_top_k`` it is routed to times the share of the experts held
+        here (what this chip computes for it, under a balanced router)."""
+        run = self.moe_top_k * self.held_experts / self.num_experts
+        return (self.held_experts - run) * self._expert_params()
 
 
 def stack_plan(kinds: list, lead: int) -> tuple[int, int, int]:
@@ -41,10 +136,10 @@ def stack_plan(kinds: list, lead: int) -> tuple[int, int, int]:
 
 
 class StackOfKinds(DecoderLM):
-    def __init__(self, config, kinds: list, lead: int = 0):
+    def __init__(self, config: StackConfig):
         super().__init__(config)
-        self.kinds = kinds
-        self.lead = min(lead, len(kinds))
+        self.kinds = kinds = config.layer_kinds()
+        self.lead = min(config.lead_layers(), len(kinds))
         self.period, self.repeats, self.left = stack_plan(kinds, self.lead)
 
     def _init_layers(self, key):

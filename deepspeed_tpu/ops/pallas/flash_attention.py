@@ -67,6 +67,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._common import _interpret
+
 NEG_INF = -1e30
 _LANES = 128          # a vector register's minor dimension
 
@@ -89,10 +91,6 @@ def _resident_max_seq(d: int) -> int:
 # Mosaic's default 16MB scoped-vmem ceiling trips at long seq x D=128 —
 # raise it (v5e/v5p have 128MB)
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _block(s: int) -> int:

@@ -24,11 +24,9 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._common import _interpret
+
 BLOCK = 1024  # rows per program, x 128 lanes
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_2d(x):

@@ -30,7 +30,7 @@ changes. The outputs alias the float32 carries the caller's loop holds: an
 expert the chunk does not reach keeps what it had, and one that began in
 an earlier chunk takes its sums up from the carry by one copy.
 
-**One trace a shape** (``ssd._bind``). Operands in the rows' dtype,
+**One trace a shape** (``_common._bind``). Operands in the rows' dtype,
 float32 accumulation. On the chip the widths are multiples of 128 and the
 tile of 8 (16 in bf16); interpret mode (any other backend, the tests)
 takes any shape.
@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ssd import _bind, _interpret, _nbytes
+from ._common import _bind, _dot, _interpret, _nbytes
 
 ROW_TILE = 256      # rows a grid step, at most
 
@@ -79,11 +79,6 @@ def tile_tables(expert, live, begun, m: int):
     init = jnp.where(opens, jnp.where(begun, _CARRY, _ZERO), 0)
     return (of_tile.astype(i32), src.astype(i32), live.astype(i32),
             init.astype(i32))
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
 
 
 def _params(resident_bytes: int, tile: int):

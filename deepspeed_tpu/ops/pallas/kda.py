@@ -93,7 +93,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ssd import _bind
+from ._common import _bind, _dot, _interpret
 
 CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
 SUB = 8         # rows a sub-block of the score matrices
@@ -110,15 +110,6 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
 
 
 def _advance(st, ub, k_out, shrink):

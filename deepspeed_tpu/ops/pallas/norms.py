@@ -18,10 +18,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..layers import layer_norm as _ln_ref
 from ..layers import rms_norm as _rms_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from ._common import _interpret
 
 
 def _rms_kernel(x_ref, s_ref, o_ref, *, eps):

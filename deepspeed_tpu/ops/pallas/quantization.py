@@ -18,11 +18,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._common import _interpret
+
 QBLOCK = 512  # elements per quantization block (lane-dim groups of 128)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):

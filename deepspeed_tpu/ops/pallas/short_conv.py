@@ -42,7 +42,7 @@ would hold the kernels to the XLU (``ops/pallas/ssd.py``).
 The taps, the bias and ``norm_scale`` reach the kernels as rows of one
 float32 [n + 2, C] operand, so a layer's q and k (which differ by the
 scale alone) are one kernel. Each kernel is traced once a shape
-(``ssd._bind``). ``norm_width`` must divide a block's lanes; on the chip
+(``_common._bind``). ``norm_width`` must divide a block's lanes; on the chip
 ``C`` must be a multiple of 128, interpret mode (any other backend, the
 tests) takes any width as one block.
 """
@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ssd import _bind, _dot, _interpret, _nbytes, _pieces
+from ._common import _bind, _dot, _interpret, _nbytes, _pieces
 
 _LANES = 128
 _ROWS = 8           # rows carried from block to block: a sublane tile

@@ -82,10 +82,11 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.extend
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._common import _bind, _dot, _interpret, _nbytes, _pieces
 
 HEADS = 16      # heads a grid step, at most
 
@@ -97,15 +98,6 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
 
 
 def _row_block(q: int) -> int:
@@ -139,15 +131,6 @@ def _check_chip_shapes(q, hb, p, n):
 
 def _cat(parts, axis):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
-
-
-def _pieces(v, dt):
-    """``v`` (float32) as three pieces in ``dt`` whose sum is ``v``."""
-    f32 = jnp.float32
-    hi = v.astype(dt)
-    rest = v - hi.astype(f32)
-    mid = rest.astype(dt)
-    return hi, mid, (rest - mid.astype(f32)).astype(dt)
 
 
 def _running_sum(v, *, reverse: bool):
@@ -393,29 +376,6 @@ def _blocks(q, h, p, n, hb, per_group, *, chunk_of):
         (1, 1, 1, n, hb * p), lambda i, l, j: (i, chunk_of(l), j, 0, 0),
         memory_space=vm)
     return wide, steps, rates, shared, ck
-
-
-_TRACED: dict = {}
-
-
-def _bind(call, scope, key, *args):
-    """``call(*args)`` under ``scope``, the ``pallas_call`` traced ONCE a
-    ``key`` (with the operands' types and the mesh they are typed on) and
-    bound from that jaxpr ever after. A step holds each kernel many times
-    (a layer unrolled, a scan's body, remat's rerun, the agreement check's
-    forward); every ``pallas_call`` traces its kernel anew, and an equation
-    with a new jaxpr is lowered anew: 12 s of a warm ``setup_s`` (PR 37).
-    Equal equations share one lowering, and each keeps its own place's
-    scope."""
-    key = (key, _interpret(), tuple(jax.typeof(x) for x in args))
-    if key not in _TRACED:
-        _TRACED[key] = jax.make_jaxpr(call)(*args)
-    with jax.named_scope(scope):
-        return jax.extend.core.jaxpr_as_fun(_TRACED[key])(*args)
-
-
-def _nbytes(*arrays):
-    return sum(x.size * jnp.dtype(x.dtype).itemsize for x in arrays)
 
 
 def _forward(x, dt, A, B, C, dims, *, states: bool):

@@ -3,13 +3,15 @@ grouped-query attention layer among them, a SwiGLU in every layer, the four
 muP multipliers and a tied head, checked on the CPU at tiny sizes against
 the plain float32 reference the benchmark keeps
 (``benchmark/architectures/granite_hybrid.py``, which imports nothing from
-the program); and both callers of the short convolution's kernels
-(ISSUE 43), Kimi-Linear and this family, held to the loss and gradients
-they computed with the ``jax.numpy`` lines in the op's place. A CPU run
-shows results and counts, never a time."""
+the program). The cell's limits against planted faults are
+``tests/test_granite_hybrid_limits.py``, and both callers of the short
+convolution's kernels (ISSUE 43) held to their parents' loss and gradients
+``tests/test_short_conv_step.py`` (PR 45: a file is one worker's under
+``--dist loadfile``, and this one was 618 s); what they share is
+``tests/helpers/family_cases.py``. A CPU run shows results and counts,
+never a time."""
 
 import json
-import pathlib
 import re
 import subprocess
 import sys
@@ -20,36 +22,20 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import GraniteHybrid, KimiLinear
+from deepspeed_tpu.models import GraniteHybrid
 from deepspeed_tpu.models.stack import stack_plan
-from deepspeed_tpu.ops import layers as L
 from deepspeed_tpu.ops.ssd import chunk_ssd, recurrent_ssd
 from deepspeed_tpu.telemetry import scopes
 
-BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-if str(BENCH) not in sys.path:
-    sys.path.insert(0, str(BENCH))
-from architectures import granite_hybrid as arch  # noqa: E402
-from kinds import train_job  # noqa: E402
-from lib import modelspec  # noqa: E402
-
-from helpers import hlo_text, short_conv_reference  # noqa: E402  (tests/)
-
-CONFIG = json.loads(
-    (BENCH / "configs" / "granite-4.0-h-micro-zero3-1chip.json").read_text())
-
-
-@pytest.fixture(autouse=True)
-def _telemetry_isolation():
-    telemetry.shutdown()
-    yield
-    telemetry.shutdown()
-
-
-def _err(got, want):
-    return float(jnp.max(jnp.abs(got - want))) / (
-        float(jnp.max(jnp.abs(want))) + 1e-30)
+from helpers import hlo_text  # noqa: E402  (tests/helpers)
+from helpers.family_cases import GRANITE_CONFIG as CONFIG
+from helpers.family_cases import (BENCH, _batch, _err,  # noqa: F401
+                                  _telemetry_isolation)
+from architectures import granite_hybrid as arch  # noqa: E402  (benchmark/,
+#                                           on sys.path by family_cases)
+from helpers.family_cases import granite_tiny as _tiny
+from helpers.family_cases import granite_weights as _weights
+from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
 
 
 # ---- the chunked scan against the recurrence -------------------------------
@@ -124,27 +110,6 @@ def test_chunked_ssd_agrees_with_the_benchmarks_recurrence():
 
 
 # ---- the whole model against the plain reference ---------------------------
-def _tiny(**kw):
-    return GraniteHybrid(size="tiny", **kw)
-
-
-def _batch(model, b=2, s=128, seed=0):
-    tok = np.random.default_rng(seed).integers(
-        0, model.config.vocab_size, (b, s + 1))
-    return jnp.asarray(tok[:, :-1]), jnp.asarray(tok[:, 1:])
-
-
-def _weights(model, seed=3):
-    """Seeded weights under which every layer carries weight in the
-    logits: at the init's own scale the one attention layer adds 0.2% to
-    the final hidden state (uniform softmax, a small output projection
-    times 0.22), and no check could see a fault in it."""
-    boost = {"wq": 16.0, "wk": 16.0, "wv": 8.0, "wo": 8.0}
-    return jax.tree_util.tree_map_with_path(
-        lambda path, w: w * boost.get(path[-1].key, 1.0),
-        model.init(jax.random.PRNGKey(seed)))
-
-
 def _ref_loss(params, tokens, targets, m):
     hidden = arch.final_hidden(params, tokens, m) / m["logits_scaling"]
     return arch.loss_of(hidden, params["embed"]["tokens"].T, targets)
@@ -173,51 +138,6 @@ def test_loss_and_gradients_match_the_float32_reference(variant):
         name = jax.tree_util.keystr(path)
         assert float(jnp.max(jnp.abs(w))) > 0, name
         assert _err(g, w) < 2e-3, name
-
-
-class _UntiedHead(GraniteHybrid):
-    """A head that is not the embedding table (its rows reversed)."""
-    def _project_vocab(self, params, x):
-        other = {**params, "embed": {
-            "tokens": jnp.flip(params["embed"]["tokens"], 0)}}
-        return super()._project_vocab(other, x)
-
-
-FAULTS = {
-    None: {},
-    "softmax_scale_head_dim_rsqrt_in_place_of_the_multiplier":
-        dict(attention_multiplier=None),
-    "no_residual_multiplier": dict(residual_multiplier=1.0),
-    "logits_not_divided": dict(logits_scaling=1.0),
-    "no_embedding_multiplier": dict(embedding_multiplier=1.0),
-    "untied_head": {},
-    "targets_off_by_one": {},
-}
-
-
-@pytest.mark.parametrize("fault", list(FAULTS), ids=lambda f: f or "none")
-def test_the_cells_limits_catch_a_planted_fault(fault):
-    """The benchmark's own decision (``kinds/train_job.py`` ``decide`` at
-    the configuration's ``check``) on the program's tail logits and loss
-    against the reference's: the program passes, each planted departure
-    from the published equations does not."""
-    right = _tiny(loss_chunk=64)
-    cls = _UntiedHead if fault == "untied_head" else GraniteHybrid
-    model = cls(size="tiny", loss_chunk=64, **FAULTS[fault])
-    params = _weights(right)
-    tokens, targets = _batch(right)
-    m = modelspec.reference_model(arch, right)
-    with jax.default_matmul_precision("highest"):
-        want_loss, want_tail = arch.reference(params, tokens, targets, m, 32)
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if fault == "targets_off_by_one":
-            targets = jnp.roll(targets, 1, axis=1)
-        got_loss = float(model.loss(params, (tokens, targets)))
-    numbers = train_job.tail_numbers(got_tail, want_tail, None)
-    ok = train_job.decide(numbers, want_loss, got_loss, CONFIG["check"])
-    assert ok == (fault is None), numbers
-    if fault is None:
-        assert numbers["logits_err_max"] < 1e-4 > numbers["loss_err"]
 
 
 # ---- the configuration, the counts, the plan -------------------------------
@@ -371,87 +291,6 @@ def test_the_named_scopes_are_metadata_and_nothing_else(granite_engine,
     named, bare = hlo_text.bare_step(*granite_engine, _DS_CONFIG, monkeypatch)
     assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
     assert bare == named
-
-
-# ---- with the jax.numpy convolution back in, the parent's step -------------
-_STEPS = {
-    "kimi_linear_the_cells_switches": (KimiLinear, dict(
-        moe_held_experts=8, attn_impl="flash", loss_chunk=64,
-        kda_head_groups=2)),
-    "granite_hybrid_the_cells_switches": (GraniteHybrid, dict(
-        attn_impl="flash", loss_chunk=64)),
-}
-
-
-def _loss_and_grads(monkeypatch, family, dtype, reference: bool):
-    """Loss and gradients of a tiny model's step on ``dtype`` weights,
-    with ``ops.layers.short_conv`` as it is or, ``reference``, as the
-    parent's lines had it (``tests/helpers/short_conv_reference.py``:
-    ``causal_conv``, the SiLU and ``_kda``'s local l2 norm, the taps and
-    the SiLU in the weights' dtype)."""
-    cls, kw = _STEPS[family]
-    if reference:
-        monkeypatch.setattr(L, "short_conv", short_conv_reference.short_conv)
-    model = cls(size="tiny", **kw)
-    params = jax.tree.map(lambda x: x.astype(dtype),
-                          model.init(jax.random.PRNGKey(3)))
-    out = jax.jit(jax.value_and_grad(model.loss))(params, _batch(model))
-    monkeypatch.undo()
-    return jax.device_get(out)
-
-
-def _leaf_errors(got, want):
-    """{leaf: |got - want| / |want| (l2)} over the leaves with a gradient
-    (the router's bias has none: selection only)."""
-    out = {}
-    for (path, w), (_, g) in zip(jax.tree_util.tree_leaves_with_path(want),
-                                 jax.tree_util.tree_leaves_with_path(got),
-                                 strict=True):
-        w, g = (np.asarray(v, np.float32) for v in (w, g))
-        if np.any(w):
-            out[jax.tree_util.keystr(path)] = float(
-                np.linalg.norm(g - w) / np.linalg.norm(w))
-        else:
-            assert not np.any(g), path
-    return out
-
-
-@pytest.mark.parametrize("family", list(_STEPS))
-def test_the_step_computes_the_parents_loss_and_gradients(monkeypatch,
-                                                          family):
-    """ISSUE 43: the short convolution, the SiLU and the l2 norms became
-    one kernel pair and nothing else moved: with the parent's
-    ``jax.numpy`` lines patched back in for the op, the tiny Kimi-Linear
-    and Granite steps compute the same loss and gradients. In float32
-    the two forms are one function (the kernels sum a head's squares from
-    three bf16 pieces and take the SiLU through tanh: rounding in the
-    seventh digit). On bf16 weights each form is its own rounding of that
-    function (the parent rounded the taps' products, their sum and the
-    SiLU to bf16, the kernels round once), so each is held to the
-    float32 gradients: the kernels' lie NO FURTHER from them than the
-    parent's (0.8 of its distance on Kimi's leaves, 0.9 on Granite's,
-    where a leaf is 1% to 5% from float32 in either form). A routed
-    expert's leaves are 10% to 20% off in both: a rounding sends a token
-    to another expert."""
-    exact, exact_g = _loss_and_grads(monkeypatch, family, "float32", False)
-    parent, parent_g = _loss_and_grads(monkeypatch, family, "float32", True)
-    assert abs(float(exact) - float(parent)) <= 2e-6 * float(parent)
-    same = _leaf_errors(exact_g, parent_g)
-    assert max(same.values()) < 2e-4, max(same.items(), key=lambda kv: kv[1])
-
-    now, now_g = _loss_and_grads(monkeypatch, family, "bfloat16", False)
-    parent, parent_g = _loss_and_grads(monkeypatch, family, "bfloat16", True)
-    assert (abs(float(now) - float(exact))
-            <= 1.5 * abs(float(parent) - float(exact)) + 2e-4 * float(exact))
-    mine, theirs = (_leaf_errors(g, exact_g) for g in (now_g, parent_g))
-    for name in mine:       # no leaf goes astray
-        assert mine[name] <= 2 * theirs[name] + 1e-2, (
-            name, mine[name], theirs[name])
-    smooth = [n for n in mine if "['experts']" not in n
-              and "['router']" not in n]
-    assert len(smooth) > 30
-    assert (np.mean([mine[n] for n in smooth])
-            <= np.mean([theirs[n] for n in smooth]))
 
 
 def test_importing_the_package_loads_no_state_space_scan():

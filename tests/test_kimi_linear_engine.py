@@ -15,7 +15,8 @@ from deepspeed_tpu.moe.sharded_moe import BIAS_UPDATE_RATE
 from deepspeed_tpu.telemetry import scopes
 
 from helpers import hlo_text  # noqa: E402  (tests/helpers)
-from test_kimi_linear import _batch, _tiny
+from helpers.family_cases import _batch
+from helpers.family_cases import kimi_tiny as _tiny
 
 
 @pytest.fixture(autouse=True)

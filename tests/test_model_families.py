@@ -300,3 +300,116 @@ def test_family_trains_through_engine(devices8):
         batch = (tokens[:, :-1], tokens[:, 1:])
         losses = [float(engine.train_batch(batch)) for _ in range(3)]
         assert losses[-1] < losses[0], (cls.__name__, losses)
+
+
+# ---- a family owns its fields and its arithmetic (PR 45) -------------------
+# num_params(), num_active_params(), flops_per_token(max_seq_len) and
+# flops_per_token(max_seq_len, causal=False) as the parent commit (PR 44,
+# 007653c) printed them, where ``ModelConfig`` held every family's fields
+# and three hand-written sums: one walk over a stack's kinds has to give the
+# same. A ``cell/<file>`` case is the model of that configuration file of
+# ``benchmark/configs/`` (its preset, ``model_overrides``, depth and
+# sequence).
+PARENT_COUNTS = {
+    "kimi_linear/tiny": (6576944, 482096, 2831616.0, 2892576),
+    "kimi_linear/48b-a3b": (49122763648, 3484541824, 22354500864.0,
+                            25877501184),
+    "granite_hybrid/tiny": (280992, 280992, 1833792.0, 1882560),
+    "granite_hybrid/4.0-h-micro": (3191396096, 3191396096, 25817369088.0,
+                                   32259770880),
+    "mellum/tiny": (1753664, 377408, 2296512.0, 2854272),
+    "mellum/12b-a2.5b": (12149915904, 2439053568, 36876957120.0,
+                         193663993344),
+    "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
+    "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
+    "cell/kimi-linear-48b-ep32-zero3-1chip": (
+        602450816, 383036288, 2556198144.0, 3059483904),
+    "cell/granite-4.0-h-micro-zero3-1chip": (
+        772160448, 772160448, 4790261376.0, 4890912384),
+    "cell/mellum2-12b-ep4-zero3-1chip": (
+        595153152, 248336640, 1699239936.0, 4371506688),
+    "cell/ouro-2.6b-pp6-zero3-1chip": (
+        612438017, 612438017, 16108191768.0, 19329024024),
+    "cell/mistral-7b-zero3-1chip": (
+        698372096, 698372096, 4492247040.0, 4995538944),
+    "cell/mistral-7b-zero3-4chip": (
+        1570820096, 1570820096, 10330963968.0, 11840839680),
+    "mistral/7b": (7241732096, 7241732096, 48282624000.0, 56335294464),
+    "mistral/tiny": (139584, 139584, 880704.0, 1034112),
+    "mixtral/8x7b": (46702792704, 12879925248, 80501563392.0, 83722002432),
+    "mixtral/tiny": (287552, 189248, 1234560.0, 1332096),
+    "qwen2_moe/a2.7b": (13692930048, 2066319360, 14814130176.0,
+                        17229754368),
+    "qwen2_moe/tiny": (337088, 238784, 1531776.0, 1629312),
+    "gpt2/tiny": (141056, 141056, 945408.0, 1042944),
+    "llama/tiny": (139584, 139584, 936576.0, 1034112),
+    "falcon/tiny": (119168, 119168, 814080.0, 911616),
+    "phi/tiny": (165376, 165376, 1091328.0, 1188864),
+    "gptneox/tiny": (165632, 165632, 1092864.0, 1190400),
+    "bloom/tiny": (132992, 132992, 897024.0, 994560),
+    "gptj/tiny": (164864, 164864, 1088256.0, 1185792),
+    "opt/tiny": (141056, 141056, 945408.0, 1042944),
+    "qwen/tiny": (139840, 139840, 938112.0, 1035648),
+    "internlm/tiny": (148288, 148288, 988800.0, 1086336),
+}
+
+
+def _counted_config(case):
+    family, size = case.split("/")
+    if family != "cell":
+        return get_model_class(family)(size=size).config
+    import json
+    import pathlib
+    file = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                       / "configs" / f"{size}.json").read_text())
+    program = file["program"]
+    return get_model_class(program["model_type"])(
+        size=program["preset"], **program.get("model_overrides", {}),
+        num_layers=file["num_hidden_layers"],
+        max_seq_len=file["max_position_embeddings"]).config
+
+
+@pytest.mark.parametrize("case", PARENT_COUNTS)
+def test_counts_are_the_parents(case):
+    c = _counted_config(case)
+    params, active, causal, full = PARENT_COUNTS[case]
+    assert c.num_params() == params
+    assert c.num_active_params() == active
+    s = c.max_seq_len
+    assert c.flops_per_token(s) == pytest.approx(causal, rel=1e-12, abs=0)
+    assert c.flops_per_token(s, causal=False) == pytest.approx(
+        full, rel=1e-12, abs=0)
+
+
+@pytest.mark.parametrize("family,stranger", [
+    ("kimi_linear", dict(mamba_n_heads=4)),
+    ("granite_hybrid", dict(kda_head_groups=4)),
+    ("mellum", dict(kda_head_groups=4)),
+    ("ouro", dict(layer_types=["attention", "attention"])),
+    ("mistral", dict(total_ut_steps=4)),
+    ("mixtral", dict(moe_held_experts=2)),
+])
+def test_another_familys_field_is_refused(family, stranger):
+    """An override that is no field of the family's own config class is a
+    ``TypeError`` at construction, not a model built in silence."""
+    with pytest.raises(TypeError, match=next(iter(stranger))):
+        get_model_class(family)(size="tiny", **stranger)
+
+
+def test_the_base_config_names_no_family():
+    """``models/base.py`` is what the one-kind decoder reads: a family's
+    fields and arithmetic live in the family's file (``models/stack.py``
+    for what the stacks of kinds share), comments included."""
+    import dataclasses
+    import inspect
+
+    from deepspeed_tpu.models import base
+    source = inspect.getsource(base).lower()
+    for word in ("kda", "mamba", "mla", "layer_types", "rope_parameters",
+                 "total_ut_steps", "kimi", "granite", "mellum", "ouro"):
+        assert word not in source, word
+    fields = {f.name for f in dataclasses.fields(base.ModelConfig)}
+    assert "moe_held_experts" not in fields and "exit_gate" not in fields
+    for gone in ("_stack_params", "_hybrid_params", "_window_stack_params",
+                 "layer_kinds", "window_stack"):
+        assert not hasattr(base.ModelConfig, gone), gone

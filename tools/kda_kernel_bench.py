@@ -154,7 +154,7 @@ def main(argv) -> int:
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kda
-    from deepspeed_tpu.ops.pallas import kda as kernels, ssd
+    from deepspeed_tpu.ops.pallas import _common, kda as kernels
     from helpers import kda_reference
     bf = jnp.bfloat16
     kernel_prepare = kda.kda_prepare
@@ -186,15 +186,15 @@ def main(argv) -> int:
         ev_pf = traced(jax, prep_fwd, args)
         prep_fwd_ms = kernel_ms(ev_pf)
         ev_pb = traced(jax, prep_bwd, (*args, *cts))
-        # a new jit and no kept trace (ssd._bind keeps one a shape): the
+        # a new jit and no kept trace (_common._bind keeps one a shape): the
         # kernel is traced again, without its inverse
         inverse = kernels._inverse_unit_lower
         kernels._inverse_unit_lower = lambda mats: mats
-        ssd._TRACED.clear()
+        _common._TRACED.clear()
         no_inverse = kernel_ms(traced(jax, jax.jit(
             lambda *a: kernels._prepare_forward(*a, CHUNK)), args))
         kernels._inverse_unit_lower = inverse
-        ssd._TRACED.clear()
+        _common._TRACED.clear()
         line = {"heads": heads, "seg": kernels.SEG,
                 "heads_a_step": kernels.HEADS,
                 "prep_chunks_a_step": kernels.NCK,
