@@ -17,6 +17,7 @@ from .ouro import Ouro, ouro_config  # noqa: F401
 from .phi import Phi, Phi3, phi3_config, phi_config  # noqa: F401
 from .qwen import (Qwen, Qwen2, Qwen2MoE, qwen2_config,  # noqa: F401
                    qwen2_moe_config, qwen_config)
+from .qwen3_next import Qwen3Next, qwen3_next_config  # noqa: F401
 from .transformer import DecoderLM  # noqa: F401
 
 

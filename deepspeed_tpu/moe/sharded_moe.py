@@ -524,13 +524,16 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
                  shared: dict | None, *, k: int, first_expert: int = 0,
                  renormalise: bool = True, scaling: float = 1.0,
                  block: int | None = None, router: str = "sigmoid",
-                 router_grad: bool = True):
+                 router_grad: bool = True,
+                 shared_gate: jax.Array | None = None):
     """A routed expert layer that is told which experts it holds (one
     chip's share under expert parallelism, without its exchange): routes
     every token over ALL ``router_w.shape[-1]`` experts, computes what the
     ``experts["w_up"].shape[0]`` experts from ``first_expert`` on give for
     the tokens routed to them (:func:`held_experts_ffn`) and adds the
-    always-on ``shared`` expert where there is one. ``router``:
+    always-on ``shared`` expert where there is one, times
+    ``sigmoid(x @ shared_gate)`` (``shared_gate`` [D, 1], float32 logits:
+    Qwen's gated shared expert) where that is given. ``router``:
     ``sigmoid`` (:func:`sigmoid_top_k`, with its selection bias
     ``router_bias``) or ``softmax`` (:func:`softmax_top_k`, no bias); the
     weights are float32 either way, times ``scaling``. A token routed only
@@ -584,7 +587,12 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
     if shared is not None:
         with jax.named_scope("ds.moe_shared"):
             _, _, h = _swiglu_rows(xt, shared["w_gate"], shared["w_up"])
-            out = out + h @ shared["w_down"]
+            y = h @ shared["w_down"]
+            if shared_gate is not None:
+                y = (y * jax.nn.sigmoid(jnp.matmul(
+                    xt, shared_gate, preferred_element_type=jnp.float32))
+                     ).astype(y.dtype)
+            out = out + y
     return out.reshape(b, s, d), {"load": load, "done": done}
 
 

@@ -1,13 +1,23 @@
-"""Kimi Delta Attention (KDA): a gated delta rule with a decay per key
-channel, in its chunked form (Kimi-Linear, ``linear_attn_config``).
+"""The gated delta rule in its chunked form, with either gate: Kimi Delta
+Attention (KDA: a decay per key CHANNEL; Kimi-Linear,
+``linear_attn_config``) and Gated DeltaNet (a decay per HEAD; Qwen3-Next,
+``linear_*`` keys).
 
 Per head, with a float32 state ``S`` [dk, dv], ``S_0 = 0``::
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-``g_t <= 0`` is the log-decay of each KEY CHANNEL, ``beta_t`` in (0, 1).
-``recurrent_kda`` is that recurrence token by token (tests only).
+``g_t <= 0`` is the log-decay, ``beta_t`` in (0, 1). KDA's ``g`` is
+[B, S, H, dk], a number a key channel; Gated DeltaNet's is [B, S, H], a
+number a head: the same recurrence with ``Diag(exp(g_t)) = exp(g_t) I``.
+Every function here takes either. With one number a head a chunk's decay
+is ONE [C, C] mask ``D_ij = exp(G_i - G_j)`` on the plain score matrices
+(``A = (K K^T * D) beta``), its exponent never positive: the preparation's
+kernels build that in place of the row blocks below (``ops/pallas/kda.py``
+``_HeadChunk``), exact at any decay; the recurrence's kernels are the
+same (``shrink`` is the chunk's one number on every channel).
+``recurrent_kda`` is the recurrence token by token (tests only).
 ``chunk_kda`` is what the models run: chunks of ``CHUNK`` tokens, inside a
 chunk with incoming state ``S`` and ``G_r = sum_{i<=r} g_i``::
 
@@ -34,11 +44,17 @@ recurrence was a ``lax.scan`` under autodiff, until PR 35 the preparation
 ``jax.numpy`` under autodiff: ``tests/helpers/kda_reference.py`` keeps
 that form as the kernels' reference.)
 
-A layer of the cell runs the preparation's forward three times (the
-forward, the layer's remat, the head group's own checkpoint) and its
-backward once; ``ds_kda_fwd`` twice, its checkpoint form once and
-``ds_kda_bwd`` once (``PERF.md`` section 5 has their times).
+A layer of EACH cell that runs this op (``train-kda-s16k-1chip``: four KDA
+layers of 32 heads in four head groups; ``train-gdn-s16k-1chip``: three
+Gated DeltaNet layers of 32 value heads in one group) runs the
+preparation's forward three times (the forward, the layer's remat, the
+head group's own checkpoint) and its backward once; ``ds_kda_fwd`` twice,
+its checkpoint form once and ``ds_kda_bwd`` once (``PERF.md`` section 5
+has their times). The operand shapes are the same in both cells (dk = dv
+= 128, 16384 tokens); the second reads its gate as rows, a number a
+token, as both read beta.
 
+A gate a CHANNEL cannot be a mask: its decay rides inside the products.
 ``exp(G_i - G_j) <= 1``, but ``exp(G_i) * exp(-G_j)`` overflows float32
 where a channel decays fast (a log-decay of -1.6 a token is -100 over a
 chunk). So the score matrices are built by row blocks of ``SUB`` rows,
@@ -59,16 +75,18 @@ from .pallas.kda import CHUNK, kda_prepare, kda_recurrence
 
 def recurrent_kda(q, k, v, g, beta):
     """The recurrence, token by token. q, k [B, S, H, dk]; v [B, S, H, dv];
-    g [B, S, H, dk] (log-decay); beta [B, S, H]. Returns o [B, S, H, dv]
-    float32."""
+    g [B, S, H, dk] or [B, S, H] (log-decay a channel, a head); beta
+    [B, S, H]. Returns o [B, S, H, dv] float32."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if g.ndim == 3:
+        g = g[..., None]
     b, _, h, dk = q.shape
     dv = v.shape[-1]
 
     def step(state, xs):
         q_t, k_t, v_t, g_t, b_t = xs
-        state = state * jnp.exp(g_t)[..., None]
+        state = state * jnp.exp(g_t)[..., None]       # [B, H, dk | 1, 1]
         u = v_t - jnp.einsum("bhk,bhkv->bhv", k_t, state)
         state = state + jnp.einsum("bhk,bhv->bhkv", k_t * b_t[..., None], u)
         return state, jnp.einsum("bhk,bhkv->bhv", q_t, state)

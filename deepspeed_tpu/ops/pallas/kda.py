@@ -36,6 +36,16 @@ beta e^(G_C - G)`` and ``shrink = e^(G_C)``.
   clamped factor passes no gradient), the decay products, and the running
   sum's transpose; writes dq, dk, dv, dg in the model's layout and dbeta.
 
+**A gate a head** (Gated DeltaNet, PR 46): ``g`` [B, S, H], one log-decay a
+token, reaches both kernels as rows [1, C] the way beta does, and a chunk
+is a ``_HeadChunk``: the decay is ONE mask ``D_ij = exp(G_i - G_j)`` (j <=
+i) on the plain ``K K^T`` and ``Q K^T`` (one [2 C, dk] x [dk, C] product),
+no row blocks, no factoring, no clamp, exact at any decay; ``decay``,
+``fade`` and ``beta`` ride down a column and broadcast along the lanes,
+``shrink`` is the chunk's one number on every channel; the inverse, ``T
+[V, K e^G]`` and their backward are the shared code, and the backward
+returns ``dg`` as rows. The recurrence's kernels do not know the gate.
+
 **The recurrence.** What is left is serial in the chunks: with the float32
 state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
 
@@ -537,8 +547,11 @@ class _Chunk:
         parts = [x._scores_and_decays(d_a, dvk, *ct[2:])
                  for x, d_a, dvk, ct in zip(chunks, d_as, dvks, cts)]
         # the running sum's transpose: a reversed sum within the chunk
-        return [(dq, dk, d_v, _hdot(x.upto, d_g, _TN), dbeta)
+        return [(dq, dk, d_v, x._sum_back(d_g), dbeta)
                 for x, (dq, dk, d_v, d_g, dbeta) in zip(chunks, parts)]
+
+    def _sum_back(self, d_g):
+        return _hdot(self.upto, d_g, _TN)
 
     def _scores_and_decays(self, d_a, dvk, dq_in, da_qk, dk_out, dshrink):
         """dq, dk, dv, dG, dbeta from da_kk and the other cotangents."""
@@ -602,27 +615,120 @@ class _Chunk:
         return dq, dk, d_v, d_g, dbeta
 
 
-def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv):
+class _HeadChunk(_Chunk):
+    """... of a gate a HEAD (Gated DeltaNet): g [1, C] float32, a ROW as
+    beta is, one log-decay a token. ``Diag(exp(g_t)) = exp(g_t) I``, so a
+    chunk's decay is ONE mask ``D_ij = exp(G_i - G_j)`` (j <= i, else 0)
+    on the plain score matrices ``K K^T`` and ``Q K^T``: its exponent is
+    never positive, so there are no row blocks, no factoring and no clamp,
+    and it is exact at ANY decay (a factored block loses a near-diagonal
+    ``exp(g_i)`` where the rows before it in the block decay past what a
+    float32 exponent holds: -16 softplus(.) a token is this family's
+    init). What a row carries down a column (``G``, ``decay``, ``fade``,
+    ``beta_col``) is [C, 1] and broadcasts along the lanes."""
+
+    def __init__(self, q, k, v, g, beta):
+        f32 = jnp.float32
+        self.dt = dt = q.dtype
+        self.c, self.dk = c, dk = k.shape
+        self.v, self.beta = v.astype(dt), beta
+        self.qf, self.kf = qf, kf = q.astype(f32), k.astype(f32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        self.low, self.diag = rows > cols, rows == cols
+        # the running sum along the row: G_j = sum_{r <= j} g_r
+        self.upto = jnp.where(rows <= cols, 1.0, 0.0).astype(f32)
+        g_row = _hdot(g, self.upto)                         # [1, C]
+        self.G = G = self._col(g_row)                       # [C, 1]
+        self.D = D = jnp.where(
+            rows >= cols, jnp.exp(jnp.minimum(G - g_row, 0.0)), 0.0)
+        self.kq = jnp.concatenate([k.astype(dt), q.astype(dt)], axis=0)
+        s = _dot(self.kq, k.astype(dt), _NT)                # [2 C, C]
+        self.s_kk, self.s_qk = s[:c], s[c:]
+        self.a_kk = jnp.where(self.low, self.s_kk * D, 0.0) * beta
+        self.a_qk = self.s_qk * D * beta
+        self.decay = jnp.exp(jnp.minimum(G, 0.0))           # [C, 1]
+        self.tail = jnp.sum(g, axis=1, keepdims=True)       # G_C, [1, 1]
+        self.kd = (kf * self.decay).astype(dt)              # K e^G
+        self.beta_col = self._col(beta)
+        self.fade = jnp.exp(jnp.minimum(self.tail - G, 0.0))
+        self.shrink_one = jnp.exp(jnp.minimum(self.tail, 0.0))
+        self.shrink = jnp.broadcast_to(self.shrink_one, (1, dk))
+
+    def _col(self, row):
+        """[1, C] down a column [C, 1]."""
+        return jnp.sum(jnp.where(self.diag, row, 0.0), axis=1, keepdims=True)
+
+    def _row(self, col):
+        return jnp.sum(jnp.where(self.diag, col, 0.0), axis=0, keepdims=True)
+
+    def _sum_back(self, d_g):
+        """The running sum's transpose of a column, as the row dg is."""
+        return _hdot(self._row(d_g), self.upto, _NT)
+
+    def _scores_and_decays(self, d_a, dvk, dq_in, da_qk, dk_out, dshrink):
+        """dq, dk, dv, dG [C, 1], dbeta from da_kk and the other
+        cotangents."""
+        f32 = jnp.float32
+        dt, c, dv = self.dt, self.c, self.v.shape[1]
+        qf, kf, G, beta, D = self.qf, self.kf, self.G, self.beta, self.D
+        d_v, dkd = dvk[:, :dv], dvk[:, dv:]
+        da = da_qk.astype(f32)
+        sd_kk, sd_qk = self.s_kk * D, self.s_qk * D     # zero above the diag
+        dbeta = jnp.sum(d_a * sd_kk + da * sd_qk, axis=0, keepdims=True)
+        p_kk, p_qk = d_a * beta, da * beta              # of the masked scores
+        ds = jnp.concatenate([p_kk * D, p_qk * D], axis=0).astype(dt)
+        dleft = _dot(ds, self.kq[:c])                   # [2 C, dk]
+        dk = dleft[:c] + _dot(ds, self.kq, _TN)
+        dq = dleft[c:]
+        # the mask: d(G_i - G_j) = dD D
+        lean = p_kk * sd_kk + p_qk * sd_qk
+        d_g = (jnp.sum(lean, axis=1, keepdims=True)
+               - self._col(jnp.sum(lean, axis=0, keepdims=True)))
+        # the decay products
+        dqi, dko = dq_in.astype(f32), dk_out.astype(f32)
+        ddecay = jnp.sum(dkd * kf + dqi * qf, axis=1, keepdims=True)
+        dq = dq + dqi * self.decay
+        dkb = dko * self.fade                               # d(k beta)
+        dk = dk + dkd * self.decay + dkb * self.beta_col
+        pull = jnp.sum(dkb * kf, axis=1, keepdims=True)     # [C, 1]
+        dbeta = dbeta + self._row(pull)
+        dfade = pull * self.beta_col                        # d(G_C - G)
+        dtail = (jnp.sum(dfade, axis=0, keepdims=True)
+                 + jnp.sum(dshrink, axis=1, keepdims=True) * self.shrink_one
+                 * _half_at_tie(self.tail))
+        last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+        d_g = (d_g + ddecay * self.decay * _half_at_tie(G) - dfade
+               + jnp.where(last, dtail, 0.0))
+        return dq, dk, d_v, d_g, dbeta
+
+
+def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv,
+            head_gate=False):
     """(the rows of chunk ``i`` in the inputs' blocks, that chunk of each
-    head of the grid step, their inverses side by side)."""
+    head of the grid step, their inverses side by side). ``head_gate``:
+    ``g_ref`` holds rows as ``b_ref`` does, a number a token."""
     rows = pl.ds(pl.multiple_of(i * c, c), c)
-    chunks = [_Chunk(q_ref[0, rows, h * dk:(h + 1) * dk],
-                     k_ref[0, rows, h * dk:(h + 1) * dk],
-                     v_ref[0, rows, h * dv:(h + 1) * dv],
-                     g_ref[0, rows, h * dk:(h + 1) * dk].astype(jnp.float32),
-                     b_ref[h, i].astype(jnp.float32)) for h in range(heads)]
+    make = _HeadChunk if head_gate else _Chunk
+    gate = (lambda h: g_ref[h, i]) if head_gate else (
+        lambda h: g_ref[0, rows, h * dk:(h + 1) * dk])
+    chunks = [make(q_ref[0, rows, h * dk:(h + 1) * dk],
+                   k_ref[0, rows, h * dk:(h + 1) * dk],
+                   v_ref[0, rows, h * dv:(h + 1) * dv],
+                   gate(h).astype(jnp.float32),
+                   b_ref[h, i].astype(jnp.float32)) for h in range(heads)]
     return rows, chunks, _Chunk.invert(chunks)
 
 
 def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
                      qi_ref, a_ref, ko_ref, sh_ref, *, heads, nck, c, dk,
-                     dv):
+                     dv, head_gate=False):
     """``nck`` chunks of ``heads`` heads: the inputs' blocks are [1,
-    nck C, heads d] of the model's [B, S, H d], beta's [heads, nck, 1,
-    C]; the operands' [heads, nck, C, .]."""
+    nck C, heads d] of the model's [B, S, H d], beta's (and a gate a
+    head's) [heads, nck, 1, C]; the operands' [heads, nck, C, .]."""
     def chunk(i, carry):
         _, chunks, _ = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads,
-                               c, dk, dv)
+                               c, dk, dv, head_gate)
         for h, chunk in enumerate(chunks):
             for ref, x in zip((uv_ref, w_ref, qi_ref, a_ref, ko_ref,
                                sh_ref), chunk.operands()):
@@ -634,18 +740,23 @@ def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
 
 def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
                      dqi_ref, da_ref, dko_ref, dsh_ref, dq_ref, dk_ref,
-                     dv_ref, dg_ref, db_ref, *, heads, nck, c, dk, dv):
+                     dv_ref, dg_ref, db_ref, *, heads, nck, c, dk, dv,
+                     head_gate=False):
     """The same blocks; rebuilds each chunk's forward, then its backward."""
     def chunk(i, carry):
         rows, chunks, ts = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i,
-                                   heads, c, dk, dv)
+                                   heads, c, dk, dv, head_gate)
         cts = [tuple(ref[h, i] for ref in (duv_ref, dw_ref, dqi_ref, da_ref,
                                            dko_ref, dsh_ref))
                for h in range(heads)]
         for h, grads in enumerate(_Chunk.gradients(chunks, ts, cts)):
-            for ref, x, d in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads,
-                                 (dk, dk, dv, dk)):
+            for ref, x, d in zip((dq_ref, dk_ref, dv_ref), grads,
+                                 (dk, dk, dv)):
                 ref[0, rows, h * d:(h + 1) * d] = x.astype(ref.dtype)
+            # dg in its input's layout: rows a head, or the model's own
+            at = (h, i) if head_gate else (0, rows,
+                                           slice(h * dk, (h + 1) * dk))
+            dg_ref[at] = grads[3].astype(dg_ref.dtype)
             db_ref[h, i] = grads[4].astype(db_ref.dtype)
         return carry
 
@@ -660,7 +771,7 @@ def _prep_geometry(q, v, chunk):
     return b, n, h, dk, v.shape[-1], nck, heads
 
 
-def _prep_specs(b, n, h, c, dk, dv, nck, heads):
+def _prep_specs(b, n, h, c, dk, dv, nck, heads, head_gate=False):
     """(the five inputs' specs, the six operands' specs and shapes): a
     grid step (batch, head block, chunk block)."""
     hb = h // heads
@@ -670,7 +781,8 @@ def _prep_specs(b, n, h, c, dk, dv, nck, heads):
     flat = lambda *d: pl.BlockSpec(  # noqa: E731
         (heads, nck, *d), lambda i, j, l: (i * hb + j, l, 0, 0),
         memory_space=pltpu.VMEM)
-    ins = [wide(dk), wide(dk), wide(dv), wide(dk), flat(1, c)]
+    ins = [wide(dk), wide(dk), wide(dv),
+           flat(1, c) if head_gate else wide(dk), flat(1, c)]
     ops = [flat(c, dv), flat(c, dk), flat(c, dk), flat(c, c), flat(c, dk),
            flat(1, dk)]
     shapes = [(b * h, n, *spec.block_shape[2:]) for spec in ops]
@@ -678,25 +790,30 @@ def _prep_specs(b, n, h, c, dk, dv, nck, heads):
 
 
 def _prep_inputs(q, k, v, g, beta, n, c):
-    """[B, S, H, d] as [B, S, H d] (no copy); beta [B, S, H] as [B H, N,
-    1, C] (one small transpose)."""
+    """[B, S, H, d] as [B, S, H d] (no copy); beta [B, S, H], and a gate
+    a head, as [B H, N, 1, C] (one small transpose)."""
     b, s, h = beta.shape
     wide = lambda x: x.reshape(b, s, -1)  # noqa: E731
-    rows = jnp.moveaxis(beta, 2, 1).reshape(b * h, n, 1, c)
-    return wide(q), wide(k), wide(v), wide(g), rows
+    rows = lambda x: jnp.moveaxis(  # noqa: E731
+        x, 2, 1).reshape(b * h, n, 1, c)
+    beta = rows(beta)
+    return (wide(q), wide(k), wide(v),
+            rows(g) if g.ndim == 3 else wide(g), beta)
 
 
 def _prepare_forward(q, k, v, g, beta, chunk):
     b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
     _check_chip_shapes(chunk, dk, dv)
-    ins, ops, shapes = _prep_specs(b, n, h, chunk, dk, dv, nck, heads)
+    head_gate = g.ndim == 3
+    ins, ops, shapes = _prep_specs(b, n, h, chunk, dk, dv, nck, heads,
+                                   head_gate)
     f32, dt = jnp.float32, q.dtype
     out_shape = [jax.ShapeDtypeStruct(s, d) for s, d in zip(
         shapes, (f32, dt, dt, dt, dt, f32))]
     args = _prep_inputs(q, k, v, g, beta, n, chunk)
     call = pl.pallas_call(
         functools.partial(_prep_fwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv),
+                          dk=dk, dv=dv, head_gate=head_gate),
         grid=(b, h // heads, n // nck),
         in_specs=ins, out_specs=ops, out_shape=out_shape,
         compiler_params=_PREP_PARAMS,
@@ -712,14 +829,15 @@ def _prepare_forward(q, k, v, g, beta, chunk):
 
 def _prepare_backward(q, k, v, g, beta, cts, chunk):
     b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
-    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads)
+    head_gate = g.ndim == 3
+    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads, head_gate)
     args = _prep_inputs(q, k, v, g, beta, n, chunk)
     *mats, dshrink = cts
     cts = (*mats, dshrink.reshape(b * h, n, 1, dk))
     out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args]
     call = pl.pallas_call(
         functools.partial(_prep_bwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv),
+                          dk=dk, dv=dv, head_gate=head_gate),
         grid=(b, h // heads, n // nck),
         in_specs=ins + ops, out_specs=ins, out_shape=out_shape,
         compiler_params=_PREP_PARAMS,
@@ -731,9 +849,11 @@ def _prepare_backward(q, k, v, g, beta, cts, chunk):
     dq, dk_, dv_, dg, dbeta = _bind(
         call, "ds.kda_prep_bwd", ("kda_prep_bwd", chunk, nck, heads), *args,
         *cts)
-    dbeta = jnp.moveaxis(dbeta.reshape(b, h, n * chunk), 1, 2)
+    tokens = lambda x: jnp.moveaxis(  # noqa: E731
+        x.reshape(b, h, n * chunk), 1, 2)
+    dbeta = tokens(dbeta)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dg.reshape(g.shape), dbeta)
+            tokens(dg) if head_gate else dg.reshape(g.shape), dbeta)
 
 
 def _prep_cost(chunks, c, dk, dv, ins, outs, *, backward: bool):
@@ -813,8 +933,9 @@ def kda_prepare(q, k, v, g, beta, *, chunk: int):
     """The six operands of ``kda_recurrence``, each [B, H, N, C, .]
     (``shrink`` [B, H, N, dk]), from q, k [B, S, H, dk], v [B, S, H, dv]
     (the matmuls run in ``q``'s dtype), g [B, S, H, dk] and beta [B, S, H]
-    (float32 in the kernels). ``S`` must be a multiple of ``chunk`` and
-    ``chunk`` of ``SUB``."""
+    (float32 in the kernels), or a gate a HEAD, g [B, S, H] (the kernels
+    then build a chunk's decay as one [C, C] mask: ``_HeadChunk``). ``S``
+    must be a multiple of ``chunk`` and ``chunk`` of ``SUB``."""
     b, s, h, _ = q.shape
     if s % chunk or chunk % SUB:
         raise ValueError(

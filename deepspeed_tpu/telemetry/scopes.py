@@ -175,6 +175,25 @@ LOOP_SCOPES = (
     #                    gate, the exit distribution, its entropy, the
     #                    mixing of the exits' losses and the statistics
 )
+# what a stack of Gated DeltaNet and gated attention layers, each routed,
+# opens inside ds.layers in place of ds.attn (models/qwen3_next.py), beside
+# ds.kda_scan and its kernels' scopes, the three ds.moe_* and the two
+# grouped-matmul kernels' of KIND_SCOPES, MIXER_SCOPES and ds.rope of
+# WINDOW_SCOPES; ``tests/test_qwen3_next_engine.py`` holds the step to
+# them. The scan's time in THIS family is read by
+# ``ds\.gdn\b.*ds\.kda_scan``, the attention kernels' by
+# ``ds\.attn_gated\b.*ds\.flash_``
+GDN_SCOPES = (
+    "ds.gdn",          # models/qwen3_next.py _one_layer: a Gated DeltaNet
+    #                    layer's norm, projections, convolutions (ds.conv),
+    #                    beta and the gate a head (ds.mix_pre), the scan
+    #                    (ds.kda_scan), the gated norm (ds.mix_post), wo
+    "ds.attn_gated",   # the same of a gated attention layer: norm,
+    #                    projections, QK-norm, partial rotation (ds.rope),
+    #                    kernel, the output's sigmoid gate, wo
+    "ds.qk_norm",      # models/qwen3_next.py _attention: the (1 + w)
+    #                    RMSNorm of q and k a head, before the rotation
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

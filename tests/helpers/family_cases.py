@@ -1,6 +1,7 @@
 """What the test files of the Kimi-Linear and Granite families share
 (``tests/test_kimi_linear*.py``, ``tests/test_kda*_kernels.py``,
-``tests/test_granite_hybrid*.py``, ``tests/test_short_conv_step.py``): a
+``tests/test_granite_hybrid*.py``, ``tests/test_short_conv_step.py``,
+``tests/test_qwen3_next*.py``): a
 file is one worker's under ``--dist loadfile``, so each family's cases lie
 in several files and their fixtures and inputs here. Importing this puts
 ``benchmark/`` on ``sys.path`` (the references are ``architectures/``'s)."""
@@ -16,7 +17,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import GraniteHybrid, KimiLinear
+from deepspeed_tpu.models import GraniteHybrid, KimiLinear, Qwen3Next
 
 BENCH = pathlib.Path(__file__).resolve().parents[2] / "benchmark"
 if str(BENCH) not in sys.path:
@@ -116,3 +117,12 @@ def granite_weights(model, seed=3):
     return jax.tree_util.tree_map_with_path(
         lambda path, w: w * boost.get(path[-1].key, 1.0),
         model.init(jax.random.PRNGKey(seed)))
+
+
+# ---- Qwen3-Next ------------------------------------------------------------
+def qnext_tiny(**kw):
+    """32 of the tiny preset's 512 experts held, and the attention layer's
+    ``w_q`` / ``w_k`` from 2 as the benchmark's configuration sets them."""
+    kw.setdefault("moe_held_experts", 32)
+    kw.setdefault("qk_norm_init", 2.0)
+    return Qwen3Next(size="tiny", **kw)

@@ -176,7 +176,8 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
                                | set(scopes.SSM_SCOPES)
                                | set(scopes.MIXER_SCOPES)
                                | set(scopes.WINDOW_SCOPES)
-                               | set(scopes.LOOP_SCOPES))
+                               | set(scopes.LOOP_SCOPES)
+                               | set(scopes.GDN_SCOPES))
 
     telemetry.shutdown()
     try:

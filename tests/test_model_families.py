@@ -320,6 +320,12 @@ PARENT_COUNTS = {
     "mellum/tiny": (1753664, 377408, 2296512.0, 2854272),
     "mellum/12b-a2.5b": (12149915904, 2439053568, 36876957120.0,
                          193663993344),
+    # PR 46's own, pinned when the family came (ISSUE 46's 625,667,136)
+    "qwen3_next/tiny": (12890056, 552904, 3275184.0, 3372720),
+    "qwen3_next/80b-a3b": (79674391296, 3874929408, 99032031744.0,
+                           176341148160),
+    "cell/qwen3-next-80b-ep16-zero3-1chip": (
+        625667136, 230878272, 1582885248.0, 1985513856),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -385,6 +391,8 @@ def test_counts_are_the_parents(case):
     ("kimi_linear", dict(mamba_n_heads=4)),
     ("granite_hybrid", dict(kda_head_groups=4)),
     ("mellum", dict(kda_head_groups=4)),
+    ("qwen3_next", dict(kda_head_groups=4)),
+    ("kimi_linear", dict(qk_norm_init=2.0)),
     ("ouro", dict(layer_types=["attention", "attention"])),
     ("mistral", dict(total_ut_steps=4)),
     ("mixtral", dict(moe_held_experts=2)),

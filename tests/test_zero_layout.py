@@ -256,13 +256,16 @@ def test_kda_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
         jax.jit(jax.grad(loss(kda.chunk_kda))).lower(*args).compile()
 
 
-def test_kda_preparation_kernels_compile_for_v5e(monkeypatch):
-    """The preparation's kernel pair (PR 35) at the cell's widths (one
+@pytest.mark.parametrize("gate", ["a_channel", "a_head"])
+def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
+    """The preparation's kernel pair (PR 35) at the cells' widths (one
     sequence of 16384, a head group of 8 heads of 128, bf16 with float32
     decays), compiled by Mosaic for one described v5e chip: the float32
     products of the inverse, the transposed products of the backward and
     the blocks of the model's [B, S, H d] layout are what interpret mode
-    cannot refuse. Per shard on ``v5e:2x2`` they compile in the test above."""
+    cannot refuse. Per shard on ``v5e:2x2`` they compile in the test above.
+    ``a_head`` (PR 46): Gated DeltaNet's gate, rows as beta's, at all 32
+    value heads of its cell: the [C, 1] columns, the [C, C] mask."""
     import re
 
     from jax.experimental import topologies
@@ -277,10 +280,12 @@ def test_kda_preparation_kernels_compile_for_v5e(monkeypatch):
     bf, f32 = jnp.bfloat16, jnp.float32
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
-    b, s, h, d, c = 1, 16384, 8, 128, kernels.CHUNK
+    b, s, h, d, c = 1, 16384, 8 if gate == "a_channel" else 32, 128, \
+        kernels.CHUNK
     n = s // c
     wide = (b, s, h, d)
-    ins = (sd(wide, bf), sd(wide, bf), sd(wide, bf), sd(wide, f32),
+    ins = (sd(wide, bf), sd(wide, bf), sd(wide, bf),
+           sd(wide if gate == "a_channel" else wide[:3], f32),
            sd(wide[:3], f32))
     cts = (sd((b * h, n, c, d), f32), sd((b * h, n, c, d), bf),
            sd((b * h, n, c, d), bf), sd((b * h, n, c, c), bf),
