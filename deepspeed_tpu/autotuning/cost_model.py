@@ -41,8 +41,12 @@ GRAD_BYTES = 4         # grads accumulate in fp32 (engine _step_parts accumulate
 # per-layer live-activation multiplier by remat policy: how many
 # [micro_batch, seq, hidden]-sized residuals each layer keeps across the
 # backward. Full recompute keeps only the layer-boundary residual; the
-# save-more policies keep attention/MLP intermediates too. Coarse by
-# design — audited against ledger memory_analysis(), not derived from it.
+# save-more policies keep attention/MLP intermediates too. (Under every
+# policy an attention layer on attn_impl="flash" keeps its output as well,
+# one more such tensor: this table knows neither the attention
+# implementation nor a stack's kinds, and leaves it to `fits`' safety
+# factor.) Coarse by design — audited against ledger memory_analysis(), not
+# derived from it.
 REMAT_ACTIVATION_FACTOR = {
     "nothing_saveable": 1.0,
     "segments": 2.0,                       # attention residuals kept

@@ -70,7 +70,10 @@ class ModelConfig:
     #                           under grad each chunk's dX and dW are made
     #                           in the forward scan, nothing is recomputed
     remat: bool = True
-    # jax.checkpoint_policies name; "nothing_saveable" = full recompute
+    # jax.checkpoint_policies name, "save_attn_ffn" or "segments";
+    # "nothing_saveable" = a layer recomputed whole but for what a kernel
+    # declares kept (the flash kernel's output and row log-sum-exp:
+    # models/transformer.py _remat_policy)
     remat_policy: str = "nothing_saveable"
     attn_impl: str = "reference"  # reference | flash
 

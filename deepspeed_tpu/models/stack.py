@@ -167,9 +167,11 @@ class StackOfKinds(DecoderLM):
 
     def _layer(self, p, x, mixers, scanned: bool):
         """One layer of the kind its keys name, as (x, counts); rematted
-        whole. An unrolled layer's checkpoint has to prevent CSE, or XLA
-        merges the recomputation with the forward pass and keeps every
-        intermediate alive; under the scan the loop boundary does that."""
+        whole, but for the residuals a kernel declares kept
+        (``_remat_policy``). An unrolled layer's checkpoint has to prevent
+        CSE, or XLA merges the recomputation with the forward pass and
+        keeps every intermediate alive; under the scan the loop boundary
+        does that."""
         c = self.config
         layer = lambda p, x: self._one_layer(p, x, mixers)  # noqa: E731
         if not c.remat:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
+from ..ops.pallas._common import KEPT_RESIDUAL
 from ..parallel.mesh import constrain_free
 from .base import Model, ModelConfig, Rules
 
@@ -281,10 +282,12 @@ class DecoderLM:
 
     def _block_segmented(self, p, x, attn_fn, positions):
         """Segment remat: attention sits OUTSIDE any jax.checkpoint, so
-        its custom-VJP residuals (q, k, v, o, lse) are stored and the
-        backward never re-runs the forward flash kernel (custom_vjp under
-        remat re-executes its fwd rule — measured ~2ms/layer on v5e at
-        GPT-2 shapes). The projections around it are rematted in two
+        all five of its custom-VJP residuals (q, k, v, o, lse) are stored
+        and the backward never re-runs the forward flash kernel, nor the
+        projections that make q, k, v. (A whole-layer checkpoint stores
+        `o` and `lse` alone, which the kernel's forward rule declares
+        kept, `_remat_policy`: it skips the kernel's rerun too, and makes
+        q, k, v again.) The projections around it are rematted in two
         segments:
 
         - seg_qkv (norm + qkv projection): saves nothing internally; its
@@ -294,8 +297,9 @@ class DecoderLM:
           and the activation function — no matmul re-runs.
 
         Net per-layer saves at [B=24, S=1024, D=768]: ~378MB vs ~302MB
-        for "save_attn_ffn", in exchange for skipping the flash rerun and
-        the attn-proj + up-matmul recomputes (~3.5ms/layer on v5e).
+        for "save_attn_ffn", in exchange for skipping the qkv, attn-proj
+        and up-matmul recomputes (~3.5ms/layer on v5e with the flash
+        rerun, which no policy pays any more).
         """
         c = self.config
         from jax.ad_checkpoint import checkpoint_name
@@ -537,7 +541,7 @@ class DecoderLM:
         if c.remat and c.remat_policy != "segments":
             # "segments" applies selective checkpoints INSIDE block()
             # (attention outside remat); wrapping the whole body here
-            # would re-introduce the flash fwd rerun it exists to avoid
+            # would rerun the projections it exists to keep
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=_remat_policy(c.remat_policy))
         (x, aux), _ = jax.lax.scan(
@@ -580,21 +584,24 @@ class DecoderLM:
 
 
 def _remat_policy(name: str):
-    """Map a config policy name to a jax.checkpoint policy. Besides the
-    stock jax.checkpoint_policies names, ``save_attn_ffn`` saves the
-    O(S)-sized per-layer tensors named "qkv"/"attn_out"/"ffn" (both the
-    reference attention and the flash wrapper name their outputs) —
-    backward then recomputes only norms and the O(S^2) attention scores,
-    usually the best single-chip throughput point."""
+    """Map a config policy name to a jax.checkpoint policy. Every policy
+    keeps what a kernel's forward rule declares (`KEPT_RESIDUAL`: the
+    flash kernel's output and row log-sum-exp, O(S) bytes whose rerun is
+    the O(S^2) kernel), so ``nothing_saveable`` keeps that and nothing
+    else: the backward reruns a layer's norms, projections and MLP, not
+    its attention kernel. Besides the stock jax.checkpoint_policies
+    names, ``save_attn_ffn`` saves the O(S)-sized per-layer tensors named
+    "qkv"/"attn_out"/"ffn" (both the reference attention and the flash
+    wrapper name their outputs) — backward then recomputes only norms
+    and, with the reference attention, the O(S^2) scores; usually the
+    best single-chip throughput point."""
+    names = jax.checkpoint_policies.save_only_these_names
     if name == "nothing_saveable":
-        return None
+        return names(KEPT_RESIDUAL)
     if name == "save_attn_ffn":
-        # save the O(S)-sized per-layer tensors (qkv, attention output,
-        # ffn hidden); backward recomputes only norms and the O(S^2)
-        # attention scores — the usual best single-chip throughput point
-        return jax.checkpoint_policies.save_only_these_names(
-            "qkv", "attn_out", "ffn")
-    return getattr(jax.checkpoint_policies, name)
+        return names("qkv", "attn_out", "ffn", KEPT_RESIDUAL)
+    return jax.checkpoint_policies.save_from_both_policies(
+        getattr(jax.checkpoint_policies, name), names(KEPT_RESIDUAL))
 
 
 def _unpack_batch(batch):

@@ -1,12 +1,21 @@
 """What the kernel modules of this package share: the interpret-mode gate,
 the float32 product, the bf16 pieces of a float32 value, a byte count for
-``cost_estimate`` and the once-a-shape trace of a ``pallas_call``."""
+``cost_estimate``, the once-a-shape trace of a ``pallas_call`` and the
+name under which a kernel's forward rule declares the residuals a rematted
+region keeps."""
 
 from __future__ import annotations
 
 import jax
 import jax.extend
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+# What a kernel pair's forward rule passes through `_keep` stays alive
+# across a `jax.checkpoint` whose policy comes from
+# models/transformer.py `_remat_policy`: every policy it returns saves this
+# name. For residuals of O(S) bytes whose rerun is O(S^2) work.
+KEPT_RESIDUAL = "kernel_kept"
 
 
 def _interpret() -> bool:
@@ -29,6 +38,23 @@ def _pieces(v, dt):
 
 def _nbytes(*arrays):
     return sum(x.size * jnp.dtype(x.dtype).itemsize for x in arrays)
+
+
+def _keep(kernel: str, *arrays):
+    """``arrays`` named `KEPT_RESIDUAL`: a forward rule returns them both
+    as its output and inside its residuals, and a rematted region's
+    backward then reads them back where it would have rerun the kernel.
+    Outside a ``jax.checkpoint`` the name does nothing. Trace time, host
+    only: gauge ``ds_kernel_kept_bytes`` says what ONE call declares."""
+    from ...utils.telemetry_probe import active_telemetry
+    tel = active_telemetry()
+    reg = tel.get_registry() if tel is not None else None
+    if reg is not None:
+        reg.gauge("ds_kernel_kept_bytes",
+                  "bytes of the residuals one call of the kernel last "
+                  "traced declares kept across a rematted region"
+                  ).set(_nbytes(*arrays), kernel=kernel)
+    return tuple(checkpoint_name(x, KEPT_RESIDUAL) for x in arrays)
 
 
 _TRACED: dict = {}

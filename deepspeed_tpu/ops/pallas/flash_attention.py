@@ -67,7 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import _interpret
+from ._common import _interpret, _keep
 
 NEG_INF = -1e30
 _LANES = 128          # a vector register's minor dimension
@@ -402,6 +402,10 @@ def _flash_fwd_rule(q, k, v, causal, window, rep):
     sc = 1.0 / np.sqrt(q.shape[-1])
     o, lse = _flash_fwd(q, k, v, causal=causal, sc=sc, window=window,
                         rep=rep)
+    # O(S) bytes that cost O(S^2) work to make again: a rematted layer
+    # keeps them, and its backward reruns the projections for q, k, v but
+    # not this kernel
+    o, lse = _keep("flash", o, lse)
     return o, (q, k, v, o, lse)
 
 
@@ -420,8 +424,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """Drop-in attn_fn: q [B, S, Hq, D], k/v [B, S, Hkv, D], matches
     ops.layers.dot_product_attention numerics. GQA is native: the
     kernels index the shared kv head per q-head group, so repeated k/v
-    are never materialized (and remat residuals store unrepeated k/v —
-    rep x smaller than the repeat-then-attend form). ``window``
+    are never materialized (and the custom-VJP residuals hold unrepeated
+    k/v — rep x smaller than the repeat-then-attend form; under a
+    whole-layer ``jax.checkpoint`` only the output and the row
+    log-sum-exp are stored, and the forward kernel is not rerun).
+    ``window``
     restricts each query to its last `window` positions (Mistral sliding
     window; kernel skips blocks fully outside the band).
 
@@ -495,8 +502,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
             o.transpose(0, 2, 1, 3).astype(q.dtype), "attn_out")
     # GQA-native: k/v stay per-kv-head ([B*Hkv, S, D]); the kernels index
     # kv rows at q_head_idx // rep, so repeated k/v are never
-    # materialized — and the custom-VJP residuals (what remat stores per
-    # layer) hold the UNREPEATED k/v
+    # materialized — and the custom-VJP residuals hold the UNREPEATED k/v
+    # (of the five, a rematted layer stores `o` and `lse`,
+    # `_flash_fwd_rule`, and makes q, k, v again)
     to_bh = lambda x: bhsd(x).reshape(-1, s, x.shape[-1])  # noqa: E731
     o = _flash(to_bh(q), to_bh(k), to_bh(v), causal, window, rep)
     return checkpoint_name(

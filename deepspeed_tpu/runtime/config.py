@@ -129,14 +129,20 @@ class ActivationCheckpointingConfig(DeepSpeedConfigModel):
     ``policy`` is set EXPLICITLY the engine plumbs it into the model's
     ``remat_policy`` (``"none"`` disables remat entirely) — the knob
     the autotuning planner's chosen plan patches, so a plan ``apply()``
-    reproduces the remat decision through config alone."""
+    reproduces the remat decision through config alone. Under every
+    policy a rematted layer also keeps what a kernel's forward rule
+    declares (the flash kernel's output and row log-sum-exp, one
+    layer-boundary-sized tensor an attention layer): see
+    ``models/transformer.py`` ``_remat_policy``."""
     partition_activations: bool = False
     cpu_checkpointing: bool = False
     contiguous_memory_optimization: bool = False
     number_checkpoints: Optional[int] = None
     synchronize_checkpoint_boundary: bool = False
     profile: bool = False
-    # TPU-native: jax.checkpoint policy name ("none" = remat off)
+    # TPU-native: jax.checkpoint policy name ("none" = remat off;
+    # "nothing_saveable" = whole-layer recompute but for kernels' declared
+    # residuals)
     policy: str = "nothing_saveable"
 
 

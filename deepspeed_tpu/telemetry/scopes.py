@@ -153,7 +153,8 @@ MIXER_SCOPES = (
 # ds.moe_router and ds.moe_experts of KIND_SCOPES; ``tests/test_mellum.py``
 # holds this list equal to what that model's step carries. The kernels'
 # ds.flash_fwd / ds.flash_bwd lie inside the scope of their layer's kind in
-# the forward, in remat's rerun and in the backward rule, so one kind's
+# the forward and in the backward rule (remat keeps the forward kernel's
+# declared residuals and does not rerun it), so one kind's
 # kernel time is read by ``ds\.attn_swa\b.*ds\.flash_``
 WINDOW_SCOPES = (
     "ds.attn_swa",     # models/mellum.py _one_layer: a sliding_attention

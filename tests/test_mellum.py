@@ -534,8 +534,10 @@ def test_traced_and_untraced_steps_are_one_program_and_the_counts_land(
 def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
         mellum_engine):
     """Both kernels' scopes lie inside the scope of their layer's kind in
-    the forward, in remat's rerun and in the backward rule, so one kind's
-    kernel time can be read alone; the rotation is named inside both."""
+    the forward and in the backward rule, so one kind's kernel time can be
+    read alone; the rotation is named inside both, and in remat's rerun,
+    which holds no forward kernel (PR 47: the layer keeps its ``o`` and
+    ``lse``)."""
     engine, batch = mellum_engine
     hlo = engine._train_step.lower(
         engine.state, engine._put_batch(batch)).compile().as_text()
@@ -550,11 +552,11 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
     paths = {row["scope"] for row in work.values() if row["scope"]}
     for kind in ("swa", "full"):
         for want in (f"fwd:ds.layers/ds.attn_{kind}/ds.flash_fwd",
-                     f"bwd:ds.layers/ds.attn_{kind}/ds.flash_fwd",
                      f"bwd:ds.layers/ds.attn_{kind}/ds.flash_bwd",
                      f"fwd:ds.layers/ds.attn_{kind}/ds.rope",
                      f"bwd:ds.layers/ds.attn_{kind}/ds.rope"):
             assert want in paths, want
+        assert f"bwd:ds.layers/ds.attn_{kind}/ds.flash_fwd" not in paths
     kernels = [p for p in paths if "ds.flash_" in p]
     assert all(re.search(r"ds\.attn_(swa|full)/ds\.flash_", p)
                for p in kernels), kernels
@@ -582,31 +584,51 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
 # routed layer behind a KDA and an MLA mixer; a Mamba and an attention layer);
 # beside each the sha256 of its lowered train step and the sum of its seeded
 # master weights' magnitudes AT THE PARENT (commit 2d920a0, this file's
-# `_step_text` run on that checkout). `granite_hybrid`'s hash is PR 43's:
-# the mixers' short convolution, SiLU and l2 norms are the kernel pair of
-# `ops/pallas/short_conv.py` since. `kimi_linear`'s is PR 44's: its KDA
-# preparation takes the inverse's float32 products two heads to a product
-# (`ops/pallas/kda.py` `_pdot`; its routed layers sweep their held experts
-# through the grouped-matmul kernels since PR 41). No `mistral` step holds
-# either: `mistral`'s hash is still that parent's, and all three families'
-# weights are
+# `_step_text` run on that checkout). All three families' weights are still
+# that parent's. The hashes are PR 47's: a rematted layer now keeps the
+# flash kernel's `o` and `lse` (`ops/pallas/_common.py` `KEPT_RESIDUAL`,
+# `models/transformer.py` `_remat_policy`), so `kimi_linear`'s and
+# `granite_hybrid`'s steps under `nothing_saveable` hold `ds_flash_fwd`
+# once a layer and were taken again from PR 47's tree (before it:
+# `granite_hybrid`'s PR 43's, the short convolution's kernel pair;
+# `kimi_linear`'s PR 44's, two heads to a product in the preparation).
+# `mistral` runs `remat_policy="segments"`, attention outside every
+# `jax.checkpoint`, where the name does nothing: its hash was taken AT PR
+# 47'S PARENT (commit f3a203d, `_renumbered(_step_text(...))` on that
+# checkout) and holds in PR 47's tree, so the Mistral cells run the
+# parent's program. It is the hash of the text with its symbols
+# renumbered, because the raw text's (3f2beba9... at that parent) cannot
+# hold: JAX lowers every distinct equation as a private function named
+# after its primitive and inlines it, the two new `name` equations are two
+# more such functions, and MLIR's symbol table numbers every LATER
+# collision two higher (`@closed_call_168` is `@closed_call_170`, eleven
+# such names and no other character of 3501 lines).
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "8a8e03b8d412e8bc25fc8d4ae9b4f228d011094564c5c0c1efc8417495c5cc4c",
+        "befd1c7a5551d01cb15c271d83a92f70a5f5f18f417f6c1051a4f739596cf776",
         7191.956369750438),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
         loss_chunk=64),
-        "b940718f5df138fc1d1327ba1f78afa25b97108cc9eebb2d64d64d336ae38512",
+        "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
         2422.812915172007),
     "mistral": (Mistral, dict(attn_impl="flash", loss_chunk=64,
                               remat_policy="segments", sliding_window=64),
-                "3f2beba942a14e42f5e09dc9ebc97e50bff56d0146e40c11cab5aac4d6e887b4",
+                "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
                 2339.9930015786545),
 }
+
+
+def _renumbered(text: str) -> str:
+    """``text`` with every symbol (``@name``) renamed by the order of its
+    first appearance: what is left is the program, whatever numbers MLIR's
+    symbol table gave the private functions' names."""
+    table = {}
+    return re.sub(r"@[\w.]+", lambda m: table.setdefault(
+        m.group(0), f"@f{len(table)}"), text)
 
 
 def _step_text(family: str):
@@ -632,5 +654,6 @@ def test_the_other_families_steps_are_the_parents_programs(family):
     text, weights = _step_text(family)
     _, _, parent_text, parent_weights = _FAMILIES[family]
     assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == parent_text
+    assert hashlib.sha256(
+        _renumbered(text).encode()).hexdigest() == parent_text
     assert weights == parent_weights

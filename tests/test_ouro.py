@@ -380,8 +380,9 @@ def test_traced_and_untraced_steps_are_one_program_and_the_gauges_land(
 
 def test_step_scopes_are_the_lists(ouro_engine):
     """ds.loop inside ds.layers with ds.attn / ds.mlp (and the kernels)
-    inside it, forward and backward; ds.exit_gate inside ds.loss_head; no
-    op of a kind the table does not know."""
+    inside it, forward and backward; remat's rerun holds no forward flash
+    kernel (PR 47: a layer keeps its ``o`` and ``lse``); ds.exit_gate
+    inside ds.loss_head; no op of a kind the table does not know."""
     engine, batch = ouro_engine
     hlo = engine._train_step.lower(
         engine.state, engine._put_batch(batch)).compile().as_text()
@@ -391,8 +392,8 @@ def test_step_scopes_are_the_lists(ouro_engine):
     assert found == set(scopes.DEVICE_SCOPES) | set(scopes.LOOP_SCOPES)
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values() if row["scope"]}
+    assert "bwd:ds.layers/ds.loop/ds.attn/ds.flash_fwd" not in paths
     for want in ("fwd:ds.layers/ds.loop/ds.attn/ds.flash_fwd",
-                 "bwd:ds.layers/ds.loop/ds.attn/ds.flash_fwd",
                  "bwd:ds.layers/ds.loop/ds.attn/ds.flash_bwd",
                  "fwd:ds.layers/ds.loop/ds.mlp",
                  "bwd:ds.layers/ds.loop/ds.mlp",
@@ -437,7 +438,11 @@ def test_mellums_step_is_the_parents_program():
     ``mistral``, ``kimi_linear`` and ``granite_hybrid`` to their parents'
     (unchanged here: this PR's edits lie on all their paths, the head, the
     engine's feed and ``ModelConfig`` among them); this holds the fourth,
-    ``mellum``, to the text and the seeded weights of commit 9909adf."""
+    ``mellum``, to the seeded weights of commit 9909adf. The text's hash
+    is PR 47's, taken from its tree: Mellum's layers are rematted whole
+    under ``nothing_saveable``, which since PR 47 keeps the flash kernel's
+    ``o`` and ``lse`` (``ops/pallas/_common.py`` ``KEPT_RESIDUAL``), so the
+    step holds ``ds_flash_fwd`` once an attention layer and not twice."""
     model = Mellum(size="tiny", moe_held_experts=16, attn_impl="flash",
                    loss_chunk=64)
     engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
@@ -447,6 +452,6 @@ def test_mellums_step_is_the_parents_program():
     leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "a5b78e07e5a4b70637e52deb768992f36b025674c943ac46ea63238ac694baa5")
+        "3e8dc16c223bbdea97ec128ddefa28d73ccdecbdb32db249564bf9fec46c4c96")
     assert float(sum(np.abs(x.astype(np.float64)).sum()
                      for x in leaves)) == 36510.69588080405

@@ -123,22 +123,28 @@ def test_step_scopes_are_the_lists_and_each_kernel_lies_in_its_layer(
 # AT THE PARENT (commit 142c688, this file's `_step_text` run on that
 # checkout): what this PR edits lies on their paths too (`ops/kda.py`
 # `chunk_kda` and `kda_prepare`, `moe_ffn_held`'s entry, the scope lists).
+# All five hashes are PR 47's, taken again from its tree: every one of these
+# steps runs under `nothing_saveable` (`mistral` here at its default policy;
+# the `segments` step the Mistral cells run is held to the parent's in
+# `tests/test_mellum.py`), which since PR 47 keeps the flash kernel's `o`
+# and `lse` (`ops/pallas/_common.py` `KEPT_RESIDUAL`, `_remat_policy`): a
+# step holds `ds_flash_fwd` once an attention layer and not twice.
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64, kda_head_groups=2), "8a8e03b8d412e8bc25fc8d4ae9b4f228d011094564c5c0c1efc8417495c5cc4c"),
+        loss_chunk=64, kda_head_groups=2), "277841b46c3b8a472406d695a32f612feba19bee865cfe78ab30a0ca2a6121dd"),
     "mellum": (Mellum, dict(
         num_layers=2, layer_types=["sliding_attention", "full_attention"],
         moe_held_experts=16, attn_impl="flash", loss_chunk=64),
-        "7213edb55376bb738168dc7335e91f8c18e32a5adf7cd4c96cc8b231ef1f66b7"),
+        "715346728a9dc71ea00b3136461553874b23578a46fdf66c830721421ae19af3"),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
-        loss_chunk=64), "b940718f5df138fc1d1327ba1f78afa25b97108cc9eebb2d64d64d336ae38512"),
+        loss_chunk=64), "b577bff512e4102d37f268264abfca574a8a597317e25ad4b7c7d7b427ca3775"),
     "ouro": (Ouro, dict(num_layers=2, attn_impl="flash", loss_chunk=64),
-             "e53e57611fc1ceabfa7b6a02c199f680d417f484bac5a89a617de777f50279d2"),
+             "2dbcb2addc2f251025db4b1d77ddf3a90d00956b868171db4bc7ffb28ac2c2a6"),
     "mistral": (Mistral, dict(attn_impl="flash", loss_chunk=64,
-                              sliding_window=64), "90f089fa3e2a7df4538acf9b3e6bda35cca946234ada0c1426772767b29e959f"),
+                              sliding_window=64), "4338c9cbb6e43adf61bfebcf0927a6353f3df99e1f17314838011a5c8baa2901"),
 }
 
 
