@@ -330,37 +330,39 @@ def _held_tiles(layout, tile: int, m: int):
             grouped_matmul.tile_tables(e.astype(jnp.int32), live, at > 0, m))
 
 
-def _add_rows(acc, index, rows, valid):
-    """``acc[index[r]] += rows[r]`` over a chunk's valid rows, ONE add a
-    chunk. XLA sorts the indices, brings the rows into that order and,
-    past some 8k rows, walks ``acc`` once through VMEM: milliseconds A
-    CALL for a float32 [16384, 2304] whatever the rows (``PERF.md``
-    section 6, PR 41, has the chip's figures). An add a block of one
-    expert's rows pays that walk for a sixteenth of the rows, told or not
-    that its indices are sorted and distinct: most of what the block loop
-    this replaced cost. The rows that are no expert's get an index past
-    ``acc``'s end and are dropped: their tile may not have run."""
-    return acc.at[jnp.where(valid, index, acc.shape[0])].add(
-        rows.astype(acc.dtype), mode="drop")
+def _held_sweep(idx, weights, first, n_held, block, chunk, shape, body,
+                carry):
+    """The sum over tokens, float32 ``shape`` = [N, D], of what
+    ``body(rows, tokens, valid, scale, tables, tile, carry)`` gives for
+    each chunk of the rows routed to the held experts (``scale`` [C, 1]: a
+    row's routing weight, 0 where it is no expert's): ``body`` returns the
+    chunk's float32 rows [C, D] in expert order and its own ``carry``.
+    ``chunk`` rows in whole row tiles a chunk: as many chunks as this
+    batch's routing needs (a loop whose trip count is data;
+    ``held_chunk``: ONE where no more rows are held than a balanced router
+    sends). An expert's run is padded to ROW TILES here
+    (``grouped_matmul.row_tile``: it divides the block, the unit the
+    callers count the padding by), so that a chunk holds no tile without a
+    live row but its last ones.
 
-
-def _held_sweep(idx, weights, first, n_held, block, chunk, body, carry):
-    """``body(rows, tokens, valid, scale, tables, tile, carry)`` over the
-    chunks of the rows routed to the held experts (``scale`` [C, 1]: a
-    row's routing weight, 0 where it is no expert's), ``chunk`` rows in
-    whole row tiles each: as many chunks as this batch's routing needs (a
-    loop whose trip count is data; ``held_chunk``: ONE where no more rows
-    are held than a balanced router sends). An expert's run is padded to
-    ROW TILES here (``grouped_matmul.row_tile``: it divides the block, the
-    unit the callers count the padding by), so that a chunk holds no tile
-    without a live row but its last ones."""
-    k = idx.shape[1]
+    A chunk's rows reach their tokens without a scatter-add (XLA's walks
+    the whole float32 [N, D] operand through VMEM a call, and sorts the
+    rows first: 4.4 ms at 36,864 rows of 2304; ``PERF.md`` section 6, PRs
+    41 and 48): the chunk's row ids are sorted once more, by token (int32
+    pairs), ONE gather brings the float32 rows into that order, the rows
+    that are no expert's last (they name the chunk's row 0, which ran: a
+    dead tile's rows are not written and may hold anything), and
+    ``grouped_matmul.add_rows`` sums each token's run into the
+    accumulator, writing each tile of it once. The first chunk's call is
+    told the accumulator is zeros."""
+    n, k = idx.shape
     tile = grouped_matmul.row_tile(block)
     m = -(-(chunk or n_held * block) // tile)
     layout = _held_layout(idx, weights, first, n_held, tile)
     run, tables = _held_tiles(layout, tile, m)
 
     def one(c, carry):
+        acc, carry = carry
         # the chunk's row ids and routing weights: a slice of the layout
         # a tile, cut here for the chunk's tiles and not before the loop
         # for every tile the layout can hold (PERF.md section 6, PR 41)
@@ -371,25 +373,30 @@ def _held_sweep(idx, weights, first, n_held, block, chunk, body, carry):
         valid = (jnp.arange(tile)[None, :] < mine[2][:, None]).reshape(-1)
         rows = cut(layout[0]).reshape(-1)
         scale = jnp.where(valid, cut(layout[4]).reshape(-1), 0.0)
-        return body(rows, rows // k, valid, scale[:, None], mine, tile,
-                    carry)
+        ys, carry = body(rows, rows // k, valid, scale[:, None], mine, tile,
+                         carry)
+        by_token, at = lax.sort(
+            (jnp.where(valid, rows, n * k),
+             jnp.arange(rows.shape[0], dtype=jnp.int32)), num_keys=1)
+        held = by_token < n * k
+        acc = grouped_matmul.add_rows(
+            acc, ys[jnp.where(held, at, 0)],
+            jnp.where(held, by_token // k, n), c == 0)
+        return acc, carry
 
-    return lax.fori_loop(0, (layout[3][-1] + m - 1) // m, one, carry)
+    return lax.fori_loop(0, (layout[3][-1] + m - 1) // m, one,
+                         (jnp.zeros(shape, jnp.float32), carry))
 
 
 def _held_forward(x, idx, weights, experts, first, block, chunk):
-    n, d = x.shape
     n_held = experts["w_up"].shape[0]
 
-    def body(rows, tokens, valid, scale, tables, tile, carry):
-        out, done = carry
-        y = grouped_matmul.forward(x[tokens], scale, tables[:3], experts,
-                                   tile)
-        return _add_rows(out, tokens, y, valid), done + jnp.sum(valid)
+    def body(rows, tokens, valid, scale, tables, tile, done):
+        return (grouped_matmul.forward(x[tokens], scale, tables[:3], experts,
+                                       tile), done + jnp.sum(valid))
 
-    out, done = _held_sweep(
-        idx, weights, first, n_held, block, chunk, body,
-        (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32)))
+    out, done = _held_sweep(idx, weights, first, n_held, block, chunk,
+                            x.shape, body, jnp.zeros((), jnp.int32))
     return out.astype(x.dtype), done
 
 
@@ -433,27 +440,26 @@ def _held_fwd_rule(x, idx, weights, experts, first, block, router_grad,
 def _held_bwd_rule(first, block, router_grad, chunk, res, cts):
     x, idx, weights, experts = res
     dout = cts[0]
-    n, d = x.shape
-    k = idx.shape[1]
+    n, k = idx.shape
     f32 = jnp.float32
     names = ("w_gate", "w_up", "w_down")
     # opened here: a custom_vjp's backward function is traced outside the
     # scope its forward was called under
     with jax.named_scope("ds.moe_experts"):
         def body(rows, tokens, valid, scale, tables, tile, carry):
-            dx, dw, sums = carry
+            dw, sums = carry
             dxs, dwt, sums = grouped_matmul.backward(
                 x[tokens], dout[tokens], scale, tables, experts, sums, tile,
                 router_grad)
-            dx = _add_rows(dx, tokens, dxs, valid)
-            if router_grad:
-                dw = _add_rows(dw, rows, dwt[:, 0], valid)
-            return dx, dw, sums
+            if router_grad:     # a row a choice: distinct, nothing summed
+                dw = dw.at[jnp.where(valid, rows, n * k)].add(
+                    dwt[:, 0], mode="drop")
+            return dxs, (dw, sums)
 
         n_held = experts["w_up"].shape[0]
-        dx, dw, sums = _held_sweep(
-            idx, weights, first, n_held, block, chunk, body,
-            (jnp.zeros((n, d), f32), jnp.zeros((n * k,), f32),
+        dx, (dw, sums) = _held_sweep(
+            idx, weights, first, n_held, block, chunk, x.shape, body,
+            (jnp.zeros((n * k,), f32),
              [jnp.zeros(experts[name].shape, f32) for name in names]))
     d_experts = {name: s.astype(experts[name].dtype)
                  for name, s in zip(names, sums)}
@@ -505,15 +511,18 @@ def held_chunk(tokens: int, k: int, n_experts: int, n_held: int,
     = 36,864; the Kimi cell: 4096 + 8 x 256 = 6144). The runs are padded
     to row tiles, so a chunk of that size holds ANY split of the even
     total between the held experts, however skewed. A chunk costs its
-    gathers and its adds to tokens by the rows it HOLDS, live or not
-    (``_add_rows``: a walk of the float32 [N, D] carry a call), and its
-    kernels by the row tiles that hold a live row: so one chunk a sweep
-    is what a share that is sent its even total should pay, and no more
-    rows than that. A share that is sent MORE than its even total (and
-    the ends of its runs) takes a second chunk: its gathers and adds
-    again for the tiles left over (``PERF.md`` section 6, PR 41,
-    ``mellum_over`` of ``tools/moe_kernel_bench.py``: 5.4 + 7.5 ms a
-    layer at the Mellum shape), and no more memory: the loop's body holds
+    gathers by the rows it HOLDS, live or not (its rows of ``x``, of the
+    cotangent, and of its float32 results into token order: 36 to 44 ns a
+    row from HBM), its add to tokens by those rows and the tiles of the
+    accumulator they reach (``grouped_matmul.add_rows``: no walk of the
+    float32 [N, D] a call), and its matmul kernels by the row tiles that
+    hold a live row: so one chunk a sweep is what a share that is sent
+    its even total should pay, and no more rows than that. A share that
+    is sent MORE than its even total (and the ends of its runs) takes a
+    second chunk: its gathers and its add again for the tiles left over
+    (``PERF.md`` section 6, PR 48, ``mellum_over`` of
+    ``tools/moe_kernel_bench.py``: 3.7 + 5.7 ms a layer at the Mellum
+    shape), and no more memory: the loop's body holds
     one chunk's temporaries whatever its trips."""
     return (math.ceil(n_held * tokens * k / n_experts)
             + n_held * grouped_matmul.row_tile(block))

@@ -30,6 +30,17 @@ changes. The outputs alias the float32 carries the caller's loop holds: an
 expert the chunk does not reach keeps what it had, and one that began in
 an earlier chunk takes its sums up from the carry by one copy.
 
+``ds_moe_add_rows`` (``add_rows``): the add of a chunk's float32 rows to
+their tokens. The rows come in TOKEN order (the caller's one gather), so a
+token's rows are consecutive; the grid walks the pairs (tile of tokens, row
+tile) that meet, from tables as above (``add_tables``), and a pair's
+segment sum is an exact 0/1 product on the MXU: ``onehot[T, R] @ rows[R,
+D]`` with the float32 rows in three bf16 pieces and float32 sums. A tile
+of the float32 ``[N, D]`` carry stays in VMEM while its pairs pass and is
+written ONCE; the output aliases the carry, so a tile that no row of the
+chunk reaches stays in place, and a sweep's first chunk does not read the
+carry at all (it is zeros).
+
 **One trace a shape** (``_common._bind``). Operands in the rows' dtype,
 float32 accumulation. On the chip the widths are multiples of 128 and the
 tile of 8 (16 in bf16); interpret mode (any other backend, the tests)
@@ -39,15 +50,24 @@ takes any shape.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import _bind, _dot, _interpret, _nbytes
+from ._common import _bind, _dot, _interpret, _nbytes, _pieces
 
 ROW_TILE = 256      # rows a grid step, at most
+ADD_TILE = 128      # ``add_rows``: tokens a tile of the carry and rows a
+#                     row tile, at most. Its blocks and temporaries fit the
+#                     16 MiB of VMEM every XLA op may use, so the kernel
+#                     asks for no more: a kernel that does, as the last op
+#                     of the sweep's loop body, keeps XLA from bringing
+#                     ``x`` back into VMEM behind it for the next chunk's
+#                     row gather (7 ns a row from there, 36 from HBM:
+#                     PERF.md section 6, PR 48)
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -230,3 +250,99 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
                             ("bwd", tile, router_grad), *tables, xs, dys,
                             scale, *w, *sums)
     return dxs, dwt, sums
+
+
+# ------------------------------------------------------ the add to tokens
+def add_tables(tokens, n: int, t: int, r: int, fresh):
+    """``add_rows``' tables [n / t + C / r] (int32) from a chunk's
+    ``tokens`` [C] in ascending order (``n`` or more where a row is no
+    one's: last): a grid step is one pair (tile of ``t`` tokens, row tile
+    of ``r`` rows) that holds rows of those tokens, the pairs of a token
+    tile one after another and the token tiles ascending (a merge: each
+    step opens a token tile or a row tile, so there are fewer pairs than
+    steps). Returns (the pair's token tile, its row tile, the tile of the
+    carry it READS, init: ``_ZERO`` or ``_CARRY`` at a token tile's first
+    pair, whether it is live). The steps past the last pair name its
+    blocks and run nothing; ``fresh`` (a traced bool: the carry is zeros)
+    makes every step read the carry's tile 0, which is fetched once and
+    not looked at."""
+    i32 = jnp.int32
+    n_t, n_r = n // t, tokens.shape[0] // r
+    edges = jnp.arange(n_t + 1, dtype=i32) * t
+    lo = jnp.sum(tokens[None, :] < edges[:, None], axis=1, dtype=i32)
+    first = lo[:-1] // r
+    pairs = jnp.where(lo[1:] > lo[:-1], (lo[1:] - 1) // r - first + 1, 0)
+    ends = jnp.cumsum(pairs)
+    s = jnp.arange(n_t + n_r, dtype=i32)
+    live = s < ends[-1]
+    at = jnp.maximum(jnp.minimum(s, ends[-1] - 1), 0)
+    tt = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1,
+                             dtype=i32), n_t - 1)
+    begin = ends[tt] - pairs[tt]
+    rt = jnp.minimum(first[tt] + at - begin, n_r - 1)
+    # step 0 opens its tile whatever the chunk holds: the output block of
+    # a grid is written back even where no step stored to it
+    opens = (live & (at == begin)) | (s == 0)
+    init = jnp.where(opens, jnp.where(fresh, _ZERO, _CARRY), 0)
+    return tt, rt, jnp.where(fresh, 0, tt), init, live.astype(i32)
+
+
+def _add_kernel(tt_ref, rt_ref, ct_ref, init_ref, live_ref, rows_ref,
+                tok_ref, carry_ref, out_ref):
+    del rt_ref, ct_ref
+    s = pl.program_id(0)
+
+    @pl.when(init_ref[s] == _ZERO)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    @pl.when(init_ref[s] == _CARRY)
+    def _():
+        out_ref[...] = carry_ref[...]
+
+    @pl.when(live_ref[s] > 0)
+    def _():
+        t, r = out_ref.shape[0], rows_ref.shape[0]
+        token = tt_ref[s] * t + jax.lax.broadcasted_iota(jnp.int32, (t, r), 0)
+        onehot = jnp.where(tok_ref[0] == token, 1.0, 0.0).astype(jnp.bfloat16)
+        # 0/1 times a bf16 piece is exact, the sums are float32: the three
+        # pieces' products add up to the rows' own float32 sum
+        hi, mid, low = _pieces(rows_ref[...], jnp.bfloat16)
+        out_ref[...] += (_dot(onehot, low) + _dot(onehot, mid)
+                         + _dot(onehot, hi))
+
+
+def add_rows(acc, rows, tokens, fresh):
+    """``acc`` [N, D] float32 with ``rows[i]`` added to ``acc[tokens[i]]``:
+    ``rows`` [C, D] float32 in TOKEN order (``tokens`` [C] int32 ascending;
+    ``N`` or more where a row is no one's, which come last and may hold
+    anything finite), ``fresh``: a traced bool, ``acc`` is zeros and is
+    not read. ``acc`` is given up to the result (an aliased output): each
+    tile of it that the rows reach is written once, the others stay. The
+    tiles come from the shapes: ``ADD_TILE`` tokens and rows at most, the
+    largest that divide ``N`` and ``C``."""
+    n, d = acc.shape
+    c = rows.shape[0]
+    t, r = math.gcd(n, ADD_TILE), math.gcd(c, ADD_TILE)
+    tables = add_tables(tokens, n, t, r, fresh)
+    call = pl.pallas_call(
+        _add_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(n // t + c // r,),
+            in_specs=[
+                pl.BlockSpec((r, d), lambda s, tt, rt, *_: (rt[s], 0)),
+                pl.BlockSpec((1, 1, r), lambda s, tt, rt, *_: (rt[s], 0, 0)),
+                pl.BlockSpec((t, d), lambda s, tt, rt, ct, *_: (ct[s], 0))],
+            out_specs=pl.BlockSpec((t, d), lambda s, tt, *_: (tt[s], 0))),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        input_output_aliases={7: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        cost_estimate=pl.CostEstimate(
+            flops=int(6 * (n // t + c // r) * t * r * d), transcendentals=0,
+            bytes_accessed=int(_nbytes(rows) + 2 * _nbytes(acc))),
+        interpret=_interpret(),
+        name="ds_moe_add_rows",
+    )
+    return _bind(call, "ds.moe_add_rows", ("add", t, r), *tables, rows,
+                 tokens.reshape(c // r, 1, r), acc)[0]

@@ -109,10 +109,12 @@ KIND_SCOPES = (
     #                    (ds.flash_fwd / ds.flash_bwd inside it)
     "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router
     "ds.moe_experts",  # moe/sharded_moe.py held_experts_ffn, fwd and bwd:
-    #                    the sort, the gathers, the adds to tokens and the
-    #                    two kernels (the backward rule opens it again)
+    #                    the sorts, the gathers and the three kernels
+    #                    (the backward rule opens it again)
     "ds.moe_gmm_fwd",  # ops/pallas/grouped_matmul.py forward: ds_moe_gmm_fwd
     "ds.moe_gmm_bwd",  # ops/pallas/grouped_matmul.py backward: ds_moe_gmm_bwd
+    "ds.moe_add_rows",  # ops/pallas/grouped_matmul.py add_rows: a chunk's
+    #                    rows summed into their tokens, ds_moe_add_rows
     "ds.moe_shared",   # moe/sharded_moe.py moe_ffn_held: the shared expert
 )
 # what a stack of Mamba-2 and attention layers opens inside ds.layers beside

@@ -1,12 +1,14 @@
-"""The held experts' grouped-matmul kernel pair (``ops/pallas/
-grouped_matmul.py``, PR 41) under ``moe.sharded_moe.held_experts_ffn``
-against the ``jax.numpy`` block loop it replaced (``tests/helpers/
-held_reference.py``): interpret mode, jitted, tiny widths that keep the
-lane rule. The row tile is a quarter of the block here and a chunk four
-blocks' rows (a block an expert held, what ``held_experts_ffn`` takes
+"""The held experts' grouped-matmul kernel pair and the add to tokens
+(``ops/pallas/grouped_matmul.py``, PRs 41 and 48) under
+``moe.sharded_moe.held_experts_ffn`` against the ``jax.numpy`` block loop
+they replaced (``tests/helpers/held_reference.py``): interpret mode,
+jitted, tiny widths that keep the lane rule. The row tile is a quarter of
+the block here and a chunk four blocks' rows (a block an expert held, what ``held_experts_ffn`` takes
 where it is told none; one case runs other chunks), so that a run ends
-in a part-empty tile and crosses chunks as at the cells' sizes. ``tests/test_kimi_linear.py`` and
-``tests/test_mellum.py`` hold the same function to a dense sum over
+in a part-empty tile and crosses chunks as at the cells' sizes; the add
+takes tiles of 32 tokens and 32 rows, so that a chunk's rows meet several
+tiles of the carry and a tile of it several row tiles.
+``tests/test_kimi_linear.py`` and ``tests/test_mellum.py`` hold the same function to a dense sum over
 experts; its compile for the chip is in ``tests/test_zero_layout.py``."""
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ TOKENS, TOP_K, EXPERTS, HELD, BLOCK, TILE = 320, 2, 8, 4, 64, 16
 @pytest.fixture(autouse=True)
 def _small_tiles(monkeypatch):
     monkeypatch.setattr(grouped_matmul, "row_tile", lambda block: TILE)
+    monkeypatch.setattr(grouped_matmul, "ADD_TILE", 32)
 
 
 def _routing(load: str, rng):
@@ -100,6 +103,37 @@ def test_kernel_pair_matches_the_block_loop(load, width):
         assert _err(g, w) < 2e-5, (name, _err(g, w))
     if load == "absent_only":
         assert not any(np.asarray(g).any() for g in got), load
+
+
+@pytest.mark.parametrize("width", [384, 256], ids=["f384", "f256"])
+@pytest.mark.parametrize("load", LOADS)
+def test_add_rows_matches_a_scatter_add(load, width):
+    """``ds_moe_add_rows`` alone, float32 against ``.at[tokens].add``: the
+    load's rows for the held experts (a token holds 0 to ``TOP_K`` of
+    them) in token order, cut into two chunks whose last tiles are empty
+    (the second wholly, under ``absent_only`` both): the first adds to a
+    carry it is told is zeros (and does not read: it holds NaNs here
+    where a row of that chunk will land), the second to what the first
+    left."""
+    rng = np.random.default_rng(1)
+    held = np.flatnonzero(_routing(load, rng).reshape(-1) < HELD)
+    tokens = (held // TOP_K).astype(np.int32)
+    if load == "balanced":      # a token with every choice held, one with none
+        held_of = np.bincount(tokens, minlength=TOKENS)
+        assert held_of.max() == TOP_K and held_of.min() == 0
+    cut, c = len(held) * 2 // 3, -(-len(held) // 32) * 32 + 64
+    rows = rng.standard_normal((len(held), width), dtype=np.float32)
+    want = np.zeros((TOKENS, width), np.float32)
+    np.add.at(want, tokens, rows)
+    pad = lambda v, fill: np.concatenate(  # noqa: E731
+        [v, np.full((c - len(v), *v.shape[1:]), fill, v.dtype)])
+    add = jax.jit(grouped_matmul.add_rows)
+    acc = jnp.zeros(want.shape).at[tokens[:cut]].set(jnp.nan)
+    for fresh, part in ((True, slice(0, cut)), (False, slice(cut, None))):
+        acc = add(acc, pad(rows[part], 7.0), pad(tokens[part], TOKENS),
+                  fresh)
+    assert acc.shape == want.shape and acc.dtype == jnp.float32
+    assert _err(acc, want) < 1e-6, _err(acc, want)
 
 
 @pytest.mark.parametrize("chunk", [TILE, 3 * TILE + 1, 24 * TILE])

@@ -547,7 +547,7 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
     assert found == (set(scopes.DEVICE_SCOPES) - {"ds.attn", "ds.mlp"}
                      | set(scopes.WINDOW_SCOPES)
                      | {"ds.moe_router", "ds.moe_experts", "ds.moe_gmm_fwd",
-                        "ds.moe_gmm_bwd"})
+                        "ds.moe_gmm_bwd", "ds.moe_add_rows"})
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values() if row["scope"]}
     for kind in ("swa", "full"):
@@ -569,7 +569,9 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
     # backward rule keeps the inputs alone and nothing else of the layer
     # reads the sweep's result, so the compiler drops it
     for want in ("fwd:ds.layers/ds.moe_experts/ds.moe_gmm_fwd",
-                 "bwd:ds.layers/ds.moe_experts/ds.moe_gmm_bwd"):
+                 "fwd:ds.layers/ds.moe_experts/ds.moe_add_rows",
+                 "bwd:ds.layers/ds.moe_experts/ds.moe_gmm_bwd",
+                 "bwd:ds.layers/ds.moe_experts/ds.moe_add_rows"):
         assert want in paths, want
     # what the cell's attn_ms.mellum reads: the layer less its kernels
     rx = re.compile(r"ds\.attn_(swa|full)\b(?!.*ds\.flash_)")
@@ -602,13 +604,18 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
 # after its primitive and inlines it, the two new `name` equations are two
 # more such functions, and MLIR's symbol table numbers every LATER
 # collision two higher (`@closed_call_168` is `@closed_call_170`, eleven
-# such names and no other character of 3501 lines).
+# such names and no other character of 3501 lines). `kimi_linear`'s is PR
+# 48's, taken again from its tree: the held sweep's add to tokens is the
+# kernel `ds_moe_add_rows` after one more sort and gather, not XLA's
+# scatter-add (`moe/sharded_moe.py` `_held_sweep`), which is that step's
+# program by design; `granite_hybrid` and `mistral` hold no routed layer and
+# keep PR 47's hashes.
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "befd1c7a5551d01cb15c271d83a92f70a5f5f18f417f6c1051a4f739596cf776",
+        "bdc8d6163d8b71d21575f11baf26bab61e08ef4277a2e33f628d0066ae4ba799",
         7191.956369750438),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",

@@ -439,10 +439,11 @@ def test_mellums_step_is_the_parents_program():
     (unchanged here: this PR's edits lie on all their paths, the head, the
     engine's feed and ``ModelConfig`` among them); this holds the fourth,
     ``mellum``, to the seeded weights of commit 9909adf. The text's hash
-    is PR 47's, taken from its tree: Mellum's layers are rematted whole
-    under ``nothing_saveable``, which since PR 47 keeps the flash kernel's
-    ``o`` and ``lse`` (``ops/pallas/_common.py`` ``KEPT_RESIDUAL``), so the
-    step holds ``ds_flash_fwd`` once an attention layer and not twice."""
+    is PR 48's, taken from its tree: the held sweep's add to tokens is the
+    kernel ``ds_moe_add_rows`` after one more sort and gather, not XLA's
+    scatter-add (``moe/sharded_moe.py`` ``_held_sweep``), which is this
+    step's program by design (PR 47's before it: a rematted layer keeps
+    the flash kernel's ``o`` and ``lse``)."""
     model = Mellum(size="tiny", moe_held_experts=16, attn_impl="flash",
                    loss_chunk=64)
     engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
@@ -452,6 +453,6 @@ def test_mellums_step_is_the_parents_program():
     leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "3e8dc16c223bbdea97ec128ddefa28d73ccdecbdb32db249564bf9fec46c4c96")
+        "52c05205bb9db5bbef6a9d3fcba20c9a274748fe38d1c70f1e305e226ffdcc27")
     assert float(sum(np.abs(x.astype(np.float64)).sum()
                      for x in leaves)) == 36510.69588080405

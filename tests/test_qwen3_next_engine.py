@@ -129,15 +129,19 @@ def test_step_scopes_are_the_lists_and_each_kernel_lies_in_its_layer(
 # `tests/test_mellum.py`), which since PR 47 keeps the flash kernel's `o`
 # and `lse` (`ops/pallas/_common.py` `KEPT_RESIDUAL`, `_remat_policy`): a
 # step holds `ds_flash_fwd` once an attention layer and not twice.
+# `kimi_linear`'s and `mellum`'s are PR 48's, taken again from its tree: the
+# held sweep's add to tokens is the kernel `ds_moe_add_rows` after one more
+# sort and gather, not XLA's scatter-add, which is a routed step's program by
+# design; the three families without a routed layer keep PR 47's.
 _FAMILIES = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64, kda_head_groups=2), "277841b46c3b8a472406d695a32f612feba19bee865cfe78ab30a0ca2a6121dd"),
+        loss_chunk=64, kda_head_groups=2), "a01fbd642111ee72c1f44a792b79b915468e65bff97e6ed0d3247aa40b9da953"),
     "mellum": (Mellum, dict(
         num_layers=2, layer_types=["sliding_attention", "full_attention"],
         moe_held_experts=16, attn_impl="flash", loss_chunk=64),
-        "715346728a9dc71ea00b3136461553874b23578a46fdf66c830721421ae19af3"),
+        "e3c9878334adf0dbb6ed737c78a38f948feaf92ce7c0dd3a3c2848b3aefccbf6"),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
         loss_chunk=64), "b577bff512e4102d37f268264abfca574a8a597317e25ad4b7c7d7b427ca3775"),

@@ -1,29 +1,34 @@
 """The held experts' sweep alone, on the chip: time and results of this
 checkout's ``moe/sharded_moe.py`` ``held_experts_ffn`` (the grouped-matmul
-kernels of ``ops/pallas/grouped_matmul.py`` and what XLA does round them)
-against the ``jax.numpy`` block loop it replaced
+kernels and the add to tokens of ``ops/pallas/grouped_matmul.py`` and what
+XLA does round them) against the ``jax.numpy`` block loop it replaced
 (``tests/helpers/held_reference.py``).
 
     chiprun -- python tools/moe_kernel_bench.py [ROW_TILE ...]
 
-Sizes a change to the kernels before a cell is run (PR 41). The shapes are
-the two cells' (16384 tokens, top 8, hidden 2304): ``mellum`` holds 16 of
+Sizes a change to the kernels before a cell is run (PRs 41, 48). The
+shapes are the three cells' (16384 tokens): at top 8 and hidden 2304,
+``mellum`` holds 16 of
 64 experts of 896 at a balanced 2048 rows each (ONE chunk of 36,864 rows),
 ``mellum_over`` the same with eight of them at 2688: 5120 rows over the
 share's even total, so a second chunk (what that costs: ``held_chunk``);
 ``kimi`` holds 8 of 256 of 1024 at 288 rows each, ``kimi_skew`` the same
-with one expert at 1163 (a chunk of 6144 rows holds either). A line a
+with one expert at 1163 (a chunk of 6144 rows holds either); at top 10
+and hidden 2048, ``qnext`` holds 32 of 512 experts of 512 at a balanced
+320 rows each (a chunk of 14,336 rows, a row tile of 128). A line a
 shape and ROW_TILE (default
 128, 256, 512: the largest multiple of 128 up to it that divides the
 block is the kernels' row tile), each number the device busy time of one
 call in ms, from a profiler trace of 10 calls:
 
-- ``fwd``: one layer's forward sweep, ``busy`` and the kernel's part;
+- ``fwd``: one layer's forward sweep, ``busy``, each kernel's part (the
+  add to tokens, ``ds_moe_add_rows``, beside the matmuls') and the
+  ``rest``, which is XLA's;
 - ``grad``: ``jax.grad`` of a rematted layer's sweep (a loss linear in
   the result, so the backward sweep alone, as in a train step: the
   backward rule keeps nothing but the inputs and the compiler drops the
   rerun's forward sweep), ``busy``, each kernel's part and the ``rest``
-  (the sort, the gathers, the adds to tokens, the loop);
+  (the sorts, the gathers, the loop);
 - ``ops``: the longest device ops of ``grad`` outside the kernels;
 - ``router_grad``: whether the routing weights' gradient is made;
 - with the default row tile also ``jnp``: the same two of the block loop,
@@ -50,26 +55,29 @@ sys.path.insert(2, os.path.join(ROOT, "tools"))
 # the trace of CALLS calls and its reductions: one definition for the tools
 from kda_kernel_bench import CALLS, busy_ms, rel_err, traced  # noqa: E402
 
-TOKENS, TOP_K, HIDDEN = 16384, 8, 2304
-KERNELS = ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd")
-# name: (experts, held, expert width, rows of expert 0, router_grad)
-SHAPES = {"mellum": (64, 16, 896, None, False),
-          "mellum_over": (64, 16, 896, 2688, False),
-          "kimi": (256, 8, 1024, None, True),
-          "kimi_skew": (256, 8, 1024, 1163, True)}
+TOKENS = 16384
+KERNELS = ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd", "ds_moe_add_rows")
+# name: (experts, held, expert width, rows of expert 0, router_grad,
+#        top k, hidden width)
+SHAPES = {"mellum": (64, 16, 896, None, False, 8, 2304),
+          "mellum_over": (64, 16, 896, 2688, False, 8, 2304),
+          "kimi": (256, 8, 1024, None, True, 8, 2304),
+          "kimi_skew": (256, 8, 1024, 1163, True, 8, 2304),
+          "qnext": (512, 32, 512, None, False, 10, 2048)}
 
 
-def routing(experts: int, held: int, skew):
-    """idx [TOKENS, TOP_K]: ``mellum`` sends every expert its even 2048
-    rows, with ``skew`` the first eight that many; the Kimi shapes send
+def routing(experts: int, held: int, skew, top_k: int):
+    """idx [TOKENS, top_k]: ``mellum`` and ``qnext`` send every expert its
+    even rows (2048, 320), ``mellum`` with ``skew`` the first eight that
+    many; the Kimi shapes send
     the held experts 9/16 of their even 512 (the cell reads 247-294),
     with ``skew`` expert 0 that many, from tokens that had not chosen
     it."""
-    n, j = np.arange(TOKENS)[:, None], np.arange(TOP_K)[None, :]
-    idx = (n + experts // TOP_K * j) % experts
-    if experts > 64:
-        away = held + (n + (experts // TOP_K - 1) * j) % (experts - held)
-        sent = n // (experts // TOP_K) % 16 < 9
+    n, j = np.arange(TOKENS)[:, None], np.arange(top_k)[None, :]
+    idx = (n + experts // top_k * j) % experts
+    if experts == 256:
+        away = held + (n + (experts // top_k - 1) * j) % (experts - held)
+        sent = n // (experts // top_k) % 16 < 9
         idx = np.where(sent, idx, away)
         if skew:
             more = np.flatnonzero(~sent[:, 0])[:skew - 288]
@@ -83,18 +91,18 @@ def routing(experts: int, held: int, skew):
 
 def inputs(name: str, seed: int = 41):
     import jax.numpy as jnp
-    experts, held, width, skew, _ = SHAPES[name]
+    experts, held, width, skew, _, top_k, hidden = SHAPES[name]
     rng = np.random.default_rng(seed)
     bf, f32 = jnp.bfloat16, jnp.float32
     normal = lambda *s: rng.standard_normal(s, dtype=np.float32)  # noqa: E731
-    ex = {"w_gate": normal(held, HIDDEN, width) / HIDDEN ** 0.5,
-          "w_up": normal(held, HIDDEN, width) / HIDDEN ** 0.5,
-          "w_down": normal(held, width, HIDDEN) / width ** 0.5}
-    return (jnp.asarray(normal(TOKENS, HIDDEN), bf),
-            jnp.asarray(routing(experts, held, skew)),
-            jnp.asarray(rng.uniform(0.05, 0.3, (TOKENS, TOP_K)), f32),
+    ex = {"w_gate": normal(held, hidden, width) / hidden ** 0.5,
+          "w_up": normal(held, hidden, width) / hidden ** 0.5,
+          "w_down": normal(held, width, hidden) / width ** 0.5}
+    return (jnp.asarray(normal(TOKENS, hidden), bf),
+            jnp.asarray(routing(experts, held, skew, top_k)),
+            jnp.asarray(rng.uniform(0.05, 0.3, (TOKENS, top_k)), f32),
             {k: jnp.asarray(v, bf) for k, v in ex.items()},
-            jnp.asarray(normal(TOKENS, HIDDEN), bf))
+            jnp.asarray(normal(TOKENS, hidden), bf))
 
 
 def split(events) -> dict:
@@ -110,7 +118,7 @@ def longest(events, n: int = 6) -> dict:
     total = collections.Counter()
     for name, a, b in events:
         op = name.split(" = ")[0].lstrip("%")
-        if not op.startswith(("while", "ds_moe_gmm", "conditional")):
+        if not op.startswith(("while", "ds_moe_", "conditional")):
             total[op] += b - a
     return {op: round(1e-6 * ns / CALLS, 3) for op, ns in total.most_common(n)}
 
@@ -133,10 +141,10 @@ def main(argv) -> int:
                            * ct.astype(f32))
         return jax.jit(layer), jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
-    for name, (experts, held, width, _, router_grad) in SHAPES.items():
+    for name, (experts, held, _, _, router_grad, top_k, _) in SHAPES.items():
         x, idx, w, ex, ct = inputs(name)
-        block = sharded_moe.held_block(TOKENS, TOP_K, experts)
-        chunk = sharded_moe.held_chunk(TOKENS, TOP_K, experts, held, block)
+        block = sharded_moe.held_block(TOKENS, top_k, experts)
+        chunk = sharded_moe.held_chunk(TOKENS, top_k, experts, held, block)
         rows = np.bincount(np.asarray(idx).ravel(),
                            minlength=experts)[:held]
         default = grouped_matmul.ROW_TILE
