@@ -161,6 +161,24 @@ def test_train_batches_come_from_the_seed():
 
 
 # ---- BENCHMARK.json and the files agree -----------------------------------
+def test_no_cell_reads_one_thing_under_two_names():
+    """One entry a definition (PR 49 folded 103 copies into 23). What an
+    adding PR can still do, because it may edit no file that is there, is
+    bring COPIES of the step readers under a suffix of its own, for its
+    own cells (README, "Adding things", point 4); the next ``benchmark``
+    PR folds them, which it can exactly because two entries of one
+    definition never share a cell."""
+    seen = {}
+    for entry in B["per_layer"]:
+        spec = files.load_layer_metric(entry["name"])
+        key = json.dumps([spec[k] for k in (
+            "layer", "unit", "source", "moves", "reducer")], sort_keys=True)
+        for other, cells in seen.setdefault(key, []):
+            assert not set(cells) & set(entry["workloads"]), (
+                entry["name"], other)
+        seen[key].append((entry["name"], entry["workloads"]))
+
+
 def test_benchmark_json_agrees_with_the_files():
     e2e = {m["name"]: m for m in B["end_to_end"]}
     per_layer = {m["name"]: m for m in B["per_layer"]}
@@ -181,6 +199,9 @@ def test_benchmark_json_agrees_with_the_files():
         for m in cell["end_to_end"]:
             assert w["name"] in e2e[m].get("workloads", CELLS), (m, w["name"])
         assert cell["per_layer"]
+        # a cell reports exactly what the contract owes it
+        assert set(cell["per_layer"]) == {
+            m["name"] for m in B["per_layer"] if w["name"] in m["workloads"]}
         for m in cell["per_layer"]:
             spec = files.load_layer_metric(m)
             entry = per_layer[m]
@@ -188,8 +209,6 @@ def test_benchmark_json_agrees_with_the_files():
                     spec["layer"], spec["moves"]) == (
                 entry["unit"], entry["better"], entry["source"],
                 entry["layer"], entry["moves"])
-            assert w["name"] in entry["workloads"]
-            assert set(entry["workloads"]) <= set(spec["cells"])
             # every cell that reports a metric reports the one it moves
             assert spec["moves"] in cell["end_to_end"], (m, w["name"])
             assert callable(reducers.find(spec["reducer"]["name"]))
@@ -220,6 +239,7 @@ def test_every_name_a_file_gives_resolves():
     for path in sorted(os.listdir(os.path.join(BENCH, "layer_metrics"))):
         spec = files.load_layer_metric(path[:-5])
         assert callable(reducers.find(spec["reducer"]["name"]))
+        # a list on both sides, the same list (tier-1 holds the same)
         assert sorted(per_layer[spec["name"]]["workloads"]) == sorted(
             spec["cells"])
         for cell in spec["cells"]:
@@ -306,16 +326,12 @@ def test_untraced_run_turns_no_telemetry_on():
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_the_control_comes_out_not_correct_on_cpu(cell):
-    """``tests/control.py`` at the tiny preset: the reference with fp8
-    operands in the program's place fails the check the program passes
-    (on the chip at the cells' own size: ``PERF.md`` section 2)."""
+def _control(cell, stand_in):
     chips = files.load_cell(cell)["chips"]
     code = ("import sys, json; sys.path[:0] = [%r, %r]; "
             "import cpu_rig, control; print(json.dumps("
-            "control.control(%r, 3000000019, cpu_rig.RIG)))"
-            % (HERE, BENCH, cell))
+            "control.control(%r, 3000000019, cpu_rig.RIG, %r)))"
+            % (HERE, BENCH, cell, stand_in))
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}",
                JAX_COMPILATION_CACHE_DIR=os.path.join(
@@ -324,9 +340,30 @@ def test_the_control_comes_out_not_correct_on_cpu(cell):
                        capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     got = json.loads(p.stdout.strip().splitlines()[-1])
-    assert got["correct"] is False and got["control"] == "float8_e4m3fn"
-    assert got["rounded_matmuls"] > 0
-    assert got["got"]["logits_err_rms"] > got["limits"]["logits_err_rms"]
+    assert got["control"] == stand_in and got["rounded_matmuls"] > 0
+    return got
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_control_comes_out_not_correct_on_cpu(cell):
+    """``tests/control.py`` at the tiny preset: the reference with fp8
+    operands in the program's place fails the check the program passes
+    (on the chip at the cells' own size: ``PERF.md`` section 2). A cell's
+    limits are set from chip readings at its own size, and may lie above
+    what fp8 reads at the tiny widths (the Mellum cell's ``logits_err_rms``:
+    limit 0.055, fp8 0.0377 here, 0.2242 at least on the chip). Which case
+    a cell is in is read off the run, not off its name: where fp8 stays
+    under the limit, the CPU holds what it can, that the control reads an
+    order of magnitude over the bfloat16 stand-in, which is what a right
+    program looks like; NOT correct is then held on the chip alone."""
+    got = _control(cell, "float8_e4m3fn")
+    if got["got"]["logits_err_rms"] > got["limits"]["logits_err_rms"]:
+        assert got["correct"] is False
+        return
+    right = _control(cell, "bfloat16")
+    assert right["correct"] is True
+    for key in ("logits_err_rms", "logits_err_max"):
+        assert got["got"][key] > 10 * right["got"][key], key
 
 
 def test_no_tpu_no_result():

@@ -101,7 +101,7 @@ def test_traced_run_reports_on_cpu():
     line, out = _run_rig(CELL, "1", "3")
     assert line["correct"] is True and line["failed"] == 0, out[-3000:]
     got = set(line["metrics"])
-    assert {"mfu.ssm", "h2d_ms.ssm", "setup_import_s.ssm"} <= got
+    assert {"mfu.train", "h2d_ms.train", "setup_import_s.train"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
     assert "compiles_in_window=0" in out
 

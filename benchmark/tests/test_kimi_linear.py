@@ -133,9 +133,9 @@ def test_traced_run_reports_the_counters_on_cpu():
     line, out = _run_rig(CELL, "1", "3")
     assert line["correct"] is True and line["failed"] == 0, out[-3000:]
     got = set(line["metrics"])
-    assert {"mfu.kda", "held_expert_tokens.kda"} <= got
+    assert {"mfu.train", "held_expert_tokens.routed"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
     # 128 tokens x top-8 of 256: 4 rows a held expert under a balanced
     # router, nothing dropped (or the metric would be missing)
-    assert 1 < line["metrics"]["held_expert_tokens.kda"]["value"] < 12
+    assert 1 < line["metrics"]["held_expert_tokens.routed"]["value"] < 12
     assert "compiles_in_window=0" in out

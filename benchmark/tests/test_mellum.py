@@ -126,10 +126,10 @@ def test_the_cell_runs_on_cpu(trace):
     if trace == "0":
         assert got == {"train_tokens_per_s", "setup_s"}
         return
-    assert {"mfu.mellum", "h2d_ms.mellum", "setup_import_s.mellum",
-            "held_expert_tokens.mellum", "moe_pad_share.mellum"} <= got
+    assert {"mfu.train", "h2d_ms.train", "setup_import_s.train",
+            "held_expert_tokens.routed", "moe_pad_share.routed"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
-    assert 0.0 < line["metrics"]["moe_pad_share.mellum"]["value"] < 100.0
+    assert 0.0 < line["metrics"]["moe_pad_share.routed"]["value"] < 100.0
 
 
 def test_the_attention_kinds_control_sees_each_planted_fault():

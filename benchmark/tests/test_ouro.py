@@ -116,7 +116,7 @@ def test_the_cell_runs_on_cpu(trace):
     if trace == "0":
         assert got == {"train_tokens_per_s", "setup_s"}
         return
-    assert {"mfu.ouro", "h2d_ms.ouro", "setup_import_s.ouro",
+    assert {"mfu.train", "h2d_ms.train", "setup_import_s.train",
             "expected_exit_pass.ouro"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
     assert 1.0 <= line["metrics"]["expected_exit_pass.ouro"]["value"] <= 4.0

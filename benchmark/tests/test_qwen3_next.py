@@ -238,12 +238,12 @@ def test_the_cell_runs_on_cpu(trace):
     if trace == "0":
         assert got == {"train_tokens_per_s", "setup_s"}
         return
-    assert {"mfu.qnext", "held_expert_tokens.qnext",
-            "moe_pad_share.qnext"} <= got
+    assert {"mfu.train", "held_expert_tokens.routed",
+            "moe_pad_share.routed"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
     # 128 tokens x top-10 of 512: 2.5 rows a held expert if balanced
-    assert 0.5 < line["metrics"]["held_expert_tokens.qnext"]["value"] < 8
-    assert 0.0 < line["metrics"]["moe_pad_share.qnext"]["value"] < 100.0
+    assert 0.5 < line["metrics"]["held_expert_tokens.routed"]["value"] < 8
+    assert 0.0 < line["metrics"]["moe_pad_share.routed"]["value"] < 100.0
 
 
 def test_the_control_sees_each_planted_fault():

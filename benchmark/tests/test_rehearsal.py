@@ -2,10 +2,18 @@
 ``benchmark/`` the files under ``tests/rehearsal/`` are ADDED (an
 architecture module for the program's ``mixtral`` class whose reference
 returns a mask, a configuration of its ``tiny`` preset and one at OLMoE's
-router shape, a cell each, a traffic mix, two per-layer metrics, a reducer
-in a new file, the script that reads where routing flips), nothing that
-was there is edited, and the new cells run through ``cpu_rig``. Then what
-must fail does, naming what is known. By hand:
+router shape, a cell each, a traffic mix, per-layer metrics, a reducer in
+a new file, the script that reads where routing flips), nothing that was
+there is edited, and the new cells run through ``cpu_rig``. A new train
+cell cannot join the seventeen step readers every accepted train cell
+reports (``*.train``): a metric's file lists its cells, ``BENCHMARK.json``
+the same list, and an adding PR edits no file that is there. So it brings
+COPIES of them under a suffix of its own (``*.moe``: the file's
+definition, its own cells) beside the one reading that is its own, and
+the next ``benchmark`` PR folds them. Tier-1's
+``tests/test_benchmark_contract.py`` and the suite here both pass on
+that tree, unedited. Then what must fail does, naming what is
+known. By hand:
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
@@ -24,6 +32,14 @@ BENCH = os.path.dirname(HERE)
 CHECKOUT = os.path.dirname(BENCH)
 ADDED = os.path.join(HERE, "rehearsal")
 CELL = "train-moe-tiny-1chip"
+OWN_METRIC = "expert_flops_share.moe"
+
+
+def _known(root, directory):
+    """What ``lib/files.py`` lists as known in an error: the modules of
+    the copy, the rehearsal's among them."""
+    return sorted(p[:-3] for p in os.listdir(root / "benchmark" / directory)
+                  if p.endswith(".py") and not p.startswith("_"))
 
 
 def _hashes(root):
@@ -83,10 +99,32 @@ def test_a_second_architecture_runs_from_added_files_only(copy):
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0, p.stdout[-3000:]
-    assert set(line["metrics"]) == {"mfu.moe", "expert_flops_share.moe"}
+    # the new cell reports a copy of each step reader every accepted train
+    # cell reports (on the CPU those that need no device plane) and its
+    # one new name
+    def cell_file(root, name=f"{CELL}.json"):
+        with open(os.path.join(root, "cells", name)) as f:
+            return json.load(f)["per_layer"]
+    listed = cell_file(copy / "benchmark")
+    accepted = [cell_file(BENCH, n)
+                for n in os.listdir(os.path.join(BENCH, "cells"))]
+    every_cells = set(accepted[0]).intersection(*accepted[1:])
+    assert len(every_cells) == 17
+    assert all(n.endswith(".train") for n in every_cells)
+    copies = {n[:-len("train")] + "moe" for n in every_cells}
+    assert set(listed) == copies | {OWN_METRIC}
+    for n in copies:            # a copy says what the accepted file says
+        theirs, ours = (json.load(open(os.path.join(
+            root, "layer_metrics", name + ".json"))) for root, name in (
+            (BENCH, n[:-len("moe")] + "train"), (copy / "benchmark", n)))
+        for key in ("layer", "unit", "better", "source", "moves", "reducer"):
+            assert ours[key] == theirs[key], (n, key)
+    assert {"mfu.moe", "setup_import_s.moe", "setup_init_s.moe",
+            "setup_compile_s.moe", OWN_METRIC} <= set(line["metrics"])
+    assert set(line["metrics"]) <= set(listed)
     # 2 of 4 experts a token: 2 layers x 2 x 3 x 64 x 128 x 2 FLOPs of
     # 2 x (that + projections 24576 + router 512 + attention) + head 65536
-    share = line["metrics"]["expert_flops_share.moe"]["value"]
+    share = line["metrics"][OWN_METRIC]["value"]
     assert 50.0 < share < 65.0
     agree = [ln for ln in p.stdout.splitlines() if ln.startswith("agreement")]
     assert len(agree) == 1 and "ok=True" in agree[0]
@@ -104,7 +142,9 @@ def test_a_second_architecture_runs_from_added_files_only(copy):
 
 def _write_benchmark_json_entries(root):
     """``BENCHMARK.json`` of the copy gains what a PR adding these files
-    writes there (README, "Adding things", step 5), read off the files."""
+    writes there (README, "Adding things", step 5), read off the files:
+    an entry for each file it adds, and its cells' names in ONE list that
+    was there, ``train_tokens_per_s``'s ``workloads``."""
     def added(kind):
         for name in sorted(os.listdir(os.path.join(ADDED, kind))):
             with open(os.path.join(ADDED, kind, name)) as f:
@@ -137,8 +177,26 @@ def test_the_suite_stays_green_with_the_added_files(copy):
     cell runs end to end, and ``tests/control.py``, which knows no
     architecture, comes out not correct on it."""
     _write_benchmark_json_entries(copy)
-    before = _hashes(copy)
+    contract = os.path.join(CHECKOUT, "tests", "test_benchmark_contract.py")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=CHECKOUT)
+    if os.path.isfile(contract):
+        # tier-1's reading of the same tree: ``BENCHMARK.json`` against the
+        # files and the driver's rules of form (its two tests that build
+        # an engine hold the program, which the copy does not change)
+        os.mkdir(copy / "tests")
+        shutil.copy(contract, copy / "tests")
+        p = subprocess.run(
+            [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+             "tests/test_benchmark_contract.py", "-k",
+             "every_cell_has_its_files or keeps_the_drivers_form"
+             " or every_per_layer_metric_has_its_file"],
+            cwd=copy, env=env, capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+        with open(copy / "BENCHMARK.json") as f:
+            cells = json.load(f)["workloads"]
+        # a case a cell, one a group of the contract, one for the files
+        assert f"{len(cells) + 4 + 1} passed" in p.stdout, p.stdout[-500:]
+    before = _hashes(copy)
     p = subprocess.run(
         [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
          "benchmark/tests/test_benchmark.py",
@@ -189,22 +247,24 @@ def test_the_routed_check_passes_bf16_and_fails_fp8(copy, stand_in, correct):
 def _unknown_architecture(root):
     _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
           architecture="mamba")
-    return "0", ["architecture 'mamba'", "known: ['mistral', 'mixtral']"]
+    return "0", ["architecture 'mamba'",
+                 f"known: {_known(root, 'architectures')}"]
 
 
 def _no_architecture_key(root):
     _edit(root / "benchmark/configs/mixtral-tiny-zero3-1chip.json",
           drop=["architecture"])
-    return "0", ["architecture None", "known: ['mistral', 'mixtral']"]
+    return "0", ["architecture None",
+                 f"known: {_known(root, 'architectures')}"]
 
 
 def _unknown_kind(root):
     _edit(root / "benchmark/traffic/pretrain-s8k.json", kind="serve_open")
-    return "0", ["kind 'serve_open'", "known: ['train_job']"]
+    return "0", ["kind 'serve_open'", f"known: {_known(root, 'kinds')}"]
 
 
 def _unknown_reducer(root):
-    _edit(root / "benchmark/layer_metrics/mfu.moe.json",
+    _edit(root / f"benchmark/layer_metrics/{OWN_METRIC}.json",
           reducer={"name": "mfu_of_nothing", "args": {}})
     return "1", ["no reducer named 'mfu_of_nothing'", "'mfu_pct'",
                  "'expert_flops_share_pct'", "'scope_ms_per_step'"]
