@@ -13,6 +13,15 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
 os.environ["JAX_PLATFORMS"] = "cpu"  # tests never touch the real chip
+# LLVM's optimizer off for the CPU backend's code (PR 50): a heavy case's
+# seconds are mostly XLA compiling a tiny model's step with its kernels
+# interpreted, LLVM's passes were the larger part of that, and no test reads
+# a CPU time. The HLO passes are not touched: a whole step's optimized text
+# is the same byte for byte at level 0 and 3, and so is what libtpu compiles
+# for a described chip. What differs is the CPU's last bits (the seeded
+# weights' sums of ``tests/test_step_pins.py`` were taken under this level).
+if "--xla_backend_optimization_level" not in os.environ["XLA_FLAGS"]:
+    os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -280,6 +289,42 @@ def pytest_report_header(config):
     return None
 
 
+# the queue's order (ISSUE 50). Under ``--dist loadfile`` a file is one
+# worker's, and xdist 3.8 hands the files out by their NUMBER OF CASES, most
+# first, unless ``--no-loadscope-reorder``: the files that are long because
+# they hold a few long cases (a family's tiny model, a kernel in interpret
+# mode, an AOT compile) then start last, and whichever worker draws one as
+# the queue runs dry holds the whole run. These start first, in this order,
+# longest first; every other file keeps its collected place behind them. No
+# seconds are kept here (a CPU's seconds are no record): re-take the ORDER
+# from the ten lines a whole run prints at its end (``pytest_terminal_summary``
+# below). ``test_zero_layout.py`` stays one file (one file describes the
+# topology) and so stands first.
+_LONGEST_FIRST = (
+    "test_zero_layout.py",
+    "test_kimi_linear_engine.py",
+    "test_kda_prep_kernels.py",
+    "test_short_conv_step.py",
+    "test_kda_kernels.py",
+    "test_kimi_linear_limits.py",
+    "test_short_conv.py",
+    "test_ouro.py",
+    "test_kimi_linear_reference.py",
+    "test_qwen3_next_reference.py",
+    "test_mellum_reference.py",
+    "test_granite_hybrid.py",
+)
+
+
+def longest_first(names):
+    """``names`` (a file name an item, in collected order) as the indices of
+    the order the queue takes: ``_LONGEST_FIRST``'s files first, in the
+    tuple's order, every other in its collected place (a stable sort)."""
+    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    return sorted(range(len(names)),
+                  key=lambda i: rank.get(names[i], len(rank)))
+
+
 def _marker_keys(item):
     fname = os.path.basename(str(item.fspath))
     return ((fname, item.name), (fname, item.name.split("[")[0]),
@@ -292,14 +337,18 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: heavy tests excluded from the tier-1 run "
         "(conftest._SLOW allowlist)")
+    # xdist's loadfile queue as collected (below: longest first), not by
+    # case count; the option exists whenever xdist is loaded, and a run
+    # without it has no queue
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
 
 
 def pytest_collection_modifyitems(config, items):
     matched = {}
-    files_seen = set()
-    for item in items:
-        fname = os.path.basename(str(item.fspath))
-        files_seen.add(fname)
+    names = [os.path.basename(str(item.fspath)) for item in items]
+    files_seen = set(names)
+    for item, fname in zip(items, names):
         for tier, mark in ((_FAST, pytest.mark.fast),
                            (_SLOW, pytest.mark.slow)):
             for key in _marker_keys(item):
@@ -307,6 +356,7 @@ def pytest_collection_modifyitems(config, items):
                     matched.setdefault(id(tier), set()).add(key)
                     item.add_marker(mark)
                     break
+    items[:] = [items[i] for i in longest_first(names)]
     # a rename must not silently shrink a tier — flag allowlist entries
     # that matched nothing. Only enforced for whole-file / whole-suite
     # collection: node-id ("file.py::test") or -k runs legitimately
@@ -315,12 +365,38 @@ def pytest_collection_modifyitems(config, items):
                 or bool(config.option.keyword))
     if narrowed:
         return
+    if all(os.path.isdir(a) for a in config.args):
+        stale = [n for n in _LONGEST_FIRST if n not in files_seen]
+        if stale:
+            raise pytest.UsageError(
+                f"conftest._LONGEST_FIRST names no collected file: {stale}")
     for name, tier in (("_FAST", _FAST), ("_SLOW", _SLOW)):
         stale = [k for k in tier - matched.get(id(tier), set())
                  if k[0] in files_seen]
         if stale:
             raise pytest.UsageError(
                 f"conftest.{name} entries match no collected test: {stale}")
+
+
+def pytest_terminal_summary(terminalreporter, config):
+    """A run over workers says what its wall was made of: the ten files
+    with the most seconds (set-up, call and tear-down of every case, from
+    the reports the controller holds). ``_LONGEST_FIRST`` is re-taken from
+    these lines; nothing is written and nothing reads them."""
+    if hasattr(config, "workerinput") or not config.getoption(
+            "numprocesses", None):
+        return
+    seconds = {}
+    for reports in terminalreporter.stats.values():
+        for rep in reports:
+            if hasattr(rep, "duration") and hasattr(rep, "nodeid"):
+                fname = os.path.basename(rep.nodeid.split("::")[0])
+                seconds[fname] = seconds.get(fname, 0.0) + rep.duration
+    if not seconds:
+        return
+    terminalreporter.section("the ten longest files (a file is one worker's)")
+    for fname in sorted(seconds, key=seconds.get, reverse=True)[:10]:
+        terminalreporter.write_line(f"{seconds[fname]:8.1f} s  {fname}")
 
 
 @pytest.fixture(autouse=True)

@@ -1,11 +1,16 @@
-"""What the test files of the Kimi-Linear and Granite families share
-(``tests/test_kimi_linear*.py``, ``tests/test_kda*_kernels.py``,
-``tests/test_granite_hybrid*.py``, ``tests/test_short_conv_step.py``,
-``tests/test_qwen3_next*.py``): a
-file is one worker's under ``--dist loadfile``, so each family's cases lie
-in several files and their fixtures and inputs here. Importing this puts
+"""What the test files of the Kimi-Linear, Granite, Mellum and Qwen3-Next
+families share (``tests/test_kimi_linear*.py``,
+``tests/test_kda*_kernels.py``, ``tests/test_granite_hybrid*.py``,
+``tests/test_mellum*.py``, ``tests/test_qwen3_next*.py``,
+``tests/test_short_conv_step.py``; since PR 50 a family's float32 reference
+comparison is ``tests/test_<family>_reference.py`` and every family's step
+pin a row of ``tests/test_step_pins.py``, which take the engine's
+``DS_CONFIG`` from here as the families' engine files do): a file is one
+worker's under ``--dist loadfile``, so each family's cases lie in several
+files and their fixtures and inputs here. Importing this puts
 ``benchmark/`` on ``sys.path`` (the references are ``architectures/``'s)."""
 
+import functools
 import gc
 import json
 import pathlib
@@ -17,15 +22,33 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import GraniteHybrid, KimiLinear, Qwen3Next
+from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum,
+                                  Qwen3Next)
 
 BENCH = pathlib.Path(__file__).resolve().parents[2] / "benchmark"
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
-from architectures import kimi_linear  # noqa: E402
+from architectures import kimi_linear, mellum, qwen3_next  # noqa: E402
+from lib import modelspec  # noqa: E402
 
-GRANITE_CONFIG = json.loads(
-    (BENCH / "configs" / "granite-4.0-h-micro-zero3-1chip.json").read_text())
+def _config(name):
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+GRANITE_CONFIG = _config("granite-4.0-h-micro-zero3-1chip")
+MELLUM_CONFIG = _config("mellum2-12b-ep4-zero3-1chip")
+QNEXT_CONFIG = _config("qwen3-next-80b-ep16-zero3-1chip")
+
+
+# the engine every family's tiny model is trained and lowered under: ZeRO-3
+# bf16 over every device of the mesh
+DS_CONFIG = {
+    "train_batch_size": 8, "bf16": {"enabled": True},
+    "zero_optimization": {"stage": 3},
+    "optimizer": {"type": "AdamW",
+                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
+    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
+    "steps_per_print": 10 ** 9}
 
 
 @pytest.fixture(autouse=True)
@@ -37,11 +60,14 @@ def _telemetry_isolation():
 
 @pytest.fixture(autouse=True)
 def _drop_compiled_programs():
-    """The KDA tests run the kernels eagerly in interpret mode: a call
-    compiles some hundred small programs that no cache ever finds again,
-    each a few memory mappings, and a test worker that has run this file
-    passed the kernel's 65530 mappings a process and died in XLA's
-    compiler (PR 35). Dropping JAX's caches after a test returns them."""
+    """For a file that runs kernels EAGERLY in interpret mode (the two
+    KDA kernel files): a call compiles some hundred small programs that no
+    cache ever finds again, each a few memory mappings, and a test worker
+    that has run such a file passed the kernel's 65530 mappings a process
+    and died in XLA's compiler (PR 35). Dropping JAX's caches after a test
+    returns them. A file whose cases each go through one ``jax.jit`` does
+    not import this (PR 50): it makes every case trace and compile its
+    model's ``init`` and every small eager line again."""
     yield
     jax.clear_caches()
     gc.collect()
@@ -61,6 +87,29 @@ def _batch(model, b=2, s=128, seed=0):
     tok = np.random.default_rng(seed).integers(
         0, model.config.vocab_size, (b, s + 1))
     return jnp.asarray(tok[:, :-1]), jnp.asarray(tok[:, 1:])
+
+
+def _reference_says(arch, config, model, params):
+    """``params``, a batch, what the float32 reference ``arch`` says of them
+    at the margin of the cell's own ``check`` (loss, tail logits, mask), and
+    the reference's model ``m``: what a family's reference comparison and
+    its planted faults are both held to."""
+    tokens, targets = _batch(model)
+    m = modelspec.reference_model(arch, model, config["check"])
+    with jax.default_matmul_precision("highest"):
+        want = arch.reference(params, tokens, targets, m, 32)
+    return params, tokens, targets, want, m
+
+
+def _reference_grads(arch, params, tokens, targets, m):
+    """The gradient of the float32 reference's loss, for an ``arch`` whose
+    head is ``lm_head``; one program (eager, every line of the reference
+    compiles alone)."""
+    def loss(params, tokens, targets):
+        hidden, _ = arch._forward(params, tokens, m)
+        return arch.loss_of(hidden, params["lm_head"], targets)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.grad(loss))(params, tokens, targets)
 
 
 # ---- Kimi-Linear -----------------------------------------------------------
@@ -119,6 +168,34 @@ def granite_weights(model, seed=3):
         model.init(jax.random.PRNGKey(seed)))
 
 
+# ---- Mellum 2 --------------------------------------------------------------
+def mellum_tiny(**kw):
+    kw.setdefault("moe_held_experts", 16)
+    return Mellum(size="tiny", **kw)
+
+
+def mellum_weights(model, seed=3):
+    """Seeded weights under which the attention layers AND the experts
+    carry weight in the logits (``PERF.md`` section 2 found for Granite
+    that at the init's own scale a softmax is near uniform and a layer's
+    output projection small, so no check could see a fault in the layer):
+    sharper scores, larger values, larger experts."""
+    boost = {"tokens": 0.02, "wq": 4.0, "wk": 4.0, "wv": 8.0, "wo": 8.0,
+             "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0}
+    return jax.tree_util.tree_map_with_path(
+        lambda path, w: w * boost.get(path[-1].key, 1.0),
+        model.init(jax.random.PRNGKey(seed)))
+
+
+@functools.lru_cache(maxsize=None)
+def mellum_right(held: int = 16):
+    """``_reference_says`` of the right model's boosted weights with
+    ``held`` of the 64 experts held."""
+    model = mellum_tiny(loss_chunk=64, moe_held_experts=held)
+    return _reference_says(mellum, MELLUM_CONFIG, model,
+                           mellum_weights(model))
+
+
 # ---- Qwen3-Next ------------------------------------------------------------
 def qnext_tiny(**kw):
     """32 of the tiny preset's 512 experts held, and the attention layer's
@@ -126,3 +203,34 @@ def qnext_tiny(**kw):
     kw.setdefault("moe_held_experts", 32)
     kw.setdefault("qk_norm_init", 2.0)
     return Qwen3Next(size="tiny", **kw)
+
+
+def qnext_weights(model, seed=3):
+    """Seeded weights under which every part this family adds carries
+    weight in the logits: a small embedding under larger values, outputs
+    and experts; a shared expert's gate and a decay off their flat middle;
+    and every norm weight drawn (they start at 0 or 1, where ``(1 + w)``
+    and ``w`` cannot be told from a missing weight)."""
+    boost = {"tokens": 0.05, "wv": 4.0, "wo": 8.0, "w_ba": 20.0,
+             "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0, "shared_gate": 50.0}
+    norms = {"ln1_scale", "ln2_scale", "scale", "q_norm", "k_norm", "o_norm"}
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def one(path, w):
+        name = path[-1].key
+        if name in norms:
+            return w + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype)
+        if name == "A_log":         # slow heads too: the state has to matter
+            return w - 4.0
+        return w * boost.get(name, 1.0)
+
+    return jax.tree_util.tree_map_with_path(
+        one, model.init(jax.random.PRNGKey(seed)))
+
+
+@functools.lru_cache(maxsize=None)
+def qnext_right():
+    """``_reference_says`` of the right model's boosted weights."""
+    model = qnext_tiny()
+    return _reference_says(qwen3_next, QNEXT_CONFIG, model,
+                           qnext_weights(model))

@@ -23,21 +23,25 @@ def program_only(hlo: str) -> str:
                   lambda m: ids.setdefault(m.group(), f"%{len(ids)}"), text)
 
 
-def bare_step(engine, batch, ds_config, monkeypatch) -> tuple[str, str]:
-    """(the engine's compiled step, the same step built again with every
-    ``jax.named_scope`` a null context), both as ``program_only``."""
+def step_hlo(engine, batch) -> str:
+    """The engine's compiled train step as text. A whole compile: a file
+    that reads it in several tests keeps it in a module's fixture."""
+    return engine._train_step.lower(
+        engine.state, engine._put_batch(batch)).compile().as_text()
+
+
+def bare_step(engine, batch, ds_config, monkeypatch, hlo) -> tuple[str, str]:
+    """(the engine's compiled step ``hlo``, the same step built again with
+    every ``jax.named_scope`` a null context), both as ``program_only``."""
     import contextlib
 
     import jax
 
     import deepspeed_tpu as ds
-    text = lambda e: program_only(e._train_step.lower(  # noqa: E731
-        e.state, e._put_batch(batch)).compile().as_text())
-    named = text(engine)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare, *_ = ds.initialize(model=engine.module, config=dict(ds_config))
-    return named, text(bare)
+    return program_only(hlo), program_only(step_hlo(bare, batch))
 
 
 def assert_conv_scope_is_the_kernels(hlo: str, mixer: str, others) -> None:

@@ -3,10 +3,12 @@ grouped-query attention layer among them, a SwiGLU in every layer, the four
 muP multipliers and a tied head, checked on the CPU at tiny sizes against
 the plain float32 reference the benchmark keeps
 (``benchmark/architectures/granite_hybrid.py``, which imports nothing from
-the program). The cell's limits against planted faults are
-``tests/test_granite_hybrid_limits.py``, and both callers of the short
+the program). The whole model's loss and gradients against that reference
+are ``tests/test_granite_hybrid_reference.py``'s (PR 50), the cell's limits
+against planted faults ``tests/test_granite_hybrid_limits.py``'s, and both
+callers of the short
 convolution's kernels (ISSUE 43) held to their parents' loss and gradients
-``tests/test_short_conv_step.py`` (PR 45: a file is one worker's under
+``tests/test_short_conv_step.py``'s (PR 45: a file is one worker's under
 ``--dist loadfile``, and this one was 618 s); what they share is
 ``tests/helpers/family_cases.py``. A CPU run shows results and counts,
 never a time."""
@@ -28,13 +30,13 @@ from deepspeed_tpu.ops.ssd import chunk_ssd, recurrent_ssd
 from deepspeed_tpu.telemetry import scopes
 
 from helpers import hlo_text  # noqa: E402  (tests/helpers)
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG
 from helpers.family_cases import GRANITE_CONFIG as CONFIG
 from helpers.family_cases import (BENCH, _batch, _err,  # noqa: F401
                                   _telemetry_isolation)
 from architectures import granite_hybrid as arch  # noqa: E402  (benchmark/,
 #                                           on sys.path by family_cases)
 from helpers.family_cases import granite_tiny as _tiny
-from helpers.family_cases import granite_weights as _weights
 from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
 
 
@@ -109,37 +111,6 @@ def test_chunked_ssd_agrees_with_the_benchmarks_recurrence():
         chunk_ssd(x[:, :, :3], dt[:, :, :3], A[:3], B, C, chunk=32)
 
 
-# ---- the whole model against the plain reference ---------------------------
-def _ref_loss(params, tokens, targets, m):
-    hidden = arch.final_hidden(params, tokens, m) / m["logits_scaling"]
-    return arch.loss_of(hidden, params["embed"]["tokens"].T, targets)
-
-
-@pytest.mark.parametrize("variant", ["plain", "flash_chunked_loss",
-                                     "no_remat"])
-def test_loss_and_gradients_match_the_float32_reference(variant):
-    kw = {"plain": {},
-          "flash_chunked_loss": dict(attn_impl="flash", loss_chunk=64),
-          "no_remat": dict(remat=False)}[variant]
-    model = _tiny(**kw)
-    params = _weights(model)
-    tokens, targets = _batch(model)
-    m = modelspec.reference_model(arch, model)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(_ref_loss)(params, tokens,
-                                                     targets, m)
-        got, got_g = jax.value_and_grad(model.loss)(params,
-                                                    (tokens, targets))
-    assert abs(float(got) - float(want)) <= 2e-5 * float(want)
-    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
-    flat_g = jax.tree_util.tree_leaves_with_path(got_g)
-    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
-    for (path, w), (_, g) in zip(flat_w, flat_g):
-        name = jax.tree_util.keystr(path)
-        assert float(jnp.max(jnp.abs(w))) > 0, name
-        assert _err(g, w) < 2e-3, name
-
-
 # ---- the configuration, the counts, the plan -------------------------------
 def test_the_configuration_file_builds_the_published_model():
     """``lib/modelspec.py`` holds the model as built to every published
@@ -201,13 +172,6 @@ def test_required_operations_by_hand():
 
 
 # ---- the engine ------------------------------------------------------------
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
 
 
 @pytest.fixture(scope="module")
@@ -215,6 +179,11 @@ def granite_engine():
     model = _tiny(attn_impl="flash", loss_chunk=64)
     engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
     return engine, _batch(model, b=8)
+
+
+@pytest.fixture(scope="module")
+def hlo(granite_engine):
+    return hlo_text.step_hlo(*granite_engine)
 
 
 def test_engine_trains_through_the_compiled_step(granite_engine):
@@ -232,10 +201,7 @@ def test_engine_trains_through_the_compiled_step(granite_engine):
     assert np.all(moved.max(axis=1) > 0)    # every row: the head's share
 
 
-def test_step_scopes_are_the_lists(granite_engine):
-    engine, batch = granite_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
+def test_step_scopes_are_the_lists(hlo):
     found = set()
     for op_name in re.findall(r'op_name="([^"]*)"', hlo):
         found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
@@ -257,7 +223,7 @@ def test_step_scopes_are_the_lists(granite_engine):
 
 
 def test_the_mixer_parts_lie_inside_ds_mamba_and_no_kind_is_unknown(
-        granite_engine):
+        hlo):
     """ISSUE 36: the convolution and what lies before and after the scan
     are named inside ds.mamba, straight under it in both directions and
     never inside the attention layer or the FFN (the compiler moves an
@@ -265,9 +231,6 @@ def test_the_mixer_parts_lie_inside_ds_mamba_and_no_kind_is_unknown(
     holds theirs); the table of kinds knows every instruction of the
     step. ISSUE 43: the convolution is a kernel pair that holds the SiLU
     too."""
-    engine, batch = granite_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values()}
     for part in scopes.MIXER_SCOPES:
@@ -284,11 +247,12 @@ def test_the_mixer_parts_lie_inside_ds_mamba_and_no_kind_is_unknown(
     assert not unknown, unknown
 
 
-def test_the_named_scopes_are_metadata_and_nothing_else(granite_engine,
-                                                        monkeypatch):
+def test_the_named_scopes_are_metadata_and_nothing_else(
+        granite_engine, hlo, monkeypatch):
     """The step compiled with every ``jax.named_scope`` a null context is
     the same optimized program once ``metadata={...}`` is taken out."""
-    named, bare = hlo_text.bare_step(*granite_engine, _DS_CONFIG, monkeypatch)
+    named, bare = hlo_text.bare_step(*granite_engine, _DS_CONFIG,
+                                     monkeypatch, hlo)
     assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
     assert bare == named
 

@@ -30,13 +30,14 @@ from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
 @pytest.mark.parametrize("groups", [1, 3])
 def test_chunked_kda_matches_the_recurrence_forward_and_backward(groups):
     args = _kda_inputs()
-    want = recurrent_kda(*args)
-    got = chunk_kda(*args, head_groups=groups)
+    # jitted: eager, every line round the kernels compiles alone
+    want = jax.jit(recurrent_kda)(*args)
+    got = jax.jit(lambda *a: chunk_kda(*a, head_groups=groups))(*args)
     _close(got, want, 1e-5, "forward")
     w = jnp.asarray(np.random.default_rng(1).normal(size=want.shape),
                     jnp.float32)
-    grad = lambda f: jax.grad(  # noqa: E731
-        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args)
     for name, g, r in zip("qkvgb", grad(
             lambda *a: chunk_kda(*a, head_groups=groups)),
             grad(recurrent_kda)):
@@ -46,7 +47,8 @@ def test_chunked_kda_matches_the_recurrence_forward_and_backward(groups):
 
 def test_chunked_kda_agrees_with_the_benchmarks_recurrence_and_its_shape():
     args = _kda_inputs(b=1, s=128, h=2)
-    _close(chunk_kda(*args), arch.kda_recurrence(*args), 1e-5)
+    _close(jax.jit(chunk_kda)(*args), jax.jit(arch.kda_recurrence)(*args),
+           1e-5)
     with pytest.raises(ValueError, match="multiple of the chunk"):
         chunk_kda(*[a[:, :100] for a in args])
 
@@ -64,15 +66,16 @@ def test_kda_kernels_match_the_recurrence(groups, seq, dtype, monkeypatch):
     tol_o, tol_g = 1e-5, 2e-5
     if dtype == "bfloat16":
         args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
-    want = recurrent_kda(*args)
-    got = chunk_kda(*args, head_groups=groups)
+    # jitted: eager, every line round the kernels compiles alone
+    want = jax.jit(recurrent_kda)(*args)
+    got = jax.jit(lambda *a: chunk_kda(*a, head_groups=groups))(*args)
     assert got.dtype == args[2].dtype
     _close(got.astype(jnp.float32), want, tol_o, "forward")
     w = jnp.asarray(np.random.default_rng(1).normal(size=want.shape),
                     jnp.float32)
-    grad = lambda f: jax.grad(  # noqa: E731
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
         lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w),
-        argnums=(0, 1, 2, 3, 4))(*args)
+        argnums=(0, 1, 2, 3, 4)))(*args)
     for name, g, r in zip("qkvgb", grad(
             lambda *a: chunk_kda(*a, head_groups=groups)),
             grad(recurrent_kda)):

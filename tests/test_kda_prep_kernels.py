@@ -39,15 +39,20 @@ def test_chunked_kda_stays_finite_where_a_channel_forgets_in_one_token(
     tol_o, tol_g = 1e-5, 2e-5
     if dtype == "bfloat16":
         args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
-    f32 = lambda f: lambda *a: f(*a).astype(jnp.float32)  # noqa: E731
-    want = recurrent_kda(*args)
-    got = f32(chunk_kda)(*args)
+
+    def both(f):
+        """``f``'s float32 output and the gradients of its sum, one
+        program (eager, every line round the kernels compiles alone)."""
+        def total(*a):
+            out = f(*a).astype(jnp.float32)
+            return jnp.sum(out), out
+        return jax.jit(jax.value_and_grad(
+            total, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    (_, want), want_g = both(recurrent_kda)
+    (_, got), grads = both(chunk_kda)
     assert bool(jnp.all(jnp.isfinite(got)))
     _close(got, want, tol_o, "forward")
-    grads = jax.grad(lambda *a: jnp.sum(f32(chunk_kda)(*a)),
-                     argnums=(0, 1, 2, 3, 4))(*args)
-    want_g = jax.grad(lambda *a: jnp.sum(recurrent_kda(*a)),
-                      argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("qkvgb", grads, want_g):
         assert bool(jnp.all(jnp.isfinite(a))), name
         _close(a.astype(jnp.float32), b.astype(jnp.float32), tol_g,

@@ -20,16 +20,10 @@ from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
 from deepspeed_tpu.ops.pallas import _common
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-from helpers.family_cases import (_batch, _drop_compiled_programs,  # noqa: F401,E501
-                                  _telemetry_isolation, _walk_eqns)
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG
+from helpers.family_cases import (_batch, _telemetry_isolation,  # noqa: F401
+                                  _walk_eqns)
 
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
 
 # family -> (class, the `tiny` preset's switches, attention layer
 # applications in the TRACED program: a scan's body is traced once, so

@@ -4,12 +4,14 @@ at tiny sizes against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/kimi_linear.py``, which imports nothing from
 the program). A CPU run shows results and counts, never a time. A file is
 one worker's under ``--dist loadfile`` (this one was 1381 s of the gate's
-1470 at PR 41, 906 at PR 44), so the family's cases lie in five: the cases
+1470 at PR 41, 906 at PR 44), so the family's cases lie in six: the cases
 that train the engine are ``tests/test_kimi_linear_engine.py`` (PR 41),
 the KDA kernels' ``tests/test_kda_kernels.py`` and
 ``tests/test_kda_prep_kernels.py``, the cell's limits, the router and the
-parents' programs ``tests/test_kimi_linear_limits.py`` (PR 45); what they
-share is ``tests/helpers/family_cases.py``."""
+parents' programs ``tests/test_kimi_linear_limits.py`` (PR 45), the whole
+model against the float32 reference
+``tests/test_kimi_linear_reference.py`` (PR 50); what they share is
+``tests/helpers/family_cases.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -20,47 +22,10 @@ from deepspeed_tpu.models import KimiLinear
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-from helpers.family_cases import (_batch, _close,  # noqa: F401
-                                  _drop_compiled_programs,
-                                  _telemetry_isolation)
+from helpers.family_cases import _close, _telemetry_isolation  # noqa: F401
 from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
 #                                           on sys.path by family_cases)
-from helpers.family_cases import kimi_ref_loss as _ref_loss
 from helpers.family_cases import kimi_tiny as _tiny
-from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
-
-
-# ---- the whole model against the plain reference ---------------------------
-@pytest.mark.parametrize("variant", ["plain", "flash_chunked_loss_groups",
-                                     "no_remat"])
-def test_loss_and_gradients_match_the_float32_reference(variant):
-    kw = {"plain": {},
-          "flash_chunked_loss_groups": dict(attn_impl="flash", loss_chunk=64,
-                                            kda_head_groups=2),
-          "no_remat": dict(remat=False)}[variant]
-    model = _tiny(**kw)
-    params = model.init(jax.random.PRNGKey(3))
-    tokens, targets = _batch(model)
-    m = modelspec.reference_model(arch, model, {"routing_margin": 0.0,
-                                                "excluded_share_max": 1.0})
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(_ref_loss)(params, tokens,
-                                                     targets, m)
-        # jitted: eager, every interpreted kernel call compiles alone
-        got, got_g = jax.jit(jax.value_and_grad(model.loss))(
-            params, (tokens, targets))
-    assert abs(float(got) - float(want)) <= 2e-5 * float(want)
-    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
-    flat_g = jax.tree_util.tree_leaves_with_path(got_g)
-    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
-    for (path, w), (_, g) in zip(flat_w, flat_g):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['router_bias']"):
-            # selection only: no gradient reaches the correction bias
-            assert not np.any(np.asarray(g)), name
-            continue
-        assert float(jnp.max(jnp.abs(w))) > 0, name
-        _close(g, w, 2e-3, name)
 
 
 # ---- MLA: the flash path (key 24, value 16) against plain softmax ----------
@@ -71,11 +36,12 @@ def test_flash_attention_with_a_narrower_value_matches_plain_softmax():
     v = jnp.asarray(rng.normal(size=(2, 256, 4, 16)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(2, 256, 4, 16)), jnp.float32)
     want = arch.causal_attention(q, k, v)
-    got = flash_attention(q, k, v, causal=True)
+    # jitted: eager, every line round the kernels compiles alone
+    got = jax.jit(lambda *a: flash_attention(*a, causal=True))(q, k, v)
     assert got.shape == (2, 256, 4, 16)
     _close(got, want, 1e-5, "forward")
-    grad = lambda f: jax.grad(  # noqa: E731
-        lambda q, k, v: jnp.sum(f(q, k, v) * w), argnums=(0, 1, 2))(q, k, v)
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(f(q, k, v) * w), argnums=(0, 1, 2)))(q, k, v)
     for name, g, r in zip("qkv", grad(flash_attention),
                           grad(arch.causal_attention)):
         _close(g, r, 1e-4, f"d{name}")
@@ -89,7 +55,8 @@ def test_mla_layer_through_flash_matches_the_plain_layer():
     want = arch.mla_mixer(p, h, heads=c.num_heads, nope=c.qk_nope_head_dim,
                           rope=c.qk_rope_head_dim, dv=c.v_head_dim,
                           lora=c.kv_lora_rank, eps=c.norm_eps)
-    got = _tiny(attn_impl="flash")._mla(p, h, flash_attention)
+    got = jax.jit(lambda p, h: _tiny(attn_impl="flash")._mla(
+        p, h, flash_attention))(p, h)
     _close(got, want, 1e-5)
 
 

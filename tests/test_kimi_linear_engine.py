@@ -15,6 +15,7 @@ from deepspeed_tpu.moe.sharded_moe import BIAS_UPDATE_RATE
 from deepspeed_tpu.telemetry import scopes
 
 from helpers import hlo_text  # noqa: E402  (tests/helpers)
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG
 from helpers.family_cases import _batch
 from helpers.family_cases import kimi_tiny as _tiny
 
@@ -26,20 +27,16 @@ def _telemetry_isolation():
     telemetry.shutdown()
 
 
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
-
-
 @pytest.fixture(scope="module")
 def kimi_engine():
     model = _tiny(attn_impl="flash", loss_chunk=64)
     engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
     return engine, _batch(model, b=8)
+
+
+@pytest.fixture(scope="module")
+def hlo(kimi_engine):
+    return hlo_text.step_hlo(*kimi_engine)
 
 
 def test_engine_trains_and_only_after_step_moves_the_router_bias(kimi_engine):
@@ -94,10 +91,7 @@ def test_traced_and_untraced_steps_are_one_program_and_the_counts_land(
     assert 16 < low <= rows / (calls * 8) <= high < 48
 
 
-def test_step_scopes_are_the_lists(kimi_engine):
-    engine, batch = kimi_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
+def test_step_scopes_are_the_lists(hlo):
     found = set()
     for op_name in re.findall(r'op_name="([^"]*)"', hlo):
         found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
@@ -126,16 +120,14 @@ def test_step_scopes_are_the_lists(kimi_engine):
 
 
 def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
-        kimi_engine):
+        kimi_engine, hlo):
     """ISSUE 36: the convolution and what lies before and after the scan
     are named inside ds.kda, straight under it in both directions and
     never inside the MLA layer; the layer's pre-norm counts with its
     mixer; and the table of kinds knows every instruction of the step.
     ISSUE 43: the convolution is a kernel pair that holds the SiLU and
     the l2 norms too."""
-    engine, batch = kimi_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
+    engine = kimi_engine[0]
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values()}
     for part in scopes.MIXER_SCOPES:
@@ -162,10 +154,11 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
     assert not unknown, unknown
 
 
-def test_the_named_scopes_are_metadata_and_nothing_else(kimi_engine,
-                                                        monkeypatch):
+def test_the_named_scopes_are_metadata_and_nothing_else(
+        kimi_engine, hlo, monkeypatch):
     """The step compiled with every ``jax.named_scope`` a null context is
     the same optimized program once ``metadata={...}`` is taken out."""
-    named, bare = hlo_text.bare_step(*kimi_engine, _DS_CONFIG, monkeypatch)
+    named, bare = hlo_text.bare_step(*kimi_engine, _DS_CONFIG, monkeypatch,
+                                     hlo)
     assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
     assert bare == named

@@ -21,7 +21,6 @@ from deepspeed_tpu.ops import kda as kda_ops
 from deepspeed_tpu.ops.pallas import kda as kda_kernels
 
 from helpers.family_cases import (BENCH, _batch, _close,  # noqa: F401
-                                  _drop_compiled_programs,
                                   _telemetry_isolation)
 from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
 #                                           on sys.path by family_cases)

@@ -3,17 +3,15 @@ its own rotary table (plain, YaRN), an explicit head width, and in every
 layer a held share of softmax-routed experts, checked on the CPU at tiny
 sizes against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/mellum.py``, which imports nothing from the
-program); and the three other families' train steps held to the programs
-they were before this PR. A CPU run shows results and counts, never a
+program). The whole model's loss and gradients against that reference are
+``tests/test_mellum_reference.py``'s (PR 50: a file is one worker's under
+``--dist loadfile``), the other families' train steps
+``tests/test_step_pins.py``'s. A CPU run shows results and counts, never a
 time."""
 
-import functools
-import hashlib
 import json
 import math
-import pathlib
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +20,7 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
-                                  ModelConfig)
+from deepspeed_tpu.models import Mellum, ModelConfig
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.moe.sharded_moe import (_held_layout, held_block,
@@ -33,119 +30,22 @@ from deepspeed_tpu.moe.sharded_moe import (_held_layout, held_block,
 from deepspeed_tpu.ops import layers as L
 from deepspeed_tpu.telemetry import scopes
 
-BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-if str(BENCH) not in sys.path:
-    sys.path.insert(0, str(BENCH))
-from architectures import mellum as arch  # noqa: E402
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG
+from helpers.family_cases import MELLUM_CONFIG as CONFIG
+from helpers.family_cases import (_batch, _err,  # noqa: F401
+                                  _telemetry_isolation, mellum_right)
+from helpers.family_cases import mellum_tiny as _tiny
+from architectures import mellum as arch  # noqa: E402  (benchmark/, on
+#                                           sys.path by family_cases)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
 
-CONFIG = json.loads(
-    (BENCH / "configs" / "mellum2-12b-ep4-zero3-1chip.json").read_text())
 PUBLISHED_YARN = CONFIG["rope_parameters"]["full_attention"]
-
-
-@pytest.fixture(autouse=True)
-def _telemetry_isolation():
-    telemetry.shutdown()
-    yield
-    telemetry.shutdown()
-
-
-def _err(got, want):
-    return float(jnp.max(jnp.abs(got - want))) / (
-        float(jnp.max(jnp.abs(want))) + 1e-30)
-
-
-def _tiny(**kw):
-    kw.setdefault("moe_held_experts", 16)
-    return Mellum(size="tiny", **kw)
-
-
-def _batch(model, b=2, s=128, seed=0):
-    tok = np.random.default_rng(seed).integers(
-        0, model.config.vocab_size, (b, s + 1))
-    return jnp.asarray(tok[:, :-1]), jnp.asarray(tok[:, 1:])
-
-
-def _weights(model, seed=3):
-    """Seeded weights under which the attention layers AND the experts
-    carry weight in the logits (``PERF.md`` section 2 found for Granite
-    that at the init's own scale a softmax is near uniform and a layer's
-    output projection small, so no check could see a fault in the layer):
-    sharper scores, larger values, larger experts."""
-    boost = {"tokens": 0.02, "wq": 4.0, "wk": 4.0, "wv": 8.0, "wo": 8.0,
-             "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0}
-    return jax.tree_util.tree_map_with_path(
-        lambda path, w: w * boost.get(path[-1].key, 1.0),
-        model.init(jax.random.PRNGKey(seed)))
-
-
-def _ref_loss(params, tokens, targets, m):
-    hidden, _ = arch._forward(params, tokens, m)
-    return arch.loss_of(hidden, params["lm_head"], targets)
-
-
-# ---- the whole model against the plain reference ---------------------------
-@functools.lru_cache(maxsize=None)
-def _right(held: int):
-    """The right model's boosted weights with ``held`` of the 64 experts
-    held, a batch, what the float32 reference says of them at the cell's
-    own margin (loss, tail logits, mask), and the reference's gradient."""
-    model = _tiny(loss_chunk=64, moe_held_experts=held)
-    params = _weights(model)
-    tokens, targets = _batch(model)
-    m = modelspec.reference_model(arch, model, CONFIG["check"])
-    with jax.default_matmul_precision("highest"):
-        want = arch.reference(params, tokens, targets, m, 32)
-        grads = jax.grad(_ref_loss)(params, tokens, targets, m)
-    return params, tokens, targets, want, grads
 
 
 @pytest.fixture(scope="module")
 def right():
-    return _right(16)
-
-
-@pytest.mark.parametrize("variant", ["plain", "flash_chunked_loss",
-                                     "flash_whole_layer_held"])
-def test_loss_logits_and_gradients_match_the_float32_reference(variant):
-    """Loss to 2e-5 (float32 sums in another order), tail logits to 5e-4
-    of their largest (the boosted scores sharpen the softmax, which
-    amplifies the last bits), and on the cell's path (flash kernels,
-    chunked loss, every layer rematted) every gradient to 2e-3 of its
-    largest (the kernels' online softmax and the dispatch's scatter-adds
-    sum in another order than the reference's dense forms). A share (16
-    of 64 held) leaves the routing alone in the backward: its routers'
-    gradients are zero on both sides, and what flows to the layer's input
-    flows through the experts alone; with the whole layer held the router
-    trains and its gradient is compared like the others."""
-    held = 64 if variant == "flash_whole_layer_held" else 16
-    kw = dict(remat=False) if variant == "plain" else dict(
-        attn_impl="flash", loss_chunk=64)
-    model = _tiny(moe_held_experts=held, **kw)
-    params, tokens, targets, (want, want_tail, _), want_g = _right(held)
-    with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
-    assert abs(float(got) - want) <= 2e-5 * want
-    assert _err(got_tail, want_tail) < 5e-4
-    if got_g is None:
-        return
-    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
-    flat_g = jax.tree_util.tree_leaves_with_path(got_g)
-    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
-    for (path, w), (_, g) in zip(flat_w, flat_g):
-        name = jax.tree_util.keystr(path)
-        if held < 64 and name.endswith("['router']"):
-            assert not np.any(w) and not np.any(g), name
-            continue
-        assert float(jnp.max(jnp.abs(w))) > 0, name
-        assert _err(g, w) < 2e-3, name
+    return mellum_right(16)
 
 
 # ---- the rotary tables -----------------------------------------------------
@@ -468,15 +368,6 @@ def test_required_operations_by_hand():
 
 
 # ---- the engine ------------------------------------------------------------
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
-
-
 @pytest.fixture(scope="module")
 def mellum_engine():
     model = _tiny(attn_impl="flash", loss_chunk=64)
@@ -579,88 +470,3 @@ def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
     assert not any(rx.search(p) for p in kernels)
     unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
     assert not unknown, unknown
-
-
-# ---- the three other families' steps are the parents' programs -------------
-# two layers of each stack: every kind of layer this PR's edits reach (a
-# routed layer behind a KDA and an MLA mixer; a Mamba and an attention layer);
-# beside each the sha256 of its lowered train step and the sum of its seeded
-# master weights' magnitudes AT THE PARENT (commit 2d920a0, this file's
-# `_step_text` run on that checkout). All three families' weights are still
-# that parent's. The hashes are PR 47's: a rematted layer now keeps the
-# flash kernel's `o` and `lse` (`ops/pallas/_common.py` `KEPT_RESIDUAL`,
-# `models/transformer.py` `_remat_policy`), so `kimi_linear`'s and
-# `granite_hybrid`'s steps under `nothing_saveable` hold `ds_flash_fwd`
-# once a layer and were taken again from PR 47's tree (before it:
-# `granite_hybrid`'s PR 43's, the short convolution's kernel pair;
-# `kimi_linear`'s PR 44's, two heads to a product in the preparation).
-# `mistral` runs `remat_policy="segments"`, attention outside every
-# `jax.checkpoint`, where the name does nothing: its hash was taken AT PR
-# 47'S PARENT (commit f3a203d, `_renumbered(_step_text(...))` on that
-# checkout) and holds in PR 47's tree, so the Mistral cells run the
-# parent's program. It is the hash of the text with its symbols
-# renumbered, because the raw text's (3f2beba9... at that parent) cannot
-# hold: JAX lowers every distinct equation as a private function named
-# after its primitive and inlines it, the two new `name` equations are two
-# more such functions, and MLIR's symbol table numbers every LATER
-# collision two higher (`@closed_call_168` is `@closed_call_170`, eleven
-# such names and no other character of 3501 lines). `kimi_linear`'s is PR
-# 48's, taken again from its tree: the held sweep's add to tokens is the
-# kernel `ds_moe_add_rows` after one more sort and gather, not XLA's
-# scatter-add (`moe/sharded_moe.py` `_held_sweep`), which is that step's
-# program by design; `granite_hybrid` and `mistral` hold no routed layer and
-# keep PR 47's hashes.
-_FAMILIES = {
-    "kimi_linear": (KimiLinear, dict(
-        num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
-        first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64, kda_head_groups=2),
-        "bdc8d6163d8b71d21575f11baf26bab61e08ef4277a2e33f628d0066ae4ba799",
-        7191.956369750438),
-    "granite_hybrid": (GraniteHybrid, dict(
-        num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
-        loss_chunk=64),
-        "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
-        2422.812915172007),
-    "mistral": (Mistral, dict(attn_impl="flash", loss_chunk=64,
-                              remat_policy="segments", sliding_window=64),
-                "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
-                2339.9930015786545),
-}
-
-
-def _renumbered(text: str) -> str:
-    """``text`` with every symbol (``@name``) renamed by the order of its
-    first appearance: what is left is the program, whatever numbers MLIR's
-    symbol table gave the private functions' names."""
-    table = {}
-    return re.sub(r"@[\w.]+", lambda m: table.setdefault(
-        m.group(0), f"@f{len(table)}"), text)
-
-
-def _step_text(family: str):
-    cls, model_kw, *_ = _FAMILIES[family]
-    model = cls(size="tiny", **model_kw)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    tok = np.zeros((8, model.config.max_seq_len), np.int32)
-    text = engine._train_step.lower(
-        engine.state, engine._put_batch((tok, tok))).as_text()
-    leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
-    return text, float(sum(np.abs(x.astype(np.float64)).sum()
-                           for x in leaves))
-
-
-@pytest.mark.parametrize("family", list(_FAMILIES))
-def test_the_other_families_steps_are_the_parents_programs(family):
-    """What this PR touched lies on another family's path too (the head
-    width's property, the rotary table's builder, the held layer's entry
-    and its block rule, the routed stack's ``after_step`` and ``loss``):
-    the lowered train step is the parent's text (no source locations in
-    it) and the seeded weights the parent's numbers, at the switches the
-    cells run."""
-    text, weights = _step_text(family)
-    _, _, parent_text, parent_weights = _FAMILIES[family]
-    assert "loc(" not in text
-    assert hashlib.sha256(
-        _renumbered(text).encode()).hexdigest() == parent_text
-    assert weights == parent_weights

@@ -5,7 +5,6 @@ tiny preset against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/ouro.py``, which imports nothing from the
 program). A CPU run shows results and counts, never a time."""
 
-import hashlib
 import pathlib
 import re
 import sys
@@ -17,9 +16,8 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import Mellum, Ouro, get_model_class
+from deepspeed_tpu.models import Ouro, get_model_class
 from deepspeed_tpu.models.transformer import (_chunk_logits,
-                                              _chunked_cross_entropy,
                                               _chunked_weighted_cross_entropy)
 from deepspeed_tpu.parallel.partition import match_rules
 from deepspeed_tpu.telemetry import scopes
@@ -29,6 +27,8 @@ if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 from architectures import ouro as arch  # noqa: E402
 from lib import modelspec  # noqa: E402
+
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG  # noqa: E402
 
 TAIL = 32
 
@@ -249,21 +249,6 @@ def test_weighted_chunked_head_is_the_plain_forms_gradient(dtype):
         assert g.dtype == w.dtype and _err(g, w) < tol
 
 
-def test_the_unweighted_head_is_the_parents_program():
-    """``_chunked_cross_entropy`` is on the path of every cell: with no
-    weights its lowered text (value and gradient) is the parent's (commit
-    9909adf; no source locations in it)."""
-    x = jax.ShapeDtypeStruct((2, 128, 64), jnp.bfloat16)
-    W = jax.ShapeDtypeStruct((64, 512), jnp.bfloat16)
-    t = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(jax.value_and_grad(
-        lambda x, W, t: _chunked_cross_entropy(x, W, None, t, 32),
-        argnums=(0, 1))).lower(x, W, t).as_text()
-    assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "e4b425dd87402f9216cae4e4bd9f1e349f6e3fb5515488abb2badeecb6e8e10f")
-
-
 # ---- the family, its refusals ----------------------------------------------
 def test_registry_presets_and_partition_rules():
     assert get_model_class("ouro") is Ouro
@@ -312,15 +297,6 @@ def test_what_runs_a_layer_at_a_time_refuses():
 
 
 # ---- through the engine ----------------------------------------------------
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
-
-
 @pytest.fixture(scope="module")
 def ouro_engine():
     model = _tiny(attn_impl="flash", loss_chunk=64)
@@ -430,29 +406,3 @@ def test_four_devices_agree_with_one(devices8, monkeypatch):
     for path in _GRADS:
         assert _err(_leaf(got[4][1], path), _leaf(got[1][1], path)) < 2e-2, \
             path
-
-
-# ---- the fourth other family's step is the parent's program ----------------
-def test_mellums_step_is_the_parents_program():
-    """``tests/test_mellum.py`` holds the lowered train steps of
-    ``mistral``, ``kimi_linear`` and ``granite_hybrid`` to their parents'
-    (unchanged here: this PR's edits lie on all their paths, the head, the
-    engine's feed and ``ModelConfig`` among them); this holds the fourth,
-    ``mellum``, to the seeded weights of commit 9909adf. The text's hash
-    is PR 48's, taken from its tree: the held sweep's add to tokens is the
-    kernel ``ds_moe_add_rows`` after one more sort and gather, not XLA's
-    scatter-add (``moe/sharded_moe.py`` ``_held_sweep``), which is this
-    step's program by design (PR 47's before it: a rematted layer keeps
-    the flash kernel's ``o`` and ``lse``)."""
-    model = Mellum(size="tiny", moe_held_experts=16, attn_impl="flash",
-                   loss_chunk=64)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    tok = np.zeros((8, model.config.max_seq_len), np.int32)
-    text = engine._train_step.lower(
-        engine.state, engine._put_batch((tok, tok))).as_text()
-    leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
-    assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "52c05205bb9db5bbef6a9d3fcba20c9a274748fe38d1c70f1e305e226ffdcc27")
-    assert float(sum(np.abs(x.astype(np.float64)).sum()
-                     for x in leaves)) == 36510.69588080405

@@ -6,18 +6,16 @@ experts beside a gated shared expert, checked on the CPU at tiny sizes
 against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/qwen3_next.py``, which imports nothing from the
 program). The scan with a gate a head and the held share are
-``tests/test_qwen3_next_scan.py``'s, the engine, the scopes and the five
-other families' train steps ``tests/test_qwen3_next_engine.py``'s (a file
-is one worker's under ``--dist loadfile``). A CPU run shows results and
-counts, never a time."""
+``tests/test_qwen3_next_scan.py``'s, the engine and the scopes
+``tests/test_qwen3_next_engine.py``'s, the whole model's loss, logits and
+gradients against the reference ``tests/test_qwen3_next_reference.py``'s
+(PR 50; a file is one worker's under ``--dist loadfile``). A CPU run shows
+results and counts, never a time."""
 
-import functools
 import json
-import pathlib
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,134 +23,18 @@ from deepspeed_tpu.models import Qwen3Next, get_model_class
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.ops import layers as L
 
-from helpers.family_cases import (_batch, _drop_compiled_programs,  # noqa: F401,E501
-                                  _err, _telemetry_isolation)
+from helpers.family_cases import QNEXT_CONFIG as CONFIG
+from helpers.family_cases import (BENCH, _telemetry_isolation,  # noqa: F401
+                                  qnext_right)
 from helpers.family_cases import qnext_tiny as _tiny
 
-BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-for path in (BENCH, BENCH / "tests"):
-    if str(path) not in sys.path:
-        sys.path.insert(0, str(path))
-from architectures import qwen3_next as arch  # noqa: E402
+if str(BENCH / "tests") not in sys.path:
+    sys.path.insert(0, str(BENCH / "tests"))
+from architectures import qwen3_next as arch  # noqa: E402  (benchmark/, on
+#                                           sys.path by family_cases)
 from gdn_control import FAULTS, plant  # noqa: E402
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
-
-NAME = "qwen3-next-80b-ep16-zero3-1chip"
-CONFIG = json.loads((BENCH / "configs" / f"{NAME}.json").read_text())
-
-
-def _weights(model, seed=3):
-    """Seeded weights under which every part this family adds carries
-    weight in the logits: a small embedding under larger values, outputs
-    and experts; a shared expert's gate and a decay off their flat middle;
-    and every norm weight drawn (they start at 0 or 1, where ``(1 + w)``
-    and ``w`` cannot be told from a missing weight)."""
-    boost = {"tokens": 0.05, "wv": 4.0, "wo": 8.0, "w_ba": 20.0,
-             "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0, "shared_gate": 50.0}
-    norms = {"ln1_scale", "ln2_scale", "scale", "q_norm", "k_norm", "o_norm"}
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def one(path, w):
-        name = path[-1].key
-        if name in norms:
-            return w + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype)
-        if name == "A_log":         # slow heads too: the state has to matter
-            return w - 4.0
-        return w * boost.get(name, 1.0)
-
-    return jax.tree_util.tree_map_with_path(
-        one, model.init(jax.random.PRNGKey(seed)))
-
-
-def _ref_loss(params, tokens, targets, m):
-    hidden, _ = arch._forward(params, tokens, m)
-    return arch.loss_of(hidden, params["lm_head"], targets)
-
-
-# ---- the whole model against the plain reference ---------------------------
-@functools.lru_cache(maxsize=None)
-def _right(held: int = 32):
-    """Boosted weights with ``held`` of the 512 experts held, a batch, what
-    the float32 reference says of them at the cell's own margin (loss,
-    tail logits, mask), and the reference's gradient."""
-    model = _tiny(moe_held_experts=held)
-    params = _weights(model)
-    tokens, targets = _batch(model)
-    m = modelspec.reference_model(arch, model, CONFIG["check"])
-    with jax.default_matmul_precision("highest"):
-        want = arch.reference(params, tokens, targets, m, 32)
-        grads = jax.grad(_ref_loss)(params, tokens, targets, m)
-    return params, tokens, targets, want, grads
-
-
-# the gradients ISSUE 46 names, by the leaf's path
-_NAMED = ("w_qkvz", "w_ba", "A_log", "dt_bias", "o_norm", "conv", "wq",
-          "q_norm", "k_norm", "shared_gate", "w_gate", "ln1_scale")
-
-
-@pytest.mark.parametrize("variant", ["plain_f32", "flash_chunked_loss_f32",
-                                     "flash_chunked_loss_bf16"])
-def test_loss_logits_and_gradients_match_the_float32_reference(variant):
-    """Float32: loss to 2e-5, tail logits to 5e-4 of their largest, and on
-    the cell's path (flash kernels, the chunked scan's kernels, chunked
-    loss, every layer rematted) every gradient to 3e-3 of its largest; a
-    share's routers' gradients are zero on both sides. The gate half of
-    ``W_q`` is compared apart from its query half. bfloat16 weights (what
-    the engine computes with) at the init's own scale against the float32
-    reference on the same weights, over the positions its mask counts:
-    loss to 0.5%, logits to 5% of their largest and 2% rms."""
-    kw = dict(remat=False) if variant == "plain_f32" else dict(
-        attn_impl="flash", loss_chunk=64)
-    model = _tiny(**kw)
-    params, tokens, targets, (want, want_tail, _), want_g = _right()
-    if variant.endswith("bf16"):
-        # the init's own weights, as the cell runs them: under the boost a
-        # rounding of 2^-9 is amplified past any limit worth holding
-        params = model.init(jax.random.PRNGKey(3))
-        m = modelspec.reference_model(arch, model, CONFIG["check"])
-        with jax.default_matmul_precision("highest"):
-            want, want_tail, counted = arch.reference(
-                params, tokens, targets, m, 32)
-        low = jax.tree_util.tree_map(
-            lambda w: w.astype(jnp.bfloat16), params)
-        numbers = train_job.tail_numbers(
-            model.apply(low, tokens)[:, -32:], want_tail, counted)
-        got = float(model.loss(low, (tokens, targets)))
-        assert abs(got - want) <= 5e-3 * want
-        assert numbers["logits_err_max"] < 5e-2, numbers
-        assert numbers["logits_err_rms"] < 2e-2, numbers
-        return
-    with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain_f32":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
-    assert abs(float(got) - want) <= 2e-5 * want
-    assert _err(got_tail, want_tail) < 5e-4
-    if got_g is None:
-        return
-    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
-    flat_g = jax.tree_util.tree_leaves_with_path(got_g)
-    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
-    seen = set()
-    for (path, w), (_, g) in zip(flat_w, flat_g):
-        name = jax.tree_util.keystr(path)
-        seen.add(path[-1].key)
-        if name.endswith("['router']"):
-            assert not np.any(w) and not np.any(g), name
-            continue
-        assert float(jnp.max(jnp.abs(w))) > 0, name
-        assert _err(g, w) < 3e-3, name
-        if path[-1].key == "wq":        # [D, H, (query | gate)]
-            hd = model.config.head_dim
-            halves = lambda x: x.reshape(*x.shape[:-1], -1, 2, hd)  # noqa: E731
-            for half in (0, 1):
-                assert _err(halves(g)[..., half, :],
-                            halves(w)[..., half, :]) < 3e-3, (name, half)
-    assert set(_NAMED) <= seen
 
 
 # ---- planted faults, through the benchmark's own decision ------------------
@@ -164,7 +46,8 @@ def test_the_cells_limits_catch_a_planted_fault(fault):
     reference's: the program passes, each departure
     ``benchmark/tests/gdn_control.py`` plants (the same it plants on the
     chip) does not."""
-    params, tokens, targets, (want_loss, want_tail, counted), _ = _right()
+    params, tokens, targets, (want_loss, want_tail, counted), _ = (
+        qnext_right())
     model = _tiny()
     if fault is not None:
         model = plant(model, fault)

@@ -1,10 +1,9 @@
 """Qwen3-Next (ISSUE 46) through the engine: ``ds.initialize`` under ZeRO-3
 bf16 on one device and on eight, the held experts' counts, the step's
-scopes; and the five other cells' families held to the train steps they
-had before this PR (``tests/test_qwen3_next.py`` holds the model to its
-reference). A CPU run shows results and counts, never a time."""
+scopes (``tests/test_qwen3_next_reference.py`` holds the model to its
+reference, ``tests/test_step_pins.py`` every family's train step to its
+parent's). A CPU run shows results and counts, never a time."""
 
-import hashlib
 import re
 
 import jax
@@ -13,25 +12,14 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
-                                  Ouro)
 from deepspeed_tpu.telemetry import scopes
 
-from helpers.family_cases import (_batch, _drop_compiled_programs,  # noqa: F401,E501
-                                  _telemetry_isolation)
+from helpers.family_cases import DS_CONFIG as _DS_CONFIG
+from helpers.family_cases import _batch, _telemetry_isolation  # noqa: F401
 from helpers.family_cases import qnext_tiny as _tiny
 
 
 # ---- the engine ------------------------------------------------------------
-_DS_CONFIG = {
-    "train_batch_size": 8, "bf16": {"enabled": True},
-    "zero_optimization": {"stage": 3},
-    "optimizer": {"type": "AdamW",
-                  "params": {"lr": 3e-4, "weight_decay": 0.1}},
-    "gradient_clipping": 1.0, "mesh": {"fsdp": -1},
-    "steps_per_print": 10 ** 9}
-
-
 @pytest.fixture(scope="module")
 def qnext_engine():
     model = _tiny(attn_impl="flash", loss_chunk=64)
@@ -116,58 +104,3 @@ def test_step_scopes_are_the_lists_and_each_kernel_lies_in_its_layer(
     flash = [p for p in paths if "ds.flash_" in p]
     assert flash and all(re.search(r"ds\.attn_gated\b.*ds\.flash_", p)
                          for p in flash), flash
-
-
-# ---- the five other cells' families keep their train steps -----------------
-# two layers of each stack; beside each the sha256 of its lowered train step
-# AT THE PARENT (commit 142c688, this file's `_step_text` run on that
-# checkout): what this PR edits lies on their paths too (`ops/kda.py`
-# `chunk_kda` and `kda_prepare`, `moe_ffn_held`'s entry, the scope lists).
-# All five hashes are PR 47's, taken again from its tree: every one of these
-# steps runs under `nothing_saveable` (`mistral` here at its default policy;
-# the `segments` step the Mistral cells run is held to the parent's in
-# `tests/test_mellum.py`), which since PR 47 keeps the flash kernel's `o`
-# and `lse` (`ops/pallas/_common.py` `KEPT_RESIDUAL`, `_remat_policy`): a
-# step holds `ds_flash_fwd` once an attention layer and not twice.
-# `kimi_linear`'s and `mellum`'s are PR 48's, taken again from its tree: the
-# held sweep's add to tokens is the kernel `ds_moe_add_rows` after one more
-# sort and gather, not XLA's scatter-add, which is a routed step's program by
-# design; the three families without a routed layer keep PR 47's.
-_FAMILIES = {
-    "kimi_linear": (KimiLinear, dict(
-        num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
-        first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64, kda_head_groups=2), "a01fbd642111ee72c1f44a792b79b915468e65bff97e6ed0d3247aa40b9da953"),
-    "mellum": (Mellum, dict(
-        num_layers=2, layer_types=["sliding_attention", "full_attention"],
-        moe_held_experts=16, attn_impl="flash", loss_chunk=64),
-        "e3c9878334adf0dbb6ed737c78a38f948feaf92ce7c0dd3a3c2848b3aefccbf6"),
-    "granite_hybrid": (GraniteHybrid, dict(
-        num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
-        loss_chunk=64), "b577bff512e4102d37f268264abfca574a8a597317e25ad4b7c7d7b427ca3775"),
-    "ouro": (Ouro, dict(num_layers=2, attn_impl="flash", loss_chunk=64),
-             "2dbcb2addc2f251025db4b1d77ddf3a90d00956b868171db4bc7ffb28ac2c2a6"),
-    "mistral": (Mistral, dict(attn_impl="flash", loss_chunk=64,
-                              sliding_window=64), "4338c9cbb6e43adf61bfebcf0927a6353f3df99e1f17314838011a5c8baa2901"),
-}
-
-
-def _step_text(family: str) -> str:
-    # a kernel is traced once a shape and bound from that trace ever after
-    # (``ops/pallas/_common.py`` ``_bind``): one that an earlier test of
-    # this file traced under its own model would be bound here
-    from deepspeed_tpu.ops.pallas import _common
-    _common._TRACED.clear()
-    cls, model_kw, _ = _FAMILIES[family]
-    model = cls(size="tiny", **model_kw)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    tok = np.zeros((8, model.config.max_seq_len), np.int32)
-    return engine._train_step.lower(
-        engine.state, engine._put_batch((tok, tok))).as_text()
-
-
-@pytest.mark.parametrize("family", list(_FAMILIES))
-def test_the_other_families_steps_are_the_parents_programs(family):
-    text = _step_text(family)
-    assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == _FAMILIES[family][2]

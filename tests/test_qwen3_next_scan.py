@@ -15,8 +15,7 @@ import pytest
 from deepspeed_tpu.moe.sharded_moe import held_block, moe_ffn_held
 from deepspeed_tpu.ops import kda
 
-from helpers.family_cases import (_close, _drop_compiled_programs,  # noqa: F401,E501
-                                  _err)
+from helpers.family_cases import _close, _err
 from architectures import qwen3_next as arch  # noqa: E402
 
 
@@ -54,19 +53,22 @@ def test_a_gate_a_head_through_the_kernels_is_the_recurrence(case):
     q, k, v, g, beta = args
     assert g.shape == beta.shape and float(jnp.min(g)) < (
         -80 if case == "fastest_decay" else -10)
-    want = kda.recurrent_kda(*args)
+    # jitted: eager, every line round the kernels compiles alone
+    recurrent = jax.jit(kda.recurrent_kda)
+    chunked = jax.jit(kda.chunk_kda, static_argnames="head_groups")
+    want = recurrent(*args)
     wide = jnp.broadcast_to(g[..., None], q.shape)
-    _close(kda.recurrent_kda(q, k, v, wide, beta), want, 0, "recurrent")
-    got = kda.chunk_kda(*args)
+    _close(recurrent(q, k, v, wide, beta), want, 0, "recurrent")
+    got = chunked(*args)
     _close(got, want, 2e-5, "forward")
-    np.testing.assert_array_equal(got, kda.chunk_kda(*args, head_groups=2))
-    widened = _err(kda.chunk_kda(q, k, v, wide, beta), want)
+    np.testing.assert_array_equal(got, chunked(*args, head_groups=2))
+    widened = _err(chunked(q, k, v, wide, beta), want)
     assert widened < 2e-5 if case == "fastest_decay" else widened > 1e-3
     cot = jnp.asarray(np.random.default_rng(9).normal(size=want.shape),
                       jnp.float32)
-    loss = lambda f: (lambda *a: jnp.sum(f(*a) * cot))  # noqa: E731
-    want_g = jax.grad(loss(kda.recurrent_kda), argnums=range(5))(*args)
-    got_g = jax.grad(loss(kda.chunk_kda), argnums=range(5))(*args)
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * cot), argnums=range(5)))(*args)
+    want_g, got_g = grad(kda.recurrent_kda), grad(kda.chunk_kda)
     for name, a, b in zip("q k v g beta".split(), got_g, want_g):
         assert a.shape == b.shape, name
         # at -80 a token nothing is remembered and dg is 1e-16: held to

@@ -1,0 +1,139 @@
+"""Every stored hash of a lowered program (ISSUE 50): the train steps of the
+six families the benchmark's configurations run, at the switches their cells
+run them with, and the chunked head every cell's loss goes through. A PR
+that edits code on another family's path shows here that the other
+families' steps are still the parents' programs; a PR that changes a
+program BY DESIGN re-takes its pins here and nowhere else, and says in the
+table's comment which and why. A pin is a row of ``_PINS``, never a test of
+a family's own file. A CPU run shows results and counts, never a time."""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
+                                  Ouro, Qwen3Next)
+from deepspeed_tpu.models.transformer import _chunked_cross_entropy
+from deepspeed_tpu.ops.pallas import _common
+
+from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
+
+# row -> (class, switches over the tiny preset, the sha256 of the lowered
+# train step with its symbols renumbered, the sum of the seeded master
+# weights' magnitudes). Two layers of a stack where two hold every kind of
+# layer the family has (a routed layer behind a KDA and an MLA mixer; a Mamba
+# and an attention layer; a window and a full attention layer; a Gated
+# DeltaNet and a gated attention layer); ``mellum`` also at the preset's own
+# four, ``mistral`` at both remat policies (``segments`` is what the Mistral
+# cells run: attention outside every ``jax.checkpoint``). Rows and switches
+# are those that ``tests/test_mellum.py``, ``tests/test_ouro.py`` and
+# ``tests/test_qwen3_next_engine.py`` held until PR 50, each once, and
+# ``qwen3_next``, which no file held. Every number was taken on PR 50's
+# parent (commit d65b177, this file's ``_step_pin`` run on that checkout),
+# where each was first shown equal under the form its old file kept
+# (``CHANGES.md``, PR 50). The sums are the CPU's arithmetic as
+# ``tests/conftest.py`` has it compiled (LLVM's optimizer off since PR 50:
+# the init's last bits differ, a part in 1e10 of a sum, and the texts do
+# not). The programs are PR 48's for the routed families
+# (``kimi_linear``, ``mellum``, ``qwen3_next``: the held sweep's add to tokens
+# is the kernel ``ds_moe_add_rows``) and PR 47's for the others (a rematted
+# layer keeps the flash kernel's ``o`` and ``lse``).
+_PINS = {
+    "kimi_linear": (KimiLinear, dict(
+        num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
+        first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
+        loss_chunk=64, kda_head_groups=2),
+        "bdc8d6163d8b71d21575f11baf26bab61e08ef4277a2e33f628d0066ae4ba799",
+        7191.956370612894),
+    "granite_hybrid": (GraniteHybrid, dict(
+        num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
+        loss_chunk=64),
+        "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
+        2422.8129150247487),
+    "mellum": (Mellum, dict(
+        moe_held_experts=16, attn_impl="flash", loss_chunk=64),
+        "6757d265d311670d63b7cb789f660f8b24d0670e7052abf56d987ad41a6882b4",
+        36510.69587289919),
+    "mellum_two_layers": (Mellum, dict(
+        num_layers=2, layer_types=["sliding_attention", "full_attention"],
+        moe_held_experts=16, attn_impl="flash", loss_chunk=64),
+        "804d32f3a8ec122e419a8accb0baca66cd2d04ac0687a83165295576e9baa962",
+        31755.548628388842),
+    "ouro": (Ouro, dict(num_layers=2, attn_impl="flash", loss_chunk=64),
+             "9eec4588f2e615a8871cef610ca85b5dc9984269762d3ecda677f84ad4d2f3d0",
+             2733.7353564571135),
+    "mistral": (Mistral, dict(
+        attn_impl="flash", loss_chunk=64, sliding_window=64),
+        "78f5c2263a6399b676b44438f75273068c1f2d668ae62f890529c98bd4251219",
+        2339.9930016614694),
+    "mistral_segments": (Mistral, dict(
+        attn_impl="flash", loss_chunk=64, sliding_window=64,
+        remat_policy="segments"),
+        "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
+        2339.9930016614694),
+    "qwen3_next": (Qwen3Next, dict(
+        num_layers=2, full_attention_interval=2, moe_held_experts=32,
+        qk_norm_init=2.0, attn_impl="flash", loss_chunk=64),
+        "ee1242b18203a27974544d1ce6ecbbfb33d6f467ae5a5583c366e91988b673e1",
+        39458.17879846059),
+}
+
+
+def _pin(text: str) -> str:
+    """The sha256 of a lowered program's ``text`` with every symbol
+    (``@name``) renamed by the order of its first appearance: what is left is
+    the program, whatever numbers MLIR's symbol table gave the private
+    functions' names (JAX lowers every distinct equation as a private
+    function named after its primitive, and one more such function numbers
+    every LATER collision higher: PR 47 found eleven names of 3501 lines
+    moved and no other character)."""
+    assert "loc(" not in text       # no source locations in it
+    table = {}
+    return hashlib.sha256(re.sub(
+        r"@[\w.]+", lambda m: table.setdefault(m.group(0), f"@f{len(table)}"),
+        text).encode()).hexdigest()
+
+
+def _step_pin(row: str):
+    # a kernel is traced once a shape and bound from that trace ever after
+    # (``ops/pallas/_common.py`` ``_bind``): one that an earlier row traced
+    # under its own model would be bound here
+    _common._TRACED.clear()
+    cls, switches, *_ = _PINS[row]
+    model = cls(size="tiny", **switches)
+    engine, *_ = ds.initialize(model=model, config=dict(DS_CONFIG))
+    tok = np.zeros((8, model.config.max_seq_len), np.int32)
+    text = engine._train_step.lower(
+        engine.state, engine._put_batch((tok, tok))).as_text()
+    leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
+    return _pin(text), float(sum(np.abs(x.astype(np.float64)).sum()
+                                 for x in leaves))
+
+
+@pytest.mark.parametrize("row", list(_PINS))
+def test_the_families_steps_are_the_parents_programs(row):
+    """The lowered train step under ZeRO-3 bf16 on the 8-device mesh is the
+    parent's text and the seeded weights the parent's numbers."""
+    assert _step_pin(row) == _PINS[row][2:]
+
+
+def test_the_unweighted_head_is_the_parents_program():
+    """``_chunked_cross_entropy`` is on the path of every cell: with no
+    weights its lowered text (value and gradient) is the parent's (commit
+    9909adf, ISSUE 42, which gave the head its weighted form; the hash of
+    the text as it is, as ``tests/test_ouro.py`` kept it until PR 50: one
+    jitted function has no private functions to number)."""
+    x = jax.ShapeDtypeStruct((2, 128, 64), jnp.bfloat16)
+    W = jax.ShapeDtypeStruct((64, 512), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    text = jax.jit(jax.value_and_grad(
+        lambda x, W, t: _chunked_cross_entropy(x, W, None, t, 32),
+        argnums=(0, 1))).lower(x, W, t).as_text()
+    assert "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "e4b425dd87402f9216cae4e4bd9f1e349f6e3fb5515488abb2badeecb6e8e10f")

@@ -364,19 +364,32 @@ def test_ssd_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
         grad(ssd.chunk_ssd, 256).lower(*args).compile()
 
 
-def test_grouped_matmul_kernels_compile_for_v5e(monkeypatch):
+@pytest.mark.parametrize("experts,held,f,router_grad,k,d,want", [
+    (64, 16, 896, False, 8, 2304, (768, 36864)),
+    (256, 8, 1024, True, 8, 2304, (1024, 6144)),
+    (512, 32, 512, False, 10, 2048, (640, 14336)),
+], ids=["mellum2-12b-ep4-zero3-1chip", "kimi-linear-48b-ep32-zero3-1chip",
+         "qwen3-next-80b-ep16-zero3-1chip"])
+def test_grouped_matmul_kernels_compile_for_v5e(
+        monkeypatch, experts, held, f, router_grad, k, d, want):
     """The held experts' kernels (PRs 41, 48) under ``held_experts_ffn`` at
     the three cells' widths (16384 tokens; top 8, hidden 2304: 16 experts
     of 896 in chunks of 36,864 rows; 8 of 1024 in chunks of 6144, the
     routing weights' gradient on; top 10, hidden 2048: 32 of 512 in chunks
-    of 14,336 at a row tile of 128), forward, remat's rerun and
+    of 14,336 at a row tile of 128), a case each, forward, remat's rerun and
     backward, compiled by Mosaic for one described v5e chip: an expert's
     weights and its float32 ``dW`` blocks held in VMEM at two buffers each
     (97 MB of the chip's 128 at 1024 wide), the transposed products, the
     copy from the aliased carry, and the add's one-hot product on a tile
     of the carry that is both an input and the output are what interpret
     mode cannot refuse. No scatter is left onto a float32 [N, D], and
-    XLA still serves the gather of ``x``'s rows from VMEM."""
+    XLA still serves the gather of ``x``'s rows from VMEM. A width compiles
+    for 17 to 19 s alone (a minute beside five busy workers), and it is
+    neither the kernels nor the tokens (PR 50): the forward kernel alone
+    is 1.4 s, one ``lax.sort`` of 131,072 int32 pairs, as the sweep
+    holds, alone 15.8 s of XLA's TPU compiler, and 4096, 8192 and 16384 tokens
+    read 17.0, 17.4 and 19.3 s at 1024 wide, while ``held_block`` and
+    ``held_chunk`` return the cells' own pairs at 16384 alone: it stays."""
     import re
 
     from jax.experimental import topologies
@@ -392,35 +405,28 @@ def test_grouped_matmul_kernels_compile_for_v5e(monkeypatch):
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
     n = 16384
-    for experts, held, f, router_grad, k, d, want in (
-            (64, 16, 896, False, 8, 2304, (768, 36864)),
-            (256, 8, 1024, True, 8, 2304, (1024, 6144)),
-            (512, 32, 512, False, 10, 2048, (640, 14336))):
-        block = sharded_moe.held_block(n, k, experts)
-        chunk = sharded_moe.held_chunk(n, k, experts, held, block)
-        assert (block, chunk) == want, experts
-        layer = jax.checkpoint(lambda x, idx, w, ex: (
-            sharded_moe.held_experts_ffn(x, idx, w, ex, 0, block,
-                                         router_grad, chunk)[0]))
-        grad = jax.jit(jax.grad(
-            lambda x, w, ex, idx: 0.5 * jnp.sum(
-                layer(x, idx, w, ex).astype(f32) ** 2), argnums=(0, 1, 2)))
-        hlo = grad.lower(
-            sd((n, d), bf), sd((n, k), f32),
-            {"w_gate": sd((held, d, f), bf), "w_up": sd((held, d, f), bf),
-             "w_down": sd((held, f, d), bf)},
-            sd((n, k), jnp.int32)).compile().as_text()
-        for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd",
-                       "ds_moe_add_rows"):
-            assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), (
-                experts, kernel)
-        assert not re.search(rf"= f32\[{n},{d}\]\S* scatter\(", hlo), experts
-        # the loop still carries ``x`` in VMEM (``S(1)``) for the row gather,
-        # as with XLA's scatter-add as the body's last op: an add that asked
-        # for more VMEM than an XLA op gets would stop that (PR 48)
-        assert router_grad or re.search(
-            rf"= bf16\[{n},{d}\]\S*S\(1\)\S* get-tuple-element\(",
-            hlo), experts
+    block = sharded_moe.held_block(n, k, experts)
+    chunk = sharded_moe.held_chunk(n, k, experts, held, block)
+    assert (block, chunk) == want
+    layer = jax.checkpoint(lambda x, idx, w, ex: (
+        sharded_moe.held_experts_ffn(x, idx, w, ex, 0, block,
+                                     router_grad, chunk)[0]))
+    grad = jax.jit(jax.grad(
+        lambda x, w, ex, idx: 0.5 * jnp.sum(
+            layer(x, idx, w, ex).astype(f32) ** 2), argnums=(0, 1, 2)))
+    hlo = grad.lower(
+        sd((n, d), bf), sd((n, k), f32),
+        {"w_gate": sd((held, d, f), bf), "w_up": sd((held, d, f), bf),
+         "w_down": sd((held, f, d), bf)},
+        sd((n, k), jnp.int32)).compile().as_text()
+    for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd", "ds_moe_add_rows"):
+        assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), kernel
+    assert not re.search(rf"= f32\[{n},{d}\]\S* scatter\(", hlo)
+    # the loop still carries ``x`` in VMEM (``S(1)``) for the row gather,
+    # as with XLA's scatter-add as the body's last op: an add that asked
+    # for more VMEM than an XLA op gets would stop that (PR 48)
+    assert router_grad or re.search(
+        rf"= bf16\[{n},{d}\]\S*S\(1\)\S* get-tuple-element\(", hlo)
 
 
 def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
