@@ -44,15 +44,22 @@ recurrence was a ``lax.scan`` under autodiff, until PR 35 the preparation
 ``jax.numpy`` under autodiff: ``tests/helpers/kda_reference.py`` keeps
 that form as the kernels' reference.)
 
-A layer of EACH cell that runs this op (``train-kda-s16k-1chip``: four KDA
-layers of 32 heads in four head groups; ``train-gdn-s16k-1chip``: three
-Gated DeltaNet layers of 32 value heads in one group) runs the
-preparation's forward three times (the forward, the layer's remat, the
-head group's own checkpoint) and its backward once; ``ds_kda_fwd`` twice,
-its checkpoint form once and ``ds_kda_bwd`` once (``PERF.md`` section 5
-has their times). The operand shapes are the same in both cells (dk = dv
-= 128, 16384 tokens); the second reads its gate as rows, a number a
-token, as both read beta.
+A layer of the Kimi cell (``train-kda-s16k-1chip``: four KDA layers of 32
+heads in four head groups) runs the preparation's forward twice (the
+forward, and the head group's own checkpoint in the backward) and its
+backward once; ``ds_kda_fwd`` once, its checkpoint form once and
+``ds_kda_bwd`` once (``PERF.md`` section 5 has their times). The layer's
+own remat does not run the scan again: ``chunk_kda`` declares its ``o``
+kept (``_kept``), 2 B S H dv bytes a layer (134 MB) alive across the
+backward, and the rerun lacked nothing else (until PR 51 it ran both
+forward kernels a third time). A layer of the Qwen3-Next cell
+(``train-gdn-s16k-1chip``: three Gated DeltaNet layers of 32 value heads in
+ONE group) keeps nothing and reruns the scan: the compiled step holds the
+preparation's forward twice a layer all the same (with no loop round it
+XLA merges the layer's rerun of it with the group's) and ``ds_kda_fwd``
+twice and its checkpoint form once. The operand shapes are the same in
+both cells (dk = dv = 128, 16384 tokens); the second reads its gate as
+rows, a number a token, as both read beta.
 
 A gate a CHANNEL cannot be a mask: its decay rides inside the products.
 ``exp(G_i - G_j) <= 1``, but ``exp(G_i) * exp(-G_j)`` overflows float32
@@ -70,6 +77,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .pallas._common import _keep
 from .pallas.kda import CHUNK, kda_prepare, kda_recurrence
 
 
@@ -110,9 +118,22 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
     again, then the recurrence's checkpoint form and the two backward
     kernels; ``ds_kda_fwd`` itself is not run again (its ``o`` is dead in
     the rerun). Without that checkpoint the engine's train step of the
-    Kimi cell peaks at 13.19 GiB, with it at 12.62 (AOT for one v5e chip,
-    PR 44; 14.19 and 13.50 in PR 35, before the short convolution's
-    kernels)."""
+    Kimi cell peaked at 13.19 GiB, with it at 12.62 (AOT for one v5e chip,
+    PR 44).
+
+    With more than one group the map is a loop, and the result is declared
+    kept (``_kept``) OUTSIDE it and the groups' checkpoints, where the
+    policy of a layer's ``jax.checkpoint`` sees the name
+    (``models/transformer.py`` ``_remat_policy``): the layer's rerun then
+    holds no kernel of the scan, and the Kimi cell's step peaks at 12.88
+    GiB of 15.75 (PR 51; 12.63 before). With ONE group nothing is kept:
+    XLA already merges the layer's rerun of the preparation with the
+    group's (no loop hides it), so one ``ds_kda_fwd`` a layer is all a kept
+    ``o`` saves (5.4 ms of the Qwen3-Next cell's 437 ms step), and with it
+    kept XLA lays ``ds.mix_post``'s backward out as the projections are
+    and pays four more float32 [S, H dv] relayouts a layer for it (14.6
+    ms): the step read 442.7 ms for 437.4 and 1.2% fewer tokens/s in four
+    pairs of four (my chip runs, PR 51)."""
     h = q.shape[2]
     if h % head_groups:
         raise ValueError(f"chunk_kda: {h} heads in {head_groups} groups")
@@ -126,8 +147,26 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
         lambda xs: _chunk_kda(*xs, chunk=chunk), prevent_cse=False)
     with jax.named_scope("ds.kda_scan"):
         o = jax.lax.map(one, tuple(split(x) for x in (q, k, v, g, beta)))
+    if head_groups > 1:
+        o = _kept(o)
     o = jnp.moveaxis(o, 0, 2)                       # [B, S, G, H/G, dv]
     return o.reshape(*o.shape[:2], h, o.shape[-1])
+
+
+@jax.custom_vjp
+def _kept(o):
+    """``o`` as it is; differentiated, ``o`` declared kept (``_keep``)
+    where a rematted layer's policy sees the name: outside the head
+    groups' ``lax.map`` and their checkpoints. It is the map's own result
+    [G, B, S, H/G, dv] that is named, not its relayout to [B, S, H, dv],
+    which a layer's rerun makes again: named after the relayout, the Kimi
+    cell's compiled step kept two float32 [S, H dv] tensors of
+    ``ds.mix_post``'s backward alive through the scan's (13.56 GiB for
+    12.88, AOT, PR 51)."""
+    return o
+
+
+_kept.defvjp(lambda o: (_keep("kda", o)[0], None), lambda _, do: (do,))
 
 
 def sharded_chunk_kda(act_sharding):

@@ -75,7 +75,10 @@ state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
   Under a ``jax.checkpoint`` that reruns the forward rule for its
   residuals (``chunk_kda`` puts one around each group of heads) the rule's
   ``o`` is dead and the forward kernel is not run again: the rerun costs
-  the preparation's forward and the checkpoint form only.
+  the preparation's forward and the checkpoint form only. Where the
+  groups are more than one that rerun is the only one: ``chunk_kda``
+  declares its ``o`` kept, so a rematted layer's backward reads it back
+  (PR 51) and a layer runs the forward kernel once in each form.
 
 The kernels hold the state TRANSPOSED (``St`` [dv, dk]): ``shrink`` then
 scales lanes and broadcasts as the row it is stored as, ``dshrink`` is a

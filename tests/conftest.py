@@ -309,6 +309,7 @@ _LONGEST_FIRST = (
     "test_kimi_linear_limits.py",
     "test_short_conv.py",
     "test_ouro.py",
+    "test_kept_residuals.py",
     "test_kimi_linear_reference.py",
     "test_qwen3_next_reference.py",
     "test_mellum_reference.py",

@@ -3,10 +3,13 @@ the flash kernel's output and row log-sum-exp pass through
 ``ops/pallas/_common.py`` ``_keep`` under the name ``KEPT_RESIDUAL``, and
 every policy of ``models/transformer.py`` ``_remat_policy`` saves that name,
 so the backward of a layer under ``jax.checkpoint`` reruns its norms and
-projections but not ``ds_flash_fwd``. The kernels are interpreted here: a
-CPU run shows counts and bits, never a time."""
+projections but not ``ds_flash_fwd``. The delta rule's scan declares its
+``o`` the same way (ISSUE 51, ``ops/kda.py`` ``chunk_kda``): the rerun then
+holds no kernel of the scan, and the backward's own rerun of a head group
+is the one left (the scan alone and its gauge: ``tests/test_kept_scan.py``). The kernels
+are interpreted here: a CPU run shows counts and bits, never a time."""
 
-import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,103 +18,84 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
-                                  Ouro, Qwen3Next, ouro, stack, transformer)
-from deepspeed_tpu.ops.pallas import _common
+from deepspeed_tpu.models import Mistral, transformer
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
 from helpers.family_cases import DS_CONFIG as _DS_CONFIG
-from helpers.family_cases import (_batch, _telemetry_isolation,  # noqa: F401
-                                  _walk_eqns)
+from helpers.family_cases import _telemetry_isolation  # noqa: F401
+from helpers.kept_cases import (REMATTED, keep_nothing, kernel_calls, tiny,
+                                value_and_grads)
 
 
-# family -> (class, the `tiny` preset's switches, attention layer
-# applications in the TRACED program: a scan's body is traced once, so
-# Ouro's 2 layers x 4 passes are one application, and Mellum's two kinds
-# of attention layer are two)
-_REMATTED = {
-    "kimi_linear": (KimiLinear, dict(moe_held_experts=8), 1),
-    "granite_hybrid": (GraniteHybrid, {}, 1),
-    "mellum": (Mellum, dict(moe_held_experts=16), 2),
-    "ouro": (Ouro, {}, 1),
-    "qwen3_next": (Qwen3Next, dict(moe_held_experts=32), 1),
-}
+@functools.cache
+def _train_step_calls(family, nothing_kept=False):
+    """The kernels of the engine's train step (eight virtual devices, so
+    the scans run per shard), once a family and policy for the cases that
+    read them."""
+    with pytest.MonkeyPatch.context() as patch:
+        if nothing_kept:
+            keep_nothing(patch)
+        model = tiny(family)
+        assert model.config.remat and model.config.remat_policy == \
+            "nothing_saveable"
+        engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
+        tok = np.zeros((8, model.config.max_seq_len), np.int32)
+        return kernel_calls(engine._train_step, engine.state,
+                            engine._put_batch((tok, tok)))
 
 
-def _tiny(family):
-    cls, model_kw, _ = _REMATTED[family]
-    return cls(size="tiny", attn_impl="flash", loss_chunk=64, **model_kw)
-
-
-def _kernel_calls(fn, *args):
-    """How often each Pallas kernel is called in ``fn``'s traced program,
-    by the kernel's name. Interpreted kernels lower to plain HLO, so the
-    lowered text of a CPU step holds no kernel's name: the jaxpr that is
-    lowered does. Traced through a function of its own, so that no trace
-    made under another policy is found again."""
-    _common._TRACED.clear()
-    jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
-    return collections.Counter(
-        eqn.params["name"] for eqn in _walk_eqns(jaxpr.jaxpr)
-        if eqn.primitive.name == "pallas_call")
-
-
-def _keep_nothing(monkeypatch):
-    """``jax.checkpoint(policy=None)`` wherever a model asks
-    ``_remat_policy``: what every policy name meant before this PR."""
-    for module in (transformer, stack, ouro):
-        monkeypatch.setattr(module, "_remat_policy", lambda name: None)
-
-
-def _train_step_calls(family):
-    model = _tiny(family)
-    assert model.config.remat and model.config.remat_policy == \
-        "nothing_saveable"
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    tok = np.zeros((8, model.config.max_seq_len), np.int32)
-    return _kernel_calls(engine._train_step, engine.state,
-                         engine._put_batch((tok, tok)))
-
-
-@pytest.mark.parametrize("family", list(_REMATTED))
-def test_a_rematted_step_runs_the_forward_kernel_once_an_application(
-        family, monkeypatch):
+@pytest.mark.parametrize("family", list(REMATTED))
+def test_a_rematted_step_runs_the_forward_kernel_once_an_application(family):
     """The engine's train step of each rematted family holds one
     ``ds_flash_fwd`` and one ``ds_flash_bwd`` an attention layer
     application; the same step built on ``policy=None`` holds the forward
     kernel twice."""
-    applications = _REMATTED[family][2]
+    applications = REMATTED[family][2]
     calls = _train_step_calls(family)
     assert calls["ds_flash_fwd"] == calls["ds_flash_bwd"] == applications
-    _keep_nothing(monkeypatch)
-    calls = _train_step_calls(family)
+    calls = _train_step_calls(family, nothing_kept=True)
     assert calls["ds_flash_bwd"] == applications
     assert calls["ds_flash_fwd"] == 2 * applications
 
 
-@pytest.mark.parametrize("family", ["mellum", "ouro"])
+@pytest.mark.parametrize("family, runs", [("kimi_linear", 2),
+                                          ("qwen3_next", 3)])
+def test_a_rematted_step_runs_the_scan_twice_where_groups_are_a_loop(
+        family, runs):
+    """Kimi-Linear's KDA heads run in head groups under a loop: a layer's
+    forward and its groups' own rerun in the backward are what is left, the
+    preparation's forward and ``ds_kda_fwd`` (its ``o`` form once, its
+    checkpoint form once) twice a backward kernel, where the step built on
+    ``policy=None`` holds them three times (the layer's rerun made ``o``
+    again). Qwen3-Next's Gated DeltaNet heads run in ONE group, which keeps
+    nothing: three times under either. Every other kernel but
+    ``ds_flash_fwd`` runs as often as under ``policy=None``: the backward
+    still needs q, k, v, g and beta, so the convolutions rerun as
+    before."""
+    kept = _train_step_calls(family)
+    rerun = _train_step_calls(family, nothing_kept=True)
+    assert kept["ds_kda_prep_bwd"] == kept["ds_kda_bwd"] > 0
+    for fwd, bwd in (("ds_kda_prep_fwd", "ds_kda_prep_bwd"),
+                     ("ds_kda_fwd", "ds_kda_bwd")):
+        assert kept[fwd] == runs * kept[bwd]
+        assert rerun[fwd] == 3 * rerun[bwd] == 3 * kept[bwd]
+    moved = {"ds_kda_prep_fwd", "ds_kda_fwd", "ds_flash_fwd"}
+    assert {k: n for k, n in kept.items() if k not in moved} == \
+        {k: n for k, n in rerun.items() if k not in moved}
+
+
+@pytest.mark.parametrize("family", ["mellum", "ouro", "qwen3_next"])
 def test_the_gradients_are_policy_nones_bit_for_bit(family, monkeypatch):
     """The kept ``o`` and ``lse`` are the bits the rerun would have made:
-    every gradient of the loss is the one ``policy=None`` gives."""
-    model = _tiny(family)
-    params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
-                          model.init(jax.random.PRNGKey(1)))
-    batch = _batch(model, b=2)
-
-    def loss(p):
-        out = model.loss(p, batch)
-        return out[0] if isinstance(out, tuple) else out
-
-    kept = jax.device_get(jax.jit(jax.value_and_grad(loss))(params))
-    _keep_nothing(monkeypatch)
-    rerun = jax.device_get(jax.jit(jax.value_and_grad(loss))(params))
-    assert float(kept[0]) == float(rerun[0]) and np.isfinite(kept[0])
-    flat = jax.tree_util.tree_leaves_with_path(kept[1])
-    assert any(np.any(np.asarray(g, np.float32) != 0) for _, g in flat)
-    for (path, got), want in zip(flat, jax.tree.leaves(rerun[1])):
-        np.testing.assert_array_equal(
-            np.asarray(got, np.float32), np.asarray(want, np.float32),
-            err_msg=jax.tree_util.keystr(path))
+    every gradient of the loss is the one ``policy=None`` gives
+    (Qwen3-Next's one head group keeps nothing of its scans: its gated
+    attention layer's flash kernels do)."""
+    kept = value_and_grads(family)
+    keep_nothing(monkeypatch)
+    rerun = value_and_grads(family)
+    assert kept[0] == rerun[0]
+    for path, got in kept[1].items():
+        np.testing.assert_array_equal(got, rerun[1][path], err_msg=path)
 
 
 def test_the_gauge_reads_what_one_call_declares():
@@ -153,12 +137,12 @@ def test_every_policy_keeps_the_name(policy, monkeypatch):
     def grad(p, t):
         return jax.grad(lambda p: model.loss(p, (t, t)))(p)
 
-    calls = _kernel_calls(grad, params, tok)
+    calls = kernel_calls(grad, params, tok)
     assert calls["ds_flash_fwd"] == calls["ds_flash_bwd"] == 1
     policies = jax.checkpoint_policies
     as_it_was = {"save_attn_ffn": policies.save_only_these_names(
         "qkv", "attn_out", "ffn"),
         "dots_saveable": policies.dots_saveable}[policy]
     monkeypatch.setattr(transformer, "_remat_policy", lambda name: as_it_was)
-    calls = _kernel_calls(grad, params, tok)
+    calls = kernel_calls(grad, params, tok)
     assert (calls["ds_flash_fwd"], calls["ds_flash_bwd"]) == (2, 1)

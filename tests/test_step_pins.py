@@ -42,13 +42,19 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # not). The programs are PR 48's for the routed families
 # (``kimi_linear``, ``mellum``, ``qwen3_next``: the held sweep's add to tokens
 # is the kernel ``ds_moe_add_rows``) and PR 47's for the others (a rematted
-# layer keeps the flash kernel's ``o`` and ``lse``).
+# layer keeps the flash kernel's ``o`` and ``lse``). PR 51 re-took
+# ``kimi_linear`` by design (its KDA layers run in two head groups here, four
+# in its cell: a rematted layer keeps the delta-rule scan's ``o``,
+# ``ops/kda.py`` ``chunk_kda``, and the layer's rerun holds no kernel of the
+# scan); the seven other rows stand: ``qwen3_next`` calls the op with one
+# head group, where nothing is kept, and the five other families never call
+# it.
 _PINS = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "bdc8d6163d8b71d21575f11baf26bab61e08ef4277a2e33f628d0066ae4ba799",
+        "b738a8308e507302e4ddf5de4aeec4424e8f21e62e4ca33ed349dbfa247b140e",
         7191.956370612894),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",

@@ -31,9 +31,10 @@ head count:
   in their place: device busy time a call, each kernel's part, the rest;
 - ``layer_ms`` (at 32 heads): ``jax.grad`` of a layer's ``chunk_kda`` (four
   groups under ``lax.map``, each under its checkpoint, the whole under the
-  layer's remat; the loss is quadratic, but its first forward is still
-  dead: two of a step's three forward runs and the backward), split the
-  same way: ``rest`` is what ``lax.map`` and XLA's copies add;
+  layer's remat with the models' policy, which keeps ``o``; the loss is
+  quadratic: what a step runs of a layer, the forward, the groups' rerun
+  and the backward), split the same way: ``rest`` is what ``lax.map`` and
+  XLA's copies add;
 - ``err`` / ``prep_err``: the recurrence's ``o`` and six cotangents against
   the scan's, the preparation's six operands and five gradients against
   the ``jax.numpy`` form's: largest difference over the largest value.
@@ -247,8 +248,10 @@ def main(argv) -> int:
             kda.kda_prepare = kernel_prepare
             line["chunk_kda_ms"] = whole
         if heads == 32:     # a layer of the cell: four groups under lax.map
+            from deepspeed_tpu.models.transformer import _remat_policy
             layer = jax.checkpoint(
-                lambda *a: kda.chunk_kda(*a, head_groups=4))
+                lambda *a: kda.chunk_kda(*a, head_groups=4),
+                policy=_remat_policy("nothing_saveable"))
             grad = jax.jit(jax.grad(
                 lambda *a: 0.5 * jnp.sum(
                     layer(*a).astype(jnp.float32) ** 2),
