@@ -990,26 +990,38 @@ class DeepSpeedEngine:
         if batch is None:
             if data_iter is None:
                 raise ValueError("train_batch needs a batch or data_iter")
-            batch = next(data_iter)
-        if st is not None:
-            st.data_ready()
+            # an iterator's stall under its own name, not the caller's
+            with (tel.span("step_fetch")
+                  if tel is not None else _NULLCM):
+                batch = next(data_iter)
+            if st is not None:
+                # a batch handed in is ready when the step begins,
+                # which is what the record holds without this mark
+                st.data_ready()
+        # the children cover train_batch's host path without holes, so a
+        # device trace's idle under bare train_batch is the spans' own
+        # cost (docs/observability.md: who reads which)
         with (tel.span(TRAIN_BATCH_TIMER, step=self.global_steps + 1)
               if tel is not None else _NULLCM):
-            batch = self._apply_curriculum(batch)
+            with (tel.span("train_batch/prepare")
+                  if tel is not None else _NULLCM):
+                batch = self._apply_curriculum(batch)
             with (tel.span("batch_to_device")
                   if tel is not None else _NULLCM):
                 batch = self._put_batch(batch)
-            if st is not None:
-                # h2d covers curriculum slicing + the device transfer
-                st.h2d_done()
-            if tel is not None:
-                # device-truth hooks (ISSUE 5): BEFORE the dispatch
-                # (state is donated through the step) and OUTSIDE the
-                # sentinel watch scope (first-sight ledger
-                # registration compiles once, which the recompile
-                # sentinel must not see)
-                self._device_truth_observe(tel, batch)
-            self.tput_timer.start()
+            with (tel.span("train_batch/observe")
+                  if tel is not None else _NULLCM):
+                if st is not None:
+                    # h2d covers curriculum slicing + the device transfer
+                    st.h2d_done()
+                if tel is not None:
+                    # device-truth hooks (ISSUE 5): BEFORE the dispatch
+                    # (state is donated through the step) and OUTSIDE the
+                    # sentinel watch scope (first-sight ledger
+                    # registration compiles once, which the recompile
+                    # sentinel must not see)
+                    self._device_truth_observe(tel, batch)
+                self.tput_timer.start()
             if self._offload_opt is not None:
                 metrics = self._train_batch_offload(batch)
             else:
@@ -1036,22 +1048,24 @@ class DeepSpeedEngine:
                             self._disable_host_memory(e)
                             self.state, metrics = self._train_step(
                                 self.state, batch)
-            self._dispatched = True
-            if st is not None:
-                # both paths dispatch the same ledger-observed
-                # executable; host bookkeeping past this point lands
-                # in dispatch_overhead
-                st.dispatch_done("compiled_step")
-            self.global_steps += 1
-            self.global_samples += self.train_batch_size_
-            self._last_metrics = metrics
-            if self._numsan is not None:
-                self._numsan_feed(metrics)
-            if self.global_steps % self.config.steps_per_print == 0:
-                self.tput_timer.stop(sync=metrics["loss"])
-                self._report(metrics)
-            else:
-                self.tput_timer.stop(report_speed=False)
+            with (tel.span("train_batch/account")
+                  if tel is not None else _NULLCM):
+                self._dispatched = True
+                if st is not None:
+                    # both paths dispatch the same ledger-observed
+                    # executable; host bookkeeping past this point lands
+                    # in dispatch_overhead
+                    st.dispatch_done("compiled_step")
+                self.global_steps += 1
+                self.global_samples += self.train_batch_size_
+                self._last_metrics = metrics
+                if self._numsan is not None:
+                    self._numsan_feed(metrics)
+                if self.global_steps % self.config.steps_per_print == 0:
+                    self.tput_timer.stop(sync=metrics["loss"])
+                    self._report(metrics)
+                else:
+                    self.tput_timer.stop(report_speed=False)
         # flushes run OUTSIDE the train_batch span so export/monitor
         # cost never pollutes the step timing; step_boundary names them, so
         # that what a device trace shows under no span is the caller's time
@@ -1113,8 +1127,7 @@ class DeepSpeedEngine:
             return _NULLCM
         stack = contextlib.ExitStack()
         if s is not None:
-            struct = tuple((tuple(x.shape), str(x.dtype))
-                           for x in jax.tree.leaves(batch))
+            struct = self._batch_struct(batch)
             if struct != self._last_batch_struct:
                 if self._last_batch_struct is not None:
                     s.expect("batch abstract shapes/dtypes changed")
@@ -1123,6 +1136,13 @@ class DeepSpeedEngine:
         if self._hot_guard is not None:
             stack.enter_context(self._hot_guard())
         return stack
+
+    @staticmethod
+    def _batch_struct(batch) -> tuple:
+        """The batch's abstract shapes and dtypes: what the recompile
+        sentinel is told about and the executable ledger's short path
+        compares (a batch is a few leaves; the state is hundreds)."""
+        return tuple((x.shape, x.dtype) for x in jax.tree.leaves(batch))
 
     def _applied_steps(self) -> int:
         """Number of optimizer steps actually applied (the optax count) —
@@ -1219,9 +1239,15 @@ class DeepSpeedEngine:
         led = tel.get_ledger()
         if led is not None:
             # offload tier reuses the same attribute for its grads
-            # step, so one observation point covers both paths
+            # step, so one observation point covers both paths. The
+            # state's avals change only with the step program
+            # (``_disable_host_memory`` rebuilds it, and the ledger
+            # compares the callable), so the batch's structure says
+            # whether the operands are the last step's: no walk over
+            # the state's hundreds of leaves every step
             entry = led.observe("compiled_step", self._train_step,
-                                (self.state, batch), mesh=self.mesh)
+                                (self.state, batch), mesh=self.mesh,
+                                struct=self._batch_struct(batch))
             if self._meshsan is not None:
                 # traffic-contract check (ISSUE 15): once per NEW
                 # executable (signature-deduped inside), a set lookup

@@ -116,13 +116,17 @@ class ExecutableEntry:
 class ExecutableLedger:
     """Process-wide registry of compiled executables' device-truth
     cost. Thread-safe; ``observe()`` is cheap after first registration
-    (signature hash + dict lookup) and NEVER raises — a broken cost
+    (a comparison where the caller hands its ``struct``, else the
+    signature walk + dict lookup) and NEVER raises — a broken cost
     model must not take down the training step it measures."""
 
     def __init__(self, hlo_collectives: bool = True):
         self.hlo_collectives = bool(hlo_collectives)
         self._lock = threading.Lock()
         self._entries: dict[tuple, ExecutableEntry] = {}
+        # name -> (jitted, struct, entry) of the last observation that
+        # came with a ``struct``: observe()'s short path
+        self._last: dict[str, tuple] = {}
         # compile-path seconds by phase, fed by the jax.monitoring
         # listener in bridges.py (covers EVERY compile in the process,
         # including ones the ledger never sees an observe() for)
@@ -132,26 +136,42 @@ class ExecutableLedger:
     # -- registration --------------------------------------------------
     def observe(self, name: str, jitted, args: tuple = (),
                 kwargs: Optional[dict] = None, mesh=None,
-                n_devices: Optional[int] = None) -> \
-            Optional[ExecutableEntry]:
+                n_devices: Optional[int] = None,
+                struct=None) -> Optional[ExecutableEntry]:
         """Count one dispatch of ``jitted`` at these operands,
         registering cost/memory/collective analysis on first sight of
         the (name, signature) pair. Call BEFORE the dispatch when any
         operand is donated. Returns the entry (None only if even the
-        signature walk failed)."""
+        signature walk failed).
+
+        ``struct`` is the caller's word for the operands' structure
+        (anything comparable; None = no word): while ``jitted`` is the
+        same object and ``struct`` equals the one it came with the last
+        time under this name, the operands are taken to be what they
+        were and the entry found then is counted again, with no walk
+        over the leaves. The walk runs at first sight and whenever
+        either changes, so a new shape still registers."""
+        last = self._last.get(name) if struct is not None else None
+        if last is not None and last[0] is jitted and last[1] == struct:
+            entry = last[2]
+            with self._lock:
+                entry.calls += 1
+            return entry
         try:
             key = (name, _signature(args, kwargs))
         except Exception:
             return None
         with self._lock:
             entry = self._entries.get(key)
-            if entry is not None:
-                entry.calls += 1
-                return entry
-            entry = self._entries[key] = ExecutableEntry(name, key[1])
-            entry.calls = 1
-        self._register(entry, jitted, args, kwargs or {}, mesh,
-                       n_devices)
+            new = entry is None
+            if new:
+                entry = self._entries[key] = ExecutableEntry(name, key[1])
+            entry.calls += 1
+            if struct is not None:
+                self._last[name] = (jitted, struct, entry)
+        if new:
+            self._register(entry, jitted, args, kwargs or {}, mesh,
+                           n_devices)
         return entry
 
     def _register(self, entry: ExecutableEntry, jitted, args, kwargs,
@@ -322,6 +342,7 @@ class ExecutableLedger:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+            self._last.clear()
             self.compile_seconds.clear()
             self.compile_events.clear()
 

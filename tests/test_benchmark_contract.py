@@ -331,3 +331,210 @@ def test_work_reducers_read_nothing_where_the_program_wrote_no_file(
                 {"trace": None}, {"trace": None, "op_scopes_path": None}):
         assert reducers.find("work_ms_per_step")(ctx, args) is None
         assert reducers.find("work_gib_per_step")(ctx, args) is None
+
+
+# ---- the host's side of a step (ISSUE 52) ----------------------------------
+# reducers/hostgap.py and the six definitions that read it: data beside a
+# by-hand reader (benchmark/tests/gap_split.py), as the twenty above are and
+# for the same reason.
+GAP_METRICS = json.loads((BENCH / "tests" / "gap_metrics.json").read_text())
+GAP_PARTS = ("caller", "prepare", "h2d", "observe", "dispatch", "launch")
+GAP_ARGS = {"module": "^jit_train_step",
+            "launch": "^TpuLoadedExecutable::ExecuteLaunch$"}
+SCOPED_TRACE = BENCH / "tests" / "data" / "train_1chip_3steps_scoped.xplane.pb"
+
+
+def test_the_gap_metrics_are_the_six_parts():
+    assert sorted(GAP_METRICS) == sorted(
+        f"gap_{part}_ms.train" for part in GAP_PARTS)
+
+
+@pytest.mark.parametrize("name", sorted(GAP_METRICS))
+def test_a_gap_metric_is_a_metric_file_in_all_but_place(name):
+    """The keys of a metric file, the train entry's layer, all seven cells
+    of the contract, the reducer of ``reducers/hostgap.py`` with the
+    module and the launch event ``clock_bracket_us.train`` reads; a name
+    the contract does not have yet."""
+    _bench_on_path()
+    from lib import reducers
+    from reducers import hostgap
+    spec = GAP_METRICS[name]
+    assert set(spec) == {"layer", "unit", "better", "source", "moves",
+                         "cells", "what", "reducer"}
+    assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", name)
+    assert name not in {m["name"] for m in CONTRACT["per_layer"]}
+    assert (spec["layer"], spec["unit"], spec["better"], spec["source"],
+            spec["moves"]) == ("train entry", "ms", "lower", "device_trace",
+                               "train_tokens_per_s")
+    assert spec["layer"] in {m["layer"] for m in CONTRACT["per_layer"]}
+    assert sorted(spec["cells"]) == sorted(
+        w["name"] for w in CONTRACT["workloads"])
+    assert spec["reducer"]["name"] == "gap_part_ms"
+    assert reducers.find("gap_part_ms") is hostgap.gap_part_ms
+    bracket = _load(BENCH / "layer_metrics" / "clock_bracket_us.train.json")
+    args = dict(spec["reducer"]["args"])
+    assert name == f"gap_{args.pop('part')}_ms.train"
+    assert args == bracket["reducer"]["args"] == GAP_ARGS
+    assert spec["what"] and "\n" not in spec["what"]
+
+
+def _synthetic_gaps(new_spans=True, offset=0.0):
+    """Four runs of the step on one chip, 100 ms apart with 3 ms between
+    them, and the host's path of each on a clock ``offset`` seconds behind
+    the device's; the caller blocks, so the bracket has both limits."""
+    _bench_on_path()
+    from lib import trace as tr
+    ops, modules, py, rt = [], [], [], []
+    for i in range(4):
+        d0 = 0.1 * i
+        modules.append(("jit_train_step(1)", d0 - 1e-5, d0 + 0.0971))
+        ops += [("%fusion.1 = f32[8] fusion(...)", d0, d0 + 0.05),
+                ("%fusion.2 = f32[8] fusion(...)", d0 + 0.05, d0 + 0.097)]
+        h = d0 - 0.003 - offset                # the last step's end, host's
+        tb = h + 0.0004 + 1e-5 * i             # a caller a little later
+        py += [("train_batch", tb, tb + 0.0030),
+               ("batch_to_device", tb + 0.0002, tb + 0.0012),
+               ("compiled_step", tb + 0.00152, tb + 0.0022),
+               ("PjitFunction(train_step)", tb + 0.00153, tb + 0.0021),
+               ("step_boundary", tb + 0.0031, tb + 0.0032)]
+        if new_spans:
+            py += [("train_batch/prepare", tb, tb + 0.00019),
+                   ("train_batch/observe", tb + 0.0012, tb + 0.0015),
+                   ("train_batch/account", tb + 0.0022, tb + 0.0030)]
+        rt.append(("TpuLoadedExecutable::ExecuteLaunch",
+                   tb + 0.0019, tb + 0.002))
+    return tr.Trace({0: {tr.OPS_LINE: ops, tr.MODULES_LINE: modules}},
+                    {"python3": sorted(py, key=lambda e: e[1]),
+                     "main/1": rt})
+
+
+def test_gap_parts_sum_to_the_gap_every_step():
+    _bench_on_path()
+    from lib import reducers, trace as tr
+    from reducers import hostgap
+    t = _synthetic_gaps()
+    rows = hostgap.gap_parts(t, GAP_ARGS["module"], GAP_ARGS["launch"], 0.0)
+    gaps = tr.step_gaps(t, GAP_ARGS["module"])
+    assert len(rows) == len(gaps) == 3
+    for i, (row, gap) in enumerate(zip(rows, gaps), start=1):
+        assert row["gap"] == gap == pytest.approx(0.003)
+        parts = [row[k] for k in GAP_PARTS + ("other",)]
+        assert None not in parts
+        assert sum(parts) == pytest.approx(gap, abs=1e-6)
+        assert row["caller"] == pytest.approx(0.0004 + 1e-5 * i)
+        assert (row["prepare"], row["h2d"], row["observe"],
+                row["dispatch"]) == pytest.approx(
+                    (0.0002, 0.0010, 0.0003, 0.00038))
+        assert row["other"] == pytest.approx(0.00002)   # observe's end to
+        assert row["launch"] == pytest.approx(          # compiled_step
+            0.003 - 0.0019 - 0.0004 - 1e-5 * i)
+    # the reducer: the bracket's own midpoint, the median over the steps
+    ctx = {"trace": t}
+    read = {part: reducers.find("gap_part_ms")(ctx, dict(GAP_ARGS, part=part))
+            for part in GAP_PARTS + ("other",)}
+    mid = ctx["clock_bracket"]["midpoint"]
+    assert read["h2d"] == pytest.approx(1.0)
+    assert read["caller"] == pytest.approx(1e3 * (0.00042 + mid))
+    assert read["launch"] == pytest.approx(1e3 * (0.00068 - mid))
+    assert sum(read.values()) == pytest.approx(3.0, abs=1e-3)
+
+
+def test_an_offset_error_moves_only_the_two_ends():
+    """Four parts are differences of host events; ``caller`` and ``launch``
+    hold one device event each, so 0.5 ms of offset moves them by +0.5 and
+    -0.5 and their sum not at all."""
+    _bench_on_path()
+    from reducers import hostgap
+    t = _synthetic_gaps()
+    a = hostgap.gap_parts(t, GAP_ARGS["module"], GAP_ARGS["launch"], 0.0)
+    b = hostgap.gap_parts(t, GAP_ARGS["module"], GAP_ARGS["launch"], 0.0005)
+    for x, y in zip(a, b):
+        assert y["caller"] - x["caller"] == pytest.approx(0.0005)
+        assert y["launch"] - x["launch"] == pytest.approx(-0.0005)
+        for k in ("prepare", "h2d", "observe", "dispatch", "other", "gap"):
+            assert y[k] == x[k]
+    # and the same trace read on a host clock 1.2 ms behind the device's
+    # gives the same parts, because the bracket finds the offset
+    from lib import reducers
+    for part in GAP_PARTS:
+        args = dict(GAP_ARGS, part=part)
+        assert reducers.find("gap_part_ms")(
+            {"trace": _synthetic_gaps(offset=0.0012)}, args) == pytest.approx(
+                reducers.find("gap_part_ms")({"trace": t}, args), abs=1e-6)
+
+
+@pytest.mark.parametrize("part", GAP_PARTS + ("other",))
+def test_gap_parts_of_a_program_without_the_new_spans(part):
+    """A parent from before PR 52: ``prepare`` and ``observe`` read nothing
+    and their time lies in ``other``; without a trace, or with a caller
+    that does not block (no midpoint), every part reads nothing."""
+    _bench_on_path()
+    from lib import reducers
+    read = reducers.find("gap_part_ms")
+    args = dict(GAP_ARGS, part=part)
+    old = read({"trace": _synthetic_gaps(new_spans=False)}, args)
+    new = read({"trace": _synthetic_gaps()}, args)
+    if part in ("prepare", "observe"):
+        assert old is None and new is not None
+    elif part == "other":
+        assert old == pytest.approx(new + 0.2 + 0.3)
+    else:
+        assert old == pytest.approx(new)
+    assert read({"trace": None}, args) is None
+    assert read({"trace": _synthetic_gaps(),
+                 "clock_bracket": {"upper": 0.0, "lower": None, "steps": 3,
+                                   "midpoint": None}}, args) is None
+
+
+@pytest.mark.parametrize("part", GAP_PARTS + ("other",))
+def test_gap_parts_on_a_trace_recorded_on_the_chip(part):
+    """``train_1chip_3steps_scoped.xplane.pb`` (PR 24's program: the
+    launch event is there, the new spans are not): four parts read, two
+    do not, and with ``other`` they sum to every step's gap."""
+    _bench_on_path()
+    from lib import reducers, trace as tr
+    from reducers import hostgap
+    ctx = {"trace": tr.Trace.from_file(str(SCOPED_TRACE))}
+    value = reducers.find("gap_part_ms")(ctx, dict(GAP_ARGS, part=part))
+    if part in ("prepare", "observe"):
+        assert value is None
+    else:
+        assert 0.2 < value < 2.0
+    rows = hostgap.parts_of(ctx, GAP_ARGS)
+    gaps = tr.step_gaps(ctx["trace"], GAP_ARGS["module"])
+    assert [r["gap"] for r in rows] == gaps and len(gaps) == 3
+    for r in rows:
+        assert sum(r[k] or 0.0 for k in GAP_PARTS + ("other",)) == \
+            pytest.approx(r["gap"], abs=1e-6)
+
+
+def test_gap_split_reads_a_traced_runs_directory(tmp_path, monkeypatch):
+    """The by-hand reader on the recorded trace laid out as a traced run
+    leaves it: the rows sum, the twice-opened ``PjitFunction(train_step)``
+    lies inside ``compiled_step``, an untraced rate gives its gap."""
+    _bench_on_path()
+    import shutil
+    import sys
+    from lib import tracer
+    run = tmp_path / "train-s8k-1chip" / "plugins" / "profile" / "t"
+    run.mkdir(parents=True)
+    shutil.copy(SCOPED_TRACE, run)
+    monkeypatch.setattr(tracer, "TRACE_ROOT", tmp_path)
+    sys.path.insert(0, str(BENCH / "tests"))
+    try:
+        import gap_split
+    finally:
+        sys.path.pop(0)
+    out = gap_split.gap_split("train-s8k-1chip", "31000")
+    assert set(out["metrics"]) == set(GAP_METRICS)
+    assert out["metrics"]["gap_observe_ms.train"] is None
+    assert out["host_gap_ms"] == pytest.approx(3.556, abs=1e-3)
+    assert all(s["sum"] == pytest.approx(s["gap"], abs=1e-3)
+               for s in out["steps"])
+    assert out["bracket_us"]["lower"] < out["bracket_us"]["upper"]
+    (pjit,) = [e for e in out["in_compiled_step"]
+               if e["name"] == "PjitFunction(train_step)"]
+    assert pjit["events_per_step"] == 2.0 and pjit["thread"] == "python3"
+    assert out["untraced"]["implied_gap_ms"] == pytest.approx(
+        1e3 * 8192 / 31000 - out["untraced"]["device_step_ms"])
+    json.dumps(out)

@@ -470,18 +470,33 @@ def test_flush_to_monitor_writes_events(tmp_path):
 # disabled-mode guards (satellite)
 # ---------------------------------------------------------------------
 
-def test_disabled_mode_zero_events_and_no_hot_path_errors(devices8):
-    """Telemetry off: engine + fused decode run clean, and no tracer or
-    registry state ever comes into existence."""
+@pytest.fixture(scope="module")
+def plain_engine():
+    """ONE engine built with telemetry off for the cases below: a case
+    that wants telemetry configures it itself (the engine probes for it
+    every step), so none of them builds an engine of its own."""
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import GPT2
-    assert not telemetry.is_active()
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    telemetry.shutdown()
     engine, _, _, _ = ds.initialize(model=GPT2(size="tiny"), config={
         "train_batch_size": 16,
         "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
         "steps_per_print": 100})
-    tokens = jax.random.randint(jax.random.PRNGKey(0), (16, 17), 0, 512)
-    batch = (tokens[:, :-1], tokens[:, 1:])
+    return engine
+
+
+def _batch(seq=16):
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (16, seq + 1), 0, 512)
+    return (tokens[:, :-1], tokens[:, 1:])
+
+
+def test_disabled_mode_zero_events_and_no_hot_path_errors(plain_engine):
+    """Telemetry off: engine + fused decode run clean, and no tracer or
+    registry state ever comes into existence."""
+    assert not telemetry.is_active()
+    engine, batch = plain_engine, _batch()
     float(engine.train_batch(batch))
     loss = engine.forward(batch)
     engine.backward(loss)
@@ -498,6 +513,129 @@ def test_disabled_mode_zero_events_and_no_hot_path_errors(devices8):
     assert telemetry.get_timeseries() is None
     assert telemetry.get_health_monitor() is None
     assert telemetry.get_fleet() is None
+
+
+def test_a_step_with_telemetry_off_imports_nothing(plain_engine):
+    """``train_batch``'s three new contexts are the shared no-op when
+    telemetry is off: with the package taken out of ``sys.modules`` a step
+    (and a step that pulls its batch) runs and puts none of it back."""
+    from deepspeed_tpu.runtime import engine as engine_mod
+    held = {k: sys.modules.pop(k) for k in list(sys.modules)
+            if k.split(".")[:2] == ["deepspeed_tpu", "telemetry"]}
+    try:
+        assert engine_mod._telemetry() is None
+        float(plain_engine.train_batch(_batch()))
+        float(plain_engine.train_batch(data_iter=iter([_batch()])))
+        assert not [k for k in sys.modules
+                    if k.startswith("deepspeed_tpu.telemetry")]
+    finally:
+        sys.modules.update(held)
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s.depth == parent.depth + 1
+                   and parent.ts_us <= s.ts_us
+                   and s.ts_us + s.dur_us <= parent.ts_us + parent.dur_us),
+                  key=lambda s: s.ts_us)
+
+
+def test_train_batch_spans_cover_the_host_path(plain_engine):
+    """The names and their order, every child inside ``train_batch``, and
+    what no child covers (bare ``train_batch``: what a device trace's
+    idle reads under that name) under 5% of the span."""
+    telemetry.configure(executable_ledger=True)
+    for _ in range(7):
+        plain_engine.train_batch(_batch()).block_until_ready()
+    spans = telemetry.get_tracer().spans()
+    steps = [s for s in spans if s.name == "train_batch"][1:]
+    assert len(steps) == 6
+    bare = []
+    for step in steps:
+        kids = _children(spans, step)
+        assert [k.name for k in kids] == [
+            "train_batch/prepare", "batch_to_device", "train_batch/observe",
+            "compiled_step", "train_batch/account"]
+        assert all(a.ts_us + a.dur_us <= b.ts_us
+                   for a, b in zip(kids, kids[1:]))
+        bare.append(1.0 - sum(k.dur_us for k in kids) / step.dur_us)
+    assert sorted(bare)[len(bare) // 2] < 0.05, bare
+    names = {s.name for s in spans}
+    assert "step_boundary" in names and "step_fetch" not in names
+    depth0 = {s.name for s in spans if s.depth == 0}
+    assert depth0 == {"train_batch", "step_boundary"}
+
+
+def test_step_fetch_only_where_the_engine_pulls_the_batch(plain_engine):
+    """An iterator's stall lies under ``step_fetch``, before and outside
+    ``train_batch``; a caller that hands the batch in opens none."""
+    telemetry.configure()
+
+    def slow():
+        while True:
+            time.sleep(0.02)
+            yield _batch()
+
+    it = slow()
+    for _ in range(2):
+        plain_engine.train_batch(data_iter=it).block_until_ready()
+    plain_engine.train_batch(_batch()).block_until_ready()
+    spans = telemetry.get_tracer().spans()
+    fetch = [s for s in spans if s.name == "step_fetch"]
+    assert len(fetch) == 2 and all(s.depth == 0 for s in fetch)
+    assert all(s.dur_us >= 20e3 for s in fetch)
+    batches = [s for s in spans if s.name == "train_batch"]
+    assert len(batches) == 3
+    for f, b in zip(fetch, batches):
+        assert f.ts_us + f.dur_us <= b.ts_us
+
+
+def test_ledger_observes_equal_steps_by_comparison(plain_engine,
+                                                   monkeypatch):
+    """``observe`` walks the operands at first sight and when the batch's
+    structure changes, not every step: ten equal steps are one walk and
+    ten calls, a new shape registers a second entry, the old shape finds
+    the first again."""
+    from deepspeed_tpu.telemetry import ledger
+    walks = []
+    walk = ledger._signature
+    monkeypatch.setattr(ledger, "_signature",
+                        lambda a, k: walks.append(1) or walk(a, k))
+    telemetry.configure(executable_ledger=True)
+    for _ in range(10):
+        plain_engine.train_batch(_batch()).block_until_ready()
+    led = telemetry.get_ledger()
+    (first,) = led.entries()
+    assert (first.name, first.calls, len(walks)) == ("compiled_step", 10, 1)
+    plain_engine.train_batch(_batch(seq=8)).block_until_ready()
+    assert len(led.entries()) == 2 and len(walks) == 2
+    second = next(e for e in led.entries() if e is not first)
+    assert second.calls == 1 and second.signature != first.signature
+    plain_engine.train_batch(_batch()).block_until_ready()
+    assert (first.calls, second.calls, len(walks)) == (11, 1, 3)
+    assert led.calls_by_name() == {"compiled_step": 12}
+
+
+def test_ledger_short_path_needs_the_callers_word():
+    """Without ``struct`` every observation walks (the serving engine's
+    call); with it, another callable or another ``struct`` walks again."""
+    from deepspeed_tpu.telemetry import ledger
+    led = ledger.ExecutableLedger(hlo_collectives=False)
+    f = jax.jit(lambda x: x + 1)
+    g = jax.jit(lambda x: x + 2)
+    x, y = jnp.ones(4), jnp.ones(8)
+    a = led.observe("f", f, (x,))
+    assert led.observe("f", f, (x,)) is a and a.calls == 2
+    assert "f" not in led._last
+    b = led.observe("f", f, (x,), struct="x")
+    assert b is a and led._last["f"][2] is a
+    # the short path trusts the word: the operand is not looked at
+    assert led.observe("f", f, (y,), struct="x") is a and a.calls == 4
+    c = led.observe("f", f, (y,), struct="y")
+    assert c is not a and c.calls == 1
+    assert led.observe("f", g, (y,), struct="y") is c and c.calls == 2
+    assert led._last["f"][0] is g
+    led.clear()
+    assert not led._last and len(led) == 0
 
 
 def test_device_truth_opt_in_defaults_off():
