@@ -28,7 +28,8 @@ value_heads`` Hv value heads, of ``linear_key_head_dim`` dk and
 ``conv4`` is one causal depthwise convolution (``linear_conv_kernel_dim``
 taps, no bias) over the channels of ``[q | k | v]``; with the SiLU and the
 l2 norm it is a pass of ``ops.layers.short_conv`` each. The scan is
-``ops/kda.py``'s, which takes ``g`` [B, S, Hv].
+``ops/kda.py``'s, which takes ``g`` [B, S, Hv] and q, k at their Hk heads
+(its kernels read a key head where each of its value heads needs it).
 
 **Gated attention** (``num_heads`` H query and ``num_kv_heads`` key/value
 heads of ``attn_head_dim`` D, NOT hidden / heads)::
@@ -317,12 +318,12 @@ class Qwen3Next(RoutedStackOfKinds):
         z = h @ cols(w, 2 * kw + vw, vw)
         ba = h @ p["w_ba"]
         with jax.named_scope("ds.mix_pre"):
-            # a key head serves hv / hk value heads
-            q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
             beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
             g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., hv:].astype(f32) + p["dt_bias"].astype(f32))
-        # one head group: the step fits whole (12.77 GiB, AOT for a v5e)
+        # q and k at their hk key heads: the scan reads a key head for the
+        # hv / hk value heads it serves. One head group: the step fits
+        # whole (12.77 GiB, AOT for a v5e)
         o = kda_fn(q, k, v, g, beta)
         with jax.named_scope("ds.mix_post"):
             o = L.rms_norm(o.astype(f32), p["o_norm"].astype(f32),

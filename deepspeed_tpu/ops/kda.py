@@ -82,15 +82,18 @@ from .pallas.kda import CHUNK, kda_prepare, kda_recurrence
 
 
 def recurrent_kda(q, k, v, g, beta):
-    """The recurrence, token by token. q, k [B, S, H, dk]; v [B, S, H, dv];
-    g [B, S, H, dk] or [B, S, H] (log-decay a channel, a head); beta
-    [B, S, H]. Returns o [B, S, H, dv] float32."""
+    """The recurrence, token by token. q, k [B, S, Hk, dk]; v [B, S, H,
+    dv]; g [B, S, H, dk] or [B, S, H] (log-decay a channel, a head); beta
+    [B, S, H]. ``H`` is a multiple of ``Hk``: a key head serves ``H / Hk``
+    consecutive value heads (repeated here, in float32). Returns o
+    [B, S, H, dv] float32."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
     if g.ndim == 3:
         g = g[..., None]
-    b, _, h, dk = q.shape
-    dv = v.shape[-1]
+    b, _, h, dv = v.shape
+    q, k = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (q, k))
+    dk = q.shape[-1]
 
     def step(state, xs):
         q_t, k_t, v_t, g_t, b_t = xs
@@ -109,6 +112,13 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
     ``q``'s dtype with float32 accumulation, the decays, the score
     matrices' inverse and the carried state in float32. Returns o
     [B, S, H, dv] in ``v``'s dtype. ``S`` must be a multiple of ``chunk``.
+
+    q and k come at their own head count ``Hk`` (``q.shape[2]`` against
+    ``v.shape[2]``: Qwen3-Next's 16 key heads serve 32 value heads, Kimi's
+    are as many): the preparation's kernels read a key head where each of
+    its value heads needs it and sum those heads' ``dq`` and ``dk`` before
+    they store them, so no repeated q or k is ever written (19.1 ms of the
+    Qwen3-Next cell's 437 ms step until PR 53: my chip runs, PR 53).
 
     The heads (they are independent) run in ``head_groups`` groups, one
     after the other under ``lax.map``, each under its own
@@ -134,12 +144,13 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
     and pays four more float32 [S, H dv] relayouts a layer for it (14.6
     ms): the step read 442.7 ms for 437.4 and 1.2% fewer tokens/s in four
     pairs of four (my chip runs, PR 51)."""
-    h = q.shape[2]
-    if h % head_groups:
-        raise ValueError(f"chunk_kda: {h} heads in {head_groups} groups")
+    h, hk = v.shape[2], q.shape[2]
+    if h % head_groups or hk % head_groups:
+        raise ValueError(
+            f"chunk_kda: {h} heads ({hk} of q and k) in {head_groups} groups")
 
-    def split(x):       # [B, S, H, ...] -> [G, B, S, H/G, ...]
-        x = x.reshape(*x.shape[:2], head_groups, h // head_groups,
+    def split(x):       # [B, S, H, ...] -> [G, B, S, H/G, ...], H its own
+        x = x.reshape(*x.shape[:2], head_groups, x.shape[2] // head_groups,
                       *x.shape[3:])
         return jnp.moveaxis(x, 2, 0)
 
@@ -179,7 +190,7 @@ def sharded_chunk_kda(act_sharding):
 
 
 def _chunk_kda(q, k, v, g, beta, *, chunk):
-    b, s, h, _ = q.shape
+    b, s, h, _ = v.shape
     with jax.named_scope("ds.kda_scan"):
         o = kda_recurrence(*kda_prepare(q, k, v, g, beta, chunk=chunk),
                            out_dtype=v.dtype)           # [B, H, N, C, dv]

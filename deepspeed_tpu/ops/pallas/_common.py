@@ -1,7 +1,7 @@
 """What the kernel modules of this package share: the interpret-mode gate,
 the float32 product, the bf16 pieces of a float32 value, a byte count for
-``cost_estimate``, the once-a-shape trace of a ``pallas_call`` and the
-name under which a kernel's forward rule declares the residuals a rematted
+``cost_estimate``, the registry of the trace-time gauges, the once-a-shape
+trace of a ``pallas_call`` and the name under which a kernel's forward rule declares the residuals a rematted
 region keeps."""
 
 from __future__ import annotations
@@ -40,15 +40,22 @@ def _nbytes(*arrays):
     return sum(x.size * jnp.dtype(x.dtype).itemsize for x in arrays)
 
 
+def _registry():
+    """The metrics registry where telemetry is on, else None: what a
+    kernel module's trace-time gauges (a function of shapes, said where the
+    kernel is built; host only) are set on."""
+    from ...utils.telemetry_probe import active_telemetry
+    tel = active_telemetry()
+    return tel.get_registry() if tel is not None else None
+
+
 def _keep(kernel: str, *arrays):
     """``arrays`` named `KEPT_RESIDUAL`: a forward rule returns them both
     as its output and inside its residuals, and a rematted region's
     backward then reads them back where it would have rerun the kernel.
     Outside a ``jax.checkpoint`` the name does nothing. Trace time, host
     only: gauge ``ds_kernel_kept_bytes`` says what ONE call declares."""
-    from ...utils.telemetry_probe import active_telemetry
-    tel = active_telemetry()
-    reg = tel.get_registry() if tel is not None else None
+    reg = _registry()
     if reg is not None:
         reg.gauge("ds_kernel_kept_bytes",
                   "bytes of the residuals one call of the kernel last "

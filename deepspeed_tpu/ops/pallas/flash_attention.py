@@ -67,7 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import _interpret, _keep
+from ._common import _interpret, _keep, _registry
 
 NEG_INF = -1e30
 _LANES = 128          # a vector register's minor dimension
@@ -174,9 +174,7 @@ def _cut(s, off, window, q_axis: int):
 def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool):
     """Trace time, host only: how often the maskless body engages is a
     function of shapes, so it is counted where the kernel is built."""
-    from ...utils.telemetry_probe import active_telemetry
-    tel = active_telemetry()
-    reg = tel.get_registry() if tel is not None else None
+    reg = _registry()
     if reg is None:
         return
     g = reg.gauge("ds_flash_tiles",

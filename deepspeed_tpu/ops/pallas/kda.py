@@ -46,6 +46,17 @@ no row blocks, no factoring, no clamp, exact at any decay; ``decay``,
 [V, K e^G]`` and their backward are the shared code, and the backward
 returns ``dg`` as rows. The recurrence's kernels do not know the gate.
 
+**Fewer key heads than value heads** (Qwen3-Next: 16 to 32; PR 53): q and
+k arrive at their own head count ``Hk``, v, g and beta at ``H``; ``rep = H
+/ Hk`` is read from the shapes. A grid step of ``heads`` value heads reads
+``heads / rep`` key heads (q's and k's BlockSpecs are that many ``dk``
+lanes of [B, S, Hk dk] at the same block index), head ``h`` slices them at
+key head ``h // rep``, and the backward sums the ``rep`` heads' ``dq`` and
+``dk`` in float32 before the one store into [B, S, Hk dk]: no repeated
+copy of q or k exists, and no sum over pairs after the kernel. At ``rep``
+1 every slice, block and store is what it was. The six operands, and so
+the recurrence's kernels, are per value head either way.
+
 **The recurrence.** What is left is serial in the chunks: with the float32
 state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
 
@@ -106,7 +117,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import _bind, _dot, _interpret
+from ._common import _bind, _dot, _interpret, _registry
 
 CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
 SUB = 8         # rows a sub-block of the score matrices
@@ -706,17 +717,24 @@ class _HeadChunk(_Chunk):
         return dq, dk, d_v, d_g, dbeta
 
 
+def _key_lanes(h, rep, dk):
+    """The lanes of value head ``h``'s key head in q's and k's blocks."""
+    return slice(h // rep * dk, (h // rep + 1) * dk)
+
+
 def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv,
-            head_gate=False):
+            head_gate=False, rep=1):
     """(the rows of chunk ``i`` in the inputs' blocks, that chunk of each
     head of the grid step, their inverses side by side). ``head_gate``:
-    ``g_ref`` holds rows as ``b_ref`` does, a number a token."""
+    ``g_ref`` holds rows as ``b_ref`` does, a number a token. ``rep``: the
+    value heads a key head serves; q's and k's blocks hold ``heads // rep``
+    key heads, and head ``h`` reads the lanes of key head ``h // rep``."""
     rows = pl.ds(pl.multiple_of(i * c, c), c)
     make = _HeadChunk if head_gate else _Chunk
     gate = (lambda h: g_ref[h, i]) if head_gate else (
         lambda h: g_ref[0, rows, h * dk:(h + 1) * dk])
-    chunks = [make(q_ref[0, rows, h * dk:(h + 1) * dk],
-                   k_ref[0, rows, h * dk:(h + 1) * dk],
+    chunks = [make(q_ref[0, rows, _key_lanes(h, rep, dk)],
+                   k_ref[0, rows, _key_lanes(h, rep, dk)],
                    v_ref[0, rows, h * dv:(h + 1) * dv],
                    gate(h).astype(jnp.float32),
                    b_ref[h, i].astype(jnp.float32)) for h in range(heads)]
@@ -725,13 +743,14 @@ def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads, c, dk, dv,
 
 def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
                      qi_ref, a_ref, ko_ref, sh_ref, *, heads, nck, c, dk,
-                     dv, head_gate=False):
+                     dv, head_gate=False, rep=1):
     """``nck`` chunks of ``heads`` heads: the inputs' blocks are [1,
-    nck C, heads d] of the model's [B, S, H d], beta's (and a gate a
-    head's) [heads, nck, 1, C]; the operands' [heads, nck, C, .]."""
+    nck C, heads d] of the model's [B, S, H d] (q's and k's ``heads //
+    rep`` key heads wide), beta's (and a gate a head's) [heads, nck, 1,
+    C]; the operands' [heads, nck, C, .]."""
     def chunk(i, carry):
         _, chunks, _ = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i, heads,
-                               c, dk, dv, head_gate)
+                               c, dk, dv, head_gate, rep)
         for h, chunk in enumerate(chunks):
             for ref, x in zip((uv_ref, w_ref, qi_ref, a_ref, ko_ref,
                                sh_ref), chunk.operands()):
@@ -744,18 +763,25 @@ def _prep_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, uv_ref, w_ref,
 def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
                      dqi_ref, da_ref, dko_ref, dsh_ref, dq_ref, dk_ref,
                      dv_ref, dg_ref, db_ref, *, heads, nck, c, dk, dv,
-                     head_gate=False):
-    """The same blocks; rebuilds each chunk's forward, then its backward."""
+                     head_gate=False, rep=1):
+    """The same blocks; rebuilds each chunk's forward, then its backward.
+    The ``rep`` value heads of a key head lie in one grid step: their
+    ``dq`` and ``dk`` are summed here in float32 and written once."""
     def chunk(i, carry):
         rows, chunks, ts = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, i,
-                                   heads, c, dk, dv, head_gate)
+                                   heads, c, dk, dv, head_gate, rep)
         cts = [tuple(ref[h, i] for ref in (duv_ref, dw_ref, dqi_ref, da_ref,
                                            dko_ref, dsh_ref))
                for h in range(heads)]
         for h, grads in enumerate(_Chunk.gradients(chunks, ts, cts)):
-            for ref, x, d in zip((dq_ref, dk_ref, dv_ref), grads,
-                                 (dk, dk, dv)):
-                ref[0, rows, h * d:(h + 1) * d] = x.astype(ref.dtype)
+            dqk = grads[:2] if h % rep == 0 else [
+                a + x for a, x in zip(dqk, grads[:2])]
+            if h % rep == rep - 1:
+                for ref, x in zip((dq_ref, dk_ref), dqk):
+                    ref[0, rows, _key_lanes(h, rep, dk)] = x.astype(
+                        ref.dtype)
+            dv_ref[0, rows, h * dv:(h + 1) * dv] = grads[2].astype(
+                dv_ref.dtype)
             # dg in its input's layout: rows a head, or the model's own
             at = (h, i) if head_gate else (0, rows,
                                            slice(h * dk, (h + 1) * dk))
@@ -767,24 +793,41 @@ def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
 
 
 def _prep_geometry(q, v, chunk):
-    b, s, h, dk = q.shape
+    """(B, chunks, the VALUE heads, the value heads a key head serves, dk,
+    dv, chunks and heads a grid step). The head count is ``v``'s; q and k
+    may hold fewer heads, each serving ``rep`` consecutive value heads, and
+    a grid step then takes whole key heads."""
+    b, s, hk, dk = q.shape
+    h = v.shape[2]
+    if h % hk:
+        raise ValueError(
+            f"kda kernels: {h} value heads are no multiple of q's and k's "
+            f"{hk} heads")
+    rep = h // hk
     n = s // chunk
     nck = next(d for d in range(min(NCK, n), 0, -1) if n % d == 0)
-    heads = next(d for d in range(min(PREP_HEADS, h), 0, -1) if h % d == 0)
-    return b, n, h, dk, v.shape[-1], nck, heads
+    heads = next((d for d in range(min(PREP_HEADS, h), 0, -1)
+                  if h % d == 0 and d % rep == 0), None)
+    if heads is None:
+        raise ValueError(
+            f"kda kernels: a key head serves {rep} value heads, more than "
+            f"the {PREP_HEADS} heads a grid step of the preparation holds")
+    return b, n, h, rep, dk, v.shape[-1], nck, heads
 
 
-def _prep_specs(b, n, h, c, dk, dv, nck, heads, head_gate=False):
+def _prep_specs(b, n, h, c, dk, dv, nck, heads, head_gate=False, rep=1):
     """(the five inputs' specs, the six operands' specs and shapes): a
-    grid step (batch, head block, chunk block)."""
+    grid step (batch, head block, chunk block). q's and k's blocks are the
+    ``heads // rep`` key heads of the step's value heads, at the same
+    block index."""
     hb = h // heads
-    wide = lambda d: pl.BlockSpec(  # noqa: E731
-        (1, nck * c, heads * d), lambda i, j, l: (i, l, j),
+    wide = lambda d, n=heads: pl.BlockSpec(  # noqa: E731
+        (1, nck * c, n * d), lambda i, j, l: (i, l, j),
         memory_space=pltpu.VMEM)
     flat = lambda *d: pl.BlockSpec(  # noqa: E731
         (heads, nck, *d), lambda i, j, l: (i * hb + j, l, 0, 0),
         memory_space=pltpu.VMEM)
-    ins = [wide(dk), wide(dk), wide(dv),
+    ins = [wide(dk, heads // rep), wide(dk, heads // rep), wide(dv),
            flat(1, c) if head_gate else wide(dk), flat(1, c)]
     ops = [flat(c, dv), flat(c, dk), flat(c, dk), flat(c, c), flat(c, dk),
            flat(1, dk)]
@@ -804,19 +847,34 @@ def _prep_inputs(q, k, v, g, beta, n, c):
             rows(g) if g.ndim == 3 else wide(g), beta)
 
 
+def _gauge_heads(key: int, value: int):
+    """Trace time, host only: whether q and k reach the kernels at fewer
+    heads than v is a function of shapes, so it is said where the
+    preparation's kernel is built."""
+    reg = _registry()
+    if reg is None:
+        return
+    g = reg.gauge("ds_kda_heads",
+                  "heads of q and k (key) and of v, g, beta (value) of the "
+                  "delta-rule scan's preparation kernel last built")
+    g.set(key, kind="key")
+    g.set(value, kind="value")
+
+
 def _prepare_forward(q, k, v, g, beta, chunk):
-    b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
+    b, n, h, rep, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
     _check_chip_shapes(chunk, dk, dv)
+    _gauge_heads(h // rep, h)
     head_gate = g.ndim == 3
     ins, ops, shapes = _prep_specs(b, n, h, chunk, dk, dv, nck, heads,
-                                   head_gate)
+                                   head_gate, rep)
     f32, dt = jnp.float32, q.dtype
     out_shape = [jax.ShapeDtypeStruct(s, d) for s, d in zip(
         shapes, (f32, dt, dt, dt, dt, f32))]
     args = _prep_inputs(q, k, v, g, beta, n, chunk)
     call = pl.pallas_call(
         functools.partial(_prep_fwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv, head_gate=head_gate),
+                          dk=dk, dv=dv, head_gate=head_gate, rep=rep),
         grid=(b, h // heads, n // nck),
         in_specs=ins, out_specs=ops, out_shape=out_shape,
         compiler_params=_PREP_PARAMS,
@@ -826,21 +884,23 @@ def _prepare_forward(q, k, v, g, beta, chunk):
         name="ds_kda_prep_fwd",
     )
     u_v, w, q_in, a_qk, k_out, shrink = _bind(
-        call, "ds.kda_prep_fwd", ("kda_prep_fwd", chunk, nck, heads), *args)
+        call, "ds.kda_prep_fwd", ("kda_prep_fwd", chunk, nck, heads, rep),
+        *args)
     return u_v, w, q_in, a_qk, k_out, shrink.reshape(b * h, n, dk)
 
 
 def _prepare_backward(q, k, v, g, beta, cts, chunk):
-    b, n, h, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
+    b, n, h, rep, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
     head_gate = g.ndim == 3
-    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads, head_gate)
+    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads, head_gate,
+                              rep)
     args = _prep_inputs(q, k, v, g, beta, n, chunk)
     *mats, dshrink = cts
     cts = (*mats, dshrink.reshape(b * h, n, 1, dk))
     out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args]
     call = pl.pallas_call(
         functools.partial(_prep_bwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv, head_gate=head_gate),
+                          dk=dk, dv=dv, head_gate=head_gate, rep=rep),
         grid=(b, h // heads, n // nck),
         in_specs=ins + ops, out_specs=ins, out_shape=out_shape,
         compiler_params=_PREP_PARAMS,
@@ -850,8 +910,8 @@ def _prepare_backward(q, k, v, g, beta, cts, chunk):
         name="ds_kda_prep_bwd",
     )
     dq, dk_, dv_, dg, dbeta = _bind(
-        call, "ds.kda_prep_bwd", ("kda_prep_bwd", chunk, nck, heads), *args,
-        *cts)
+        call, "ds.kda_prep_bwd", ("kda_prep_bwd", chunk, nck, heads, rep),
+        *args, *cts)
     tokens = lambda x: jnp.moveaxis(  # noqa: E731
         x.reshape(b, h, n * chunk), 1, 2)
     dbeta = tokens(dbeta)
@@ -934,12 +994,16 @@ _prepare.defvjp(_prepare_fwd, _prepare_bwd)
 
 def kda_prepare(q, k, v, g, beta, *, chunk: int):
     """The six operands of ``kda_recurrence``, each [B, H, N, C, .]
-    (``shrink`` [B, H, N, dk]), from q, k [B, S, H, dk], v [B, S, H, dv]
+    (``shrink`` [B, H, N, dk]), from q, k [B, S, Hk, dk], v [B, S, H, dv]
     (the matmuls run in ``q``'s dtype), g [B, S, H, dk] and beta [B, S, H]
     (float32 in the kernels), or a gate a HEAD, g [B, S, H] (the kernels
-    then build a chunk's decay as one [C, C] mask: ``_HeadChunk``). ``S``
-    must be a multiple of ``chunk`` and ``chunk`` of ``SUB``."""
-    b, s, h, _ = q.shape
+    then build a chunk's decay as one [C, C] mask: ``_HeadChunk``). ``H``
+    is a multiple of ``Hk``: key head ``j`` serves the value heads ``j H /
+    Hk`` and on, read from the shapes; the kernels index q and k there and
+    never repeat them, and dq, dk come back at ``Hk`` heads, the value
+    heads' parts summed in float32. ``S`` must be a multiple of ``chunk``
+    and ``chunk`` of ``SUB``."""
+    b, s, h, _ = v.shape
     if s % chunk or chunk % SUB:
         raise ValueError(
             f"chunk_kda: sequence {s} must be a multiple of the chunk "

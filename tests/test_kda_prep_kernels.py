@@ -61,10 +61,11 @@ def test_chunked_kda_stays_finite_where_a_channel_forgets_in_one_token(
 
 # ---- KDA: the preparation's kernel pair (interpret mode) -------------------
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("heads_a_step,chunks", [
-    (1, 3), (1, 5), (2, 3), (2, 5), (3, 3), (4, 3), (8, 3)])
+@pytest.mark.parametrize("heads_a_step,chunks,rep", [
+    (1, 3, 1), (1, 5, 1), (2, 3, 1), (2, 5, 1), (3, 3, 1), (4, 3, 1),
+    (8, 3, 1), (2, 5, 2), (4, 3, 2), (4, 3, 4)])
 def test_kda_preparation_kernels_match_the_jax_numpy_preparation(
-        heads_a_step, chunks, dtype, monkeypatch):
+        heads_a_step, chunks, rep, dtype, monkeypatch):
     """``ds_kda_prep_fwd`` and ``ds_kda_prep_bwd`` against the preparation
     as ``ops/kda.py`` held it in ``jax.numpy`` (``helpers/kda_reference``)
     and its autodiff: the six operands, and the five input gradients from
@@ -72,26 +73,33 @@ def test_kda_preparation_kernels_match_the_jax_numpy_preparation(
     steps of 1 (a grid step takes a divisor of the chunk count). The heads
     of a grid step take the inverse's float32 products two to a product
     (PR 44): one head runs alone, 2, 4 and 8 are one, two and four pairs,
-    3 a pair and a lone head."""
+    3 a pair and a lone head. ``rep`` (PR 53): q and k hold a head for every
+    ``rep`` of v's, the reference takes them repeated and its autodiff sums
+    the cotangents of a key head's copies, which the backward kernel does
+    before its one store: a grid step's blocks of q and k are 1, 2 and 1
+    key heads wide where v's are 2, 4 and 4 (two key heads under 8 value
+    heads of one grid step: ``tests/test_qwen3_next_scan.py``)."""
     monkeypatch.setattr(kda_kernels, "PREP_HEADS", heads_a_step)
     monkeypatch.setattr(kda_kernels, "NCK", 4)
     args = _kda_inputs(s=64 * chunks, h=max(2, heads_a_step),
                        b=2 if heads_a_step < 3 else 1)
+    args[:2] = [x[:, :, ::rep] for x in args[:2]]
     tol_o, tol_g = 1e-5, 2e-5
     if dtype == "bfloat16":
         args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
-    b, _, h, dk = args[0].shape
     geometry = kda_kernels._prep_geometry(args[0], args[2], 64)
     assert geometry[-2:] == ({3: 3, 5: 1}[chunks], heads_a_step)
+    reference = lambda q, k, *rest, chunk: kda_reference.prepare(  # noqa: E731
+        *(jnp.repeat(x, rep, axis=2) for x in (q, k)), *rest, chunk=chunk)
     rng = np.random.default_rng(chunks)
     cts = tuple(jnp.asarray(rng.normal(size=x.shape), x.dtype)
                 for x in jax.eval_shape(
-                    lambda *a: kda_reference.prepare(*a, chunk=64), *args))
+                    lambda *a: reference(*a, chunk=64), *args))
     # jitted: eager, every interpreted kernel call compiles alone
     both = lambda f: jax.jit(lambda *a: (  # noqa: E731
         lambda out, pull: (out, pull(cts)))(
             *jax.vjp(lambda *x: f(*x, chunk=64), *a)))(*args)
-    want, want_g = both(kda_reference.prepare)
+    want, want_g = both(reference)
     got, got_g = both(kda_kernels.kda_prepare)
     names = ("u_v", "w", "q_in", "a_qk", "k_out", "shrink")
     for name, x, y in zip(names, got, want):

@@ -1,9 +1,10 @@
 """Qwen3-Next (ISSUE 46), the parts under the layers: the delta rule's
 chunked form and its kernels with a gate a HEAD against the recurrence
-token by token, and the held share of 512 small experts beside the gated
-shared expert against the uncut layer (``tests/test_qwen3_next.py`` holds
-the model to its reference). A CPU run shows results and counts, never a
-time."""
+token by token, q and k at their KEY heads (ISSUE 53: the kernels read a
+key head where each of its value heads needs it, at either gate), and the
+held share of 512 small experts beside the gated shared expert against the
+uncut layer (``tests/test_qwen3_next.py`` holds the model to its
+reference). A CPU run shows results and counts, never a time."""
 
 import functools
 
@@ -12,23 +13,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu import telemetry
 from deepspeed_tpu.moe.sharded_moe import held_block, moe_ffn_held
 from deepspeed_tpu.ops import kda
 
-from helpers.family_cases import _close, _err
+from helpers.family_cases import (_batch, _close, _err,  # noqa: F401
+                                  _kda_inputs, _telemetry_isolation,
+                                  _walk_eqns)
+from helpers.kept_cases import kernel_calls, tiny
 from architectures import qwen3_next as arch  # noqa: E402
 
 
 # ---- the scan with a gate a head -------------------------------------------
 def _gdn_inputs(b=2, s=192, hk=2, hv=4, dk=32, dv=16, seed=0, fast=False):
-    """q and k at ``hk`` key heads repeated to ``hv`` value heads, as the
-    layer hands them in; ``g`` [B, S, hv] from A = U(0, 16) (``fast``:
-    every head at A = 16 and a softplus of 5: -80 a token, -5120 a chunk)."""
+    """q and k at ``hk`` key heads beside v, g, beta at ``hv`` value heads,
+    as the layer hands them in; ``g`` [B, S, hv] from A = U(0, 16)
+    (``fast``: every head at A = 16 and a softplus of 5: -80 a token,
+    -5120 a chunk)."""
     rng = np.random.default_rng(seed)
     l2 = lambda x: x / np.sqrt((x ** 2).sum(-1, keepdims=True) + 1e-6)  # noqa: E731
-    rep = lambda x: np.repeat(x, hv // hk, axis=2)  # noqa: E731
-    q = rep(l2(rng.normal(size=(b, s, hk, dk))) / np.sqrt(dk))
-    k = rep(l2(rng.normal(size=(b, s, hk, dk))))
+    q = l2(rng.normal(size=(b, s, hk, dk))) / np.sqrt(dk)
+    k = l2(rng.normal(size=(b, s, hk, dk)))
     v = rng.normal(size=(b, s, hv, dv))
     a = np.full(hv, 16.0) if fast else rng.uniform(0.01, 16, size=hv)
     soft = np.log1p(np.exp(rng.normal(size=(b, s, hv)) + (5 if fast else 1)))
@@ -57,7 +62,7 @@ def test_a_gate_a_head_through_the_kernels_is_the_recurrence(case):
     recurrent = jax.jit(kda.recurrent_kda)
     chunked = jax.jit(kda.chunk_kda, static_argnames="head_groups")
     want = recurrent(*args)
-    wide = jnp.broadcast_to(g[..., None], q.shape)
+    wide = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
     _close(recurrent(q, k, v, wide, beta), want, 0, "recurrent")
     got = chunked(*args)
     _close(got, want, 2e-5, "forward")
@@ -75,6 +80,123 @@ def test_a_gate_a_head_through_the_kernels_is_the_recurrence(case):
         # an absolute 1e-9 there, to 2e-4 of its largest otherwise
         scale = max(float(jnp.max(jnp.abs(b))), 5e-6)
         assert float(jnp.max(jnp.abs(a - b))) <= 2e-4 * scale, name
+
+
+# ---- q and k at the key heads ----------------------------------------------
+def _key_head_inputs(rep, gate, hk=2):
+    """q, k at ``hk`` key heads; v, beta and the gate (a head's [B, S, H],
+    a channel's [B, S, H, dk]) at ``H = rep hk`` value heads, float32."""
+    if gate == "a_head":
+        return _gdn_inputs(b=1, s=128, hk=hk, hv=rep * hk, seed=rep)
+    args = _kda_inputs(b=1, s=128, h=rep * hk, seed=rep)
+    args[:2] = [x[:, :, ::rep] for x in args[:2]]
+    return args
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+@pytest.mark.parametrize("gate", ["a_head", "a_channel"])
+@pytest.mark.parametrize("rep", [2, 4])
+def test_a_key_head_is_read_where_its_value_heads_need_it(rep, gate, groups):
+    """``chunk_kda`` with q and k at ``Hk`` heads and v, g, beta at ``rep
+    Hk`` (``rep`` is read from the shapes): against ``recurrent_kda`` on
+    the repeated heads, forward and the five gradients, ``dq`` and ``dk``
+    in the KEY heads' shape; the forward bit-equal to the parent's form
+    (``jnp.repeat``, then the op at equal head counts). Two key heads in
+    one head group and in two: a group holds whole key heads."""
+    args = _key_head_inputs(rep, gate)
+    cot = jnp.asarray(np.random.default_rng(9).normal(
+        size=args[2].shape), jnp.float32)
+
+    def both(f):
+        total = lambda *a: (lambda o: (jnp.sum(o * cot), o))(f(*a))  # noqa: E731
+        return jax.jit(jax.value_and_grad(
+            total, argnums=range(5), has_aux=True))(*args)
+
+    scan = functools.partial(kda.chunk_kda, head_groups=groups)
+    repeated = lambda q, k, *rest: scan(  # noqa: E731
+        *(jnp.repeat(x, rep, axis=2) for x in (q, k)), *rest)
+    (_, want), want_g = both(kda.recurrent_kda)
+    (_, got), got_g = both(scan)
+    np.testing.assert_array_equal(got, jax.jit(repeated)(*args))
+    _close(got, want, 2e-5, "forward")
+    for name, x, a, y in zip("q k v g beta".split(), got_g, args, want_g):
+        assert x.shape == a.shape == y.shape, name
+        _close(x, y, 2e-4, f"d{name}")
+
+
+@pytest.mark.parametrize("case, hk, h, groups, says", [
+    ("a_group_cuts_a_key_head", 2, 4, 4, "2 of q and k"),
+    ("no_head_block_holds_the_heads_of_a_key_head", 1, 16, 1,
+     "serves 16 value heads"),
+    ("value_heads_no_multiple_of_key_heads", 3, 4, 1, "no multiple")])
+def test_head_counts_the_scan_cannot_run_are_refused(case, hk, h, groups,
+                                                     says):
+    """Nothing is repeated in silence: head groups that would cut a key
+    head, more value heads to a key head than a grid step of the
+    preparation holds, and value heads that are no multiple of the key
+    heads are each a ``ValueError`` that says so."""
+    sd = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    args = (sd(1, 64, hk, 32), sd(1, 64, hk, 32), sd(1, 64, h, 16),
+            sd(1, 64, h), sd(1, 64, h))
+    with pytest.raises(ValueError, match=says):
+        jax.eval_shape(functools.partial(kda.chunk_kda, head_groups=groups),
+                       *args)
+
+
+def _loss_gradient(family):
+    """(the tiny model, the gradient of its loss as a function of seeded
+    shapes): what the engine's train step differentiates."""
+    model = tiny(family)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch = _batch(model)
+
+    def grad(p):
+        out = model.loss(p, batch)
+        return out[0] if isinstance(out, tuple) else out
+
+    return model, jax.grad(grad), params
+
+
+def test_the_step_holds_no_repeated_q_or_k_and_the_parents_kernel_counts():
+    """The tiny Qwen3-Next step's gradient as it is traced: nothing under
+    ``ds.mix_pre`` (forward, remat's rerun or transpose) is larger than
+    the [B, S, 2 Hv] projection beta and the gate are cut from, where the
+    parent repeated q and k there ([B, S, Hv, dk]) and summed the pairs
+    back; and the scan's kernels are called as often as at the parent,
+    3 / 1 / 3 / 1 (one head group keeps nothing, PR 51; the period's three
+    Gated DeltaNet layers are one traced body)."""
+    model, grad, params = _loss_gradient("qwen3_next")
+    c = model.config
+    calls = kernel_calls(grad, params)
+    assert [calls[k] for k in ("ds_kda_prep_fwd", "ds_kda_prep_bwd",
+                               "ds_kda_fwd", "ds_kda_bwd")] == [3, 1, 3, 1]
+    pre = [v.aval for e in _walk_eqns(jax.make_jaxpr(grad)(params).jaxpr)
+           if "ds.mix_pre" in str(e.source_info.name_stack)
+           for v in e.outvars]
+    tokens = 2 * c.max_seq_len
+    assert pre and max(a.size for a in pre) <= (
+        tokens * 2 * c.linear_num_value_heads), max(
+            pre, key=lambda a: a.size)
+
+
+@pytest.mark.parametrize("family, key, value", [("qwen3_next", 2, 4),
+                                                ("kimi_linear", 2, 2)])
+def test_the_gauge_says_the_heads_the_preparation_was_built_for(
+        family, key, value):
+    """``ds_kda_heads{kind="key"|"value"}`` is set where the preparation's
+    kernel is built (trace time): 2 key heads to 4 value heads at the tiny
+    Qwen3-Next, as many of each at the tiny Kimi-Linear (a head group of
+    its two). With telemetry off nothing is touched."""
+    _, grad, params = _loss_gradient(family)
+    jax.eval_shape(grad, params)
+    telemetry.configure()
+    assert telemetry.get_registry().get("ds_kda_heads") is None
+    # a new model: jax.checkpoint keeps a function's trace
+    _, grad, params = _loss_gradient(family)
+    jax.eval_shape(grad, params)
+    gauge = telemetry.get_registry().get("ds_kda_heads")
+    assert (gauge.value(kind="key"), gauge.value(kind="value")) == (
+        key, value)
 
 
 # ---- the held share and the gated shared expert ----------------------------

@@ -46,9 +46,16 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # ``kimi_linear`` by design (its KDA layers run in two head groups here, four
 # in its cell: a rematted layer keeps the delta-rule scan's ``o``,
 # ``ops/kda.py`` ``chunk_kda``, and the layer's rerun holds no kernel of the
-# scan); the seven other rows stand: ``qwen3_next`` calls the op with one
+# scan); the seven other rows stood: ``qwen3_next`` calls the op with one
 # head group, where nothing is kept, and the five other families never call
-# it.
+# it. PR 53 re-took ``qwen3_next`` by design (its Gated DeltaNet layer hands
+# q and k to the scan at their 2 key heads where it repeated them to the 4
+# value heads: ``models/qwen3_next.py`` ``_gdn`` holds no ``jnp.repeat``, the
+# preparation's kernels read a key head for the value heads it serves and
+# sum those heads' ``dq`` and ``dk`` before their one store,
+# ``ops/pallas/kda.py``; the seeded weights are the parent's); the seven
+# other rows stand: ``kimi_linear`` calls the op with as many key heads as
+# value heads, where every slice, block and store is the parent's.
 _PINS = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
@@ -85,7 +92,7 @@ _PINS = {
     "qwen3_next": (Qwen3Next, dict(
         num_layers=2, full_attention_interval=2, moe_held_experts=32,
         qk_norm_init=2.0, attn_impl="flash", loss_chunk=64),
-        "ee1242b18203a27974544d1ce6ecbbfb33d6f467ae5a5583c366e91988b673e1",
+        "6bee3752161b25651463093ce15855be1e5030ae8a99ae594ca430fc6dae2678",
         39458.17879846059),
 }
 

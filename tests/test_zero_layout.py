@@ -256,7 +256,7 @@ def test_kda_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
         jax.jit(jax.grad(loss(kda.chunk_kda))).lower(*args).compile()
 
 
-@pytest.mark.parametrize("gate", ["a_channel", "a_head"])
+@pytest.mark.parametrize("gate", ["a_channel", "a_head", "a_head_16_keys"])
 def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
     """The preparation's kernel pair (PR 35) at the cells' widths (one
     sequence of 16384, a head group of 8 heads of 128, bf16 with float32
@@ -265,7 +265,11 @@ def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
     the blocks of the model's [B, S, H d] layout are what interpret mode
     cannot refuse. Per shard on ``v5e:2x2`` they compile in the test above.
     ``a_head`` (PR 46): Gated DeltaNet's gate, rows as beta's, at all 32
-    value heads of its cell: the [C, 1] columns, the [C, C] mask."""
+    value heads of its cell: the [C, 1] columns, the [C, C] mask.
+    ``a_head_16_keys`` (PR 53): q and k at the cell's 16 key heads, as its
+    layer hands them in: blocks of 4 key heads (512 lanes) beside blocks of
+    8 value heads, the pair's ``dq`` and ``dk`` summed in the kernel and
+    stored at the key heads' width."""
     import re
 
     from jax.experimental import topologies
@@ -284,7 +288,8 @@ def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
         kernels.CHUNK
     n = s // c
     wide = (b, s, h, d)
-    ins = (sd(wide, bf), sd(wide, bf), sd(wide, bf),
+    keys = (b, s, 16, d) if gate == "a_head_16_keys" else wide
+    ins = (sd(keys, bf), sd(keys, bf), sd(wide, bf),
            sd(wide if gate == "a_channel" else wide[:3], f32),
            sd(wide[:3], f32))
     cts = (sd((b * h, n, c, d), f32), sd((b * h, n, c, d), bf),
@@ -299,6 +304,8 @@ def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
                          compiled.as_text()), name
     assert [x.shape for x in jax.eval_shape(fwd, *ins)] == [
         x.shape for x in cts]
+    assert [x.shape for x in jax.eval_shape(bwd, *ins, *cts)] == [
+        x.shape for x in ins]
     with pytest.raises(ValueError, match="multiples of 128"):
         fwd.lower(*(sd(x.shape[:3] + (64,) * (len(x.shape) - 3), x.dtype)
                     for x in ins))

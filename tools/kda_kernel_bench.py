@@ -1,12 +1,16 @@
 """The four KDA kernels alone, on the chip: time and results of this
 checkout's ``ops/pallas/kda.py`` against the forms they replaced.
 
-    chiprun -- python tools/kda_kernel_bench.py [HEADS ...]
+    chiprun -- python tools/kda_kernel_bench.py [--key-heads N]
+        [--gate channel|head] [HEADS ...]
 
 Sizes a change to the kernels before a cell is run (PRs 32, 35). Shapes
 are the cell's (one sequence of 16384, heads of 128, chunks of 64) at each
-HEADS (default 8, a head group of the cell, and 32, a layer). A line a
-head count:
+HEADS (default 8, a head group of the Kimi cell, and 32, a layer).
+``--key-heads`` gives q and k fewer heads than v, g and beta (default: as
+many), ``--gate head`` a log-decay a head (default: a channel):
+``--key-heads 16 --gate head 32`` is a Gated DeltaNet layer of the
+Qwen3-Next cell. A line a head count:
 
 - ``kernel_ms``: the recurrence's forward kernel, its checkpoint form and
   its backward kernel, each the mean duration of the ``tpu_custom_call``
@@ -20,7 +24,12 @@ head count:
   is the inverse's own time, the heads' placement side by side and their
   taking apart in it (PR 44); ``jnp_fwd`` / ``jnp_fwd_bwd``: the
   ``jax.numpy`` preparation they replaced (``tests/helpers/
-  kda_reference.py``) and its autodiff, device busy time a call;
+  kda_reference.py``; q and k repeated to the value heads and a gate a head
+  widened to the channels for it: this tool's decays are mild enough for
+  its row blocks) and its autodiff, device busy time a call; with fewer key
+  heads ``repeated_fwd_busy`` / ``repeated_bwd_busy``: the form PR 53
+  replaced, ``jnp.repeat`` of q and k and then the kernel at equal head
+  counts, the backward with XLA's sum over a key head's copies;
 - ``scan_ms``: the recurrence in PR 31's form (``scan_recurrence`` below,
   a copy: ``jax.lax.scan`` over the chunks, autodiff backward) on the same
   operands, forward alone and forward with backward, device busy time;
@@ -30,8 +39,9 @@ head count:
   forward is dead) with the kernels and with the ``jax.numpy`` preparation
   in their place: device busy time a call, each kernel's part, the rest;
 - ``layer_ms`` (at 32 heads): ``jax.grad`` of a layer's ``chunk_kda`` (four
-  groups under ``lax.map``, each under its checkpoint, the whole under the
-  layer's remat with the models' policy, which keeps ``o``; the loss is
+  groups under ``lax.map`` with a gate a channel, ONE with a gate a head,
+  as the two cells run it, each under its checkpoint, the whole under the
+  layer's remat with the models' policy, which keeps ``o`` of four; the loss is
   quadratic: what a step runs of a layer, the forward, the groups' rerun
   and the backward), split the same way: ``rest`` is what ``lax.map`` and
   XLA's copies add;
@@ -86,17 +96,20 @@ def scan_recurrence(u_v, w, q_in, a_qk, k_out, shrink, *, out_dtype):
     return jnp.moveaxis(o, 0, 2)
 
 
-def inputs(heads: int, seed: int = 32):
-    """q, k, v (bf16), g, beta (float32) [1, SEQ, heads, D] as the model's
-    mixer makes them: unit keys, decays from 1e-3 to 1.6 a token."""
+def inputs(heads: int, key_heads: int, head_gate: bool, seed: int = 32):
+    """q, k [1, SEQ, key_heads, D], v [1, SEQ, heads, D] (bf16), g (a
+    channel's [1, SEQ, heads, D] or a head's [1, SEQ, heads]) and beta
+    (float32) as the model's mixer makes them: unit keys, decays from 1e-3
+    to 1.6 a token."""
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
     shape = (1, SEQ, heads, D)
+    keys = (1, SEQ, key_heads, D)
     unit = lambda x: x / np.sqrt((x ** 2).sum(-1, keepdims=True) + 1e-6)  # noqa: E731
-    q = unit(rng.normal(size=shape)) / np.sqrt(D)
-    k = unit(rng.normal(size=shape))
+    q = unit(rng.normal(size=keys)) / np.sqrt(D)
+    k = unit(rng.normal(size=keys))
     v = rng.normal(size=shape)
-    g = -np.exp(rng.uniform(-7, 0.5, size=shape))
+    g = -np.exp(rng.uniform(-7, 0.5, size=shape[:3] if head_gate else shape))
     beta = 1 / (1 + np.exp(-rng.normal(size=shape[:3])))
     return ([jnp.asarray(x, jnp.bfloat16) for x in (q, k, v)]
             + [jnp.asarray(x, jnp.float32) for x in (g, beta)])
@@ -152,6 +165,12 @@ def rel_err(a, b) -> float:
 
 
 def main(argv) -> int:
+    import argparse
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("heads", nargs="*", type=int, default=[8, 32])
+    ap.add_argument("--key-heads", type=int, default=None)
+    ap.add_argument("--gate", choices=("channel", "head"), default="channel")
+    opts = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops import kda
@@ -159,8 +178,20 @@ def main(argv) -> int:
     from helpers import kda_reference
     bf = jnp.bfloat16
     kernel_prepare = kda.kda_prepare
-    for heads in [int(a) for a in argv] or [8, 32]:
-        args = inputs(heads)
+    head_gate = opts.gate == "head"
+    for heads in opts.heads:
+        key_heads = opts.key_heads or heads
+        rep = heads // key_heads
+        args = inputs(heads, key_heads, head_gate)
+        repeat = lambda x: jnp.repeat(x, rep, axis=2)  # noqa: E731
+
+        def jnp_prepare(q, k, v, g, beta, *, chunk):
+            """The ``jax.numpy`` form at what it knows: equal head counts,
+            a gate a channel."""
+            if head_gate:
+                g = jnp.broadcast_to(g[..., None], v.shape[:3] + (D,))
+            return kda_reference.prepare(repeat(q), repeat(k), v, g, beta,
+                                         chunk=chunk)
         ops = jax.jit(lambda *a: kernel_prepare(*a, chunk=CHUNK))(*args)
         rng = np.random.default_rng(1)
         do = jnp.asarray(rng.normal(size=ops[0].shape), bf)
@@ -174,7 +205,7 @@ def main(argv) -> int:
         prep_fwd = jax.jit(lambda *a: kernels._prepare_forward(*a, CHUNK))
         prep_bwd = jax.jit(
             lambda *a: kernels._prepare_backward(*a[:5], a[5:], CHUNK))
-        ref_fwd = jax.jit(lambda *a: kda_reference.prepare(*a, chunk=CHUNK))
+        ref_fwd = jax.jit(lambda *a: jnp_prepare(*a, chunk=CHUNK))
         pull = lambda f: jax.jit(lambda *a: jax.vjp(  # noqa: E731
             lambda *x: tuple(y.reshape(-1, *y.shape[2:])
                              for y in f(*x, chunk=CHUNK)),
@@ -196,7 +227,8 @@ def main(argv) -> int:
             lambda *a: kernels._prepare_forward(*a, CHUNK)), args))
         kernels._inverse_unit_lower = inverse
         _common._TRACED.clear()
-        line = {"heads": heads, "seg": kernels.SEG,
+        line = {"heads": heads, "key_heads": key_heads, "gate": opts.gate,
+                "seg": kernels.SEG,
                 "heads_a_step": kernels.HEADS,
                 "prep_chunks_a_step": kernels.NCK,
                 "prep_heads_a_step": kernels.PREP_HEADS,
@@ -212,7 +244,7 @@ def main(argv) -> int:
                     "bwd": kernel_ms(ev_pb), "bwd_busy": busy_ms(ev_pb),
                     "jnp_fwd": busy_ms(traced(jax, ref_fwd, args)),
                     "jnp_fwd_bwd": busy_ms(traced(
-                        jax, pull(kda_reference.prepare), (*args, *cts)))},
+                        jax, pull(jnp_prepare), (*args, *cts)))},
                 "scan_ms": {
                     "fwd": busy_ms(traced(jax, scan_fwd, ops)),
                     "fwd_bwd": busy_ms(traced(jax, vjp(scan_recurrence),
@@ -228,14 +260,21 @@ def main(argv) -> int:
                     map(rel_err,
                         (*ops, *pull(kernel_prepare)(*args, *cts)),
                         (*ref_fwd(*args),
-                         *pull(kda_reference.prepare)(*args, *cts)))))}
+                         *pull(jnp_prepare)(*args, *cts)))))}
+        if rep > 1:     # what PR 53 replaced: the repeat, then equal heads
+            repeated = lambda q, k, *rest, chunk: kernel_prepare(  # noqa: E731
+                repeat(q), repeat(k), *rest, chunk=chunk)
+            line["prep_ms"]["repeated_fwd_busy"] = busy_ms(traced(
+                jax, jax.jit(lambda *a: repeated(*a, chunk=CHUNK)), args))
+            line["prep_ms"]["repeated_bwd_busy"] = busy_ms(traced(
+                jax, pull(repeated), (*args, *cts)))
         del ops, flat, ck, do, cts
         if heads == 8:      # a head group of the cell, without lax.map
             wgt = jnp.asarray(np.random.default_rng(2).normal(
                 size=args[2].shape), bf)
             whole = {}
             for name, f in (("kernels", kernel_prepare),
-                            ("jnp_prepare", kda_reference.prepare)):
+                            ("jnp_prepare", jnp_prepare)):
                 kda.kda_prepare = f
                 # a new function a form: jax.checkpoint keeps its trace
                 group = jax.checkpoint(
@@ -247,10 +286,12 @@ def main(argv) -> int:
                 whole[name] = by_kernel(ev)
             kda.kda_prepare = kernel_prepare
             line["chunk_kda_ms"] = whole
-        if heads == 32:     # a layer of the cell: four groups under lax.map
+        if heads == 32:     # a layer of a cell: Kimi's four groups under
+            #                     lax.map, Qwen3-Next's one
             from deepspeed_tpu.models.transformer import _remat_policy
             layer = jax.checkpoint(
-                lambda *a: kda.chunk_kda(*a, head_groups=4),
+                lambda *a: kda.chunk_kda(
+                    *a, head_groups=1 if head_gate else 4),
                 policy=_remat_policy("nothing_saveable"))
             grad = jax.jit(jax.grad(
                 lambda *a: 0.5 * jnp.sum(
