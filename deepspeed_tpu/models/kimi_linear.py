@@ -230,18 +230,7 @@ class KimiLinear(RoutedStackOfKinds):
         routed to the experts held here and the rows they computed
         (equal, or rows were dropped), over ``moe_held_calls`` routed
         layers of ``moe_held_experts`` each."""
-        from ..moe.sharded_moe import balance_bias
-        layers = {g: dict(slots) for g, slots in params["layers"].items()}
-
-        def move_bias(group, slot, counts):
-            p = layers[group][slot]
-            moe = dict(p["moe"])
-            moe["router_bias"] = balance_bias(moe["router_bias"],
-                                              counts["load"])
-            layers[group][slot] = {**p, "moe": moe}
-
-        metrics = self._held_metrics(stats, each=move_bias)
-        return {**params, "layers": layers}, metrics
+        return self._balanced(params, stats)
 
     # ---------------- init ----------------
     def _init_layer(self, key, kind, lead_shape=()):

@@ -244,7 +244,9 @@ class RoutedStackOfKinds(StackOfKinds):
     counts back. ``loss(with_stats=True)`` returns them beside the loss,
     and the engine calls the family's ``after_step(params, stats)`` with
     the step's updated weights and takes ``(params, metrics)`` from it;
-    ``_held_metrics`` makes the metrics every such family returns."""
+    ``_held_metrics`` makes the metrics every such family returns, and
+    ``_balanced`` is the whole ``after_step`` of one with a selection
+    bias."""
 
     def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
              with_stats: bool = False):
@@ -260,6 +262,24 @@ class RoutedStackOfKinds(StackOfKinds):
         behind: the routed families' recorder."""
         from ..moe.dispatch import record_held_expert_counts
         record_held_expert_counts(reg, metrics)
+
+    def _balanced(self, params, stats):
+        """The ``after_step`` of a family whose routed layers select with a
+        bias (``moe["router_bias"]``): every routed layer's bias moves by
+        ``BIAS_UPDATE_RATE`` against its experts' load in the step
+        (``balance_bias``). Returns (params, ``_held_metrics``)."""
+        from ..moe.sharded_moe import balance_bias
+        layers = {g: dict(slots) for g, slots in params["layers"].items()}
+
+        def move_bias(group, slot, counts):
+            p = layers[group][slot]
+            moe = dict(p["moe"])
+            moe["router_bias"] = balance_bias(moe["router_bias"],
+                                              counts["load"])
+            layers[group][slot] = {**p, "moe": moe}
+
+        metrics = self._held_metrics(stats, each=move_bias)
+        return {**params, "layers": layers}, metrics
 
     def _held_metrics(self, stats, each=None) -> dict:
         """What an ``after_step`` returns of the routed layers' counts of

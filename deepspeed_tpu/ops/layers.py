@@ -87,6 +87,32 @@ def sharded_short_conv(act_sharding):
     return conv
 
 
+def gated_short_conv(bcx, w):
+    """LFM2's gated short convolution, one pass over the input projection's
+    output: bcx [B, S, 3 C] = ``[B | Cg | X]`` (three equal column runs),
+    taps w [n, C];
+
+        u = B * X;  c_t = sum_i w[i] u_{t-(n-1)+i}, zeros before the start
+        y = Cg * c
+
+    with no bias and no activation. Float32 inside, rounded once to
+    ``bcx``'s dtype; returns [B, S, C]. A Pallas kernel pair under one
+    ``custom_vjp`` (``ops/pallas/short_conv.py``; scope ``ds.gconv_mix``)
+    that reads the three runs where they lie and writes the cotangent of
+    ``bcx`` as one array; ``tests/helpers/gated_conv_reference.py`` keeps
+    the ``jax.numpy`` form."""
+    from .pallas.short_conv import gated_short_conv as kernels
+    return kernels(bcx, w)
+
+
+def sharded_gated_short_conv(act_sharding):
+    """``gated_short_conv`` for a multi-device mesh: per shard of the batch
+    under a shard_map, as ``sharded_short_conv`` and for its reasons (the
+    taps' gradient is summed over the shards)."""
+    from ..parallel.mesh import per_batch_shard
+    return per_batch_shard(gated_short_conv, act_sharding, (True, False))
+
+
 def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
                   original_max_position_embeddings: int,
                   beta_fast: float = 32.0, beta_slow: float = 1.0, **_kw):

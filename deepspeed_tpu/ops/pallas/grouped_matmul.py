@@ -69,6 +69,8 @@ ADD_TILE = 128      # ``add_rows``: tokens a tile of the carry and rows a
 #                     row gather (7 ns a row from there, 36 from HBM:
 #                     PERF.md section 6, PR 48)
 
+_RESIDENT_MAX = 100 << 20   # ``backward``: an expert's blocks in VMEM
+
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 _ZERO, _CARRY = 1, 2                # a tile's ``init``: the dW before it
@@ -114,8 +116,8 @@ def _rows_spec(tile, width):
     return pl.BlockSpec((tile, width), lambda t, e, src, *_: (src[t], 0))
 
 
-def _expert_spec(shape):
-    return pl.BlockSpec((1, *shape), lambda t, e, *_: (e[t], 0, 0))
+def _expert_spec(shape, **kw):
+    return pl.BlockSpec((1, *shape), lambda t, e, *_: (e[t], 0, 0), **kw)
 
 
 # ---------------------------------------------------------------- forward
@@ -221,13 +223,24 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
     w = [experts[n] for n in names]
     held, _, f = w[0].shape
     carry = pl.BlockSpec(memory_space=pl.ANY)
+    # an expert's weights and its float32 dW blocks, two buffers each; past
+    # ``_RESIDENT_MAX`` (hidden 2048 by an expert of 1536: 113 MiB, and the
+    # kernel asked 127.3 of the 126 it may have) the weights take ONE: the
+    # next expert's are fetched when its first tile arrives and not behind
+    # the last tile before it (23 us an expert at 819 GB/s)
+    resident = 2 * (_nbytes(*w) + _nbytes(*sums)) // held
+    once = {}
+    if resident > _RESIDENT_MAX:
+        once = {"pipeline_mode": pl.Buffered(1)}
+        resident -= _nbytes(*w) // held
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, router_grad=router_grad),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(c // tile,),
             in_specs=[_rows_spec(tile, d), _rows_spec(tile, d),
-                      _rows_spec(tile, 1), _expert_spec((d, f)),
-                      _expert_spec((d, f)), _expert_spec((f, d)),
+                      _rows_spec(tile, 1), _expert_spec((d, f), **once),
+                      _expert_spec((d, f), **once),
+                      _expert_spec((f, d), **once),
                       carry, carry, carry],
             out_specs=[_rows_spec(tile, d), _rows_spec(tile, 1),
                        _expert_spec((d, f)), _expert_spec((d, f)),
@@ -237,8 +250,7 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
                    jax.ShapeDtypeStruct((c, 1), jnp.float32),
                    *(jax.ShapeDtypeStruct(s.shape, s.dtype) for s in sums)],
         input_output_aliases={10: 2, 11: 3, 12: 4},
-        compiler_params=_params(
-            2 * (_nbytes(*w) + _nbytes(*sums)) // held, tile),
+        compiler_params=_params(resident, tile),
         cost_estimate=pl.CostEstimate(
             flops=int(16 * c * d * f), transcendentals=int(c * f),
             bytes_accessed=int(4 * _nbytes(xs) + _nbytes(scale, *w)

@@ -1,6 +1,9 @@
 """The short convolution of the recurrent mixers (KDA's q, k, v; Mamba-2's
 xBC) with what follows it elementwise, as a Pallas kernel pair under one
-``jax.custom_vjp``; ``ops/layers.py`` ``short_conv`` is the caller.
+``jax.custom_vjp``; ``ops/layers.py`` ``short_conv`` is the caller. LFM2's
+gated form (two linear gates round the taps, no activation) is a second
+pair on the same geometry, taps and carried rows, at the end of the file;
+``ops/layers.py`` ``gated_short_conv`` is its caller.
 
 Per channel ``c`` with ``n`` taps, zeros before the start::
 
@@ -73,11 +76,12 @@ def _pack(dtype) -> int:
     return _ROWS * max(4 // jnp.dtype(dtype).itemsize, 1)
 
 
-def _geometry(s: int, c: int, dtype, norm_width):
+def _geometry(s: int, c: int, dtype, norm_width, seq_block=None):
     """(rows a block, channels a block, rows a chunk) from the shape. A
     block of channels is the MXU's tile of 128 lanes (whole heads); a
-    block of the sequence the largest under ``_SEQ_BLOCK`` that divides
-    it, taken by chunks of ``_CHUNK`` rows."""
+    block of the sequence the largest under ``seq_block`` (``_SEQ_BLOCK``
+    where none is given) that divides it, taken by chunks of ``_CHUNK``
+    rows."""
     pack = _pack(dtype)
     if s % pack:
         raise ValueError(
@@ -95,8 +99,8 @@ def _geometry(s: int, c: int, dtype, norm_width):
         raise ValueError(
             f"short_conv: norm_width {norm_width} does not divide the "
             f"{cb} lanes of a block")
-    sb = max(d for d in range(pack, min(s, _SEQ_BLOCK) + 1, pack)
-             if s % d == 0)
+    sb = max(d for d in range(pack, min(s, seq_block or _SEQ_BLOCK) + 1,
+                              pack) if s % d == 0)
     rc = max(d for d in range(pack, max(_CHUNK, pack) + 1, pack)
              if sb % d == 0)
     return sb, cb, rc
@@ -371,3 +375,247 @@ def short_conv(x, w, bias=None, *, norm_width: int | None = None,
     if bias is None:
         bias = jnp.zeros(x.shape[2:], w.dtype)
     return _short_conv(x, w, bias, norm_width, float(norm_scale))
+
+
+# ------------------------------------------------------- the gated form
+# LFM2's operator: two linear gates round the taps, no activation, no bias
+#
+#     [B | Cg | X] = bcx          three equal column runs of [B, S, 3 C]
+#     u = B * X;   c_t = sum_i w[i] u[t - (n - 1) + i];   y = Cg * c
+#
+# - **Forward** (``ds_gated_conv_fwd``): the grid and the chunks of the
+#   plain form; ``bcx`` arrives three times, a BlockSpec a column run, so
+#   each run is read where the projection wrote it (no split, no copy).
+#   The 8 rows of ``u`` before a block are carried in VMEM.
+# - **Backward** (``ds_gated_conv_bwd``): residuals ``bcx``, ``w``. Blocks
+#   and chunks last to first; a chunk rebuilds ``u`` and ``c`` and
+#
+#       dCg = dy * c;   dc = dy * Cg;   du_t = sum_i w[i] dc[t + (n-1) - i]
+#       dB = du * X;    dX = du * B;    dw[i] = sum_t dc_t u[t - (n-1) + i]
+#
+#   The cotangent of ``bcx`` is ONE [B, S, 3 C] array in the projection's
+#   own layout: the grid's last axis walks its three column runs. The
+#   first visit of a block does the work, writes ``dB`` and keeps ``dCg``
+#   and ``dX`` in VMEM; the two that follow only store them (the operands'
+#   blocks do not move, so nothing is fetched again).
+_GATED_SEQ_BLOCK = 2048     # rows a grid step: five blocks in flight and
+#                             two kept, 0.5 MiB each in bf16
+_GATED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _gated_geometry(bcx, w):
+    b, s, c3 = bcx.shape
+    n, c = w.shape
+    if c3 != 3 * c:
+        raise ValueError(
+            f"gated_short_conv: {c3} columns are not [B | Cg | X] of "
+            f"{c} channels each")
+    if n - 1 > _ROWS:
+        raise ValueError(
+            f"gated_short_conv: {n} taps reach past the {_ROWS} rows "
+            f"carried from block to block")
+    return _geometry(s, c, bcx.dtype, None, _GATED_SEQ_BLOCK)
+
+
+def _gated_specs(geo, nc, *, block_of):
+    """BlockSpecs of a grid step (channel block, batch, sequence block, and
+    in the backward the column run written): ``run(r)`` the block of
+    column run ``r`` of a [B, S, 3 C] array (``r`` None: the run the
+    grid's last axis names), ``halo(r, pack)`` the ``pack`` rows before
+    it, ``taps(n)`` rows a channel."""
+    sb, cb, _ = geo
+    vm = pltpu.VMEM
+
+    def run(r):
+        return pl.BlockSpec(
+            (1, sb, cb), lambda j, i, l, *at: (
+                i, block_of(l), j + (at[0] if r is None else r) * nc),
+            memory_space=vm)
+
+    def halo(r, pack):
+        return pl.BlockSpec(
+            (1, pack, cb), lambda j, i, l, *at: (
+                i, jnp.maximum(block_of(l) * (sb // pack) - 1, 0),
+                j + r * nc), memory_space=vm)
+
+    def taps(n):
+        return pl.BlockSpec((n, cb), lambda j, i, l, *at: (0, j),
+                            memory_space=vm)
+    return run, halo, taps
+
+
+def _gated_fwd_kernel(b_ref, cg_ref, x_ref, w_ref, y_ref, tail_ref, *, rc):
+    """One block of the sequence by one block of channels. ``tail_ref``
+    [8, cb] carries the last rows of the block's ``u`` to the next."""
+    f32 = jnp.float32
+    w = w_ref[:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        tail_ref[:] = jnp.zeros(tail_ref.shape, f32)
+
+    def chunk(r, before):
+        rows = pl.ds(pl.multiple_of(r * rc, rc), rc)
+        u = b_ref[0, rows, :].astype(f32) * x_ref[0, rows, :].astype(f32)
+        _, c = _taps(before, u, w, 0.0)
+        y_ref[0, rows, :] = (cg_ref[0, rows, :].astype(f32)
+                             * c).astype(y_ref.dtype)
+        return u[rc - _ROWS:]
+
+    tail_ref[:] = jax.lax.fori_loop(0, x_ref.shape[1] // rc, chunk,
+                                    tail_ref[:])
+
+
+def _gated_forward(bcx, w):
+    """bcx [B, S, 3 C]; w [n, C] float32. y [B, S, C] in ``bcx``'s dtype."""
+    b, s, _ = bcx.shape
+    n, c = w.shape
+    geo = sb, cb, rc = _gated_geometry(bcx, w)
+    run, _, taps = _gated_specs(geo, c // cb, block_of=lambda l: l)
+    out_shape = jax.ShapeDtypeStruct((b, s, c), bcx.dtype)
+    call = pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, rc=rc),
+        grid=(c // cb, b, s // sb),
+        in_specs=[run(0), run(1), run(2), taps(n)],
+        out_specs=run(0),
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((_ROWS, cb), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        cost_estimate=pl.CostEstimate(
+            flops=int(b * s * c * (2 * n + 2)), transcendentals=0,
+            bytes_accessed=int(_nbytes(bcx, w, out_shape))),
+        interpret=_interpret(),
+        name="ds_gated_conv_fwd",
+    )
+    return _bind(call, "ds.gconv_mix", ("gated_fwd", geo),
+                 bcx, bcx, bcx, w)[0]
+
+
+def _gated_bwd_kernel(b_ref, cg_ref, x_ref, bh_ref, xh_ref, w_ref, dy_ref,
+                      d_ref, dw_ref, head_ref, keep_ref, *, rc):
+    """One block of the sequence by one block of channels, three visits
+    (the grid's last axis: the column run of ``d_ref``), the sequence
+    blocks arriving last to first. ``bh_ref`` and ``xh_ref`` end with the
+    8 rows of ``B`` and ``X`` before the block; ``head_ref`` [8, cb]
+    carries the first rows of the block's ``dc`` to the block before;
+    ``keep_ref`` [2, sb, cb] holds ``dCg`` and ``dX`` from the first visit
+    to the two that store them; ``dw_ref`` [n, cb] gathers ``dw``."""
+    f32 = jnp.float32
+    sb, cb = x_ref.shape[1:]
+    n = w_ref.shape[0]
+    pack = xh_ref.shape[1]
+    blocks, chunks = pl.num_programs(2), sb // rc
+    last_first, visit = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((visit == 0) & (last_first == 0))
+    def _():
+        head_ref[:] = jnp.zeros(head_ref.shape, f32)
+
+    @pl.when((visit == 0) & (last_first == 0) & (pl.program_id(1) == 0))
+    def _():
+        dw_ref[:] = jnp.zeros(dw_ref.shape, f32)
+
+    @pl.when(visit == 0)
+    def _():
+        w = w_ref[:]
+        start = jnp.where(
+            last_first == blocks - 1, 0.0,
+            bh_ref[0, pack - _ROWS:, :].astype(f32)
+            * xh_ref[0, pack - _ROWS:, :].astype(f32))
+
+        def chunk(k, carry):
+            after, sums = carry
+            r = chunks - 1 - k
+            rows = pl.ds(pl.multiple_of(r * rc, rc), rc)
+            gate_b = b_ref[0, rows, :].astype(f32)
+            x = x_ref[0, rows, :].astype(f32)
+            back = pl.ds(pl.multiple_of(jnp.maximum(r * rc - pack, 0),
+                                        pack), pack)
+            before = jnp.where(
+                r == 0, start,
+                (b_ref[0, back, :].astype(f32)
+                 * x_ref[0, back, :].astype(f32))[pack - _ROWS:])
+            seen, c = _taps(before, gate_b * x, w, 0.0)
+            dy = dy_ref[0, rows, :].astype(f32)
+            dc = dy * cg_ref[0, rows, :].astype(f32)
+            ext = jnp.concatenate([dc, after], axis=0)
+            du = w[n - 1:n] * dc
+            for i in range(n - 1):
+                du = du + w[i:i + 1] * pltpu.roll(
+                    ext, rc + _ROWS - (n - 1 - i), 0)[:rc]
+            d_ref[0, rows, :] = (du * x).astype(d_ref.dtype)
+            keep_ref[0, rows, :] = (dy * c).astype(keep_ref.dtype)
+            keep_ref[1, rows, :] = (du * gate_b).astype(keep_ref.dtype)
+            # a chunk's sums over its rows, kept a sublane tile high
+            fold = lambda v: v.reshape(  # noqa: E731
+                rc // _ROWS, _ROWS, cb).sum(axis=0)
+            return dc[:_ROWS], tuple(acc + fold(dc * v)
+                                     for acc, v in zip(sums, seen))
+
+        zeros = (jnp.zeros((_ROWS, cb), f32),) * n
+        head_ref[:], sums = jax.lax.fori_loop(0, chunks, chunk,
+                                              (head_ref[:], zeros))
+        dw_ref[:] += jnp.concatenate(
+            [jnp.sum(v, axis=0, keepdims=True) for v in sums], axis=0)
+
+    @pl.when(visit > 0)
+    def _():
+        d_ref[0] = keep_ref[visit - 1]
+
+
+def _gated_backward(bcx, w, dy):
+    """d bcx [B, S, 3 C] in ``bcx``'s dtype; dw [n, C] float32."""
+    b, s, _ = bcx.shape
+    n, c = w.shape
+    geo = sb, cb, rc = _gated_geometry(bcx, w)
+    blocks, nc = s // sb, c // cb
+    run, halo, taps = _gated_specs(geo, nc,
+                                   block_of=lambda l: blocks - 1 - l)
+    pack = _pack(bcx.dtype)
+    out_shape = [jax.ShapeDtypeStruct(bcx.shape, bcx.dtype),
+                 jax.ShapeDtypeStruct((n, c), jnp.float32)]
+    call = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, rc=rc),
+        grid=(nc, b, blocks, 3),
+        in_specs=[run(0), run(1), run(2), halo(0, pack), halo(2, pack),
+                  taps(n), run(0)],
+        out_specs=[run(None), taps(n)],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((_ROWS, cb), jnp.float32),
+                        pltpu.VMEM((2, sb, cb), bcx.dtype)],
+        compiler_params=_GATED_PARAMS,
+        cost_estimate=pl.CostEstimate(
+            flops=int(b * s * c * (6 * n + 8)), transcendentals=0,
+            bytes_accessed=int(_nbytes(bcx, w, dy, *out_shape))),
+        interpret=_interpret(),
+        name="ds_gated_conv_bwd",
+    )
+    return _bind(call, "ds.gconv_mix", ("gated_bwd", geo),
+                 bcx, bcx, bcx, bcx, bcx, w, dy)
+
+
+@jax.custom_vjp
+def gated_short_conv(bcx, w):
+    """``Cg * conv(B * X)`` of ``bcx`` = ``[B | Cg | X]`` [B, S, 3 C] with
+    the taps ``w`` [n, C], causal and depthwise, zeros before the start,
+    no bias and no activation (the comment above). Float32 inside, rounded
+    once. Returns [B, S, C] in ``bcx``'s dtype."""
+    return _gated_forward(bcx, w.astype(jnp.float32))
+
+
+def _gated_conv_fwd(bcx, w):
+    return gated_short_conv(bcx, w), (bcx, w)
+
+
+def _gated_conv_bwd(inputs, dy):
+    bcx, w = inputs
+    # _bind opens ds.gconv_mix here too: a custom_vjp's backward function is
+    # traced outside the scope its forward was called under
+    dbcx, dw = _gated_backward(bcx, w.astype(jnp.float32),
+                               dy.astype(bcx.dtype))
+    return dbcx, dw.astype(w.dtype)
+
+
+gated_short_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)

@@ -197,6 +197,24 @@ GDN_SCOPES = (
     "ds.qk_norm",      # models/qwen3_next.py _attention: the (1 + w)
     #                    RMSNorm of q and k a head, before the rotation
 )
+# what a stack of gated short-convolution and grouped-query attention layers
+# opens inside ds.layers (models/lfm2_moe.py) beside ds.attn and ds.mlp of
+# DEVICE_SCOPES (its attention kind and its leading dense layer), ds.qk_norm
+# of GDN_SCOPES, ds.rope of WINDOW_SCOPES, ds.moe_router, ds.moe_experts and
+# the three grouped-matmul kernels' of KIND_SCOPES (no ds.moe_shared: the
+# family has no shared expert); ``tests/test_lfm2_moe_engine.py`` holds the
+# step to them
+LFM_SCOPES = (
+    "ds.gconv",        # models/lfm2_moe.py _one_layer: a conv mixer whole
+    #                    (its norm and the three parts below)
+    "ds.gconv_in",     # models/lfm2_moe.py _conv: the hidden -> 3 x hidden
+    #                    input projection [B | Cg | X]
+    "ds.gconv_mix",    # ops/layers.py gated_short_conv: Cg * conv(B * X),
+    #                    the kernels ds_gated_conv_fwd / ds_gated_conv_bwd
+    #                    of ops/pallas/short_conv.py and nothing else (the
+    #                    backward rule opens the scope itself)
+    "ds.gconv_out",    # models/lfm2_moe.py _conv: the output projection
+)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

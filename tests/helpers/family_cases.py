@@ -1,8 +1,8 @@
-"""What the test files of the Kimi-Linear, Granite, Mellum and Qwen3-Next
-families share (``tests/test_kimi_linear*.py``,
+"""What the test files of the Kimi-Linear, Granite, Mellum, Qwen3-Next and
+LFM2-MoE families share (``tests/test_kimi_linear*.py``,
 ``tests/test_kda*_kernels.py``, ``tests/test_granite_hybrid*.py``,
 ``tests/test_mellum*.py``, ``tests/test_qwen3_next*.py``,
-``tests/test_short_conv_step.py``; since PR 50 a family's float32 reference
+``tests/test_lfm2_moe*.py``, ``tests/test_short_conv_step.py``; since PR 50 a family's float32 reference
 comparison is ``tests/test_<family>_reference.py`` and every family's step
 pin a row of ``tests/test_step_pins.py``, which take the engine's
 ``DS_CONFIG`` from here as the families' engine files do): a file is one
@@ -22,13 +22,14 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum,
-                                  Qwen3Next)
+from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Lfm2Moe,
+                                  Mellum, Qwen3Next)
 
 BENCH = pathlib.Path(__file__).resolve().parents[2] / "benchmark"
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
-from architectures import kimi_linear, mellum, qwen3_next  # noqa: E402
+from architectures import (kimi_linear, lfm2_moe, mellum,  # noqa: E402
+                           qwen3_next)
 from lib import modelspec  # noqa: E402
 
 def _config(name):
@@ -38,6 +39,7 @@ def _config(name):
 GRANITE_CONFIG = _config("granite-4.0-h-micro-zero3-1chip")
 MELLUM_CONFIG = _config("mellum2-12b-ep4-zero3-1chip")
 QNEXT_CONFIG = _config("qwen3-next-80b-ep16-zero3-1chip")
+LFM_CONFIG = _config("lfm2-24b-ep8-zero3-1chip")
 
 
 # the engine every family's tiny model is trained and lowered under: ZeRO-3
@@ -234,3 +236,50 @@ def qnext_right():
     model = qnext_tiny()
     return _reference_says(qwen3_next, QNEXT_CONFIG, model,
                            qnext_weights(model))
+
+
+# ---- LFM2-MoE --------------------------------------------------------------
+def lfm_tiny(**kw):
+    """8 of the tiny preset's 64 experts held, as the cell holds them, in
+    three layers that hold every kind (a dense conv layer, a routed
+    attention layer, a routed conv layer): two layers fewer to compile a
+    case than the preset's own five."""
+    kw.setdefault("moe_held_experts", 8)
+    if "layer_types" not in kw:
+        kw.update(num_layers=3,
+                  layer_types=["conv", "full_attention", "conv"])
+    return Lfm2Moe(size="tiny", **kw)
+
+
+def lfm_weights(model, seed=3):
+    """Seeded weights under which every part this family adds carries
+    weight in the logits at the tiny widths: a larger table under larger
+    projections, outputs and experts (at the init's own scale a layer of
+    hidden 64 adds a hundredth of the embedding), sharper attention scores,
+    an expert bias that moves the selection past the mask's margin, and
+    every norm weight drawn (they start at 1, where ``w`` cannot be told
+    from a missing weight)."""
+    boost = {"tokens": 5.0, "w_in": 6.0, "w_out": 8.0, "wv": 4.0, "wo": 8.0,
+             "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0, "router_bias": 10.0}
+    norms = {"ln1_scale", "ln2_scale", "scale"}
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def one(path, w):
+        name = path[-1].key
+        if name in norms:
+            return w + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype)
+        if name in ("q_norm", "k_norm"):    # scores of deviation 9
+            return 3.0 * w + 0.5 * jax.random.normal(next(keys), w.shape,
+                                                     w.dtype)
+        return w * boost.get(name, 1.0)
+
+    return jax.tree_util.tree_map_with_path(
+        one, model.init(jax.random.PRNGKey(seed)))
+
+
+@functools.lru_cache(maxsize=None)
+def lfm_right(held: int = 8):
+    """``_reference_says`` of the right model's boosted weights with
+    ``held`` of the 64 experts held."""
+    model = lfm_tiny(moe_held_experts=held)
+    return _reference_says(lfm2_moe, LFM_CONFIG, model, lfm_weights(model))

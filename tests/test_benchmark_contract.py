@@ -177,7 +177,8 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
                                | set(scopes.MIXER_SCOPES)
                                | set(scopes.WINDOW_SCOPES)
                                | set(scopes.LOOP_SCOPES)
-                               | set(scopes.GDN_SCOPES))
+                               | set(scopes.GDN_SCOPES)
+                               | set(scopes.LFM_SCOPES))
 
     telemetry.shutdown()
     try:
@@ -351,8 +352,10 @@ def test_the_gap_metrics_are_the_six_parts():
 
 @pytest.mark.parametrize("name", sorted(GAP_METRICS))
 def test_a_gap_metric_is_a_metric_file_in_all_but_place(name):
-    """The keys of a metric file, the train entry's layer, all seven cells
-    of the contract, the reducer of ``reducers/hostgap.py`` with the
+    """The keys of a metric file, the train entry's layer, the cells of
+    ``clock_bracket_us.train`` (the seven the contract had when the parts
+    came: a cell a later PR adds is none of the file's until a ``benchmark``
+    PR appends it, PR 54), the reducer of ``reducers/hostgap.py`` with the
     module and the launch event ``clock_bracket_us.train`` reads; a name
     the contract does not have yet."""
     _bench_on_path()
@@ -367,11 +370,11 @@ def test_a_gap_metric_is_a_metric_file_in_all_but_place(name):
             spec["moves"]) == ("train entry", "ms", "lower", "device_trace",
                                "train_tokens_per_s")
     assert spec["layer"] in {m["layer"] for m in CONTRACT["per_layer"]}
-    assert sorted(spec["cells"]) == sorted(
-        w["name"] for w in CONTRACT["workloads"])
+    bracket = _load(BENCH / "layer_metrics" / "clock_bracket_us.train.json")
+    assert sorted(spec["cells"]) == sorted(bracket["cells"])
+    assert set(spec["cells"]) <= {w["name"] for w in CONTRACT["workloads"]}
     assert spec["reducer"]["name"] == "gap_part_ms"
     assert reducers.find("gap_part_ms") is hostgap.gap_part_ms
-    bracket = _load(BENCH / "layer_metrics" / "clock_bracket_us.train.json")
     args = dict(spec["reducer"]["args"])
     assert name == f"gap_{args.pop('part')}_ms.train"
     assert args == bracket["reducer"]["args"] == GAP_ARGS

@@ -326,6 +326,13 @@ PARENT_COUNTS = {
                            176341148160),
     "cell/qwen3-next-80b-ep16-zero3-1chip": (
         625667136, 230878272, 1582885248.0, 1985513856),
+    # PR 54's own, pinned when the family came (ISSUE 54's 469,284,992 and
+    # the four routed layers' expert biases of 64)
+    "lfm2_moe/tiny": (1726176, 251616, 1566912.0, 1615680),
+    "lfm2_moe/24b-a2b": (23843661440, 2326881920, 29691897600.0,
+                         45420414720),
+    "cell/lfm2-24b-ep8-zero3-1chip": (
+        469285248, 186169728, 1217939712.0, 1318590720),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -392,6 +399,8 @@ def test_counts_are_the_parents(case):
     ("granite_hybrid", dict(kda_head_groups=4)),
     ("mellum", dict(kda_head_groups=4)),
     ("qwen3_next", dict(kda_head_groups=4)),
+    ("lfm2_moe", dict(kda_head_groups=4)),
+    ("granite_hybrid", dict(conv_L_cache=3)),
     ("kimi_linear", dict(qk_norm_init=2.0)),
     ("ouro", dict(layer_types=["attention", "attention"])),
     ("mistral", dict(total_ut_steps=4)),

@@ -16,8 +16,8 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Mellum, Mistral,
-                                  Ouro, Qwen3Next)
+from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Lfm2Moe, Mellum,
+                                  Mistral, Ouro, Qwen3Next)
 from deepspeed_tpu.models.transformer import _chunked_cross_entropy
 from deepspeed_tpu.ops.pallas import _common
 
@@ -55,7 +55,12 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # sum those heads' ``dq`` and ``dk`` before their one store,
 # ``ops/pallas/kda.py``; the seeded weights are the parent's); the seven
 # other rows stand: ``kimi_linear`` calls the op with as many key heads as
-# value heads, where every slice, block and store is the parent's.
+# value heads, where every slice, block and store is the parent's. PR 54
+# added ``lfm2_moe`` (a routed attention layer and a routed conv layer, 8 of
+# 64 experts held; taken on its own tree, the first that has the family) and
+# moved ``kimi_linear``'s ``after_step`` body into
+# ``RoutedStackOfKinds._balanced`` word for word: the eight rows before it
+# stand.
 _PINS = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
@@ -94,6 +99,12 @@ _PINS = {
         qk_norm_init=2.0, attn_impl="flash", loss_chunk=64),
         "6bee3752161b25651463093ce15855be1e5030ae8a99ae594ca430fc6dae2678",
         39458.17879846059),
+    "lfm2_moe": (Lfm2Moe, dict(
+        num_layers=2, layer_types=["full_attention", "conv"],
+        num_dense_layers=0, moe_held_experts=8, attn_impl="flash",
+        loss_chunk=64),
+        "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",
+        3449.799246064109),
 }
 
 

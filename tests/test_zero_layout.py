@@ -535,3 +535,89 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
     assert "all-to-all" not in hlo and "all-gather" not in hlo
     with pytest.raises(Exception, match="[Mm]osaic"):
         grad(L.short_conv, norm_width=128).lower(*args).compile()
+
+
+def test_gated_conv_kernels_and_the_widest_held_backward_compile_for_v5e(
+        monkeypatch):
+    """PR 54's two shapes that interpret mode cannot refuse, compiled by
+    Mosaic for one described v5e chip. The gated short convolution's
+    kernel pair at the LFM2 cell's widths ([2, 8192, 3 x 2048] bf16, three
+    taps): three BlockSpecs read the column runs of one array, the
+    backward's last grid axis writes the three runs of ONE cotangent, and
+    the rematted gradient holds the forward once (the first forward is
+    dead under this loss) and no copy of either operand. Then the held
+    experts' backward at hidden 2048 by an expert of 1536, the widest a
+    cell has: an expert's weights and float32 ``dW`` blocks at two buffers
+    each asked 127.3 MiB of the 126 a kernel may have, so past
+    ``_RESIDENT_MAX`` the weights take one buffer
+    (``grouped_matmul.backward``); at Kimi's 2304 by 1024 they keep two.
+    Then ``sharded_gated_short_conv`` on ``v5e:2x2``, where the bare call
+    cannot be partitioned."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops import layers as L
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, d=bf: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+
+    def grad(fn):
+        layer = jax.checkpoint(fn)
+        return jax.jit(jax.grad(
+            lambda a, w: 0.5 * jnp.sum(layer(a, w).astype(f32) ** 2),
+            argnums=(0, 1)))
+
+    compiled = grad(L.gated_short_conv).lower(
+        sd((2, 8192, 6144)), sd((3, 2048))).compile()
+    hlo = compiled.as_text()
+    calls = lambda k: len(re.findall(  # noqa: E731
+        rf"%{k}[.\w]* = .*custom-call", hlo))
+    assert (calls("ds_gated_conv_fwd"), calls("ds_gated_conv_bwd")) == (1, 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    with pytest.raises(ValueError, match="multiple of 128, not 2080"):
+        jax.jit(L.gated_short_conv).lower(sd((2, 8192, 6240)),
+                                          sd((3, 2080)))
+
+    # the held experts of a routed layer of the cell, backward included
+    n, d, f, k, experts, held = 16384, 2048, 1536, 4, 64, 8
+    block = sharded_moe.held_block(n, k, experts)
+    chunk = sharded_moe.held_chunk(n, k, experts, held, block)
+    assert (block, grouped_matmul.row_tile(block), chunk) == (640, 128, 9216)
+    layer = jax.checkpoint(lambda x, idx, w, ex: (
+        sharded_moe.held_experts_ffn(x, idx, w, ex, 0, block, False,
+                                     chunk)[0]))
+    hlo = jax.jit(jax.grad(
+        lambda x, w, ex, idx: 0.5 * jnp.sum(
+            layer(x, idx, w, ex).astype(f32) ** 2), argnums=(0, 2))).lower(
+        sd((n, d)), sd((n, k), f32),
+        {"w_gate": sd((held, d, f)), "w_up": sd((held, d, f)),
+         "w_down": sd((held, f, d))},
+        sd((n, k), jnp.int32)).compile().as_text()
+    for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd", "ds_moe_add_rows"):
+        assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), kernel
+    wide = 2 * 3 * d * f * (2 + 4)
+    assert wide > grouped_matmul._RESIDENT_MAX > 2 * 3 * 2304 * 1024 * 6
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    act = mt.sharding(mt.batch_axes(), "sp")
+    args = (jax.ShapeDtypeStruct(
+        (4, 2048, 768), bf,
+        sharding=NamedSharding(mt.mesh, P(mt.batch_axes(), None, None))),
+            jax.ShapeDtypeStruct((3, 256), bf,
+                                 sharding=NamedSharding(mt.mesh, P())))
+    hlo = grad(L.sharded_gated_short_conv(act)).lower(
+        *args).compile().as_text()
+    for kernel in ("ds_gated_conv_fwd", "ds_gated_conv_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo and "all-gather" not in hlo
+    with pytest.raises(Exception, match="[Mm]osaic"):
+        grad(L.gated_short_conv).lower(*args).compile()
