@@ -18,6 +18,10 @@ sigmoid-routed experts of width 1024 (top 8, one shared expert) after it.
 
 ``conv4`` is a causal depthwise convolution of width 4 along the sequence;
 with the SiLU and the l2 norm it is one pass of ``ops.layers.short_conv``.
+The gated norm of ``o`` (the head's RMSNorm rounded to bf16, the gate's
+sigmoid with its bias, the product) is one pass of
+``ops.layers.gated_norm``, which reads ``o`` a head where the scan's
+kernel wrote it.
 **MLA** (``mla_use_nope``: neither part of q or k is rotated)::
 
     q = x Wq  as H x (nope + rope);   [c, k_pe] = x Wkva  (kv_lora + rope)
@@ -44,6 +48,7 @@ read from the keys it holds.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +59,24 @@ from ..ops import layers as L
 from .base import mean_context, register_model
 from .stack import RoutedStackConfig, RoutedStackOfKinds
 from .transformer import _dense_init
+
+
+@jax.custom_vjp
+def _together(xs):
+    """``xs`` as they are; differentiated, none of their cotangents moves
+    on before all of them are there (an optimization barrier, which costs
+    nothing on the device). XLA's scheduler puts a weight-gradient matmul
+    off for as long as memory allows, and its [S, H d] operand stays alive
+    meanwhile: with the gated norm a kernel pair, ``_kda``'s ``y`` and
+    ``dgate`` of a layer lived through the scan's backward, and the tail
+    layer's through the period's whole loop (the Kimi cell's step compiled
+    to 13.05 GiB for the parent's 12.88; with the two ties of ``_kda``
+    12.49: AOT for a v5e, PR 55)."""
+    return xs
+
+
+_together.defvjp(lambda xs: (xs, None),
+                 lambda _, ds: (jax.lax.optimization_barrier(ds),))
 
 
 @dataclasses.dataclass
@@ -325,14 +348,17 @@ class KimiLinear(RoutedStackOfKinds):
         }
 
     # ---------------- the mixers ----------------
-    def _kda(self, p, h, kda_fn, conv_fn):
+    def _kda(self, p, h, kda_fn, conv_fn, norm_fn):
         """One KDA mixer on the normed ``h``. The projections carry ds.kda
         alone (what kind "matmul" finds). q, k and v are each ONE pass of
         ``conv_fn`` (``ops.layers.short_conv``, scope ds.conv) over their
         projection: the convolution, SiLU and, for q and k, the head's l2
         norm, written in the [B, S, H d] layout the scan's kernels read.
         What else lies before the scan (beta, the decay and ``g``) is
-        ds.mix_pre, what lies after it ds.mix_post."""
+        ds.mix_pre; what lies after it, the gated norm, is ONE pass of
+        ``norm_fn`` (``ops.layers.gated_norm``, which opens ds.mix_post)
+        over the scan's ``o`` as ``kda_fn`` hands it over and the gate's
+        pre-activation as its projection wrote it."""
         c = self.config
         b, s, _ = h.shape
         nh, dk = c.kda_num_heads, c.kda_head_dim
@@ -352,13 +378,17 @@ class KimiLinear(RoutedStackOfKinds):
             g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
                 * heads(jax.nn.softplus(decay))
         o = kda_fn(q, k, v, g, beta, head_groups=c.kda_head_groups)
-        gate = (h @ p["w_g1"]) @ p["w_g2"]
-        with jax.named_scope("ds.mix_post"):
-            gate = jax.nn.sigmoid(gate.astype(f32) + p["b_g"].astype(f32))
-            o = L.rms_norm(o, p["o_norm"], c.norm_eps).reshape(
-                b, s, nh * dk)
-            o = (o.astype(f32) * gate).astype(h.dtype)
-        return o @ p["wo"]
+        # dgate is used up (by w_g2's gradient and the low-rank map's
+        # cotangent) before do moves on into the scan's backward
+        o, low, w_g2 = _together((o, h @ p["w_g1"], p["w_g2"]))
+        gate = low @ w_g2
+        # the published arithmetic: the norm returns o's dtype before the
+        # product (``L.rms_norm``), the gate is a sigmoid with a bias
+        o = norm_fn(o, gate, p["o_norm"], p["b_g"], act="sigmoid",
+                    eps=c.norm_eps, round_norm=True)
+        # ... and y (by wo's gradient) before dy moves on into the norm's
+        y, wo = _together((o, p["wo"]))
+        return y @ wo
 
     def _mla(self, p, h, attn_fn):
         c = self.config
@@ -387,11 +417,11 @@ class KimiLinear(RoutedStackOfKinds):
             scaling=c.routed_scaling_factor)
 
     # ---------------- one layer, the stack ----------------
-    def _mix(self, p, x, attn_fn, kda_fn, conv_fn):
+    def _mix(self, p, x, attn_fn, kda_fn, conv_fn, norm_fn):
         with jax.named_scope("ds.kda" if "kda" in p else "ds.mla"):
             h = L.rms_norm(x, p["ln1_scale"], self.config.norm_eps)
             if "kda" in p:
-                return x + self._kda(p["kda"], h, kda_fn, conv_fn)
+                return x + self._kda(p["kda"], h, kda_fn, conv_fn, norm_fn)
             return x + self._mla(p["mla"], h, attn_fn)
 
     def _channel(self, p, x):
@@ -409,12 +439,22 @@ class KimiLinear(RoutedStackOfKinds):
     def _mixers(self, attn_fn, act_sharding):
         """(attention, KDA, short convolution): on a mesh of more than one
         device the KDA and the convolution's kernels run per shard of
-        ``act_sharding``."""
+        ``act_sharding``. On one device the scan hands ``o`` over as its
+        kernel wrote it, the heads' stack (``chunk_kda(by_head=True)``):
+        the gated norm reads either form."""
         from ..ops.kda import chunk_kda, sharded_chunk_kda
         if act_sharding is None:
-            return attn_fn, chunk_kda, L.short_conv
+            return (attn_fn, functools.partial(chunk_kda, by_head=True),
+                    L.short_conv)
         return (attn_fn, sharded_chunk_kda(act_sharding),
                 L.sharded_short_conv(act_sharding))
+
+    def _layer_fns(self, attn_fn, act_sharding):
+        """``_mixers`` and the gated norm behind the scan, per shard where
+        they are."""
+        return (*self._mixers(attn_fn, act_sharding),
+                L.gated_norm if act_sharding is None
+                else L.sharded_gated_norm(act_sharding))
 
     # ---------------- sharding ----------------
     def partition_rules(self):

@@ -61,6 +61,7 @@ tail have no cache in ``inference/``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -295,12 +296,14 @@ class Qwen3Next(RoutedStackOfKinds):
         }
 
     # ---------------- the mixers ----------------
-    def _gdn(self, p, h, kda_fn, conv_fn):
+    def _gdn(self, p, h, kda_fn, conv_fn, norm_fn):
         """One Gated DeltaNet mixer on the normed ``h``. The projections
         carry ds.gdn alone (what kind "matmul" finds); q, k and v are each
         ONE pass of ``conv_fn`` (scope ds.conv) over their columns of the
-        projection; beta and the gate are ds.mix_pre, the gated norm
-        ds.mix_post."""
+        projection; beta and the gate are ds.mix_pre, the gated norm ONE
+        pass of ``norm_fn`` (``ops.layers.gated_norm``, which opens
+        ds.mix_post) over the scan's ``o`` as ``kda_fn`` hands it over and
+        ``z`` as the projection wrote it."""
         c = self.config
         b, s, _ = h.shape
         hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
@@ -325,10 +328,8 @@ class Qwen3Next(RoutedStackOfKinds):
         # hv / hk value heads it serves. One head group: the step fits
         # whole (12.77 GiB, AOT for a v5e)
         o = kda_fn(q, k, v, g, beta)
-        with jax.named_scope("ds.mix_post"):
-            o = L.rms_norm(o.astype(f32), p["o_norm"].astype(f32),
-                           c.norm_eps).reshape(b, s, vw)
-            o = (o * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+        # the published arithmetic: float32 from o to the last cast, SiLU
+        o = norm_fn(o, z, p["o_norm"], act="silu", eps=c.norm_eps)
         return o @ p["wo"]
 
     def _attention(self, p, h, attn):
@@ -358,11 +359,11 @@ class Qwen3Next(RoutedStackOfKinds):
     def _one_layer(self, p, x, mixers):
         from ..moe import sharded_moe
         c = self.config
-        attn_fn, kda_fn, conv_fn = mixers
+        attn_fn, kda_fn, conv_fn, norm_fn = mixers
         if "gdn" in p:
             with jax.named_scope("ds.gdn"):
                 h = self._norm(x, p["ln1_scale"])
-                x = x + self._gdn(p["gdn"], h, kda_fn, conv_fn)
+                x = x + self._gdn(p["gdn"], h, kda_fn, conv_fn, norm_fn)
         else:
             with jax.named_scope("ds.attn_gated"):
                 h = self._norm(x, p["ln1_scale"])
@@ -386,12 +387,22 @@ class Qwen3Next(RoutedStackOfKinds):
     def _mixers(self, attn_fn, act_sharding):
         """(attention, the delta rule's scan, short convolution): on a mesh
         of more than one device the scan's and the convolution's kernels
-        run per shard of ``act_sharding``."""
+        run per shard of ``act_sharding``. On one device the scan hands
+        ``o`` over as its kernel wrote it, the heads' stack
+        (``chunk_kda(by_head=True)``): the gated norm reads either form."""
         from ..ops.kda import chunk_kda, sharded_chunk_kda
         if act_sharding is None:
-            return attn_fn, chunk_kda, L.short_conv
+            return (attn_fn, functools.partial(chunk_kda, by_head=True),
+                    L.short_conv)
         return (attn_fn, sharded_chunk_kda(act_sharding),
                 L.sharded_short_conv(act_sharding))
+
+    def _layer_fns(self, attn_fn, act_sharding):
+        """``_mixers`` and the gated norm behind the scan, per shard where
+        they are."""
+        return (*self._mixers(attn_fn, act_sharding),
+                L.gated_norm if act_sharding is None
+                else L.sharded_gated_norm(act_sharding))
 
     # ---------------- sharding ----------------
     def partition_rules(self):

@@ -18,7 +18,9 @@ Every layer is rematted whole. The kinds (one hashable a layer) and the
 leading layers are the config's; a family gives three methods:
 ``_init_layer(key, kind, lead_shape)``, ``_mixers(attn_fn, act_sharding)``
 (what its layers call to mix tokens, with the kernels imported there and
-not at import) and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``.
+not at import) and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``. A
+family whose layers call a kernel beside their mixers' (the delta-rule
+families' gated norm) hands it over behind them: ``_layer_fns``.
 """
 
 from __future__ import annotations
@@ -165,6 +167,12 @@ class StackOfKinds(DecoderLM):
             return flash_attention
         return L.dot_product_attention
 
+    def _layer_fns(self, attn_fn, act_sharding):
+        """What ``_one_layer`` is handed as ``mixers``: the family's
+        ``_mixers``, and behind them whatever else its layers must run per
+        shard on a mesh of more than one device."""
+        return self._mixers(attn_fn, act_sharding)
+
     def _layer(self, p, x, mixers, scanned: bool):
         """One layer of the kind its keys name, as (x, counts); rematted
         whole, but for the residuals a kernel declares kept
@@ -185,7 +193,7 @@ class StackOfKinds(DecoderLM):
         that counts anything (a routed one), a ``period`` slot's stacked
         over the repeats as its parameters are. On a mesh of more than one
         device (``act_sharding``) a family's kernels run per shard."""
-        mixers = self._mixers(self._attn(attn_fn), act_sharding)
+        mixers = self._layer_fns(self._attn(attn_fn), act_sharding)
         stats = {"lead": {}, "period": {}, "tail": {}}
 
         def unrolled(group, n, x):

@@ -107,11 +107,19 @@ def recurrent_kda(q, k, v, g, beta):
     return o.swapaxes(0, 1)
 
 
-def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
+def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1,
+              by_head: bool = False):
     """The chunked form; arguments as ``recurrent_kda``. The matmuls run in
     ``q``'s dtype with float32 accumulation, the decays, the score
     matrices' inverse and the carried state in float32. Returns o
     [B, S, H, dv] in ``v``'s dtype. ``S`` must be a multiple of ``chunk``.
+
+    ``by_head``: o as the recurrence's kernel writes it and the head
+    groups' map stacks it, [G, B, H / G, S, dv] (G = ``head_groups``), for
+    a consumer that reads a head where it lies (``ops/layers.py``
+    ``gated_norm``): the relayout to [B, S, H, dv], 2 B S H dv bytes each
+    way behind the kernel, in a rematted layer's rerun and in front of the
+    scan's backward, is then never made.
 
     q and k come at their own head count ``Hk`` (``q.shape[2]`` against
     ``v.shape[2]``: Qwen3-Next's 16 key heads serve 32 value heads, Kimi's
@@ -155,11 +163,14 @@ def chunk_kda(q, k, v, g, beta, *, chunk: int = CHUNK, head_groups: int = 1):
         return jnp.moveaxis(x, 2, 0)
 
     one = jax.checkpoint(
-        lambda xs: _chunk_kda(*xs, chunk=chunk), prevent_cse=False)
+        lambda xs: _chunk_kda(*xs, chunk=chunk, by_head=by_head),
+        prevent_cse=False)
     with jax.named_scope("ds.kda_scan"):
         o = jax.lax.map(one, tuple(split(x) for x in (q, k, v, g, beta)))
     if head_groups > 1:
         o = _kept(o)
+    if by_head:
+        return o                                    # [G, B, H/G, S, dv]
     o = jnp.moveaxis(o, 0, 2)                       # [B, S, G, H/G, dv]
     return o.reshape(*o.shape[:2], h, o.shape[-1])
 
@@ -169,7 +180,8 @@ def _kept(o):
     """``o`` as it is; differentiated, ``o`` declared kept (``_keep``)
     where a rematted layer's policy sees the name: outside the head
     groups' ``lax.map`` and their checkpoints. It is the map's own result
-    [G, B, S, H/G, dv] that is named, not its relayout to [B, S, H, dv],
+    [G, B, S, H/G, dv] ([G, B, H/G, S, dv] ``by_head``) that is named, not
+    its relayout to [B, S, H, dv],
     which a layer's rerun makes again: named after the relayout, the Kimi
     cell's compiled step kept two float32 [S, H dv] tensors of
     ``ds.mix_post``'s backward alive through the scan's (13.56 GiB for
@@ -189,9 +201,11 @@ def sharded_chunk_kda(act_sharding):
     return per_batch_shard(chunk_kda, act_sharding, (True,) * 5)
 
 
-def _chunk_kda(q, k, v, g, beta, *, chunk):
+def _chunk_kda(q, k, v, g, beta, *, chunk, by_head=False):
     b, s, h, _ = v.shape
     with jax.named_scope("ds.kda_scan"):
         o = kda_recurrence(*kda_prepare(q, k, v, g, beta, chunk=chunk),
                            out_dtype=v.dtype)           # [B, H, N, C, dv]
+    if by_head:
+        return o.reshape(b, h, s, v.shape[-1])
     return jnp.moveaxis(o, 1, 3).reshape(b, s, h, v.shape[-1])

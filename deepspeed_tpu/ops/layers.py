@@ -6,7 +6,8 @@ TPU, XLA fuses these elementwise/norm ops into surrounding matmuls; Pallas
 variants (deepspeed_tpu/ops/pallas/) replace the ones XLA can't fuse well
 (flash attention, quantized collectives, fused optimizers); ``short_conv``
 (the recurrent mixers' convolution, SiLU and l2 norm in one pass) is the
-thin caller of such a pair, as ``ops/ssd.py`` is of its own.
+thin caller of such a pair, as ``ops/ssd.py`` is of its own, and so are
+``gated_short_conv`` and ``gated_norm``.
 
 Everything here is shape-static and jit-safe.
 """
@@ -111,6 +112,49 @@ def sharded_gated_short_conv(act_sharding):
     taps' gradient is summed over the shards)."""
     from ..parallel.mesh import per_batch_shard
     return per_batch_shard(gated_short_conv, act_sharding, (True, False))
+
+
+def gated_norm(o, gate, w, bias=None, *, act: str, eps: float = 1e-6,
+               round_norm: bool = False):
+    """The gated per-head RMSNorm between a delta-rule scan and the output
+    projection, one pass: o [B, S, H, d] or, as ``ops.kda.chunk_kda(
+    by_head=True)`` leaves it, the heads' stack [G, B, H / G, S, d]; the
+    gate's pre-activation [B, S, H d]; the norm's weight w [d]; bias
+    [H d] or None;
+
+        n = o * rsqrt(mean_d(o^2) + eps) * w         a head of d channels
+        y = n * act(gate (+ bias))                   act: sigmoid | silu
+
+    The callers say their family's published arithmetic: ``act``, a
+    ``bias`` or none, and ``round_norm`` (``n`` passes through ``o``'s
+    dtype before the product: Kimi's norm returns its input's dtype;
+    Qwen3-Next's stays float32). Float32 inside, rounded once to the
+    gate's dtype; returns [B, S, H d]. A Pallas kernel pair under one
+    ``custom_vjp`` (``ops/pallas/gated_norm.py``; scope ``ds.mix_post``,
+    opened by the op in both directions) whose residuals are its inputs;
+    ``tests/helpers/gated_norm_reference.py`` keeps the two ``jax.numpy``
+    forms the mixers ran until PR 55."""
+    from .pallas.gated_norm import gated_norm as kernels
+    return kernels(o, gate, w, bias, act=act, eps=eps, round_norm=round_norm)
+
+
+def sharded_gated_norm(act_sharding):
+    """``gated_norm`` for a multi-device mesh: per shard of the batch under
+    a shard_map, as ``sharded_short_conv`` and for its reasons (heads and
+    rows are independent; the weight's and the bias's gradients are summed
+    over the shards). ``o`` leads with the batch there: [B, S, H, d]."""
+    from ..parallel.mesh import per_batch_shard
+
+    def gate_first(gate, o, *rest, **kw):   # the result is laid out as
+        return gated_norm(o, gate, *rest, **kw)     # the first argument
+
+    def norm(o, gate, w, bias=None, **kw):
+        rest = (w,) if bias is None else (w, bias)
+        return per_batch_shard(gate_first, act_sharding,
+                               (True, True) + (False,) * len(rest))(
+            gate, o, *rest, **kw)
+
+    return norm
 
 
 def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,

@@ -146,9 +146,14 @@ MIXER_SCOPES = (
     #                    beta, the decay's softplus and g;
     #                    models/granite_hybrid.py _mamba: dt's softplus,
     #                    the split into x, B, C
-    "ds.mix_post",     # between the scan and the output projection. _kda:
-    #                    the gate's sigmoid, o_norm, the product; _mamba:
-    #                    D x, the silu(z) gate, the gated norm
+    "ds.mix_post",     # between the scan and the output projection. _kda
+    #                    and models/qwen3_next.py _gdn: the gated per-head
+    #                    norm as ops/layers.py gated_norm, the kernels
+    #                    ds_gated_norm_fwd / ds_gated_norm_bwd of
+    #                    ops/pallas/gated_norm.py and nothing else (the op
+    #                    opens the scope, in its backward rule too);
+    #                    _mamba, still XLA's: D x, the silu(z) gate, the
+    #                    gated norm over the whole row
 )
 # what a stack of window and full attention layers, each routed, opens
 # inside ds.layers in place of ds.attn (models/mellum.py), beside

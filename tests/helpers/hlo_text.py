@@ -44,23 +44,40 @@ def bare_step(engine, batch, ds_config, monkeypatch, hlo) -> tuple[str, str]:
     return program_only(hlo), program_only(step_hlo(bare, batch))
 
 
-def assert_conv_scope_is_the_kernels(hlo: str, mixer: str, others) -> None:
-    """ISSUE 43: in a compiled step, scope ``ds.conv`` holds the short
-    convolution's two kernels and nothing else (interpreted on a CPU, so
-    every instruction of a kernel's body carries its name): the forward
-    under fwd: and, run again by remat, under bwd:, the backward under
-    bwd:; inside ``mixer`` (``ds.kda`` | ``ds.mamba``) but for the few per
-    cent of a kernel's constants that its one trace a shape names by
-    ``ds.conv`` alone, and never inside one of the ``others``."""
-    conv = [m.group(1) for m in re.finditer(
-        r'op_name="([^"]*ds\.conv[^"]*)"', hlo)]
+def assert_scope_is_the_kernels(hlo: str, scope: str, stem: str, mixer: str,
+                                others) -> None:
+    """In a compiled step, ``scope`` holds the two kernels ``<stem>fwd`` /
+    ``<stem>bwd`` and nothing else (interpreted on a CPU, so every
+    instruction of a kernel's body carries its name): the forward under
+    fwd: and, run again by remat, under bwd:, the backward under bwd:;
+    inside ``mixer`` but for the few per cent of a kernel's constants that
+    its one trace a shape names by ``scope`` alone, and never inside one
+    of the ``others``."""
+    held = [m.group(1) for m in re.finditer(
+        rf'op_name="([^"]*{re.escape(scope)}\b[^"]*)"', hlo)]
     sides = {(k, "bwd" if "transpose(" in name else "fwd")
-             for name in conv
-             for k in re.findall(r"ds_short_conv_(?:fwd|bwd)", name)[:1]
+             for name in held
+             for k in re.findall(rf"{stem}(?:fwd|bwd)", name)[:1]
              if mixer in name}
-    assert all("ds_short_conv_" in name for name in conv)
-    assert sides == {("ds_short_conv_fwd", "fwd"),
-                     ("ds_short_conv_fwd", "bwd"),
-                     ("ds_short_conv_bwd", "bwd")}
-    assert sum(f"/{mixer}/" in name for name in conv) > 0.9 * len(conv)
-    assert not any(o in name for name in conv for o in others)
+    assert all(stem in name for name in held), [
+        name for name in held if stem not in name][:3]
+    assert sides == {(f"{stem}fwd", "fwd"), (f"{stem}fwd", "bwd"),
+                     (f"{stem}bwd", "bwd")}
+    assert sum(f"/{mixer}/" in name for name in held) > 0.9 * len(held)
+    assert not any(o in name for name in held for o in others)
+
+
+def assert_conv_scope_is_the_kernels(hlo: str, mixer: str, others) -> None:
+    """ISSUE 43: scope ``ds.conv`` holds the short convolution's two
+    kernels and nothing else, inside ``mixer`` (``ds.kda`` | ``ds.mamba``
+    | ``ds.gdn``)."""
+    assert_scope_is_the_kernels(hlo, "ds.conv", "ds_short_conv_", mixer,
+                                others)
+
+
+def assert_gated_norm_scope_is_the_kernels(hlo: str, mixer: str,
+                                           others) -> None:
+    """ISSUE 55: scope ``ds.mix_post`` inside ``mixer`` (``ds.kda`` |
+    ``ds.gdn``) holds the gated norm's two kernels and no other leaf op."""
+    assert_scope_is_the_kernels(hlo, "ds.mix_post", "ds_gated_norm_", mixer,
+                                others)

@@ -71,10 +71,14 @@ def test_a_rematted_step_runs_the_scan_twice_where_groups_are_a_loop(
     nothing: three times under either. Every other kernel but
     ``ds_flash_fwd`` runs as often as under ``policy=None``: the backward
     still needs q, k, v, g and beta, so the convolutions rerun as
-    before."""
+    before, and the gated norm behind the scan (ISSUE 55) runs its forward
+    twice (the forward; remat's rerun for the output matmul) and its
+    backward once a layer under either."""
     kept = _train_step_calls(family)
     rerun = _train_step_calls(family, nothing_kept=True)
     assert kept["ds_kda_prep_bwd"] == kept["ds_kda_bwd"] > 0
+    assert (kept["ds_gated_norm_fwd"], kept["ds_gated_norm_bwd"]) == (
+        2 * kept["ds_kda_bwd"], kept["ds_kda_bwd"])
     for fwd, bwd in (("ds_kda_prep_fwd", "ds_kda_prep_bwd"),
                      ("ds_kda_fwd", "ds_kda_bwd")):
         assert kept[fwd] == runs * kept[bwd]

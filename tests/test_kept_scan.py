@@ -69,6 +69,52 @@ def test_a_rematted_scan_keeps_its_output(gate, form):
     assert _scan_calls(grad(None), *args) == (3, 1, 3, 1)
 
 
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_heads_stack_is_the_scans_output_where_the_kernel_wrote_it(
+        groups):
+    """``chunk_kda(by_head=True)`` (ISSUE 55): ``o`` as [G, B, H / G, S,
+    dv], the bits of the [B, S, H, dv] form moved, for a consumer that
+    reads a head where it lies (``ops.layers.gated_norm``); the five
+    gradients are the same bits, and under a layer's policy the kernels
+    run as often (the name is the stack's own: nothing is moved for it)."""
+    b, s, h, d = 2, 128, 4, 32
+    rng = np.random.default_rng(1)
+    unit = lambda x: x / np.sqrt((x ** 2).sum(-1, keepdims=True))  # noqa: E731
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = (b, s, h, d)
+    args = (jnp.asarray(unit(rng.normal(size=shape)) * d ** -0.5, bf),
+            jnp.asarray(unit(rng.normal(size=shape)), bf),
+            jnp.asarray(rng.normal(size=shape), bf),
+            jnp.asarray(-np.exp(rng.uniform(-7, 0.5, shape)), f32),
+            jnp.asarray(1 / (1 + np.exp(-rng.normal(size=shape[:3]))), f32))
+    weight = jnp.asarray(rng.normal(size=shape), f32)
+    stack = lambda x: x.reshape(  # noqa: E731
+        b, s, groups, h // groups, d).transpose(2, 0, 3, 1, 4)
+
+    def run(by_head):
+        def layer(*a):
+            o = kda.chunk_kda(*a, head_groups=groups, by_head=by_head)
+            w = stack(weight) if by_head else weight
+            return jnp.sum(jnp.tanh(o.astype(f32)) * w), o
+        fn = jax.checkpoint(
+            layer, policy=transformer._remat_policy("nothing_saveable"))
+        grad = jax.grad(fn, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        return jax.device_get(jax.jit(grad)(*args)), _scan_calls(
+            lambda *a: grad(*a)[0], *args)
+
+    (want, o), calls = run(False)
+    (got, o_stack), calls_stack = run(True)
+    assert o_stack.shape == (groups, b, h // groups, s, d)
+    np.testing.assert_array_equal(np.asarray(o_stack, np.float32),
+                                  np.asarray(stack(o), np.float32))
+    for name, g, w_ in zip("q k v g beta".split(), got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w_, np.float32),
+                                      err_msg=name)
+    assert calls_stack == calls == ((2, 1, 2, 1) if groups > 1
+                                    else (3, 1, 3, 1))
+
+
 def test_one_head_group_keeps_nothing():
     """With one group the map is no loop and ``chunk_kda`` names nothing
     (XLA merges the layer's rerun of the preparation with the group's

@@ -126,7 +126,8 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
     never inside the MLA layer; the layer's pre-norm counts with its
     mixer; and the table of kinds knows every instruction of the step.
     ISSUE 43: the convolution is a kernel pair that holds the SiLU and
-    the l2 norms too."""
+    the l2 norms too. ISSUE 55: so is the gated norm: ds.mix_post holds
+    ``ds_gated_norm_fwd`` / ``ds_gated_norm_bwd`` and no other leaf op."""
     engine = kimi_engine[0]
     work = scopes.op_work(hlo)
     paths = {row["scope"] for row in work.values()}
@@ -134,11 +135,13 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
         mine = {p for p in paths if re.search(rf"{re.escape(part)}\b", p)}
         assert {f"{d}:ds.layers/ds.kda/{part}"
                 for d in ("fwd", "bwd")} <= mine, (part, mine)
-        if part == "ds.conv":   # below: the interpreted kernels' constants
-            continue
+        if part in ("ds.conv", "ds.mix_post"):  # below: the interpreted
+            continue                            # kernels' constants
         assert all("ds.layers/ds.kda/" in p and "ds.mla" not in p
                    for p in mine), (part, mine)
     hlo_text.assert_conv_scope_is_the_kernels(hlo, "ds.kda", ("ds.mla",))
+    hlo_text.assert_gated_norm_scope_is_the_kernels(hlo, "ds.kda",
+                                                    ("ds.mla",))
     # q, k and v leave ds.conv as [B, S, H d] for the scan: ds.mix_pre
     # holds no bf16 op of their [., ., H, d] any more (g is float32)
     c = engine.module.config
@@ -146,7 +149,7 @@ def test_the_mixer_parts_lie_inside_ds_kda_and_no_kind_is_unknown(
     assert not [line for line in hlo.splitlines()
                 if re.search(heads, line) and "ds.mix_pre" in line]
     # the layer's pre-norm is the one rsqrt straight under ds.kda (the
-    # head's l2 norms are the kernels', o_norm is ds.mix_post's)
+    # head's l2 norms and o_norm are the kernels')
     norms = {row["scope"] for name, row in work.items()
              if name.startswith("rsqrt")}
     assert {"fwd:ds.layers/ds.kda", "bwd:ds.layers/ds.kda"} <= norms, norms

@@ -14,6 +14,7 @@ import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.telemetry import scopes
 
+from helpers import hlo_text
 from helpers.family_cases import DS_CONFIG as _DS_CONFIG
 from helpers.family_cases import _batch, _telemetry_isolation  # noqa: F401
 from helpers.family_cases import qnext_tiny as _tiny
@@ -104,3 +105,6 @@ def test_step_scopes_are_the_lists_and_each_kernel_lies_in_its_layer(
     flash = [p for p in paths if "ds.flash_" in p]
     assert flash and all(re.search(r"ds\.attn_gated\b.*ds\.flash_", p)
                          for p in flash), flash
+    # ISSUE 55: the gated norm is its kernel pair and no other leaf op
+    hlo_text.assert_gated_norm_scope_is_the_kernels(hlo, "ds.gdn",
+                                                    ("ds.attn_gated",))

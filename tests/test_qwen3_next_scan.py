@@ -164,12 +164,15 @@ def test_the_step_holds_no_repeated_q_or_k_and_the_parents_kernel_counts():
     parent repeated q and k there ([B, S, Hv, dk]) and summed the pairs
     back; and the scan's kernels are called as often as at the parent,
     3 / 1 / 3 / 1 (one head group keeps nothing, PR 51; the period's three
-    Gated DeltaNet layers are one traced body)."""
+    Gated DeltaNet layers are one traced body); the gated norm behind the
+    scan is its kernel pair, 2 / 1 (ISSUE 55)."""
     model, grad, params = _loss_gradient("qwen3_next")
     c = model.config
     calls = kernel_calls(grad, params)
     assert [calls[k] for k in ("ds_kda_prep_fwd", "ds_kda_prep_bwd",
                                "ds_kda_fwd", "ds_kda_bwd")] == [3, 1, 3, 1]
+    assert [calls[k] for k in ("ds_gated_norm_fwd",
+                               "ds_gated_norm_bwd")] == [2, 1]
     pre = [v.aval for e in _walk_eqns(jax.make_jaxpr(grad)(params).jaxpr)
            if "ds.mix_pre" in str(e.source_info.name_stack)
            for v in e.outvars]

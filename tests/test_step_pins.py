@@ -60,13 +60,21 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # 64 experts held; taken on its own tree, the first that has the family) and
 # moved ``kimi_linear``'s ``after_step`` body into
 # ``RoutedStackOfKinds._balanced`` word for word: the eight rows before it
-# stand.
+# stand. PR 55 re-took ``kimi_linear`` and ``qwen3_next`` by design (the
+# gated norm between the delta-rule scan and the output projection is the
+# kernel pair of ``ops/layers.py`` ``gated_norm`` where ``_kda`` and ``_gdn``
+# held ``jax.numpy`` expressions; on one device the scan hands ``o`` over as
+# the heads' stack, ``chunk_kda(by_head=True)``, on this mesh per shard as
+# [B, S, H, d]; ``_kda`` ties the cotangents round the norm, ``_together``;
+# the seeded weights are the parent's); the seven other rows stand: no other
+# family calls the op, and ``models/stack.py`` hands their ``_mixers`` on as
+# they were.
 _PINS = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
         first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
         loss_chunk=64, kda_head_groups=2),
-        "b738a8308e507302e4ddf5de4aeec4424e8f21e62e4ca33ed349dbfa247b140e",
+        "96351243eb33e8ab98c087dc598f18c51becb25bed1506d3e460341bd864bfe7",
         7191.956370612894),
     "granite_hybrid": (GraniteHybrid, dict(
         num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
@@ -97,7 +105,7 @@ _PINS = {
     "qwen3_next": (Qwen3Next, dict(
         num_layers=2, full_attention_interval=2, moe_held_experts=32,
         qk_norm_init=2.0, attn_impl="flash", loss_chunk=64),
-        "6bee3752161b25651463093ce15855be1e5030ae8a99ae594ca430fc6dae2678",
+        "b9fddf2a2a4d287989936797e229ff20f68344e9ffd0978ac858e42b70b9e743",
         39458.17879846059),
     "lfm2_moe": (Lfm2Moe, dict(
         num_layers=2, layer_types=["full_attention", "conv"],

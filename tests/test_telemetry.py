@@ -352,8 +352,12 @@ def test_comms_log_summary_with_telemetry_window():
     with telemetry.span("train_batch"):
         time.sleep(0.01)
     lg = CommsLogger()
-    lg.append("all_reduce", 1 << 20)
-    lg.append("all_reduce", 1 << 20)
+    # sizes (numbers only: nothing is allocated) large enough that the
+    # columns' three decimals hold the 1% below when a loaded machine makes
+    # the window seven times the sleep (2 MiB over 70 ms printed 0.030 and
+    # 0.027: a whole run's one failure, PR 55)
+    lg.append("all_reduce", 1 << 30)
+    lg.append("all_reduce", 1 << 30)
     lg.append("all_gather", 1 << 10)
     text = lg.log_summary(world_size=8, print_log=False)
     assert "algbw(GB/s)" in text and "busbw(GB/s)" in text

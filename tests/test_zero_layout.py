@@ -495,17 +495,32 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
     p = jax.eval_shape(lambda k: model._init_layer(k, ("kda", "dense")),
                        jax.random.PRNGKey(0))["kda"]
     p = jax.tree.map(lambda x: sd(*x.shape), p)
-    _, kda_fn, conv_fn = model._mixers(None, None)
+    _, kda_fn, conv_fn, norm_fn = model._layer_fns(None, None)
 
     def mixer(p, h):
         with jax.named_scope("ds.kda"):
-            return model._kda(p, h, kda_fn, conv_fn)
+            return model._kda(p, h, kda_fn, conv_fn, norm_fn)
 
     hlo = jax.jit(jax.grad(lambda p, h: jnp.sum(
         jax.checkpoint(mixer)(p, h).astype(f32) ** 2), argnums=(0, 1))).lower(
             p, sd(1, 16384, 2304)).compile().as_text()
     assert (calls(hlo, "ds_short_conv_fwd"),
             calls(hlo, "ds_short_conv_bwd")) == (6, 3)
+    # ISSUE 55: the gated norm behind the scan is its kernel pair (the
+    # forward, remat's rerun for the output matmul, one backward) and
+    # ds.mix_post holds nothing else; the scan's ``o`` reaches it, and
+    # ``do`` leaves it, as the heads' stack the scan's kernels write and
+    # read, so no instruction moves a bf16 [., 16384, ., 128] of ``o``'s
+    # size but the three splits of q, k and v counted below
+    assert (calls(hlo, "ds_gated_norm_fwd"),
+            calls(hlo, "ds_gated_norm_bwd")) == (2, 1)
+    post = [line for line in hlo.splitlines() if "ds.mix_post" in line]
+    assert len(post) == 3 + sum("get-tuple-element" in x for x in post), [
+        x[:120] for x in post]
+    assert not any(re.search(r"bf16\[[\d,]+\]\{[^}]*S\(1\)", x)
+                   for x in post)
+    assert not re.search(
+        r"= bf16\[4,1,8,16384,128\]\S* (copy|transpose)\(", hlo)
     moved = {"fwd": [], "bwd": [], "mix_pre": []}
     for line in hlo.splitlines():
         m = re.match(r"\s*%[\w.\-]+ = (bf16\[[\d,]+\])\S* "
@@ -535,6 +550,76 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
     assert "all-to-all" not in hlo and "all-gather" not in hlo
     with pytest.raises(Exception, match="[Mm]osaic"):
         grad(L.short_conv, norm_width=128).lower(*args).compile()
+
+
+def test_gated_norm_kernels_compile_for_v5e_alone_and_per_shard(monkeypatch):
+    """The gated norm's kernel pair (ISSUE 55) at the two cells' widths,
+    compiled by Mosaic for one described v5e chip in both callers' forms
+    (sigmoid with a bias and the bf16-rounded norm on four head groups'
+    stack; SiLU in float32 on one group's) and on ``o`` as [B, S, H, d]:
+    the lane reductions, the squeezed group and head axes of the stack's
+    blocks and the sums' revisited output block are what interpret mode
+    cannot refuse. Neither kernel asks for more VMEM than any XLA op gets
+    (as the last op of a loop's body a larger limit costs the loop XLA's
+    staging: PR 48), and no operand or result is staged in VMEM (``S(1)``)
+    round the calls. Then ``sharded_gated_norm`` on ``v5e:2x2`` with the
+    batch over ``fsdp``, where the bare call cannot be partitioned."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.ops import layers as L
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, bf, sharding=one)
+    kimi = dict(act="sigmoid", eps=1e-5, round_norm=True)
+    qwen = dict(act="silu", eps=1e-6)
+
+    def grad(fn, n, **kw):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a, **kw).astype(f32)),
+            argnums=tuple(range(n))))
+
+    gate = sd(1, 16384, 4096)
+    for o, bias, kw in ((sd(4, 1, 8, 16384, 128), True, kimi),
+                        (sd(1, 1, 32, 16384, 128), False, qwen),
+                        (sd(1, 16384, 32, 128), True, kimi)):
+        args = (o, gate, sd(128)) + ((sd(4096),) if bias else ())
+        hlo = grad(L.gated_norm, len(args), **kw).lower(
+            *args).compile().as_text()
+        found = [line for line in hlo.splitlines()
+                 if re.search(r"%ds_gated_norm_(fwd|bwd)[.\w]* = .*custom-call",
+                              line)]
+        assert len(found) == 2, (o.shape, len(found))
+        for line in found:
+            # (the sums' 32 KB may live there: no [16384, .] tensor does)
+            assert not re.search(r"bf16\[[\d,]+\]\{[^}]*S\(1\)", line), line[:300]
+            asked = re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                               line)[0]
+            assert all(int(n) <= 16 * 2 ** 20 for n in re.findall(
+                r'"size":"(\d+)"', asked)), asked
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    act = mt.sharding(mt.batch_axes(), "sp")
+    put = lambda spec, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, bf, sharding=NamedSharding(mt.mesh, spec))
+    rows = P(mt.batch_axes(), None, None)
+    args = (put(P(mt.batch_axes(), None, None, None), 4, 2048, 4, 128),
+            put(rows, 4, 2048, 512), put(P(), 128), put(P(), 512))
+    hlo = grad(L.sharded_gated_norm(act), 4, **kimi).lower(
+        *args).compile().as_text()
+    for kernel in ("ds_gated_norm_fwd", "ds_gated_norm_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo and "all-gather" not in hlo
+    with pytest.raises(Exception, match="[Mm]osaic"):
+        grad(L.gated_norm, 4, **kimi).lower(*args).compile()
 
 
 def test_gated_conv_kernels_and_the_widest_held_backward_compile_for_v5e(
