@@ -20,6 +20,7 @@ from .qwen import (Qwen, Qwen2, Qwen2MoE, qwen2_config,  # noqa: F401
                    qwen2_moe_config, qwen_config)
 from .qwen3_next import Qwen3Next, qwen3_next_config  # noqa: F401
 from .transformer import DecoderLM  # noqa: F401
+from .xing4 import Xing4, xing4_config  # noqa: F401
 
 
 def from_pretrained(model_path: str, **config_overrides):

@@ -70,6 +70,7 @@ ADD_TILE = 128      # ``add_rows``: tokens a tile of the carry and rows a
 #                     PERF.md section 6, PR 48)
 
 _RESIDENT_MAX = 100 << 20   # ``backward``: an expert's blocks in VMEM
+_VMEM_MAX = 126 << 20       # what a kernel may ask
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -103,13 +104,15 @@ def tile_tables(expert, live, begun, m: int):
             init.astype(i32))
 
 
-def _params(resident_bytes: int, tile: int):
+def _params(resident_bytes: int, tile: int, row_bytes: int = 64 << 10,
+            axes: int = 1):
     # what stays in VMEM (weights and dW blocks, two buffers each) and
-    # room for the row tiles and the float32 temporaries
+    # room for the row tiles and the float32 temporaries (``row_bytes`` a
+    # row: 32 B a channel at hidden 2048)
     return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
+        dimension_semantics=("arbitrary",) * axes,
         vmem_limit_bytes=int(min(
-            resident_bytes + (16 << 20) + tile * (64 << 10), 126 << 20)))
+            resident_bytes + (16 << 20) + tile * row_bytes, _VMEM_MAX)))
 
 
 def _rows_spec(tile, width):
@@ -169,21 +172,33 @@ def forward(xs, scale, tables, experts, tile: int):
 def _bwd_kernel(e_ref, src_ref, live_ref, init_ref, xs_ref, dys_ref,
                 scale_ref, wg_ref, wu_ref, wd_ref, cg_ref, cu_ref, cd_ref,
                 dxs_ref, dwt_ref, dg_ref, du_ref, dd_ref, sem, *,
-                router_grad: bool):
+                router_grad: bool, cols: int):
     del src_ref
-    t = pl.program_id(0)
-    sums = ((cg_ref, dg_ref), (cu_ref, du_ref), (cd_ref, dd_ref))
+    # the row tiles are the grid's last axis; in front of it, where the
+    # expert's columns are cut, the cut (``backward``)
+    t = pl.program_id(0 if cols == 1 else 1)
+    run = pl.program_id(0) if cols > 1 else 0
+    # (carry, block, the carry's axis the cut runs along)
+    sums = ((cg_ref, dg_ref, 2), (cu_ref, du_ref, 2), (cd_ref, dd_ref, 1))
 
     @pl.when(init_ref[t] == _ZERO)
     def _():
-        for _, ref in sums:
+        for _, ref, _ in sums:
             ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
     @pl.when(init_ref[t] == _CARRY)
     def _():
-        copies = [pltpu.make_async_copy(carry.at[pl.ds(e_ref[t], 1)], ref,
+        def part(carry, ref, axis):
+            if cols == 1:
+                return carry.at[pl.ds(e_ref[t], 1)]
+            at = [pl.ds(e_ref[t], 1), slice(None), slice(None)]
+            width = ref.shape[axis]
+            at[axis] = pl.ds(run * width, width)
+            return carry.at[tuple(at)]
+
+        copies = [pltpu.make_async_copy(part(carry, ref, axis), ref,
                                         sem.at[i])
-                  for i, (carry, ref) in enumerate(sums)]
+                  for i, (carry, ref, axis) in enumerate(sums)]
         for copy in copies:
             copy.start()
         for copy in copies:
@@ -209,6 +224,36 @@ def _bwd_kernel(e_ref, src_ref, live_ref, init_ref, xs_ref, dys_ref,
         dd_ref[0] += _dot((h * scale).astype(x.dtype), dy, _TN)
 
 
+def backward_geometry(d: int, f: int, tile: int, itemsize: int):
+    """How ``backward`` holds ONE expert of ``d`` by ``f`` in VMEM, from
+    the shapes alone: (column runs the expert is cut into, buffers its
+    weights take, bytes resident). An expert's weights and its float32
+    ``dW`` blocks take two buffers each; past ``_RESIDENT_MAX`` (hidden
+    2048 by an expert of 1536: 113 MiB, and the kernel asked 127.3 of the
+    126 it may have) the weights take ONE: the next expert's are fetched
+    when its first tile arrives and not behind the last tile before it (23
+    us an expert at 819 GB/s). Where even that, with the row tiles' share,
+    is more than a kernel may ask (hidden 3584 by 1024: 105 MiB and 44
+    more), the expert is cut by COLUMNS of ``f`` (``w_gate``, ``w_up`` and
+    their ``dW`` by columns, ``w_down`` and its ``dW`` by rows), into the
+    fewest runs, a power of two of whole 128-lane blocks, that fit: every
+    product of the backward but ``dx`` and the row's ``dy . y`` is a
+    column's own, so a run walks the chunk's row tiles as the whole expert
+    would, and the caller sums the runs' ``dx`` and ``dy . y``."""
+    def held(cols):
+        weights, sums = 3 * d * f * itemsize // cols, 3 * d * f * 4 // cols
+        resident, buffers = 2 * (weights + sums), 2
+        if resident > _RESIDENT_MAX:
+            resident, buffers = resident - weights, 1
+        return buffers, resident
+
+    cols = 1
+    while (held(cols)[1] + (16 << 20) + tile * 32 * d > _VMEM_MAX
+           and f % (256 * cols) == 0):   # what ``_params`` would ask
+        cols *= 2
+    return (cols, *held(cols))
+
+
 def backward(xs, dys, scale, tables, experts, sums, tile: int,
              router_grad: bool):
     """The chunk's part of the backward: (``dxs`` [C, D] float32 (the
@@ -221,36 +266,46 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
     c, d = xs.shape
     names = ("w_gate", "w_up", "w_down")
     w = [experts[n] for n in names]
-    held, _, f = w[0].shape
+    f = w[0].shape[-1]
     carry = pl.BlockSpec(memory_space=pl.ANY)
-    # an expert's weights and its float32 dW blocks, two buffers each; past
-    # ``_RESIDENT_MAX`` (hidden 2048 by an expert of 1536: 113 MiB, and the
-    # kernel asked 127.3 of the 126 it may have) the weights take ONE: the
-    # next expert's are fetched when its first tile arrives and not behind
-    # the last tile before it (23 us an expert at 819 GB/s)
-    resident = 2 * (_nbytes(*w) + _nbytes(*sums)) // held
-    once = {}
-    if resident > _RESIDENT_MAX:
-        once = {"pipeline_mode": pl.Buffered(1)}
-        resident -= _nbytes(*w) // held
+    cols, buffers, resident = backward_geometry(
+        d, f, tile, jnp.dtype(w[0].dtype).itemsize)
+    once = {"pipeline_mode": pl.Buffered(1)} if buffers == 1 else {}
+    fc = f // cols
+    # index maps take (column run j, row tile t, the tables); the whole
+    # expert's grid has no axis for j
+    step = (lambda fn: fn) if cols > 1 else (
+        lambda fn: lambda t, *tables: fn(0, t, *tables))
+    rows = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), step(lambda j, t, e, src, *_: (src[t], 0)))
+    by_columns = lambda **kw: pl.BlockSpec(  # noqa: E731
+        (1, d, fc), step(lambda j, t, e, *_: (e[t], 0, j)), **kw)
+    by_rows = lambda **kw: pl.BlockSpec(  # noqa: E731
+        (1, fc, d), step(lambda j, t, e, *_: (e[t], j, 0)), **kw)
+    if cols == 1:
+        grid, slab, lead = (c // tile,), rows, ()
+        params = _params(resident, tile)
+    else:
+        # a run's dx and dy . y go to a slab of their own
+        grid, lead = (cols, c // tile), (cols,)
+        slab = lambda width: pl.BlockSpec(  # noqa: E731
+            (None, tile, width), lambda j, t, e, src, *_: (j, src[t], 0))
+        params = _params(resident, tile, 32 * d, 2)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, router_grad=router_grad),
+        functools.partial(_bwd_kernel, router_grad=router_grad, cols=cols),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(c // tile,),
-            in_specs=[_rows_spec(tile, d), _rows_spec(tile, d),
-                      _rows_spec(tile, 1), _expert_spec((d, f), **once),
-                      _expert_spec((d, f), **once),
-                      _expert_spec((f, d), **once),
+            num_scalar_prefetch=4, grid=grid,
+            in_specs=[rows(d), rows(d), rows(1),
+                      by_columns(**once), by_columns(**once), by_rows(**once),
                       carry, carry, carry],
-            out_specs=[_rows_spec(tile, d), _rows_spec(tile, 1),
-                       _expert_spec((d, f)), _expert_spec((d, f)),
-                       _expert_spec((f, d))],
+            out_specs=[slab(d), slab(1), by_columns(), by_columns(),
+                       by_rows()],
             scratch_shapes=[pltpu.SemaphoreType.DMA((3,))]),
-        out_shape=[jax.ShapeDtypeStruct((c, d), jnp.float32),
-                   jax.ShapeDtypeStruct((c, 1), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((*lead, c, d), jnp.float32),
+                   jax.ShapeDtypeStruct((*lead, c, 1), jnp.float32),
                    *(jax.ShapeDtypeStruct(s.shape, s.dtype) for s in sums)],
         input_output_aliases={10: 2, 11: 3, 12: 4},
-        compiler_params=_params(resident, tile),
+        compiler_params=params,
         cost_estimate=pl.CostEstimate(
             flops=int(16 * c * d * f), transcendentals=int(c * f),
             bytes_accessed=int(4 * _nbytes(xs) + _nbytes(scale, *w)
@@ -261,6 +316,8 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
     dxs, dwt, *sums = _bind(call, "ds.moe_gmm_bwd",
                             ("bwd", tile, router_grad), *tables, xs, dys,
                             scale, *w, *sums)
+    if cols > 1:
+        dxs, dwt = dxs.sum(axis=0), dwt.sum(axis=0)
     return dxs, dwt, sums
 
 

@@ -220,6 +220,34 @@ LFM_SCOPES = (
     #                    backward rule opens the scope itself)
     "ds.gconv_out",    # models/lfm2_moe.py _conv: the output projection
 )
+# what a stack whose residual path is several streams mixed by
+# manifold-constrained hyper-connections opens inside ds.layers
+# (models/xing4.py) beside ds.attn and ds.mlp of DEVICE_SCOPES (its latent
+# attention and its leading dense layers), ds.rope of WINDOW_SCOPES and the
+# routed layers' four and their kernels' of KIND_SCOPES;
+# ``tests/test_xing4_engine.py`` holds the step to them
+MHC_SCOPES = (
+    "ds.mhc",          # models/xing4.py _sublayer: both stream passes of a
+    #                    sublayer and the coefficients between them (opened
+    #                    twice, round the sublayer, which lies outside)
+    "ds.mhc_pre",      # ops/mhc.py mhc_pre: the pass in front of a
+    #                    sublayer, the kernels ds_mhc_pre_fwd / ds_mhc_pre_bwd
+    #                    of ops/pallas/mhc.py and nothing else on the chip
+    #                    (_pre_forward, _pre_backward: the backward rule
+    #                    opens the scope itself); the jax.numpy form elsewhere
+    "ds.mhc_coef",     # ops/mhc.py coefficients: H_post's sigmoid, the
+    #                    clamp, exp, the Sinkhorn iterations, the residual
+    "ds.mhc_post",     # ops/mhc.py mhc_post: the pass behind a sublayer,
+    #                    ds_mhc_post_fwd / ds_mhc_post_bwd of
+    #                    ops/pallas/mhc.py (_post_forward, _post_backward)
+    "ds.mhc_spread",   # models/xing4.py _layer_stack: the embedding copied
+    #                    to the streams
+    "ds.mhc_fold",     # models/xing4.py _layer_stack: the streams summed
+)
+# every list above: what a metric file may name
+KNOWN_SCOPES = frozenset(
+    DEVICE_SCOPES + KIND_SCOPES + SSM_SCOPES + MIXER_SCOPES + WINDOW_SCOPES
+    + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

@@ -305,12 +305,14 @@ _LONGEST_FIRST = (
     "test_kimi_linear_engine.py",
     "test_kda_prep_kernels.py",
     "test_short_conv_step.py",
+    "test_qwen3_next_scan.py",
     "test_kda_kernels.py",
     "test_kimi_linear_limits.py",
     "test_short_conv.py",
     "test_ouro.py",
     "test_kept_residuals.py",
     "test_kimi_linear_reference.py",
+    "test_xing4.py",
     "test_qwen3_next_reference.py",
     "test_mellum_reference.py",
     "test_granite_hybrid.py",

@@ -1,5 +1,5 @@
-"""What the test files of the Kimi-Linear, Granite, Mellum, Qwen3-Next and
-LFM2-MoE families share (``tests/test_kimi_linear*.py``,
+"""What the test files of the Kimi-Linear, Granite, Mellum, Qwen3-Next,
+LFM2-MoE and Xing4 families share (``tests/test_kimi_linear*.py``,
 ``tests/test_kda*_kernels.py``, ``tests/test_granite_hybrid*.py``,
 ``tests/test_mellum*.py``, ``tests/test_qwen3_next*.py``,
 ``tests/test_lfm2_moe*.py``, ``tests/test_short_conv_step.py``; since PR 50 a family's float32 reference
@@ -23,13 +23,13 @@ import pytest
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Lfm2Moe,
-                                  Mellum, Qwen3Next)
+                                  Mellum, Qwen3Next, Xing4)
 
 BENCH = pathlib.Path(__file__).resolve().parents[2] / "benchmark"
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 from architectures import (kimi_linear, lfm2_moe, mellum,  # noqa: E402
-                           qwen3_next)
+                           qwen3_next, xing4)
 from lib import modelspec  # noqa: E402
 
 def _config(name):
@@ -40,6 +40,7 @@ GRANITE_CONFIG = _config("granite-4.0-h-micro-zero3-1chip")
 MELLUM_CONFIG = _config("mellum2-12b-ep4-zero3-1chip")
 QNEXT_CONFIG = _config("qwen3-next-80b-ep16-zero3-1chip")
 LFM_CONFIG = _config("lfm2-24b-ep8-zero3-1chip")
+XING_CONFIG = _config("xing4.0-29b-ep8-zero3-1chip")
 
 
 # the engine every family's tiny model is trained and lowered under: ZeRO-3
@@ -283,3 +284,51 @@ def lfm_right(held: int = 8):
     ``held`` of the 64 experts held."""
     model = lfm_tiny(moe_held_experts=held)
     return _reference_says(lfm2_moe, LFM_CONFIG, model, lfm_weights(model))
+
+
+# ---- Xing4 -----------------------------------------------------------------
+def xing_tiny(**kw):
+    """8 of the tiny preset's 64 experts held and the seeded values of the
+    benchmark's configuration (``assumed``: the coefficients' static and
+    input-dependent parts at comparable deviation),
+    in three layers that hold both kinds (a leading dense layer, two
+    routed ones under the scan): two layers fewer to compile a case than
+    the preset's own five."""
+    kw.setdefault("moe_held_experts", 8)
+    kw.setdefault("num_layers", 3)
+    for key in ("mhc_alpha_init", "mhc_b_std"):
+        kw.setdefault(key, XING_CONFIG["program"]["model_overrides"][key])
+    return Xing4(size="tiny", **kw)
+
+
+def xing_weights(model, seed=3):
+    """Seeded weights under which every part this family adds carries
+    weight in the logits at the tiny widths: a larger table under larger
+    values, outputs and experts (at the init's own scale a layer of hidden
+    64 adds a hundredth of the embedding), sharper attention scores,
+    alphas apart from one another,
+    and every norm weight drawn (they start at 1, where ``w`` cannot
+    be told from a missing weight)."""
+    boost = {"tokens": 5.0, "wq_b": 3.0, "w_kva": 4.0, "w_kvb": 3.0, "wo": 8.0, "w_gate": 6.0,
+             "w_up": 6.0, "w_down": 8.0, "router_bias": 10.0}
+    norms = {"ln1_scale", "ln2_scale", "scale", "q_norm", "kv_norm"}
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def one(path, w):
+        name = path[-1].key
+        if name in norms:
+            return w + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype)
+        if name == "alpha":
+            return w * jnp.asarray([1.0, 0.7, 1.3], w.dtype)
+        return w * boost.get(name, 1.0)
+
+    return jax.tree_util.tree_map_with_path(
+        one, model.init(jax.random.PRNGKey(seed)))
+
+
+@functools.lru_cache(maxsize=None)
+def xing_right(held: int = 8):
+    """``_reference_says`` of the right model's boosted weights with
+    ``held`` of the 64 experts held."""
+    model = xing_tiny(moe_held_experts=held)
+    return _reference_says(xing4, XING_CONFIG, model, xing_weights(model))

@@ -171,14 +171,7 @@ def test_the_step_keeps_the_names_the_reducers_find(devices8):
     modules = set(re.findall(r'"module": "([^"]+)"', text))
     assert modules == {"^jit_train_step"}
     named = set(re.findall(r"ds\.[a-z_]+", text))
-    assert named and named <= (set(scopes.DEVICE_SCOPES)
-                               | set(scopes.KIND_SCOPES)
-                               | set(scopes.SSM_SCOPES)
-                               | set(scopes.MIXER_SCOPES)
-                               | set(scopes.WINDOW_SCOPES)
-                               | set(scopes.LOOP_SCOPES)
-                               | set(scopes.GDN_SCOPES)
-                               | set(scopes.LFM_SCOPES))
+    assert named and named <= scopes.KNOWN_SCOPES
 
     telemetry.shutdown()
     try:

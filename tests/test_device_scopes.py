@@ -530,3 +530,36 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
         name: row["scope"] for name, row in work.items()}
     assert work == led.op_work_by_name()["compiled_step"]
     assert not any(row["kind"] == "other" for row in work.values())
+
+
+# ---- the hyper-connected streams' scopes (ISSUE 56) ------------------------
+@pytest.mark.parametrize("scope, file, function", [
+    ("ds.mhc", "models/xing4.py", "_sublayer"),
+    ("ds.mhc_pre", "ops/mhc.py", "mhc_pre"),
+    ("ds.mhc_pre", "ops/pallas/mhc.py", "_pre_forward"),
+    ("ds.mhc_pre", "ops/pallas/mhc.py", "_pre_backward"),
+    ("ds.mhc_coef", "ops/mhc.py", "coefficients"),
+    ("ds.mhc_post", "ops/mhc.py", "mhc_post"),
+    ("ds.mhc_post", "ops/pallas/mhc.py", "_post_forward"),
+    ("ds.mhc_post", "ops/pallas/mhc.py", "_post_backward"),
+    ("ds.mhc_spread", "models/xing4.py", "_layer_stack"),
+    ("ds.mhc_fold", "models/xing4.py", "_layer_stack"),
+])
+def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
+                                                          function):
+    """``MHC_SCOPES`` names, a scope, the file and the function that opens
+    it: the function's source holds the scope's name as a literal, and
+    every list of the registry is in ``KNOWN_SCOPES`` (what a metric file
+    may name: ``tests/test_benchmark_contract.py``)."""
+    import ast
+    import pathlib
+
+    import deepspeed_tpu
+    assert scope in scopes.MHC_SCOPES and scope in scopes.KNOWN_SCOPES
+    source = (pathlib.Path(deepspeed_tpu.__file__).parent / file).read_text()
+    body = next(ast.get_source_segment(source, node)
+                for node in ast.walk(ast.parse(source))
+                if isinstance(node, ast.FunctionDef)
+                and node.name == function)
+    assert f'"{scope}"' in body
+    assert set(scopes.TOP_SCOPES) <= scopes.KNOWN_SCOPES

@@ -217,3 +217,49 @@ def test_tile_tables_name_one_expert_a_tile_and_skip_the_dead():
 def test_row_tile_divides_the_block(block, tile, monkeypatch):
     monkeypatch.undo()
     assert grouped_matmul.row_tile(block) == tile and block % tile == 0
+
+
+# ---- the backward's hold of one expert (ISSUE 56) --------------------------
+@pytest.mark.parametrize("d, f, tile, want", [
+    (2304, 1024, 256, (1, 2, 84934656)),    # Kimi: whole, two buffers
+    (2304, 896, 256, (1, 2, 74317824)),     # Mellum
+    (2048, 512, 128, (1, 2, 37748736)),     # Qwen3-Next
+    (2048, 1536, 128, (1, 1, 94371840)),    # LFM2: the weights take one
+    (3584, 1024, 256, (2, 2, 66060288)),    # Xing4: two column runs
+], ids=["kimi", "mellum", "qnext", "lfm", "xing"])
+def test_backward_geometry_by_shape(d, f, tile, want):
+    """(column runs, the weights' buffers, bytes resident) of
+    ``grouped_matmul.backward`` from the shapes alone: the four shapes the
+    accepted cells run hold an expert whole, exactly as before the column
+    cut was written (one grid axis, the same blocks and buffering: the
+    rows of ``tests/test_step_pins.py`` hold their lowered steps); hidden
+    3584 by 1024 is cut in two, each half at two buffers."""
+    assert grouped_matmul.backward_geometry(d, f, tile, 2) == want
+
+
+@pytest.mark.parametrize("load", ["balanced", "one_expert",
+                                  "an_expert_with_none", "absent_only"])
+@pytest.mark.parametrize("router_grad", [True, False], ids=["rg", "norg"])
+def test_the_column_cut_gives_what_the_whole_expert_gives(
+        load, router_grad, monkeypatch):
+    """With ``_VMEM_MAX`` lowered until an expert of 128 by 512 is cut
+    into four column runs (a second grid axis in front of the row tiles,
+    ``dW`` blocks by columns, ``dx`` and the row's ``dy . y`` summed over
+    the runs by the caller), every gradient is the block loop's; several
+    chunks, so that a carried ``dW`` is copied in by columns too."""
+    from deepspeed_tpu.ops.pallas import _common
+    args = _inputs(load, 128, 512, jnp.float32)
+    want = _all(held_reference.held_experts_ffn)(*args)
+    monkeypatch.setattr(grouped_matmul, "_VMEM_MAX", 17 << 20)
+    assert grouped_matmul.backward_geometry(128, 512, TILE, 4)[0] == 4
+    _common._TRACED.clear()
+    try:
+        got = _all(held_experts_ffn, router_grad, 3 * TILE + 1)(*args)
+    finally:
+        _common._TRACED.clear()
+    for name, g, w in zip(NAMES, got, want):
+        if name == "dweights" and not router_grad:
+            assert not np.asarray(g).any()
+            continue
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert _err(g, w) < 2e-5, (name, _err(g, w))

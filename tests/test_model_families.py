@@ -333,6 +333,13 @@ PARENT_COUNTS = {
                          45420414720),
     "cell/lfm2-24b-ep8-zero3-1chip": (
         469285248, 186169728, 1217939712.0, 1318590720),
+    # PR 56's own, pinned when the family came (ISSUE 56's 631 M: layers 2
+    # to 5, phi, b and alpha of eight sublayers among them)
+    "xing4_0/tiny": (1839846, 365286, 2396868.0, 2701668),
+    "xing4_0/29b-a4b": (29505505264, 4402595824, 345762066336.0,
+                        667883384736),
+    "cell/xing4.0-29b-ep8-zero3-1chip": (
+        631149528, 300848088, 2463651600.0, 3470161680),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -400,6 +407,8 @@ def test_counts_are_the_parents(case):
     ("mellum", dict(kda_head_groups=4)),
     ("qwen3_next", dict(kda_head_groups=4)),
     ("lfm2_moe", dict(kda_head_groups=4)),
+    ("xing4_0", dict(qk_norm_init=2.0)),
+    ("kimi_linear", dict(hc_mult=4)),
     ("granite_hybrid", dict(conv_L_cache=3)),
     ("kimi_linear", dict(qk_norm_init=2.0)),
     ("ouro", dict(layer_types=["attention", "attention"])),

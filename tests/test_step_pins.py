@@ -17,7 +17,7 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Lfm2Moe, Mellum,
-                                  Mistral, Ouro, Qwen3Next)
+                                  Mistral, Ouro, Qwen3Next, Xing4)
 from deepspeed_tpu.models.transformer import _chunked_cross_entropy
 from deepspeed_tpu.ops.pallas import _common
 
@@ -68,7 +68,12 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # [B, S, H, d]; ``_kda`` ties the cotangents round the norm, ``_together``;
 # the seeded weights are the parent's); the seven other rows stand: no other
 # family calls the op, and ``models/stack.py`` hands their ``_mixers`` on as
-# they were.
+# they were. PR 56 added ``xing4_0`` (a leading dense layer and a routed one,
+# 8 of 64 experts held, both under four hyper-connected streams, at the
+# seeded spreads of its cell; taken on its own tree, the first that has the
+# family) and cut ``grouped_matmul.backward`` by columns for an expert too
+# wide to hold: the nine rows before it stand, their experts are held whole
+# and lower to the text they lowered to.
 _PINS = {
     "kimi_linear": (KimiLinear, dict(
         num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
@@ -113,6 +118,12 @@ _PINS = {
         loss_chunk=64),
         "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",
         3449.799246064109),
+    "xing4_0": (Xing4, dict(
+        num_layers=2, first_k_dense_replace=1, moe_held_experts=8,
+        mhc_alpha_init=(2.0, 2.0, 0.5), mhc_b_std=(2.0, 2.0, 0.5),
+        attn_impl="flash", loss_chunk=64),
+        "7d0db59390f004a09922ce9ac1b47e8557f20f5884037f8f0dd022c42b2a6715",
+        4668.748035160373),
 }
 
 

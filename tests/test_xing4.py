@@ -1,0 +1,242 @@
+"""Xing4 (ISSUE 56): four residual streams mixed by Sinkhorn-constrained
+hyper-connections round rotated, low-rank-query latent attention and a held
+share of bias-corrected sigmoid-routed experts, checked on the CPU at tiny
+sizes: the whole model against the plain float32 reference the benchmark
+keeps (``benchmark/architectures/xing4.py``, which imports nothing from
+the program), ``ops/mhc.py`` against a loop over the tokens, and the four
+kernels of ``ops/pallas/mhc.py`` in interpret mode against ``ops/mhc.py``.
+The planted faults and the shares are ``tests/test_xing4_limits.py``'s, the
+engine ``tests/test_xing4_engine.py``'s (a file is one worker's under
+``--dist loadfile``). A CPU run shows results and counts, never a time."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import mhc
+from deepspeed_tpu.ops.pallas import mhc as kernels
+
+from helpers.family_cases import XING_CONFIG as CONFIG
+from helpers.family_cases import (_err, _reference_grads,  # noqa: F401
+                                  _telemetry_isolation, xing_right)
+from helpers.family_cases import xing_tiny as _tiny
+from architectures import xing4 as arch  # noqa: E402  (benchmark/, on
+#                                      sys.path by family_cases)
+from kinds import train_job  # noqa: E402
+from lib import modelspec  # noqa: E402
+
+F32 = jnp.float32
+
+
+# ---- the whole model against the float32 reference -------------------------
+@functools.lru_cache(maxsize=None)
+def _right():
+    params, tokens, targets, want, m = xing_right()
+    return params, tokens, targets, want, _reference_grads(
+        arch, params, tokens, targets, m)
+
+
+# every parameter group ISSUE 56 names, by the leaf's path
+_NAMED = ("phi", "b", "alpha", "wq_a", "q_norm", "wq_b", "w_kva", "kv_norm",
+          "w_kvb", "wo", "w_gate", "w_down", "router", "ln1_scale",
+          "ln2_scale", "tokens", "scale", "lm_head")
+
+
+@pytest.mark.parametrize("variant", ["plain_f32", "flash_chunked_loss_f32",
+                                     "flash_chunked_loss_bf16"])
+def test_loss_logits_and_gradients_match_the_float32_reference(variant):
+    """Float32: loss to 2e-5, tail logits to 5e-4 of their largest, and on
+    the cell's path (flash kernels, chunked loss, every layer rematted,
+    the scan over the routed layers) every gradient, ``phi``, ``b`` and
+    ``alpha`` of both sublayers among them, to 3e-3 of its largest; the
+    selection bias's gradient is zero on both sides. bfloat16 weights
+    (what the engine computes with) at the init's own scale against the
+    float32 reference on the same weights, over the positions its mask
+    counts: loss to 0.5%, logits to 5% of their largest and 2% rms."""
+    kw = dict(remat=False) if variant == "plain_f32" else dict(
+        attn_impl="flash", loss_chunk=64)
+    model = _tiny(**kw)
+    params, tokens, targets, (want, want_tail, _), want_g = _right()
+    if variant.endswith("bf16"):
+        params = model.init(jax.random.PRNGKey(3))
+        m = modelspec.reference_model(arch, model, CONFIG["check"])
+        with jax.default_matmul_precision("highest"):
+            want, want_tail, counted = arch.reference(
+                params, tokens, targets, m, 32)
+        low = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), params)
+        numbers = train_job.tail_numbers(
+            model.apply(low, tokens)[:, -32:], want_tail, counted)
+        got = float(model.loss(low, (tokens, targets)))
+        assert abs(got - want) <= 5e-3 * want
+        assert numbers["logits_err_max"] < 5e-2, numbers
+        assert numbers["logits_err_rms"] < 2e-2, numbers
+        return
+    with jax.default_matmul_precision("highest"):
+        got_tail = model.apply(params, tokens)[:, -32:]
+        if variant == "plain_f32":
+            got, got_g = model.loss(params, (tokens, targets)), None
+        else:
+            got, got_g = jax.value_and_grad(model.loss)(params,
+                                                        (tokens, targets))
+    assert abs(float(got) - want) <= 2e-5 * want
+    assert _err(got_tail, want_tail) < 5e-4
+    if got_g is None:
+        return
+    flat_w = jax.tree_util.tree_leaves_with_path(want_g)
+    flat_g = jax.tree_util.tree_leaves_with_path(got_g)
+    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
+    seen = set()
+    for (path, w), (_, g) in zip(flat_w, flat_g):
+        name = jax.tree_util.keystr(path)
+        seen.add(path[-1].key)
+        if path[-1].key == "router_bias":
+            assert not np.any(w) and not np.any(g), name
+            continue
+        assert float(jnp.max(jnp.abs(w))) > 0, name
+        assert _err(g, w) < 3e-3, name
+    assert set(_NAMED) <= seen
+
+
+# ---- ops/mhc.py against a loop over the tokens -----------------------------
+def _inputs(t=48, n=4, c=32, dtype=F32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    k = n * (n + 2)
+    x = jax.random.normal(ks[0], (t, n, c), F32).astype(dtype)
+    phi = (jax.random.normal(ks[1], (n * c, k), F32)
+           * (n * c) ** -0.5).astype(dtype)
+    b = (0.5 * jax.random.normal(ks[2], (k,), F32)).astype(dtype)
+    b = b.at[2 * n:].add(jnp.eye(n).reshape(-1).astype(dtype))
+    alpha = jnp.asarray([0.5, 0.35, 0.65], dtype)
+    y = jax.random.normal(ks[3], (t, c), F32).astype(dtype)
+    return x, phi, b, alpha, y
+
+
+def test_the_two_passes_are_the_equations_a_token_at_a_time():
+    """``mhc_pre`` / ``mhc_post`` (the ``jax.numpy`` forms, tokens on the
+    last axis inside the Sinkhorn iteration) against ISSUE 56's equations
+    written for ONE token in numpy float64 and looped; ``H_res`` is doubly
+    stochastic to the residual the op reports, and the residual is the
+    largest deviation of any row or column sum."""
+    x, phi, b, alpha, y = _inputs()
+    t, n, c = x.shape
+    eps, lo, hi, iters = 1e-6, -30.0, 30.0, 20
+    u, h_post, h_res, residual = mhc.mhc_pre(
+        x[None], phi, b, alpha, eps=eps, clamp=(lo, hi), iters=iters)
+    out = mhc.mhc_post(x[None], y[None], h_post, h_res)
+    X, P, B, A, Y = (np.asarray(v, np.float64) for v in (x, phi, b, alpha, y))
+    sig = lambda v: 1 / (1 + np.exp(-v))  # noqa: E731
+    worst = 0.0
+    for tok in range(t):
+        vec = X[tok].reshape(-1)
+        xv = vec / np.sqrt(np.mean(vec ** 2) + eps)
+        z = xv @ P
+        h_pre = sig(A[0] * z[:n] + B[:n])
+        post = 2 * sig(A[1] * z[n:2 * n] + B[n:2 * n])
+        m = np.exp(np.clip(A[2] * z[2 * n:] + B[2 * n:], lo, hi)).reshape(n, n)
+        for _ in range(iters):
+            m = m / (m.sum(axis=1, keepdims=True) + eps)
+            m = m / (m.sum(axis=0, keepdims=True) + eps)
+        worst = max(worst, np.abs(m.sum(axis=1) - 1).max(),
+                    np.abs(m.sum(axis=0) - 1).max())
+        np.testing.assert_allclose(u[0, tok], h_pre @ X[tok], atol=2e-5)
+        np.testing.assert_allclose(h_post[0, tok], post, atol=2e-6)
+        np.testing.assert_allclose(h_res[0, tok], m, atol=2e-6)
+        np.testing.assert_allclose(
+            out[0, tok], m @ X[tok] + post[:, None] * Y[tok][None],
+            atol=3e-5)
+    got = np.asarray(h_res[0], np.float64)
+    sums = max(np.abs(got.sum(-1) - 1).max(), np.abs(got.sum(-2) - 1).max())
+    assert float(residual) == pytest.approx(sums, abs=1e-6)
+    assert float(residual) == pytest.approx(worst, abs=1e-6)
+    assert 0 < float(residual) < 1e-3
+    # a single iteration leaves the rows far off: the counter sees it
+    one = mhc.mhc_pre(x[None], phi, b, alpha, eps=eps, clamp=(lo, hi),
+                      iters=1)[3]
+    assert float(one) > 30 * float(residual)
+
+
+def test_a_logit_past_the_clamp_is_clamped_and_the_shapes_are_checked():
+    x, phi, b, alpha, y = _inputs()
+    n = x.shape[1]
+    far = b.at[2 * n + 1].set(100.0)
+    u, h_post, h_res, residual = mhc.mhc_pre(x[None], phi, far, alpha)
+    assert np.all(np.isfinite(np.asarray(h_res)))
+    assert np.asarray(h_res)[0, :, 0, 1].min() > 0.9
+    loose = mhc.mhc_pre(x[None], phi, far, alpha, clamp=(-1e30, 1e30))[2]
+    assert not np.all(np.isfinite(np.asarray(loose)))
+    with pytest.raises(ValueError, match="mhc_pre"):
+        mhc.mhc_pre(x[None], phi[:, :-1], b, alpha)
+    with pytest.raises(ValueError, match="mhc_post"):
+        mhc.mhc_post(x[None], y[None, :, :-1], h_post, h_res)
+
+
+# ---- the kernels, interpreted, against ops/mhc.py --------------------------
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("tokens", [64, 96], ids=["t64", "t96"])
+def test_the_kernels_match_the_jnp_forms_forward_and_both_cotangents(
+        dtype, tokens):
+    """``ds_mhc_pre_*`` and ``ds_mhc_post_*`` in interpret mode against
+    ``ops/mhc.py`` ``pre_reference`` / ``post_reference`` on the same
+    operands: the outputs, and the gradient of a weighted sum of BOTH
+    outputs of the pre pass (``raw`` and ``u``) to every operand (``X``,
+    ``phi``, ``b``, ``alpha``; ``X``, ``y``, ``H_post``, ``H_res``).
+    Float32 to 2e-5 of the largest; bfloat16 streams to a rounding. 96
+    tokens are three row tiles of 32: ``dphi`` is summed over them."""
+    x, phi, b, alpha, y = _inputs(t=tokens, dtype=dtype)
+    t, n, c = x.shape
+    ks = jax.random.split(jax.random.PRNGKey(9), 6)
+    w_raw = jax.random.normal(ks[0], (t, n * (n + 2)), F32)
+    w_u = jax.random.normal(ks[1], (t, c), F32)
+    w_out = jax.random.normal(ks[2], (t, n, c), F32)
+    h_post = 2 * jax.random.uniform(ks[3], (t, n), F32)
+    h_res = jax.random.uniform(ks[4], (t, n * n), F32)
+    tol = 2e-5 if dtype == F32 else 1.2e-2
+
+    def pre(fn):
+        def loss(x, phi, b, alpha):
+            raw, u = fn(x, phi, b, alpha, 1e-6)
+            return (raw, u), jnp.sum(raw * w_raw) + jnp.sum(
+                u.astype(F32) * w_u)
+        return jax.jit(lambda *a: (loss(*a)[0], jax.grad(
+            lambda *a: loss(*a)[1], argnums=(0, 1, 2, 3))(*a)))
+
+    def post(fn):
+        loss = lambda *a: jnp.sum(fn(*a).astype(F32) * w_out)  # noqa: E731
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            loss, argnums=(0, 1, 2, 3))(*a)))
+
+    for make, got_fn, want_fn, args in (
+            (pre, kernels.mhc_pre, mhc.pre_reference, (x, phi, b, alpha)),
+            (post, kernels.mhc_post, mhc.post_reference,
+             (x, y, h_post, h_res))):
+        got, want = make(got_fn)(*args), make(want_fn)(*args)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert _err(g.astype(F32), w.astype(F32)) < tol
+
+
+def test_the_op_calls_the_kernels_where_the_backend_is_the_chip(monkeypatch):
+    """``ops/mhc.py`` takes the kernel pair on a TPU and the ``jax.numpy``
+    forms elsewhere; through the kernels (interpreted) the op's four
+    results and the rematted gradient are the ``jax.numpy`` path's."""
+    x, phi, b, alpha, y = _inputs(t=64)
+
+    def loss(x, phi, b, alpha, y):
+        layer = jax.checkpoint(lambda x, phi, b, alpha, y: mhc.mhc_post(
+            x[None], y[None], *mhc.mhc_pre(x[None], phi, b, alpha)[1:3]))
+        return jnp.sum(layer(x, phi, b, alpha, y) ** 2)
+
+    want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        x, phi, b, alpha, y)
+    monkeypatch.setattr(mhc, "_use_kernels", lambda: True)
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss))(x, phi, b, alpha, y))
+    for name in ("ds_mhc_pre_fwd", "ds_mhc_pre_bwd", "ds_mhc_post_fwd",
+                 "ds_mhc_post_bwd"):
+        assert name in jaxpr
+    got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        x, phi, b, alpha, y)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _err(g, w) < 2e-5

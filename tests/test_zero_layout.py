@@ -706,3 +706,74 @@ def test_gated_conv_kernels_and_the_widest_held_backward_compile_for_v5e(
     assert "all-to-all" not in hlo and "all-gather" not in hlo
     with pytest.raises(Exception, match="[Mm]osaic"):
         grad(L.gated_short_conv).lower(*args).compile()
+
+
+def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
+    """PR 56's shapes that interpret mode cannot refuse, compiled by Mosaic
+    for one described v5e chip. The two stream passes of ``ops/mhc.py``
+    at the Xing4 cell's widths (8192 tokens of 4 streams of 3584 bf16,
+    24 coefficients a token in one 128-lane row): a row tile of [256,
+    14336], the masked lane sums that read a coefficient's column, the
+    transposed products of the pre pass's backward and its ``dphi`` block
+    summed across the grid; a rematted gradient holds the pre pass twice
+    (the forward and remat's rerun), the post pass once (its rerun's
+    output is dead: the backward needs X, y and the coefficients alone) and
+    each backward kernel once. Then the held experts' backward at
+    hidden 3584 by an expert of 1024, cut into two column runs
+    (``grouped_matmul.backward_geometry``): the second grid axis, the
+    column blocks of ``dW`` and the copy from the aliased carry by
+    columns."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops import mhc
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, d=bf: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    s, n, c = 8192, 4, 3584
+
+    def sublayer(x, phi, b, alpha):
+        u, h_post, h_res, _ = mhc.mhc_pre(x, phi, b, alpha)
+        return mhc.mhc_post(x, u * 0.5, h_post, h_res)
+
+    layer = jax.checkpoint(sublayer)
+    hlo = jax.jit(jax.grad(
+        lambda *a: 0.5 * jnp.sum(layer(*a).astype(f32) ** 2),
+        argnums=(0, 1, 2, 3))).lower(
+        sd((1, s, n, c)), sd((n * c, 24)), sd((24,)), sd((3,))
+    ).compile().as_text()
+    calls = lambda k: len(re.findall(  # noqa: E731
+        rf"%{k}[.\w]* = .*custom-call", hlo))
+    assert [calls(f"ds_mhc_{k}") for k in (
+        "pre_fwd", "pre_bwd", "post_fwd", "post_bwd")] == [2, 1, 1, 1]
+    with pytest.raises(ValueError, match="multiple of 128"):
+        jax.jit(sublayer).lower(sd((1, s, n, 3600)), sd((n * 3600, 24)),
+                                sd((24,)), sd((3,)))
+
+    tokens, d, f, k, experts, held = 8192, 3584, 1024, 4, 64, 8
+    block = sharded_moe.held_block(tokens, k, experts)
+    chunk = sharded_moe.held_chunk(tokens, k, experts, held, block)
+    tile = grouped_matmul.row_tile(block)
+    assert grouped_matmul.backward_geometry(d, f, tile, 2)[0] == 2
+    routed = jax.checkpoint(lambda x, idx, w, ex: (
+        sharded_moe.held_experts_ffn(x, idx, w, ex, 0, block, True,
+                                     chunk)[0]))
+    hlo = jax.jit(jax.grad(
+        lambda x, w, ex, idx: 0.5 * jnp.sum(
+            routed(x, idx, w, ex).astype(f32) ** 2), argnums=(0, 1, 2))
+    ).lower(
+        sd((tokens, d)), sd((tokens, k), f32),
+        {"w_gate": sd((held, d, f)), "w_up": sd((held, d, f)),
+         "w_down": sd((held, f, d))},
+        sd((tokens, k), jnp.int32)).compile().as_text()
+    for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd", "ds_moe_add_rows"):
+        assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), kernel
