@@ -16,9 +16,13 @@ hidden size, and each sublayer ``F`` (the attention, then the channel
 mixer, each with its own ``phi``, ``b``, ``alpha``) runs as
 (``ops/mhc.py``; arXiv:2512.24880 on arXiv:2409.19606)::
 
-    u, H_post, H_res = mhc_pre(X, phi, b, alpha)      u = sum_i H_pre[i] X[i]
+    u, H_post, H_res, X = mhc_pre(X, phi, b, alpha)   u = sum_i H_pre[i] X[i]
     y  = F(rmsnorm(u))                                as in any pre-norm stack
     X' = mhc_post(X, y, H_post, H_res)                H_res X + H_post^T y
+
+(``mhc_post`` reads the ``X`` that ``mhc_pre`` hands on, so the streams have
+one consumer and their two cotangents are added inside the pre pass's
+backward kernel, not by an op of XLA's over [S, n C].)
 
 The embedding is copied to all ``n`` streams before the first layer
 (scope ``ds.mhc_spread``) and the streams are summed before the final norm
@@ -418,7 +422,7 @@ class Xing4(RoutedStackOfKinds):
         b, s, _ = x.shape
         xs = x.reshape(b, s, c.hc_mult, c.hidden_size)
         with jax.named_scope("ds.mhc"):
-            u, h_post, h_res, residual = mhc.mhc_pre(
+            u, h_post, h_res, residual, xs = mhc.mhc_pre(
                 xs, hc["phi"], hc["b"], hc["alpha"], eps=c.hc_eps,
                 clamp=(float(c.mhc_h_res_clamp_min),
                        float(c.mhc_h_res_clamp_max)),

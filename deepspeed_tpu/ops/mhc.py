@@ -20,23 +20,39 @@ written, ``iters`` unrolled steps; it runs with the TOKENS on the last
 axis ([n, n, T]: a [T, n, n] array's [4, 4] minor dimensions would fill a
 thirty-second of a vector register).
 
-On the chip the two passes over ``X`` are Pallas kernel pairs
-(``ops/pallas/mhc.py``: ``ds_mhc_pre_*`` gives ``raw`` and ``u`` in one
-read of ``X``, ``ds_mhc_post_*`` gives ``X'`` in one read of ``X`` and
-``y``); what lies between them on [T, n (n + 2)] numbers (``H_post``'s
-sigmoid, the clamp, ``exp``, Sinkhorn) is XLA's, under scope
-``ds.mhc_coef``. On any other backend both passes are the ``jax.numpy``
-forms here (``pre_reference``, ``post_reference``), which the kernels are
-tested against.
+On the chip all of it is Pallas kernel pairs (``ops/pallas/mhc.py``):
+``ds_mhc_pre_*`` gives ``raw`` and ``u`` in one read of ``X``,
+``ds_mhc_coef_*`` (scope ``ds.mhc_coef``) makes ``H_post`` and ``H_res`` of
+it, ``ds_mhc_post_*`` gives ``X'`` in one read of ``X`` and ``y``; a
+token's coefficients pass from kernel to kernel as ONE 128-lane float32
+row, forward and backward, with no op of XLA's between. ``mhc_pre`` hands
+``X`` on to ``mhc_post`` (``Handed``), so the streams have ONE consumer and
+the post pass's ``dX`` is added inside ``ds_mhc_pre_bwd``. On any other
+backend the passes and ``coefficients`` are the ``jax.numpy`` forms here,
+which the kernels are tested against.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 _F32 = jnp.float32
+
+
+class Handed(NamedTuple):
+    """What ``mhc_pre`` hands ``mhc_post`` on the chip in ``X``'s place:
+    the streams as the pre pass's kernels passed them on, and the row
+    ``[H_post | H_res | 0]`` that ``h_post`` and ``h_res`` were cut from.
+    ``mhc_post`` reads the row when it is given those two arrays
+    themselves, and builds one from whatever else it is given."""
+    x: jax.Array
+    row: jax.Array
+    h_post: jax.Array
+    h_res: jax.Array
 
 
 def _use_kernels() -> bool:
@@ -95,7 +111,10 @@ def mhc_pre(x, phi, b, alpha, *, eps: float = 1e-6,
     """The pass in front of a sublayer. x [B, S, n, C]; phi [n C,
     n (n + 2)]; b [n (n + 2)]; alpha [3]. Returns (u [B, S, C] in x's
     dtype, H_post [B, S, n] and H_res [B, S, n, n] float32, the Sinkhorn
-    residual: a float32 scalar, no gradient)."""
+    residual: a float32 scalar, no gradient, and the streams for
+    ``mhc_post`` to read: ``x``, or on the chip a ``Handed``; a caller that
+    gives ``mhc_post`` this and not its own ``x`` leaves the streams ONE
+    consumer)."""
     bsz, s, n, c = x.shape
     if phi.shape != (n * c, n * (n + 2)) or b.shape != (n * (n + 2),) \
             or alpha.shape != (3,):
@@ -104,22 +123,34 @@ def mhc_pre(x, phi, b, alpha, *, eps: float = 1e-6,
             f"[{n * (n + 2)}] and alpha [3], not {phi.shape}, {b.shape}, "
             f"{alpha.shape}")
     flat = x.reshape(bsz * s, n, c)
+    on, row = x, None
     if _use_kernels():
         from .pallas import mhc as kernels
-        raw, u = kernels.mhc_pre(flat, phi, b, alpha, float(eps))
+        raw, u, flat = kernels.mhc_pre(flat, phi, b, alpha, float(eps))
+        row, residual = kernels.coefficients(
+            raw, n, float(eps), tuple(float(v) for v in clamp), int(iters))
+        h_post, h_res = row[:, :n], row[:, n:n + n * n]
     else:
         with jax.named_scope("ds.mhc_pre"):
             raw, u = pre_reference(flat, phi, b, alpha, eps)
-    h_post, h_res, residual = coefficients(
-        raw[:, n:], n, eps=eps, clamp=clamp, iters=iters)
-    return (u.reshape(bsz, s, c), h_post.reshape(bsz, s, n),
-            h_res.reshape(bsz, s, n, n), residual)
+        h_post, h_res, residual = coefficients(
+            raw[:, n:], n, eps=eps, clamp=clamp, iters=iters)
+    u, h_post, h_res = (u.reshape(bsz, s, c), h_post.reshape(bsz, s, n),
+                        h_res.reshape(bsz, s, n, n))
+    if row is not None:
+        on = Handed(flat.reshape(x.shape), row, h_post, h_res)
+    return u, h_post, h_res, residual, on
 
 
 def mhc_post(x, y, h_post, h_res):
     """The pass behind a sublayer: ``X' = H_res X + H_post^T y``. x
-    [B, S, n, C], y [B, S, C], H_post [B, S, n], H_res [B, S, n, n];
-    returns X' like x."""
+    [B, S, n, C] (or what ``mhc_pre`` handed on), y [B, S, C], H_post
+    [B, S, n], H_res [B, S, n, n]; returns X' like x."""
+    row = None
+    if isinstance(x, Handed):
+        if h_post is x.h_post and h_res is x.h_res:
+            row = x.row
+        x = x.x
     bsz, s, n, c = x.shape
     if y.shape != (bsz, s, c) or h_post.shape != (bsz, s, n) \
             or h_res.shape != (bsz, s, n, n):
@@ -133,7 +164,9 @@ def mhc_post(x, y, h_post, h_res):
             h_res.reshape(t, n * n).astype(_F32))
     if _use_kernels():
         from .pallas import mhc as kernels
-        out = kernels.mhc_post(*args)
+        if row is None:
+            row = kernels.coefficient_row(*args[2:])
+        out = kernels.mhc_post(*args[:2], row)
     else:
         with jax.named_scope("ds.mhc_post"):
             out = post_reference(*args)

@@ -235,8 +235,12 @@ MHC_SCOPES = (
     #                    of ops/pallas/mhc.py and nothing else on the chip
     #                    (_pre_forward, _pre_backward: the backward rule
     #                    opens the scope itself); the jax.numpy form elsewhere
-    "ds.mhc_coef",     # ops/mhc.py coefficients: H_post's sigmoid, the
-    #                    clamp, exp, the Sinkhorn iterations, the residual
+    "ds.mhc_coef",     # H_post's sigmoid, the clamp, exp, the Sinkhorn
+    #                    iterations, the residual: on the chip the kernels
+    #                    ds_mhc_coef_fwd / ds_mhc_coef_bwd of
+    #                    ops/pallas/mhc.py and nothing else (_coef_forward,
+    #                    _coef_backward: 128-lane rows in and out); the
+    #                    jax.numpy form elsewhere (ops/mhc.py coefficients)
     "ds.mhc_post",     # ops/mhc.py mhc_post: the pass behind a sublayer,
     #                    ds_mhc_post_fwd / ds_mhc_post_bwd of
     #                    ops/pallas/mhc.py (_post_forward, _post_backward)

@@ -539,6 +539,8 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     ("ds.mhc_pre", "ops/pallas/mhc.py", "_pre_forward"),
     ("ds.mhc_pre", "ops/pallas/mhc.py", "_pre_backward"),
     ("ds.mhc_coef", "ops/mhc.py", "coefficients"),
+    ("ds.mhc_coef", "ops/pallas/mhc.py", "_coef_forward"),
+    ("ds.mhc_coef", "ops/pallas/mhc.py", "_coef_backward"),
     ("ds.mhc_post", "ops/mhc.py", "mhc_post"),
     ("ds.mhc_post", "ops/pallas/mhc.py", "_post_forward"),
     ("ds.mhc_post", "ops/pallas/mhc.py", "_post_backward"),

@@ -103,7 +103,7 @@ def test_the_eight_shares_of_a_routed_layer_add_up_through_the_stream_pass():
             x, hc, lambda u: (arch.routed(
                 p, u[0], top_k=k, first=0, renormalise=True,
                 scaling=2.0)[0][None],), **hyper)
-        u, h_post, h_res, _ = mhc.mhc_pre(x, hc["phi"], hc["b"],
+        u, h_post, h_res, _, _ = mhc.mhc_pre(x, hc["phi"], hc["b"],
                                           hc["alpha"], **hyper)
         zero = jnp.zeros_like(u)
         own = mhc.mhc_post(x, zero, h_post, h_res)          # H_res X

@@ -709,16 +709,25 @@ def test_gated_conv_kernels_and_the_widest_held_backward_compile_for_v5e(
 
 
 def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
-    """PR 56's shapes that interpret mode cannot refuse, compiled by Mosaic
-    for one described v5e chip. The two stream passes of ``ops/mhc.py``
-    at the Xing4 cell's widths (8192 tokens of 4 streams of 3584 bf16,
-    24 coefficients a token in one 128-lane row): a row tile of [256,
-    14336], the masked lane sums that read a coefficient's column, the
-    transposed products of the pre pass's backward and its ``dphi`` block
-    summed across the grid; a rematted gradient holds the pre pass twice
-    (the forward and remat's rerun), the post pass once (its rerun's
-    output is dead: the backward needs X, y and the coefficients alone) and
-    each backward kernel once. Then the held experts' backward at
+    """PR 56's and PR 57's shapes that interpret mode cannot refuse,
+    compiled by Mosaic for one described v5e chip. The stream passes of
+    ``ops/mhc.py`` at the Xing4 cell's widths (8192 tokens of 4 streams of
+    3584 bf16, 24 coefficients a token in one 128-lane row): a row tile of
+    [256, 14336], the masked lane sums that read a coefficient's column,
+    the transposed products of the pre pass's backward and its ``dphi``,
+    ``db`` and ``dalpha`` blocks summed across the grid; the coefficient
+    kernels' transposes of a [128, 128] float32 tile and their reads with a
+    sublane stride (a token tile as [8, 128] an entry). A rematted gradient
+    holds the pre pass and the coefficients' forward twice (the forward and
+    remat's rerun), the post pass once (its rerun's output is dead: the
+    backward needs X, y and the coefficients alone) and each backward
+    kernel once. PR 57: ``X`` has ONE consumer, so no ``add`` of two
+    cotangents of the streams is left (``ds_mhc_pre_bwd`` takes the post
+    pass's ``dX`` in, aliased to its own); under ``ds.mhc_coef`` lie the
+    two custom calls and nothing of XLA's; and the rows that pass from
+    kernel to kernel bring no copy staged through VMEM that PR 56's text
+    did not have (59 there, among 6068 instructions; 2 among 266 now). Then the
+    held experts' backward at
     hidden 3584 by an expert of 1024, cut into two column runs
     (``grouped_matmul.backward_geometry``): the second grid axis, the
     column blocks of ``dW`` and the copy from the aliased carry by
@@ -742,8 +751,8 @@ def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
     s, n, c = 8192, 4, 3584
 
     def sublayer(x, phi, b, alpha):
-        u, h_post, h_res, _ = mhc.mhc_pre(x, phi, b, alpha)
-        return mhc.mhc_post(x, u * 0.5, h_post, h_res)
+        u, h_post, h_res, _, on = mhc.mhc_pre(x, phi, b, alpha)
+        return mhc.mhc_post(on, u * 0.5, h_post, h_res)
 
     layer = jax.checkpoint(sublayer)
     hlo = jax.jit(jax.grad(
@@ -754,7 +763,20 @@ def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
     calls = lambda k: len(re.findall(  # noqa: E731
         rf"%{k}[.\w]* = .*custom-call", hlo))
     assert [calls(f"ds_mhc_{k}") for k in (
-        "pre_fwd", "pre_bwd", "post_fwd", "post_bwd")] == [2, 1, 1, 1]
+        "pre_fwd", "pre_bwd", "coef_fwd", "coef_bwd", "post_fwd",
+        "post_bwd")] == [2, 1, 2, 1, 1, 1]
+    assert not re.search(r"= bf16\[(?:1,)?8192,14336\]\S* add\(", hlo)
+    under_coef = [line for line in hlo.splitlines()
+                  if re.search(r'op_name="[^"]*ds\.mhc_coef\b', line)
+                  and " get-tuple-element(" not in line]
+    assert len(under_coef) == 3 and all(
+        re.search(r"%ds_mhc_coef_(?:fwd|bwd)[.\w]* = .*custom-call", line)
+        for line in under_coef), under_coef
+    # PR 56's text: 59 copies into VMEM and 188 asynchronous ones (the
+    # [4, 8192] pieces of XLA's Sinkhorn), 2 and 9 now
+    assert len(re.findall(r"S\(1\)\S* copy\(", hlo)) <= 59
+    assert hlo.count(" copy-start(") <= 188
+    assert len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = ", hlo, re.M)) < 600
     with pytest.raises(ValueError, match="multiple of 128"):
         jax.jit(sublayer).lower(sd((1, s, n, 3600)), sd((n * 3600, 24)),
                                 sd((24,)), sd((3,)))
