@@ -7,6 +7,7 @@ code paths the compiler emits for a real pod slice (SURVEY §4 implication).
 """
 
 import os
+import sys
 
 # Must be set before jax is imported anywhere.
 if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
@@ -27,6 +28,9 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)
+# the families' shared cases live in a helper module (ISSUE 58): their
+# asserts are rewritten as a test module's are
+pytest.register_assert_rewrite("helpers.family_suite")
 
 
 # ---------------------------------------------------------------------
@@ -298,24 +302,31 @@ def pytest_report_header(config):
 # longest first; every other file keeps its collected place behind them. No
 # seconds are kept here (a CPU's seconds are no record): re-take the ORDER
 # from the ten lines a whole run prints at its end (``pytest_terminal_summary``
-# below). ``test_zero_layout.py`` stays one file (one file describes the
-# topology) and so stands first.
+# below; PR 58 took twenty names, by the mean of five runs' junit files: a
+# file late in the alphabet that starts last is the run's tail).
+# ``test_zero_layout.py`` stays one file (one file describes the topology)
+# and so stands first.
 _LONGEST_FIRST = (
     "test_zero_layout.py",
-    "test_kimi_linear_engine.py",
     "test_kda_prep_kernels.py",
-    "test_short_conv_step.py",
     "test_qwen3_next_scan.py",
-    "test_kda_kernels.py",
     "test_kimi_linear_limits.py",
-    "test_short_conv.py",
-    "test_ouro.py",
-    "test_kept_residuals.py",
+    "test_kimi_linear_engine.py",
+    "test_kda_kernels.py",
     "test_kimi_linear_reference.py",
     "test_xing4.py",
-    "test_qwen3_next_reference.py",
-    "test_mellum_reference.py",
-    "test_granite_hybrid.py",
+    "test_kept_scan.py",
+    "test_engine.py",
+    "test_model_families.py",
+    "test_ssd_kernels.py",
+    "test_grouped_matmul.py",
+    "test_qwen3_next_engine.py",
+    "test_mellum.py",
+    "test_inference_v2.py",
+    "test_xing4_limits.py",
+    "test_ouro.py",
+    "test_short_conv_step.py",
+    "test_short_conv.py",
 )
 
 
@@ -382,24 +393,45 @@ def pytest_collection_modifyitems(config, items):
 
 
 def pytest_terminal_summary(terminalreporter, config):
-    """A run over workers says what its wall was made of: the ten files
-    with the most seconds (set-up, call and tear-down of every case, from
-    the reports the controller holds). ``_LONGEST_FIRST`` is re-taken from
-    these lines; nothing is written and nothing reads them."""
+    """A run over workers says what its wall was made of: the sum of the
+    cases' seconds (set-up, call and tear-down of every case, from the
+    reports the controller holds), the ten files and the ten cases with the
+    most. ``_LONGEST_FIRST`` is re-taken from the files' lines and the next
+    issue's table from all three; nothing is written and nothing reads
+    them."""
     if hasattr(config, "workerinput") or not config.getoption(
             "numprocesses", None):
         return
-    seconds = {}
+    files, cases = {}, {}
     for reports in terminalreporter.stats.values():
         for rep in reports:
             if hasattr(rep, "duration") and hasattr(rep, "nodeid"):
                 fname = os.path.basename(rep.nodeid.split("::")[0])
-                seconds[fname] = seconds.get(fname, 0.0) + rep.duration
-    if not seconds:
+                files[fname] = files.get(fname, 0.0) + rep.duration
+                case = rep.nodeid.removeprefix("tests/")
+                cases[case] = cases.get(case, 0.0) + rep.duration
+    if not files:
         return
     terminalreporter.section("the ten longest files (a file is one worker's)")
-    for fname in sorted(seconds, key=seconds.get, reverse=True)[:10]:
-        terminalreporter.write_line(f"{seconds[fname]:8.1f} s  {fname}")
+    for fname in sorted(files, key=files.get, reverse=True)[:10]:
+        terminalreporter.write_line(f"{files[fname]:8.1f} s  {fname}")
+    terminalreporter.section(
+        f"the cases' seconds sum to {sum(files.values()):.0f}; the ten "
+        f"longest cases")
+    for case in sorted(cases, key=cases.get, reverse=True)[:10]:
+        terminalreporter.write_line(f"{cases[case]:8.1f} s  {case}")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _programs_last_a_file():
+    """``helpers/families.py`` ``program(...)`` builds a program once a
+    FILE: a worker runs several files, and an engine one file trained is
+    not the seeded one the next file's pin sums (PR 58: the ``mellum`` row
+    of ``tests/test_step_pins.py`` behind ``tests/test_mellum_engine.py``)."""
+    yield
+    families = sys.modules.get("helpers.families")
+    if families is not None:
+        families._program.cache_clear()
 
 
 @pytest.fixture(autouse=True)

@@ -7,6 +7,8 @@ else)."""
 
 import re
 
+from helpers.families import program
+
 _TABLE = re.compile(
     r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
     r"(?:.+\n)*\n?", re.M)
@@ -23,25 +25,13 @@ def program_only(hlo: str) -> str:
                   lambda m: ids.setdefault(m.group(), f"%{len(ids)}"), text)
 
 
-def step_hlo(engine, batch) -> str:
-    """The engine's compiled train step as text. A whole compile: a file
-    that reads it in several tests keeps it in a module's fixture."""
-    return engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
-
-
-def bare_step(engine, batch, ds_config, monkeypatch, hlo) -> tuple[str, str]:
-    """(the engine's compiled step ``hlo``, the same step built again with
-    every ``jax.named_scope`` a null context), both as ``program_only``."""
-    import contextlib
-
-    import jax
-
-    import deepspeed_tpu as ds
-    monkeypatch.setattr(jax, "named_scope",
-                        lambda name: contextlib.nullcontext())
-    bare, *_ = ds.initialize(model=engine.module, config=dict(ds_config))
-    return program_only(hlo), program_only(step_hlo(bare, batch))
+def assert_scopes_are_metadata(family: str) -> None:
+    """The family's compiled step (``families.program``) and the same step
+    built again with every ``jax.named_scope`` a null context are one
+    program once ``metadata={...}`` is taken out."""
+    named = program_only(program(family).hlo)
+    assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
+    assert program_only(program(family, patch="bare").hlo) == named
 
 
 def assert_scope_is_the_kernels(hlo: str, scope: str, stem: str, mixer: str,

@@ -10,11 +10,11 @@ callers of the short
 convolution's kernels (ISSUE 43) held to their parents' loss and gradients
 ``tests/test_short_conv_step.py``'s (PR 45: a file is one worker's under
 ``--dist loadfile``, and this one was 618 s); what they share is
-``tests/helpers/family_cases.py``. A CPU run shows results and counts,
+``tests/helpers/families.py``. A CPU run shows results and counts,
 never a time."""
 
+import functools
 import json
-import re
 import subprocess
 import sys
 
@@ -23,21 +23,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
 from deepspeed_tpu.models import GraniteHybrid
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.ops.ssd import chunk_ssd, recurrent_ssd
-from deepspeed_tpu.telemetry import scopes
 
-from helpers import hlo_text  # noqa: E402  (tests/helpers)
-from helpers.family_cases import DS_CONFIG as _DS_CONFIG
-from helpers.family_cases import GRANITE_CONFIG as CONFIG
-from helpers.family_cases import (BENCH, _batch, _err,  # noqa: F401
-                                  _telemetry_isolation)
+from helpers.families import config_of, tiny
+from helpers.families import (BENCH, _batch, _err,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import granite_hybrid as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
-from helpers.family_cases import granite_tiny as _tiny
-from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
+#                                           on sys.path by families)
+from lib import modelspec  # noqa: E402  (benchmark/, by families)
+
+CONFIG = config_of("granite_hybrid")
+_tiny = functools.partial(tiny, "granite_hybrid")
 
 
 # ---- the chunked scan against the recurrence -------------------------------
@@ -169,92 +167,6 @@ def test_required_operations_by_hand():
     flash = arch.gqa_flash_call_cost(m, 1, 8192, backward=False)
     assert flash["flops"] == 4 * 64 * 32 * 8192 * 8193 // 2
     assert arch.least_seconds(flash, peaks)[1] == "compute"
-
-
-# ---- the engine ------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def granite_engine():
-    model = _tiny(attn_impl="flash", loss_chunk=64)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    return engine, _batch(model, b=8)
-
-
-@pytest.fixture(scope="module")
-def hlo(granite_engine):
-    return hlo_text.step_hlo(*granite_engine)
-
-
-def test_engine_trains_through_the_compiled_step(granite_engine):
-    """``ds.initialize`` and the engine's compiled step as for every other
-    family: no ``with_stats``, no ``after_step``, a falling loss, and the
-    tied table's gradient reaches it from the lookup and from the head."""
-    engine, batch = granite_engine
-    assert not hasattr(engine.module, "after_step")
-    before = np.asarray(engine.state["master"]["embed"]["tokens"]).copy()
-    losses = [float(engine.train_batch(batch)) for _ in range(4)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert "lm_head" not in engine.state["master"]
-    moved = np.abs(np.asarray(
-        engine.state["master"]["embed"]["tokens"]) - before)
-    assert np.all(moved.max(axis=1) > 0)    # every row: the head's share
-
-
-def test_step_scopes_are_the_lists(hlo):
-    found = set()
-    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
-        found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
-    assert found == (set(scopes.DEVICE_SCOPES) | set(scopes.SSM_SCOPES)
-                     | set(scopes.MIXER_SCOPES))
-    by_op = scopes.op_scopes(hlo)
-    paths = {p for p in by_op.values() if p}
-    for scope in ("ds.mamba/ds.ssd", "ds.attn/ds.flash_fwd", "ds.mlp"):
-        assert any(p.startswith("fwd:ds.layers") and scope in p
-                   for p in paths), scope
-    for scope in ("ds.mamba/ds.ssd", "ds.flash_bwd", "ds.mlp"):
-        assert any(p.startswith("bwd:ds.layers") and scope in p
-                   for p in paths), scope
-    # the scan stands inside the mixer's scope (but for a dozen broadcasts
-    # of its constants, the mask and the zero state, which remat's trace
-    # names by the innermost scope alone)
-    scan = [p for p in by_op.values() if "ds.ssd" in p]
-    assert sum("ds.mamba" in p for p in scan) > 0.99 * len(scan)
-
-
-def test_the_mixer_parts_lie_inside_ds_mamba_and_no_kind_is_unknown(
-        hlo):
-    """ISSUE 36: the convolution and what lies before and after the scan
-    are named inside ds.mamba, straight under it in both directions and
-    never inside the attention layer or the FFN (the compiler moves an
-    instruction or two of them into the scan's loop, whose path then
-    holds theirs); the table of kinds knows every instruction of the
-    step. ISSUE 43: the convolution is a kernel pair that holds the SiLU
-    too."""
-    work = scopes.op_work(hlo)
-    paths = {row["scope"] for row in work.values()}
-    for part in scopes.MIXER_SCOPES:
-        mine = {p for p in paths if re.search(rf"{re.escape(part)}\b", p)}
-        assert {f"{d}:ds.layers/ds.mamba/{part}"
-                for d in ("fwd", "bwd")} <= mine, (part, mine)
-        if part == "ds.conv":   # below: the interpreted kernels' constants
-            continue
-        assert all("ds.layers/ds.mamba/" in p and "ds.attn" not in p
-                   and "ds.mlp" not in p for p in mine), (part, mine)
-    hlo_text.assert_conv_scope_is_the_kernels(
-        hlo, "ds.mamba", ("ds.attn", "ds.mlp"))
-    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
-    assert not unknown, unknown
-
-
-def test_the_named_scopes_are_metadata_and_nothing_else(
-        granite_engine, hlo, monkeypatch):
-    """The step compiled with every ``jax.named_scope`` a null context is
-    the same optimized program once ``metadata={...}`` is taken out."""
-    named, bare = hlo_text.bare_step(*granite_engine, _DS_CONFIG,
-                                     monkeypatch, hlo)
-    assert re.search(r"\bds\.[a-z_]+", named) is None     # all metadata
-    assert bare == named
 
 
 def test_importing_the_package_loads_no_state_space_scan():

@@ -4,20 +4,24 @@ from the published equations. These cases were
 under ``--dist loadfile``). A CPU run shows results and counts, never a
 time."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from deepspeed_tpu.models import GraniteHybrid
 
-from helpers.family_cases import GRANITE_CONFIG as CONFIG
-from helpers.family_cases import _batch
+from helpers.families import config_of, tail_loss_grads, tiny, weights
+from helpers.families import _batch
 from architectures import granite_hybrid as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
-from helpers.family_cases import granite_tiny as _tiny
-from helpers.family_cases import granite_weights as _weights
-from kinds import train_job  # noqa: E402  (benchmark/, by family_cases)
+#                                           on sys.path by families)
+from kinds import train_job  # noqa: E402  (benchmark/, by families)
 from lib import modelspec  # noqa: E402
+
+CONFIG = config_of("granite_hybrid")
+_tiny = functools.partial(tiny, "granite_hybrid")
+_weights = functools.partial(weights, "granite_hybrid")
 
 
 class _UntiedHead(GraniteHybrid):
@@ -54,10 +58,11 @@ def test_the_cells_limits_catch_a_planted_fault(fault):
     m = modelspec.reference_model(arch, right)
     with jax.default_matmul_precision("highest"):
         want_loss, want_tail = arch.reference(params, tokens, targets, m, 32)
-        got_tail = model.apply(params, tokens)[:, -32:]
         if fault == "targets_off_by_one":
             targets = jnp.roll(targets, 1, axis=1)
-        got_loss = float(model.loss(params, (tokens, targets)))
+        got_tail, got_loss, _ = tail_loss_grads(model, params, tokens,
+                                                targets, grads=False)
+    got_loss = float(got_loss)
     numbers = train_job.tail_numbers(got_tail, want_tail, None)
     ok = train_job.decide(numbers, want_loss, got_loss, CONFIG["check"])
     assert ok == (fault is None), numbers

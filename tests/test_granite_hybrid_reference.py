@@ -13,13 +13,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from helpers.family_cases import (_batch, _err,  # noqa: F401
-                                  _telemetry_isolation)
+from helpers.families import tiny, weights
+from helpers.families import (_batch, _err,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import granite_hybrid as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
-from helpers.family_cases import granite_tiny as _tiny
-from helpers.family_cases import granite_weights as _weights
-from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
+#                                           on sys.path by families)
+from lib import modelspec  # noqa: E402  (benchmark/, by families)
+
+_tiny = functools.partial(tiny, "granite_hybrid")
+_weights = functools.partial(weights, "granite_hybrid")
 
 
 def _ref_loss(params, tokens, targets, m):

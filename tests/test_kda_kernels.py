@@ -19,11 +19,11 @@ from deepspeed_tpu.ops import kda as kda_ops
 from deepspeed_tpu.ops.kda import chunk_kda, recurrent_kda
 from deepspeed_tpu.ops.pallas import kda as kda_kernels
 
-from helpers.family_cases import (BENCH, _as_bf16, _close,  # noqa: F401
-                                  _drop_compiled_programs, _kda_inputs,
-                                  _walk_eqns)
+from helpers.families import (BENCH, _as_bf16, _close,  # noqa: F401
+                               _kda_inputs,
+                               _walk_eqns)
 from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
+#                                           on sys.path by families)
 
 
 # ---- KDA: the chunked form against the recurrence --------------------------

@@ -9,97 +9,33 @@ holds no kernel of the scan, and the backward's own rerun of a head group
 is the one left (the scan alone and its gauge: ``tests/test_kept_scan.py``). The kernels
 are interpreted here: a CPU run shows counts and bits, never a time."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.models import Mistral, transformer
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-from helpers.family_cases import DS_CONFIG as _DS_CONFIG
-from helpers.family_cases import _telemetry_isolation  # noqa: F401
-from helpers.kept_cases import (REMATTED, keep_nothing, kernel_calls, tiny,
-                                value_and_grads)
-
-
-@functools.cache
-def _train_step_calls(family, nothing_kept=False):
-    """The kernels of the engine's train step (eight virtual devices, so
-    the scans run per shard), once a family and policy for the cases that
-    read them."""
-    with pytest.MonkeyPatch.context() as patch:
-        if nothing_kept:
-            keep_nothing(patch)
-        model = tiny(family)
-        assert model.config.remat and model.config.remat_policy == \
-            "nothing_saveable"
-        engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-        tok = np.zeros((8, model.config.max_seq_len), np.int32)
-        return kernel_calls(engine._train_step, engine.state,
-                            engine._put_batch((tok, tok)))
-
-
-@pytest.mark.parametrize("family", list(REMATTED))
-def test_a_rematted_step_runs_the_forward_kernel_once_an_application(family):
-    """The engine's train step of each rematted family holds one
-    ``ds_flash_fwd`` and one ``ds_flash_bwd`` an attention layer
-    application; the same step built on ``policy=None`` holds the forward
-    kernel twice."""
-    applications = REMATTED[family][2]
-    calls = _train_step_calls(family)
-    assert calls["ds_flash_fwd"] == calls["ds_flash_bwd"] == applications
-    calls = _train_step_calls(family, nothing_kept=True)
-    assert calls["ds_flash_bwd"] == applications
-    assert calls["ds_flash_fwd"] == 2 * applications
-
-
-@pytest.mark.parametrize("family, runs", [("kimi_linear", 2),
-                                          ("qwen3_next", 3)])
-def test_a_rematted_step_runs_the_scan_twice_where_groups_are_a_loop(
-        family, runs):
-    """Kimi-Linear's KDA heads run in head groups under a loop: a layer's
-    forward and its groups' own rerun in the backward are what is left, the
-    preparation's forward and ``ds_kda_fwd`` (its ``o`` form once, its
-    checkpoint form once) twice a backward kernel, where the step built on
-    ``policy=None`` holds them three times (the layer's rerun made ``o``
-    again). Qwen3-Next's Gated DeltaNet heads run in ONE group, which keeps
-    nothing: three times under either. Every other kernel but
-    ``ds_flash_fwd`` runs as often as under ``policy=None``: the backward
-    still needs q, k, v, g and beta, so the convolutions rerun as
-    before, and the gated norm behind the scan (ISSUE 55) runs its forward
-    twice (the forward; remat's rerun for the output matmul) and its
-    backward once a layer under either."""
-    kept = _train_step_calls(family)
-    rerun = _train_step_calls(family, nothing_kept=True)
-    assert kept["ds_kda_prep_bwd"] == kept["ds_kda_bwd"] > 0
-    assert (kept["ds_gated_norm_fwd"], kept["ds_gated_norm_bwd"]) == (
-        2 * kept["ds_kda_bwd"], kept["ds_kda_bwd"])
-    for fwd, bwd in (("ds_kda_prep_fwd", "ds_kda_prep_bwd"),
-                     ("ds_kda_fwd", "ds_kda_bwd")):
-        assert kept[fwd] == runs * kept[bwd]
-        assert rerun[fwd] == 3 * rerun[bwd] == 3 * kept[bwd]
-    moved = {"ds_kda_prep_fwd", "ds_kda_fwd", "ds_flash_fwd"}
-    assert {k: n for k, n in kept.items() if k not in moved} == \
-        {k: n for k, n in rerun.items() if k not in moved}
+from helpers.families import _telemetry_isolation  # noqa: F401
+from helpers.families import flat_grads, kernel_calls, program
 
 
 @pytest.mark.parametrize("family", ["mellum", "ouro", "qwen3_next"])
-def test_the_gradients_are_policy_nones_bit_for_bit(family, monkeypatch):
+def test_the_gradients_are_policy_nones_bit_for_bit(family):
     """The kept ``o`` and ``lse`` are the bits the rerun would have made:
     every gradient of the loss is the one ``policy=None`` gives
     (Qwen3-Next's one head group keeps nothing of its scans: its gated
-    attention layer's flash kernels do)."""
-    kept = value_and_grads(family)
-    keep_nothing(monkeypatch)
-    rerun = value_and_grads(family)
-    assert kept[0] == rerun[0]
-    for path, got in kept[1].items():
-        np.testing.assert_array_equal(got, rerun[1][path], err_msg=path)
+    attention layer's flash kernels do: it is held at two such layers, the
+    row's ``kept`` cut, ISSUE 58)."""
+    kept, rerun = (program(family, "kept", patch=patch
+                           ).loss_and_grads(1) for patch in
+                   (None, "keep_nothing"))
+    assert np.isfinite(kept[0]) and kept[0] == rerun[0]
+    want = flat_grads(rerun[1])
+    for path, got in flat_grads(kept[1]).items():
+        np.testing.assert_array_equal(got, want[path], err_msg=path)
 
 
 def test_the_gauge_reads_what_one_call_declares():

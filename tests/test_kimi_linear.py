@@ -11,7 +11,9 @@ the KDA kernels' ``tests/test_kda_kernels.py`` and
 parents' programs ``tests/test_kimi_linear_limits.py`` (PR 45), the whole
 model against the float32 reference
 ``tests/test_kimi_linear_reference.py`` (PR 50); what they share is
-``tests/helpers/family_cases.py``."""
+``tests/helpers/families.py``."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,12 +22,17 @@ import pytest
 
 from deepspeed_tpu.models import KimiLinear
 from deepspeed_tpu.models.stack import stack_plan
+from deepspeed_tpu.ops.kda import chunk_kda, recurrent_kda
+from deepspeed_tpu.ops.pallas import kda as kda_kernels
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-from helpers.family_cases import _close, _telemetry_isolation  # noqa: F401
+from helpers.families import tiny
+from helpers.families import (_as_bf16, _close, _kda_inputs,  # noqa: F401
+                              _telemetry_isolation)
 from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
-from helpers.family_cases import kimi_tiny as _tiny
+#                                           on sys.path by families)
+
+_tiny = functools.partial(tiny, "kimi_linear")
 
 
 # ---- MLA: the flash path (key 24, value 16) against plain softmax ----------
@@ -96,3 +103,41 @@ def test_published_preset_counts():
     whole = KimiLinear(size="48b-a3b").config
     assert 47e9 < whole.num_params() < 50e9     # "48B"
     assert 2.5e9 < whole.num_active_params() < 3.6e9    # "A3B"
+
+
+# ---- the decays at which the jax.numpy preparation overflowed ---------------
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("per_token", [6.0, 12.0, 20.0])
+def test_chunked_kda_stays_finite_where_a_channel_forgets_in_one_token(
+        per_token, dtype):
+    """Past a log-decay of -5.5 a token a 16-row block's own columns
+    overflowed float32 and a training run on the chip went NaN (PR 31):
+    8 rows, a clamped exponent and an exact diagonal hold any decay, and
+    the kernels (PR 32) get their operands from those; in bfloat16 as the
+    cell runs them, too. Two heads are one grid step of the preparation:
+    their inverses run side by side in one product (PR 44)."""
+    args = _kda_inputs(b=1, s=128, h=2)
+    assert kda_kernels._prep_geometry(args[0], args[2], 64)[-1] == 2
+    g = args[3].at[..., 0].set(-per_token).at[..., 1].set(-per_token / 2)
+    args[3] = g
+    tol_o, tol_g = 1e-5, 2e-5
+    if dtype == "bfloat16":
+        args, tol_o, tol_g = _as_bf16(args), 2e-2, 4e-2
+
+    def both(f):
+        """``f``'s float32 output and the gradients of its sum, one
+        program (eager, every line round the kernels compiles alone)."""
+        def total(*a):
+            out = f(*a).astype(jnp.float32)
+            return jnp.sum(out), out
+        return jax.jit(jax.value_and_grad(
+            total, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    (_, want), want_g = both(recurrent_kda)
+    (_, got), grads = both(chunk_kda)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    _close(got, want, tol_o, "forward")
+    for name, a, b in zip("qkvgb", grads, want_g):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol_g,
+               f"d{name}")

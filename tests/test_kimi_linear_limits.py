@@ -6,6 +6,7 @@ the sigmoid router and a held share of its experts by hand, and the other
 architectures' steps held to their parents' programs. A CPU run shows
 results and counts, never a time."""
 
+import functools
 import re
 
 import jax
@@ -20,13 +21,15 @@ from deepspeed_tpu.moe.sharded_moe import (balance_bias, held_experts_ffn,
 from deepspeed_tpu.ops import kda as kda_ops
 from deepspeed_tpu.ops.pallas import kda as kda_kernels
 
-from helpers.family_cases import (BENCH, _batch, _close,  # noqa: F401
-                                  _telemetry_isolation)
+from helpers.families import config_of, tiny
+from helpers.families import (BENCH, _batch, _close,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import kimi_linear as arch  # noqa: E402  (benchmark/,
-#                                           on sys.path by family_cases)
-from helpers.family_cases import kimi_ref_loss as _ref_loss
-from helpers.family_cases import kimi_tiny as _tiny
-from lib import modelspec  # noqa: E402  (benchmark/, by family_cases)
+#                                           on sys.path by families)
+from helpers.families import kimi_ref_loss as _ref_loss
+from lib import modelspec  # noqa: E402  (benchmark/, by families)
+
+_tiny = functools.partial(tiny, "kimi_linear")
 
 
 # ---- the reference's tail logits, the cell's loss limit ---------------------
@@ -58,18 +61,16 @@ def test_the_cells_loss_limit_catches_a_planted_fault(fault):
     arithmetic: the program's chunked loss passes it, a loss whose targets
     are shifted once more, or whose mean leaves one chunk's positions out
     of the count, does not (the decision is the benchmark's own)."""
-    import json
-
     from kinds import train_job
-    check = json.loads((BENCH / "configs" /
-                        "kimi-linear-48b-ep32-zero3-1chip.json").read_text()
-                       )["check"]
+    check = config_of("kimi_linear")["check"]
     model = _tiny(loss_chunk=64)
     params = model.init(jax.random.PRNGKey(3))
     tokens, targets = _batch(model)
     m = modelspec.reference_model(arch, model, check)
     with jax.default_matmul_precision("highest"):
-        want = float(_ref_loss(params, tokens, targets, m))
+        # jitted: eager, every line of the reference compiles alone
+        want = float(jax.jit(lambda *a: _ref_loss(*a, m))(
+            params, tokens, targets))
         if fault == "targets_off_by_one":
             targets = jnp.roll(targets, 1, axis=1)
         got = float(jax.jit(model.loss)(params, (tokens, targets)))

@@ -9,6 +9,7 @@ model's loss, logits and gradients against the reference
 ``tests/test_lfm2_moe_reference.py``'s (a file is one worker's under
 ``--dist loadfile``). A CPU run shows results and counts, never a time."""
 
+import functools
 import json
 import sys
 
@@ -22,15 +23,17 @@ from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.ops import layers as L
 
-from helpers.family_cases import LFM_CONFIG as CONFIG
-from helpers.family_cases import (BENCH, _close,  # noqa: F401
-                                  _telemetry_isolation, lfm_right)
-from helpers.family_cases import lfm_tiny as _tiny
+from helpers.families import config_of, right, tiny
+from helpers.families import (BENCH, _close,  # noqa: F401
+                               _telemetry_isolation)
+
+CONFIG = config_of("lfm2_moe")
+_tiny = functools.partial(tiny, "lfm2_moe")
 
 if str(BENCH / "tests") not in sys.path:
     sys.path.insert(0, str(BENCH / "tests"))
 from architectures import lfm2_moe as arch  # noqa: E402  (benchmark/, on
-#                                         sys.path by family_cases)
+#                                         sys.path by families)
 from kinds import train_job  # noqa: E402
 from lfm_control import FAULTS, plant  # noqa: E402
 from lib import modelspec  # noqa: E402
@@ -45,7 +48,7 @@ def test_the_cells_limits_catch_a_planted_fault(fault):
     reference's: the program passes, each departure
     ``benchmark/tests/lfm_control.py`` plants (the same it plants on the
     chip) does not."""
-    params, tokens, targets, (want_loss, want_tail, counted), _ = lfm_right()
+    params, tokens, targets, (want_loss, want_tail, counted), _ = right("lfm2_moe")
     model = _tiny()
     if fault is not None:
         model = plant(model, fault)

@@ -13,22 +13,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from helpers.family_cases import LFM_CONFIG as CONFIG
-from helpers.family_cases import (_err, _telemetry_isolation,  # noqa: F401
-                                  lfm_right)
-from helpers.family_cases import lfm_tiny as _tiny
+from helpers.families import config_of, right, tail_loss_grads, tiny
+from helpers.families import (_err, _telemetry_isolation)  # noqa: F401
 from architectures import lfm2_moe as arch  # noqa: E402  (benchmark/, on
-#                                         sys.path by family_cases)
+#                                         sys.path by families)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
+
+CONFIG = config_of("lfm2_moe")
+_tiny = functools.partial(tiny, "lfm2_moe")
 
 
 @functools.lru_cache(maxsize=None)
 def _right():
-    """``lfm_right`` (boosted weights, a batch of two sequences, the
+    """``right("lfm2_moe")`` (boosted weights, a batch of two sequences, the
     float32 reference's loss, tail logits and mask) with the reference's
     gradient in the place of its model; the head is the table."""
-    params, tokens, targets, want, m = lfm_right()
+    params, tokens, targets, want, m = right("lfm2_moe")
 
     def loss(params, tokens, targets):
         hidden, _ = arch._forward(params, tokens, m)
@@ -67,20 +68,17 @@ def test_loss_logits_and_gradients_match_the_float32_reference(variant):
                 params, tokens, targets, m, 32)
         low = jax.tree_util.tree_map(
             lambda w: w.astype(jnp.bfloat16), params)
-        numbers = train_job.tail_numbers(
-            model.apply(low, tokens)[:, -32:], want_tail, counted)
-        got = float(model.loss(low, (tokens, targets)))
+        got_tail, got, _ = tail_loss_grads(model, low, tokens, targets,
+                                           grads=False)
+        numbers = train_job.tail_numbers(got_tail, want_tail, counted)
+        got = float(got)
         assert abs(got - want) <= 5e-3 * want
         assert numbers["logits_err_max"] < 5e-2, numbers
         assert numbers["logits_err_rms"] < 2e-2, numbers
         return
     with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain_f32":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
+        got_tail, got, got_g = tail_loss_grads(
+            model, params, tokens, targets, grads=variant != "plain_f32")
     assert abs(float(got) - want) <= 2e-5 * want
     assert _err(got_tail, want_tail) < 5e-4
     if got_g is None:

@@ -9,17 +9,15 @@ program). The whole model's loss and gradients against that reference are
 ``tests/test_step_pins.py``'s. A CPU run shows results and counts, never a
 time."""
 
+import functools
 import json
 import math
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu import telemetry
 from deepspeed_tpu.models import Mellum, ModelConfig
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.moe import sharded_moe
@@ -28,24 +26,25 @@ from deepspeed_tpu.moe.sharded_moe import (_held_layout, held_block,
                                            sigmoid_top_k, softmax_top_k,
                                            top_k_gating)
 from deepspeed_tpu.ops import layers as L
-from deepspeed_tpu.telemetry import scopes
 
-from helpers.family_cases import DS_CONFIG as _DS_CONFIG
-from helpers.family_cases import MELLUM_CONFIG as CONFIG
-from helpers.family_cases import (_batch, _err,  # noqa: F401
-                                  _telemetry_isolation, mellum_right)
-from helpers.family_cases import mellum_tiny as _tiny
+from helpers import families
+from helpers.families import config_of, tiny
+from helpers.families import (_batch, _err,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import mellum as arch  # noqa: E402  (benchmark/, on
-#                                           sys.path by family_cases)
+#                                           sys.path by families)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
+
+CONFIG = config_of("mellum")
+_tiny = functools.partial(tiny, "mellum")
 
 PUBLISHED_YARN = CONFIG["rope_parameters"]["full_attention"]
 
 
 @pytest.fixture(scope="module")
 def right():
-    return mellum_right(16)
+    return families.right("mellum", 16, loss_chunk=64)
 
 
 # ---- the rotary tables -----------------------------------------------------
@@ -365,108 +364,3 @@ def test_required_operations_by_hand():
     assert moe["flops"] == 4 * 32768 * 6 * 2304 * 896
     assert arch.moe_call_cost(m, 1, 16384, backward=False, rows=100)[
         "flops"] == 4 * 100 * 6 * 2304 * 896
-
-
-# ---- the engine ------------------------------------------------------------
-@pytest.fixture(scope="module")
-def mellum_engine():
-    model = _tiny(attn_impl="flash", loss_chunk=64)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    return engine, _batch(model, b=8)
-
-
-def test_engine_trains_and_after_step_moves_no_weight(mellum_engine):
-    """``ds.initialize`` and the compiled step as for the other routed
-    family: ``loss(with_stats=True)``, an ``after_step`` that returns the
-    weights it was given, a falling loss, and the held experts' counts as
-    device scalars of the step."""
-    engine, batch = mellum_engine
-    params = {"layers": {"tail": {}}}
-    assert engine.module.after_step(params, {})[0] is params
-    assert not hasattr(engine.module, "optimizer_frozen")
-    losses = [float(engine.train_batch(batch)) for _ in range(4)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
-    m = engine._last_metrics
-    assert int(m["moe_held_calls"]) == 4 and int(m["moe_held_experts"]) == 16
-    assert int(m["moe_held_rows"]) == int(m["moe_held_done"]) > 0
-    # 8 x 128 tokens x top-8 of 64 experts: 128 a held expert if even
-    assert 96 < int(m["moe_held_rows"]) / (4 * 16) < 160
-    assert int(m["moe_held_block"]) == held_block(8 * 128, 8, 64) == 256
-    assert 4 * 16 <= int(m["moe_held_blocks"]) <= 4 * 16 * 2
-    assert 0 <= int(m["moe_load_min"]) < 128 < int(m["moe_load_max"])
-
-
-def test_traced_and_untraced_steps_are_one_program_and_the_counts_land(
-        mellum_engine):
-    engine, batch = mellum_engine
-    text = lambda e: e._train_step.lower(  # noqa: E731
-        e.state, e._put_batch(batch)).as_text()
-    untraced = text(engine)
-    assert "callback" not in untraced
-    telemetry.configure()
-    traced, *_ = ds.initialize(model=engine.module, config=dict(_DS_CONFIG))
-    assert text(traced) == untraced
-    for _ in range(3):
-        traced.train_batch(batch)
-    reg = telemetry.get_registry()
-    value = lambda name: reg.get(name).value()  # noqa: E731
-    assert value("ds_moe_held_calls_total") == 2 * 4    # one step behind
-    assert value("ds_moe_dropped_rows_total") == 0
-    assert value("ds_moe_held_experts") == 16
-    rows, blocks = (value("ds_moe_held_rows_total"),
-                    value("ds_moe_held_blocks_total"))
-    assert value("ds_moe_held_block_rows") == 256
-    assert 0.0 < 1 - rows / (blocks * 256) < 0.75       # the padding
-    assert value("ds_moe_load_step_min") < 128 < value("ds_moe_load_step_max")
-    assert (value("ds_moe_held_tokens_step_min") <= rows / (8 * 16)
-            <= value("ds_moe_held_tokens_step_max"))
-
-
-def test_step_scopes_are_the_lists_and_the_kernels_have_their_kind(
-        mellum_engine):
-    """Both kernels' scopes lie inside the scope of their layer's kind in
-    the forward and in the backward rule, so one kind's kernel time can be
-    read alone; the rotation is named inside both, and in remat's rerun,
-    which holds no forward kernel (PR 47: the layer keeps its ``o`` and
-    ``lse``)."""
-    engine, batch = mellum_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
-    found = set()
-    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
-        found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
-    assert found == (set(scopes.DEVICE_SCOPES) - {"ds.attn", "ds.mlp"}
-                     | set(scopes.WINDOW_SCOPES)
-                     | {"ds.moe_router", "ds.moe_experts", "ds.moe_gmm_fwd",
-                        "ds.moe_gmm_bwd", "ds.moe_add_rows"})
-    work = scopes.op_work(hlo)
-    paths = {row["scope"] for row in work.values() if row["scope"]}
-    for kind in ("swa", "full"):
-        for want in (f"fwd:ds.layers/ds.attn_{kind}/ds.flash_fwd",
-                     f"bwd:ds.layers/ds.attn_{kind}/ds.flash_bwd",
-                     f"fwd:ds.layers/ds.attn_{kind}/ds.rope",
-                     f"bwd:ds.layers/ds.attn_{kind}/ds.rope"):
-            assert want in paths, want
-        assert f"bwd:ds.layers/ds.attn_{kind}/ds.flash_fwd" not in paths
-    kernels = [p for p in paths if "ds.flash_" in p]
-    assert all(re.search(r"ds\.attn_(swa|full)/ds\.flash_", p)
-               for p in kernels), kernels
-    for scope in ("ds.moe_router", "ds.moe_experts"):
-        assert {d for d in ("fwd", "bwd") if any(
-            p.startswith(d + ":ds.layers") and scope in p
-            for p in paths)} == {"fwd", "bwd"}, scope
-    # the grouped-matmul kernels (interpreted here) inside the scope
-    # moe_ms.mellum reads. Remat's rerun holds no forward sweep: the
-    # backward rule keeps the inputs alone and nothing else of the layer
-    # reads the sweep's result, so the compiler drops it
-    for want in ("fwd:ds.layers/ds.moe_experts/ds.moe_gmm_fwd",
-                 "fwd:ds.layers/ds.moe_experts/ds.moe_add_rows",
-                 "bwd:ds.layers/ds.moe_experts/ds.moe_gmm_bwd",
-                 "bwd:ds.layers/ds.moe_experts/ds.moe_add_rows"):
-        assert want in paths, want
-    # what the cell's attn_ms.mellum reads: the layer less its kernels
-    rx = re.compile(r"ds\.attn_(swa|full)\b(?!.*ds\.flash_)")
-    assert any(rx.search(p) for p in paths)
-    assert not any(rx.search(p) for p in kernels)
-    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
-    assert not unknown, unknown

@@ -13,19 +13,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from helpers.family_cases import (_err, _reference_grads,  # noqa: F401
-                                  _telemetry_isolation, mellum_right)
-from helpers.family_cases import mellum_tiny as _tiny
+from helpers.families import right, tail_loss_grads, tiny
+from helpers.families import (_err, _reference_grads,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import mellum as arch  # noqa: E402  (benchmark/, on
-#                                           sys.path by family_cases)
+#                                           sys.path by families)
+
+_tiny = functools.partial(tiny, "mellum")
 
 
 @functools.lru_cache(maxsize=None)
 def _right(held: int):
-    """``mellum_right`` (boosted weights with ``held`` of the 64 experts
+    """``right("mellum")`` (boosted weights with ``held`` of the 64 experts
     held, a batch, the float32 reference's loss, tail logits and mask) with
     the reference's gradient in the place of its model."""
-    params, tokens, targets, want, m = mellum_right(held)
+    params, tokens, targets, want, m = right("mellum", held, loss_chunk=64)
     grads = _reference_grads(arch, params, tokens, targets, m)
     return params, tokens, targets, want, grads
 
@@ -49,12 +51,8 @@ def test_loss_logits_and_gradients_match_the_float32_reference(variant):
     model = _tiny(moe_held_experts=held, **kw)
     params, tokens, targets, (want, want_tail, _), want_g = _right(held)
     with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
+        got_tail, got, got_g = tail_loss_grads(
+            model, params, tokens, targets, grads=variant != "plain")
     assert abs(float(got) - want) <= 2e-5 * want
     assert _err(got_tail, want_tail) < 5e-4
     if got_g is None:

@@ -62,9 +62,11 @@ def test_train_only_family_init_loss_rules_and_refusal(cls):
     assert model.config.num_params() == n_actual, cls.__name__
     match_rules(model.partition_rules(), params)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 512)
-    loss = model.loss(params, (tokens[:, :-1], tokens[:, 1:]))
+    # jitted: eager, every line of a stack of kinds compiles alone
+    loss = jax.jit(model.loss)(params, (tokens[:, :-1], tokens[:, 1:]))
     assert jnp.isfinite(loss)
-    assert model.apply(params, tokens[:, :-1]).shape == (2, 128, 512)
+    assert jax.jit(model.apply)(params, tokens[:, :-1]).shape == (
+        2, 128, 512)
     for entry in (model.decode, model.init_cache):
         with pytest.raises(NotImplementedError):
             entry()

@@ -5,49 +5,28 @@ tiny preset against the plain float32 reference the benchmark keeps
 (``benchmark/architectures/ouro.py``, which imports nothing from the
 program). A CPU run shows results and counts, never a time."""
 
-import pathlib
-import re
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu import telemetry
 from deepspeed_tpu.models import Ouro, get_model_class
 from deepspeed_tpu.models.transformer import (_chunk_logits,
                                               _chunked_weighted_cross_entropy)
 from deepspeed_tpu.parallel.partition import match_rules
-from deepspeed_tpu.telemetry import scopes
 
-BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
-if str(BENCH) not in sys.path:
-    sys.path.insert(0, str(BENCH))
-from architectures import ouro as arch  # noqa: E402
-from lib import modelspec  # noqa: E402
+from helpers.families import DS_CONFIG as _DS_CONFIG
+from helpers.families import _batch, _telemetry_isolation  # noqa: F401
+from helpers.families import arch_of, tail_loss_grads
+from lib import modelspec  # noqa: E402  (benchmark/, by helpers.families)
 
-from helpers.family_cases import DS_CONFIG as _DS_CONFIG  # noqa: E402
-
+arch = arch_of("ouro")
 TAIL = 32
-
-
-@pytest.fixture(autouse=True)
-def _telemetry_isolation():
-    telemetry.shutdown()
-    yield
-    telemetry.shutdown()
 
 
 def _tiny(**kw):
     return Ouro(size="tiny", **kw)
-
-
-def _batch(model, b=2, s=128, seed=0):
-    tok = np.random.default_rng(seed).integers(
-        0, model.config.vocab_size, (b, s + 1))
-    return jnp.asarray(tok[:, :-1]), jnp.asarray(tok[:, 1:])
 
 
 def _weights(model, seed=3):
@@ -85,7 +64,9 @@ def right():
     m = modelspec.reference_model(arch, model)
     with jax.default_matmul_precision("highest"):
         loss, tail = arch.reference(params, tokens, targets, m, TAIL)
-        grads = jax.grad(arch.expected_loss)(params, tokens, targets, m)
+        # jitted: eager, every line of the reference's gradient compiles alone
+        grads = jax.jit(jax.grad(
+            lambda *a: arch.expected_loss(*a, m)))(params, tokens, targets)
     return params, tokens, targets, m, loss, tail, grads
 
 
@@ -116,9 +97,8 @@ def test_float32_agrees_with_the_reference(right, variant):
           if variant == "flash_chunked_head" else {})
     model = _tiny(**kw)
     with jax.default_matmul_precision("highest"):
-        got_loss, got = jax.value_and_grad(model.loss)(
-            params, (tokens, targets))
-        got_tail = model.apply(params, tokens)[:, -TAIL:]
+        got_tail, got_loss, got = tail_loss_grads(model, params, tokens,
+                                                  targets, tail=TAIL)
     assert abs(float(got_loss) - loss) / loss < 2e-6
     assert _err(got_tail, tail) < 2e-5
     for path in _GRADS:
@@ -143,12 +123,14 @@ def test_bf16_agrees_with_the_reference_and_the_passes_sum_in_bf16(right):
     with jax.default_matmul_precision("highest"):
         as_f32 = jax.tree.map(lambda w: w.astype(jnp.float32), rounded)
         loss, tail = arch.reference(as_f32, tokens, targets, m, TAIL)
-        grads = jax.grad(arch.expected_loss)(as_f32, tokens, targets, m)
+        grads = jax.jit(jax.grad(lambda *a: arch.expected_loss(*a, m)))(
+            as_f32, tokens, targets)
     model = _tiny(attn_impl="flash", loss_chunk=32)
-    got_loss, got = jax.value_and_grad(model.loss)(rounded, (tokens, targets))
+    got_tail, got_loss, got = tail_loss_grads(model, rounded, tokens,
+                                              targets, tail=TAIL)
     assert got_loss.dtype == jnp.float32
     assert abs(float(got_loss) - loss) / loss < 2e-3
-    assert _err(model.apply(rounded, tokens)[:, -TAIL:], tail) < 3e-2
+    assert _err(got_tail, tail) < 3e-2
     errs = {path: _err(_leaf(got, path), _leaf(grads, path))
             for path in _GRADS}
     print("bf16 gradient errors:", errs)
@@ -296,94 +278,7 @@ def test_what_runs_a_layer_at_a_time_refuses():
         _tiny(total_ut_steps=0)
 
 
-# ---- through the engine ----------------------------------------------------
-@pytest.fixture(scope="module")
-def ouro_engine():
-    model = _tiny(attn_impl="flash", loss_chunk=64)
-    engine, *_ = ds.initialize(model=model, config=dict(_DS_CONFIG))
-    return engine, _batch(model, b=8)
-
-
-def test_engine_trains_and_hands_the_exit_statistics_on(ouro_engine):
-    engine, batch = ouro_engine
-    params = {"layers": {}}
-    stats = {"exit_prob": jnp.ones(4), "exit_nll": jnp.ones(4),
-             "exit_entropy": jnp.float32(2), "micro_batches": jnp.float32(2)}
-    kept, metrics = engine.module.after_step(params, stats)
-    assert kept is params and float(metrics["exit_entropy_mean"]) == 1.0
-    assert float(metrics["exit_prob_mean_4"]) == 0.5
-    losses = [float(engine.train_batch(batch)) for _ in range(4)]
-    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
-    m = engine._last_metrics
-    assert int(m["loop_passes"]) == 4
-    assert sum(float(m[f"exit_prob_mean_{i}"]) for i in range(1, 5)) \
-        == pytest.approx(1.0, abs=1e-4)
-    assert all(np.ndim(m[k]) == 0 for k in m)
-    assert 0 < float(m["exit_entropy_mean"]) < np.log(4) + 1e-6
-    assert 5 < float(m["exit_nll_mean_1"]) < 7
-
-
-def test_traced_and_untraced_steps_are_one_program_and_the_gauges_land(
-        ouro_engine):
-    """The statistics are outputs of the compiled step, traced or not (no
-    host callback); with telemetry on the engine feeds the model's own
-    recorder one step behind."""
-    engine, batch = ouro_engine
-    text = lambda e: e._train_step.lower(  # noqa: E731
-        e.state, e._put_batch(batch)).as_text()
-    untraced = text(engine)
-    assert "callback" not in untraced
-    telemetry.configure()
-    traced, *_ = ds.initialize(model=engine.module, config=dict(_DS_CONFIG))
-    assert text(traced) == untraced
-    traced.train_batch(batch)
-    reg = telemetry.get_registry()
-    assert reg.get("ds_loop_passes") is None        # one step behind
-    traced.train_batch(batch)
-    first = traced._model_metrics_pending
-    assert reg.get("ds_loop_passes").value() == 4
-    traced.train_batch(batch)
-    prob, nll = reg.get("ds_exit_prob_mean"), reg.get("ds_exit_nll_mean")
-    got = [prob.value(**{"pass": str(i)}) for i in range(1, 5)]
-    assert sum(got) == pytest.approx(1.0, abs=1e-4)
-    # the registry holds the step BEFORE the one just dispatched
-    assert got[0] == pytest.approx(float(first["exit_prob_mean_1"]))
-    assert nll.value(**{"pass": "4"}) == pytest.approx(
-        float(first["exit_nll_mean_4"]))
-    assert reg.get("ds_exit_entropy_mean").value() == pytest.approx(
-        float(first["exit_entropy_mean"]))
-
-
-def test_step_scopes_are_the_lists(ouro_engine):
-    """ds.loop inside ds.layers with ds.attn / ds.mlp (and the kernels)
-    inside it, forward and backward; remat's rerun holds no forward flash
-    kernel (PR 47: a layer keeps its ``o`` and ``lse``); ds.exit_gate
-    inside ds.loss_head; no op of a kind the table does not know."""
-    engine, batch = ouro_engine
-    hlo = engine._train_step.lower(
-        engine.state, engine._put_batch(batch)).compile().as_text()
-    found = set()
-    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
-        found.update(re.findall(r"ds\.[A-Za-z0-9_]+", op_name))
-    assert found == set(scopes.DEVICE_SCOPES) | set(scopes.LOOP_SCOPES)
-    work = scopes.op_work(hlo)
-    paths = {row["scope"] for row in work.values() if row["scope"]}
-    assert "bwd:ds.layers/ds.loop/ds.attn/ds.flash_fwd" not in paths
-    for want in ("fwd:ds.layers/ds.loop/ds.attn/ds.flash_fwd",
-                 "bwd:ds.layers/ds.loop/ds.attn/ds.flash_bwd",
-                 "fwd:ds.layers/ds.loop/ds.mlp",
-                 "bwd:ds.layers/ds.loop/ds.mlp",
-                 "fwd:ds.layers/ds.loop"):
-        assert want in paths, (want, sorted(paths))
-    gate = [p for p in paths if "ds.exit_gate" in p]
-    assert gate and all("ds.loss_head/ds.exit_gate" in p for p in gate)
-    # what loop_ms.ouro reads: ds.loop less the sublayers
-    assert any(re.search(r"ds\.loop\b", p)
-               and not re.search(r"ds\.(attn|mlp)\b", p) for p in paths)
-    unknown = sorted(n for n, row in work.items() if row["kind"] == "other")
-    assert not unknown, unknown
-
-
+# ---- through the engine (the shared cases: tests/test_ouro_engine.py) ------
 def test_four_devices_agree_with_one(devices8, monkeypatch):
     """On a forced four-device mesh (``fsdp`` = 4) the loss and the
     gradient the engine makes agree with one device's: the loop gathers a
@@ -399,7 +294,8 @@ def test_four_devices_agree_with_one(devices8, monkeypatch):
         engine, *_ = ds.initialize(model=model, config=dict(
             _DS_CONFIG, train_batch_size=4, mesh={"fsdp": n}))
         assert engine.mesh.size == engine.topology.sizes["fsdp"] == n
-        loss, grads = jax.value_and_grad(engine._loss_fn)(
+        # jitted: eager, every line and interpreted kernel compiles alone
+        loss, grads = jax.jit(jax.value_and_grad(engine._loss_fn))(
             engine.state["params"], engine._put_batch(batch))
         got[n] = float(loss), jax.device_get(grads)
     assert got[4][0] == pytest.approx(got[1][0], rel=1e-3)

@@ -12,6 +12,7 @@ gradients against the reference ``tests/test_qwen3_next_reference.py``'s
 (PR 50; a file is one worker's under ``--dist loadfile``). A CPU run shows
 results and counts, never a time."""
 
+import functools
 import json
 import sys
 
@@ -23,15 +24,16 @@ from deepspeed_tpu.models import Qwen3Next, get_model_class
 from deepspeed_tpu.models.stack import stack_plan
 from deepspeed_tpu.ops import layers as L
 
-from helpers.family_cases import QNEXT_CONFIG as CONFIG
-from helpers.family_cases import (BENCH, _telemetry_isolation,  # noqa: F401
-                                  qnext_right)
-from helpers.family_cases import qnext_tiny as _tiny
+from helpers.families import config_of, right, tiny
+from helpers.families import (BENCH, _telemetry_isolation)  # noqa: F401
+
+CONFIG = config_of("qwen3_next")
+_tiny = functools.partial(tiny, "qwen3_next")
 
 if str(BENCH / "tests") not in sys.path:
     sys.path.insert(0, str(BENCH / "tests"))
 from architectures import qwen3_next as arch  # noqa: E402  (benchmark/, on
-#                                           sys.path by family_cases)
+#                                           sys.path by families)
 from gdn_control import FAULTS, plant  # noqa: E402
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
@@ -47,7 +49,7 @@ def test_the_cells_limits_catch_a_planted_fault(fault):
     ``benchmark/tests/gdn_control.py`` plants (the same it plants on the
     chip) does not."""
     params, tokens, targets, (want_loss, want_tail, counted), _ = (
-        qnext_right())
+        right("qwen3_next"))
     model = _tiny()
     if fault is not None:
         model = plant(model, fault)

@@ -14,22 +14,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from helpers.family_cases import QNEXT_CONFIG as CONFIG
-from helpers.family_cases import (_err, _reference_grads,  # noqa: F401
-                                  _telemetry_isolation, qnext_right)
-from helpers.family_cases import qnext_tiny as _tiny
+from helpers.families import config_of, right, tail_loss_grads, tiny
+from helpers.families import (_err, _reference_grads,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import qwen3_next as arch  # noqa: E402  (benchmark/, on
-#                                           sys.path by family_cases)
+#                                           sys.path by families)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
+
+CONFIG = config_of("qwen3_next")
+_tiny = functools.partial(tiny, "qwen3_next")
 
 
 @functools.lru_cache(maxsize=None)
 def _right():
-    """``qnext_right`` (boosted weights, a batch, the float32 reference's
+    """``right("qwen3_next")`` (boosted weights, a batch, the float32 reference's
     loss, tail logits and mask) with the reference's gradient in the place
     of its model."""
-    params, tokens, targets, want, m = qnext_right()
+    params, tokens, targets, want, m = right("qwen3_next")
     grads = _reference_grads(arch, params, tokens, targets, m)
     return params, tokens, targets, want, grads
 
@@ -64,20 +66,17 @@ def test_loss_logits_and_gradients_match_the_float32_reference(variant):
                 params, tokens, targets, m, 32)
         low = jax.tree_util.tree_map(
             lambda w: w.astype(jnp.bfloat16), params)
-        numbers = train_job.tail_numbers(
-            model.apply(low, tokens)[:, -32:], want_tail, counted)
-        got = float(model.loss(low, (tokens, targets)))
+        got_tail, got, _ = tail_loss_grads(model, low, tokens, targets,
+                                           grads=False)
+        numbers = train_job.tail_numbers(got_tail, want_tail, counted)
+        got = float(got)
         assert abs(got - want) <= 5e-3 * want
         assert numbers["logits_err_max"] < 5e-2, numbers
         assert numbers["logits_err_rms"] < 2e-2, numbers
         return
     with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain_f32":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
+        got_tail, got, got_g = tail_loss_grads(
+            model, params, tokens, targets, grads=variant != "plain_f32")
     assert abs(float(got) - want) <= 2e-5 * want
     assert _err(got_tail, want_tail) < 5e-4
     if got_g is None:

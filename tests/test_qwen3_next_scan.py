@@ -17,10 +17,10 @@ from deepspeed_tpu import telemetry
 from deepspeed_tpu.moe.sharded_moe import held_block, moe_ffn_held
 from deepspeed_tpu.ops import kda
 
-from helpers.family_cases import (_batch, _close, _err,  # noqa: F401
-                                  _kda_inputs, _telemetry_isolation,
-                                  _walk_eqns)
-from helpers.kept_cases import kernel_calls, tiny
+from helpers.families import (_batch, _close, _err,  # noqa: F401
+                               _kda_inputs, _telemetry_isolation,
+                               _walk_eqns)
+from helpers.families import FAMILIES, kernel_calls, tiny
 from architectures import qwen3_next as arch  # noqa: E402
 
 
@@ -145,8 +145,9 @@ def test_head_counts_the_scan_cannot_run_are_refused(case, hk, h, groups,
 
 def _loss_gradient(family):
     """(the tiny model, the gradient of its loss as a function of seeded
-    shapes): what the engine's train step differentiates."""
-    model = tiny(family)
+    shapes): what the engine's train step differentiates. A NEW model a
+    call: ``jax.checkpoint`` keeps a function's trace."""
+    model = tiny(family, **FAMILIES[family].step)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     batch = _batch(model)
 

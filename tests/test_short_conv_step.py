@@ -1,52 +1,33 @@
 """Both callers of the short convolution's kernels (ISSUE 43),
 Kimi-Linear and Granite 4.0-H, held to the loss and gradients they
-computed with the ``jax.numpy`` lines in the op's place. The case was
-``tests/test_granite_hybrid.py``'s until PR 45 (a file is one worker's
-under ``--dist loadfile``, and this is four steps of each family in
-interpret mode), and one case a family until PR 50 (Kimi-Linear's four
-steps in one case were the longest case of the suite). A CPU run shows
-results and counts, never a time."""
-
-import functools
+computed with the ``jax.numpy`` lines in the op's place, at the smallest
+stack that holds the op (ISSUE 58: ONE layer of the kind, the row's
+``short_conv`` cut of ``tests/helpers/families.py``; the families' whole
+stacks are held to float32 in ``tests/test_<family>_reference.py`` and the
+kernels in ``tests/test_short_conv.py``). The case was
+``tests/test_granite_hybrid.py``'s until PR 45, one case a family until PR
+50, and the preset's whole five layers until PR 58 (Kimi-Linear's were four
+of the eight cases over 90 s). A CPU run shows results and counts, never a
+time."""
 
 import jax
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models import GraniteHybrid, KimiLinear
-from deepspeed_tpu.ops import layers as L
-
-from helpers import short_conv_reference  # noqa: E402  (tests/helpers)
-from helpers.family_cases import _batch
+from helpers.families import SHORT_CONV, program
 
 
-# ---- with the jax.numpy convolution back in, the parent's step -------------
-_STEPS = {
-    "kimi_linear_the_cells_switches": (KimiLinear, dict(
-        moe_held_experts=8, attn_impl="flash", loss_chunk=64,
-        kda_head_groups=2)),
-    "granite_hybrid_the_cells_switches": (GraniteHybrid, dict(
-        attn_impl="flash", loss_chunk=64)),
-}
-
-
-@functools.lru_cache(maxsize=None)
 def _loss_and_grads(family, dtype, reference: bool):
-    """Loss and gradients of a tiny model's step on ``dtype`` weights,
-    with ``ops.layers.short_conv`` as it is or, ``reference``, as the
-    parent's lines had it (``tests/helpers/short_conv_reference.py``:
+    """Loss and gradients of one layer's step on ``dtype`` weights, with
+    ``ops.layers.short_conv`` as it is or, ``reference``, as the parent's
+    lines had it (``tests/helpers/short_conv_reference.py``:
     ``causal_conv``, the SiLU and ``_kda``'s local l2 norm, the taps and
-    the SiLU in the weights' dtype). Each whole step is computed once: the
-    float32 kernels' step is what both cases of a family hold to."""
-    cls, kw = _STEPS[family]
-    with pytest.MonkeyPatch.context() as patch:
-        if reference:
-            patch.setattr(L, "short_conv", short_conv_reference.short_conv)
-        model = cls(size="tiny", **kw)
-        params = jax.tree.map(lambda x: x.astype(dtype),
-                              model.init(jax.random.PRNGKey(3)))
-        out = jax.jit(jax.value_and_grad(model.loss))(params, _batch(model))
-        return jax.device_get(out)
+    the SiLU in the weights' dtype). Each step is computed once a file
+    (``families.program``): the float32 kernels' step is what both cases
+    of a family hold to."""
+    return program(family, "short_conv", dtype=dtype,
+                   patch="short_conv_reference" if reference else None
+                   ).loss_and_grads(3)
 
 
 def _leaf_errors(got, want):
@@ -65,12 +46,14 @@ def _leaf_errors(got, want):
     return out
 
 
-@pytest.mark.parametrize("family", list(_STEPS))
+@pytest.mark.parametrize("family", SHORT_CONV)
 def test_the_float32_step_computes_the_parents_loss_and_gradients(family):
     """ISSUE 43: the short convolution, the SiLU and the l2 norms became
     one kernel pair and nothing else moved: with the parent's
-    ``jax.numpy`` lines patched back in for the op, the tiny Kimi-Linear
-    and Granite steps compute the same loss and gradients. In float32
+    ``jax.numpy`` lines patched back in for the op, a KDA and a Mamba-2
+    layer's step compute the same loss and gradients (read at one layer,
+    Kimi-Linear's and Granite's: a loss 8e-8 and 0 apart, a leaf 9e-7 and
+    5e-7). In float32
     the two forms are one function (the kernels sum a head's squares from
     three bf16 pieces and take the SiLU through tanh: rounding in the
     seventh digit)."""
@@ -81,7 +64,7 @@ def test_the_float32_step_computes_the_parents_loss_and_gradients(family):
     assert max(same.values()) < 2e-4, max(same.items(), key=lambda kv: kv[1])
 
 
-@pytest.mark.parametrize("family", list(_STEPS))
+@pytest.mark.parametrize("family", SHORT_CONV)
 def test_the_bfloat16_step_lies_no_further_from_float32_than_the_parents(
         family):
     """On bf16 weights each form is its own rounding of the float32
@@ -91,7 +74,9 @@ def test_the_bfloat16_step_lies_no_further_from_float32_than_the_parents(
     parent's (0.8 of its distance on Kimi's leaves, 0.9 on Granite's,
     where a leaf is 1% to 5% from float32 in either form). A routed
     expert's leaves are 10% to 20% off in both: a rounding sends a token
-    to another expert."""
+    to another expert. One layer has 24 smooth leaves (Kimi-Linear's KDA
+    layer) or 15 (Granite's Mamba-2 layer, every one of its leaves) where
+    the five-layer stacks had over 30."""
     exact, exact_g = _loss_and_grads(family, "float32", False)
     now, now_g = _loss_and_grads(family, "bfloat16", False)
     parent, parent_g = _loss_and_grads(family, "bfloat16", True)
@@ -103,6 +88,6 @@ def test_the_bfloat16_step_lies_no_further_from_float32_than_the_parents(
             name, mine[name], theirs[name])
     smooth = [n for n in mine if "['experts']" not in n
               and "['router']" not in n]
-    assert len(smooth) > 30
+    assert len(smooth) >= 15
     assert (np.mean([mine[n] for n in smooth])
             <= np.mean([theirs[n] for n in smooth]))

@@ -15,17 +15,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
-from deepspeed_tpu.models import (GraniteHybrid, KimiLinear, Lfm2Moe, Mellum,
-                                  Mistral, Ouro, Qwen3Next, Xing4)
 from deepspeed_tpu.models.transformer import _chunked_cross_entropy
 from deepspeed_tpu.ops.pallas import _common
 
-from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
+from helpers.families import _telemetry_isolation  # noqa: F401
+from helpers.families import program
 
-# row -> (class, switches over the tiny preset, the sha256 of the lowered
-# train step with its symbols renumbered, the sum of the seeded master
-# weights' magnitudes). Two layers of a stack where two hold every kind of
+# row -> (the sha256 of the lowered train step with its symbols renumbered,
+# the sum of the seeded master weights' magnitudes). The class and the
+# switches over the tiny preset are the row's of ``tests/helpers/families.py``
+# (cell's switches, step's switches, ``two_layers`` cut: here until PR 58).
+# Two layers of a stack where two hold every kind of
 # layer the family has (a routed layer behind a KDA and an MLA mixer; a Mamba
 # and an attention layer; a window and a full attention layer; a Gated
 # DeltaNet and a gated attention layer); ``mellum`` also at the preset's own
@@ -75,55 +75,44 @@ from helpers.family_cases import DS_CONFIG, _telemetry_isolation  # noqa: F401
 # wide to hold: the nine rows before it stand, their experts are held whole
 # and lower to the text they lowered to.
 _PINS = {
-    "kimi_linear": (KimiLinear, dict(
-        num_layers=2, kda_layers=(1,), full_attn_layers=(2,),
-        first_k_dense_replace=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64, kda_head_groups=2),
+    "kimi_linear": (
         "96351243eb33e8ab98c087dc598f18c51becb25bed1506d3e460341bd864bfe7",
         7191.956370612894),
-    "granite_hybrid": (GraniteHybrid, dict(
-        num_layers=2, layer_types=["mamba", "attention"], attn_impl="flash",
-        loss_chunk=64),
+    "granite_hybrid": (
         "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
         2422.8129150247487),
-    "mellum": (Mellum, dict(
-        moe_held_experts=16, attn_impl="flash", loss_chunk=64),
+    "mellum": (
         "6757d265d311670d63b7cb789f660f8b24d0670e7052abf56d987ad41a6882b4",
         36510.69587289919),
-    "mellum_two_layers": (Mellum, dict(
-        num_layers=2, layer_types=["sliding_attention", "full_attention"],
-        moe_held_experts=16, attn_impl="flash", loss_chunk=64),
+    "mellum_two_layers": (
         "804d32f3a8ec122e419a8accb0baca66cd2d04ac0687a83165295576e9baa962",
         31755.548628388842),
-    "ouro": (Ouro, dict(num_layers=2, attn_impl="flash", loss_chunk=64),
-             "9eec4588f2e615a8871cef610ca85b5dc9984269762d3ecda677f84ad4d2f3d0",
-             2733.7353564571135),
-    "mistral": (Mistral, dict(
-        attn_impl="flash", loss_chunk=64, sliding_window=64),
+    "ouro": (
+        "9eec4588f2e615a8871cef610ca85b5dc9984269762d3ecda677f84ad4d2f3d0",
+        2733.7353564571135),
+    "mistral": (
         "78f5c2263a6399b676b44438f75273068c1f2d668ae62f890529c98bd4251219",
         2339.9930016614694),
-    "mistral_segments": (Mistral, dict(
-        attn_impl="flash", loss_chunk=64, sliding_window=64,
-        remat_policy="segments"),
+    "mistral_segments": (
         "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
         2339.9930016614694),
-    "qwen3_next": (Qwen3Next, dict(
-        num_layers=2, full_attention_interval=2, moe_held_experts=32,
-        qk_norm_init=2.0, attn_impl="flash", loss_chunk=64),
+    "qwen3_next": (
         "b9fddf2a2a4d287989936797e229ff20f68344e9ffd0978ac858e42b70b9e743",
         39458.17879846059),
-    "lfm2_moe": (Lfm2Moe, dict(
-        num_layers=2, layer_types=["full_attention", "conv"],
-        num_dense_layers=0, moe_held_experts=8, attn_impl="flash",
-        loss_chunk=64),
+    "lfm2_moe": (
         "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",
         3449.799246064109),
-    "xing4_0": (Xing4, dict(
-        num_layers=2, first_k_dense_replace=1, moe_held_experts=8,
-        mhc_alpha_init=(2.0, 2.0, 0.5), mhc_b_std=(2.0, 2.0, 0.5),
-        attn_impl="flash", loss_chunk=64),
+    "xing4_0": (
         "7d0db59390f004a09922ce9ac1b47e8557f20f5884037f8f0dd022c42b2a6715",
         4668.748035160373),
+}
+# the rows that are not a family's two-layer cut under the family's name: (family, cut of its
+# layers, further switches)
+_OTHER_CUTS = {
+    "mellum": ("mellum", "cell", {}),
+    "mellum_two_layers": ("mellum", "two_layers", {}),
+    "mistral": ("mistral", "cell", {}),
+    "mistral_segments": ("mistral", "cell", dict(remat_policy="segments")),
 }
 
 
@@ -147,13 +136,11 @@ def _step_pin(row: str):
     # (``ops/pallas/_common.py`` ``_bind``): one that an earlier row traced
     # under its own model would be bound here
     _common._TRACED.clear()
-    cls, switches, *_ = _PINS[row]
-    model = cls(size="tiny", **switches)
-    engine, *_ = ds.initialize(model=model, config=dict(DS_CONFIG))
-    tok = np.zeros((8, model.config.max_seq_len), np.int32)
-    text = engine._train_step.lower(
-        engine.state, engine._put_batch((tok, tok))).as_text()
-    leaves = jax.device_get(jax.tree.leaves(engine.state["master"]))
+    family, cut, switches = _OTHER_CUTS.get(row, (row, "two_layers", {}))
+    step = program(family, cut, **switches)
+    tok = np.zeros((8, step.model.config.max_seq_len), np.int32)
+    text = step.lower((tok, tok)).as_text()
+    leaves = jax.device_get(jax.tree.leaves(step.engine.state["master"]))
     return _pin(text), float(sum(np.abs(x.astype(np.float64)).sum()
                                  for x in leaves))
 
@@ -162,7 +149,7 @@ def _step_pin(row: str):
 def test_the_families_steps_are_the_parents_programs(row):
     """The lowered train step under ZeRO-3 bf16 on the 8-device mesh is the
     parent's text and the seeded weights the parent's numbers."""
-    assert _step_pin(row) == _PINS[row][2:]
+    assert _step_pin(row) == _PINS[row]
 
 
 def test_the_unweighted_head_is_the_parents_program():

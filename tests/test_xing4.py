@@ -19,14 +19,16 @@ import pytest
 from deepspeed_tpu.ops import mhc
 from deepspeed_tpu.ops.pallas import mhc as kernels
 
-from helpers.family_cases import XING_CONFIG as CONFIG
-from helpers.family_cases import (_err, _reference_grads,  # noqa: F401
-                                  _telemetry_isolation, xing_right)
-from helpers.family_cases import xing_tiny as _tiny
+from helpers.families import config_of, right, tail_loss_grads, tiny
+from helpers.families import (_err, _reference_grads,  # noqa: F401
+                               _telemetry_isolation)
 from architectures import xing4 as arch  # noqa: E402  (benchmark/, on
-#                                      sys.path by family_cases)
+#                                      sys.path by families)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
+
+CONFIG = config_of("xing4_0")
+_tiny = functools.partial(tiny, "xing4_0")
 
 F32 = jnp.float32
 
@@ -34,7 +36,7 @@ F32 = jnp.float32
 # ---- the whole model against the float32 reference -------------------------
 @functools.lru_cache(maxsize=None)
 def _right():
-    params, tokens, targets, want, m = xing_right()
+    params, tokens, targets, want, m = right("xing4_0")
     return params, tokens, targets, want, _reference_grads(
         arch, params, tokens, targets, m)
 
@@ -67,20 +69,17 @@ def test_loss_logits_and_gradients_match_the_float32_reference(variant):
             want, want_tail, counted = arch.reference(
                 params, tokens, targets, m, 32)
         low = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), params)
-        numbers = train_job.tail_numbers(
-            model.apply(low, tokens)[:, -32:], want_tail, counted)
-        got = float(model.loss(low, (tokens, targets)))
+        got_tail, got, _ = tail_loss_grads(model, low, tokens, targets,
+                                           grads=False)
+        numbers = train_job.tail_numbers(got_tail, want_tail, counted)
+        got = float(got)
         assert abs(got - want) <= 5e-3 * want
         assert numbers["logits_err_max"] < 5e-2, numbers
         assert numbers["logits_err_rms"] < 2e-2, numbers
         return
     with jax.default_matmul_precision("highest"):
-        got_tail = model.apply(params, tokens)[:, -32:]
-        if variant == "plain_f32":
-            got, got_g = model.loss(params, (tokens, targets)), None
-        else:
-            got, got_g = jax.value_and_grad(model.loss)(params,
-                                                        (tokens, targets))
+        got_tail, got, got_g = tail_loss_grads(
+            model, params, tokens, targets, grads=variant != "plain_f32")
     assert abs(float(got) - want) <= 2e-5 * want
     assert _err(got_tail, want_tail) < 5e-4
     if got_g is None:

@@ -7,6 +7,7 @@ pass behind it, the shared expert counted once; the configuration file
 builds the published model; what the family refuses. A CPU run shows
 results and counts, never a time."""
 
+import functools
 import json
 import sys
 
@@ -21,15 +22,17 @@ from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.ops import layers as L
 from deepspeed_tpu.ops import mhc
 
-from helpers.family_cases import XING_CONFIG as CONFIG
-from helpers.family_cases import (BENCH, _close,  # noqa: F401
-                                  _telemetry_isolation, xing_right)
-from helpers.family_cases import xing_tiny as _tiny
+from helpers.families import config_of, right, tiny
+from helpers.families import (BENCH, _close,  # noqa: F401
+                               _telemetry_isolation)
+
+CONFIG = config_of("xing4_0")
+_tiny = functools.partial(tiny, "xing4_0")
 
 if str(BENCH / "tests") not in sys.path:
     sys.path.insert(0, str(BENCH / "tests"))
 from architectures import xing4 as arch  # noqa: E402  (benchmark/, on
-#                                      sys.path by family_cases)
+#                                      sys.path by families)
 from kinds import train_job  # noqa: E402
 from lib import modelspec  # noqa: E402
 from mhc_control import FAULTS, plant, with_planted_logit  # noqa: E402
@@ -45,7 +48,7 @@ def test_the_cells_limits_catch_a_planted_fault(fault):
     ``benchmark/tests/mhc_control.py`` plants does not. The dropped clamp
     is judged on weights with a res logit planted past it (100), which
     the right program clamps and passes on too."""
-    params, tokens, targets, want, m = xing_right()
+    params, tokens, targets, want, m = right("xing4_0")
     model = _tiny()
     programs = {fault: model if fault is None else plant(model, fault)}
     if fault == "clamp_dropped":
