@@ -26,8 +26,8 @@ on both sides.
 - **Operands** are read where they lie: the gate and ``y`` [B, S, H d] (a
   matmul's output, a matmul's input), ``o`` either [B, S, H, d] or, from
   ``ops/kda.py`` ``chunk_kda(by_head=True)``, the heads' stack [G, B,
-  H / G, S, d] as the scan's kernel writes it and the head groups'
-  ``lax.map`` stacks it: a head is a 128-lane column block of ``y``
+  H / G, S, d] as the scan's kernel writes it, a head group at its rows
+  of the one stack: a head is a 128-lane column block of ``y``
   either way, so only ``o``'s index map differs, and ``do`` is written in
   ``o``'s form (no relayout of 2 B S H d bytes behind the scan's kernel,
   in front of this call or behind its backward).

@@ -1,8 +1,9 @@
 """Chunked Kimi Delta Attention as two Pallas kernel pairs: the
 preparation of a chunk, and the recurrence over the chunks.
 
-**The preparation** (``kda_prepare``, one ``jax.custom_vjp`` whose only
-residuals are its five inputs) makes, chunk by chunk of ``C`` tokens and
+**The preparation** (``_prepare_forward`` / ``_prepare_backward``; its
+backward needs its five inputs and nothing else) makes, chunk by chunk of
+``C`` tokens and
 head by head, what does not need the state: with ``G`` the chunk's running
 sum of the log-decays ``g``, the score matrices ``a_kk`` (strictly lower)
 and ``a_qk`` (lower, exact diagonal), ``T = (I + a_kk)^-1``, and from them
@@ -64,7 +65,7 @@ state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
     o  = q_in S + a_qk u
     S' = Diag(shrink) S + k_out^T u
 
-``kda_recurrence`` runs that under one ``jax.custom_vjp``:
+``_forward`` / ``_backward`` run that:
 
 - **Forward** (``ds_kda_fwd``): grid (batch x heads, segments of ``SEG``
   chunks); a grid step loops over its segment's chunks with the state in
@@ -83,13 +84,32 @@ state ``S`` [dk, dv] of one head, ``S = 0`` before the first chunk::
       dshrink = rowsum(S * dS')
       dS = Diag(shrink) dS' + q_in^T do - w^T du
 
-  Under a ``jax.checkpoint`` that reruns the forward rule for its
-  residuals (``chunk_kda`` puts one around each group of heads) the rule's
-  ``o`` is dead and the forward kernel is not run again: the rerun costs
-  the preparation's forward and the checkpoint form only. Where the
-  groups are more than one that rerun is the only one: ``chunk_kda``
-  declares its ``o`` kept, so a rematted layer's backward reads it back
-  (PR 51) and a layer runs the forward kernel once in each form.
+  The scan's backward rule (``ops/kda.py`` ``_scan``, one ``custom_vjp``
+  over all the head groups) makes a group's six operands again and runs
+  the checkpoint form: the forward kernel is not run again. Where the
+  groups are more than one the rule's ``o`` is declared kept, so a
+  rematted layer's backward reads it back (PR 51) and a layer runs the
+  forward kernel once in each form.
+
+**Head groups** (ISSUE 59). ``chunk_kda`` runs the heads in groups, one
+after the other, so that the six operands live for one group at a time. A
+group is an offset in the four calls' index maps, not a slice: ``grp``,
+the group's index, is every call's one scalar-prefetch operand (a loop's
+counter or a constant), the preparation's calls take the WHOLE q, k, v, g
+[B, S, H d] and the whole rows of beta (and of a gate a head) and read at
+the group's first head block (``_prep_specs``), ``_forward`` writes the
+group's ``o`` at its rows of the groups' stack [G BH, N, C, dv] and
+``_backward`` reads its ``do`` there (``_specs`` ``stack``), and
+``_prepare_backward`` writes dq, dk, dv, dg, dbeta where the group's heads
+lie in the whole gradients. What several groups write into is carried
+from call to call and aliased to the output (``into``,
+``input_output_aliases``): buffers made NOT initialised (``lax.empty``:
+``AllocateBuffer`` on the chip), since every block is written by exactly
+one group: nothing is zeroed and nothing is added. One group carries
+nothing: it is offset 0 of the same calls, which then make their own
+outputs (``into`` None). ``kda_prepare`` and ``kda_recurrence`` are the
+two pairs alone, a ``custom_vjp`` each over the same four builders (tests,
+``tools/kda_kernel_bench.py``).
 
 The kernels hold the state TRANSPOSED (``St`` [dv, dk]): ``shrink`` then
 scales lanes and broadcasts as the row it is stored as, ``dshrink`` is a
@@ -110,6 +130,7 @@ shape.
 from __future__ import annotations
 
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -117,7 +138,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import _bind, _dot, _interpret, _registry
+from ._common import _bind, _dot, _interpret, _nbytes, _registry
 
 CHUNK = 64      # tokens a chunk: the matmuls are [64, 128] x [128, 128]
 SUB = 8         # rows a sub-block of the score matrices
@@ -207,41 +228,83 @@ def _pad_chunks(ops, pad: int):
     return (*(grow(x, 0) for x in mats), grow(shrink, 1))
 
 
-def _specs(shapes, heads, seg):
-    """A segment of ``heads`` heads of each [BH, N, ...] array."""
-    return [pl.BlockSpec((heads, seg, *s[2:]),
-                         lambda b, j, _r=len(s) - 2: (b, j) + (0,) * _r,
-                         memory_space=pltpu.VMEM) for s in shapes]
+def _specs(shapes, heads, seg, stack: int = 0):
+    """A segment of ``heads`` heads of each [BH, N, ...] array: a group's
+    own array or, with ``stack`` (the head blocks a group), the group's
+    rows of the groups' stack [G BH, N, ...], ``grp`` its scalar-prefetch
+    operand."""
+    return [pl.BlockSpec(
+        (heads, seg, *s[2:]),
+        lambda b, j, grp, _r=len(s) - 2: (grp[0] * stack + b if stack else b,
+                                          j) + (0,) * _r,
+        memory_space=pltpu.VMEM) for s in shapes]
 
 
-def _forward(ops, out_dtype, *, states: bool):
-    """``o`` [BH, N, C, dv] in ``out_dtype``, or with ``states`` the float32
-    checkpoints [BH, N / seg, dv, dk] (of the padded chunk count)."""
+def _group(grp):
+    """A head group's index, a Python int or a loop's counter, as the one
+    scalar-prefetch operand the four calls' index maps read."""
+    return jnp.asarray(grp, jnp.int32).reshape(1)
+
+
+def _with_group(kernel, inputs: int, carried: int):
+    """``kernel`` as a call with the group's index prefetched and
+    ``carried`` aliased buffers behind its ``inputs`` hands it its refs:
+    the index maps alone read the first, and nothing reads the buffers
+    the outputs are written into."""
+    return lambda grp, *refs: kernel(*refs[:inputs],
+                                     *refs[inputs + carried:])
+
+
+_CARRIED = pl.BlockSpec(memory_space=pl.ANY)
+
+
+def _stack(bh: int, n: int, c: int, dv: int, dtype):
+    """The head groups' stack of ``o`` [G BH, N, C, dv] for ``_forward``
+    to write into, of whole segments and NOT initialised."""
+    seg = min(SEG, n)
+    return jax.lax.empty((bh, -(-n // seg) * seg, c, dv), dtype)
+
+
+def _forward(ops, out_dtype, *, states: bool, grp=0, into=None):
+    """Head group ``grp``: ``o`` in ``out_dtype`` written at the group's
+    rows of ``into``, the groups' stack [G BH, N, C, dv] (``_stack``),
+    which is returned (None: one group's own ``o``, a new array); or with
+    ``states`` the group's float32 checkpoints [BH, N / seg, dv, dk]. Both
+    of the padded chunk count."""
     u_v, w = ops[:2]
     bh, n, c, dk, seg, heads = _geometry(w)
     dv = u_v.shape[-1]
     _check_chip_shapes(c, dk, dv)
     ops = _pad_chunks(ops, -n % seg)
     nseg = ops[0].shape[1] // seg
+    one = (bh, nseg * seg, c, dv)
+    carried = () if into is None else (into,)
     if states:
         out_shape = jax.ShapeDtypeStruct((bh, nseg, dv, dk), jnp.float32)
         out_spec = pl.BlockSpec((heads, 1, dv, dk),
-                                lambda b, j: (b, j, 0, 0),
+                                lambda b, j, grp: (b, j, 0, 0),
                                 memory_space=pltpu.VMEM)
     else:
-        out_shape = jax.ShapeDtypeStruct((bh, nseg * seg, c, dv), out_dtype)
-        out_spec, = _specs([out_shape.shape], heads, seg)
+        out_shape = jax.ShapeDtypeStruct(
+            one if into is None else into.shape, out_dtype)
+        out_spec, = _specs([one], heads, seg, stack=bh // heads)
     mm = 2 * c * dk * dv
     flops = bh * nseg * seg * (2 * mm if states else 3 * mm + 2 * c * c * dv)
+    # a group's own: its rows of the stack
     nbytes = sum(x.size * x.dtype.itemsize for x in ops) + (
-        np.prod(out_shape.shape) * jnp.dtype(out_shape.dtype).itemsize)
+        np.prod(out_shape.shape if states else one)
+        * jnp.dtype(out_shape.dtype).itemsize)
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, seg=seg, states=states),
-        grid=(bh // heads, nseg),
-        in_specs=_specs([x.shape for x in ops], heads, seg),
-        out_specs=out_spec,
+        _with_group(functools.partial(_fwd_kernel, heads=heads, seg=seg,
+                                      states=states), len(ops), len(carried)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh // heads, nseg),
+            in_specs=_specs([x.shape for x in ops], heads, seg)
+            + [_CARRIED] * len(carried),
+            out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)]),
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
+        input_output_aliases={1 + len(ops): 0} if carried else {},
         compiler_params=_COMPILER_PARAMS,
         cost_estimate=pl.CostEstimate(flops=int(flops), transcendentals=0,
                                       bytes_accessed=int(nbytes)),
@@ -250,9 +313,9 @@ def _forward(ops, out_dtype, *, states: bool):
     )
     # the scope and the kernel's name are all a device trace shows of this
     # call (telemetry/scopes.py)
-    with jax.named_scope("ds.kda_fwd"):
-        out = call(*ops)
-    return out if states else out[:, :n]
+    return _bind(call, "ds.kda_fwd",
+                 ("kda_fwd", states, heads, seg, into is None),
+                 _group(grp), *ops, *carried)[0]
 
 
 # ---------------------------------------------------------------- backward
@@ -312,8 +375,10 @@ def _bwd_kernel(uv_ref, w_ref, q_ref, a_ref, k_ref, sh_ref, ck_ref, do_ref,
         ds_ref[h] = dst
 
 
-def _backward(ops, ck, do):
-    """The six cotangents, each in its operand's shape and dtype."""
+def _backward(ops, ck, do, grp=0):
+    """The six cotangents of head group ``grp``, each in its operand's
+    shape and dtype; ``do`` the groups' stack [G BH, N, C, dv], read at
+    the group's rows."""
     u_v, w = ops[:2]
     bh, n, c, dk, seg, heads = _geometry(w)
     dv = u_v.shape[-1]
@@ -325,34 +390,37 @@ def _backward(ops, ck, do):
     last = nseg - 1
     rev = lambda spec: pl.BlockSpec(  # noqa: E731
         spec.block_shape,
-        lambda b, j, _m=spec.index_map: _m(b, last - j),
+        lambda b, j, grp, _m=spec.index_map: _m(b, last - j, grp),
         memory_space=pltpu.VMEM)
     shapes = [x.shape for x in ops]
-    ck_spec = pl.BlockSpec((heads, 1, dv, dk), lambda b, j: (b, j, 0, 0),
+    ck_spec = pl.BlockSpec((heads, 1, dv, dk),
+                           lambda b, j, grp: (b, j, 0, 0),
                            memory_space=pltpu.VMEM)
     mm = 2 * c * dk * dv
     flops = bh * nseg * seg * (8 * mm + 4 * c * c * dv)
     nbytes = (2 * sum(x.size * x.dtype.itemsize for x in ops)
-              + ck.size * 4 + do.size * do.dtype.itemsize)
+              + ck.size * 4 + u_v.size * do.dtype.itemsize)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, seg=seg),
-        grid=(bh // heads, nseg),
-        in_specs=[rev(s) for s in
-                  (*_specs(shapes, heads, seg), ck_spec,
-                   *_specs([do.shape], heads, seg))],
-        out_specs=[rev(s) for s in _specs(shapes, heads, seg)],
+        _with_group(functools.partial(_bwd_kernel, heads=heads, seg=seg),
+                    len(ops) + 2, 0),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh // heads, nseg),
+            in_specs=[rev(s) for s in
+                      (*_specs(shapes, heads, seg), ck_spec,
+                       *_specs([do.shape], heads, seg, stack=bh // heads))],
+            out_specs=[rev(s) for s in _specs(shapes, heads, seg)],
+            scratch_shapes=[pltpu.VMEM((heads, seg, dv, dk), jnp.float32),
+                            pltpu.VMEM((heads, seg, c, dv), w.dtype),
+                            pltpu.VMEM((heads, dv, dk), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in ops],
-        scratch_shapes=[pltpu.VMEM((heads, seg, dv, dk), jnp.float32),
-                        pltpu.VMEM((heads, seg, c, dv), w.dtype),
-                        pltpu.VMEM((heads, dv, dk), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         cost_estimate=pl.CostEstimate(flops=int(flops), transcendentals=0,
                                       bytes_accessed=int(nbytes)),
         interpret=_interpret(),
         name="ds_kda_bwd",
     )
-    with jax.named_scope("ds.kda_bwd"):
-        grads = call(*ops, ck, do)
+    grads = _bind(call, "ds.kda_bwd", ("kda_bwd", heads, seg),
+                  _group(grp), *ops, ck, do)
     return tuple(x[:, :n] for x in grads)
 
 
@@ -792,13 +860,18 @@ def _prep_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, duv_ref, dw_ref,
     jax.lax.fori_loop(0, nck, chunk, 0)
 
 
-def _prep_geometry(q, v, chunk):
+def _prep_geometry(q, v, chunk, groups: int = 1):
     """(B, chunks, the VALUE heads, the value heads a key head serves, dk,
     dv, chunks and heads a grid step). The head count is ``v``'s; q and k
     may hold fewer heads, each serving ``rep`` consecutive value heads, and
-    a grid step then takes whole key heads."""
+    a grid step then takes whole key heads of ONE of the ``groups`` head
+    groups."""
     b, s, hk, dk = q.shape
     h = v.shape[2]
+    if s % chunk or chunk % SUB:
+        raise ValueError(
+            f"chunk_kda: sequence {s} must be a multiple of the chunk "
+            f"{chunk}, and the chunk of {SUB}")
     if h % hk:
         raise ValueError(
             f"kda kernels: {h} value heads are no multiple of q's and k's "
@@ -806,8 +879,8 @@ def _prep_geometry(q, v, chunk):
     rep = h // hk
     n = s // chunk
     nck = next(d for d in range(min(NCK, n), 0, -1) if n % d == 0)
-    heads = next((d for d in range(min(PREP_HEADS, h), 0, -1)
-                  if h % d == 0 and d % rep == 0), None)
+    heads = next((d for d in range(min(PREP_HEADS, h // groups), 0, -1)
+                  if h // groups % d == 0 and d % rep == 0), None)
     if heads is None:
         raise ValueError(
             f"kda kernels: a key head serves {rep} value heads, more than "
@@ -815,116 +888,166 @@ def _prep_geometry(q, v, chunk):
     return b, n, h, rep, dk, v.shape[-1], nck, heads
 
 
-def _prep_specs(b, n, h, c, dk, dv, nck, heads, head_gate=False, rep=1):
+class _Prep(typing.NamedTuple):
+    """What the preparation's two calls are built from: ``_prep_geometry``'s
+    eight, the chunk, whether the gate is a head's, and the head groups."""
+    b: int
+    n: int
+    h: int
+    rep: int
+    dk: int
+    dv: int
+    nck: int
+    heads: int
+    c: int
+    head_gate: bool
+    groups: int
+
+
+def _prep_specs(p: _Prep):
     """(the five inputs' specs, the six operands' specs and shapes): a
-    grid step (batch, head block, chunk block). q's and k's blocks are the
-    ``heads // rep`` key heads of the step's value heads, at the same
-    block index."""
-    hb = h // heads
+    grid step (batch, head block of the group, chunk block). The inputs
+    are the WHOLE arrays: a head group is an offset in their index maps,
+    the group's first head block (``grp``, the scalar-prefetch operand,
+    times the head blocks a group), the same in q's and k's blocks of the
+    ``heads // rep`` key heads of the step's value heads. The operands
+    are the group's own."""
+    heads, nck, c, dk, dv = p.heads, p.nck, p.c, p.dk, p.dv
+    hb = p.h // heads               # head blocks of the whole arrays,
+    gb = hb // p.groups             # of a group
     wide = lambda d, n=heads: pl.BlockSpec(  # noqa: E731
-        (1, nck * c, n * d), lambda i, j, l: (i, l, j),
+        (1, nck * c, n * d), lambda i, j, l, grp: (i, l, grp[0] * gb + j),
+        memory_space=pltpu.VMEM)
+    rows = lambda *d: pl.BlockSpec(  # noqa: E731
+        (heads, nck, *d),
+        lambda i, j, l, grp: (i * hb + grp[0] * gb + j, l, 0, 0),
         memory_space=pltpu.VMEM)
     flat = lambda *d: pl.BlockSpec(  # noqa: E731
-        (heads, nck, *d), lambda i, j, l: (i * hb + j, l, 0, 0),
+        (heads, nck, *d), lambda i, j, l, grp: (i * gb + j, l, 0, 0),
         memory_space=pltpu.VMEM)
-    ins = [wide(dk, heads // rep), wide(dk, heads // rep), wide(dv),
-           flat(1, c) if head_gate else wide(dk), flat(1, c)]
+    ins = [wide(dk, heads // p.rep), wide(dk, heads // p.rep), wide(dv),
+           rows(1, c) if p.head_gate else wide(dk), rows(1, c)]
     ops = [flat(c, dv), flat(c, dk), flat(c, dk), flat(c, c), flat(c, dk),
            flat(1, dk)]
-    shapes = [(b * h, n, *spec.block_shape[2:]) for spec in ops]
+    shapes = [(p.b * p.h // p.groups, p.n, *spec.block_shape[2:])
+              for spec in ops]
     return ins, ops, shapes
 
 
-def _prep_inputs(q, k, v, g, beta, n, c):
-    """[B, S, H, d] as [B, S, H d] (no copy); beta [B, S, H], and a gate
-    a head, as [B H, N, 1, C] (one small transpose)."""
-    b, s, h = beta.shape
-    wide = lambda x: x.reshape(b, s, -1)  # noqa: E731
+def _prep_inputs(q, k, v, g, beta, chunk, groups: int = 1):
+    """(q, k, v, g, beta as the preparation's calls read them, what the
+    calls are built from): [B, S, H, d] as [B, S, H d] (no copy); beta
+    [B, S, H], and a gate a head, as [B H, N, 1, C] (one small
+    transpose). Made ONCE for all the head groups' calls: a loop over the
+    groups carries these and nothing of the model's own shapes."""
+    p = _Prep(*_prep_geometry(q, v, chunk, groups), chunk, g.ndim == 3,
+              groups)
+    wide = lambda x: x.reshape(p.b, p.n * chunk, -1)  # noqa: E731
     rows = lambda x: jnp.moveaxis(  # noqa: E731
-        x, 2, 1).reshape(b * h, n, 1, c)
-    beta = rows(beta)
+        x, 2, 1).reshape(p.b * p.h, p.n, 1, chunk)
     return (wide(q), wide(k), wide(v),
-            rows(g) if g.ndim == 3 else wide(g), beta)
+            rows(g) if p.head_gate else wide(g), rows(beta)), p
 
 
-def _gauge_heads(key: int, value: int):
+def _gauge_heads(key: int, value: int, groups: int):
     """Trace time, host only: whether q and k reach the kernels at fewer
-    heads than v is a function of shapes, so it is said where the
+    heads than v, and in how many head groups the scan runs them, is a
+    function of shapes and of the caller's count, so it is said where the
     preparation's kernel is built."""
     reg = _registry()
     if reg is None:
         return
     g = reg.gauge("ds_kda_heads",
                   "heads of q and k (key) and of v, g, beta (value) of the "
-                  "delta-rule scan's preparation kernel last built")
+                  "delta-rule scan's preparation kernel last built, and "
+                  "the head groups it runs them in (groups)")
     g.set(key, kind="key")
     g.set(value, kind="value")
+    g.set(groups, kind="groups")
 
 
-def _prepare_forward(q, k, v, g, beta, chunk):
-    b, n, h, rep, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
-    _check_chip_shapes(chunk, dk, dv)
-    _gauge_heads(h // rep, h)
-    head_gate = g.ndim == 3
-    ins, ops, shapes = _prep_specs(b, n, h, chunk, dk, dv, nck, heads,
-                                   head_gate, rep)
-    f32, dt = jnp.float32, q.dtype
+def _prep_grid(p: _Prep, kernel, inputs: int, carried: int, **specs):
+    """The preparation's grid and kernel, either direction."""
+    return dict(
+        kernel=_with_group(functools.partial(
+            kernel, heads=p.heads, nck=p.nck, c=p.c, dk=p.dk, dv=p.dv,
+            head_gate=p.head_gate, rep=p.rep), inputs, carried),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(p.b, p.h // p.groups // p.heads, p.n // p.nck), **specs),
+        compiler_params=_PREP_PARAMS, interpret=_interpret())
+
+
+def _prepare_forward(args, p: _Prep, grp=0):
+    """The six operands of head group ``grp``, [B H / G, N, C, .]
+    (``shrink`` [B H / G, N, dk]), read from the whole q, k, v, g and beta
+    (``args``, ``p``: ``_prep_inputs``) at the group's heads."""
+    _check_chip_shapes(p.c, p.dk, p.dv)
+    _gauge_heads(p.h // p.rep, p.h, p.groups)
+    ins, ops, shapes = _prep_specs(p)
+    f32, dt = jnp.float32, args[0].dtype
     out_shape = [jax.ShapeDtypeStruct(s, d) for s, d in zip(
         shapes, (f32, dt, dt, dt, dt, f32))]
-    args = _prep_inputs(q, k, v, g, beta, n, chunk)
     call = pl.pallas_call(
-        functools.partial(_prep_fwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv, head_gate=head_gate, rep=rep),
-        grid=(b, h // heads, n // nck),
-        in_specs=ins, out_specs=ops, out_shape=out_shape,
-        compiler_params=_PREP_PARAMS,
-        cost_estimate=_prep_cost(b * h * n, chunk, dk, dv, args, out_shape,
-                                 backward=False),
-        interpret=_interpret(),
+        **_prep_grid(p, _prep_fwd_kernel, len(args), 0, in_specs=ins,
+                     out_specs=ops),
+        out_shape=out_shape,
+        cost_estimate=_prep_cost(p, _nbytes(*args) // p.groups
+                                 + _nbytes(*out_shape), backward=False),
         name="ds_kda_prep_fwd",
     )
     u_v, w, q_in, a_qk, k_out, shrink = _bind(
-        call, "ds.kda_prep_fwd", ("kda_prep_fwd", chunk, nck, heads, rep),
-        *args)
-    return u_v, w, q_in, a_qk, k_out, shrink.reshape(b * h, n, dk)
+        call, "ds.kda_prep_fwd", ("kda_prep_fwd", p), _group(grp), *args)
+    return u_v, w, q_in, a_qk, k_out, shrink.reshape(-1, p.n, p.dk)
 
 
-def _prepare_backward(q, k, v, g, beta, cts, chunk):
-    b, n, h, rep, dk, dv, nck, heads = _prep_geometry(q, v, chunk)
-    head_gate = g.ndim == 3
-    ins, ops, _ = _prep_specs(b, n, h, chunk, dk, dv, nck, heads, head_gate,
-                              rep)
-    args = _prep_inputs(q, k, v, g, beta, n, chunk)
+def _prepare_backward(args, cts, p: _Prep, grp=0, into=None):
+    """dq, dk, dv, dg and dbeta of head group ``grp`` written where the
+    group's heads lie in the WHOLE gradients, in ``args``' layout
+    (``_prep_gradients`` is the way back to the model's): into ``into``,
+    the five buffers the groups carry along, which are returned, or with
+    one group into new ones (None). Every block is written by exactly one
+    group, so nothing is zeroed and nothing is added."""
+    ins, ops, _ = _prep_specs(p)
     *mats, dshrink = cts
-    cts = (*mats, dshrink.reshape(b * h, n, 1, dk))
-    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args]
+    cts = (*mats, dshrink.reshape(-1, p.n, 1, p.dk))
+    carried = () if into is None else tuple(into)
+    first = 1 + len(args) + len(cts)        # behind the group's index
     call = pl.pallas_call(
-        functools.partial(_prep_bwd_kernel, heads=heads, nck=nck, c=chunk,
-                          dk=dk, dv=dv, head_gate=head_gate, rep=rep),
-        grid=(b, h // heads, n // nck),
-        in_specs=ins + ops, out_specs=ins, out_shape=out_shape,
-        compiler_params=_PREP_PARAMS,
-        cost_estimate=_prep_cost(b * h * n, chunk, dk, dv, (*args, *cts),
-                                 out_shape, backward=True),
-        interpret=_interpret(),
+        **_prep_grid(p, _prep_bwd_kernel, len(args) + len(cts),
+                     len(carried),
+                     in_specs=ins + ops + [_CARRIED] * len(carried),
+                     out_specs=ins),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args],
+        input_output_aliases={first + i: i for i in range(len(carried))},
+        cost_estimate=_prep_cost(p, 2 * _nbytes(*args) // p.groups
+                                 + _nbytes(*cts), backward=True),
         name="ds_kda_prep_bwd",
     )
-    dq, dk_, dv_, dg, dbeta = _bind(
-        call, "ds.kda_prep_bwd", ("kda_prep_bwd", chunk, nck, heads, rep),
-        *args, *cts)
+    return tuple(_bind(
+        call, "ds.kda_prep_bwd", ("kda_prep_bwd", p, into is None),
+        _group(grp), *args, *cts, *carried))
+
+
+def _prep_gradients(grads, q, k, v, g, beta):
+    """``_prepare_backward``'s five in the model's shapes: the rows of
+    dbeta, and of a gate a head's dg, back to [B, S, H]."""
+    dq, dk_, dv_, dg, dbeta = grads
     tokens = lambda x: jnp.moveaxis(  # noqa: E731
-        x.reshape(b, h, n * chunk), 1, 2)
-    dbeta = tokens(dbeta)
+        x.reshape(*beta.shape[::2], beta.shape[1]), 1, 2)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            tokens(dg) if head_gate else dg.reshape(g.shape), dbeta)
+            tokens(dg) if g.ndim == 3 else dg.reshape(g.shape),
+            tokens(dbeta))
 
 
-def _prep_cost(chunks, c, dk, dv, ins, outs, *, backward: bool):
+def _prep_cost(p: _Prep, nbytes, *, backward: bool):
     """Matmul passes as bf16 FLOPs (a float32 product is six), the
     exponentials, and every operand's one trip. The FLOPs are the
     mathematics': a [C, C] product counts ``2 C^3`` a pass whether it runs
     alone or beside another head's, where the pair takes half the passes
     through the MXU (and multiplies as many zeros)."""
+    c, dk, dv = p.c, p.dk, p.dv
+    chunks = p.b * p.h // p.groups * p.n
     square = 2 * c * c * c
     flops = (6 * 10 * square + 2 * c * c * dk       # the inverse; G
              + 2 * 2 * c * c * dk                   # the score blocks
@@ -932,8 +1055,6 @@ def _prep_cost(chunks, c, dk, dv, ins, outs, *, backward: bool):
     if backward:
         flops += (6 * 2 * square + 6 * 2 * c * c * dk
                   + 2 * 2 * c * c * (dk + dv) + 2 * 4 * c * c * dk)
-    nbytes = sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
-                 for x in (*ins, *outs))
     return pl.CostEstimate(
         flops=int(chunks * flops), bytes_accessed=int(nbytes),
         transcendentals=int(chunks * (2 if backward else 1) * c * dk
@@ -948,7 +1069,7 @@ def _recurrence(*ops_and_dtype):
 
 def _recurrence_fwd(u_v, w, q_in, a_qk, k_out, shrink, out_dtype):
     ops = (u_v, w, q_in, a_qk, k_out, shrink)
-    return _forward(ops, out_dtype, states=False), ops
+    return _forward(ops, out_dtype, states=False)[:, :w.shape[1]], ops
 
 
 def _recurrence_bwd(out_dtype, ops, do):
@@ -976,17 +1097,18 @@ def kda_recurrence(u_v, w, q_in, a_qk, k_out, shrink, *, out_dtype):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _prepare(q, k, v, g, beta, chunk):
-    return _prepare_forward(q, k, v, g, beta, chunk)
+    return _prepare_forward(*_prep_inputs(q, k, v, g, beta, chunk))
 
 
 def _prepare_fwd(q, k, v, g, beta, chunk):
-    return _prepare_forward(q, k, v, g, beta, chunk), (q, k, v, g, beta)
+    return _prepare(q, k, v, g, beta, chunk), (q, k, v, g, beta)
 
 
 def _prepare_bwd(chunk, inputs, cts):
     # opened here, as _recurrence_bwd opens it
     with jax.named_scope("ds.kda_scan"):
-        return _prepare_backward(*inputs, cts, chunk)
+        args, p = _prep_inputs(*inputs, chunk)
+        return _prep_gradients(_prepare_backward(args, cts, p), *inputs)
 
 
 _prepare.defvjp(_prepare_fwd, _prepare_bwd)
@@ -1003,10 +1125,6 @@ def kda_prepare(q, k, v, g, beta, *, chunk: int):
     never repeat them, and dq, dk come back at ``Hk`` heads, the value
     heads' parts summed in float32. ``S`` must be a multiple of ``chunk``
     and ``chunk`` of ``SUB``."""
-    b, s, h, _ = v.shape
-    if s % chunk or chunk % SUB:
-        raise ValueError(
-            f"chunk_kda: sequence {s} must be a multiple of the chunk "
-            f"{chunk}, and the chunk of {SUB}")
+    b, _, h, _ = v.shape
     return tuple(x.reshape(b, h, *x.shape[1:])
                  for x in _prepare(q, k, v, g, beta, chunk))

@@ -99,8 +99,9 @@ KIND_SCOPES = (
     "ds.kda",          # models/kimi_linear.py _mix: KDA's projections,
     #                    convolutions, gates, norm and output matmul
     "ds.kda_scan",     # ops/kda.py chunk_kda: the chunked delta rule (the
-    #                    four kernels and XLA's copies round them; the two
-    #                    backward rules open it again, outside the forward's)
+    #                    four kernels, a head group after the other, and
+    #                    what XLA leaves round them; the backward rule
+    #                    opens it again, outside the forward's)
     "ds.kda_prep_fwd",  # ops/pallas/kda.py _prepare_forward: ds_kda_prep_fwd
     "ds.kda_prep_bwd",  # ops/pallas/kda.py _prepare_backward: ds_kda_prep_bwd
     "ds.kda_fwd",      # ops/pallas/kda.py _forward: ds_kda_fwd, either form

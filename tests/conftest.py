@@ -313,6 +313,7 @@ _LONGEST_FIRST = (
     "test_kimi_linear_limits.py",
     "test_kimi_linear_engine.py",
     "test_kda_kernels.py",
+    "test_kda_head_groups.py",
     "test_kimi_linear_reference.py",
     "test_xing4.py",
     "test_kept_scan.py",

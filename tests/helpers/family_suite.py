@@ -181,11 +181,13 @@ def cases(family, *, trained=None, behind=None, scoped=None, paths=()):
 
     def test_a_rematted_step_runs_the_scan_twice_where_groups_are_a_loop():
         """ISSUE 51: where the head groups are a loop (Kimi-Linear) a
-        layer's forward and its groups' own rerun in the backward are what
+        layer's forward and the backward rule's own preparation are what
         is left: the preparation's forward and ``ds_kda_fwd`` twice a
         backward kernel, three times on ``policy=None`` (the layer's rerun
         made ``o`` again); ONE group (Qwen3-Next) keeps nothing: three
-        under either (the row's ``scan_runs``). Every other kernel but
+        under either (the row's ``scan_runs``). The traced step holds
+        each of the scan's calls once a layer at any count of groups
+        (ISSUE 59: the body of the ONE rolled loop). Every other kernel but
         ``ds_flash_fwd`` runs as often as under ``policy=None``: the
         backward still needs q, k, v, g and beta, so the convolutions rerun,
         and the gated norm (ISSUE 55) runs its forward twice and its

@@ -1,8 +1,10 @@
 """The delta rule's scan under a rematted layer (ISSUE 51): ``ops/kda.py``
-``chunk_kda`` declares its ``o`` kept (``ops/pallas/_common.py`` ``_keep``),
-so a checkpoint whose policy saves the name runs the scan's forward kernels
-twice a backward (the forward; the head groups' own checkpoint) and not
-three times where the head groups are a loop (one group keeps nothing),
+``chunk_kda`` declares its ``o`` kept (``ops/pallas/_common.py`` ``_keep``,
+in the forward rule of the ONE ``custom_vjp`` over the grouped scan since
+ISSUE 59), so a checkpoint whose policy saves the name runs the scan's
+forward kernels twice a backward (the forward; the backward rule's own
+preparation and checkpoint form) and not three times where the head groups
+are more than one (one group keeps nothing),
 alone and per shard of the batch on a mesh, with the bits the rerun makes;
 and the one family whose whole step cannot be held bit for bit on the CPU.
 The engine's steps by kernel and the other families bit for bit:
@@ -49,7 +51,9 @@ def test_a_rematted_scan_keeps_its_output(gate, form):
     alone and per shard of the batch on a mesh (``sharded_chunk_kda``: the
     name lies inside the ``shard_map``, where the policy still sees it):
     two forwards a backward in two head groups, three under
-    ``policy=None``."""
+    ``policy=None``. The traced program holds every call ONCE whatever
+    the count: the body of the one rolled loop over the groups
+    (``ops/kda.py`` ``_each_group``)."""
     scan = functools.partial(kda.chunk_kda, head_groups=2)
     if form == "per_shard":
         topo = MeshTopology(TopologyConfig(fsdp=4, tp=2))
@@ -116,7 +120,7 @@ def test_the_heads_stack_is_the_scans_output_where_the_kernel_wrote_it(
 
 
 def test_one_head_group_keeps_nothing():
-    """With one group the map is no loop and ``chunk_kda`` names nothing
+    """With one group there is no loop and ``chunk_kda`` names nothing
     (XLA merges the layer's rerun of the preparation with the group's
     there, and a kept ``o`` cost the Qwen3-Next cell's step more than its
     one ``ds_kda_fwd``: ``ops/kda.py``): three forwards a backward under

@@ -316,7 +316,7 @@ def test_the_other_architectures_steps_run_nothing_of_kda(monkeypatch,
 
     now = step_text()
     for module, names in ((kda_ops, ("chunk_kda", "sharded_chunk_kda",
-                                     "_chunk_kda", "recurrent_kda")),
+                                     "_scan", "recurrent_kda")),
                           (kda_kernels, ("kda_prepare", "kda_recurrence",
                                          "_Chunk", "_forward", "_backward"))):
         for name in names:

@@ -183,14 +183,16 @@ def test_the_step_holds_no_repeated_q_or_k_and_the_parents_kernel_counts():
             pre, key=lambda a: a.size)
 
 
-@pytest.mark.parametrize("family, key, value", [("qwen3_next", 2, 4),
-                                                ("kimi_linear", 2, 2)])
+@pytest.mark.parametrize("family, key, value, groups", [
+    ("qwen3_next", 2, 4, 1), ("kimi_linear", 4, 4, 2)])
 def test_the_gauge_says_the_heads_the_preparation_was_built_for(
-        family, key, value):
-    """``ds_kda_heads{kind="key"|"value"}`` is set where the preparation's
-    kernel is built (trace time): 2 key heads to 4 value heads at the tiny
-    Qwen3-Next, as many of each at the tiny Kimi-Linear (a head group of
-    its two). With telemetry off nothing is touched."""
+        family, key, value, groups):
+    """``ds_kda_heads{kind="key"|"value"|"groups"}`` is set where the
+    preparation's kernel is built (trace time): 2 key heads to 4 value
+    heads in one head group at the tiny Qwen3-Next, as many of each in two
+    groups at the tiny Kimi-Linear (the WHOLE arrays' heads since ISSUE
+    59: a group is an offset in them, not a slice). With telemetry off
+    nothing is touched."""
     _, grad, params = _loss_gradient(family)
     jax.eval_shape(grad, params)
     telemetry.configure()
@@ -199,8 +201,8 @@ def test_the_gauge_says_the_heads_the_preparation_was_built_for(
     _, grad, params = _loss_gradient(family)
     jax.eval_shape(grad, params)
     gauge = telemetry.get_registry().get("ds_kda_heads")
-    assert (gauge.value(kind="key"), gauge.value(kind="value")) == (
-        key, value)
+    assert (gauge.value(kind="key"), gauge.value(kind="value"),
+            gauge.value(kind="groups")) == (key, value, groups)
 
 
 # ---- the held share and the gated shared expert ----------------------------

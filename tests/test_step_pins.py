@@ -73,10 +73,18 @@ from helpers.families import program
 # seeded spreads of its cell; taken on its own tree, the first that has the
 # family) and cut ``grouped_matmul.backward`` by columns for an expert too
 # wide to hold: the nine rows before it stand, their experts are held whole
-# and lower to the text they lowered to.
+# and lower to the text they lowered to. PR 59 re-took ``kimi_linear`` and
+# ``qwen3_next`` by design (the delta-rule scan's head groups are an offset
+# in its kernels' index maps and ONE ``custom_vjp`` spans the grouped scan,
+# ``ops/kda.py`` ``_scan``: the four calls take the group's index as a
+# scalar-prefetch operand and the whole arrays, at one group too, where the
+# offset is 0 of the same calls; Kimi's two groups here are one rolled loop
+# over buffers that are not initialised; the ``lax.map``, the per-group
+# ``jax.checkpoint`` and ``_kept`` are gone; the seeded weights are the
+# parent's); the eight other rows stand: no other family calls the op.
 _PINS = {
     "kimi_linear": (
-        "96351243eb33e8ab98c087dc598f18c51becb25bed1506d3e460341bd864bfe7",
+        "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
         7191.956370612894),
     "granite_hybrid": (
         "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
@@ -97,7 +105,7 @@ _PINS = {
         "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
         2339.9930016614694),
     "qwen3_next": (
-        "b9fddf2a2a4d287989936797e229ff20f68344e9ffd0978ac858e42b70b9e743",
+        "cec92a9b712f3c14307258a68d2c40626f56876a508a39728df86696ffa32b1d",
         39458.17879846059),
     "lfm2_moe": (
         "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",

@@ -295,8 +295,11 @@ def test_kda_preparation_kernels_compile_for_v5e(gate, monkeypatch):
     cts = (sd((b * h, n, c, d), f32), sd((b * h, n, c, d), bf),
            sd((b * h, n, c, d), bf), sd((b * h, n, c, c), bf),
            sd((b * h, n, c, d), bf), sd((b * h, n, d), f32))
-    fwd = jax.jit(lambda *a: kernels._prepare_forward(*a, c))
-    bwd = jax.jit(lambda *a: kernels._prepare_backward(*a[:5], a[5:], c))
+    fwd = jax.jit(lambda *a: kernels._prepare_forward(
+        *kernels._prep_inputs(*a, c)))
+    bwd = jax.jit(lambda *a: (lambda ins, p: kernels._prep_gradients(
+        kernels._prepare_backward(ins, a[5:], p), *a[:5]))(
+            *kernels._prep_inputs(*a[:5], c)))
     for name, fn, args in (("ds_kda_prep_fwd", fwd, ins),
                            ("ds_kda_prep_bwd", bwd, (*ins, *cts))):
         compiled = fn.lower(*args).compile()
@@ -445,10 +448,13 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
     ``dw`` and the products with ones are what interpret mode cannot
     refuse. Then a whole KDA mixer at the cell's widths and four head
     groups: q, k and v leave their kernels in the layout the scan's
-    kernels read, so between them lies ONE bf16 copy a tensor each way
-    (the split into head groups, its merge in the backward) and none under
-    ds.mix_pre, where the parent relaid each out in float32 and bf16.
-    Then ``sharded_short_conv`` on ``v5e:2x2`` with the batch over
+    kernels read, and a head group is an offset in those kernels' index
+    maps (ISSUE 59), so between them lies NO copy of a tensor, under
+    ds.kda_scan (until PR 59 one bf16 copy a tensor each way: the split
+    into head groups, its merge in the backward) or under ds.mix_pre
+    (where PR 43's parent relaid each out in float32 and bf16), and the
+    scan's four kernels are called as often as the groups' ONE rolled loop
+    holds them (once each direction), at four groups and at one. Then ``sharded_short_conv`` on ``v5e:2x2`` with the batch over
     ``fsdp``, where the bare call cannot be partitioned."""
     import re
 
@@ -459,8 +465,11 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
                                             topology_name="v5e:2x2")
     except Exception as e:      # no libtpu here: nothing to compile with
         pytest.skip(f"no v5e:2x2 topology description: {e}")
+    import numpy as np
+
     from deepspeed_tpu.models.kimi_linear import (KimiLinear,
                                                   kimi_linear_config)
+    from deepspeed_tpu.models.transformer import _remat_policy
     from deepspeed_tpu.ops import layers as L
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -490,20 +499,38 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
         jax.jit(L.short_conv).lower(sd(1, 8192, 4160), sd(4, 4160))
 
     # a KDA mixer of the cell, forward, remat's rerun and backward
-    model = KimiLinear(config=kimi_linear_config(
-        "48b-a3b", kda_head_groups=4, param_dtype=bf))
-    p = jax.eval_shape(lambda k: model._init_layer(k, ("kda", "dense")),
-                       jax.random.PRNGKey(0))["kda"]
-    p = jax.tree.map(lambda x: sd(*x.shape), p)
-    _, kda_fn, conv_fn, norm_fn = model._layer_fns(None, None)
+    def mixer_hlo(groups):
+        model = KimiLinear(config=kimi_linear_config(
+            "48b-a3b", kda_head_groups=groups, param_dtype=bf))
+        p = jax.eval_shape(lambda k: model._init_layer(k, ("kda", "dense")),
+                           jax.random.PRNGKey(0))["kda"]
+        p = jax.tree.map(lambda x: sd(*x.shape), p)
+        _, kda_fn, conv_fn, norm_fn = model._layer_fns(None, None)
 
-    def mixer(p, h):
-        with jax.named_scope("ds.kda"):
-            return model._kda(p, h, kda_fn, conv_fn, norm_fn)
+        def mixer(p, h):
+            with jax.named_scope("ds.kda"):
+                return model._kda(p, h, kda_fn, conv_fn, norm_fn)
 
-    hlo = jax.jit(jax.grad(lambda p, h: jnp.sum(
-        jax.checkpoint(mixer)(p, h).astype(f32) ** 2), argnums=(0, 1))).lower(
-            p, sd(1, 16384, 2304)).compile().as_text()
+        return jax.jit(jax.grad(lambda p, h: jnp.sum(jax.checkpoint(
+            mixer, policy=_remat_policy("nothing_saveable"))(p, h).astype(
+                f32) ** 2), argnums=(0, 1))).lower(
+                    p, sd(1, 16384, 2304)).compile().as_text()
+
+    def scan_calls(hlo):
+        return tuple(calls(hlo, k) for k in (
+            "ds_kda_prep_fwd", "ds_kda_prep_bwd", "ds_kda_fwd",
+            "ds_kda_bwd"))
+
+    # ONE group keeps nothing and the layer's rerun runs the scan again:
+    # XLA merges the rerun's preparation with the backward rule's (no loop
+    # hides it), ``ds_kda_fwd`` is the forward's, the rerun's and the
+    # checkpoint form
+    assert scan_calls(mixer_hlo(1)) == (2, 1, 3, 1)
+    hlo = mixer_hlo(4)
+    # four groups: ``o`` is kept, the rerun holds no kernel of the scan;
+    # the forward and the backward rule each hold their calls once, the
+    # body of the rolled loop over the four
+    assert scan_calls(hlo) == (2, 1, 2, 1)
     assert (calls(hlo, "ds_short_conv_fwd"),
             calls(hlo, "ds_short_conv_bwd")) == (6, 3)
     # ISSUE 55: the gated norm behind the scan is its kernel pair (the
@@ -511,7 +538,7 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
     # ds.mix_post holds nothing else; the scan's ``o`` reaches it, and
     # ``do`` leaves it, as the heads' stack the scan's kernels write and
     # read, so no instruction moves a bf16 [., 16384, ., 128] of ``o``'s
-    # size but the three splits of q, k and v counted below
+    # size, nor of q's, k's and v's (the checks below)
     assert (calls(hlo, "ds_gated_norm_fwd"),
             calls(hlo, "ds_gated_norm_bwd")) == (2, 1)
     post = [line for line in hlo.splitlines() if "ds.mix_post" in line]
@@ -530,10 +557,22 @@ def test_short_conv_kernels_compile_for_v5e_and_leave_one_copy_a_tensor(
             moved["mix_pre" if "ds.mix_pre" in path else
                   "bwd" if "transpose(jvp" in path.split(";")[0]
                   and "rematted" not in path else "fwd"].append(path)
-    # q, k, v split into the head groups: forward and remat's rerun; their
-    # cotangents merged again
+    # q, k, v are not split into the head groups, forward or rerun, nor
+    # their cotangents merged again (6, 3, [] until PR 59)
     assert (len(moved["fwd"]), len(moved["bwd"]), moved["mix_pre"]) == (
-        6, 3, []), moved
+        0, 0, []), moved
+    # and nothing of q's, k's, v's, the float32 g's or o's size is copied,
+    # transposed, sliced or updated in place under the scan's scope, in a
+    # fusion or out of one: the kernels read and write the whole arrays
+    size = 16384 * 4096
+    under_scan = [
+        line.strip()[:160] for line in hlo.splitlines()
+        for m in [re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* (copy|transpose|"
+            r"dynamic-slice|dynamic-update-slice)\(.*op_name=\"[^\"]*"
+            r"ds\.kda_scan", line)]
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= size]
+    assert not under_scan, under_scan
 
     mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
     act = mt.sharding(mt.batch_axes(), "sp")

@@ -33,18 +33,19 @@ Qwen3-Next cell. A line a head count:
 - ``scan_ms``: the recurrence in PR 31's form (``scan_recurrence`` below,
   a copy: ``jax.lax.scan`` over the chunks, autodiff backward) on the same
   operands, forward alone and forward with backward, device busy time;
-- ``chunk_kda_ms`` (at 8 heads): ``jax.grad`` of one head group of
-  ``ops.kda.chunk_kda`` under its ``jax.checkpoint`` (so the backward with
-  the preparation's rerun; the loss is linear in ``o``, so the first
-  forward is dead) with the kernels and with the ``jax.numpy`` preparation
-  in their place: device busy time a call, each kernel's part, the rest;
+- ``chunk_kda_ms`` (at 8 heads): ``jax.grad`` of ``ops.kda.chunk_kda`` on
+  one head group's heads (so the backward with the preparation run again;
+  the loss is linear in ``o``, so the first forward is dead): device busy
+  time a call, each kernel's part, the rest;
 - ``layer_ms`` (at 32 heads): ``jax.grad`` of a layer's ``chunk_kda`` (four
-  groups under ``lax.map`` with a gate a channel, ONE with a gate a head,
-  as the two cells run it, each under its checkpoint, the whole under the
-  layer's remat with the models' policy, which keeps ``o`` of four; the loss is
-  quadratic: what a step runs of a layer, the forward, the groups' rerun
-  and the backward), split the same way: ``rest`` is what ``lax.map`` and
-  XLA's copies add;
+  head groups with a gate a channel, ONE with a gate a head, as the two
+  cells run it, under the layer's remat with the models' policy, which
+  keeps ``o`` of four; the loss is quadratic: what a step runs of a layer,
+  the forward, the backward's preparation and the backward), split the
+  same way: ``outside_kernels`` (``rest`` until PR 59, the same reading)
+  is the layer's time outside the four kernels, what XLA moves round
+  them, and ``moves`` the part of it in ops named copy, transpose, slice
+  or dynamic-update-slice;
 - ``err`` / ``prep_err``: the recurrence's ``o`` and six cotangents against
   the scan's, the preparation's six operands and five gradients against
   the ``jax.numpy`` form's: largest difference over the largest value.
@@ -150,12 +151,16 @@ def kernel_ms(events) -> float:
 
 
 def by_kernel(events) -> dict:
-    """Device busy time a call, each KDA kernel's part and the rest."""
+    """Device busy time a call, each KDA kernel's part, the time outside
+    them and the part of that in ops that only move data."""
     out = {"busy": busy_ms(events)}
     for k in ("ds_kda_prep_fwd", "ds_kda_prep_bwd", "ds_kda_fwd",
               "ds_kda_bwd"):
         out[k] = busy_ms(events, rf"^%?{k}[.\d]* = ")
-    out["rest"] = 2 * out["busy"] - sum(out.values())
+    out["outside_kernels"] = 2 * out["busy"] - sum(out.values())
+    out["moves"] = busy_ms(
+        events, r"^%?(copy|transpose|slice|dynamic-slice|dynamic_slice"
+        r"|dynamic-update-slice|dynamic_update_slice)[.\w\-]* = ")
     return out
 
 
@@ -177,7 +182,7 @@ def main(argv) -> int:
     from deepspeed_tpu.ops.pallas import _common, kda as kernels
     from helpers import kda_reference
     bf = jnp.bfloat16
-    kernel_prepare = kda.kda_prepare
+    kernel_prepare = kernels.kda_prepare
     head_gate = opts.gate == "head"
     for heads in opts.heads:
         key_heads = opts.key_heads or heads
@@ -202,9 +207,12 @@ def main(argv) -> int:
         fwd = jax.jit(lambda *o: kernels._forward(o, bf, states=False))
         states = jax.jit(lambda *o: kernels._forward(o, bf, states=True))
         bwd = jax.jit(lambda *a: kernels._backward(a[:6], a[6], a[7]))
-        prep_fwd = jax.jit(lambda *a: kernels._prepare_forward(*a, CHUNK))
-        prep_bwd = jax.jit(
-            lambda *a: kernels._prepare_backward(*a[:5], a[5:], CHUNK))
+        prep_fwd = jax.jit(lambda *a: kernels._prepare_forward(
+            *kernels._prep_inputs(*a, CHUNK)))
+        prep_bwd = jax.jit(lambda *a: (
+            lambda ins, p: kernels._prep_gradients(
+                kernels._prepare_backward(ins, a[5:], p), *a[:5]))(
+                    *kernels._prep_inputs(*a[:5], CHUNK)))
         ref_fwd = jax.jit(lambda *a: jnp_prepare(*a, chunk=CHUNK))
         pull = lambda f: jax.jit(lambda *a: jax.vjp(  # noqa: E731
             lambda *x: tuple(y.reshape(-1, *y.shape[2:])
@@ -224,7 +232,8 @@ def main(argv) -> int:
         kernels._inverse_unit_lower = lambda mats: mats
         _common._TRACED.clear()
         no_inverse = kernel_ms(traced(jax, jax.jit(
-            lambda *a: kernels._prepare_forward(*a, CHUNK)), args))
+            lambda *a: kernels._prepare_forward(
+                *kernels._prep_inputs(*a, CHUNK))), args))
         kernels._inverse_unit_lower = inverse
         _common._TRACED.clear()
         line = {"heads": heads, "key_heads": key_heads, "gate": opts.gate,
@@ -252,7 +261,7 @@ def main(argv) -> int:
                 "err": dict(zip(
                     ("o", "du_v", "dw", "dq_in", "da_qk", "dk_out",
                      "dshrink"),
-                    map(rel_err, vjp(kda.kda_recurrence)(*ops, do),
+                    map(rel_err, vjp(kernels.kda_recurrence)(*ops, do),
                         vjp(scan_recurrence)(*ops, do)))),
                 "prep_err": dict(zip(
                     ("u_v", "w", "q_in", "a_qk", "k_out", "shrink",
@@ -269,25 +278,17 @@ def main(argv) -> int:
             line["prep_ms"]["repeated_bwd_busy"] = busy_ms(traced(
                 jax, pull(repeated), (*args, *cts)))
         del ops, flat, ck, do, cts
-        if heads == 8:      # a head group of the cell, without lax.map
+        if heads == 8:      # a head group's heads of the cell, alone
             wgt = jnp.asarray(np.random.default_rng(2).normal(
                 size=args[2].shape), bf)
-            whole = {}
-            for name, f in (("kernels", kernel_prepare),
-                            ("jnp_prepare", jnp_prepare)):
-                kda.kda_prepare = f
-                # a new function a form: jax.checkpoint keeps its trace
-                group = jax.checkpoint(
-                    lambda *a: kda._chunk_kda(*a, chunk=CHUNK))
-                grad = jax.jit(jax.grad(
-                    lambda *a: jnp.sum(group(*a).astype(jnp.float32) * wgt),
-                    argnums=(0, 1, 2, 3, 4)))
-                ev = traced(jax, grad, args)
-                whole[name] = by_kernel(ev)
-            kda.kda_prepare = kernel_prepare
-            line["chunk_kda_ms"] = whole
-        if heads == 32:     # a layer of a cell: Kimi's four groups under
-            #                     lax.map, Qwen3-Next's one
+            grad = jax.jit(jax.grad(
+                lambda *a: jnp.sum(
+                    kda.chunk_kda(*a).astype(jnp.float32) * wgt),
+                argnums=(0, 1, 2, 3, 4)))
+            line["chunk_kda_ms"] = {"kernels": by_kernel(
+                traced(jax, grad, args))}
+        if heads == 32:     # a layer of a cell: Kimi's four head groups,
+            #                     Qwen3-Next's one
             from deepspeed_tpu.models.transformer import _remat_policy
             layer = jax.checkpoint(
                 lambda *a: kda.chunk_kda(
