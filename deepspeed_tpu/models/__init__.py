@@ -8,6 +8,7 @@ from .gptneox import GPTNeoX, gptneox_config  # noqa: F401
 from .granite_hybrid import GraniteHybrid, granite_hybrid_config  # noqa: F401
 from .internlm import InternLM, internlm_config  # noqa: F401
 from .kimi_linear import KimiLinear, kimi_linear_config  # noqa: F401
+from .laguna import Laguna, laguna_config  # noqa: F401
 from .lfm2_moe import Lfm2Moe, lfm2_moe_config  # noqa: F401
 from .llama import Llama, llama_config  # noqa: F401
 from .mellum import Mellum, mellum_config  # noqa: F401

@@ -42,18 +42,16 @@ pipeline are not here (``StackOfKinds._one_kind_only``).
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import mean_context, register_model
-from .stack import RoutedStackConfig, RoutedStackOfKinds
+from .stack import (ATTENTION_KINDS as _KINDS, RoutedStackConfig,
+                    RoutedStackOfKinds, WindowAndFullAttention)
 from .transformer import _dense_init
 
-_KINDS = {"sliding_attention": "swa", "full_attention": "full"}
 _ROPE = {
     "full_attention": {
         "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -142,40 +140,22 @@ def mellum_config(size: str = "12b-a2.5b", **overrides) -> MellumConfig:
 
 
 @register_model("mellum")
-class Mellum(RoutedStackOfKinds):
+class Mellum(WindowAndFullAttention, RoutedStackOfKinds):
     def __init__(self, config: MellumConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
             raise ValueError(
                 "pass either an explicit config or size/overrides, not both")
         c = config or mellum_config(size or "12b-a2.5b", **overrides)
-        if len(c.layer_types) != c.num_layers or set(c.layer_types) - set(
-                _KINDS):
-            raise ValueError(
-                f"Mellum needs {c.num_layers} layer_types of "
-                f"{sorted(_KINDS)}, not {c.layer_types}")
-        if set(c.layer_types) - set(c.rope_parameters):
-            raise ValueError(
-                f"rope_parameters has no section for "
-                f"{sorted(set(c.layer_types) - set(c.rope_parameters))}")
-        if "sliding_attention" in c.layer_types and not c.sliding_window:
-            raise ValueError("sliding_attention layers need sliding_window")
         if (c.moe_router_activation != "softmax" or c.tie_embeddings
                 or c.moe_num_shared_experts or c.use_bias
                 or c.num_experts <= 0):
             raise NotImplementedError(
                 "Mellum has a softmax router over its experts, no shared "
                 "expert, no bias and an untied head")
-        if c.held_experts > c.num_experts:
-            raise ValueError(
-                f"{c.held_experts} experts held of the router's "
-                f"{c.num_experts}")
         super().__init__(c)
-        self._ropes = {
-            _KINDS[t]: L.rotary_embedding(c.max_seq_len, c.head_dim,
-                                          c.rope_theta,
-                                          scaling=c.rope_parameters[t])
-            for t in sorted(set(c.layer_types))}
+        self._check_attention_kinds()
+        self._ropes = self._rope_tables()
 
     def after_step(self, params, stats):
         """No weight moves after the optimizer's update (no selection bias
@@ -268,50 +248,5 @@ class Mellum(RoutedStackOfKinds):
             h, moe["router"], None, moe["experts"], None, k=c.moe_top_k,
             renormalise=c.moe_norm_topk, router="softmax",
             router_grad=c.held_experts == c.num_experts)
-        # the blocks the dispatch swept, from the load and its own rule
-        block = sharded_moe.held_block(h.shape[0] * h.shape[1], c.moe_top_k,
-                                       c.num_experts)
-        blocks = jnp.sum(-(-counts["load"][:c.held_experts] // block))
-        return x + y, {**counts, "blocks": blocks,
-                       "block": jnp.int32(block)}
-
-    def _mixers(self, attn_fn, act_sharding):
-        """{kind: attention of that kind's window}. ``attn_fn`` is the
-        stack's (``_attn``): the flash kernels, plain attention, or what
-        the engine bound for a mesh, which has to take a window a call
-        (``sharded_flash_attention`` does; the sequence-parallel wrappers
-        apply none, and a window layer cannot run full-causal)."""
-        c = self.config
-        if attn_fn is L.dot_product_attention:
-            def plain(q, k, v, window):
-                bias = (None if window is None
-                        else L.window_bias(q.shape[1], window))
-                return attn_fn(q, k, v, causal=True, bias=bias)
-            of = lambda w: functools.partial(plain, window=w)  # noqa: E731
-        else:
-            from ..ops.pallas.flash_attention import flash_attention
-            if attn_fn is not flash_attention and not getattr(
-                    attn_fn, "applies_window", False):
-                raise NotImplementedError(
-                    "Mellum's window layers need an attention that applies "
-                    "a window a call: the sequence-parallel wrappers do not")
-            of = lambda w: functools.partial(  # noqa: E731
-                attn_fn, causal=True, window=w)
-        return {"swa": of(c.sliding_window), "full": of(None)}
-
-    # ---------------- sharding ----------------
-    def partition_rules(self):
-        """Tensor-parallel rules by head / expert dimension; the leading
-        axis of a ``period`` stack is the scan's and stays whole."""
-        def both(pattern, *spec):
-            return [(rf"layers/period/.*{pattern}", P(None, *spec)),
-                    (rf"layers/(lead|tail)/.*{pattern}", P(*spec))]
-
-        rules = [(r"embed/tokens", P("tp", None))]
-        for pattern, spec in [
-                (r"(swa|full)/(wq|wk|wv)$", (None, "tp")),
-                (r"(swa|full)/wo$", ("tp", None)),
-                (r"experts/(w_up|w_gate)$", ("ep", None, "tp")),
-                (r"experts/w_down$", ("ep", "tp", None))]:
-            rules += both(pattern, *spec)
-        return rules + [(r"lm_head$", P(None, "tp"))]
+        counts = self._held_blocks(counts, h.shape[0] * h.shape[1])
+        return x + y, counts

@@ -21,14 +21,21 @@ leading layers are the config's; a family gives three methods:
 not at import) and ``_one_layer(p, x, mixers)`` -> ``(x, counts)``. A
 family whose layers call a kernel beside their mixers' (the delta-rule
 families' gated norm) hands it over behind them: ``_layer_fns``.
+
+**Window and full attention layers mixed** (``WindowAndFullAttention``):
+what ``models/mellum.py`` and ``models/laguna.py`` share: the two kinds'
+names, a rotary table a kind, the mixers by window and the partition
+rules.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import ModelConfig
@@ -252,9 +259,10 @@ class RoutedStackOfKinds(StackOfKinds):
     counts back. ``loss(with_stats=True)`` returns them beside the loss,
     and the engine calls the family's ``after_step(params, stats)`` with
     the step's updated weights and takes ``(params, metrics)`` from it;
-    ``_held_metrics`` makes the metrics every such family returns, and
-    ``_balanced`` is the whole ``after_step`` of one with a selection
-    bias."""
+    ``_held_metrics`` makes the metrics every such family returns,
+    ``_held_blocks`` adds the blocks the dispatch swept to a layer's
+    counts, and ``_balanced`` is the whole ``after_step`` of one with a
+    selection bias."""
 
     def loss(self, params, batch, *, attn_fn=None, act_sharding=None,
              with_stats: bool = False):
@@ -270,6 +278,15 @@ class RoutedStackOfKinds(StackOfKinds):
         behind: the routed families' recorder."""
         from ..moe.dispatch import record_held_expert_counts
         record_held_expert_counts(reg, metrics)
+
+    def _held_blocks(self, counts, tokens: int) -> dict:
+        """A routed layer's counts with the blocks the dispatch swept,
+        from the load and the dispatch's own rule."""
+        from ..moe import sharded_moe
+        c = self.config
+        block = sharded_moe.held_block(tokens, c.moe_top_k, c.num_experts)
+        blocks = jnp.sum(-(-counts["load"][:c.held_experts] // block))
+        return {**counts, "blocks": blocks, "block": jnp.int32(block)}
 
     def _balanced(self, params, stats):
         """The ``after_step`` of a family whose routed layers select with a
@@ -326,3 +343,94 @@ class RoutedStackOfKinds(StackOfKinds):
                            moe_load_max=jnp.max(jnp.stack(tops)),
                            moe_load_min=jnp.min(jnp.stack(leasts)))
         return metrics
+
+
+# a published ``layer_types`` entry -> the key a layer's attention weights
+# lie under, the name of its mixer and the tail of its scope ds.attn_<kind>
+ATTENTION_KINDS = {"sliding_attention": "swa", "full_attention": "full"}
+
+
+class WindowAndFullAttention:
+    """What the routed stacks whose ``layer_types`` mix ``sliding_attention``
+    and ``full_attention`` share (a mixin in front of
+    ``RoutedStackOfKinds``): the config's checks, a rotary table a kind
+    from ``rope_parameters``, the mixers (a kind's window) and the
+    partition rules. The layer itself, its head counts and whatever it
+    adds (a gate, a shared expert) are the family's."""
+
+    # partition rules a family adds to the shared ones: (pattern, spec)
+    _more_rules: tuple = ()
+
+    def _check_attention_kinds(self):
+        """The config names ``num_layers`` kinds of the two, a rotary
+        section for each it names, and a window where a layer has one."""
+        c, name = self.config, type(self).__name__
+        if len(c.layer_types) != c.num_layers or set(c.layer_types) - set(
+                ATTENTION_KINDS):
+            raise ValueError(
+                f"{name} needs {c.num_layers} layer_types of "
+                f"{sorted(ATTENTION_KINDS)}, not {c.layer_types}")
+        if set(c.layer_types) - set(c.rope_parameters):
+            raise ValueError(
+                f"rope_parameters has no section for "
+                f"{sorted(set(c.layer_types) - set(c.rope_parameters))}")
+        if "sliding_attention" in c.layer_types and not c.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if c.held_experts > c.num_experts:
+            raise ValueError(
+                f"{c.held_experts} experts held of the router's "
+                f"{c.num_experts}")
+
+    def _rope_tables(self) -> dict:
+        """{kind: (cos, sin)} of the kinds the stack has, each from its
+        section of ``rope_parameters`` (``ops/layers.py``
+        ``rotary_embedding``: a section's ``partial_rotary_factor`` makes
+        the table narrower than the head)."""
+        c = self.config
+        return {
+            ATTENTION_KINDS[t]: L.rotary_embedding(
+                c.max_seq_len, c.head_dim, c.rope_theta,
+                scaling=c.rope_parameters[t])
+            for t in sorted(set(c.layer_types))}
+
+    def _mixers(self, attn_fn, act_sharding):
+        """{kind: attention of that kind's window}. ``attn_fn`` is the
+        stack's (``_attn``): the flash kernels, plain attention, or what
+        the engine bound for a mesh, which has to take a window a call
+        (``sharded_flash_attention`` does; the sequence-parallel wrappers
+        apply none, and a window layer cannot run full-causal)."""
+        c = self.config
+        if attn_fn is L.dot_product_attention:
+            def plain(q, k, v, window):
+                bias = (None if window is None
+                        else L.window_bias(q.shape[1], window))
+                return attn_fn(q, k, v, causal=True, bias=bias)
+            of = lambda w: functools.partial(plain, window=w)  # noqa: E731
+        else:
+            from ..ops.pallas.flash_attention import flash_attention
+            if attn_fn is not flash_attention and not getattr(
+                    attn_fn, "applies_window", False):
+                raise NotImplementedError(
+                    f"{type(self).__name__}'s window layers need an "
+                    f"attention that applies a window a call: the "
+                    f"sequence-parallel wrappers do not")
+            of = lambda w: functools.partial(  # noqa: E731
+                attn_fn, causal=True, window=w)
+        return {"swa": of(c.sliding_window), "full": of(None)}
+
+    def partition_rules(self):
+        """Tensor-parallel rules by head / expert dimension; the leading
+        axis of a ``period`` stack is the scan's and stays whole."""
+        def both(pattern, *spec):
+            return [(rf"layers/period/.*{pattern}", P(None, *spec)),
+                    (rf"layers/(lead|tail)/.*{pattern}", P(*spec))]
+
+        rules = [(r"embed/tokens", P("tp", None))]
+        for pattern, spec in [
+                (r"(swa|full)/(wq|wk|wv)$", (None, "tp")),
+                (r"(swa|full)/wo$", ("tp", None)),
+                (r"experts/(w_up|w_gate)$", ("ep", None, "tp")),
+                (r"experts/w_down$", ("ep", "tp", None)),
+                *self._more_rules]:
+            rules += both(pattern, *spec)
+        return rules + [(r"lm_head$", P(None, "tp"))]

@@ -184,15 +184,27 @@ def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
 
 def rotary_embedding(seq_len: int, head_dim: int, theta: float = 10000.0,
                      dtype=jnp.float32, *, scaling: dict | None = None):
-    """Precompute RoPE cos/sin tables [seq, head_dim//2]. ``scaling``: one
+    """Precompute RoPE cos/sin tables [seq, rotated // 2]. ``scaling``: one
     section of a published ``rope_parameters`` (``rope_type`` ``default``
-    or ``yarn``; its ``rope_theta`` wins over ``theta``). A YaRN table
-    is multiplied by ``attention_factor`` (``0.1 ln(factor) + 1`` where
-    the section gives none), so q k^T carries its square. Another
-    ``rope_type`` (linear, dynamic NTK, llama3, longrope) is not built."""
+    or ``yarn``; its ``rope_theta`` wins over ``theta``). Its
+    ``partial_rotary_factor`` (1 where it gives none) says what share of
+    the head is ROTATED: the table is built for ``head_dim x factor``
+    channels, YaRN's correction range is reckoned on that width, and
+    :func:`apply_rotary` rotates the leading channels of a head that is
+    wider than the table. A YaRN table is multiplied by
+    ``attention_factor`` (``0.1 ln(factor) + 1`` where the section gives
+    none), so q k^T carries its square. Another ``rope_type`` (linear,
+    dynamic NTK, llama3, longrope) is not built."""
     scaling = dict(scaling or {})
     theta = float(scaling.get("rope_theta", theta))
     kind = scaling.get("rope_type", "default")
+    part = scaling.pop("partial_rotary_factor", 1)
+    if part != 1:
+        head_dim = int(head_dim * part)
+        if head_dim <= 0 or head_dim % 2:
+            raise ValueError(
+                f"partial_rotary_factor {part} leaves {head_dim} rotated "
+                f"channels: pairs need an even number")
     scale = 1.0
     if kind == "yarn":
         inv_freq, _, _ = yarn_inv_freq(head_dim, theta, **scaling)
@@ -212,11 +224,14 @@ def rotary_embedding(seq_len: int, head_dim: int, theta: float = 10000.0,
 
 
 def apply_rotary(x, cos, sin, positions=None):
-    """Apply rotary embedding. x: [B, S, H, D]; cos/sin: [S_max, D//2] or
-    already-sliced [S, D//2]; positions: optional [B, S] int32 for
-    decode-time offsets (reference kernel: apply_rotary_pos_emb.cu)."""
+    """Apply rotary embedding. x: [B, S, H, D]; cos/sin: [S_max, R//2] or
+    already-sliced [S, R//2]; positions: optional [B, S] int32 for
+    decode-time offsets (reference kernel: apply_rotary_pos_emb.cu).
+    Where the table is narrower than the head (R < D: a partial rotation,
+    :func:`rotary_embedding`) the leading R channels are rotated, pairs
+    (i, i + R / 2), and the other D - R pass through."""
     if positions is not None:
-        cos = cos[positions]  # [B, S, D//2]
+        cos = cos[positions]  # [B, S, R//2]
         sin = sin[positions]
         cos = cos[:, :, None, :]
         sin = sin[:, :, None, :]
@@ -224,9 +239,16 @@ def apply_rotary(x, cos, sin, positions=None):
         s = x.shape[1]
         cos = cos[None, :s, None, :]
         sin = sin[None, :s, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    rot = 2 * cos.shape[-1]
+    if rot > x.shape[-1]:
+        raise ValueError(f"a table of {rot} rotated channels for a head of "
+                         f"{x.shape[-1]}")
+    xr = x if rot == x.shape[-1] else x[..., :rot]
+    x1, x2 = jnp.split(xr.astype(jnp.float32), 2, axis=-1)
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rot < x.shape[-1]:
+        parts.append(x[..., rot:].astype(jnp.float32))
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def window_bias(seq_len: int, window: int):

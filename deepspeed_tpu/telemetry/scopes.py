@@ -157,7 +157,7 @@ MIXER_SCOPES = (
     #                    gated norm over the whole row
 )
 # what a stack of window and full attention layers, each routed, opens
-# inside ds.layers in place of ds.attn (models/mellum.py), beside
+# inside ds.layers in place of ds.attn (models/mellum.py, models/laguna.py), beside
 # ds.moe_router and ds.moe_experts of KIND_SCOPES; ``tests/test_mellum.py``
 # holds this list equal to what that model's step carries. The kernels'
 # ds.flash_fwd / ds.flash_bwd lie inside the scope of their layer's kind in
@@ -249,10 +249,21 @@ MHC_SCOPES = (
     #                    to the streams
     "ds.mhc_fold",     # models/xing4.py _layer_stack: the streams summed
 )
+# what a window-and-full stack whose attention output is gated a head opens
+# inside ds.attn_swa / ds.attn_full of WINDOW_SCOPES (models/laguna.py),
+# beside ds.rope, ds.mlp of DEVICE_SCOPES (its leading dense layer) and the
+# routed layers' four and their kernels' of KIND_SCOPES (ds.moe_shared
+# among them); ``tests/test_laguna_engine.py`` holds the step to them
+GATE_SCOPES = (
+    "ds.attn_gate",    # models/laguna.py _attention: the gate's narrow
+    #                    projection (hidden -> one column a head, float32
+    #                    logits), its sigmoid and the multiply of a head's
+    #                    channels in front of wo
+)
 # every list above: what a metric file may name
 KNOWN_SCOPES = frozenset(
     DEVICE_SCOPES + KIND_SCOPES + SSM_SCOPES + MIXER_SCOPES + WINDOW_SCOPES
-    + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES)
+    + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES + GATE_SCOPES)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

@@ -265,6 +265,31 @@ FAMILIES = {
                         mhc_b_std=(2.0, 2.0, 0.5)),
         # the leading dense layer and the scanned routed ones: two traced
         rematted=2),
+    "laguna": Family(
+        # the preset's own five layers: a leading dense full layer, three
+        # routed window layers under the scan, a routed full layer
+        models.Laguna, cell=dict(moe_held_experts=8),
+        config="laguna-s-2.1-ep32-zero3-1chip", arch="laguna",
+        # Mellum's rule (sharper scores, larger values, outputs and
+        # experts, the shared expert and the dense lead among them: they
+        # share the leaf names); the gate's projection is drawn at unit
+        # logits and needs none
+        boost={"tokens": 0.02, "wq": 4.0, "wk": 4.0, "wv": 8.0, "wo": 8.0,
+               "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0},
+        held=8,
+        # 8 x 128 tokens x top-10 of 256 experts: 40 a held expert if even
+        engine=dict(calls=4, per_expert=(24, 60), block=128, steps=4,
+                    behind="traced", cell="train-lag-s8k-1chip"),
+        scopes=frozenset(set(S.DEVICE_SCOPES) - {"ds.attn"}
+                         | set(S.WINDOW_SCOPES) | set(S.GATE_SCOPES) | _MOE
+                         | {"ds.moe_shared"}),
+        # a dense window layer and a routed full one: both widths of the
+        # attention, both channel mixers
+        two_layers=dict(num_layers=2,
+                        layer_types=["sliding_attention", "full_attention"],
+                        mlp_layer_types=["dense", "sparse"]),
+        # the leading layer, the scanned window layers, the full tail
+        rematted=3),
 }
 # the rows whose step is rematted with a kept residual in it, a delta-rule
 # scan, a short convolution: what the cross-family cases are parametrised by

@@ -546,18 +546,22 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     ("ds.mhc_post", "ops/pallas/mhc.py", "_post_backward"),
     ("ds.mhc_spread", "models/xing4.py", "_layer_stack"),
     ("ds.mhc_fold", "models/xing4.py", "_layer_stack"),
+    # the gate a head (ISSUE 60)
+    ("ds.attn_gate", "models/laguna.py", "_attention"),
+    ("ds.rope", "models/laguna.py", "_attention"),
 ])
 def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
                                                           function):
-    """``MHC_SCOPES`` names, a scope, the file and the function that opens
-    it: the function's source holds the scope's name as a literal, and
-    every list of the registry is in ``KNOWN_SCOPES`` (what a metric file
-    may name: ``tests/test_benchmark_contract.py``)."""
+    """``MHC_SCOPES`` and ``GATE_SCOPES`` name, a scope, the file and the
+    function that opens it: the function's source holds the scope's name
+    as a literal, and every list of the registry is in ``KNOWN_SCOPES``
+    (what a metric file may name: ``tests/test_benchmark_contract.py``)."""
     import ast
     import pathlib
 
     import deepspeed_tpu
-    assert scope in scopes.MHC_SCOPES and scope in scopes.KNOWN_SCOPES
+    assert scope in (scopes.MHC_SCOPES + scopes.GATE_SCOPES
+                     + scopes.WINDOW_SCOPES) and scope in scopes.KNOWN_SCOPES
     source = (pathlib.Path(deepspeed_tpu.__file__).parent / file).read_text()
     body = next(ast.get_source_segment(source, node)
                 for node in ast.walk(ast.parse(source))

@@ -342,6 +342,13 @@ PARENT_COUNTS = {
                         667883384736),
     "cell/xing4.0-29b-ep8-zero3-1chip": (
         631149528, 300848088, 2463651600.0, 3470161680),
+    # PR 60's own, pinned when the family came (ISSUE 60's 653,577,216:
+    # layers 1 to 4, the head count a kind)
+    "laguna/tiny": (6621504, 575808, 3650784.0, 4536192),
+    "laguna/s-2.1": (117561953280, 8449231872, 514740548556.0,
+                     5151266850816),
+    "cell/laguna-s-2.1-ep32-zero3-1chip": (
+        653577216, 363383808, 2415689856.0, 5270980608),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -411,6 +418,8 @@ def test_counts_are_the_parents(case):
     ("lfm2_moe", dict(kda_head_groups=4)),
     ("xing4_0", dict(qk_norm_init=2.0)),
     ("kimi_linear", dict(hc_mult=4)),
+    ("laguna", dict(hc_mult=4)),
+    ("mellum", dict(num_attention_heads_per_layer=[4, 4, 4, 4])),
     ("granite_hybrid", dict(conv_L_cache=3)),
     ("kimi_linear", dict(qk_norm_init=2.0)),
     ("ouro", dict(layer_types=["attention", "attention"])),

@@ -82,6 +82,18 @@ from helpers.families import program
 # over buffers that are not initialised; the ``lax.map``, the per-group
 # ``jax.checkpoint`` and ``_kept`` are gone; the seeded weights are the
 # parent's); the eight other rows stand: no other family calls the op.
+# PR 60 added ``laguna`` (a dense window layer of 6 query heads and a routed
+# full layer of 4, over 2 key heads, 8 of 256 experts held beside the shared
+# one, the gate a head, half the full layer's head rotated; taken on its own
+# tree, the first that has the family), gave ``ops/layers.py``
+# ``apply_rotary`` / ``rotary_embedding`` a rotated width narrower than the
+# head and moved what ``models/mellum.py`` shares with it (the mixers, the
+# tables a kind, the partition rules) into ``models/stack.py``
+# ``WindowAndFullAttention``, the blocks' count into
+# ``RoutedStackOfKinds._held_blocks``: the eleven rows before it
+# stand, ``mellum``'s two among them (the same equations in the same order;
+# a whole-head table lowers to the text it lowered to,
+# ``tests/test_laguna.py``).
 _PINS = {
     "kimi_linear": (
         "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
@@ -113,6 +125,9 @@ _PINS = {
     "xing4_0": (
         "7d0db59390f004a09922ce9ac1b47e8557f20f5884037f8f0dd022c42b2a6715",
         4668.748035160373),
+    "laguna": (
+        "2154dc320f6a0d31cac3f12ba0aa7c091f83f73b6e4d4bf4d7cbac04a5517de5",
+        31325.334374967497),
 }
 # the rows that are not a family's two-layer cut under the family's name: (family, cut of its
 # layers, further switches)

@@ -838,3 +838,51 @@ def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
         sd((tokens, k), jnp.int32)).compile().as_text()
     for kernel in ("ds_moe_gmm_fwd", "ds_moe_gmm_bwd", "ds_moe_add_rows"):
         assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), kernel
+
+
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)])
+def test_flash_kernels_compile_for_v5e_at_the_laguna_cells_shapes(
+        monkeypatch, heads, window):
+    """PR 60's shapes no cell had run, compiled by Mosaic for one described
+    v5e chip: 8192 tokens of 72 query heads over 8 key heads (9 a key
+    head) with a window EQUAL to the kernel's block (every query block
+    meets two tiles and both are masked: ``tile_counts`` says 31 masked,
+    none unmasked), and of 48 over 8 causal; forward, remat's kept
+    residuals and the one-pass backward with its sum of dk and dv over a
+    group's query heads."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    import importlib
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.bfloat16, sharding=one)
+    s, kv, d = 8192, 8, 128
+    block = fa._block(s)
+    counts = fa.tile_counts(s, block, window, True)
+    if window is not None:
+        assert block == window == 512
+        assert (counts["masked"], counts["unmasked"]) == (31, 0)
+    else:
+        assert counts["skipped"] == 0 and counts["unmasked"] == 120
+    from deepspeed_tpu.models.transformer import _remat_policy
+    layer = jax.checkpoint(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           window=window),
+        policy=_remat_policy("nothing_saveable"))
+    hlo = jax.jit(jax.grad(
+        lambda q, k, v: 0.5 * jnp.sum(
+            layer(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    ).lower(sd((1, s, heads, d)), sd((1, s, kv, d)),
+            sd((1, s, kv, d))).compile().as_text()
+    for kernel in ("ds_flash_fwd", "ds_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\w]* = .*custom-call",
+                              hlo)) == 1, kernel
