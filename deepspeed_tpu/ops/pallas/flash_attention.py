@@ -37,6 +37,22 @@ Design notes (why this beats the stock two-pass kernel at model shapes):
   mask is a small part of a tile (4% of the forward, 1% of the backward:
   the vector unit has slots to spare); what a tile waits for is the
   serial chain matmul, row statistics, exp, matmul, and its relayouts.
+- **The band**: square tiles follow a window badly once it is as narrow
+  as they are. At a window of one block (512 at seq 8192) every query
+  block meets two tiles and both are masked: 31 tiles, 8.1 M pairs swept
+  a head for the 4.06 M the mask leaves live. So where the window is at
+  most two blocks wide (`band_rows`; both are known at trace time) a grid
+  step runs no loop: its block goes by groups of ``t`` rows, straight-line
+  code, and a group meets ONE span of ``round_up(window, t) + t`` rows of
+  the opposing operand (`band_span`: keys ``(r0 - window, r0 + t)`` of a
+  query group, queries ``[c0, c0 + t + window - 1)`` of a key group),
+  held inside the row at its ends. It is one call of the SAME body, with
+  the span in the place of a tile and the one mask `_cut` gives from the
+  two groups' distance, from the empty carry: the forward's online
+  rescale degenerates to one softmax pass. 5.2 M pairs a head at seq
+  8192, window 512; 18.9 M for 24.4 M at seq 16384, window 1024 (gauge
+  `ds_flash_pairs`). The grid, the BlockSpecs and the `custom_vjp` are
+  the loops'; a wider window, or none, traces to the loops' program.
 - **No lane-by-lane relayouts**: the row statistics live as columns
   ([bq, 1]) in the forward and are stored lane-dense ([1, S]); the
   forward turns them once a q block through the transpose unit, and the
@@ -171,16 +187,80 @@ def _cut(s, off, window, q_axis: int):
     return jnp.where(live, s, NEG_INF)
 
 
+# ------------------------------------------------------------------ the band
+# rows of a group: the fewest pairs swept past the mask at whole 128-lane
+# rows of statistics. On a v5e, forward + backward a head against the loops:
+# -30.6% at seq 8192, window 512 and -28.5% at 16384, 1024; at 256 rows the
+# score tile spills twice as often and the two shapes read 4.6% and 0.4% behind
+# (PERF.md section 6, PR 61)
+_BAND_ROWS = 128
+
+
+def band_rows(b: int, window: int | None, causal: bool) -> int | None:
+    """Rows ``t`` of a band group (queries in the forward, keys in the
+    backward), or None where the sweep is the loops' (no window, or one
+    past two blocks). A group of ``t`` rows meets ONE span of ``band_span``
+    rows of the opposing operand under one mask, in place of the block's
+    two or three [b, b] tiles, every one of them masked."""
+    if not causal or window is None or window > 2 * b:
+        return None
+    return min(b, _BAND_ROWS)
+
+
+def band_span(s: int, t: int, window: int) -> int:
+    """Rows of the opposing operand a group meets: a query of the group
+    sees keys in ``(r0 - window, r0 + t)`` and a key is seen by queries in
+    ``[c0, c0 + t + window - 1)``; both fit ``t``-aligned in this many."""
+    return min(s, -(-window // t) * t + t)
+
+
+def _fwd_span(g, t: int, span: int):
+    """First key group of the span of query group ``g`` (units of ``t``):
+    the span ends with the group's own keys, held at the row's start."""
+    return jnp.maximum(0, g + 1 - span // t)
+
+
+def _bwd_span(g, s: int, t: int, span: int):
+    """First query group of the span of key group ``g``: the span starts
+    with the group's own queries, held at the row's end."""
+    return jnp.minimum(g, (s - span) // t)
+
+
+def pair_counts(s: int, b: int, window, causal: bool) -> dict:
+    """Pairs a (batch x head) row's sweep computes (``swept``: the loops'
+    tiles, or the band's groups by their spans) and pairs the mask leaves
+    ``live``."""
+    t = band_rows(b, window, causal)
+    if t is None:
+        tiles = tile_counts(s, b, window, causal)
+        swept = (tiles["masked"] + tiles["unmasked"]) * b * b
+    else:
+        swept = s * band_span(s, t, window)
+    w = s if window is None else min(window, s)
+    live = w * (w + 1) // 2 + (s - w) * w if causal else s * s
+    return {"swept": swept, "live": live}
+
+
 def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool):
-    """Trace time, host only: how often the maskless body engages is a
-    function of shapes, so it is counted where the kernel is built."""
+    """Trace time, host only: how often the maskless body engages, and how
+    many of the pairs a sweep computes the mask leaves live, are functions
+    of shapes, so they are counted where the kernel is built. The band's
+    groups count as masked tiles."""
     reg = _registry()
     if reg is None:
         return
     g = reg.gauge("ds_flash_tiles",
                   "score tiles a (batch x head) row of the flash kernel "
                   "last built runs masked / unmasked / skips by the window")
-    for kind, n in tile_counts(s, b, window, causal).items():
+    t = band_rows(b, window, causal)
+    tiles = (tile_counts(s, b, window, causal) if t is None else
+             dict(zip(TILE_KINDS, (s // t, 0, 0))))
+    for kind, n in tiles.items():
+        g.set(n, kernel=kernel, kind=kind)
+    g = reg.gauge("ds_flash_pairs",
+                  "query-key pairs a (batch x head) row of the flash kernel "
+                  "last built computes (swept) / the mask leaves live")
+    for kind, n in pair_counts(s, b, window, causal).items():
         g.set(n, kernel=kernel, kind=kind)
 
 
@@ -234,18 +314,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
     """Online-softmax forward: q block vs the VMEM-resident k/v row.
     ``window`` (Mistral SWA): query r sees keys in (r - window, r] — the
     kv sweep starts at the window's first live block, and only the tiles
-    the diagonal or the window's far edge cuts carry the mask."""
+    the diagonal or the window's far edge cuts carry the mask. Under a
+    narrow window (`band_rows`) the block's rows go by groups, each
+    against its one key span."""
     i = pl.program_id(1)
-    q = q_ref[0]
     d = v_ref.shape[-1]
 
-    def body(j, carry, masked):
+    def body(j, carry, *, q, qi, step, n, masked):
+        # keys [j * step, j * step + n) against the rows ``q``, whose first
+        # is row ``qi * step``: a [bk, bk] tile of the loops (step = n =
+        # bk), or a group's span of the band (step = t, n = span)
         o_acc, m, l = carry
-        kj = k_ref[0, pl.ds(j * bk, bk), :]
-        vj = v_ref[0, pl.ds(j * bk, bk), :]
+        kj = k_ref[0, pl.ds(j * step, n), :]
+        vj = v_ref[0, pl.ds(j * step, n), :]
         s = jnp.dot(q, kj.T, preferred_element_type=jnp.float32) * sc
         if masked:
-            s = _cut(s, (i - j) * bq, window, q_axis=0)
+            s = _cut(s, (qi - j) * step, window, q_axis=0)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -254,11 +338,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
                                        preferred_element_type=jnp.float32)
         return o_acc, m_new, l
 
-    cut = functools.partial(body, masked=True)
-    whole = functools.partial(body, masked=False)
-    carry = (jnp.zeros((bq, d), jnp.float32),
-             jnp.full((bq, 1), NEG_INF, jnp.float32),
-             jnp.zeros((bq, 1), jnp.float32))
+    def empty(rows):
+        return (jnp.zeros((rows, d), jnp.float32),
+                jnp.full((rows, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32))
+
+    def finish(carry, at):
+        o_acc, m, l = carry
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, at, :] = o_acc / l
+        # the statistics are a column ([rows, 1], a row a sublane) and the
+        # output is lane-dense ([1, rows]): through the transpose unit, not
+        # lane by lane
+        lse = jnp.broadcast_to(m + jnp.log(l), (o_acc.shape[0], _LANES))
+        lse_ref[0, :, at] = lse.T[:1]
+
+    t = band_rows(bq, window, causal)
+    if t is not None:
+        # straight-line code: a group's one softmax pass starts from the
+        # empty carry (``exp(NEG_INF - m)`` is 0), and nothing orders the
+        # groups among themselves
+        span = band_span(nk * bk, t, window)
+        for g in range(bq // t):
+            rows = pl.ds(g * t, t)
+            qi = i * (bq // t) + g
+            finish(body(_fwd_span(qi, t, span), empty(t), q=q_ref[0, rows, :],
+                        qi=qi, step=t, n=span, masked=True), rows)
+        return
+    tile = functools.partial(body, q=q_ref[0], qi=i, step=bk, n=bk)
+    cut = functools.partial(tile, masked=True)
+    whole = functools.partial(tile, masked=False)
+    carry = empty(bq)
     if causal:
         # q block i attends kv blocks [lo, i] (bq == bk), in that order
         edge, dead = tile_bands(nk * bk, bk, window)
@@ -269,13 +379,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
         carry = cut(i, carry)
     else:
         carry = jax.lax.fori_loop(0, nk, whole, carry)
-    o_acc, m, l = carry
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = o_acc / l
-    # the statistics are a column ([bq, 1], a row a sublane) and the output
-    # is lane-dense ([1, bq]): through the transpose unit, not lane by lane
-    lse = jnp.broadcast_to(m + jnp.log(l), (bq, _LANES))
-    lse_ref[0] = lse.T[:1]
+    finish(carry, slice(None))
 
 
 # ---------------------------------------------------------------- backward
@@ -297,23 +401,26 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_ref[:] = jnp.zeros_like(dq_ref)
 
-    def body(i, carry, masked):
-        # the tile is held TRANSPOSED ([bk, bq]: keys by queries): lse and
+    def body(i, carry, *, k, v, kj, step, n, masked):
+        # queries [i * step, i * step + n) against the keys ``k``, whose
+        # first is key ``kj * step``: a [bq, bq] tile of the loops (step =
+        # n = bq), or a group's span of the band (step = t, n = span).
+        # The tile is held TRANSPOSED (keys by queries): lse and
         # delta broadcast as the lane-dense rows they are stored as, p and
         # ds are already the left operands dv and dk need, and only dq
         # contracts over the tile's major dimension (one in-loop transpose
         # where the [bq, bk] form has two, plus two relayouts of a row
         # into a column); same products, same order of accumulation
         dk_acc, dv_acc = carry
-        rows = (0, pl.ds(i * bq, bq), slice(None))
+        rows = (0, pl.ds(i * step, n), slice(None))
         qi = q_ref[rows]
         doi = do_ref[rows]
-        lse = lse_ref[0, :, pl.ds(i * bq, bq)]                # [1, bq]
-        delta = delta_ref[0, :, pl.ds(i * bq, bq)]
+        lse = lse_ref[0, :, pl.ds(i * step, n)]               # [1, n]
+        delta = delta_ref[0, :, pl.ds(i * step, n)]
         s = jax.lax.dot_general(k, qi, nt,
                                 preferred_element_type=jnp.float32) * sc
         if masked:
-            s = _cut(s, (i - j) * bq, window, q_axis=1)
+            s = _cut(s, (i - kj) * step, window, q_axis=1)
         p = jnp.exp(s - lse).astype(k.dtype)
         dv_acc += jnp.dot(p, doi, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, doi, nt,
@@ -325,10 +432,26 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32) * sc
         return dk_acc, dv_acc
 
-    cut = functools.partial(body, masked=True)
-    whole = functools.partial(body, masked=False)
-    carry = (jnp.zeros(k.shape, jnp.float32),
-             jnp.zeros(v.shape, jnp.float32))
+    def empty(rows):
+        return (jnp.zeros((rows, k.shape[-1]), jnp.float32),
+                jnp.zeros((rows, v.shape[-1]), jnp.float32))
+
+    t = band_rows(bk, window, causal)
+    if t is not None:
+        # the forward's band transposed: the block's keys go by groups,
+        # each against its one query span
+        span = band_span(nq * bq, t, window)
+        for g in range(bk // t):
+            rows = slice(g * t, (g + 1) * t)
+            kj = j * (bk // t) + g
+            dk_ref[0, rows, :], dv_ref[0, rows, :] = body(
+                _bwd_span(kj, nq * bq, t, span), empty(t), k=k[rows],
+                v=v[rows], kj=kj, step=t, n=span, masked=True)
+        return
+    tile = functools.partial(body, k=k, v=v, kj=j, step=bq, n=bq)
+    cut = functools.partial(tile, masked=True)
+    whole = functools.partial(tile, masked=False)
+    carry = empty(bk)
     if causal:
         # kv block j is attended by q blocks [j, hi) (bq == bk), in that
         # order

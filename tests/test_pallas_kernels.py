@@ -85,10 +85,11 @@ def test_flash_unaligned_seq_falls_back_exact():
                                atol=2e-5, rtol=2e-5)
 
 
-def _live_pairs(s, window, causal):
-    """The real mask, pair by pair: [s, s] bool."""
-    rel = np.arange(s)[:, None] - np.arange(s)[None, :]
-    live = rel >= 0 if causal else np.ones((s, s), bool)
+def _live_pairs(s, window, causal, rows=slice(None), cols=slice(None)):
+    """The real mask, pair by pair: [s, s] bool (or its ``rows`` x ``cols``
+    alone: the cells' lengths do not fit whole)."""
+    rel = np.arange(s)[rows, None] - np.arange(s)[None, cols]
+    live = rel >= 0 if causal else np.ones(rel.shape, bool)
     return live & (rel < window) if window is not None else live
 
 
@@ -138,21 +139,85 @@ def test_flash_tile_classes_match_the_mask(s, b, window, causal):
             assert lo == a and c == hi
 
 
-@pytest.mark.parametrize("window,hq,hkv,dtype", [
-    (300, 4, 4, jnp.float32), (300, 4, 1, jnp.float32),
-    (256, 4, 4, jnp.float32), (256, 4, 1, jnp.float32),
-    (100, 4, 4, jnp.float32), (100, 4, 1, jnp.float32),
-    (640, 4, 4, jnp.float32), (640, 4, 1, jnp.float32),
-    (None, 4, 4, jnp.float32), (None, 4, 1, jnp.float32),
-    (300, 4, 1, jnp.bfloat16),
+@pytest.mark.parametrize("s,b,window", [
+    (8192, 512, 512), (16384, 512, 1024),       # the Laguna and Mellum cells
+    (1024, 512, 512), (1024, 512, 1024), (1024, 512, 700), (1024, 256, 512),
+    (640, 128, 256), (640, 128, 100), (640, 128, 129), (640, 128, 1),
+    (128, 128, 64),
+    (8192, 512, 4096), (8192, 512, 1025), (640, 128, 300), (640, 128, 257),
+    (8192, 512, None), (640, 128, None),        # the loops
 ])
-def test_flash_all_tile_classes_match_reference(window, hq, hkv, dtype):
-    """Forward and all three gradients against the exact masked form at
-    five 128-blocks: windows 300 and 256 reach masked (diagonal and far
-    edge), unmasked and skipped tiles in one call, 100 leaves no unmasked
-    tile, 640 and None no far edge."""
+def test_flash_band_covers_the_mask(s, b, window):
+    """Where the window is at most two blocks wide each group of ``t`` rows
+    meets ONE span: every live pair of the group lies in it, the span is in
+    bounds and ``t``-aligned, the mask the body cuts it by (``off`` from
+    the two group indices) is the real one, and the pairs the gauge counts
+    are a direct count; forward (groups of queries, spans of keys) and
+    backward (groups of keys, spans of queries). A wider window, or none,
+    takes the loops."""
+    live_all = sum(int(_live_pairs(s, window, True, slice(r, r + b)).sum())
+                   for r in range(0, s, b))
+    t = fa.band_rows(b, window, True)
+    counts = fa.pair_counts(s, b, window, True)
+    assert counts["live"] == live_all
+    assert fa.band_rows(b, window, False) is None
+    if window is None or window > 2 * b:
+        assert t is None
+        tiles = fa.tile_counts(s, b, window, True)
+        assert counts["swept"] == (tiles["masked"] + tiles["unmasked"]) * b * b
+        return
+    assert b % t == 0 and (t % 128 == 0 or t == b == s)
+    span = fa.band_span(s, t, window)
+    assert span % t == 0 and t <= span <= s
+    for kernel in ("fwd", "bwd"):
+        swept = 0
+        for g in range(s // t):
+            own = slice(g * t, (g + 1) * t)
+            if kernel == "fwd":         # [t queries, s keys]
+                first = int(fa._fwd_span(g, t, span))
+                live = _live_pairs(s, window, True, rows=own)
+            else:                       # [t keys, s queries]
+                first = int(fa._bwd_span(g, s, t, span))
+                live = _live_pairs(s, window, True, cols=own).T
+            met = slice(first * t, first * t + span)
+            assert 0 <= met.start and met.stop <= s, (kernel, g)
+            assert live[:, met].sum() == live.sum(), (kernel, g)
+            # the body's mask: ``rel`` is query - key, from the local
+            # indices and the two groups' distance
+            own_less_met = (np.arange(t)[:, None] - np.arange(span)[None, :]
+                            + (g - first) * t)
+            rel = own_less_met if kernel == "fwd" else -own_less_met
+            assert np.array_equal((rel >= 0) & (rel < window),
+                                  live[:, met]), (kernel, g)
+            swept += t * span
+        assert counts["swept"] == swept
+
+
+@pytest.mark.parametrize("s,window,hq,hkv,dtype", [
+    (640, 300, 4, 4, jnp.float32), (640, 300, 4, 1, jnp.float32),
+    (640, 256, 4, 4, jnp.float32), (640, 256, 4, 1, jnp.float32),
+    (640, 100, 4, 4, jnp.float32), (640, 100, 4, 1, jnp.float32),
+    (640, 640, 4, 4, jnp.float32), (640, 640, 4, 1, jnp.float32),
+    (640, None, 4, 4, jnp.float32), (640, None, 4, 1, jnp.float32),
+    (640, 300, 4, 1, jnp.bfloat16),
+    (1024, 512, 4, 4, jnp.float32), (1024, 512, 4, 1, jnp.float32),
+    (1024, 1024, 4, 4, jnp.float32), (1024, 1024, 4, 1, jnp.float32),
+    (1024, 512, 4, 1, jnp.bfloat16),
+])
+def test_flash_all_tile_classes_match_reference(s, window, hq, hkv, dtype):
+    """Forward and all three gradients against the exact masked form. At
+    five 128-blocks: window 300 reaches masked (diagonal and far edge),
+    unmasked and skipped tiles in one call of the LOOPS, 640 and None no
+    far edge; windows 100 and 256 are at most two blocks wide and run the
+    BAND, at one group a block. At two 512-blocks, windows 512 and 1024
+    run the band at several groups a block (``t < b``), the first groups'
+    spans held at the row's start and the last key groups' at its end."""
     from deepspeed_tpu.ops.layers import window_bias
-    s, d = 640, 32
+    d = 32
+    b = fa._block(s)
+    t = fa.band_rows(b, window, True)
+    assert (t is None) == (window in (300, 640, None))
+    assert t is None or (t < b) == (s == 1024)
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (1, s, hq, d), dtype)
     k = jax.random.normal(ks[1], (1, s, hkv, d), dtype)
@@ -183,15 +248,24 @@ def test_flash_all_tile_classes_match_reference(window, hq, hkv, dtype):
                                    atol=bwd_tol, rtol=bwd_tol)
 
 
-@pytest.mark.parametrize("window,want", [
-    (4096, {"masked": 24, "unmasked": 84, "skipped": 28}),
-    (None, {"masked": 16, "unmasked": 120, "skipped": 0}),
+@pytest.mark.parametrize("window,want,swept,live", [
+    (4096, {"masked": 24, "unmasked": 84, "skipped": 28},
+     108 * 512 * 512, 25_167_872),
+    (None, {"masked": 16, "unmasked": 120, "skipped": 0},
+     136 * 512 * 512, 33_558_528),
+    # the Laguna cell's window layers: the band's groups count as masked,
+    # and 0.78 of the swept pairs are live where the loops' 31 tiles of
+    # 512 x 512 had 0.50
+    (512, {"masked": 64, "unmasked": 0, "skipped": 0},
+     8192 * 640, 4_063_488),
 ])
-def test_flash_tiles_gauge(window, want):
-    """``ds_flash_tiles`` is set where the kernels are built (trace time,
-    so an abstract evaluation is enough), at the cells' shape."""
+def test_flash_tiles_gauge(window, want, swept, live):
+    """``ds_flash_tiles`` and ``ds_flash_pairs`` are set where the kernels
+    are built (trace time, so an abstract evaluation is enough), at the
+    cells' shape."""
     from deepspeed_tpu import telemetry
-    assert fa.tile_counts(8192, 512, window, True) == want
+    if window is None or window > 1024:
+        assert fa.tile_counts(8192, 512, window, True) == want
     x = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
 
     def grad(q, k, v):
@@ -203,11 +277,14 @@ def test_flash_tiles_gauge(window, want):
     try:
         reg = telemetry.get_registry()
         assert reg.get("ds_flash_tiles") is None
+        assert reg.get("ds_flash_pairs") is None
         jax.eval_shape(grad, x, x, x)
-        g = reg.get("ds_flash_tiles")
+        g, pairs = reg.get("ds_flash_tiles"), reg.get("ds_flash_pairs")
         for kernel in ("fwd", "bwd"):
             assert {k: g.value(kernel=kernel, kind=k)
                     for k in fa.TILE_KINDS} == want
+            assert pairs.value(kernel=kernel, kind="swept") == swept
+            assert pairs.value(kernel=kernel, kind="live") == live
     finally:
         telemetry.shutdown()
 

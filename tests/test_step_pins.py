@@ -94,6 +94,16 @@ from helpers.families import program
 # stand, ``mellum``'s two among them (the same equations in the same order;
 # a whole-head table lowers to the text it lowered to,
 # ``tests/test_laguna.py``).
+# PR 61 re-took ``mellum``, ``mellum_two_layers``, ``mistral``,
+# ``mistral_segments`` and ``laguna`` by design: their tiny presets have a
+# window of at most two of the flash kernels' blocks (64 at one block of
+# 128 here; 512 and 1024 at blocks of 512 in the Laguna and Mellum cells),
+# where a grid step now runs its rows by groups against ONE span under one
+# mask and no loop (``ops/pallas/flash_attention.py`` ``band_rows``; the
+# seeded weights are the parent's). The seven other rows stand, their
+# layers pass no window, and the Mistral CELLS' window of 4096 is eight
+# blocks: ``test_the_flash_loops_are_the_parents_program`` below holds the
+# loops to the parent's text.
 _PINS = {
     "kimi_linear": (
         "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
@@ -102,19 +112,19 @@ _PINS = {
         "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
         2422.8129150247487),
     "mellum": (
-        "6757d265d311670d63b7cb789f660f8b24d0670e7052abf56d987ad41a6882b4",
+        "31cec3cc35031e45326e06fc1bc8dede9493c53fe0f24048f8a90530755e967a",
         36510.69587289919),
     "mellum_two_layers": (
-        "804d32f3a8ec122e419a8accb0baca66cd2d04ac0687a83165295576e9baa962",
+        "ed3dfdfe66bf36f12c4c25ebcd86ef0570a9304cbdc2d74704f5d4b4881fa391",
         31755.548628388842),
     "ouro": (
         "9eec4588f2e615a8871cef610ca85b5dc9984269762d3ecda677f84ad4d2f3d0",
         2733.7353564571135),
     "mistral": (
-        "78f5c2263a6399b676b44438f75273068c1f2d668ae62f890529c98bd4251219",
+        "a17a78d1f0971400b6af99bf037488344b6902deeb9bc5b8facf00a36cc9d7e4",
         2339.9930016614694),
     "mistral_segments": (
-        "212d597669e76e05c1af740740365e7817c22e6f086073c18dac476861936582",
+        "ff01f5cdc985b238b6f862877aa11232503e0a0743274f22dddd837917e3a975",
         2339.9930016614694),
     "qwen3_next": (
         "cec92a9b712f3c14307258a68d2c40626f56876a508a39728df86696ffa32b1d",
@@ -126,7 +136,7 @@ _PINS = {
         "7d0db59390f004a09922ce9ac1b47e8557f20f5884037f8f0dd022c42b2a6715",
         4668.748035160373),
     "laguna": (
-        "2154dc320f6a0d31cac3f12ba0aa7c091f83f73b6e4d4bf4d7cbac04a5517de5",
+        "abcd8641dd6b81d5f21e8bafa1c029566029a5e428a09bd7693ea7c8d8045522",
         31325.334374967497),
 }
 # the rows that are not a family's two-layer cut under the family's name: (family, cut of its
@@ -190,3 +200,27 @@ def test_the_unweighted_head_is_the_parents_program():
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "e4b425dd87402f9216cae4e4bd9f1e349f6e3fb5515488abb2badeecb6e8e10f")
+
+
+@pytest.mark.parametrize("window,want", [
+    (300, "9bd80c841e4c1315ea14a3d4e3763726ae750e9977aacb1e59aff8d5bb7f74a2"),
+    (None, "4a81f6ab21af3578b533b5407f1b30b3026c80af3bc35eb927f0c56b16632890"),
+])
+def test_the_flash_loops_are_the_parents_program(window, want):
+    """Past a window of two blocks, and with none, the flash kernels sweep
+    by the loops, and the program they trace to (value and the three
+    gradients at five 128-blocks, 4 query heads over 2; window 300 meets
+    all three ranges) is the one PR 61's parent traced (commit c369513,
+    this function run on that checkout): the band of ISSUE 61 is another
+    way to call the one body, chosen at trace time, and the cells whose
+    window is wider (Mistral's 4096 at block 512) or absent run what they
+    ran. The tiny ``mistral`` rows above have a window of 64 at one block
+    of 128 and took the band."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    q = jax.ShapeDtypeStruct((1, 640, 4, 32), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 640, 2, 32), jnp.bfloat16)
+    text = jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2))).lower(q, k, k).as_text()
+    assert _pin(text) == want

@@ -840,16 +840,21 @@ def test_mhc_kernels_and_the_cut_held_backward_compile_for_v5e(monkeypatch):
         assert re.search(rf"%{kernel}[.\w]* = .*custom-call", hlo), kernel
 
 
-@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)])
+@pytest.mark.parametrize("s,heads,kv,window", [
+    (8192, 72, 8, 512), (8192, 48, 8, None), (16384, 32, 4, 1024)])
 def test_flash_kernels_compile_for_v5e_at_the_laguna_cells_shapes(
-        monkeypatch, heads, window):
-    """PR 60's shapes no cell had run, compiled by Mosaic for one described
-    v5e chip: 8192 tokens of 72 query heads over 8 key heads (9 a key
-    head) with a window EQUAL to the kernel's block (every query block
-    meets two tiles and both are masked: ``tile_counts`` says 31 masked,
-    none unmasked), and of 48 over 8 causal; forward, remat's kept
-    residuals and the one-pass backward with its sum of dk and dv over a
-    group's query heads."""
+        monkeypatch, s, heads, kv, window):
+    """Shapes compiled by Mosaic for one described v5e chip. PR 60's: 8192
+    tokens of 72 query heads over 8 key heads (9 a key head) with a window
+    EQUAL to the kernel's block, and of 48 over 8 causal. PR 61: at a
+    window of at most two blocks both kernels run the BAND (a group of
+    rows against one span, `band_rows`), which sweeps 5,242,880 pairs a
+    head for the 4,063,488 the mask leaves live where the loops' 31 masked
+    tiles swept 8,126,464; the Mellum cell's window layers (16384 tokens,
+    32 query heads over 4, window 1024: two blocks) are the band's widest
+    span. Forward, remat's kept residuals and the one-pass backward with
+    its sum of dk and dv over a group's query heads, under
+    ``vmem_limit_bytes`` as it stands."""
     import re
 
     from jax.experimental import topologies
@@ -865,14 +870,17 @@ def test_flash_kernels_compile_for_v5e_at_the_laguna_cells_shapes(
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
         s, jnp.bfloat16, sharding=one)
-    s, kv, d = 8192, 8, 128
+    d = 128
     block = fa._block(s)
-    counts = fa.tile_counts(s, block, window, True)
-    if window is not None:
-        assert block == window == 512
-        assert (counts["masked"], counts["unmasked"]) == (31, 0)
-    else:
+    assert block == 512
+    if window is None:
+        counts = fa.tile_counts(s, block, window, True)
         assert counts["skipped"] == 0 and counts["unmasked"] == 120
+        assert fa.band_rows(block, window, True) is None
+    else:
+        assert fa.pair_counts(s, block, window, True) == {
+            512: {"swept": 5_242_880, "live": 4_063_488},
+            1024: {"swept": 18_874_368, "live": 16_253_440}}[window]
     from deepspeed_tpu.models.transformer import _remat_policy
     layer = jax.checkpoint(
         lambda q, k, v: fa.flash_attention(q, k, v, causal=True,

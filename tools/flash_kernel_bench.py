@@ -9,7 +9,9 @@ version of the kernel file (the parent's, one with a block deleted);
 the first variant is this checkout's and the others are compared with it
 bit for bit (o, lse, dq, dk, dv). Shapes are the benchmark cells' per-chip
 ones (32 q / 8 kv heads of 128) at each ``S:WINDOW`` (default 8192:4096;
-``none`` for full causal). Kernel time is the mean duration of the
+``none`` for full causal; ``8192:512`` and ``16384:1024`` are the Laguna
+and the Mellum cells' window layers, whose sweep is the band's: time a
+head is what carries over to their 72 / 32 heads). Kernel time is the mean duration of the
 ``tpu_custom_call`` events of a profiler trace of 10 calls; it is a
 device number and exists only on a TPU. Not the yardstick: what a user
 feels is ``benchmark/run.py``.
@@ -33,12 +35,16 @@ HQ, HKV, D, CALLS = 32, 8, 128, 10
 
 
 def load(path: str | None):
-    if path is None:
+    if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
-        return importlib.import_module(
-            "deepspeed_tpu.ops.pallas.flash_attention")
+    checkout = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.flash_attention")
+    if path is None:
+        return checkout
+    # a name inside the package, so that the copy's relative imports hold
     spec = importlib.util.spec_from_file_location(
-        "flash_copy_" + hashlib.sha1(path.encode()).hexdigest()[:8], path)
+        "deepspeed_tpu.ops.pallas._flash_copy_"
+        + hashlib.sha1(path.encode()).hexdigest()[:8], path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
