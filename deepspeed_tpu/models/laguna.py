@@ -385,11 +385,7 @@ class Laguna(WindowAndFullAttention, RoutedStackOfKinds):
         q = (h @ p["wq"]).reshape(b, s, nh, hd)
         k = (h @ p["wk"]).reshape(b, s, nkv, hd)
         v = (h @ p["wv"]).reshape(b, s, nkv, hd)
-        with jax.named_scope("ds.rope"):
-            cos, sin = self._ropes[kind]
-            q = L.apply_rotary(q, cos, sin)
-            k = L.apply_rotary(k, cos, sin)
-        a = attn(q, k, v)
+        a = L.rotary_attention(attn, q, k, v, self._ropes[kind])
         with jax.named_scope("ds.attn_gate"):
             g = jax.nn.sigmoid(jnp.matmul(
                 h, p["wg"], preferred_element_type=jnp.float32))

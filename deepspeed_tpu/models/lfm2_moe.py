@@ -306,10 +306,8 @@ class Lfm2Moe(RoutedStackOfKinds):
         with jax.named_scope("ds.qk_norm"):
             q = L.rms_norm(q, p["q_norm"], c.norm_eps)
             k = L.rms_norm(k, p["k_norm"], c.norm_eps)
-        with jax.named_scope("ds.rope"):
-            cos, sin = self._rope
-            q, k = L.apply_rotary(q, cos, sin), L.apply_rotary(k, cos, sin)
-        return attn_fn(q, k, v, causal=True).reshape(b, s, nh * hd) @ p["wo"]
+        a = L.rotary_attention(attn_fn, q, k, v, self._rotary, causal=True)
+        return a.reshape(b, s, nh * hd) @ p["wo"]
 
     def _routed(self, p, h):
         """(out, counts) of a routed layer: a share without its peers

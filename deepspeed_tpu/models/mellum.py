@@ -227,11 +227,8 @@ class Mellum(WindowAndFullAttention, RoutedStackOfKinds):
         q = (h @ p["wq"]).reshape(b, s, nh, hd)
         k = (h @ p["wk"]).reshape(b, s, nkv, hd)
         v = (h @ p["wv"]).reshape(b, s, nkv, hd)
-        with jax.named_scope("ds.rope"):
-            cos, sin = self._ropes[kind]
-            q = L.apply_rotary(q, cos, sin)
-            k = L.apply_rotary(k, cos, sin)
-        return attn(q, k, v).reshape(b, s, nh * hd) @ p["wo"]
+        a = L.rotary_attention(attn, q, k, v, self._ropes[kind])
+        return a.reshape(b, s, nh * hd) @ p["wo"]
 
     def _one_layer(self, p, x, mixers):
         from ..moe import sharded_moe

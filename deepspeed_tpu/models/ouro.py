@@ -173,8 +173,9 @@ class Ouro(DecoderLM):
         device scope."""
         with jax.named_scope("ds.attn"):
             h = self._norm(x, p["ln1_scale"])
-            q, k, v = self._qkv(p, h, positions)
-            a = self._attn_out(p, attn_fn(q, k, v, causal=True))
+            rotary = self._hands_rotary(attn_fn, positions)
+            q, k, v = self._qkv(p, h, positions, rotate=not rotary)
+            a = self._attn_out(p, attn_fn(q, k, v, causal=True, **rotary))
             x = x + self._norm(a, p["ln1_out_scale"])
         with jax.named_scope("ds.mlp"):
             m, _ = self._mlp(p, self._norm(x, p["ln2_scale"]))

@@ -203,6 +203,7 @@ class Qwen3Next(RoutedStackOfKinds):
         super().__init__(c)
         self._rope = L.rotary_embedding(c.max_seq_len, c.rotary_dim,
                                         c.rope_theta)
+        self._rotary = L.rotary_tables(*self._rope, c.head_dim)
 
     def after_step(self, params, stats):
         """No weight moves after the optimizer's update (no selection bias
@@ -336,7 +337,6 @@ class Qwen3Next(RoutedStackOfKinds):
         c = self.config
         b, s, _ = h.shape
         nh, nkv, hd = c.num_heads, c.num_kv_heads, c.head_dim
-        rot = c.rotary_dim
         qg = (h @ p["wq"]).reshape(b, s, nh, 2 * hd)
         q, gate = qg[..., :hd], qg[..., hd:].reshape(b, s, nh * hd)
         k = (h @ p["wk"]).reshape(b, s, nkv, hd)
@@ -344,13 +344,9 @@ class Qwen3Next(RoutedStackOfKinds):
         with jax.named_scope("ds.qk_norm"):
             q = self._norm(q, p["q_norm"])
             k = self._norm(k, p["k_norm"])
-        with jax.named_scope("ds.rope"):
-            cos, sin = self._rope
-            part = lambda x: jnp.concatenate(  # noqa: E731
-                [L.apply_rotary(x[..., :rot], cos, sin), x[..., rot:]],
-                axis=-1)
-            q, k = part(q), part(k)
-        a = attn(q, k, v, causal=True).reshape(b, s, nh * hd)
+        # the whole head with its table of ``rotary_dim`` channels
+        a = L.rotary_attention(attn, q, k, v, self._rotary, causal=True
+                               ).reshape(b, s, nh * hd)
         a = (a.astype(jnp.float32)
              * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(h.dtype)
         return a @ p["wo"]

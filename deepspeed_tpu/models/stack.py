@@ -382,15 +382,15 @@ class WindowAndFullAttention:
                 f"{c.num_experts}")
 
     def _rope_tables(self) -> dict:
-        """{kind: (cos, sin)} of the kinds the stack has, each from its
-        section of ``rope_parameters`` (``ops/layers.py``
+        """{kind: ``RotaryTables``} of the kinds the stack has, each from
+        its section of ``rope_parameters`` (``ops/layers.py``
         ``rotary_embedding``: a section's ``partial_rotary_factor`` makes
         the table narrower than the head)."""
         c = self.config
         return {
-            ATTENTION_KINDS[t]: L.rotary_embedding(
+            ATTENTION_KINDS[t]: L.rotary_tables(*L.rotary_embedding(
                 c.max_seq_len, c.head_dim, c.rope_theta,
-                scaling=c.rope_parameters[t])
+                scaling=c.rope_parameters[t]), c.head_dim)
             for t in sorted(set(c.layer_types))}
 
     def _mixers(self, attn_fn, act_sharding):

@@ -53,9 +53,10 @@ class DecoderLM:
                                        * config.rotary_pct) // 2 * 2)
             self._rope = L.rotary_embedding(
                 config.max_seq_len, self._rot_dim, config.rope_theta)
+            self._rotary = L.rotary_tables(*self._rope, config.head_dim)
         else:
             self._rot_dim = 0
-            self._rope = None
+            self._rope = self._rotary = None
         self._alibi_slopes = (L.alibi_slopes(config.num_heads)
                               if config.position_embedding == "alibi"
                               else None)
@@ -164,9 +165,19 @@ class DecoderLM:
                              params["embed"]["ln_bias"], c.norm_eps)
         return x
 
+    def _hands_rotary(self, attn_fn, positions=None) -> dict:
+        """``{"rotary": tables}`` where ``attn_fn`` rotates q and k itself
+        (``ops.layers.hands_rotary``: the flash kernels' wrappers at a
+        lane-aligned head, no ``positions``), else ``{}``: what a block
+        hands ``attn_fn`` beside the q and k that ``_qkv(rotate=not ...)``
+        then leaves unrotated."""
+        return ({"rotary": self._rotary}
+                if L.hands_rotary(attn_fn, self._rotary, positions) else {})
+
     def _qkv(self, p: PyTree, h: jax.Array,
-             positions: jax.Array | None = None):
-        """Shared q/k/v projection (+bias, head reshape, rope)."""
+             positions: jax.Array | None = None, *, rotate: bool = True):
+        """Shared q/k/v projection (+bias, head reshape, rope unless the
+        attention rotates: ``_hands_rotary``)."""
         c = self.config
         b, s, _ = h.shape
         nh, nkv, hd = c.num_heads, c.num_kv_heads, c.head_dim
@@ -178,7 +189,7 @@ class DecoderLM:
         q = q.reshape(b, s, nh, hd)
         k = k.reshape(b, s, nkv, hd)
         v = v.reshape(b, s, nkv, hd)
-        if self._rope is not None:
+        if rotate and self._rope is not None:
             cos, sin = self._rope
             if self._rot_dim < hd:   # partial rotary: rotate a prefix
                 q = jnp.concatenate(
@@ -270,8 +281,10 @@ class DecoderLM:
         # device trace is read by, no run-time cost
         with jax.named_scope("ds.attn"):
             h = self._norm(x, p["ln1_scale"], p.get("ln1_bias"))
-            q, k, v = self._qkv(p, h, positions)
-            attn_out = self._attn_out(p, attn_fn(q, k, v, causal=True))
+            rotary = self._hands_rotary(attn_fn, positions)
+            q, k, v = self._qkv(p, h, positions, rotate=not rotary)
+            attn_out = self._attn_out(
+                p, attn_fn(q, k, v, causal=True, **rotary))
         if c.parallel_residual:
             with jax.named_scope("ds.mlp"):
                 m, aux = self._mlp(p, self._parallel_mlp_input(p, x, h))
@@ -304,14 +317,16 @@ class DecoderLM:
         c = self.config
         from jax.ad_checkpoint import checkpoint_name
 
+        rotary = self._hands_rotary(attn_fn, positions)
+
         def seg_qkv(p, x):
             h = self._norm(x, p["ln1_scale"], p.get("ln1_bias"))
-            q, k, v = self._qkv(p, h, positions)
+            q, k, v = self._qkv(p, h, positions, rotate=not rotary)
             return q, k, v, (h if c.parallel_residual else None)
 
         with jax.named_scope("ds.attn"):
             q, k, v, h = jax.checkpoint(seg_qkv, prevent_cse=False)(p, x)
-            a = attn_fn(q, k, v, causal=True)
+            a = attn_fn(q, k, v, causal=True, **rotary)
 
         def seg_out(p, x, a, h):
             with jax.named_scope("ds.attn"):

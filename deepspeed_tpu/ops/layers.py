@@ -14,7 +14,9 @@ Everything here is shape-static and jit-safe.
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -249,6 +251,74 @@ def apply_rotary(x, cos, sin, positions=None):
     if rot < x.shape[-1]:
         parts.append(x[..., rot:].astype(jnp.float32))
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)
+
+
+class RotaryTables(NamedTuple):
+    """A model's rotary tables: ``cos``, ``sin`` [S_max, R // 2] as
+    :func:`rotary_embedding` builds them, and ``wide`` (``[cos | cos |
+    1...]``, ``[-sin | sin | 0...]``, float32 [S_max, D]: a whole head's
+    row, what ``ops/pallas/rope.py`` multiplies by) where the head is a
+    whole number of 128-lane tiles, else None."""
+    cos: jax.Array
+    sin: jax.Array
+    wide: tuple | None
+
+    @property
+    def rotated(self) -> int:
+        return 2 * self.cos.shape[-1]
+
+
+def rotary_tables(cos, sin, head_dim: int) -> RotaryTables:
+    """``cos``, ``sin`` with the wide tables of a head of ``head_dim``
+    channels, built once where the model builds its tables."""
+    rot = 2 * cos.shape[-1]
+    if head_dim % 128 or rot > head_dim or cos.dtype != jnp.float32:
+        return RotaryTables(cos, sin, None)
+    rest = (cos.shape[0], head_dim - rot)
+    return RotaryTables(cos, sin, (
+        jnp.concatenate([cos, cos, jnp.ones(rest, cos.dtype)], axis=-1),
+        jnp.concatenate([-sin, sin, jnp.zeros(rest, sin.dtype)], axis=-1)))
+
+
+def rotate(q, k, tables: RotaryTables, positions=None):
+    """q and k through :func:`apply_rotary` under scope ``ds.rope``: the
+    XLA form of the rotation (gauge ``ds_rope_calls{form="xla"}``)."""
+    from .pallas.rope import count_rotation
+    # (cos, sin) alone where the rows' positions are their indices: what
+    # the benchmark's controls plant in ``apply_rotary``'s place takes those
+    how = (tables.cos, tables.sin) + (
+        () if positions is None else (positions,))
+    with jax.named_scope("ds.rope"):
+        for x in (q, k):
+            count_rotation("xla", x, tables.rotated)
+        return apply_rotary(q, *how), apply_rotary(k, *how)
+
+
+def hands_rotary(attn, tables: RotaryTables | None, positions=None) -> bool:
+    """Whether ``attn`` is handed unrotated q and k with ``rotary=tables``:
+    it says that it rotates (``applies_rotary``, as
+    ``ops/pallas/flash_attention.py`` ``flash_attention`` and its
+    per-shard wrapper do: they rotate as they lay q and k out for their
+    kernels), the head is lane-aligned (``tables.wide``) and the rows'
+    positions are their indices (training; decode gathers its own)."""
+    while isinstance(attn, functools.partial):
+        attn = attn.func
+    return (tables is not None and tables.wide is not None
+            and positions is None
+            and getattr(attn, "applies_rotary", False))
+
+
+def rotary_attention(attn, q, k, v, tables: RotaryTables, *,
+                     positions=None, **kw):
+    """``attn`` over the rotated q and k: the tables go to an attention
+    that rotates (:func:`hands_rotary`), every other one gets what
+    :func:`rotate` returns. One arithmetic either way (float32 products of
+    the input and the float32 tables, one rounding to the input's dtype),
+    two ways to move the bytes, chosen by what the shapes say."""
+    if hands_rotary(attn, tables, positions):
+        return attn(q, k, v, rotary=tables, **kw)
+    q, k = rotate(q, k, tables, positions)
+    return attn(q, k, v, **kw)
 
 
 def window_bias(seq_len: int, window: int):

@@ -541,7 +541,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
-                    window: int | None = None, **_kw):
+                    window: int | None = None, rotary=None, **_kw):
     """Drop-in attn_fn: q [B, S, Hq, D], k/v [B, S, Hkv, D], matches
     ops.layers.dot_product_attention numerics. GQA is native: the
     kernels index the shared kv head per q-head group, so repeated k/v
@@ -557,6 +557,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     sequences past the VMEM residency cap it falls back to the stock
     two-pass jax.experimental kernel on TPU (full-causal only — a window
     there falls back to the exact masked form).
+
+    ``rotary`` (``ops.layers.RotaryTables`` with their ``wide`` pair; the
+    function says ``applies_rotary``): q and k come UNROTATED and the
+    rotation rides the relayout into the kernels' [B x H, S, D], one pass
+    over each (``ops/pallas/rope.py``; ``ops.layers.rotary_attention`` is
+    who hands the tables over). The fallbacks rotate by
+    ``ops.layers.rotate`` first.
     """
     b, s, hq, d = q.shape
     hkv = k.shape[2]
@@ -567,8 +574,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError(f"q heads {hq} must be a multiple of kv heads "
                          f"{hkv}")
     rep = hq // hkv
-    if (s > 128 and s % 128 != 0) or (
-            s < 128 and jax.default_backend() == "tpu"):
+    unaligned = (s > 128 and s % 128 != 0) or (
+        s < 128 and jax.default_backend() == "tpu")
+    stock = (jax.default_backend() == "tpu"
+             and s > _resident_max_seq(max(d, dv)))
+    if rotary is not None and (unaligned or stock):
+        from ..layers import rotate
+        q, k = rotate(q, k, rotary)
+        rotary = None
+    if unaligned:
         # the blocked kernels require 128-aligned sequence lengths: an
         # unaligned tail would be silently dropped by the grid floor
         # division, and sub-128 blocks fail Mosaic's lane-width lowering
@@ -585,7 +599,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return dot_product_attention(q, k, v, causal=causal, bias=bias)
     from jax.ad_checkpoint import checkpoint_name
     bhsd = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    if jax.default_backend() == "tpu" and s > _resident_max_seq(max(d, dv)):
+    if stock:
         if rep > 1:
             # fallback paths take per-q-head kv (dot_product_attention
             # repeats internally; the stock kernel needs equal heads)
@@ -627,9 +641,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # (of the five, a rematted layer stores `o` and `lse`,
     # `_flash_fwd_rule`, and makes q, k, v again)
     to_bh = lambda x: bhsd(x).reshape(-1, s, x.shape[-1])  # noqa: E731
-    o = _flash(to_bh(q), to_bh(k), to_bh(v), causal, window, rep)
+    if rotary is not None:
+        from .rope import rotate_to_heads
+        q, k = (rotate_to_heads(x, rotary.wide, rotary.rotated)
+                for x in (q, k))
+    else:
+        q, k = to_bh(q), to_bh(k)
+    o = _flash(q, k, to_bh(v), causal, window, rep)
     return checkpoint_name(
         o.reshape(b, hq, s, dv).transpose(0, 2, 1, 3), "attn_out")
+
+
+flash_attention.applies_rotary = True
 
 
 def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
@@ -647,13 +670,17 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
     The returned callable carries ``applies_window = True``: it applies
     the model's sliding window itself, unlike the sequence-parallel
     wrappers; a call may give another ``window`` (None: none), as a stack
-    whose layers differ in it does (models/mellum.py)."""
+    whose layers differ in it does (models/mellum.py). It carries
+    ``applies_rotary = True`` too: ``rotary`` tables go to every shard
+    whole, and the rotation's kernels run inside the manual region with
+    the flash kernels."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import active_mesh
     from ...utils.jax_compat import shard_map
 
-    def attn(q, k, v, *, causal: bool = True, window=window, **_kw):
+    def attn(q, k, v, *, causal: bool = True, window=window, rotary=None,
+             **_kw):
         use, free = active_mesh(mesh)
         b_ax = tuple(a for a in batch_axes
                      if a in free and use.shape[a] > 1)
@@ -664,11 +691,15 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
                 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
                 else None)
         spec = P(b_ax or None, None, h_ax, None)
-        return shard_map(
-            functools.partial(flash_attention, causal=causal,
-                              window=window),
-            mesh=use, axis_names=set(free), in_specs=(spec, spec, spec),
-            out_specs=spec, check_vma=False)(q, k, v)
 
-    attn.applies_window = True
+        def per_shard(q, k, v, rotary):
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   rotary=rotary)
+
+        return shard_map(
+            per_shard, mesh=use, axis_names=set(free),
+            in_specs=(spec, spec, spec, jax.tree.map(lambda _: P(), rotary)),
+            out_specs=spec, check_vma=False)(q, k, v, rotary)
+
+    attn.applies_window = attn.applies_rotary = True
     return attn

@@ -168,7 +168,16 @@ WINDOW_SCOPES = (
     "ds.attn_swa",     # models/mellum.py _one_layer: a sliding_attention
     #                    layer's norm, projections, rotary, kernel, wo
     "ds.attn_full",    # the same of a full_attention layer
-    "ds.rope",         # models/mellum.py _attention: the rotation of q, k
+    "ds.rope",         # the rotation of q and k, opened by the op in
+    #                    either form: ops/layers.py rotate (apply_rotary,
+    #                    XLA's fusions) or, at a lane-aligned head on the
+    #                    flash kernels, ops/pallas/rope.py _call: the
+    #                    kernels ds_rope_fwd / ds_rope_bwd and nothing else
+    #                    (rotation and relayout to [B x H, S, D] in one
+    #                    pass; the backward rule opens the scope itself;
+    #                    under no ds.flash_* scope). models/transformer.py
+    #                    _qkv rotates under ds.attn alone, so a Mistral or
+    #                    Ouro step carries ds.rope only where the kernels run
 )
 # what a looped stack opens beside ds.attn and ds.mlp (models/ouro.py);
 # ``tests/test_ouro.py`` holds this list equal to what that model's step

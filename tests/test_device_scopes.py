@@ -548,7 +548,9 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     ("ds.mhc_fold", "models/xing4.py", "_layer_stack"),
     # the gate a head (ISSUE 60)
     ("ds.attn_gate", "models/laguna.py", "_attention"),
-    ("ds.rope", "models/laguna.py", "_attention"),
+    # the rotation, either form (ISSUE 62)
+    ("ds.rope", "ops/layers.py", "rotate"),
+    ("ds.rope", "ops/pallas/rope.py", "_call"),
 ])
 def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
                                                           function):

@@ -197,7 +197,7 @@ def test_partial_rotation_and_the_yarn_table_on_the_rotated_width():
     sections = model.config.rope_parameters
     for kind, key, rot in (("full", "full_attention", 16),
                            ("swa", "sliding_attention", 32)):
-        cos, sin = model._ropes[kind]
+        cos, sin, _ = model._ropes[kind]
         assert cos.shape == (128, rot // 2)
         freq, factor = arch.mellum.inv_freq(rot, sections[key])
         ang = np.arange(128)[:, None] * np.asarray(freq, np.float64)
@@ -208,7 +208,7 @@ def test_partial_rotation_and_the_yarn_table_on_the_rotated_width():
     # channels 16 to 31 of a full layer's q pass through; 0 to 15 are the
     # reference's rotation
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 4, 32))
-    cos, sin = model._ropes["full"]
+    cos, sin, _ = model._ropes["full"]
     got = L.apply_rotary(x, cos, sin)
     np.testing.assert_array_equal(got[..., 16:], x[..., 16:])
     want = arch.rotate_leading(x, 32, sections["full_attention"])

@@ -104,6 +104,18 @@ from helpers.families import program
 # layers pass no window, and the Mistral CELLS' window of 4096 is eight
 # blocks: ``test_the_flash_loops_are_the_parents_program`` below holds the
 # loops to the parent's text.
+# PR 62 re-took ``qwen3_next`` by design: its attention layer hands the
+# WHOLE head to ``ops/layers.py`` ``rotary_attention`` with its table of
+# ``rotary_dim`` channels (``apply_rotary`` rotates the leading channels of
+# a head wider than its table, PR 60) where ``_attention`` sliced the head,
+# rotated the slice and concatenated the rest back: one cast to float32 and
+# one rounding a head for two, the same products (the seeded weights are the
+# parent's). The eleven other rows stand: every tiny preset's head is
+# narrower than 128 lanes, so every family keeps the XLA form, the same
+# equations in the same order (``models/laguna.py``, ``models/mellum.py``
+# and ``models/lfm2_moe.py`` through ``rotary_attention`` -> ``rotate``,
+# ``models/transformer.py`` ``_qkv(rotate=True)`` as it was), and
+# ``sharded_flash_attention`` maps the same body with no tables to pass.
 _PINS = {
     "kimi_linear": (
         "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
@@ -127,7 +139,7 @@ _PINS = {
         "ff01f5cdc985b238b6f862877aa11232503e0a0743274f22dddd837917e3a975",
         2339.9930016614694),
     "qwen3_next": (
-        "cec92a9b712f3c14307258a68d2c40626f56876a508a39728df86696ffa32b1d",
+        "5222f59aa4e74f95531c12fb783bab8824d73863ecda7effa7f8a30920a5d08a",
         39458.17879846059),
     "lfm2_moe": (
         "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",

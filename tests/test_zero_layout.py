@@ -894,3 +894,83 @@ def test_flash_kernels_compile_for_v5e_at_the_laguna_cells_shapes(
     for kernel in ("ds_flash_fwd", "ds_flash_bwd"):
         assert len(re.findall(rf"%{kernel}[.\w]* = .*custom-call",
                               hlo)) == 1, kernel
+
+
+@pytest.mark.parametrize("s,heads,kv,d,rot", [
+    (8192, 72, 8, 128, 128),    # the Laguna cell's window layers
+    (8192, 48, 8, 128, 64),     # its full layer: half the head rotated
+    (16384, 32, 4, 128, 128),   # the Mellum cell
+    (16384, 16, 2, 256, 64),    # the Qwen3-Next cell: a head of two tiles
+])
+def test_rope_kernels_compile_for_v5e_alone_and_per_shard(
+        monkeypatch, s, heads, kv, d, rot):
+    """The rotation's kernel pair (ISSUE 62) at four cells' widths, compiled
+    by Mosaic for one described v5e chip inside a rematted flash layer: the
+    lane rolls, the selects of a partial rotation, a head's column run of
+    the projection's [1, S, H D] and the heads' stack on the other side are
+    what interpret mode cannot refuse. The step holds each kernel for q and
+    for k: twice forward (remat's rerun makes the flash kernels' q and k
+    again; ``o`` and ``lse`` are kept), once backward. Neither asks for more VMEM
+    than any XLA op gets, and no transpose or copy of q's size is left
+    beside them. Then ``sharded_flash_attention`` on ``v5e:2x2`` with the
+    batch over ``fsdp`` and the tables whole on every shard."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.models.transformer import _remat_policy
+    from deepspeed_tpu.ops import layers as L
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        flash_attention, sharded_flash_attention)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    tables = L.rotary_tables(*L.rotary_embedding(s, rot), d)
+    assert tables.wide is not None
+
+    def step(attn, b, *shardings):
+        """The gradients of a rematted layer's projections-to-attention
+        part: q and k reach the kernels as a matmul's [B, S, H D]."""
+        def layer(x, wq, wk, v):
+            q = (x @ wq).reshape(b, s, heads, d)
+            k = (x @ wk).reshape(b, s, kv, d)
+            return L.rotary_attention(attn, q, k, v, tables, causal=True)
+        layer = jax.checkpoint(layer,
+                               policy=_remat_policy("nothing_saveable"))
+        shapes = ((b, s, 256), (256, heads * d), (256, kv * d),
+                  (b, s, kv, d))
+        return jax.jit(jax.grad(
+            lambda *a: 0.5 * jnp.sum(layer(*a).astype(f32) ** 2),
+            argnums=(0, 1, 2, 3))).lower(*(
+                jax.ShapeDtypeStruct(dims, bf, sharding=sh)
+                for dims, sh in zip(shapes, shardings)))
+
+    one = SingleDeviceSharding(topo.devices[0])
+    hlo = step(flash_attention, 1, one, one, one, one).compile().as_text()
+    for kernel, n in (("ds_rope_fwd", 4), ("ds_rope_bwd", 2)):
+        found = [line for line in hlo.splitlines() if re.search(
+            rf"%{kernel}[.\w]* = .*custom-call", line)]
+        assert len(found) == n, (kernel, len(found))
+        for line in found:
+            asked = re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                               line)[0]
+            assert all(int(m) <= 16 * 2 ** 20 for m in re.findall(
+                r'"size":"(\d+)"', asked)), asked
+    # q is laid out for the flash kernels by the pair alone
+    assert not re.search(
+        rf"= bf16\[(1,)?{heads},{s},{d}\]\S* (copy|transpose)\(", hlo)
+    assert not re.search(
+        rf"= bf16\[1,{s},{heads},{d}\]\S* (copy|transpose)\(", hlo)
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    rows = NamedSharding(mt.mesh, P(mt.batch_axes()))
+    whole = NamedSharding(mt.mesh, P())
+    sharded = sharded_flash_attention(mt.mesh, mt.batch_axes())
+    hlo = step(sharded, 4, rows, whole, whole, rows).compile().as_text()
+    for kernel in ("ds_rope_fwd", "ds_rope_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo
