@@ -283,14 +283,20 @@ def moe_call_cost(m: dict, batch: int, seq: int, *, backward: bool,
     """FLOPs and HBM bytes of the step's held-expert calls (the routed
     layers held here; ``per: step``) at ``rows`` rows (token, choice) a
     routed layer, as the program counted them; None: a balanced router's,
-    a token's ``held_share``. The matmul units a step really runs, as
-    ``architectures/lfm2_moe.py`` counts them: three a row forward and
-    EIGHT backward (the backward rule keeps nothing of the forward but its
-    inputs and makes ``gate`` and ``up`` again before its six products;
-    remat's rerun of the forward, the program's choice, is not counted,
-    nor is a tile's padding). Bytes: every held expert's weights read once
-    (and their float32 gradients written once, backward), a row's input
-    gathered and its output scattered."""
+    a token's ``held_share``. Three matmul units a row forward and EIGHT
+    backward: the units that run (the backward rule keeps nothing of the
+    forward but its inputs and makes ``gate`` and ``up`` again before its
+    six products; remat's rerun of the forward is not counted, nor is a
+    tile's padding). THE OTHER FIVE ROUTED MODULES COUNT SIX BACKWARD (the
+    work the mathematics needs: PR 63 settled them on nine units a row and
+    could not move this one, because tier-1's ``tests/test_laguna.py``
+    ``test_required_operations_by_hand`` pins these eight and a
+    ``benchmark`` PR edits nothing under ``tests/``): this cell's
+    ``moe_experts_roofline.routed`` reads about 6% over what nine would
+    give (the backward call turns memory-bound at six; ``PERF.md`` section
+    7). Bytes: every held expert's weights read once (and their float32
+    gradients written once, backward), a row's input gathered and its
+    output scattered."""
     d, f = m["hidden_size"], m["moe_intermediate_size"]
     if rows is None:
         rows = batch * seq * held_share(m)

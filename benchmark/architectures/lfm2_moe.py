@@ -344,23 +344,23 @@ def moe_call_cost(m: dict, batch: int, seq: int, *, backward: bool,
     """FLOPs and HBM bytes of the step's held-expert calls (the routed
     layers held here; ``per: step``) at ``rows`` rows (token, choice) a
     routed layer, as the program counted them; None: a balanced router's,
-    a token's ``held_share``. The matmul units a step really runs: three a
-    row forward and EIGHT backward, because the backward rule keeps
-    nothing of the forward but its inputs and makes ``gate`` and ``up``
-    again before its six products (the other routed architectures count
-    nine where eleven run; remat's rerun of the forward, the program's
-    choice, is not counted, nor is a tile's padding). Bytes: every held
-    expert's weights read once (and their float32 gradients written once,
-    backward), a row's input gathered and its output scattered."""
+    a token's ``held_share``. Three matmuls a row forward and six backward:
+    the work the mathematics needs, the count of every routed architecture
+    here but Laguna's (a roofline is a share of the LEAST time). The
+    backward rule keeps nothing of the forward but its inputs and makes
+    ``gate`` and ``up`` again before its six products, so eleven units
+    run; that rerun is the program's choice, as remat's rerun of the
+    forward is, and neither is counted, nor is a tile's padding. Bytes:
+    every held expert's weights read once (and their float32 gradients
+    written once, backward), a row's input gathered and its output
+    scattered."""
     d, f = m["hidden_size"], m["moe_intermediate_size"]
     if rows is None:
         rows = batch * seq * held_share(m)
     weights = m["num_experts"] * 3 * d * f
-    unit = rows * 2 * d * f
+    flops = rows * 2 * 3 * d * f
     nbytes = weights * itemsize + 2 * rows * d * itemsize
     if backward:
-        flops, nbytes = 8 * unit, nbytes + weights * 4 + rows * d * itemsize
-    else:
-        flops = 3 * unit
+        flops, nbytes = 2 * flops, nbytes + weights * 4 + rows * d * itemsize
     layers = _n(m, 1, "moe")
     return {"flops": layers * flops, "bytes": layers * nbytes}

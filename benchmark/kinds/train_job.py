@@ -1,6 +1,10 @@
 """Traffic kind ``train_job``: build the training engine through
 ``ds.initialize``, check it against the plain reference, warm up, then
-train for the window, every step ending in ``block_until_ready``.
+train for the window. An untraced run keeps the traffic's
+``dispatch_ahead_seconds`` of steps sent ahead of the one it waits for
+(``steps_ahead``), so the chip is fed while the host stands still; a
+traced run waits for every step, which is what its host readers
+(``host_gap_ms.train``, ``clock_bracket_us.train``) read.
 
 From the program this takes only the system under test (the engine and
 its model) and, in a traced run, the spans it mirrors into the profiler
@@ -22,6 +26,7 @@ from lib import compilewatch, modelspec, telemetry, traffic as traffic_mod
 from reducers import program
 
 TAIL = 256      # last positions of each sequence whose logits are compared
+MAX_AHEAD = 64  # steps in flight at most, whatever the traffic asks for
 LEDGER_ENTRY = "compiled_step"      # the train step in the program's ledger
 
 
@@ -152,6 +157,17 @@ def agreement(engine, model, arch, batch: np.ndarray, check: dict) -> dict:
     return {"ref_loss": ref_loss, **numbers}
 
 
+def steps_ahead(traffic: dict, warm_step_s: float, traced: bool) -> int:
+    """How many steps the window keeps sent beyond the one it waits for:
+    the traffic's ``dispatch_ahead_seconds`` of steps at the warm-up's step
+    time, at least 1 and at most ``MAX_AHEAD``; 0 (wait for each step) in
+    a traced run and where the traffic has no such key."""
+    ahead_s = float(traffic.get("dispatch_ahead_seconds", 0.0))
+    if traced or ahead_s <= 0.0:
+        return 0
+    return int(min(MAX_AHEAD, max(1, -(-ahead_s // max(warm_step_s, 1e-3)))))
+
+
 def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
     cfg_file, tr, arch = (cell["config_file"], cell["traffic_file"],
                           cell["arch"])
@@ -165,9 +181,12 @@ def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
     pool = traffic_mod.train_batches(tr, args.seed, n_chips, vocab)
     tokens_per_step = pool[0].shape[0] * seq
 
-    def step(i):
+    def send(i):
         b = pool[i % len(pool)]
-        loss = engine.train_batch((b[:, :-1], b[:, 1:]))
+        return engine.train_batch((b[:, :-1], b[:, 1:]))
+
+    def step(i):
+        loss = send(i)
         loss.block_until_ready()
         return loss
 
@@ -178,29 +197,42 @@ def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
     agree["first_step_loss"] = first_loss
     agree_ok = decide(agree, agree["ref_loss"], first_loss, tol)
     print(f"agreement: {agree} tolerances {tol} ok={agree_ok}", flush=True)
-    for i in range(1, int(tr["warmup_steps"])):
-        step(i)
     warm = int(tr["warmup_steps"])
+    warm_step_s = float("inf")
+    for i in range(1, warm):
+        t_step = time.perf_counter()
+        step(i)
+        warm_step_s = min(warm_step_s, time.perf_counter() - t_step)
+    ahead = steps_ahead(tr, warm_step_s, tracer is not None)
     exe0 = compilewatch.executables()
 
     if tracer is not None:
         tracer.start()
     # ---- the window ----------------------------------------------------
-    # ends with the first step that finishes at or after --seconds, so the
-    # rate is all the tokens over all the time, with no step cut in two
+    # sends a step, waits for the one ``ahead`` steps before it, and reads
+    # the clock; once --seconds are up it sends nothing more, waits for all
+    # that was sent and reads the clock after that wait: the rate is all
+    # the tokens over all the time, with no step cut in two and nothing
+    # unfinished counted. ``ahead`` 0 waits for each step as it is sent.
     losses = []
     t0 = time.perf_counter()
     setup_s = t0 - t_start
+    done_at = []    # when each wait returned: a fed chip's own step times
     n = 0
     while True:
-        losses.append(step(warm + n))
+        losses.append(send(warm + n))
         n += 1
-        elapsed = time.perf_counter() - t0
-        if elapsed >= args.seconds:
+        if n - len(done_at) > ahead:
+            losses[len(done_at)].block_until_ready()
+            done_at.append(time.perf_counter())
+        if time.perf_counter() - t0 >= args.seconds:
             break
+    for loss in losses[len(done_at):]:
+        loss.block_until_ready()
+        done_at.append(time.perf_counter())
+    window_s = done_at[-1] - t0
     if tracer is not None:
         tracer.stop()
-    window_s = elapsed
     losses = [float(x) for x in losses]
     compiles_in_window = compilewatch.executables() - exe0
 
@@ -212,8 +244,15 @@ def run(cell: dict, args, rig: dict, *, tracer, t_start: float) -> dict:
         half = max(1, n // 2)
         first, last = np.mean(losses[:half]), np.mean(losses[-half:])
     falling = bool(last < first) if n >= 2 else True
-    print(f"train: steps={n} window_s={window_s:.3f} "
+    # the time between two waits' returns: where steps are sent ahead the
+    # chip's own step, by the window's first and last quarter and the longest
+    gaps = 1e3 * np.diff(done_at) if n >= 2 else np.zeros(1)
+    q = max(1, len(gaps) // 4)
+    print(f"train: steps={n} ahead={ahead} window_s={window_s:.3f} "
           f"step_ms_mean={1e3 * window_s / n:.2f} "
+          f"p50_first_quarter={np.median(gaps[:q]):.2f} "
+          f"p50_last_quarter={np.median(gaps[-q:]):.2f} "
+          f"longest={np.max(gaps):.2f} "
           f"loss first={first:.4f} last={last:.4f} finite={finite} "
           f"compiles_in_window={compiles_in_window}", flush=True)
 

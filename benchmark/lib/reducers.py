@@ -149,14 +149,3 @@ def least_ms_per_step(ctx, cost: str, per: str) -> float:
         raise files.BenchmarkFileError(
             f"per is {per!r}: a kernel runs once a 'layer' or once a 'step'")
     return 1e3 * least
-
-
-@reducer
-def flash_roofline_pct(ctx, args):
-    """Least time the chip could take for the step's flash calls (forward
-    and one-pass backward of every layer, this chip's sequences) over
-    their measured device time per step."""
-    ms = device_op_ms_per_step(ctx, args)
-    if not ms:
-        return None
-    return 100.0 * least_ms_per_step(ctx, "flash_call_cost", "layer") / ms

@@ -1,10 +1,9 @@
 """A kernel's share of its roofline, with the kernel found by the
-program's device scope. ``flash_roofline_pct`` (``lib/reducers.py``) takes
-its time from every op matching ``custom_call_target="tpu_custom_call"``,
-which is EVERY Pallas kernel: in a cell with a second one (a grouped
-matmul beside flash attention) a metric built on it would add the two.
-This one takes the time under a ``ds.`` scope, so each kernel of a cell
-gets a metric file of its own:
+program's device scope. The time is what lies under a ``ds.`` scope, never
+every op matching ``custom_call_target="tpu_custom_call"``: that is EVERY
+Pallas kernel, and in a cell with a second one (a grouped matmul or the
+rotation's pair beside flash attention) a metric built on it adds the
+two. So each kernel of a cell gets a metric file of its own:
 
     "reducer": {"name": "kernel_roofline_pct",
                 "args": {"scope": "ds\\.flash_(fwd|bwd)\\b",

@@ -100,7 +100,11 @@ def test_recorded_trace_mosaic_time_and_roofline(recorded):
     args = {"pattern": MOSAIC, "module": STEP}
     # flash forward + one-pass backward of two layers
     assert abs(reducers.device_op_ms_per_step(ctx, args) - 25.3967) < 1e-3
-    assert abs(reducers.flash_roofline_pct(ctx, args) - 57.693) < 1e-2
+    # the least time of those calls over it (what flash_roofline.train
+    # reads by scope on a trace that has scopes)
+    assert abs(100.0 * reducers.least_ms_per_step(
+        ctx, "flash_call_cost", "layer")
+        / reducers.device_op_ms_per_step(ctx, args) - 57.693) < 1e-2
     top = T.top_ops(recorded, 3)
     assert top[0][0] == "closed_call.35 [tpu_custom_call]"
     assert abs(top[0][1] - 0.0651437) < 1e-6
@@ -160,9 +164,34 @@ def test_train_batches_come_from_the_seed():
     assert 0 <= big[0].min() and big[0].max() < 32000
 
 
+@pytest.mark.parametrize("ahead_s, step_s, traced, want", [
+    (6.0, 0.576, False, 11),    # 6 s of steps, rounded up
+    (6.0, 1.5, False, 4),
+    (6.0, 30.0, False, 1),      # a step longer than the span: one ahead
+    (6.0, 0.01, False, 64),     # never more in flight than MAX_AHEAD
+    (6.0, float("inf"), False, 1),      # no warm-up step was timed
+    (6.0, 0.576, True, 0),      # a traced run waits for every step
+    (None, 0.576, False, 0),    # a traffic file without the key
+])
+def test_steps_sent_ahead(ahead_s, step_s, traced, want):
+    from kinds import train_job
+    tr = {} if ahead_s is None else {"dispatch_ahead_seconds": ahead_s}
+    assert train_job.steps_ahead(tr, step_s, traced) == want
+
+
+def test_a_traffic_that_sends_ahead_sends_four_to_eight_seconds():
+    """A stall of the host shorter than that leaves the chip fed, and the
+    last wait has an end; a file without the key waits for every step."""
+    ahead = {name: files.load_traffic(name).get("dispatch_ahead_seconds")
+             for name in sorted({w["traffic"] for w in B["workloads"]})}
+    assert all(v is None or 4.0 <= v <= 8.0 for v in ahead.values()), ahead
+    assert ahead["pretrain-s16k"] == 6.0
+
+
 # ---- BENCHMARK.json and the files agree -----------------------------------
 def test_no_cell_reads_one_thing_under_two_names():
-    """One entry a definition (PR 49 folded 103 copies into 23). What an
+    """One entry a definition (PR 49 folded 103 copies into 23, PR 63 the
+    sixty that PRs 54, 56 and 60 brought into the entries they copied). What an
     adding PR can still do, because it may edit no file that is there, is
     bring COPIES of the step readers under a suffix of its own, for its
     own cells (README, "Adding things", point 4); the next ``benchmark``
@@ -282,6 +311,12 @@ def test_cell_end_to_end_on_cpu(cell):
         assert math.isfinite(m["value"]) and m["value"] > 0
     assert line["failed"] == 0 and line["attempted"] > 0
     assert line["correct"] is True, out[-3000:]
+    # untraced: steps were sent ahead, and every step sent was waited for
+    # and counted (a loss that is not finite would have failed the run)
+    train = [ln for ln in out.splitlines() if ln.startswith("train: ")][-1]
+    sends = "dispatch_ahead_seconds" in spec["traffic_file"]
+    assert (int(train.split("ahead=")[1].split()[0]) >= 1) == sends
+    assert int(train.split("steps=")[1].split()[0]) == line["attempted"]
 
 
 def test_traced_run_reports_per_layer_metrics_on_cpu():

@@ -189,10 +189,11 @@ def test_the_cell_runs_on_cpu(trace):
     if trace == "0":
         assert got == {"train_tokens_per_s", "setup_s"}
         return
-    assert {"mfu.lag", "held_expert_tokens.lag"} <= got
+    assert {"mfu.train", "held_expert_tokens.routed",
+            "setup_init_s.train"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
-    assert len(files.load_cell(CELL)["per_layer"]) == 18
-    assert 3.0 < line["metrics"]["held_expert_tokens.lag"]["value"] < 7.0
+    assert len(files.load_cell(CELL)["per_layer"]) == 26
+    assert 3.0 < line["metrics"]["held_expert_tokens.routed"]["value"] < 7.0
 
 
 def test_the_control_judges_the_program_and_each_planted_fault():

@@ -62,8 +62,6 @@ def scoped():
 @pytest.mark.parametrize("name,value", [
     ("host_gap_ms.train", 2.8115949999999446),
     ("device_step_ms.train", 256.02368500000046),
-    ("flash_ms.train", 25.39668300000003),
-    ("flash_roofline.train", 57.692751441771556),
     ("device_idle.train", 0.8009174534002295),
     ("collective_ms.train", 0.0),
     ("exposed_collective_ms.train", 0.0),
@@ -90,6 +88,7 @@ def test_a_program_without_scopes_or_spans_leaves_the_metrics_out(old):
     for name in ("layers_fwd_ms.train", "layers_bwd_ms.train",
                  "loss_head_ms.train", "optimizer_ms.train",
                  "flash_fwd_ms.train", "flash_bwd_ms.train",
+                 "flash_ms.train", "flash_roofline.train",
                  "unscoped_ms.train", "setup_import_s.train",
                  "setup_init_s.train", "setup_compile_s.train"):
         assert _metric(old, name) is None, name
@@ -118,10 +117,16 @@ def test_scoped_parts_telescope_to_the_device_step(scoped):
     assert abs(parts["unscoped_ms.train"] - 1.3931) < 1e-3
     assert abs(sum(parts.values()) - step) < 0.01 * step
     assert parts["unscoped_ms.train"] < 0.02 * step
+    # the two scopes together ARE flash_ms.train since PR 63; this trace's
+    # only Mosaic calls are the flash pair, so the old pattern (every
+    # ``tpu_custom_call``) still reads the same time here
     flash = (_metric(scoped, "flash_fwd_ms.train")
              + _metric(scoped, "flash_bwd_ms.train"))
-    assert abs(flash - _metric(scoped, "flash_ms.train")) \
-        < 0.01 * _metric(scoped, "flash_ms.train")
+    assert flash == pytest.approx(_metric(scoped, "flash_ms.train"),
+                                  rel=1e-9)
+    mosaic = reducers.device_op_ms_per_step(scoped, {
+        "pattern": 'custom_call_target="tpu_custom_call"', "module": STEP})
+    assert abs(flash - mosaic) < 0.01 * mosaic
     top = rp.device_scopes(scoped, 3)
     assert [s for s, _ in top] == ["bwd:ds.layers/ds.mlp",
                                    "bwd:ds.loss_head",
@@ -135,15 +140,19 @@ def test_a_kernels_roofline_by_scope_reads_the_flash_kernels_share(scoped,
                                                                    old):
     """``kernel_roofline_pct`` finds its kernel by the program's scope and
     its cost by name in the architecture module: for the two flash scopes
-    it reads ``flash_roofline.train``'s value (whose reducer finds them as
-    the trace's only Mosaic calls). A second Pallas kernel under another
-    scope would move that metric and not this one."""
+    it IS ``flash_roofline.train`` since PR 63 (whose file took every
+    Mosaic call until then, and so PR 62's rotation kernels). A second
+    Pallas kernel under another scope does not move it, and the least time
+    over ``flash_ms.train`` is the same number."""
     args = {"scope": r"ds\.flash_(fwd|bwd)\b", "cost": "flash_call_cost",
             "per": "layer", "module": STEP}
     read = reducers.find("kernel_roofline_pct")
     assert read(scoped, args) == pytest.approx(
         _metric(scoped, "flash_roofline.train"), rel=1e-9)
     assert abs(read(scoped, args) - 57.6905) < 1e-3
+    assert read(scoped, args) == pytest.approx(
+        100.0 * reducers.least_ms_per_step(scoped, "flash_call_cost", "layer")
+        / _metric(scoped, "flash_ms.train"), rel=1e-9)
     # forward alone against both calls' least time: another number
     assert read(scoped, {**args, "scope": r"ds\.flash_fwd\b"}) > 100.0
     # once a step and not once a layer: the least time of one layer

@@ -170,11 +170,11 @@ def test_the_cell_runs_on_cpu(trace):
     if trace == "0":
         assert got == {"train_tokens_per_s", "setup_s"}
         return
-    assert {"mfu.mhc", "held_expert_tokens.mhc", "mhc_sinkhorn_residual.mhc",
-            "setup_init_s.mhc"} <= got
+    assert {"mfu.train", "held_expert_tokens.routed",
+            "mhc_sinkhorn_residual.mhc", "setup_init_s.train"} <= got
     assert got <= set(files.load_cell(CELL)["per_layer"])
     # 128 tokens x top-4 of 64: 8 rows a held expert if balanced
-    assert 3 < line["metrics"]["held_expert_tokens.mhc"]["value"] < 16
+    assert 3 < line["metrics"]["held_expert_tokens.routed"]["value"] < 16
     assert 0 < line["metrics"]["mhc_sinkhorn_residual.mhc"]["value"] < 1e-2
 
 
