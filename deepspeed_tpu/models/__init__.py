@@ -1,6 +1,7 @@
 from .base import Model, ModelConfig, get_model_class, register_model  # noqa: F401
 from .bert import Bert, bert_config  # noqa: F401
 from .bloom import Bloom, bloom_config  # noqa: F401
+from .deepseek_v3 import DeepseekV3, deepseek_v3_config  # noqa: F401
 from .falcon import Falcon, falcon_config  # noqa: F401
 from .gpt2 import GPT2, gpt2_config  # noqa: F401
 from .gptj import GPTJ, gptj_config  # noqa: F401

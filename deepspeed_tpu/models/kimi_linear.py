@@ -57,7 +57,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import mean_context, register_model
-from .stack import RoutedStackConfig, RoutedStackOfKinds
+from .stack import (LatentAttention, RoutedStackConfig, RoutedStackOfKinds,
+                    mla_params)
 from .transformer import _dense_init
 
 
@@ -145,15 +146,8 @@ class KimiLinearConfig(RoutedStackConfig):
                + d * h                                           # beta
                + d * r + r * inner + inner                       # out gate
                + dk + inner * d)                                 # norm, wo
-        nh = self.num_heads
-        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
-        mla = (d * nh * qk + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-               + self.kv_lora_rank
-               + self.kv_lora_rank * nh * (self.qk_nope_head_dim
-                                           + self.v_head_dim)
-               + nh * self.v_head_dim * d)
         expert = self._expert_params()
-        return {"kda": kda, "mla": mla,
+        return {"kda": kda, "mla": mla_params(self),
                 "dense": 3 * d * self.intermediate_size,
                 "expert": expert,
                 "moe": (d * self.num_experts + self.num_experts
@@ -218,7 +212,7 @@ def kimi_linear_config(size: str = "48b-a3b",
 
 
 @register_model("kimi_linear")
-class KimiLinear(RoutedStackOfKinds):
+class KimiLinear(LatentAttention, RoutedStackOfKinds):
     def __init__(self, config: KimiLinearConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
@@ -298,16 +292,7 @@ class KimiLinear(RoutedStackOfKinds):
                 "wo": w((inner, d), resid_std),
             }
         else:
-            nh = c.num_heads
-            qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-            p["mla"] = {
-                "wq": w((d, nh * qk)),
-                "w_kva": w((d, c.kv_lora_rank + c.qk_rope_head_dim)),
-                "kv_norm": ones((c.kv_lora_rank,)),
-                "w_kvb": w((c.kv_lora_rank,
-                            nh * (c.qk_nope_head_dim + c.v_head_dim))),
-                "wo": w((nh * c.v_head_dim, d), resid_std),
-            }
+            p["mla"] = self._init_mla(w, ones, resid_std)
         if channel == "dense":
             f = c.intermediate_size
             p["mlp"] = {"w_gate": w((d, f)), "w_up": w((d, f)),
@@ -389,24 +374,6 @@ class KimiLinear(RoutedStackOfKinds):
         # ... and y (by wo's gradient) before dy moves on into the norm's
         y, wo = _together((o, p["wo"]))
         return y @ wo
-
-    def _mla(self, p, h, attn_fn):
-        c = self.config
-        b, s, _ = h.shape
-        nh, nope, rope, dv = (c.num_heads, c.qk_nope_head_dim,
-                              c.qk_rope_head_dim, c.v_head_dim)
-        q = (h @ p["wq"]).reshape(b, s, nh, nope + rope)
-        kva = h @ p["w_kva"]
-        latent = L.rms_norm(kva[..., :c.kv_lora_rank], p["kv_norm"],
-                            c.norm_eps)
-        k_pe = kva[..., c.kv_lora_rank:]
-        kv = (latent @ p["w_kvb"]).reshape(b, s, nh, nope + dv)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_pe[:, :, None, :], (b, s, nh, rope))],
-            axis=-1)
-        a = attn_fn(q, k, kv[..., nope:], causal=True)
-        return a.reshape(b, s, nh * dv) @ p["wo"]
 
     def _routed(self, p, h):
         from ..moe.sharded_moe import moe_ffn_held

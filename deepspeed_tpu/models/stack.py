@@ -26,6 +26,12 @@ families' gated norm) hands it over behind them: ``_layer_fns``.
 what ``models/mellum.py`` and ``models/laguna.py`` share: the two kinds'
 names, a rotary table a kind, the mixers by window and the partition
 rules.
+
+**Latent attention** (``LatentAttention``): the MLA layer of
+``models/kimi_linear.py`` (a direct query, unrotated),
+``models/xing4.py`` (a low-rank normed query, rotated by halves under
+YaRN) and ``models/deepseek_v3.py`` (a direct query, rotated by
+interleaved pairs): its weights, their count and the mixer.
 """
 
 from __future__ import annotations
@@ -434,3 +440,104 @@ class WindowAndFullAttention:
                 *self._more_rules]:
             rules += both(pattern, *spec)
         return rules + [(r"lm_head$", P(None, "tp"))]
+
+
+def mla_params(c) -> int:
+    """A latent-attention mixer's parameters as ``LatentAttention.
+    _init_mla`` builds them, from the published keys of ``c``: a direct
+    query (``q_lora_rank`` 0 or absent) or a low-rank one with its norm."""
+    d, nh = c.hidden_size, c.num_heads
+    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    rank = getattr(c, "q_lora_rank", 0)
+    query = d * rank + rank + rank * nh * qk if rank else d * nh * qk
+    return (query + d * (c.kv_lora_rank + c.qk_rope_head_dim)
+            + c.kv_lora_rank
+            + c.kv_lora_rank * nh * (c.qk_nope_head_dim + c.v_head_dim)
+            + nh * c.v_head_dim * d)
+
+
+class LatentAttention:
+    """Latent attention (MLA) as the routed stacks that have it share it
+    (a mixin in front of ``RoutedStackOfKinds``)::
+
+        q = h Wq                       or   rmsnorm(h Wqa) Wqb    (low rank)
+            as H x (nope + rope)
+        [c, k_pe] = h Wkva  (kv_lora + rope);  [k_nope, v] = rmsnorm(c) Wkvb
+        q_pe, k_pe rotated (``_rope``: by halves, or the checkpoint's
+        interleaved pairs laid out as halves first, ``_rope_pairs``; None:
+        not rotated);   k_h = [k_nope_h, k_pe]: ONE rotated key for all H
+        y = softmax_causal(q k^T (nope + rope)^-1/2 m^2) v Wo
+
+    ``m`` is the config's ``softmax_mscale`` (YaRN's; 1 without): the
+    attention kernels' scale is the key width's ``d^-1/2``, so ``m^2``
+    rides on the query, on its latent norm's weight where it has one
+    (float32 inside the norm, one rounding: ``q`` is linear in it). In
+    training the latent is expanded and the layer runs as H-head attention
+    with a key of ``nope + rope`` and a value of ``v_head_dim`` through the
+    flash kernels. The family sets ``_rope`` (``(cos, sin)`` of
+    ``ops.layers.rotary_embedding`` over ``qk_rope_head_dim``) and
+    ``_rope_pairs`` in its ``__init__``."""
+
+    _rope = None            # (cos, sin) over qk_rope_head_dim, or None
+    _rope_pairs = False     # the rotated channels come as interleaved pairs
+
+    def _init_mla(self, w, ones, resid_std: float) -> dict:
+        """The mixer's weights, drawn in this order: ``w(shape, scale=)``
+        and ``ones(shape)`` are the family's ``_init_layer``'s, and
+        ``resid_std`` its residual outputs' deviation."""
+        c = self.config
+        d, nh = c.hidden_size, c.num_heads
+        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        rank = getattr(c, "q_lora_rank", 0)
+        query = ({"wq_a": w((d, rank)), "q_norm": ones((rank,)),
+                  "wq_b": w((rank, nh * qk))} if rank else
+                 {"wq": w((d, nh * qk))})
+        return {
+            **query,
+            "w_kva": w((d, c.kv_lora_rank + c.qk_rope_head_dim)),
+            "kv_norm": ones((c.kv_lora_rank,)),
+            "w_kvb": w((c.kv_lora_rank,
+                        nh * (c.qk_nope_head_dim + c.v_head_dim))),
+            "wo": w((nh * c.v_head_dim, d), resid_std),
+        }
+
+    def _mla_query(self, p, h):
+        """[B, S, H (nope + rope)], with the softmax scale's ``m^2``."""
+        c = self.config
+        m2 = getattr(c, "softmax_mscale", 1.0) ** 2
+        if "wq" in p:
+            q = h @ p["wq"]
+            return q if m2 == 1.0 else q * jnp.asarray(m2, q.dtype)
+        cq = L.rms_norm(h @ p["wq_a"], p["q_norm"].astype(jnp.float32) * m2,
+                        c.norm_eps)
+        return cq @ p["wq_b"]
+
+    def _rotated(self, x):
+        cos, sin = self._rope
+        return L.apply_rotary(L.pairs_to_halves(x) if self._rope_pairs else x,
+                              cos, sin)
+
+    def _mla(self, p, h, attn_fn):
+        c = self.config
+        b, s, _ = h.shape
+        nh, nope, rope, dv, r = (c.num_heads, c.qk_nope_head_dim,
+                                 c.qk_rope_head_dim, c.v_head_dim,
+                                 c.kv_lora_rank)
+        rotated = self._rope is not None
+        q = self._mla_query(p, h).reshape(b, s, nh, nope + rope)
+        kva = h @ p["w_kva"]
+        latent = L.rms_norm(kva[..., :r], p["kv_norm"], c.norm_eps)
+        if not rotated:
+            k_pe = kva[..., r:]
+        kv = (latent @ p["w_kvb"]).reshape(b, s, nh, nope + dv)
+        if rotated:
+            with jax.named_scope("ds.rope"):
+                k_pe = self._rotated(kva[..., None, r:])
+                q = jnp.concatenate(
+                    [q[..., :nope], self._rotated(q[..., nope:])], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe if rotated else k_pe[:, :, None, :],
+                              (b, s, nh, rope))], axis=-1)
+        a = attn_fn(q, k, kv[..., nope:], causal=True)
+        return a.reshape(b, s, nh * dv) @ p["wo"]

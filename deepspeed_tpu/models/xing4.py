@@ -68,7 +68,8 @@ from jax.sharding import PartitionSpec as P
 from ..ops import layers as L
 from ..ops import mhc
 from .base import mean_context, register_model
-from .stack import RoutedStackConfig, RoutedStackOfKinds
+from .stack import (LatentAttention, RoutedStackConfig, RoutedStackOfKinds,
+                    mla_params)
 from .transformer import _dense_init
 
 
@@ -158,21 +159,14 @@ class Xing4Config(RoutedStackConfig):
         latent norms, the layer's two norms, both sublayers' mHC and the
         channel mixer (the router, its bias, the shared expert and the
         experts held)."""
-        d, nh = self.hidden_size, self.num_heads
-        mla = (d * self.q_lora_rank + self.q_lora_rank
-               + self.q_lora_rank * nh * self.qk_head_dim
-               + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-               + self.kv_lora_rank
-               + self.kv_lora_rank * nh * (self.qk_nope_head_dim
-                                           + self.v_head_dim)
-               + nh * self.v_head_dim * d)
+        d = self.hidden_size
         if kind[1] == "dense":
             ff = 3 * d * self.intermediate_size
         else:
             ff = ((d + 1) * self.num_experts
                   + self._expert_params() * self.moe_num_shared_experts
                   + self._held_params())
-        return mla + 2 * d + 2 * self._mhc_params() + ff
+        return mla_params(self) + 2 * d + 2 * self._mhc_params() + ff
 
     def _layer_idle_params(self, kind) -> float:
         return self._idle_held_params() if kind[1] == "routed" else 0
@@ -237,7 +231,7 @@ def xing4_config(size: str = "29b-a4b", **overrides) -> Xing4Config:
 
 
 @register_model("xing4_0")
-class Xing4(RoutedStackOfKinds):
+class Xing4(LatentAttention, RoutedStackOfKinds):
     def __init__(self, config: Xing4Config | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
@@ -319,7 +313,7 @@ class Xing4(RoutedStackOfKinds):
     def _init_layer(self, key, kind, lead_shape=()):
         c = self.config
         dt = c.param_dtype
-        d, nh = c.hidden_size, c.num_heads
+        d = c.hidden_size
         std = 0.02
         resid_std = std / (2 * c.num_layers) ** 0.5
         ks = iter(jax.random.split(key, 20))
@@ -334,16 +328,7 @@ class Xing4(RoutedStackOfKinds):
             "ln1_scale": ones((d,)), "ln2_scale": ones((d,)),
             "hc1": self._init_mhc(next(ks), lead_shape),
             "hc2": self._init_mhc(next(ks), lead_shape),
-            "mla": {
-                "wq_a": w((d, c.q_lora_rank)),
-                "q_norm": ones((c.q_lora_rank,)),
-                "wq_b": w((c.q_lora_rank, nh * c.qk_head_dim)),
-                "w_kva": w((d, c.kv_lora_rank + c.qk_rope_head_dim)),
-                "kv_norm": ones((c.kv_lora_rank,)),
-                "w_kvb": w((c.kv_lora_rank,
-                            nh * (c.qk_nope_head_dim + c.v_head_dim))),
-                "wo": w((nh * c.v_head_dim, d), resid_std),
-            },
+            "mla": self._init_mla(w, ones, resid_std),
         }
         if kind[1] == "dense":
             f = c.intermediate_size
@@ -380,32 +365,6 @@ class Xing4(RoutedStackOfKinds):
         }
 
     # ---------------- the sublayers ----------------
-    def _mla(self, p, h, attn_fn):
-        c = self.config
-        b, s, _ = h.shape
-        nh, nope, rope, dv, r = (c.num_heads, c.qk_nope_head_dim,
-                                 c.qk_rope_head_dim, c.v_head_dim,
-                                 c.kv_lora_rank)
-        # YaRN's m^2 on the softmax scale rides on the query's latent norm
-        # (float32 inside the norm, one rounding): q is linear in it
-        cq = L.rms_norm(h @ p["wq_a"], p["q_norm"].astype(jnp.float32)
-                        * c.softmax_mscale ** 2, c.norm_eps)
-        q = (cq @ p["wq_b"]).reshape(b, s, nh, nope + rope)
-        kva = h @ p["w_kva"]
-        latent = L.rms_norm(kva[..., :r], p["kv_norm"], c.norm_eps)
-        kv = (latent @ p["w_kvb"]).reshape(b, s, nh, nope + dv)
-        with jax.named_scope("ds.rope"):
-            cos, sin = self._rope
-            k_pe = L.apply_rotary(kva[..., None, r:], cos, sin)
-            q = jnp.concatenate(
-                [q[..., :nope], L.apply_rotary(q[..., nope:], cos, sin)],
-                axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, nh, rope))],
-            axis=-1)
-        a = attn_fn(q, k, kv[..., nope:], causal=True)
-        return a.reshape(b, s, nh * dv) @ p["wo"]
-
     def _routed(self, p, h):
         from ..moe.sharded_moe import moe_ffn_held
         c = self.config

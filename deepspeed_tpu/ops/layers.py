@@ -253,6 +253,17 @@ def apply_rotary(x, cos, sin, positions=None):
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
+def pairs_to_halves(x):
+    """The last axis' interleaved pairs ``(x_0, x_1), (x_2, x_3), ...`` laid
+    out as halves ``[x_0, x_2, ... | x_1, x_3, ...]``: what
+    :func:`apply_rotary`'s pairs ``(i, i + R / 2)`` then rotate is the
+    checkpoint's pair ``(x_2i, x_2i+1)`` at ``theta_i`` (a published
+    ``rope_interleave``: DeepSeek-V3's rotated channels). Applied to q and
+    k alike it leaves their product alone, so nothing lays them back."""
+    *lead, d = x.shape
+    return jnp.swapaxes(x.reshape(*lead, d // 2, 2), -1, -2).reshape(x.shape)
+
+
 class RotaryTables(NamedTuple):
     """A model's rotary tables: ``cos``, ``sin`` [S_max, R // 2] as
     :func:`rotary_embedding` builds them, and ``wide`` (``[cos | cos |

@@ -64,9 +64,18 @@ Design notes (why this beats the stock two-pass kernel at model shapes):
 
 Layout: wrapper takes [B, S, H, D] (model convention), kernels run on
 [B*H, S, D]. The log-sum-exp is carried as [BH, 1, S] so every block
-spec is TPU-legal ((1, 1, bq) blocks). VMEM residency caps the supported
-sequence length per head dim (_resident_max_seq); past it the wrapper
-falls back to the stock two-pass jax.experimental kernel.
+spec is TPU-legal ((1, 1, bq) blocks). VMEM residency caps the row the
+kernels hold whole (`_resident_max_seq`, by the bytes the backward holds
+at the key and value widths). Past it a windowless call runs the SAME two
+kernels on equal spans of the row that fit (`segments`, `_spans_fwd`,
+`_spans_bwd`): a query span meets every key span at or under it (the
+diagonal pairs causal, the others whole), a span's partial outputs are
+merged by their log-sum-exp, and the backward runs a pair at a time
+against the row's own ``lse`` and ``delta``, which makes ``dq`` additive
+over key spans and ``dk``, ``dv`` over query spans. The pairs sweep what
+one long row would (the same tiles, `span_counts`); what is added is the
+merge, under scope ``ds.flash_merge``. A WINDOW past the cap is the one
+call left to the exact form (no configuration has one).
 
 On non-TPU backends the kernels run in Pallas interpret mode (tests), so
 the same code path is exercised everywhere.
@@ -88,25 +97,62 @@ from ._common import _interpret, _keep, _registry
 NEG_INF = -1e30
 _LANES = 128          # a vector register's minor dimension
 
-# k/v (fwd) and q/do/dq (bwd) are VMEM-resident per (batch*head) row, so
-# the working set scales with s*d: at 32k x 128 that is ~8M bf16 per
-# operand + a 16M f32 dq slab — ~45M total against the raised
-# _COMPILER_PARAMS ceiling (v5e/v5p have 128M). The dispatch gates on
-# s*d (64k at d=64, 32k at d=128, 16k at d=256). The reason for the
-# resident form is one pass over k/v where the stock kernel makes two;
-# a run before this round's records read 1.38x the stock kernel's
-# training throughput at seq 32768 x d128 (not in the ledger: no cell
-# runs that length).
-_RESIDENT_MAX_ELEMS = 32768 * 128
+# k/v (fwd) and q/do/dq (bwd) are VMEM-resident per (batch*head) row. The
+# reason for the resident form is one pass over k/v where the stock kernel
+# makes two; a run before this round's records read 1.38x the stock
+# kernel's training throughput at seq 32768 x d128. What a row costs is
+# what the BACKWARD holds of it (the forward holds k and v alone): q and do
+# in their own dtype and the float32 dq slab, each at its lanes PADDED to
+# whole 128-lane tiles (a head of 64 takes a head of 128's VMEM, one of 192
+# one of 256's) and each in the pipeline's two buffers, beside the two
+# float32 rows of statistics. Mosaic's default 16 MiB scoped ceiling trips
+# at any long row: the kernels ask for `_VMEM_LIMIT` (v5e / v5p have 128
+# MiB), and a row may take that less `_VMEM_REST`, what a grid step holds
+# beside it (the k / v / dk / dv blocks in two buffers, the [512, 512]
+# float32 tiles of the body). Compiled for a described v5e at bf16 (PR 64):
+# a key of 64 or 128 compiles to 57344 rows and not 58368, 192 / 128 to
+# 31744 and not 32768, 256 to 28672 and not 29696; the caps here are
+# 47662, 27594 and 24197. The rule this replaces, s x d <= 32768 x 128
+# unpadded, admitted 65536 x 64, which asks 132 of 128 MiB.
+_VMEM_LIMIT = 100 * 1024 * 1024
+_VMEM_REST = 4 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _resident_max_seq(d: int) -> int:
-    return _RESIDENT_MAX_ELEMS // max(d, 1)
+def _row_vmem_bytes(d: int, dv: int, itemsize: int = 2) -> int:
+    """VMEM bytes a position of a row costs the backward kernel."""
+    pad = lambda w: -(-max(w, 1) // _LANES) * _LANES  # noqa: E731
+    slabs = itemsize * (pad(d) + pad(dv)) + 4 * pad(d)      # q, do; dq
+    return 2 * slabs + 64       # lse and delta: 16 B a position a buffer
 
-# the row-resident kernels hold [S, D] slabs (q/do/dq + temps) in VMEM;
-# Mosaic's default 16MB scoped-vmem ceiling trips at long seq x D=128 —
-# raise it (v5e/v5p have 128MB)
-_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+
+def _resident_max_seq(d: int, dv: int | None = None,
+                      itemsize: int = 2) -> int:
+    """The longest row the kernels hold whole at a key of ``d`` and a value
+    of ``dv`` (``d``'s where not given)."""
+    return (_VMEM_LIMIT - _VMEM_REST) // _row_vmem_bytes(
+        d, d if dv is None else dv, itemsize)
+
+
+def segments(s: int, d: int, dv: int | None = None, itemsize: int = 2) -> int:
+    """Equal spans a row of ``s`` is run in: 1 at or under the cap; past it
+    the fewest that fit (2 at 32768 x 192 / 128), more where the spans
+    would not be whole 128-row blocks."""
+    n = -(-s // _resident_max_seq(d, dv, itemsize))
+    while n > 1 and s % (n * 128):
+        n += 1
+    return n
+
+
+def _gauge_segments(s: int, d: int, n: int):
+    reg = _registry()
+    if reg is not None:
+        reg.gauge("ds_flash_segments",
+                  "equal spans a (batch x head) row of the flash call last "
+                  "built is run in: 1 where the kernels hold the row whole, "
+                  "more past the residency cap (every pair of spans the "
+                  "mask leaves is one call of the same kernels)"
+                  ).set(n, s=str(s), d=str(d))
 
 
 def _block(s: int) -> int:
@@ -241,11 +287,25 @@ def pair_counts(s: int, b: int, window, causal: bool) -> dict:
     return {"swept": swept, "live": live}
 
 
-def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool):
+def span_counts(count, s: int, span: int, causal: bool) -> dict:
+    """``count(span, causal)`` (a dict of numbers a row) summed over the
+    pairs of spans a row of ``s`` cut in spans of ``span`` runs
+    (`_span_pairs`: the diagonal pairs causal, the others whole)."""
+    total = dict.fromkeys(count(span, causal), 0)
+    for _, _, diagonal in _span_pairs(s // span, causal):
+        for kind, n in count(span, diagonal).items():
+            total[kind] += n
+    return total
+
+
+def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool,
+                 span: int | None = None):
     """Trace time, host only: how often the maskless body engages, and how
     many of the pairs a sweep computes the mask leaves live, are functions
     of shapes, so they are counted where the kernel is built. The band's
-    groups count as masked tiles."""
+    groups count as masked tiles. A row cut in spans of ``span``
+    (windowless: `_spans_fwd`) counts what its pairs of spans sweep, all
+    of them together."""
     reg = _registry()
     if reg is None:
         return
@@ -253,28 +313,38 @@ def _gauge_tiles(kernel: str, s: int, b: int, window, causal: bool):
                   "score tiles a (batch x head) row of the flash kernel "
                   "last built runs masked / unmasked / skips by the window")
     t = band_rows(b, window, causal)
-    tiles = (tile_counts(s, b, window, causal) if t is None else
-             dict(zip(TILE_KINDS, (s // t, 0, 0))))
+    if span is not None:
+        tiles = span_counts(lambda n, c: tile_counts(n, b, None, c), s, span,
+                            causal)
+    else:
+        tiles = (tile_counts(s, b, window, causal) if t is None else
+                 dict(zip(TILE_KINDS, (s // t, 0, 0))))
     for kind, n in tiles.items():
         g.set(n, kernel=kernel, kind=kind)
     g = reg.gauge("ds_flash_pairs",
                   "query-key pairs a (batch x head) row of the flash kernel "
                   "last built computes (swept) / the mask leaves live")
-    for kind, n in pair_counts(s, b, window, causal).items():
+    pairs = (pair_counts(s, b, window, causal) if span is None else
+             span_counts(lambda n, c: pair_counts(n, b, None, c), s, span,
+                         causal))
+    for kind, n in pairs.items():
         g.set(n, kernel=kernel, kind=kind)
 
 
 # ---------------------------------------------------------------- forward
 def _flash_fwd(q, k, v, *, causal: bool, sc: float,
-               window: int | None = None, rep: int = 1):
+               window: int | None = None, rep: int = 1, part: bool = False):
     """``rep``: GQA group size — q rows are [B*Hq, S, D], k/v rows
     [B*Hkv, S, D]; the kv index maps divide the q-head grid index by
-    ``rep`` instead of materializing repeated k/v."""
+    ``rep`` instead of materializing repeated k/v. ``part``: one pair of
+    spans of a longer row (`_spans_fwd`): ``o`` stays float32 for the
+    merge, and the row's gauges are the caller's."""
     bh, s, d = q.shape
     dv = v.shape[-1]        # the value width may differ from the key's
     bq = bk = _block(s)
     grid = (bh, s // bq)
-    _gauge_tiles("fwd", s, bq, window, causal)
+    if not part:
+        _gauge_tiles("fwd", s, bq, window, causal)
     kernel = functools.partial(_fwd_kernel, sc=sc, bq=bq, bk=bk,
                                nk=s // bk, causal=causal, window=window)
     call = pl.pallas_call(
@@ -306,7 +376,7 @@ def _flash_fwd(q, k, v, *, causal: bool, sc: float,
     # call (telemetry/scopes.py); both are HLO metadata and cost no time
     with jax.named_scope("ds.flash_fwd"):
         o, lse = call(q, k, v)
-    return o.astype(q.dtype), lse
+    return (o if part else o.astype(q.dtype)), lse
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sc, bq, bk, nk,
@@ -466,14 +536,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref[0], dv_ref[0] = carry
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
-               window: int | None = None, rep: int = 1):
+def _flash_bwd_call(q, k, v, do, lse, delta, *, causal: bool, sc: float,
+                    window: int | None = None, rep: int = 1):
+    """The backward kernel on one row, or on one pair of spans of a longer
+    one (``lse`` and ``delta`` are then the WHOLE row's, cut to the query
+    span): float32 (dq, dk, dv), dk and dv a QUERY head."""
     bh, s, d = q.shape
     bq = bk = _block(s)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, s)
-    _gauge_tiles("bwd", s, bq, window, causal)
-
     # q, k and their gradients at the key width ``d``; v, do and dv at the
     # value width (latent attention: 192 beside 128; equal elsewhere)
     dv = v.shape[-1]
@@ -502,27 +571,122 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
     # opened here, inside the custom_vjp's backward function, so that the
     # scope survives shard_map and remat
     with jax.named_scope("ds.flash_bwd"):
-        dq, dk, dv_ = call(q, k, v, do, lse, delta)
+        return call(q, k, v, do, lse, delta)
+
+
+def _row_delta(o, do):
+    """``rowsum(do * o)`` as the kernels read it, [BH, 1, S] float32."""
+    bh, s, _ = o.shape
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1).reshape(bh, 1, s)
+
+
+def _key_heads(dq, dk, dv, rep: int, like):
+    """The kernel's float32 gradients as the rule returns them: dk and dv
+    summed over a key head's ``rep`` query heads (consecutive query heads
+    share one), each in its operand's dtype."""
     if rep > 1:
-        # per-q-head dk/dv -> per-kv-head (consecutive q heads share kv)
-        dk = dk.reshape(bh // rep, rep, s, d).sum(axis=1)
-        dv_ = dv_.reshape(bh // rep, rep, s, dv).sum(axis=1)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv_.astype(v.dtype)
+        bh, s, _ = dk.shape
+        dk = dk.reshape(bh // rep, rep, s, -1).sum(axis=1)
+        dv = dv.reshape(bh // rep, rep, s, -1).sum(axis=1)
+    return tuple(g.astype(x.dtype) for g, x in zip((dq, dk, dv), like))
+
+
+def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float,
+               window: int | None = None, rep: int = 1):
+    delta = _row_delta(o, do)
+    _gauge_tiles("bwd", q.shape[1], _block(q.shape[1]), window, causal)
+    dq, dk, dv = _flash_bwd_call(q, k, v, do, lse, delta, causal=causal,
+                                 sc=sc, window=window, rep=rep)
+    return _key_heads(dq, dk, dv, rep, (q, k, v))
+
+
+# ------------------------------------------------- past the residency cap
+def _span_pairs(n: int, causal: bool):
+    """(query span, key span, is the pair on the diagonal) of a row cut in
+    ``n`` spans: under a causal mask a query span meets the key spans at or
+    under it, the diagonal pairs masked, the others whole."""
+    return [(a, b, causal and a == b) for a in range(n)
+            for b in (range(a + 1) if causal else range(n))]
+
+
+def _spans_of(x, span: int, axis: int = 1) -> list:
+    """``x`` cut in its spans along ``axis``: XLA's copies, which the
+    kernels' whole-array operands need, so part of what spans cost."""
+    with jax.named_scope("ds.flash_merge"):
+        return [jax.lax.slice_in_dim(x, i, i + span, axis=axis)
+                for i in range(0, x.shape[axis], span)]
+
+
+def _spans_fwd(q, k, v, *, causal: bool, sc: float, rep: int, span: int):
+    """`_flash_fwd` of a row too long to hold (`_resident_max_seq`), as
+    ``s // span`` equal spans: the SAME kernel on every pair of a query
+    span and a key span the mask leaves anything of, and a query span's
+    partial outputs merged by their log-sum-exp (scope ``ds.flash_merge``:
+    everything here that is no kernel). Returns the row's (o, lse)."""
+    s, n = q.shape[1], q.shape[1] // span
+    _gauge_tiles("fwd", s, _block(span), None, causal, span=span)
+    qs, ks, vs = (_spans_of(x, span) for x in (q, k, v))
+    met = [[] for _ in range(n)]
+    for a, b, diagonal in _span_pairs(n, causal):
+        met[a].append(_flash_fwd(qs[a], ks[b], vs[b], causal=diagonal,
+                                 sc=sc, rep=rep, part=True))
+    with jax.named_scope("ds.flash_merge"):
+        os_, lses = [], []
+        for parts in met:
+            o, lse = parts[0]       # a span that met one key span is done
+            if len(parts) > 1:
+                lse = functools.reduce(jnp.logaddexp, [l for _, l in parts])
+                # [BH, 1, span] statistics against [BH, span, dv] outputs
+                o = sum(o * jnp.exp(l - lse).transpose(0, 2, 1)
+                        for o, l in parts)
+            os_.append(o.astype(q.dtype))
+            lses.append(lse)
+        return jnp.concatenate(os_, axis=1), jnp.concatenate(lses, axis=2)
+
+
+def _spans_bwd(q, k, v, o, lse, do, *, causal: bool, sc: float, rep: int,
+               span: int):
+    """`_flash_bwd` by the same pairs: the kernel of a pair reads the
+    query span's cut of the WHOLE row's ``lse`` and ``delta``, so its
+    probabilities are the row's and ``dq`` adds up over a query span's key
+    spans, ``dk`` and ``dv`` over a key span's query spans."""
+    s, n = q.shape[1], q.shape[1] // span
+    _gauge_tiles("bwd", s, _block(span), None, causal, span=span)
+    with jax.named_scope("ds.flash_merge"):
+        delta = _row_delta(o, do)
+    qs, ks, vs, dos = (_spans_of(x, span) for x in (q, k, v, do))
+    lses, deltas = (_spans_of(x, span, axis=2) for x in (lse, delta))
+    dqs, dks, dvs = ([[] for _ in range(n)] for _ in range(3))
+    for a, b, diagonal in _span_pairs(n, causal):
+        dq, dk, dv = _flash_bwd_call(
+            qs[a], ks[b], vs[b], dos[a], lses[a], deltas[a],
+            causal=diagonal, sc=sc, rep=rep)
+        dqs[a].append(dq), dks[b].append(dk), dvs[b].append(dv)
+    with jax.named_scope("ds.flash_merge"):
+        whole = lambda spans: jnp.concatenate(  # noqa: E731
+            [sum(parts) for parts in spans], axis=1)
+        return _key_heads(whole(dqs), whole(dks), whole(dvs), rep, (q, k, v))
 
 
 # ---------------------------------------------------------------- public
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, window, rep):
-    sc = 1.0 / np.sqrt(q.shape[-1])
-    o, _ = _flash_fwd(q, k, v, causal=causal, sc=sc, window=window,
-                      rep=rep)
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, rep, span=None):
+    """``span``: None, or the length of the equal spans a windowless row
+    too long for the kernels is run in (`segments`)."""
+    return _row_fwd(q, k, v, causal, window, rep, span)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, window, rep):
+def _row_fwd(q, k, v, causal, window, rep, span):
     sc = 1.0 / np.sqrt(q.shape[-1])
-    o, lse = _flash_fwd(q, k, v, causal=causal, sc=sc, window=window,
-                        rep=rep)
+    if span is None:
+        return _flash_fwd(q, k, v, causal=causal, sc=sc, window=window,
+                          rep=rep)
+    return _spans_fwd(q, k, v, causal=causal, sc=sc, rep=rep, span=span)
+
+
+def _flash_fwd_rule(q, k, v, causal, window, rep, span=None):
+    o, lse = _row_fwd(q, k, v, causal, window, rep, span)
     # O(S) bytes that cost O(S^2) work to make again: a rematted layer
     # keeps them, and its backward reruns the projections for q, k, v but
     # not this kernel
@@ -530,11 +694,14 @@ def _flash_fwd_rule(q, k, v, causal, window, rep):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, window, rep, res, do):
+def _flash_bwd_rule(causal, window, rep, span, res, do):
     q, k, v, o, lse = res
     sc = 1.0 / np.sqrt(q.shape[-1])
-    return _flash_bwd(q, k, v, o, lse, do, causal=causal, sc=sc,
-                      window=window, rep=rep)
+    if span is None:
+        return _flash_bwd(q, k, v, o, lse, do, causal=causal, sc=sc,
+                          window=window, rep=rep)
+    return _spans_bwd(q, k, v, o, lse, do, causal=causal, sc=sc, rep=rep,
+                      span=span)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -553,16 +720,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
     restricts each query to its last `window` positions (Mistral sliding
     window; kernel skips blocks fully outside the band).
 
-    Dispatches to the in-repo one-pass kernel (see module docstring); for
-    sequences past the VMEM residency cap it falls back to the stock
-    two-pass jax.experimental kernel on TPU (full-causal only — a window
-    there falls back to the exact masked form).
+    Dispatches to the in-repo one-pass kernels (see module docstring). A
+    sequence past the VMEM residency cap runs through the same kernels in
+    equal spans (gauge ``ds_flash_segments``; any key and value width,
+    GQA, ``rotary``, causal or not). A WINDOW past the cap falls back to
+    the exact masked form (O(S^2) memory) with a warning: no configuration
+    has one.
 
     ``rotary`` (``ops.layers.RotaryTables`` with their ``wide`` pair; the
     function says ``applies_rotary``): q and k come UNROTATED and the
     rotation rides the relayout into the kernels' [B x H, S, D], one pass
     over each (``ops/pallas/rope.py``; ``ops.layers.rotary_attention`` is
-    who hands the tables over). The fallbacks rotate by
+    who hands the tables over). The exact fallback rotates by
     ``ops.layers.rotate`` first.
     """
     b, s, hq, d = q.shape
@@ -576,65 +745,35 @@ def flash_attention(q, k, v, *, causal: bool = True,
     rep = hq // hkv
     unaligned = (s > 128 and s % 128 != 0) or (
         s < 128 and jax.default_backend() == "tpu")
-    stock = (jax.default_backend() == "tpu"
-             and s > _resident_max_seq(max(d, dv)))
-    if rotary is not None and (unaligned or stock):
-        from ..layers import rotate
-        q, k = rotate(q, k, rotary)
-        rotary = None
-    if unaligned:
+    n = 1 if unaligned else segments(s, d, dv, q.dtype.itemsize)
+    # a window past the cap is the one call left to the exact form: no
+    # configuration has one (every window layer of the cells is 8192 or
+    # 16384 long), and a band across spans is its own sweep
+    exact = unaligned or (n > 1 and window is not None)
+    if exact:
+        from ..layers import dot_product_attention, rotate, window_bias
+        from ...utils.logging import warning_once
         # the blocked kernels require 128-aligned sequence lengths: an
         # unaligned tail would be silently dropped by the grid floor
         # division, and sub-128 blocks fail Mosaic's lane-width lowering
         # on real hardware (interpret mode accepts them, so CPU tests
         # still exercise the kernel at tiny shapes) — use the exact
         # (unfused) path instead
-        from ..layers import dot_product_attention, window_bias
-        from ...utils.logging import warning_once
         warning_once(
             f"flash attention falling back to the exact unfused form "
-            f"(O(S^2) memory) at seq {s}: the kernel needs a sequence "
-            f"length that is a multiple of 128")
+            f"(O(S^2) memory) at seq {s}: "
+            + ("the kernel needs a sequence length that is a multiple of "
+               "128" if unaligned else
+               f"sliding windows are only fused up to seq "
+               f"{_resident_max_seq(d, dv, q.dtype.itemsize)} at a key of "
+               f"{d} and a value of {dv}"))
+        if rotary is not None:
+            q, k = rotate(q, k, rotary)
         bias = window_bias(s, window) if window is not None else None
         return dot_product_attention(q, k, v, causal=causal, bias=bias)
+    _gauge_segments(s, d, n)
     from jax.ad_checkpoint import checkpoint_name
     bhsd = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    if stock:
-        if rep > 1:
-            # fallback paths take per-q-head kv (dot_product_attention
-            # repeats internally; the stock kernel needs equal heads)
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        if d % 8 != 0 or window is not None or dv != d:
-            # the stock kernel needs 8-aligned head dims and supports no
-            # window, and the resident kernel's VMEM budget is sized for
-            # s <= _resident_max_seq(d) — use the exact masked form
-            from ..layers import dot_product_attention, window_bias
-            from ...utils.logging import warning_once
-            warning_once(
-                f"flash attention falling back to the exact masked form "
-                f"(O(S^2) memory) at seq {s}: "
-                + ("sliding windows are only fused up to seq "
-                   f"{_resident_max_seq(d)} at head_dim {d}"
-                   if window is not None
-                   else f"head_dim {d} is not 8-aligned" if d % 8
-                   else f"the stock kernel has one width for key and "
-                        f"value, not {d} and {dv}"))
-            bias = window_bias(s, window) if window is not None else None
-            return dot_product_attention(q, k, v, causal=causal,
-                                         bias=bias)
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes, flash_attention as tpu_flash)
-        blk = _block(s)
-        bs_ = BlockSizes(
-            block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-            block_q_major_dkv=blk, block_k_major_dkv=blk,
-            block_k_dkv=blk, block_q_dkv=blk,
-            block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
-        o = tpu_flash(bhsd(q), bhsd(k), bhsd(v), causal=causal,
-                      sm_scale=1.0 / np.sqrt(d), block_sizes=bs_)
-        return checkpoint_name(
-            o.transpose(0, 2, 1, 3).astype(q.dtype), "attn_out")
     # GQA-native: k/v stay per-kv-head ([B*Hkv, S, D]); the kernels index
     # kv rows at q_head_idx // rep, so repeated k/v are never
     # materialized — and the custom-VJP residuals hold the UNREPEATED k/v
@@ -647,7 +786,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 for x in (q, k))
     else:
         q, k = to_bh(q), to_bh(k)
-    o = _flash(q, k, to_bh(v), causal, window, rep)
+    o = _flash(q, k, to_bh(v), causal, window, rep,
+               None if n == 1 else s // n)
     return checkpoint_name(
         o.reshape(b, hq, s, dv).transpose(0, 2, 1, 3), "attn_out")
 

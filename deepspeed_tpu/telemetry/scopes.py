@@ -269,10 +269,30 @@ GATE_SCOPES = (
     #                    logits), its sigmoid and the multiply of a head's
     #                    channels in front of wo
 )
+# what a flash call opens beside ds.flash_fwd / ds.flash_bwd of DEVICE_SCOPES
+# where a (batch x head) row is longer than its kernels hold and runs in
+# equal spans (ops/pallas/flash_attention.py segments: 32768 keys of 192 in
+# models/deepseek_v3.py's published context; gauge ds_flash_segments). A
+# DeepSeek-V3 stack's step (plain pre-norm, latent attention of
+# models/stack.py LatentAttention in every layer) otherwise carries ds.attn
+# and ds.mlp of DEVICE_SCOPES, ds.rope of WINDOW_SCOPES and the routed
+# layers' four and their kernels' of KIND_SCOPES (ds.moe_shared among
+# them); ``tests/test_deepseek_v3_engine.py`` holds the step to them, and
+# ``tests/test_flash_spans.py`` a row in spans to this one
+SPAN_SCOPES = (
+    "ds.flash_merge",  # ops/pallas/flash_attention.py _spans_fwd,
+    #                    _spans_bwd: everything outside the two kernels
+    #                    that a row in spans costs: a query span's partial
+    #                    outputs merged by their log-sum-exp, the row's
+    #                    delta, dq summed over key spans and dk, dv over
+    #                    query spans, the concatenations back to the row
+    #                    (the backward rule opens it itself)
+)
 # every list above: what a metric file may name
 KNOWN_SCOPES = frozenset(
     DEVICE_SCOPES + KIND_SCOPES + SSM_SCOPES + MIXER_SCOPES + WINDOW_SCOPES
-    + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES + GATE_SCOPES)
+    + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES + GATE_SCOPES
+    + SPAN_SCOPES)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

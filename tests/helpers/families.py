@@ -290,6 +290,30 @@ FAMILIES = {
                         mlp_layer_types=["dense", "sparse"]),
         # the leading layer, the scanned window layers, the full tail
         rematted=3),
+    "deepseek_v3": Family(
+        # three layers that hold both kinds (a leading dense layer, two
+        # routed ones under the scan), every one rotated latent attention
+        models.DeepseekV3, cell=dict(moe_held_experts=16, num_layers=3),
+        config="kanana-2-30b-ep8-zero3-1chip", arch="deepseek_v3",
+        # Xing4.0's rule for the leaves they share (a larger table under
+        # sharper scores, larger outputs and experts, a bias that moves the
+        # selection past the mask's margin), the direct query in wq_b's
+        # place, every norm weight drawn
+        boost={"tokens": 5.0, "wq": 3.0, "w_kva": 4.0, "w_kvb": 3.0,
+               "wo": 8.0, "w_gate": 6.0, "w_up": 6.0, "w_down": 8.0,
+               "router_bias": 10.0},
+        special=dict.fromkeys(("ln1_scale", "ln2_scale", "scale",
+                               "kv_norm"), _norm),
+        held=16,
+        # 8 x 128 tokens x top-6 of 128 experts: 48 a held expert if even
+        engine=dict(calls=2, per_expert=(30, 70), steps=4,
+                    behind="one_device", bias=("period", (2, 128)),
+                    bias_tol=(1e-5, 2e-2), cell="train-mla-s32k-1chip"),
+        scopes=frozenset(set(S.DEVICE_SCOPES) | _MOE
+                         | {"ds.rope", "ds.moe_shared"}),
+        two_layers=dict(num_layers=2, first_k_dense_replace=1),
+        # the leading dense layer and the scanned routed ones: two traced
+        rematted=2),
 }
 # the rows whose step is rematted with a kept residual in it, a delta-rule
 # scan, a short convolution: what the cross-family cases are parametrised by

@@ -50,11 +50,12 @@ def held_counters(reg, row, steps):
     return value
 
 
-def cell_metrics_read_the_step(family, paths):
-    """The cell's own metric files read only scopes the step carries."""
+def cell_metrics_read_the_step(family, paths, but=()):
+    """The cell's own metric files read only scopes the step carries;
+    ``but`` names those whose scope only the cell's own size opens."""
     cell = json.loads((BENCH / "cells" / (
         FAMILIES[family].engine["cell"] + ".json")).read_text())
-    for name in cell["per_layer"]:
+    for name in set(cell["per_layer"]) - set(but):
         args = json.loads((BENCH / "layer_metrics" / f"{name}.json"
                            ).read_text())["reducer"]["args"]
         for key in ("pattern", "scope"):

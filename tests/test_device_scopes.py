@@ -551,10 +551,14 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     # the rotation, either form (ISSUE 62)
     ("ds.rope", "ops/layers.py", "rotate"),
     ("ds.rope", "ops/pallas/rope.py", "_call"),
+    # a row in spans (ISSUE 64), and the latent attention's own rotation
+    ("ds.flash_merge", "ops/pallas/flash_attention.py", "_spans_fwd"),
+    ("ds.flash_merge", "ops/pallas/flash_attention.py", "_spans_bwd"),
+    ("ds.rope", "models/stack.py", "_mla"),
 ])
 def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
                                                           function):
-    """``MHC_SCOPES`` and ``GATE_SCOPES`` name, a scope, the file and the
+    """``MHC_SCOPES``, ``GATE_SCOPES`` and ``SPAN_SCOPES`` name, a scope, the file and the
     function that opens it: the function's source holds the scope's name
     as a literal, and every list of the registry is in ``KNOWN_SCOPES``
     (what a metric file may name: ``tests/test_benchmark_contract.py``)."""
@@ -563,7 +567,8 @@ def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
 
     import deepspeed_tpu
     assert scope in (scopes.MHC_SCOPES + scopes.GATE_SCOPES
-                     + scopes.WINDOW_SCOPES) and scope in scopes.KNOWN_SCOPES
+                     + scopes.WINDOW_SCOPES + scopes.SPAN_SCOPES
+                     ) and scope in scopes.KNOWN_SCOPES
     source = (pathlib.Path(deepspeed_tpu.__file__).parent / file).read_text()
     body = next(ast.get_source_segment(source, node)
                 for node in ast.walk(ast.parse(source))

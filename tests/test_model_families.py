@@ -349,6 +349,13 @@ PARENT_COUNTS = {
                      5151266850816),
     "cell/laguna-s-2.1-ep32-zero3-1chip": (
         653577216, 363383808, 2415689856.0, 5270980608),
+    # PR 64's own, pinned when the family came (ISSUE 64's 511,857,152:
+    # layers 1 to 4, 16 of 128 experts held, an eighth of the vocabulary)
+    "deepseek_v3/tiny": (3403616, 405344, 2545056.0, 2849856),
+    "deepseek_v3/kanana-2-30b-a3b": (30670815104, 3614408576, 68430298368.0,
+                                     116747205888),
+    "cell/kanana-2-30b-ep8-zero3-1chip": (
+        511857152, 224023040, 5173791744.0, 9200200704),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -419,6 +426,8 @@ def test_counts_are_the_parents(case):
     ("xing4_0", dict(qk_norm_init=2.0)),
     ("kimi_linear", dict(hc_mult=4)),
     ("laguna", dict(hc_mult=4)),
+    ("deepseek_v3", dict(hc_mult=4)),
+    ("deepseek_v3", dict(mla_use_nope=True)),
     ("mellum", dict(num_attention_heads_per_layer=[4, 4, 4, 4])),
     ("granite_hybrid", dict(conv_L_cache=3)),
     ("kimi_linear", dict(qk_norm_init=2.0)),

@@ -116,6 +116,19 @@ from helpers.families import program
 # and ``models/lfm2_moe.py`` through ``rotary_attention`` -> ``rotate``,
 # ``models/transformer.py`` ``_qkv(rotate=True)`` as it was), and
 # ``sharded_flash_attention`` maps the same body with no tables to pass.
+# PR 64 added ``deepseek_v3`` (a leading dense layer and a routed one, 16 of
+# 128 experts held beside two shared ones as one SwiGLU, both under rotated
+# direct-query latent attention; taken on its own tree, the first that has
+# the family), moved the latent attention of ``kimi_linear`` and ``xing4_0``
+# into ``models/stack.py`` ``LatentAttention`` (the same equations in the
+# same order a family: Kimi's unrotated key is cut from the latent where it
+# was, Xing4.0's inside ``ds.rope``; the weights are drawn in the order they
+# were) and split the flash kernels' backward into the call and what stands
+# round it, for a row past the residency cap to run in spans
+# (``ops/pallas/flash_attention.py`` ``_spans_fwd`` / ``_spans_bwd``): the
+# twelve rows before it stand, every tiny row is held whole and lowers to
+# the text it lowered to, and
+# ``test_the_flash_loops_are_the_parents_program`` holds the loops.
 _PINS = {
     "kimi_linear": (
         "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
@@ -150,6 +163,9 @@ _PINS = {
     "laguna": (
         "abcd8641dd6b81d5f21e8bafa1c029566029a5e428a09bd7693ea7c8d8045522",
         31325.334374967497),
+    "deepseek_v3": (
+        "e83a0d3840b7b97f860ebdec4507c86c98da6c89c56941d15c140952a7acb541",
+        4508.550148079469),
 }
 # the rows that are not a family's two-layer cut under the family's name: (family, cut of its
 # layers, further switches)

@@ -896,6 +896,91 @@ def test_flash_kernels_compile_for_v5e_at_the_laguna_cells_shapes(
                               hlo)) == 1, kernel
 
 
+@pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128),
+                                  (256, 256)])
+def test_the_flash_kernels_compile_for_v5e_at_their_residency_cap(
+        monkeypatch, d, dv):
+    """ISSUE 64: ``_resident_max_seq`` admits nothing that does not
+    compile. The backward (what holds most of a row: q, do and the float32
+    dq slab at PADDED lanes, two buffers each) and the forward at the
+    longest row of whole 512-row blocks at or under the cap, two (batch x
+    head) rows, bf16, for one described v5e chip; and the row the rule
+    before it admitted at a head of 64 (65536: s x d <= 32768 x 128,
+    unpadded) is refused by Mosaic, so it is two spans now."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    import importlib
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s, t=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, t, sharding=one)
+    sc = d ** -0.5
+
+    def compiles(s):
+        jax.jit(lambda q, k, v: fa._flash_fwd(
+            q, k, v, causal=True, sc=sc)).lower(
+                sd((2, s, d)), sd((2, s, d)), sd((2, s, dv))).compile()
+        jax.jit(lambda q, k, v, o, lse, do: fa._flash_bwd(
+            q, k, v, o, lse, do, causal=True, sc=sc)).lower(
+                sd((2, s, d)), sd((2, s, d)), sd((2, s, dv)),
+                sd((2, s, dv)), sd((2, 1, s), jnp.float32),
+                sd((2, s, dv))).compile()
+
+    cap = fa._resident_max_seq(d, dv)
+    assert fa.segments(cap // 512 * 512, d, dv) == 1
+    compiles(cap // 512 * 512)
+    if d == 64:
+        assert fa.segments(65536, d, dv) == 2
+        with pytest.raises(Exception, match="vmem"):
+            compiles(65536)
+
+
+def test_a_latent_layer_at_32768_compiles_for_v5e_in_two_spans(monkeypatch):
+    """The new cell's attention call (ISSUE 64): 32768 tokens of 32 heads
+    at a key of 192 and a value of 128, a row longer than the backward
+    holds (27594), under the cells' whole-layer checkpoint, forward and the
+    three gradients, compiled by Mosaic for one described v5e chip: three
+    calls of each kernel (the pairs of two causal spans), no other kernel,
+    and the merge under its scope."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    import importlib
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.bfloat16, sharding=one)
+    s, heads = 32768, 32
+    assert fa.segments(s, 192, 128) == 2
+    from deepspeed_tpu.models.transformer import _remat_policy
+    layer = jax.checkpoint(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+        policy=_remat_policy("nothing_saveable"))
+    hlo = jax.jit(jax.grad(
+        lambda q, k, v: 0.5 * jnp.sum(
+            layer(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    ).lower(sd((1, s, heads, 192)), sd((1, s, heads, 192)),
+            sd((1, s, heads, 128))).compile().as_text()
+    for kernel in ("ds_flash_fwd", "ds_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\w]* = .*custom-call",
+                              hlo)) == 3, kernel
+    assert "ds.flash_merge" in hlo
+    assert len(re.findall(r"= .*custom-call\(.*tpu_custom_call", hlo)) == 6
+
+
 @pytest.mark.parametrize("s,heads,kv,d,rot", [
     (8192, 72, 8, 128, 128),    # the Laguna cell's window layers
     (8192, 48, 8, 128, 64),     # its full layer: half the head rotated
