@@ -162,9 +162,10 @@ class DeepseekV3(LatentAttention, RoutedStackOfKinds):
                 f"{c.held_experts} experts held of the router's "
                 f"{c.num_experts}")
         super().__init__(c)
-        self._rope = L.rotary_embedding(c.max_seq_len, c.qk_rope_head_dim,
-                                        c.rope_theta)
         self._rope_pairs = c.rope_interleave
+        self._rope = L.latent_rotary_tables(
+            *L.rotary_embedding(c.max_seq_len, c.qk_rope_head_dim,
+                                c.rope_theta), pairs=self._rope_pairs)
 
     def optimizer_frozen(self) -> str:
         """Leaves the optimizer leaves alone (the engine zeroes their

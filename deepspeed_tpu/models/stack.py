@@ -474,11 +474,14 @@ class LatentAttention:
     (float32 inside the norm, one rounding: ``q`` is linear in it). In
     training the latent is expanded and the layer runs as H-head attention
     with a key of ``nope + rope`` and a value of ``v_head_dim`` through the
-    flash kernels. The family sets ``_rope`` (``(cos, sin)`` of
-    ``ops.layers.rotary_embedding`` over ``qk_rope_head_dim``) and
-    ``_rope_pairs`` in its ``__init__``."""
+    flash kernels: ``ops.layers.latent_attention`` builds their operands,
+    or hands the three projections as they lie to an attention that takes
+    them (``ops/pallas/rope.py`` ``latent_to_heads``: one pass). The family
+    sets ``_rope`` (``ops.layers.latent_rotary_tables`` of
+    ``rotary_embedding`` over ``qk_rope_head_dim``) and ``_rope_pairs`` in
+    its ``__init__``."""
 
-    _rope = None            # (cos, sin) over qk_rope_head_dim, or None
+    _rope = None            # RotaryTables over qk_rope_head_dim, or None
     _rope_pairs = False     # the rotated channels come as interleaved pairs
 
     def _init_mla(self, w, ones, resid_std: float) -> dict:
@@ -512,11 +515,6 @@ class LatentAttention:
                         c.norm_eps)
         return cq @ p["wq_b"]
 
-    def _rotated(self, x):
-        cos, sin = self._rope
-        return L.apply_rotary(L.pairs_to_halves(x) if self._rope_pairs else x,
-                              cos, sin)
-
     def _mla(self, p, h, attn_fn):
         c = self.config
         b, s, _ = h.shape
@@ -527,17 +525,13 @@ class LatentAttention:
         q = self._mla_query(p, h).reshape(b, s, nh, nope + rope)
         kva = h @ p["w_kva"]
         latent = L.rms_norm(kva[..., :r], p["kv_norm"], c.norm_eps)
+        # (the shared key's columns are cut where and how each family's
+        # program has cut them: tests/test_step_pins.py)
         if not rotated:
             k_pe = kva[..., r:]
         kv = (latent @ p["w_kvb"]).reshape(b, s, nh, nope + dv)
         if rotated:
-            with jax.named_scope("ds.rope"):
-                k_pe = self._rotated(kva[..., None, r:])
-                q = jnp.concatenate(
-                    [q[..., :nope], self._rotated(q[..., nope:])], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_pe if rotated else k_pe[:, :, None, :],
-                              (b, s, nh, rope))], axis=-1)
-        a = attn_fn(q, k, kv[..., nope:], causal=True)
+            k_pe = kva[..., None, r:]
+        a = L.latent_attention(attn_fn, q, kv, k_pe, self._rope,
+                               pairs=self._rope_pairs, causal=True)
         return a.reshape(b, s, nh * dv) @ p["wo"]

@@ -251,9 +251,9 @@ class Xing4(LatentAttention, RoutedStackOfKinds):
                 f"{c.held_experts} experts held of the router's "
                 f"{c.num_experts}")
         super().__init__(c)
-        self._rope = L.rotary_embedding(
+        self._rope = L.latent_rotary_tables(*L.rotary_embedding(
             c.max_seq_len, c.qk_rope_head_dim, c.rope_theta,
-            scaling=c.rope_table_scaling())
+            scaling=c.rope_table_scaling()))
 
     def optimizer_frozen(self) -> str:
         """Leaves the optimizer leaves alone (the engine zeroes their

@@ -291,6 +291,28 @@ def rotary_tables(cos, sin, head_dim: int) -> RotaryTables:
         jnp.concatenate([-sin, sin, jnp.zeros(rest, sin.dtype)], axis=-1)))
 
 
+def latent_rotary_tables(cos, sin, pairs: bool = False) -> RotaryTables:
+    """``cos``, ``sin`` [S_max, rope // 2] of latent attention's rotated
+    channels with the tables ``ops/pallas/rope.py`` ``latent_to_heads``
+    multiplies by as ``wide``: a 128-lane tile's row, two heads' 64
+    channels side by side, each ``[cos | cos]``, ``[-sin | sin]`` for
+    halves or, ``pairs``, ``[c0, c0, c1, c1, ..]``, ``[-s0, s0, -s1, s1,
+    ..]`` for a checkpoint's interleaved pairs where they lie. None where
+    ``rope`` is not 64 (every published latent attention's) or the tables
+    are not float32."""
+    rope = 2 * cos.shape[-1]
+    if rope != 64 or cos.dtype != jnp.float32:
+        return RotaryTables(cos, sin, None)
+    if pairs:
+        cos_w = jnp.repeat(cos, 2, axis=-1)
+        sin_w = jnp.stack([-sin, sin], axis=-1).reshape(cos_w.shape)
+    else:
+        cos_w = jnp.concatenate([cos, cos], axis=-1)
+        sin_w = jnp.concatenate([-sin, sin], axis=-1)
+    return RotaryTables(cos, sin, (jnp.tile(cos_w, (1, 2)),
+                                   jnp.tile(sin_w, (1, 2))))
+
+
 def rotate(q, k, tables: RotaryTables, positions=None):
     """q and k through :func:`apply_rotary` under scope ``ds.rope``: the
     XLA form of the rotation (gauge ``ds_rope_calls{form="xla"}``)."""
@@ -301,7 +323,7 @@ def rotate(q, k, tables: RotaryTables, positions=None):
         () if positions is None else (positions,))
     with jax.named_scope("ds.rope"):
         for x in (q, k):
-            count_rotation("xla", x, tables.rotated)
+            count_rotation("xla", x.shape[-1], tables.rotated)
         return apply_rotary(q, *how), apply_rotary(k, *how)
 
 
@@ -330,6 +352,75 @@ def rotary_attention(attn, q, k, v, tables: RotaryTables, *,
         return attn(q, k, v, rotary=tables, **kw)
     q, k = rotate(q, k, tables, positions)
     return attn(q, k, v, **kw)
+
+
+# the rotation ``ops/pallas/rope.py`` ``latent_to_heads`` computes: it
+# stands in for these two only while the module holds them, so whoever plants
+# another rotation in their place (a test, the benchmark's controls) gets
+# the XLA form, which calls what was planted
+_ROTATION_OF_THE_KERNELS = (apply_rotary, pairs_to_halves)
+
+
+def hands_latent(attn, q, kv, k_pe, tables: RotaryTables | None,
+                 positions=None) -> bool:
+    """Whether ``attn`` is handed latent attention's projections as they
+    lie (:func:`latent_attention`'s arguments): it says that it takes them
+    (``attn.latent``, as ``ops/pallas/flash_attention.py``
+    ``flash_attention`` and its per-shard wrapper do), ``nope`` and the
+    value are whole 128-lane tiles, ``rope`` is 64 (two heads' fill one)
+    with its wide tables built (or nothing is rotated), the heads are even,
+    the rows are whole blocks of the flash kernels and their positions are
+    their indices."""
+    rope = k_pe.shape[-1]
+    nope, dv = q.shape[-1] - rope, kv.shape[-1] - (q.shape[-1] - rope)
+    return (getattr(attn, "latent", None) is not None and positions is None
+            and nope > 0 and nope % 128 == 0 and dv > 0 and dv % 128 == 0
+            and rope == 64 and q.shape[2] % 2 == 0 and q.shape[1] % 128 == 0
+            and (tables is None or tables.wide is not None)
+            and (apply_rotary, pairs_to_halves) == _ROTATION_OF_THE_KERNELS)
+
+
+def latent_attention(attn, q, kv, k_pe, tables: RotaryTables | None = None,
+                     *, pairs: bool = False, positions=None, **kw):
+    """``attn`` over latent attention's expanded heads: q [B, S, H,
+    nope + rope], kv [B, S, H, nope + dv] (a head is ``[k_nope | v]``) and
+    the ONE key k_pe [B, S, rope] all heads share (or as one head,
+    [B, S, 1, rope]); ``tables`` over the ``rope`` channels
+    (:func:`latent_rotary_tables`; None: nothing is rotated), ``pairs``:
+    they come as a checkpoint's interleaved pairs.
+    An attention that takes the three as they lie (:func:`hands_latent`)
+    rotates and builds its operands itself, one pass; every other one gets
+
+        q_h = [q_nope_h | rot(q_pe_h)],  k_h = [k_nope_h | rot(k_pe)],  v_h
+
+    built here, XLA's form: ``rot`` is :func:`apply_rotary` behind
+    :func:`pairs_to_halves` under scope ``ds.rope``. One arithmetic either
+    way; the kernels leave interleaved pairs where they lie, q and k
+    permuted alike, which their product does not see."""
+    from .pallas.rope import count_rotation
+    b, s, heads, w = q.shape
+    rope = k_pe.shape[-1]
+    nope = w - rope
+    if hands_latent(attn, q, kv, k_pe, tables, positions):
+        return attn.latent(q, kv, k_pe.reshape(b, s, rope), rotary=tables,
+                           pairs=pairs, **kw)
+    count_rotation("xla", w, 0 if tables is None else rope, 2)  # q and k
+    one_head = lambda x: x if x.ndim == 4 else x[:, :, None, :]  # noqa: E731
+    if tables is not None:
+        how = (tables.cos, tables.sin) + (
+            () if positions is None else (positions,))
+
+        def rotated(x):
+            return apply_rotary(pairs_to_halves(x) if pairs else x, *how)
+
+        with jax.named_scope("ds.rope"):
+            k_pe = rotated(one_head(k_pe))
+            q = jnp.concatenate([q[..., :nope], rotated(q[..., nope:])],
+                                axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(one_head(k_pe), (b, s, heads, rope))], axis=-1)
+    return attn(q, k, kv[..., nope:], **kw)
 
 
 def window_bias(seq_len: int, window: int):

@@ -771,28 +771,56 @@ def flash_attention(q, k, v, *, causal: bool = True,
             q, k = rotate(q, k, rotary)
         bias = window_bias(s, window) if window is not None else None
         return dot_product_attention(q, k, v, causal=causal, bias=bias)
-    _gauge_segments(s, d, n)
-    from jax.ad_checkpoint import checkpoint_name
-    bhsd = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     # GQA-native: k/v stay per-kv-head ([B*Hkv, S, D]); the kernels index
     # kv rows at q_head_idx // rep, so repeated k/v are never
     # materialized — and the custom-VJP residuals hold the UNREPEATED k/v
     # (of the five, a rematted layer stores `o` and `lse`,
     # `_flash_fwd_rule`, and makes q, k, v again)
-    to_bh = lambda x: bhsd(x).reshape(-1, s, x.shape[-1])  # noqa: E731
+    to_bh = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        -1, s, x.shape[-1])
     if rotary is not None:
         from .rope import rotate_to_heads
         q, k = (rotate_to_heads(x, rotary.wide, rotary.rotated)
                 for x in (q, k))
     else:
         q, k = to_bh(q), to_bh(k)
-    o = _flash(q, k, to_bh(v), causal, window, rep,
+    return _attend(q, k, to_bh(v), b, causal, window, n)
+
+
+def _attend(q, k, v, b: int, causal: bool, window, n: int):
+    """The kernels over operands laid out for them, q [B x Hq, S, D], k
+    [B x Hkv, S, D], v [B x Hkv, S, Dv], in ``n`` spans a row; the output
+    as the model takes it, [B, S, Hq, Dv]."""
+    from jax.ad_checkpoint import checkpoint_name
+    (bh, s, d), dv = q.shape, v.shape[-1]
+    _gauge_segments(s, d, n)
+    o = _flash(q, k, v, causal, window, bh // k.shape[0],
                None if n == 1 else s // n)
     return checkpoint_name(
-        o.reshape(b, hq, s, dv).transpose(0, 2, 1, 3), "attn_out")
+        o.reshape(b, bh // b, s, dv).transpose(0, 2, 1, 3), "attn_out")
+
+
+def latent_flash_attention(q, kv, k_pe, *, rotary=None, pairs: bool = False,
+                           causal: bool = True, **_kw):
+    """:func:`flash_attention` of latent attention's projections as they
+    lie (``ops.layers.latent_attention``'s arguments, which see; its
+    ``hands_latent`` is the rule for what comes here): q [B, S, H,
+    nope + rope], kv [B, S, H, nope + dv], the shared k_pe [B, S, rope]
+    and the ``rope`` channels' tables. The rotation, the key's
+    concatenation and the relayout to the kernels' [B x H, S, .] are ONE
+    pass over the three (``ops/pallas/rope.py`` ``latent_to_heads``). It is
+    ``flash_attention.latent``: how the function says that it takes them."""
+    from .rope import latent_to_heads
+    b, s, _, d = q.shape
+    n = segments(s, d, kv.shape[-1] + k_pe.shape[-1] - d, q.dtype.itemsize)
+    q, k, v = latent_to_heads(q, kv, k_pe,
+                              None if rotary is None else rotary.wide,
+                              pairs=pairs)
+    return _attend(q, k, v, b, causal, None, n)
 
 
 flash_attention.applies_rotary = True
+flash_attention.latent = latent_flash_attention
 
 
 def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
@@ -813,24 +841,30 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
     whose layers differ in it does (models/mellum.py). It carries
     ``applies_rotary = True`` too: ``rotary`` tables go to every shard
     whole, and the rotation's kernels run inside the manual region with
-    the flash kernels."""
+    the flash kernels; and ``latent``, which takes latent attention's
+    projections as they lie (:func:`latent_flash_attention`)."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import active_mesh
     from ...utils.jax_compat import shard_map
 
-    def attn(q, k, v, *, causal: bool = True, window=window, rotary=None,
-             **_kw):
+    def axes(batch: int, *heads):
+        """(the mesh to map over, its free axes, the axes the batch is
+        split over, the axis the heads are)."""
         use, free = active_mesh(mesh)
         b_ax = tuple(a for a in batch_axes
                      if a in free and use.shape[a] > 1)
-        if q.shape[0] % math.prod(use.shape[a] for a in b_ax):
+        if batch % math.prod(use.shape[a] for a in b_ax):
             b_ax = ()       # uneven batch: replicate, still exact
         tp = use.shape.get(tp_axis, 1)
         h_ax = (tp_axis if tp_axis in free and tp > 1
-                and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
-                else None)
-        spec = P(b_ax or None, None, h_ax, None)
+                and not any(h % tp for h in heads) else None)
+        return use, free, b_ax or None, h_ax
+
+    def attn(q, k, v, *, causal: bool = True, window=window, rotary=None,
+             **_kw):
+        use, free, b_ax, h_ax = axes(q.shape[0], q.shape[2], k.shape[2])
+        spec = P(b_ax, None, h_ax, None)
 
         def per_shard(q, k, v, rotary):
             return flash_attention(q, k, v, causal=causal, window=window,
@@ -841,5 +875,24 @@ def sharded_flash_attention(mesh, batch_axes, *, tp_axis: str = "tp",
             in_specs=(spec, spec, spec, jax.tree.map(lambda _: P(), rotary)),
             out_specs=spec, check_vma=False)(q, k, v, rotary)
 
+    def latent(q, kv, k_pe, *, rotary=None, pairs: bool = False,
+               causal: bool = True, **_kw):
+        """:func:`latent_flash_attention` per shard: the shared key goes to
+        every shard of the heads whole, and its cotangent is summed over
+        them. A shard takes whole PAIRS of heads (the kernels' unit)."""
+        use, free, b_ax, h_ax = axes(q.shape[0], q.shape[2] // 2)
+        spec = P(b_ax, None, h_ax, None)
+
+        def per_shard(q, kv, k_pe, rotary):
+            return latent_flash_attention(q, kv, k_pe, rotary=rotary,
+                                          pairs=pairs, causal=causal)
+
+        return shard_map(
+            per_shard, mesh=use, axis_names=set(free),
+            in_specs=(spec, spec, P(b_ax), jax.tree.map(lambda _: P(),
+                                                        rotary)),
+            out_specs=spec, check_vma=False)(q, kv, k_pe, rotary)
+
     attn.applies_window = attn.applies_rotary = True
+    attn.latent = latent
     return attn

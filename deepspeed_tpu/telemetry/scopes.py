@@ -107,7 +107,9 @@ KIND_SCOPES = (
     "ds.kda_fwd",      # ops/pallas/kda.py _forward: ds_kda_fwd, either form
     "ds.kda_bwd",      # ops/pallas/kda.py _backward: ds_kda_bwd
     "ds.mla",          # models/kimi_linear.py _mix: latent attention
-    #                    (ds.flash_fwd / ds.flash_bwd inside it)
+    #                    (ds.flash_fwd / ds.flash_bwd inside it, and at the
+    #                    cell's widths ds.rope of WINDOW_SCOPES: the kernels
+    #                    that lay q, k, v out)
     "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router
     "ds.moe_experts",  # moe/sharded_moe.py held_experts_ffn, fwd and bwd:
     #                    the sorts, the gathers and the three kernels
@@ -177,7 +179,14 @@ WINDOW_SCOPES = (
     #                    pass; the backward rule opens the scope itself;
     #                    under no ds.flash_* scope). models/transformer.py
     #                    _qkv rotates under ds.attn alone, so a Mistral or
-    #                    Ouro step carries ds.rope only where the kernels run
+    #                    Ouro step carries ds.rope only where the kernels run.
+    #                    In a latent-attention layer (ops/layers.py
+    #                    latent_attention) at whole lane tiles of nope and
+    #                    value it holds ds_latent_fwd / ds_latent_bwd alone
+    #                    (ops/pallas/rope.py _latent_call: the rotation, the
+    #                    key's concatenation and q's, k's, v's relayout in
+    #                    one pass, also where nothing is rotated: Kimi's
+    #                    ds.mla/ds.rope); else apply_rotary's fusions
 )
 # what a looped stack opens beside ds.attn and ds.mlp (models/ouro.py);
 # ``tests/test_ouro.py`` holds this list equal to what that model's step

@@ -50,6 +50,21 @@ def held_counters(reg, row, steps):
     return value
 
 
+def latent_rotations_built(reg, config, rotated: bool = True):
+    """What gauge ``ds_rope_calls`` says of a latent-attention step at the
+    tiny preset's widths (no lane tile among them): q and k built in XLA's
+    form at a head of ``nope + rope`` and never by the kernels
+    (``tests/test_rope_kernels.py`` runs the families at the cells'
+    128 + 64 / 128, where it reads ``form=kernel, head=192``)."""
+    gauge = reg.get("ds_rope_calls")
+    assert gauge is not None
+    built = {tuple(labels[k] for k in ("form", "head", "rotated"))
+             for labels in gauge.label_sets()}
+    rope = config.qk_rope_head_dim
+    assert built == {("xla", str(config.qk_nope_head_dim + rope),
+                      str(rope if rotated else 0))}, built
+
+
 def cell_metrics_read_the_step(family, paths, but=()):
     """The cell's own metric files read only scopes the step carries;
     ``but`` names those whose scope only the cell's own size opens."""

@@ -10,15 +10,18 @@ run shows results and counts, never a time."""
 import re
 
 from helpers.families import _telemetry_isolation  # noqa: F401
-from helpers.family_suite import cases, cell_metrics_read_the_step
+from helpers.family_suite import (cases, cell_metrics_read_the_step,
+                                  latent_rotations_built)
 
 
 def _behind(engine, batch, reg):
-    """The flash call's gauge says one span: the tiny rows are held whole."""
+    """The flash call's gauge says one span: the tiny rows are held whole;
+    the rotation's says XLA's form: a nope of 16 is no lane tile."""
     gauge = reg.get("ds_flash_segments")
     assert gauge is not None
     assert {gauge.value(**labels) for labels in gauge.label_sets()} == {1.0}
     assert reg.get("ds_moe_dropped_rows_total").value() == 0
+    latent_rotations_built(reg, engine.module.config)
 
 
 def _scoped(hlo, paths, work):

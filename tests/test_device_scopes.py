@@ -551,10 +551,13 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     # the rotation, either form (ISSUE 62)
     ("ds.rope", "ops/layers.py", "rotate"),
     ("ds.rope", "ops/pallas/rope.py", "_call"),
-    # a row in spans (ISSUE 64), and the latent attention's own rotation
+    # a row in spans (ISSUE 64), and the latent attention's own rotation:
+    # since ISSUE 65 XLA's form and the one pass that stands in for it
+    # (``models/stack.py`` ``_mla`` opened it until then)
     ("ds.flash_merge", "ops/pallas/flash_attention.py", "_spans_fwd"),
     ("ds.flash_merge", "ops/pallas/flash_attention.py", "_spans_bwd"),
-    ("ds.rope", "models/stack.py", "_mla"),
+    ("ds.rope", "ops/layers.py", "latent_attention"),
+    ("ds.rope", "ops/pallas/rope.py", "_latent_call"),
 ])
 def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
                                                           function):

@@ -14,7 +14,7 @@ from deepspeed_tpu.telemetry import scopes
 from helpers import hlo_text  # noqa: E402  (tests/helpers)
 from helpers.families import _telemetry_isolation  # noqa: F401
 from helpers.families import program
-from helpers.family_suite import cases
+from helpers.family_suite import cases, latent_rotations_built
 
 
 def _trained(engine):
@@ -36,6 +36,8 @@ def _behind(traced, batch, reg):
     low = reg.gauge("ds_moe_held_tokens_step_min").value()
     high = reg.gauge("ds_moe_held_tokens_step_max").value()
     assert 16 < low <= rows / (calls * 8) <= high < 48
+    # the latent layer's q and k at the tiny widths: XLA's form, unrotated
+    latent_rotations_built(reg, traced.module.config, rotated=False)
 
 
 def _scoped(hlo, paths, work):

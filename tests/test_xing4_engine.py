@@ -11,7 +11,8 @@ import re
 import numpy as np
 
 from helpers.families import _telemetry_isolation  # noqa: F401
-from helpers.family_suite import cases, cell_metrics_read_the_step
+from helpers.family_suite import (cases, cell_metrics_read_the_step,
+                                  latent_rotations_built)
 
 
 def _trained(engine):
@@ -32,6 +33,7 @@ def _behind(engine, batch, reg):
     assert 0 < residual < 1e-2
     assert reg.get("ds_moe_held_calls_total").value() == 2 * 2
     assert reg.get("ds_moe_dropped_rows_total").value() == 0
+    latent_rotations_built(reg, model.config)       # no lane tile: XLA's
     # the gauge keeps the largest of any step
     for value in (0.5, 0.1):
         type(model).record_step_metrics(reg, {

@@ -1059,3 +1059,87 @@ def test_rope_kernels_compile_for_v5e_alone_and_per_shard(
     for kernel in ("ds_rope_fwd", "ds_rope_bwd"):
         assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
     assert "all-to-all" not in hlo
+
+
+@pytest.mark.parametrize("s,form", [
+    (32768, "pairs"),       # the Kanana cell: interleaved pairs, two spans
+    (8192, "halves"),       # the Xing4.0 cell
+    (16384, "none"),        # the Kimi cell's latent layer: nothing rotated
+])
+def test_latent_kernels_compile_for_v5e_alone_and_per_shard(
+        monkeypatch, s, form):
+    """Latent attention's operands as ONE pass (ISSUE 65) at the three
+    cells' shapes, 32 heads of 128 + 64 over a value of 128, compiled by
+    Mosaic for one described v5e chip inside a rematted flash layer: the
+    tiles of two heads cut by selects and 32-bit lane rolls of bf16 rows,
+    the 64 lanes at a head's tail loaded and stored alone, the shared key's
+    float32 scratch. The step holds ``ds_latent_fwd`` twice (remat's rerun
+    makes the flash kernels' q, k, v again) and ``ds_latent_bwd`` once,
+    neither asks for more VMEM than any XLA op gets, and no copy or
+    transpose of q's, k's or v's size is left beside them. Then per shard
+    on ``v5e:2x2`` with the batch over ``fsdp``."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.models.transformer import _remat_policy
+    from deepspeed_tpu.ops import layers as L
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        flash_attention, sharded_flash_attention)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    heads, nope, rope, dv = 32, 128, 64, 128
+    tables = None if form == "none" else L.latent_rotary_tables(
+        *L.rotary_embedding(s, rope), pairs=form == "pairs")
+
+    def step(attn, b, *shardings):
+        """The gradients of a rematted layer's projections-to-attention
+        part: the three reach the kernels as matmuls' outputs."""
+        def layer(x, wq, wkv, wpe):
+            q = (x @ wq).reshape(b, s, heads, nope + rope)
+            kv = (x @ wkv).reshape(b, s, heads, nope + dv)
+            assert L.hands_latent(attn, q, kv, x @ wpe, tables)
+            return L.latent_attention(attn, q, kv, x @ wpe, tables,
+                                      pairs=form == "pairs", causal=True)
+        layer = jax.checkpoint(layer,
+                               policy=_remat_policy("nothing_saveable"))
+        shapes = ((b, s, 256), (256, heads * (nope + rope)),
+                  (256, heads * (nope + dv)), (256, rope))
+        return jax.jit(jax.grad(
+            lambda *a: 0.5 * jnp.sum(layer(*a).astype(f32) ** 2),
+            argnums=(0, 1, 2, 3))).lower(*(
+                jax.ShapeDtypeStruct(dims, bf, sharding=sh)
+                for dims, sh in zip(shapes, shardings)))
+
+    one = SingleDeviceSharding(topo.devices[0])
+    hlo = step(flash_attention, 1, one, one, one, one).compile().as_text()
+    for kernel, n in (("ds_latent_fwd", 2), ("ds_latent_bwd", 1)):
+        found = [line for line in hlo.splitlines() if re.search(
+            rf"%{kernel}[.\w]* = .*custom-call", line)]
+        assert len(found) == n, (kernel, len(found))
+        for line in found:
+            asked = re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                               line)[0]
+            assert all(int(m) <= 16 * 2 ** 20 for m in re.findall(
+                r'"size":"(\d+)"', asked)), asked
+    # q, k and v are laid out for the flash kernels by the pass alone
+    for width in (nope + rope, dv):
+        assert not re.search(
+            rf"= bf16\[(1,)?{heads},{s},{width}\]\S* (copy|transpose)\(",
+            hlo), width
+        assert not re.search(
+            rf"= bf16\[1,{s},{heads},{width}\]\S* (copy|transpose)\(", hlo)
+
+    mt = MeshTopology(TopologyConfig(fsdp=4), devices=topo.devices)
+    rows = NamedSharding(mt.mesh, P(mt.batch_axes()))
+    whole = NamedSharding(mt.mesh, P())
+    sharded = sharded_flash_attention(mt.mesh, mt.batch_axes())
+    hlo = step(sharded, 4, rows, whole, whole, whole).compile().as_text()
+    for kernel in ("ds_latent_fwd", "ds_latent_bwd"):
+        assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
+    assert "all-to-all" not in hlo
