@@ -15,6 +15,7 @@ from .llama import Llama, llama_config  # noqa: F401
 from .mellum import Mellum, mellum_config  # noqa: F401
 from .mistral import Mistral, mistral_config  # noqa: F401
 from .mixtral import Mixtral, mixtral_config  # noqa: F401
+from .nemotron_h import NemotronH, nemotron_h_config  # noqa: F401
 from .opt import OPT, opt_config  # noqa: F401
 from .ouro import Ouro, ouro_config  # noqa: F401
 from .phi import Phi, Phi3, phi3_config, phi_config  # noqa: F401

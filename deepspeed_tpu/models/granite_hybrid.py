@@ -20,6 +20,8 @@ hidden 2048, ``layer_types`` nine ``mamba`` to one ``attention``::
     y = chunk_ssd(x, dt, A, B, C) + D x
     out = (rmsnorm(y * silu(z)) * w) W_out       the norm over all H P
 
+(``models/stack.py`` ``Mamba2``, which ``models/nemotron_h.py`` shares.)
+
 **attention**: q ``num_heads``, k and v ``num_kv_heads`` heads, no bias, no
 positions (``position_embedding_type`` "nope"), causal over the whole
 sequence at the softmax scale ``attention_multiplier`` (1/64 at a head of
@@ -38,12 +40,12 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
 from .base import mean_context, register_model
-from .stack import StackConfig, StackOfKinds
+from .stack import (Mamba2, MambaShape, StackConfig, StackOfKinds,
+                    grouped_query_attention)
 from .transformer import _dense_init
 
 
@@ -74,16 +76,21 @@ class GraniteHybridConfig(StackConfig):
     def layer_kinds(self) -> list[str]:
         return list(self.layer_types)
 
+    def mamba_shape(self) -> MambaShape:
+        """The mixer from the published keys: one gated norm over all H P
+        channels, whatever the groups of B and C."""
+        return MambaShape(
+            heads=self.mamba_n_heads, head_dim=self.mamba_d_head,
+            state=self.mamba_d_state, groups=self.mamba_n_groups,
+            conv=self.mamba_d_conv, conv_bias=self.mamba_conv_bias,
+            chunk=self.mamba_chunk_size)
+
     def _layer_params(self, kind) -> int:
         """As ``GraniteHybrid._init_layer`` builds a layer: the mixer, a
         SwiGLU and two norms."""
-        d, h = self.hidden_size, self.mamba_n_heads
+        d = self.hidden_size
         if kind == "mamba":
-            inner = h * self.mamba_d_head
-            conv = inner + 2 * self.mamba_n_groups * self.mamba_d_state
-            mixer = (d * (inner + conv + h)          # z | xBC | dt
-                     + (self.mamba_d_conv + self.mamba_conv_bias) * conv
-                     + 3 * h + inner + inner * d)    # A, D, dt_bias, norm
+            mixer = self.mamba_shape().params(d)
         else:
             mixer = 2 * d * self.head_dim * (self.num_heads
                                              + self.num_kv_heads)
@@ -91,11 +98,9 @@ class GraniteHybridConfig(StackConfig):
 
     def _layer_mixer_flops(self, kind, seq_len, causal) -> float:
         """An attention layer multiplies a key and a value of head_dim a
-        visible pair; a Mamba head writes and reads its [P, N] state once
-        a token (2 products of 2 P N FLOPs); x3 training."""
+        visible pair; a Mamba head's state: ``MambaShape.state_flops``."""
         if kind == "mamba":
-            return 12 * self.mamba_n_heads * self.mamba_d_head \
-                * self.mamba_d_state
+            return self.mamba_shape().state_flops
         return 12 * self.num_heads * self.head_dim * mean_context(
             seq_len, causal)
 
@@ -135,7 +140,7 @@ def granite_hybrid_config(size: str = "4.0-h-micro",
 
 
 @register_model("granite_hybrid")
-class GraniteHybrid(StackOfKinds):
+class GraniteHybrid(Mamba2, StackOfKinds):
     def __init__(self, config: GraniteHybridConfig | None = None,
                  size: str | None = None, **overrides):
         if config is not None and (size is not None or overrides):
@@ -178,31 +183,7 @@ class GraniteHybrid(StackOfKinds):
              "mlp": {"w_gate": w((d, f)), "w_up": w((d, f)),
                      "w_down": w((f, d), resid_std)}}
         if kind == "mamba":
-            h = c.mamba_n_heads
-            inner = h * c.mamba_d_head
-            conv = inner + 2 * c.mamba_n_groups * c.mamba_d_state
-            # decay init (Mamba-2's): A = U(1, 16) a head; dt =
-            # exp(U(log 1e-3, log 0.1)), dt_bias its inverse softplus
-            step = jnp.exp(jax.random.uniform(
-                next(ks), (*lead_shape, h), minval=np.log(1e-3),
-                maxval=np.log(0.1)))
-            p["mamba"] = {
-                "w_in": w((d, inner + conv + h)),
-                "conv_w": jax.random.uniform(
-                    next(ks), (*lead_shape, c.mamba_d_conv, conv),
-                    minval=-0.5, maxval=0.5).astype(dt),
-                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
-                "A_log": jnp.log(jax.random.uniform(
-                    next(ks), (*lead_shape, h), minval=1.0,
-                    maxval=16.0)).astype(dt),
-                "D": ones((h,)),
-                "norm": ones((inner,)),
-                "w_out": w((inner, d), resid_std),
-            }
-            if c.mamba_conv_bias:
-                p["mamba"]["conv_b"] = jax.random.uniform(
-                    next(ks), (*lead_shape, conv), minval=-0.5,
-                    maxval=0.5).astype(dt)
+            p["mamba"] = self._init_mamba(w, ones, ks, lead_shape, resid_std)
         else:
             nh, nkv, hd = c.num_heads, c.num_kv_heads, c.head_dim
             p["attn"] = {"wq": w((d, nh * hd)), "wk": w((d, nkv * hd)),
@@ -235,54 +216,14 @@ class GraniteHybrid(StackOfKinds):
             params, x * (1.0 / self.config.logits_scaling), targets)
 
     # ---------------- the mixers ----------------
-    def _mamba(self, p, h, ssd_fn, conv_fn):
-        c = self.config
-        b, s, _ = h.shape
-        nh, hd, g, n = (c.mamba_n_heads, c.mamba_d_head, c.mamba_n_groups,
-                        c.mamba_d_state)
-        inner = nh * hd
-        f32 = jnp.float32
-        proj = h @ p["w_in"]
-        z = proj[..., :inner]
-        # the convolution and the SiLU: one pass (scope ds.conv)
-        xbc = conv_fn(proj[..., inner:2 * inner + 2 * g * n], p["conv_w"],
-                      p.get("conv_b"))
-        with jax.named_scope("ds.mix_pre"):
-            dt = jax.nn.softplus(
-                proj[..., 2 * inner + 2 * g * n:].astype(f32)
-                + p["dt_bias"].astype(f32))
-            x = xbc[..., :inner].reshape(b, s, nh, hd)
-            B = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
-            C = xbc[..., inner + g * n:].reshape(b, s, g, n)
-        y = ssd_fn(x, dt, -jnp.exp(p["A_log"].astype(f32)), B, C,
-                   chunk=min(c.mamba_chunk_size, s))
-        with jax.named_scope("ds.mix_post"):
-            y = y.astype(f32) + x.astype(f32) * p["D"].astype(f32)[:, None]
-            y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(f32))
-            y = L.rms_norm(y, p["norm"], c.norm_eps).astype(h.dtype)
-        return y @ p["w_out"]
-
     def _attention(self, p, h, attn_fn):
         c = self.config
-        b, s, _ = h.shape
-        nh, nkv, hd = c.num_heads, c.num_kv_heads, c.head_dim
-        q = (h @ p["wq"]).reshape(b, s, nh, hd)
-        k = (h @ p["wk"]).reshape(b, s, nkv, hd)
-        v = (h @ p["wv"]).reshape(b, s, nkv, hd)
-        if c.attention_multiplier is not None:
+        scale = c.attention_multiplier
+        return grouped_query_attention(
+            p, h, attn_fn, heads=c.num_heads, kv_heads=c.num_kv_heads,
+            head_dim=c.head_dim,
             # attn_fn applies head_dim ** -0.5
-            q = q * (c.attention_multiplier * hd ** 0.5)
-        return attn_fn(q, k, v, causal=True).reshape(b, s, nh * hd) @ p["wo"]
-
-    def _mixers(self, attn_fn, act_sharding):
-        """(attention, scan, short convolution): the scan's and the
-        convolution's kernels run per shard of ``act_sharding`` where the
-        mesh has more than one device."""
-        from ..ops.ssd import chunk_ssd, sharded_chunk_ssd
-        if act_sharding is None:
-            return attn_fn, chunk_ssd, L.short_conv
-        return (attn_fn, sharded_chunk_ssd(act_sharding),
-                L.sharded_short_conv(act_sharding))
+            q_scale=None if scale is None else scale * c.head_dim ** 0.5)
 
     def _residual(self, x, y):
         """x + residual_multiplier * y, in float32 and rounded once (0.22

@@ -32,15 +32,22 @@ rules.
 ``models/xing4.py`` (a low-rank normed query, rotated by halves under
 YaRN) and ``models/deepseek_v3.py`` (a direct query, rotated by
 interleaved pairs): its weights, their count and the mixer.
+
+**Mamba-2** (``Mamba2``): the state-space mixer of
+``models/granite_hybrid.py`` (one group, the gated norm over all
+channels) and ``models/nemotron_h.py`` (heads in groups, the gated norm a
+group): its weights, their count and the mixer.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import layers as L
@@ -535,3 +542,148 @@ class LatentAttention:
         a = L.latent_attention(attn_fn, q, kv, k_pe, self._rope,
                                pairs=self._rope_pairs, causal=True)
         return a.reshape(b, s, nh * dv) @ p["wo"]
+
+
+class MambaShape(typing.NamedTuple):
+    """A Mamba-2 mixer as a family's config publishes it (its
+    ``mamba_shape()``): H heads of P, state N, G groups of heads sharing B and
+    C, the convolution's taps and whether it has a bias, the scan's chunk,
+    and over how many groups of channels the gated norm takes its mean."""
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int
+    conv_bias: bool
+    chunk: int
+    norm_groups: int = 1
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """The channels the convolution runs over: x | B | C."""
+        return self.inner + 2 * self.groups * self.state
+
+    def params(self, hidden: int) -> int:
+        """The mixer's parameters as ``Mamba2._init_mamba`` builds them:
+        the input projection z | xBC | dt, the taps (and bias), A, D,
+        dt_bias a head, the gated norm's weight, the output projection."""
+        return (hidden * (self.inner + self.conv_width + self.heads)
+                + (self.conv + self.conv_bias) * self.conv_width
+                + 3 * self.heads + self.inner + self.inner * hidden)
+
+    @property
+    def state_flops(self) -> float:
+        """Beside the 6 N: a head writes and reads its [P, N] state once a
+        token (2 products of 2 P N FLOPs); x3 training."""
+        return 12 * self.heads * self.head_dim * self.state
+
+
+def grouped_query_attention(p, h, attn_fn, *, heads: int, kv_heads: int,
+                            head_dim: int, q_scale: float | None = None):
+    """A grouped-query attention mixer without positions on the normed
+    ``h`` [B, S, C]: ``wq`` at ``heads``, ``wk`` and ``wv`` at ``kv_heads``
+    heads of ``head_dim``, causal over the whole sequence, ``wo``.
+    ``attn_fn`` applies ``head_dim ** -0.5``; ``q_scale`` rides on q where
+    a family's softmax scale is another."""
+    b, s, _ = h.shape
+    q = (h @ p["wq"]).reshape(b, s, heads, head_dim)
+    k = (h @ p["wk"]).reshape(b, s, kv_heads, head_dim)
+    v = (h @ p["wv"]).reshape(b, s, kv_heads, head_dim)
+    if q_scale is not None:
+        q = q * q_scale
+    return attn_fn(q, k, v, causal=True).reshape(
+        b, s, heads * head_dim) @ p["wo"]
+
+
+class Mamba2:
+    """The Mamba-2 mixer as the stacks that have it share it (a mixin in
+    front of ``StackOfKinds``; ``ops/ssd.py`` is the scan)::
+
+        [z | xBC | dt] = h W_in          widths H P | H P + 2 G N | H
+        [x | B | C] = silu(conv(xBC) + b_conv)       causal, depthwise:
+                                          ops.layers.short_conv, one pass
+        dt = softplus(dt + dt_bias);   A = -exp(A_log)           per head
+        y = chunk_ssd(x, dt, A, B, C) + D x      head h reads group
+                                                  h // (H / G)'s B and C
+        out = (rmsnorm(y * silu(z)) * w) W_out   the mean of squares over
+                                  each of ``norm_groups`` runs of channels
+
+    The family's config says the shape from its own published keys
+    (``mamba_shape()``); ``_mixers`` hands a layer the attention, the scan
+    and the convolution."""
+
+    def _mixers(self, attn_fn, act_sharding):
+        """(attention, scan, short convolution): the scan's and the
+        convolution's kernels run per shard of ``act_sharding`` where the
+        mesh has more than one device."""
+        from ..ops.ssd import chunk_ssd, sharded_chunk_ssd
+        if act_sharding is None:
+            return attn_fn, chunk_ssd, L.short_conv
+        return (attn_fn, sharded_chunk_ssd(act_sharding),
+                L.sharded_short_conv(act_sharding))
+
+    def _init_mamba(self, w, ones, ks, lead_shape, resid_std: float) -> dict:
+        """The mixer's weights, drawn in this order: ``w(shape, scale=)``
+        and ``ones(shape)`` are the family's ``_init_layer``'s, ``ks`` its
+        keys. Decay init (Mamba-2's): A = U(1, 16) a head; dt =
+        exp(U(log 1e-3, log 0.1)), dt_bias its inverse softplus."""
+        m = self.config.mamba_shape()
+        d, dt = self.config.hidden_size, self.config.param_dtype
+        h = m.heads
+        step = jnp.exp(jax.random.uniform(
+            next(ks), (*lead_shape, h), minval=np.log(1e-3),
+            maxval=np.log(0.1)))
+        p = {
+            "w_in": w((d, m.inner + m.conv_width + h)),
+            "conv_w": jax.random.uniform(
+                next(ks), (*lead_shape, m.conv, m.conv_width),
+                minval=-0.5, maxval=0.5).astype(dt),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            "A_log": jnp.log(jax.random.uniform(
+                next(ks), (*lead_shape, h), minval=1.0,
+                maxval=16.0)).astype(dt),
+            "D": ones((h,)),
+            "norm": ones((m.inner,)),
+            "w_out": w((m.inner, d), resid_std),
+        }
+        if m.conv_bias:
+            p["conv_b"] = jax.random.uniform(
+                next(ks), (*lead_shape, m.conv_width), minval=-0.5,
+                maxval=0.5).astype(dt)
+        return p
+
+    def _mamba(self, p, h, ssd_fn, conv_fn):
+        m = self.config.mamba_shape()
+        eps = self.config.norm_eps
+        b, s, _ = h.shape
+        nh, hd, g, n, inner = m.heads, m.head_dim, m.groups, m.state, m.inner
+        f32 = jnp.float32
+        proj = h @ p["w_in"]
+        z = proj[..., :inner]
+        # the convolution and the SiLU: one pass (scope ds.conv)
+        xbc = conv_fn(proj[..., inner:2 * inner + 2 * g * n], p["conv_w"],
+                      p.get("conv_b"))
+        with jax.named_scope("ds.mix_pre"):
+            dt = jax.nn.softplus(
+                proj[..., 2 * inner + 2 * g * n:].astype(f32)
+                + p["dt_bias"].astype(f32))
+            x = xbc[..., :inner].reshape(b, s, nh, hd)
+            B = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
+            C = xbc[..., inner + g * n:].reshape(b, s, g, n)
+        y = ssd_fn(x, dt, -jnp.exp(p["A_log"].astype(f32)), B, C,
+                   chunk=min(m.chunk, s))
+        with jax.named_scope("ds.mix_post"):
+            y = y.astype(f32) + x.astype(f32) * p["D"].astype(f32)[:, None]
+            y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(f32))
+            if m.norm_groups == 1:
+                y = L.rms_norm(y, p["norm"], eps).astype(h.dtype)
+            else:
+                # a group's mean of squares over its own channels
+                y = L.rms_norm(y.reshape(b, s, m.norm_groups, -1),
+                               p["norm"].reshape(m.norm_groups, -1), eps)
+                y = y.reshape(b, s, inner).astype(h.dtype)
+        return y @ p["w_out"]

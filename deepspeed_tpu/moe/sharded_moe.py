@@ -312,6 +312,16 @@ def _swiglu_rows(xg, w_gate, w_up):
     return gate, up, jax.nn.silu(gate) * up
 
 
+def _ffn_rows(xt, p: dict, body: str):
+    """One always-on expert of ``body`` (``grouped_matmul.BODIES``) on
+    rows ``xt`` [N, D], as XLA's matmuls: the shared expert's form."""
+    if body == "swiglu":
+        h = _swiglu_rows(xt, p["w_gate"], p["w_up"])[2]
+    else:
+        h = jnp.square(jax.nn.relu(xt @ p["w_up"]))
+    return h @ p["w_down"]
+
+
 def _held_tiles(layout, tile: int, m: int):
     """Every row tile the layout can hold (the rows' ``N * k / tile`` and
     one part-empty tile an expert, in whole chunks of ``m`` tiles), laid
@@ -388,32 +398,33 @@ def _held_sweep(idx, weights, first, n_held, block, chunk, shape, body,
                          (jnp.zeros(shape, jnp.float32), carry))
 
 
-def _held_forward(x, idx, weights, experts, first, block, chunk):
+def _held_forward(x, idx, weights, experts, first, block, chunk, body):
     n_held = experts["w_up"].shape[0]
 
-    def body(rows, tokens, valid, scale, tables, tile, done):
+    def one(rows, tokens, valid, scale, tables, tile, done):
         return (grouped_matmul.forward(x[tokens], scale, tables[:3], experts,
-                                       tile), done + jnp.sum(valid))
+                                       tile, body), done + jnp.sum(valid))
 
     out, done = _held_sweep(idx, weights, first, n_held, block, chunk,
-                            x.shape, body, jnp.zeros((), jnp.int32))
+                            x.shape, one, jnp.zeros((), jnp.int32))
     return out.astype(x.dtype), done
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def held_experts_ffn(x, idx, weights, experts, first, block,
-                     router_grad=True, chunk=None):
+                     router_grad=True, chunk=None, body="swiglu"):
     """The part of a routed layer's result that the experts HELD here
     give: ``sum_j weights[n, j] * E_{idx[n, j]}(x_n)`` over the choices
-    ``j`` whose expert lies in ``[first, first + E_h)`` (``experts``:
-    SwiGLU weights ``[E_h, ...]``). Dropless: the rows routed here are
-    sorted by expert, each expert's run padded to row tiles (the largest
+    ``j`` whose expert lies in ``[first, first + E_h)`` (``experts``: the
+    weights ``[E_h, ...]`` of ``body``, one of ``grouped_matmul.BODIES``:
+    a SwiGLU, or the non-gated ``relu(.)^2`` FFN). Dropless: the rows
+    routed here are sorted by expert, each expert's run padded to row tiles (the largest
     multiple of 128 up to 256 that divides ``block``, the unit the callers
     count the padding by), and swept ``chunk`` rows at a time
     (``held_chunk`` of the shape; left out, a block an expert held), as
     many chunks as this batch's routing needs: the chunk's rows are
     gathered in expert order once, a grouped-matmul kernel
-    (``ops/pallas/grouped_matmul.py``) runs the three matmuls tile by tile
+    (``ops/pallas/grouped_matmul.py``) runs the body's matmuls tile by tile
     with an expert's weights held in VMEM across its tiles, and the
     weighted rows are added to their tokens once; no capacity, no
     [N, E, C] table. The work is that of the rows routed here, however
@@ -427,30 +438,32 @@ def held_experts_ffn(x, idx, weights, experts, first, block,
     Returns (out [N, D], rows computed): fewer than the rows routed to
     the held experts only if rows were dropped."""
     with jax.named_scope("ds.moe_experts"):
-        return _held_forward(x, idx, weights, experts, first, block, chunk)
+        return _held_forward(x, idx, weights, experts, first, block, chunk,
+                             body)
 
 
 def _held_fwd_rule(x, idx, weights, experts, first, block, router_grad,
-                   chunk):
+                   chunk, body):
     with jax.named_scope("ds.moe_experts"):
-        out = _held_forward(x, idx, weights, experts, first, block, chunk)
+        out = _held_forward(x, idx, weights, experts, first, block, chunk,
+                            body)
     return out, (x, idx, weights, experts)
 
 
-def _held_bwd_rule(first, block, router_grad, chunk, res, cts):
+def _held_bwd_rule(first, block, router_grad, chunk, body, res, cts):
     x, idx, weights, experts = res
     dout = cts[0]
     n, k = idx.shape
     f32 = jnp.float32
-    names = ("w_gate", "w_up", "w_down")
+    names = grouped_matmul.BODIES[body]
     # opened here: a custom_vjp's backward function is traced outside the
     # scope its forward was called under
     with jax.named_scope("ds.moe_experts"):
-        def body(rows, tokens, valid, scale, tables, tile, carry):
+        def one(rows, tokens, valid, scale, tables, tile, carry):
             dw, sums = carry
             dxs, dwt, sums = grouped_matmul.backward(
                 x[tokens], dout[tokens], scale, tables, experts, sums, tile,
-                router_grad)
+                router_grad, body)
             if router_grad:     # a row a choice: distinct, nothing summed
                 dw = dw.at[jnp.where(valid, rows, n * k)].add(
                     dwt[:, 0], mode="drop")
@@ -458,7 +471,7 @@ def _held_bwd_rule(first, block, router_grad, chunk, res, cts):
 
         n_held = experts["w_up"].shape[0]
         dx, (dw, sums) = _held_sweep(
-            idx, weights, first, n_held, block, chunk, x.shape, body,
+            idx, weights, first, n_held, block, chunk, x.shape, one,
             (jnp.zeros((n * k,), f32),
              [jnp.zeros(experts[name].shape, f32) for name in names]))
     d_experts = {name: s.astype(experts[name].dtype)
@@ -534,7 +547,8 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
                  renormalise: bool = True, scaling: float = 1.0,
                  block: int | None = None, router: str = "sigmoid",
                  router_grad: bool = True,
-                 shared_gate: jax.Array | None = None):
+                 shared_gate: jax.Array | None = None,
+                 body: str = "swiglu", latent: dict | None = None):
     """A routed expert layer that is told which experts it holds (one
     chip's share under expert parallelism, without its exchange): routes
     every token over ALL ``router_w.shape[-1]`` experts, computes what the
@@ -542,7 +556,13 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
     the tokens routed to them (:func:`held_experts_ffn`) and adds the
     always-on ``shared`` expert where there is one, times
     ``sigmoid(x @ shared_gate)`` (``shared_gate`` [D, 1], float32 logits:
-    Qwen's gated shared expert) where that is given. ``router``:
+    Qwen's gated shared expert) where that is given. ``body`` is the
+    experts' and the shared expert's FFN (``grouped_matmul.BODIES``).
+    With ``latent`` (``{"w_dn": [D, L], "w_up": [L, D]}``: LatentMoE) the
+    router and the shared expert read ``x`` and the routed experts read
+    ``x @ w_dn``, L wide; the held experts' weighted sum goes back through
+    ``w_up``, which has no bias, so the shares of a layer still add up.
+    ``router``:
     ``sigmoid`` (:func:`sigmoid_top_k`, with its selection bias
     ``router_bias``) or ``softmax`` (:func:`softmax_top_k`, no bias); the
     weights are float32 either way, times ``scaling``. A token routed only
@@ -590,13 +610,20 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
         load = jnp.bincount(idx.reshape(-1),
                             length=n_experts).astype(jnp.int32)
     n_held = experts["w_up"].shape[0]
+    rows = xt
+    if latent is not None:
+        with jax.named_scope("ds.moe_latent"):
+            rows = xt @ latent["w_dn"]
     out, done = held_experts_ffn(
-        xt, idx, weights, experts, int(first_expert), int(block),
-        bool(router_grad), held_chunk(b * s, k, n_experts, n_held, block))
+        rows, idx, weights, experts, int(first_expert), int(block),
+        bool(router_grad), held_chunk(b * s, k, n_experts, n_held, block),
+        body)
+    if latent is not None:
+        with jax.named_scope("ds.moe_latent"):
+            out = out @ latent["w_up"]
     if shared is not None:
         with jax.named_scope("ds.moe_shared"):
-            _, _, h = _swiglu_rows(xt, shared["w_gate"], shared["w_up"])
-            y = h @ shared["w_down"]
+            y = _ffn_rows(xt, shared, body)
             if shared_gate is not None:
                 y = (y * jax.nn.sigmoid(jnp.matmul(
                     xt, shared_gate, preferred_element_type=jnp.float32))

@@ -1,6 +1,9 @@
-"""The held experts' SwiGLU as a grouped matmul: a Pallas kernel pair over
+"""The held experts' FFN as a grouped matmul: a Pallas kernel pair over
 rows that lie in EXPERT ORDER (``moe/sharded_moe.py`` ``held_experts_ffn``
-gathers them so, a chunk at a time, and is the one caller).
+gathers them so, a chunk at a time, and is the one caller). The experts'
+``body`` is a static choice of ``BODIES``: ``swiglu`` (three weights:
+``h = silu(x Wg) * (x Wu)``) or ``relu2`` (two, no gate: ``h = relu(x
+Wu)^2``), in one tile walk, one set of tables and carries.
 
 A chunk is ``m`` row tiles (``row_tile`` rows each), each tile inside ONE
 expert's run (a run's last tile part empty, and the chunk's last tiles
@@ -9,22 +12,24 @@ tiles and reads, per tile, four numbers from scalar-prefetched tables
 (``tile_tables``): its expert, how many of its rows are live, the tile
 whose blocks it names, and what to do with the expert's ``dW`` before it.
 Consecutive tiles of one expert name the same weight blocks, so the
-pipeline fetches an expert's ``w_gate``, ``w_up``, ``w_down`` ONCE and they
-stay in VMEM while its tiles pass. A tile with no live row names the
-blocks of the last live tile before it and runs nothing: no fetch, no
-product, no store.
+pipeline fetches an expert's weights (``w_gate``, ``w_up``, ``w_down``, or
+the two of ``relu2``) ONCE and they stay in VMEM while its tiles pass. A
+tile with no live row names the blocks of the last live tile before it
+and runs nothing: no fetch, no product, no store.
 
 ``ds_moe_gmm_fwd`` (``forward``): ``gate`` and ``up`` in float32 from the
-bf16 rows, ``h = silu(gate) * up`` rounded once to the rows' dtype, the
-down projection in float32, times the row's routing weight (0 past an
-expert's count), all in VMEM: ``h`` never goes to HBM, and the rows leave
-in float32, as the caller's add to tokens takes them.
+bf16 rows, ``h = silu(gate) * up`` (``relu2``: ``relu(up)^2``) rounded once
+to the rows' dtype, the down projection in float32, times the row's
+routing weight (0 past an expert's count), all in VMEM: ``h`` never goes
+to HBM, and the rows leave in float32, as the caller's add to tokens takes
+them.
 
 ``ds_moe_gmm_bwd`` (``backward``): the same walk. ``gate`` and ``up`` are
-made again; ``dh = (dy @ w_down^T) * weight`` in float32; the row's
+made again; ``dh = (dy @ w_down^T) * weight`` in float32 (``relu2``:
+``d(x Wu) = 2 relu(x Wu) dh``); the row's
 ``dy . y`` (the routing weight's gradient, only where ``router_grad``) is
 ``sum(dy @ w_down^T * h)``, so ``y`` is not made again; ``dx`` a tile; the
-expert's three ``dW`` are summed in float32 IN their output blocks, which
+expert's ``dW`` are summed in float32 IN their output blocks, which
 stay in VMEM across the expert's tiles and go to HBM once when the expert
 changes. The outputs alias the float32 carries the caller's loop holds: an
 expert the chunk does not reach keeps what it had, and one that began in
@@ -76,6 +81,11 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 _ZERO, _CARRY = 1, 2                # a tile's ``init``: the dW before it
 
+# an expert's body -> its weights' names, the down projection last (the
+# others are [D, F], cut by columns; it is [F, D], cut by rows)
+BODIES = {"swiglu": ("w_gate", "w_up", "w_down"),
+          "relu2": ("w_up", "w_down")}
+
 
 def row_tile(block: int) -> int:
     """Rows a grid step: the largest multiple of 128 up to ``ROW_TILE``
@@ -124,62 +134,73 @@ def _expert_spec(shape, **kw):
 
 
 # ---------------------------------------------------------------- forward
-def _fwd_kernel(e_ref, src_ref, live_ref, xs_ref, scale_ref, wg_ref, wu_ref,
-                wd_ref, y_ref):
+def _fwd_kernel(e_ref, src_ref, live_ref, xs_ref, scale_ref, *refs,
+                body: str):
     del e_ref, src_ref
+    *w_refs, wd_ref, y_ref = refs
 
     @pl.when(live_ref[pl.program_id(0)] > 0)
     def _():
         x = xs_ref[...]
-        gate = _dot(x, wg_ref[0])
-        h = (jax.nn.silu(gate) * _dot(x, wu_ref[0])).astype(x.dtype)
+        if body == "swiglu":
+            wg_ref, wu_ref = w_refs
+            gate = _dot(x, wg_ref[0])
+            h = (jax.nn.silu(gate) * _dot(x, wu_ref[0])).astype(x.dtype)
+        else:
+            wu_ref, = w_refs
+            h = jnp.square(jnp.maximum(_dot(x, wu_ref[0]), 0.0)).astype(
+                x.dtype)
         y_ref[...] = (_dot(h, wd_ref[0]) * scale_ref[...]).astype(
             y_ref.dtype)
 
 
-def forward(xs, scale, tables, experts, tile: int):
+def forward(xs, scale, tables, experts, tile: int, body: str = "swiglu"):
     """``scale * E(xs)`` [C, D] float32 for a chunk's rows
     ``xs`` [C, D] in expert order, ``scale`` [C, 1] float32 (the routing
     weight; 0 where the row is not the expert's), ``tables`` =
-    ``tile_tables``' first three, ``experts`` the held SwiGLU weights
-    ``[E_h, ...]``. The rows of a tile that did not run are not written."""
+    ``tile_tables``' first three, ``experts`` the held weights
+    ``[E_h, ...]`` of ``BODIES[body]``. The rows of a tile that did not run
+    are not written."""
     c, d = xs.shape
-    w = [experts[n] for n in ("w_gate", "w_up", "w_down")]
+    w = [experts[n] for n in BODIES[body]]
     f = w[0].shape[-1]
     call = pl.pallas_call(
-        _fwd_kernel,
+        functools.partial(_fwd_kernel, body=body),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(c // tile,),
             in_specs=[_rows_spec(tile, d), _rows_spec(tile, 1),
-                      _expert_spec((d, f)), _expert_spec((d, f)),
+                      *(_expert_spec((d, f)) for _ in w[:-1]),
                       _expert_spec((f, d))],
             out_specs=_rows_spec(tile, d)),
         out_shape=jax.ShapeDtypeStruct((c, d), jnp.float32),
         compiler_params=_params(2 * _nbytes(*w) // w[0].shape[0], tile),
         cost_estimate=pl.CostEstimate(
-            flops=int(6 * c * d * f), transcendentals=int(c * f),
+            flops=int(2 * len(w) * c * d * f),
+            transcendentals=int(c * f) if body == "swiglu" else 0,
             bytes_accessed=int(3 * _nbytes(xs) + _nbytes(scale, *w))),
         interpret=_interpret(),
         name="ds_moe_gmm_fwd",
     )
     # the scope and the kernel's name are all a device trace shows of this
     # call (telemetry/scopes.py)
-    return _bind(call, "ds.moe_gmm_fwd", ("fwd", tile), *tables, xs, scale,
-                 *w)[0]
+    return _bind(call, "ds.moe_gmm_fwd", ("fwd", tile, body), *tables, xs,
+                 scale, *w)[0]
 
 
 # --------------------------------------------------------------- backward
 def _bwd_kernel(e_ref, src_ref, live_ref, init_ref, xs_ref, dys_ref,
-                scale_ref, wg_ref, wu_ref, wd_ref, cg_ref, cu_ref, cd_ref,
-                dxs_ref, dwt_ref, dg_ref, du_ref, dd_ref, sem, *,
-                router_grad: bool, cols: int):
+                scale_ref, *refs, router_grad: bool, cols: int, body: str):
     del src_ref
+    n = len(BODIES[body])
+    w_refs, c_refs = refs[:n], refs[n:2 * n]
+    dxs_ref, dwt_ref, *d_refs, sem = refs[2 * n:]
+    wd_ref, dd_ref = w_refs[-1], d_refs[-1]
     # the row tiles are the grid's last axis; in front of it, where the
     # expert's columns are cut, the cut (``backward``)
     t = pl.program_id(0 if cols == 1 else 1)
     run = pl.program_id(0) if cols > 1 else 0
     # (carry, block, the carry's axis the cut runs along)
-    sums = ((cg_ref, dg_ref, 2), (cu_ref, du_ref, 2), (cd_ref, dd_ref, 1))
+    sums = tuple(zip(c_refs, d_refs, (2,) * (n - 1) + (1,)))
 
     @pl.when(init_ref[t] == _ZERO)
     def _():
@@ -207,27 +228,40 @@ def _bwd_kernel(e_ref, src_ref, live_ref, init_ref, xs_ref, dys_ref,
     @pl.when(live_ref[t] > 0)
     def _():
         x, dy, scale = xs_ref[...], dys_ref[...], scale_ref[...]
-        gate, up = _dot(x, wg_ref[0]), _dot(x, wu_ref[0])
-        sg = jax.nn.sigmoid(gate)
-        act = gate * sg
-        h = act * up
+        if body == "swiglu":
+            wg_ref, wu_ref = w_refs[:2]
+            dg_ref, du_ref = d_refs[:2]
+            gate, up = _dot(x, wg_ref[0]), _dot(x, wu_ref[0])
+            sg = jax.nn.sigmoid(gate)
+            act = gate * sg
+            h = act * up
+        else:
+            wu_ref, du_ref = w_refs[0], d_refs[0]
+            up = jnp.maximum(_dot(x, wu_ref[0]), 0.0)
+            h = up * up
         dh = _dot(dy, wd_ref[0], _NT)
         if router_grad:
             dwt_ref[...] = jnp.sum(dh * h, axis=1, keepdims=True)
         dh = dh * scale
-        d_up = (dh * act).astype(x.dtype)
-        d_gate = (dh * up * (sg * (1 + gate * (1 - sg)))).astype(x.dtype)
-        dxs_ref[...] = (_dot(d_gate, wg_ref[0], _NT)
-                        + _dot(d_up, wu_ref[0], _NT)).astype(dxs_ref.dtype)
-        dg_ref[0] += _dot(x, d_gate, _TN)
+        if body == "swiglu":
+            d_up = (dh * act).astype(x.dtype)
+            d_gate = (dh * up * (sg * (1 + gate * (1 - sg)))).astype(x.dtype)
+            dxs_ref[...] = (_dot(d_gate, wg_ref[0], _NT)
+                            + _dot(d_up, wu_ref[0], _NT)).astype(
+                                dxs_ref.dtype)
+            dg_ref[0] += _dot(x, d_gate, _TN)
+        else:
+            d_up = (2.0 * up * dh).astype(x.dtype)
+            dxs_ref[...] = _dot(d_up, wu_ref[0], _NT).astype(dxs_ref.dtype)
         du_ref[0] += _dot(x, d_up, _TN)
         dd_ref[0] += _dot((h * scale).astype(x.dtype), dy, _TN)
 
 
-def backward_geometry(d: int, f: int, tile: int, itemsize: int):
-    """How ``backward`` holds ONE expert of ``d`` by ``f`` in VMEM, from
-    the shapes alone: (column runs the expert is cut into, buffers its
-    weights take, bytes resident). An expert's weights and its float32
+def backward_geometry(d: int, f: int, tile: int, itemsize: int,
+                      mats: int = 3):
+    """How ``backward`` holds ONE expert of ``mats`` matrices of ``d`` by
+    ``f`` in VMEM, from the shapes alone: (column runs the expert is cut
+    into, buffers its weights take, bytes resident). An expert's weights and its float32
     ``dW`` blocks take two buffers each; past ``_RESIDENT_MAX`` (hidden
     2048 by an expert of 1536: 113 MiB, and the kernel asked 127.3 of the
     126 it may have) the weights take ONE: the next expert's are fetched
@@ -241,7 +275,8 @@ def backward_geometry(d: int, f: int, tile: int, itemsize: int):
     column's own, so a run walks the chunk's row tiles as the whole expert
     would, and the caller sums the runs' ``dx`` and ``dy . y``."""
     def held(cols):
-        weights, sums = 3 * d * f * itemsize // cols, 3 * d * f * 4 // cols
+        weights = mats * d * f * itemsize // cols
+        sums = mats * d * f * 4 // cols
         resident, buffers = 2 * (weights + sums), 2
         if resident > _RESIDENT_MAX:
             resident, buffers = resident - weights, 1
@@ -255,21 +290,21 @@ def backward_geometry(d: int, f: int, tile: int, itemsize: int):
 
 
 def backward(xs, dys, scale, tables, experts, sums, tile: int,
-             router_grad: bool):
+             router_grad: bool, body: str = "swiglu"):
     """The chunk's part of the backward: (``dxs`` [C, D] float32 (the
     add to tokens takes float32 rows: a kernel's own sums, not rounded on
     the way), ``dwt`` [C, 1] float32: the row's ``dy . E(xs)`` where
-    ``router_grad``, else not written, and the three float32 ``dW`` sums
-    ``[E_h, ...]``: ``sums`` with this chunk's rows added). ``dys`` [C, D]
-    is the result's cotangent by row; the rest as ``forward``, ``tables``
-    all four."""
+    ``router_grad``, else not written, and the float32 ``dW`` sums
+    ``[E_h, ...]`` of ``BODIES[body]``: ``sums`` with this chunk's rows
+    added). ``dys`` [C, D] is the result's cotangent by row; the rest as
+    ``forward``, ``tables`` all four."""
     c, d = xs.shape
-    names = ("w_gate", "w_up", "w_down")
-    w = [experts[n] for n in names]
+    w = [experts[n] for n in BODIES[body]]
+    n = len(w)
     f = w[0].shape[-1]
     carry = pl.BlockSpec(memory_space=pl.ANY)
     cols, buffers, resident = backward_geometry(
-        d, f, tile, jnp.dtype(w[0].dtype).itemsize)
+        d, f, tile, jnp.dtype(w[0].dtype).itemsize, n)
     once = {"pipeline_mode": pl.Buffered(1)} if buffers == 1 else {}
     fc = f // cols
     # index maps take (column run j, row tile t, the tables); the whole
@@ -292,30 +327,35 @@ def backward(xs, dys, scale, tables, experts, sums, tile: int,
             (None, tile, width), lambda j, t, e, src, *_: (j, src[t], 0))
         params = _params(resident, tile, 32 * d, 2)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, router_grad=router_grad, cols=cols),
+        functools.partial(_bwd_kernel, router_grad=router_grad, cols=cols,
+                          body=body),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid,
             in_specs=[rows(d), rows(d), rows(1),
-                      by_columns(**once), by_columns(**once), by_rows(**once),
-                      carry, carry, carry],
-            out_specs=[slab(d), slab(1), by_columns(), by_columns(),
+                      *(by_columns(**once) for _ in w[:-1]), by_rows(**once),
+                      *(carry for _ in w)],
+            out_specs=[slab(d), slab(1), *(by_columns() for _ in w[:-1]),
                        by_rows()],
-            scratch_shapes=[pltpu.SemaphoreType.DMA((3,))]),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((n,))]),
         out_shape=[jax.ShapeDtypeStruct((*lead, c, d), jnp.float32),
                    jax.ShapeDtypeStruct((*lead, c, 1), jnp.float32),
                    *(jax.ShapeDtypeStruct(s.shape, s.dtype) for s in sums)],
-        input_output_aliases={10: 2, 11: 3, 12: 4},
+        # the carries (behind the 4 tables, the 3 row operands and the
+        # weights) alias the dW outputs (behind dxs and dwt)
+        input_output_aliases={7 + n + i: 2 + i for i in range(n)},
         compiler_params=params,
         cost_estimate=pl.CostEstimate(
-            flops=int(16 * c * d * f), transcendentals=int(c * f),
+            # the input matmuls again, then two products a matrix
+            flops=int((6 * n - 2) * c * d * f),
+            transcendentals=int(c * f) if body == "swiglu" else 0,
             bytes_accessed=int(4 * _nbytes(xs) + _nbytes(scale, *w)
                                + 2 * _nbytes(*sums))),
         interpret=_interpret(),
         name="ds_moe_gmm_bwd",
     )
     dxs, dwt, *sums = _bind(call, "ds.moe_gmm_bwd",
-                            ("bwd", tile, router_grad), *tables, xs, dys,
-                            scale, *w, *sums)
+                            ("bwd", tile, router_grad, body), *tables, xs,
+                            dys, scale, *w, *sums)
     if cols > 1:
         dxs, dwt = dxs.sum(axis=0), dwt.sum(axis=0)
     return dxs, dwt, sums

@@ -125,8 +125,9 @@ KIND_SCOPES = (
 # test_granite_hybrid.py`` holds this list equal to what that model's step
 # carries
 SSM_SCOPES = (
-    "ds.mamba",        # models/granite_hybrid.py _one_layer: the Mamba-2
-    #                    mixer (norm, projections, convolution, gate, norm)
+    "ds.mamba",        # models/granite_hybrid.py, models/nemotron_h.py
+    #                    _one_layer: the Mamba-2 mixer of models/stack.py
+    #                    Mamba2 (norm, projections, convolution, gate, norm)
     "ds.ssd",          # ops/ssd.py chunk_ssd: the chunked state-space scan
     #                    (the kernels and XLA's copies round them; the
     #                    backward rule opens it again, outside the forward's)
@@ -147,7 +148,7 @@ MIXER_SCOPES = (
     "ds.mix_pre",      # between the input projections and the scan, without
     #                    the convolution's pass. models/kimi_linear.py _kda:
     #                    beta, the decay's softplus and g;
-    #                    models/granite_hybrid.py _mamba: dt's softplus,
+    #                    models/stack.py Mamba2._mamba: dt's softplus,
     #                    the split into x, B, C
     "ds.mix_post",     # between the scan and the output projection. _kda
     #                    and models/qwen3_next.py _gdn: the gated per-head
@@ -156,7 +157,7 @@ MIXER_SCOPES = (
     #                    ops/pallas/gated_norm.py and nothing else (the op
     #                    opens the scope, in its backward rule too);
     #                    _mamba, still XLA's: D x, the silu(z) gate, the
-    #                    gated norm over the whole row
+    #                    gated norm over the whole row or a group's run
 )
 # what a stack of window and full attention layers, each routed, opens
 # inside ds.layers in place of ds.attn (models/mellum.py, models/laguna.py), beside
@@ -297,11 +298,22 @@ SPAN_SCOPES = (
     #                    query spans, the concatenations back to the row
     #                    (the backward rule opens it itself)
 )
+# what a routed layer whose experts work in a latent opens beside the
+# routed layers' four and their kernels' of KIND_SCOPES
+# (models/nemotron_h.py: a stack whose every layer is ONE sublayer, ds.mamba
+# with SSM_SCOPES and MIXER_SCOPES inside it, ds.attn, or a routed layer; no
+# ds.mlp); ``tests/test_nemotron_h_engine.py`` holds the step to them
+LATENT_SCOPES = (
+    "ds.moe_latent",   # moe/sharded_moe.py moe_ffn_held: the projection
+    #                    hidden -> latent in front of the held experts and
+    #                    latent -> hidden behind them (opened twice, round
+    #                    ds.moe_experts, which lies outside)
+)
 # every list above: what a metric file may name
 KNOWN_SCOPES = frozenset(
     DEVICE_SCOPES + KIND_SCOPES + SSM_SCOPES + MIXER_SCOPES + WINDOW_SCOPES
     + LOOP_SCOPES + GDN_SCOPES + LFM_SCOPES + MHC_SCOPES + GATE_SCOPES
-    + SPAN_SCOPES)
+    + SPAN_SCOPES + LATENT_SCOPES)
 # the scopes that split a train step into disjoint parts; the others lie
 # inside one of these
 TOP_SCOPES = ("ds.embed", "ds.layers", "ds.loss_head", "ds.optimizer")

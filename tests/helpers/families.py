@@ -314,6 +314,32 @@ FAMILIES = {
         two_layers=dict(num_layers=2, first_k_dense_replace=1),
         # the leading dense layer and the scanned routed ones: two traced
         rematted=2),
+    "nemotron_h": Family(
+        # the preset's own five layers, each ONE sublayer: a routed and a
+        # Mamba-2 layer twice under the scan, an attention layer behind them
+        models.NemotronH, cell=dict(moe_held_experts=8),
+        config="nemotron-3-super-120b-ep64-zero3-1chip", arch="nemotron_h",
+        # a larger table under sharper scores, larger values and outputs
+        # (Granite's rule for its one attention layer), larger mixers and
+        # experts, a bias that moves the selection past the mask's margin,
+        # every norm weight drawn (the gated norm's among them: a group's
+        # weight has to differ from another's)
+        boost={"tokens": 5.0, "wq": 16.0, "wk": 16.0, "wv": 8.0, "wo": 8.0,
+               "w_in": 4.0, "w_out": 8.0, "w_dn": 32.0, "w_up": 4.0,
+               "w_down": 4.0, "router_bias": 10.0},
+        special=dict.fromkeys(("ln1_scale", "scale", "norm"), _norm),
+        held=8,
+        # 8 x 128 tokens x top-22 of 512 experts: 44 a held expert if even
+        engine=dict(calls=2, per_expert=(28, 62), block=128, steps=4,
+                    behind="one_device", bias=("period", (2, 512)),
+                    bias_tol=(1e-5, 2e-2), cell="train-lmoe-s8k-1chip"),
+        scopes=frozenset(set(S.DEVICE_SCOPES) - {"ds.mlp"}
+                         | set(S.SSM_SCOPES) | set(S.MIXER_SCOPES) | _MOE
+                         | set(S.LATENT_SCOPES) | {"ds.moe_shared"}),
+        # three layers hold the three kinds; none repeats, all unrolled
+        two_layers=dict(num_layers=3, hybrid_override_pattern="ME*"),
+        short_conv="its Mamba-2 mixer is models/stack.py Mamba2, Granite's: "
+                   "the granite_hybrid row holds the op's step"),
 }
 # the rows whose step is rematted with a kept residual in it, a delta-rule
 # scan, a short convolution: what the cross-family cases are parametrised by

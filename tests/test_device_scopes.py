@@ -558,10 +558,12 @@ def test_op_work_is_exported_beside_op_scopes(tmp_path):
     ("ds.flash_merge", "ops/pallas/flash_attention.py", "_spans_bwd"),
     ("ds.rope", "ops/layers.py", "latent_attention"),
     ("ds.rope", "ops/pallas/rope.py", "_latent_call"),
+    # experts that work in a latent (ISSUE 66)
+    ("ds.moe_latent", "moe/sharded_moe.py", "moe_ffn_held"),
 ])
 def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
                                                           function):
-    """``MHC_SCOPES``, ``GATE_SCOPES`` and ``SPAN_SCOPES`` name, a scope, the file and the
+    """``MHC_SCOPES``, ``GATE_SCOPES``, ``SPAN_SCOPES`` and ``LATENT_SCOPES`` name, a scope, the file and the
     function that opens it: the function's source holds the scope's name
     as a literal, and every list of the registry is in ``KNOWN_SCOPES``
     (what a metric file may name: ``tests/test_benchmark_contract.py``)."""
@@ -571,7 +573,7 @@ def test_a_registered_scope_is_opened_where_the_list_says(scope, file,
     import deepspeed_tpu
     assert scope in (scopes.MHC_SCOPES + scopes.GATE_SCOPES
                      + scopes.WINDOW_SCOPES + scopes.SPAN_SCOPES
-                     ) and scope in scopes.KNOWN_SCOPES
+                     + scopes.LATENT_SCOPES) and scope in scopes.KNOWN_SCOPES
     source = (pathlib.Path(deepspeed_tpu.__file__).parent / file).read_text()
     body = next(ast.get_source_segment(source, node)
                 for node in ast.walk(ast.parse(source))

@@ -356,6 +356,14 @@ PARENT_COUNTS = {
                                      116747205888),
     "cell/kanana-2-30b-ep8-zero3-1chip": (
         511857152, 224023040, 5173791744.0, 9200200704),
+    # PR 66's own, pinned when the family came (ISSUE 66's 773,582,304:
+    # layers 26 to 36, a quarter of each mixer's heads, 8 of 512 experts
+    # held, an eighth of the vocabulary)
+    "nemotron_h/tiny": (1282608, 279088, 1576608.0, 1625376),
+    "nemotron_h/3-super-120b-a12b": (120668707840, 12770237440,
+                                     125443319808.0, 176982730752),
+    "cell/nemotron-3-super-120b-ep64-zero3-1chip": (
+        773582304, 562843104, 3040471872.0, 3090797376),
     "ouro/tiny": (148097, 148097, 3360792.0, 3750936),
     "ouro/2.6b": (2667974657, 2667974657, 216840634392.0, 371457097752),
     "cell/kimi-linear-48b-ep32-zero3-1chip": (
@@ -428,6 +436,8 @@ def test_counts_are_the_parents(case):
     ("laguna", dict(hc_mult=4)),
     ("deepseek_v3", dict(hc_mult=4)),
     ("deepseek_v3", dict(mla_use_nope=True)),
+    ("nemotron_h", dict(mamba_n_heads=4)),
+    ("granite_hybrid", dict(mamba_num_heads=4)),
     ("mellum", dict(num_attention_heads_per_layer=[4, 4, 4, 4])),
     ("granite_hybrid", dict(conv_L_cache=3)),
     ("kimi_linear", dict(qk_norm_init=2.0)),

@@ -129,6 +129,16 @@ from helpers.families import program
 # twelve rows before it stand, every tiny row is held whole and lowers to
 # the text it lowered to, and
 # ``test_the_flash_loops_are_the_parents_program`` holds the loops.
+# PR 66 added ``nemotron_h`` (three layers of ONE sublayer each: a Mamba-2
+# mixer at two groups with the gated norm a group, 8 of 512 ``relu2``
+# experts held in a latent of 16 beside a shared one, an attention layer
+# without positions; taken on its own tree, the first that has the family),
+# gave the held experts' kernel pair a static ``body`` (``ops/pallas/
+# grouped_matmul.py`` ``BODIES``: ``swiglu`` walks the refs it walked, in
+# the order it walked them), ``moe_ffn_held`` a ``latent`` and moved
+# ``granite_hybrid``'s Mamba-2 mixer into ``models/stack.py`` ``Mamba2``
+# word for word (the norm's group count its argument; the weights are
+# drawn in the order they were): the thirteen rows before it stand.
 _PINS = {
     "kimi_linear": (
         "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
@@ -166,6 +176,9 @@ _PINS = {
     "deepseek_v3": (
         "e83a0d3840b7b97f860ebdec4507c86c98da6c89c56941d15c140952a7acb541",
         4508.550148079469),
+    "nemotron_h": (
+        "21c54f8b655c92ac34919dc2d66b42cf93963f7e551f97c67b22ea8c7897e6ab",
+        5834.368574828769),
 }
 # the rows that are not a family's two-layer cut under the family's name: (family, cut of its
 # layers, further switches)
