@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pallas import grouped_matmul
+from ..ops.pallas import grouped_matmul, router
 
 
 def compute_capacity(num_tokens: int, num_experts: int, k: int,
@@ -34,17 +34,49 @@ def compute_capacity(num_tokens: int, num_experts: int, k: int,
     return max(cap, min_capacity)
 
 
+def _top_k_xla(select, scores, k: int):
+    """:func:`top_k_of` as XLA's ops: ``lax.top_k`` (on the chip a full
+    stable sort of every row), a gather of the scores, a ``bincount``."""
+    w, idx = lax.top_k(select, k)
+    if scores is not None:
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+    load = jnp.bincount(idx.reshape(-1), length=select.shape[-1])
+    return idx.astype(jnp.int32), w, load.astype(jnp.int32)
+
+
+def top_k_of(select: jax.Array, scores: jax.Array | None, k: int):
+    """The ``k`` largest of each row of ``select`` [N, E] float32, the
+    lower index first among equals (``lax.top_k``'s order), and those of
+    ``scores`` [N, E] (None: ``select``'s own values): (experts chosen
+    [N, k] int32, their scores [N, k], the rows that chose each expert
+    [E] int32). The weights' gradient goes to the scores they are; the
+    choice gets none. ONE pass of a kernel pair over the scores
+    (``ops/pallas/router.py``) where the shape allows (``router.fits``: a
+    step's worth of rows in whole grid tiles, experts in whole sublane
+    tiles), else :func:`_top_k_xla`: the same numbers, element for
+    element."""
+    n, e = select.shape
+    form = "kernel" if router.fits(n, e, k) else "xla"
+    router.count_router(form, e, k)
+    if form == "xla":
+        return _top_k_xla(select, scores, k)
+    # experts first: XLA hands the scores over in that layout, no copy
+    idx, w, load = router.top_k_rows(
+        select.T, None if scores is None else scores.T, k)
+    return idx.T, w.T, load
+
+
 def softmax_top_k(logits: jax.Array, k: int, *, renormalise: bool = True):
     """Softmax routing, once for every path that routes by it: float32
     ``probs = softmax(logits)`` over all experts, the ``k`` largest, their
     probabilities divided by their sum if ``renormalise`` (and k > 1).
-    Returns (experts chosen [N, k], weights [N, k] float32, probs
-    [N, E])."""
+    Returns (experts chosen [N, k] int32, weights [N, k] float32, probs
+    [N, E], the rows that chose each expert [E] int32)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topk_probs, topk_idx = lax.top_k(probs, k)          # [N, k]
+    topk_idx, topk_probs, load = top_k_of(probs, None, k)
     if renormalise and k > 1:
         topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
-    return topk_idx, topk_probs, probs
+    return topk_idx, topk_probs, probs, load
 
 
 def top_k_gating(logits: jax.Array, k: int, capacity_factor: float = 1.0,
@@ -64,8 +96,8 @@ def top_k_gating(logits: jax.Array, k: int, capacity_factor: float = 1.0,
         # capacity here silently one-hots overflow positions past the
         # table into zero rows (they were "kept" but never dispatched)
         capacity = max(n, min_capacity)
-    topk_idx, topk_probs, probs = softmax_top_k(logits, k,
-                                                renormalise=normalize_topk)
+    topk_idx, topk_probs, probs, _ = softmax_top_k(
+        logits, k, renormalise=normalize_topk)
 
     # slot-major positions: all slot-0 assignments get capacity positions
     # first (matches reference top2gating's second-expert offset logic)
@@ -166,14 +198,13 @@ def moe_ffn_grouped(x: jax.Array, gate_w: jax.Array, experts: dict, *,
     e = gate_w.shape[-1]
     xt = x.reshape(n, d)
     logits = xt @ gate_w                                   # [N, E]
-    topk_idx, topk_probs, probs = softmax_top_k(logits, k,
-                                                renormalise=normalize_topk)
+    topk_idx, topk_probs, probs, group_sizes = softmax_top_k(
+        logits, k, renormalise=normalize_topk)
 
     e_flat = topk_idx.reshape(-1)                          # [N*k]
     order = jnp.argsort(e_flat)                            # sorted rows
     rows = order // k                                      # token of row
     xs = jnp.take(xt, rows, axis=0)                        # moe_gather
-    group_sizes = jnp.bincount(e_flat, length=e).astype(jnp.int32)
 
     if activation == "swiglu":
         gate = lax.ragged_dot(xs, experts["w_gate"], group_sizes)
@@ -270,14 +301,14 @@ def sigmoid_top_k(logits: jax.Array, bias: jax.Array, k: int, *,
     takes part in the SELECTION only and gets no gradient); the weights
     are the chosen experts' own scores, divided by their sum (+1e-20) if
     ``renormalise``, times ``scaling``. Returns (experts chosen [N, k]
-    int32, weights [N, k] float32, selection scores [N, E])."""
+    int32, weights [N, k] float32, selection scores [N, E], the rows that
+    chose each expert [E] int32)."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     select = scores + lax.stop_gradient(bias.astype(jnp.float32))
-    _, idx = lax.top_k(select, k)
-    w = jnp.take_along_axis(scores, idx, axis=-1)
+    idx, w, load = top_k_of(select, scores, k)
     if renormalise:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * scaling, select
+    return idx, w * scaling, select, load
 
 
 def _held_layout(idx, weights, first: int, n_held: int, tile: int):
@@ -591,13 +622,12 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
         logits = jnp.matmul(xt, router_w,
                             preferred_element_type=jnp.float32)
         if router == "sigmoid":
-            idx, weights, _ = sigmoid_top_k(logits, router_bias, k,
-                                            renormalise=renormalise,
-                                            scaling=scaling)
+            idx, weights, _, load = sigmoid_top_k(
+                logits, router_bias, k, renormalise=renormalise,
+                scaling=scaling)
         elif router == "softmax" and router_bias is None:
-            idx, weights, _ = softmax_top_k(logits, k,
-                                            renormalise=renormalise)
-            idx = idx.astype(jnp.int32)
+            idx, weights, _, load = softmax_top_k(logits, k,
+                                                  renormalise=renormalise)
             if scaling != 1.0:
                 weights = weights * scaling
         else:
@@ -607,8 +637,6 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
                 f"'sigmoid' takes a bias, 'softmax' takes none")
         if not router_grad:
             weights = lax.stop_gradient(weights)
-        load = jnp.bincount(idx.reshape(-1),
-                            length=n_experts).astype(jnp.int32)
     n_held = experts["w_up"].shape[0]
     rows = xt
     if latent is not None:

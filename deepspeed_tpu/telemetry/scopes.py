@@ -110,7 +110,10 @@ KIND_SCOPES = (
     #                    (ds.flash_fwd / ds.flash_bwd inside it, and at the
     #                    cell's widths ds.rope of WINDOW_SCOPES: the kernels
     #                    that lay q, k, v out)
-    "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router
+    "ds.moe_router",   # moe/sharded_moe.py moe_ffn_held: float32 router;
+    #                    at a cell's shapes the selection is the kernels
+    #                    ds_router_fwd / ds_router_bwd (ops/pallas/router.py;
+    #                    the backward rule opens the scope again)
     "ds.moe_experts",  # moe/sharded_moe.py held_experts_ffn, fwd and bwd:
     #                    the sorts, the gathers and the three kernels
     #                    (the backward rule opens it again)

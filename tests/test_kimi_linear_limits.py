@@ -87,7 +87,7 @@ def test_sigmoid_router_by_hand():
     logits = jnp.log(jnp.asarray([[0.8, 0.6, 0.5, 0.2]])
                      / (1 - jnp.asarray([[0.8, 0.6, 0.5, 0.2]])))
     bias = jnp.asarray([0.0, -0.5, 0.0, 0.35])
-    idx, w, select = sigmoid_top_k(logits, bias, 2, scaling=2.446)
+    idx, w, select, _ = sigmoid_top_k(logits, bias, 2, scaling=2.446)
     # scores + bias = .8, .1, .5, .55: experts 0 and 3, not 0 and 1
     assert sorted(np.asarray(idx[0]).tolist()) == [0, 3]
     np.testing.assert_allclose(np.asarray(select[0]), [.8, .1, .5, .55],
@@ -97,7 +97,7 @@ def test_sigmoid_router_by_hand():
     # weights from the SCORES .8 and .2, not from scores + bias
     assert by_expert[0] == pytest.approx(2.446 * 0.8 / 1.0, rel=1e-6)
     assert by_expert[3] == pytest.approx(2.446 * 0.2 / 1.0, rel=1e-6)
-    _, raw, _ = sigmoid_top_k(logits, bias, 2, renormalise=False)
+    _, raw, _, _ = sigmoid_top_k(logits, bias, 2, renormalise=False)
     assert sorted(np.asarray(raw[0]).tolist()) == pytest.approx([0.2, 0.8])
     # and no gradient reaches the bias
     g = jax.grad(lambda b: jnp.sum(sigmoid_top_k(logits, b, 2)[1]))(bias)
@@ -171,7 +171,8 @@ def test_no_token_is_dropped_under_a_skewed_router(skew):
             "all_to_one_held_expert": jnp.where(jnp.arange(E) < 8, 5.0, 0.0),
             "none_held": jnp.where(jnp.arange(E) < 8, -5.0, 0.0)}[skew]
     xt = x.reshape(-1, D)
-    idx, w, _ = sigmoid_top_k(xt @ params["router"], bias, K, scaling=2.446)
+    idx, w, _, _ = sigmoid_top_k(xt @ params["router"], bias, K,
+                                 scaling=2.446)
     out, done = held_experts_ffn(xt, idx, w, held, 0, 16)
     want_rows = int(jnp.sum(idx < 8))
     assert int(done) == want_rows
@@ -208,7 +209,7 @@ def test_balance_bias_by_hand_and_it_holds_a_drifting_router():
     def held_load(rate, steps=60):
         bias = jnp.zeros(E)
         for t in range(steps):
-            idx, _, _ = sigmoid_top_k(logits + t * drift, bias, K)
+            idx, _, _, _ = sigmoid_top_k(logits + t * drift, bias, K)
             load = jnp.bincount(idx.reshape(-1), length=E)
             bias = balance_bias(bias, load, rate)
         return float(jnp.mean(load[:8])) / (4096 * K / E)

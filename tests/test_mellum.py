@@ -125,13 +125,13 @@ def _full_layer():
 
 def test_softmax_router_by_hand_and_the_capacity_path_shares_it():
     logits = jnp.log(jnp.asarray([[0.5, 0.1, 0.3, 0.1]]))
-    idx, w, probs = softmax_top_k(logits, 2)
+    idx, w, probs, _ = softmax_top_k(logits, 2)
     assert sorted(np.asarray(idx[0]).tolist()) == [0, 2]
     np.testing.assert_allclose(np.asarray(probs[0]), [.5, .1, .3, .1],
                                rtol=1e-6)
     np.testing.assert_allclose(sorted(np.asarray(w[0])), [.375, .625],
                                rtol=1e-6)
-    _, raw, _ = softmax_top_k(logits, 2, renormalise=False)
+    _, raw, _, _ = softmax_top_k(logits, 2, renormalise=False)
     np.testing.assert_allclose(sorted(np.asarray(raw[0])), [.3, .5],
                                rtol=1e-6)
     # top_k_gating's combine weights are these weights
@@ -186,7 +186,7 @@ def test_no_row_is_dropped_under_a_skewed_softmax_router(skew):
     plant = {"balanced": 0.0,
              "all_to_one_held_expert": 30.0 * jax.nn.one_hot(3, E),
              "none_held": jnp.where(jnp.arange(E) < 16, -30.0, 0.0)}[skew]
-    idx, w, _ = softmax_top_k(xt @ params["router"] + plant, K)
+    idx, w, _, _ = softmax_top_k(xt @ params["router"] + plant, K)
     idx = idx.astype(jnp.int32)
     out, done = held_experts_ffn(xt, idx, w, held, 0, 16)
     want_rows = int(jnp.sum(idx < 16))
@@ -226,9 +226,8 @@ def test_the_block_rule_by_shape():
 
 # ---- planted faults, through the benchmark's own decision ------------------
 def _sigmoid_for_softmax(logits, k, *, renormalise=True):
-    idx, w, scores = sigmoid_top_k(logits, jnp.zeros(logits.shape[-1]), k,
-                                   renormalise=renormalise)
-    return idx, w, scores
+    return sigmoid_top_k(logits, jnp.zeros(logits.shape[-1]), k,
+                         renormalise=renormalise)
 
 
 def _experts_16_to_31(real):

@@ -139,18 +139,28 @@ from helpers.families import program
 # ``granite_hybrid``'s Mamba-2 mixer into ``models/stack.py`` ``Mamba2``
 # word for word (the norm's group count its argument; the weights are
 # drawn in the order they were): the thirteen rows before it stand.
+# PR 67 re-took the nine routed rows by design (``kimi_linear``, ``mellum``,
+# ``mellum_two_layers``, ``qwen3_next``, ``lfm2_moe``, ``xing4_0``,
+# ``laguna``, ``deepseek_v3``, ``nemotron_h``): ``sigmoid_top_k`` and
+# ``softmax_top_k`` take experts, scores and load from ONE helper
+# (``moe/sharded_moe.py`` ``top_k_of``), so the ``bincount`` that stood at
+# the end of ``moe_ffn_held``'s router now stands before the renormalising
+# divide. At these rows' 1024 tokens the helper is XLA's form
+# (``_top_k_xla``: the same ``top_k``, gather and ``bincount`` equations; the
+# kernel pair of ``ops/pallas/router.py`` takes 8192 tokens or more), and
+# the seeded weights are the parent's. The four unrouted rows stand.
 _PINS = {
     "kimi_linear": (
-        "1854020230fb284d0e8e3a3d8d82ba921d29558750dc28ed404e5545773913f4",
+        "c29ab09229a760cd9ad6d944e0fd4c14d32dd0a2a77390b4cfc64a4cd7d264a4",
         7191.956370612894),
     "granite_hybrid": (
         "784e9ecf5ebfccdeb1b817732e5cef85f8f7725e508030251d429a08f63e80c6",
         2422.8129150247487),
     "mellum": (
-        "31cec3cc35031e45326e06fc1bc8dede9493c53fe0f24048f8a90530755e967a",
+        "93cd1379d108cc3b51293bc68f9c8e6ee2d49ef352d915add24d15f6d2aa0bbd",
         36510.69587289919),
     "mellum_two_layers": (
-        "ed3dfdfe66bf36f12c4c25ebcd86ef0570a9304cbdc2d74704f5d4b4881fa391",
+        "4263069faf840cf81347a6ceee8f9bac3319325e9a7a6e9212f0210a9da473eb",
         31755.548628388842),
     "ouro": (
         "9eec4588f2e615a8871cef610ca85b5dc9984269762d3ecda677f84ad4d2f3d0",
@@ -162,22 +172,22 @@ _PINS = {
         "ff01f5cdc985b238b6f862877aa11232503e0a0743274f22dddd837917e3a975",
         2339.9930016614694),
     "qwen3_next": (
-        "5222f59aa4e74f95531c12fb783bab8824d73863ecda7effa7f8a30920a5d08a",
+        "81ed941b873cf68d4b2926589dad57df01eef90b4f4b4ac39ea97ea6779ca536",
         39458.17879846059),
     "lfm2_moe": (
-        "16b9ab6078daeb3e473bb586f64fa663c247fca2b8d164a7d5c9c6c3f639351f",
+        "ec703ae086a420f0e9d3c535dabe731ef5f7e23d9e1fe96255a773f201d30ec8",
         3449.799246064109),
     "xing4_0": (
-        "7d0db59390f004a09922ce9ac1b47e8557f20f5884037f8f0dd022c42b2a6715",
+        "14c82542d404cecbad07f0fd0ca1ba4b62050c7580f314e6a8fecfcce0bab852",
         4668.748035160373),
     "laguna": (
-        "abcd8641dd6b81d5f21e8bafa1c029566029a5e428a09bd7693ea7c8d8045522",
+        "2a724002603526064c74b31b389bc35d41635a82bfa9068608ce4fc0dbc5d97c",
         31325.334374967497),
     "deepseek_v3": (
-        "e83a0d3840b7b97f860ebdec4507c86c98da6c89c56941d15c140952a7acb541",
+        "6e89b39d056a28e3f5132076626dd911b9dd9fbe1c4da5f110738189f6a99071",
         4508.550148079469),
     "nemotron_h": (
-        "21c54f8b655c92ac34919dc2d66b42cf93963f7e551f97c67b22ea8c7897e6ab",
+        "4d26a8a344b9860e4b0723f3084234cc3c57fc9228b4f6b87c59f66f3daab353",
         5834.368574828769),
 }
 # the rows that are not a family's two-layer cut under the family's name: (family, cut of its

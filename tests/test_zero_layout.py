@@ -1143,3 +1143,62 @@ def test_latent_kernels_compile_for_v5e_alone_and_per_shard(
     for kernel in ("ds_latent_fwd", "ds_latent_bwd"):
         assert re.search(rf"%{kernel}[.\w]* = ", hlo), kernel
     assert "all-to-all" not in hlo
+
+
+def test_router_kernels_compile_for_v5e_with_no_sort_gather_or_scatter(
+        monkeypatch):
+    """The router ALONE at the Nemotron cell's shape (ISSUE 67: 8192 tokens
+    of 4096 over 512 experts, top 22, sigmoid with a selection bias,
+    renormalised and scaled), value and gradient, compiled by Mosaic for
+    one described v5e chip: the text holds ``ds_router_fwd`` and
+    ``ds_router_bwd`` once each, no ``sort``, ``gather`` or ``scatter``
+    (``lax.top_k``'s full sort, the gather of 180,224 single scores, the
+    ``bincount``'s scatter-add and the gather's transpose: 37 ms of the
+    cell's 306), the scores reach the kernel experts-first with no copy
+    (the matmul writes that layout), and neither kernel asks for more VMEM
+    than any XLA op gets (an op of the layer scan's body: PR 48). What XLA
+    stages in VMEM round the calls is its own choice at that limit and is
+    not held here."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology description: {e}")
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas import router
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = jnp.float32
+    tokens, hidden, experts, k = 8192, 4096, 512, 22
+    assert router.fits(tokens, experts, k)
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda dims, dt=f32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dt, sharding=one)
+
+    def loss(x, w, bias, ct):
+        with jax.named_scope("ds.moe_router"):
+            logits = jnp.matmul(x, w, preferred_element_type=f32)
+            idx, weights, _, load = sharded_moe.sigmoid_top_k(
+                logits, bias, k, scaling=2.5)
+        return jnp.sum(weights * ct), (idx, load)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        sd((tokens, hidden), jnp.bfloat16), sd((hidden, experts)),
+        sd((experts,)), sd((tokens, k))).compile().as_text()
+    for kernel in ("ds_router_fwd", "ds_router_bwd"):
+        found = [line for line in hlo.splitlines() if re.search(
+            rf"%{kernel}[.\w]* = .*custom-call", line)]
+        assert len(found) == 1, (kernel, len(found))
+        asked = re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                           found[0])[0]
+        assert all(int(m) <= 16 * 2 ** 20 for m in re.findall(
+            r'"size":"(\d+)"', asked)), asked
+    for op in ("sort", "gather", "scatter"):
+        assert not re.search(rf" {op}\(", hlo), op
+    # the scores are handed over experts-first: no copy of a [N, E] tensor
+    assert not re.search(
+        rf"= f32\[({tokens},{experts}|{experts},{tokens})\]\S* "
+        r"(copy|transpose)\(", hlo)

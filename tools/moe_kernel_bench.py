@@ -5,6 +5,7 @@ XLA does round them) against the ``jax.numpy`` block loop it replaced
 (``tests/helpers/held_reference.py``).
 
     chiprun -- python tools/moe_kernel_bench.py [ROW_TILE ...]
+    chiprun -- python tools/moe_kernel_bench.py router
 
 Sizes a change to the kernels before a cell is run (PRs 41, 48). The
 shapes are the three cells' (16384 tokens): at top 8 and hidden 2304,
@@ -35,6 +36,18 @@ call in ms, from a profiler trace of 10 calls:
   and ``err``: the result and the gradients against the loop's, largest
   difference over the largest value.
 
+``router``: the routers' selection alone (``moe/sharded_moe.py``
+``sigmoid_top_k`` / ``softmax_top_k`` on float32 logits [tokens, experts]),
+at the eight routed cells' shapes (``ROUTERS``), as the kernel pair of
+``ops/pallas/router.py`` and as XLA's ``lax.top_k``, gather and
+``bincount`` (``sharded_moe._top_k_xla``). A line a shape and form, device
+busy time of one call in ms from a trace of 10: ``fwd`` (the experts
+chosen, their weights, the load), ``grad`` (``jax.grad`` of a weighted sum
+of the weights: the forward and the weights' gradient back to the
+logits), each kernel's part of them, ``ops`` the longest device ops of
+``grad`` outside the kernels; on the kernel's line ``same``: idx, weights,
+load and the gradient equal XLA's element for element.
+
 A device number, so only on a TPU. Not the yardstick: what a user feels
 is ``benchmark/run.py``.
 """
@@ -64,6 +77,18 @@ SHAPES = {"mellum": (64, 16, 896, None, False, 8, 2304),
           "kimi": (256, 8, 1024, None, True, 8, 2304),
           "kimi_skew": (256, 8, 1024, 1163, True, 8, 2304),
           "qnext": (512, 32, 512, None, False, 10, 2048)}
+
+
+ROUTER_KERNELS = ("ds_router_fwd", "ds_router_bwd")
+# cell's family: (tokens a step, experts, top k, router)
+ROUTERS = {"nemotron": (8192, 512, 22, "sigmoid"),
+           "kanana": (32768, 128, 6, "sigmoid"),
+           "qnext": (16384, 512, 10, "softmax"),
+           "kimi": (16384, 256, 8, "sigmoid"),
+           "mellum": (16384, 64, 8, "softmax"),
+           "laguna": (8192, 256, 10, "softmax"),
+           "lfm2": (16384, 64, 4, "sigmoid"),
+           "xing": (8192, 64, 4, "sigmoid")}
 
 
 def routing(experts: int, held: int, skew, top_k: int):
@@ -105,9 +130,9 @@ def inputs(name: str, seed: int = 41):
             jnp.asarray(normal(TOKENS, hidden), bf))
 
 
-def split(events) -> dict:
+def split(events, kernels=KERNELS) -> dict:
     out = {"busy": busy_ms(events)}
-    for k in KERNELS:
+    for k in kernels:
         out[k] = busy_ms(events, rf"^%?{k}[.\d]* = ")
     out["rest"] = 2 * out["busy"] - sum(out.values())
     return {k: round(v, 3) for k, v in out.items()}
@@ -118,12 +143,60 @@ def longest(events, n: int = 6) -> dict:
     total = collections.Counter()
     for name, a, b in events:
         op = name.split(" = ")[0].lstrip("%")
-        if not op.startswith(("while", "ds_moe_", "conditional")):
+        if not op.startswith(("while", "ds_moe_", "ds_router_",
+                              "conditional")):
             total[op] += b - a
     return {op: round(1e-6 * ns / CALLS, 3) for op, ns in total.most_common(n)}
 
 
+def routers() -> int:
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas import router
+    fits = router.fits
+
+    def forms(kind, k):
+        def route(logits, bias):
+            if kind == "sigmoid":
+                out = sharded_moe.sigmoid_top_k(logits, bias, k, scaling=2.5)
+            else:
+                out = sharded_moe.softmax_top_k(logits, k)
+            return out[0], out[1], out[3]
+
+        def loss(logits, bias, ct):
+            return jnp.sum(route(logits, bias)[1] * ct)
+        return jax.jit(route), jax.jit(jax.grad(loss))
+
+    for name, (tokens, experts, k, kind) in ROUTERS.items():
+        rng = np.random.default_rng(67)
+        logits = jnp.asarray(rng.standard_normal((tokens, experts),
+                                                 dtype=np.float32))
+        bias = jnp.asarray(0.01 * rng.standard_normal(experts,
+                                                      dtype=np.float32))
+        ct = jnp.asarray(rng.standard_normal((tokens, k), dtype=np.float32))
+        got = {}
+        for form in ("xla", "kernel"):
+            router.fits = fits if form == "kernel" else (lambda *a: False)
+            fwd, grad = forms(kind, k)
+            ev = traced(jax, grad, (logits, bias, ct))
+            line = {"router": name, "tokens": tokens, "experts": experts,
+                    "k": k, "kind": kind, "form": form,
+                    "fwd": split(traced(jax, fwd, (logits, bias)),
+                                 ROUTER_KERNELS),
+                    "grad": split(ev, ROUTER_KERNELS), "ops": longest(ev)}
+            got[form] = (*fwd(logits, bias), grad(logits, bias, ct))
+            if form == "kernel":
+                line["same"] = [bool(jnp.array_equal(a, b)) for a, b in
+                                zip(got["xla"], got["kernel"])]
+            print(json.dumps(line), flush=True)
+        router.fits = fits
+    return 0
+
+
 def main(argv) -> int:
+    if argv == ["router"]:
+        return routers()
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.moe import sharded_moe
