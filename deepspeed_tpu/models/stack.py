@@ -331,11 +331,17 @@ class RoutedStackOfKinds(StackOfKinds):
         (``counts["blocks"]`` of ``counts["block"]`` rows each, from the
         load and ``held_block``) also those, the rows of one, and the
         step's largest and smallest load of ANY of the router's experts
-        in one layer. ``each(group, slot, counts)`` runs first for every
-        routed layer (a bias-corrected router's update)."""
+        in one layer. And what the forward sweeps counted of themselves
+        (``moe.sharded_moe._held_sweep``), summed over the routed layers:
+        ``moe_sweep_trips`` of the chunk loop (``moe_held_calls`` where
+        no call took a second), ``moe_sweep_tiles`` with a live row,
+        ``moe_sweep_swept`` tiles the trips held, the ``moe_sweep_tile``'s
+        rows and the most trips any ONE layer call took
+        (``moe_sweep_trips_max``). ``each(group, slot, counts)`` runs first
+        for every routed layer (a bias-corrected router's update)."""
         held = self.config.held_experts
-        rows = done = blocks = calls = 0
-        block, tops, leasts = None, [], []
+        rows = done = blocks = calls = trips = tiles = swept = 0
+        block, tile, tops, leasts, most = None, None, [], [], []
         for group, slots in stats.items():
             for slot, counts in slots.items():
                 if each is not None:
@@ -343,6 +349,12 @@ class RoutedStackOfKinds(StackOfKinds):
                 rows += jnp.sum(counts["load"][..., :held])
                 done += jnp.sum(counts["done"])
                 calls += counts["done"].size
+                if "trips" in counts:
+                    trips += jnp.sum(counts["trips"])
+                    tiles += jnp.sum(counts["tiles"])
+                    swept += jnp.sum(counts["swept"])
+                    tile = jnp.max(counts["tile"])
+                    most.append(jnp.max(counts["trips"]))
                 if "blocks" in counts:
                     blocks += jnp.sum(counts["blocks"])
                     block = jnp.max(counts["block"])
@@ -351,6 +363,10 @@ class RoutedStackOfKinds(StackOfKinds):
         metrics = {"moe_held_rows": rows, "moe_held_done": done,
                    "moe_held_calls": jnp.int32(calls),
                    "moe_held_experts": jnp.int32(held)}
+        if most:
+            metrics.update(moe_sweep_trips=trips, moe_sweep_tiles=tiles,
+                           moe_sweep_swept=swept, moe_sweep_tile=tile,
+                           moe_sweep_trips_max=jnp.max(jnp.stack(most)))
         if tops:
             metrics.update(moe_held_blocks=blocks, moe_held_block=block,
                            moe_load_max=jnp.max(jnp.stack(tops)),

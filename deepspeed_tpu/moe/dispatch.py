@@ -214,14 +214,26 @@ def record_held_expert_counts(reg, counts: dict) -> None:
     the routed-layer calls, the rows routed but not computed (0, or the
     dispatch dropped tokens).
     ``ds_moe_held_rows_total / (ds_moe_held_calls_total x experts held)``
-    is the mean tokens a held expert a layer call; the two ``_step_``
+    is the mean tokens a held expert a layer call, over
+    ``ds_moe_held_steps_total`` finished steps; the two ``_step_``
     gauges keep the least and the most that mean was in one step. Where
     the step counts its blocks: ``ds_moe_held_blocks_total`` (blocks the
     dispatch swept) and ``ds_moe_held_block_rows`` (rows of one), so the
     share of swept rows that is padding is ``1 - rows / (blocks x block
     rows)``; and the largest and smallest rows ANY of the router's experts
     was sent in one layer of one step (``ds_moe_load_step_max`` /
-    ``_min``)."""
+    ``_min``). Where the sweeps count themselves (``moe_sweep_*``, the
+    chunk loop's own bound: ``moe.sharded_moe._held_sweep``):
+    ``ds_moe_sweep_trips_total`` (``ds_moe_held_calls_total`` where no call
+    took a second), ``ds_moe_sweep_tiles_total{state}`` (row tiles with a
+    ``live`` row, which a kernel runs, and tiles ``swept``, which a chunk
+    holds, gathers and adds), ``ds_moe_sweep_tile_rows``,
+    ``ds_moe_sweep_trips_step_max`` (the most trips one layer call took in
+    any step) and ``ds_moe_sweep_extra_trip_steps_total`` (steps in which
+    some call took more than one); such a step also leaves ONE host event
+    ``moe_extra_trip`` (``trips``, ``calls``) with the active tracer, inside
+    the ``step_boundary`` span that follows the NEXT step's dispatch (the
+    registry is one step behind); a step without one records no event."""
     rows, done = float(counts["moe_held_rows"]), float(counts["moe_held_done"])
     calls, held = float(counts["moe_held_calls"]), float(
         counts["moe_held_experts"])
@@ -235,6 +247,9 @@ def record_held_expert_counts(reg, counts: dict) -> None:
                 "rows (token, choice) routed to held experts").inc(rows)
     reg.counter("ds_moe_held_calls_total",
                 "held-expert layer calls").inc(calls)
+    reg.counter("ds_moe_held_steps_total",
+                "finished steps whose held-expert counts were recorded"
+                ).inc()
     reg.counter("ds_moe_dropped_rows_total",
                 "rows routed to a held expert and not computed").inc(
                     rows - done)
@@ -258,3 +273,28 @@ def record_held_expert_counts(reg, counts: dict) -> None:
         extreme("ds_moe_load_step_min",
                 "least rows any expert of the router was sent in one layer "
                 "of any step", float(counts["moe_load_min"]), min)
+    if "moe_sweep_trips" in counts:
+        trips = float(counts["moe_sweep_trips"])
+        reg.counter("ds_moe_sweep_trips_total",
+                    "trips of the held sweep's chunk loop").inc(trips)
+        tiles = reg.counter("ds_moe_sweep_tiles_total",
+                            "row tiles of the held sweep: with a live row "
+                            "(a kernel runs them) and swept (a chunk holds "
+                            "them)")
+        tiles.inc(float(counts["moe_sweep_tiles"]), state="live")
+        tiles.inc(float(counts["moe_sweep_swept"]), state="swept")
+        reg.gauge("ds_moe_sweep_tile_rows",
+                  "rows a row tile of the held sweep").set(
+                      float(counts["moe_sweep_tile"]))
+        extreme("ds_moe_sweep_trips_step_max",
+                "most trips one held-expert layer call took in any step",
+                float(counts["moe_sweep_trips_max"]), max)
+        extra = reg.counter("ds_moe_sweep_extra_trip_steps_total",
+                            "finished steps in which a held-expert layer "
+                            "call took more than one trip")
+        extra.inc(float(trips > calls))
+        if trips > calls:
+            from ..utils.telemetry_probe import tel_span
+            with tel_span("moe_extra_trip", trips=int(trips),
+                          calls=int(calls)):
+                pass

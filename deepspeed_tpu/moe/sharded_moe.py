@@ -395,7 +395,14 @@ def _held_sweep(idx, weights, first, n_held, block, chunk, shape, body,
     dead tile's rows are not written and may hold anything), and
     ``grouped_matmul.add_rows`` sums each token's run into the
     accumulator, writing each tile of it once. The first chunk's call is
-    told the accumulator is zeros."""
+    told the accumulator is zeros.
+
+    Returns (the sum, ``body``'s last carry, the sweep's own count):
+    ``trips``, the bound ``fori_loop`` is given (int32, data); ``tiles``,
+    the row tiles that hold a live row (the last of the layout's
+    ``ends``); ``swept``, the tiles the trips held (``trips`` times the
+    chunk's), live or not; and ``tile``, the rows of one. The backward's
+    sweep walks the same layout, so it takes the same trips."""
     n, k = idx.shape
     tile = grouped_matmul.row_tile(block)
     m = -(-(chunk or n_held * block) // tile)
@@ -425,8 +432,12 @@ def _held_sweep(idx, weights, first, n_held, block, chunk, shape, body,
             jnp.where(held, by_token // k, n), c == 0)
         return acc, carry
 
-    return lax.fori_loop(0, (layout[3][-1] + m - 1) // m, one,
-                         (jnp.zeros(shape, jnp.float32), carry))
+    tiles = layout[3][-1]
+    trips = (tiles + m - 1) // m
+    acc, carry = lax.fori_loop(0, trips, one,
+                               (jnp.zeros(shape, jnp.float32), carry))
+    return acc, carry, {"trips": trips, "tiles": tiles, "swept": trips * m,
+                        "tile": jnp.int32(tile)}
 
 
 def _held_forward(x, idx, weights, experts, first, block, chunk, body):
@@ -436,9 +447,9 @@ def _held_forward(x, idx, weights, experts, first, block, chunk, body):
         return (grouped_matmul.forward(x[tokens], scale, tables[:3], experts,
                                        tile, body), done + jnp.sum(valid))
 
-    out, done = _held_sweep(idx, weights, first, n_held, block, chunk,
-                            x.shape, one, jnp.zeros((), jnp.int32))
-    return out.astype(x.dtype), done
+    out, done, sweep = _held_sweep(idx, weights, first, n_held, block, chunk,
+                                   x.shape, one, jnp.zeros((), jnp.int32))
+    return out.astype(x.dtype), {"done": done, **sweep}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -466,8 +477,11 @@ def held_experts_ffn(x, idx, weights, experts, first, block,
     (zeros), and the kernel leaves that product out.
 
     x [N, D]; idx [N, k] int32 over ALL experts; weights [N, k] float32.
-    Returns (out [N, D], rows computed): fewer than the rows routed to
-    the held experts only if rows were dropped."""
+    Returns (out [N, D], counts): ``done``, the rows computed (fewer than
+    the rows routed to the held experts only if rows were dropped), and
+    what the FORWARD sweep counted of itself (``_held_sweep``: ``trips``,
+    ``tiles``, ``swept``, ``tile``; the backward's sweep walks the same
+    layout and takes the same trips, so it is not counted again)."""
     with jax.named_scope("ds.moe_experts"):
         return _held_forward(x, idx, weights, experts, first, block, chunk,
                              body)
@@ -501,7 +515,7 @@ def _held_bwd_rule(first, block, router_grad, chunk, body, res, cts):
             return dxs, (dw, sums)
 
         n_held = experts["w_up"].shape[0]
-        dx, (dw, sums) = _held_sweep(
+        dx, (dw, sums), _ = _held_sweep(
             idx, weights, first, n_held, block, chunk, x.shape, one,
             (jnp.zeros((n * k,), f32),
              [jnp.zeros(experts[name].shape, f32) for name in names]))
@@ -612,7 +626,9 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
     trainer's bias update balances, :func:`balance_bias`; its slice
     ``[first_expert, +E_h)`` is what this share was sent), and
     ``counts["done"]``, the rows this share computed: less than that
-    slice's sum only if rows were dropped."""
+    slice's sum only if rows were dropped; and the forward sweep's own
+    count (``held_experts_ffn``): ``trips`` of the chunk loop, row
+    ``tiles`` with a live row, tiles ``swept`` and the ``tile``'s rows."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     n_experts = router_w.shape[-1]
@@ -642,7 +658,7 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
     if latent is not None:
         with jax.named_scope("ds.moe_latent"):
             rows = xt @ latent["w_dn"]
-    out, done = held_experts_ffn(
+    out, counts = held_experts_ffn(
         rows, idx, weights, experts, int(first_expert), int(block),
         bool(router_grad), held_chunk(b * s, k, n_experts, n_held, block),
         body)
@@ -657,7 +673,7 @@ def moe_ffn_held(x: jax.Array, router_w: jax.Array,
                     xt, shared_gate, preferred_element_type=jnp.float32))
                      ).astype(y.dtype)
             out = out + y
-    return out.reshape(b, s, d), {"load": load, "done": done}
+    return out.reshape(b, s, d), {"load": load, **counts}
 
 
 BIAS_UPDATE_RATE = 0.001    # DeepSeek-V3's; the sigmoid-routed families follow it

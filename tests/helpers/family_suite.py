@@ -44,6 +44,12 @@ def held_counters(reg, row, steps):
     assert value("ds_moe_held_calls_total") == steps * row.engine["calls"]
     assert value("ds_moe_dropped_rows_total") == 0
     assert value("ds_moe_held_experts") == row.held
+    # the sweeps counted themselves (ISSUE 68): a trip a call or more, the
+    # live tiles among the swept ones
+    assert value("ds_moe_held_steps_total") == steps
+    assert value("ds_moe_sweep_trips_total") >= steps * row.engine["calls"]
+    tiles = reg.get("ds_moe_sweep_tiles_total")
+    assert 1 <= tiles.value(state="live") <= tiles.value(state="swept")
     if "block" in row.engine:
         assert value("ds_moe_held_block_rows") == row.engine["block"]
         assert value("ds_moe_held_blocks_total") >= 1
@@ -111,6 +117,11 @@ def cases(family, *, trained=None, behind=None, scoped=None, paths=()):
             assert int(m["moe_held_calls"]) == e["calls"]
             assert int(m["moe_held_experts"]) == row.held
             assert int(m["moe_held_rows"]) == int(m["moe_held_done"]) > 0
+            assert e["calls"] <= int(m["moe_sweep_trips"]) <= e["calls"] * int(
+                m["moe_sweep_trips_max"])
+            assert int(m["moe_held_rows"]) <= int(m["moe_sweep_tiles"]) * int(
+                m["moe_sweep_tile"]) <= int(m["moe_sweep_swept"]) * int(
+                    m["moe_sweep_tile"])
             low, high = e["per_expert"]
             assert low < int(m["moe_held_rows"]) / (
                 e["calls"] * row.held) < high

@@ -534,3 +534,256 @@ def test_gap_split_reads_a_traced_runs_directory(tmp_path, monkeypatch):
     assert out["untraced"]["implied_gap_ms"] == pytest.approx(
         1e3 * 8192 / 31000 - out["untraced"]["device_step_ms"])
     json.dumps(out)
+
+
+# ---- the held sweep counted where it runs (ISSUE 68) ------------------------
+# reducers/sweep.py and the six definitions that read it: data beside a
+# by-hand reader (benchmark/tests/sweep_split.py), as the twenty-six above are
+# and for the same reason.
+SWEEP_METRICS = json.loads(
+    (BENCH / "tests" / "sweep_metrics.json").read_text())
+# name -> (unit, source, reducer, the registry names or the scope its
+# ``what`` has to name)
+SWEEP_FORMS = {
+    "moe_sweep_trips.routed": (
+        "trips/call", "program_counter", "sweep_trips_per_call",
+        ("ds_moe_sweep_trips_total", "ds_moe_held_calls_total")),
+    "moe_extra_trip_steps.routed": (
+        "%", "program_counter", "sweep_extra_trip_steps_pct",
+        ("ds_moe_sweep_extra_trip_steps_total", "ds_moe_held_steps_total")),
+    "moe_tile_pad_share.routed": (
+        "%", "program_counter", "sweep_tile_pad_share_pct",
+        ("ds_moe_held_rows_total", "ds_moe_sweep_tiles_total{state=live}",
+         "ds_moe_sweep_tile_rows")),
+    "moe_dead_tile_share.routed": (
+        "%", "program_counter", "sweep_dead_tile_share_pct",
+        ("ds_moe_sweep_tiles_total{state=live}",
+         "ds_moe_sweep_tiles_total{state=swept}")),
+    "moe_extra_trip_cost_ms.routed": (
+        "ms", "device_trace", "extra_trip_cost_ms", ("moe_extra_trip",)),
+    "router_ms.routed": (
+        "ms", "device_trace", "scope_ms_per_step", ("ds.moe_router",)),
+}
+
+
+def _sweep_snapshot(reg_names=None):
+    """A registry's snapshot after three finished steps of five routed
+    layers, the second with a call of two trips (the recorder's own
+    output on hand-made scalars); ``reg_names`` keeps only those."""
+    import numpy as np
+    from deepspeed_tpu.moe.dispatch import record_held_expert_counts
+    from deepspeed_tpu.telemetry.registry import MetricsRegistry
+    reg = MetricsRegistry()
+    for trips, swept, most in ((5, 95, 1), (6, 114, 2), (5, 95, 1)):
+        record_held_expert_counts(reg, {k: np.int32(v) for k, v in dict(
+            moe_held_rows=14080, moe_held_done=14080, moe_held_calls=5,
+            moe_held_experts=8, moe_sweep_trips=trips, moe_sweep_tiles=80,
+            moe_sweep_swept=swept, moe_sweep_tile=256,
+            moe_sweep_trips_max=most).items()})
+    snap = reg.snapshot()
+    return snap if reg_names is None else {
+        k: v for k, v in snap.items() if k in reg_names}
+
+
+def _sweep_trace(events=(2,), from_step=0):
+    """``_synthetic_gaps``' four runs with a fifth, the run of step 1 six
+    ms longer, and a ``moe_extra_trip`` event inside the ``step_boundary``
+    behind ``train_batch`` of each step of ``events``: one step behind, so
+    the event of step 2's boundary speaks of step 1. The host's events of
+    the steps before ``from_step`` are left out: a profiler started while
+    that step's run was under way."""
+    _bench_on_path()
+    from lib import trace as tr
+    ops, modules, py, rt = [], [], [], []
+    d0 = 0.0
+    for i in range(5):
+        busy = 0.103 if i == 1 else 0.097
+        modules.append(("jit_train_step(1)", d0 - 1e-5, d0 + busy + 1e-4))
+        ops += [("%fusion.1 = f32[8] fusion(...)", d0, d0 + 0.05),
+                ("%fusion.2 = f32[8] fusion(...)", d0 + 0.05, d0 + busy)]
+        tb = d0 - 0.0026
+        if i >= from_step:
+            py += [("train_batch", tb, tb + 0.0030),
+                   ("batch_to_device", tb + 0.0002, tb + 0.0012),
+                   ("compiled_step", tb + 0.00152, tb + 0.0022),
+                   ("step_boundary", tb + 0.0031, tb + 0.0033)]
+            if i in events:
+                py.append(("moe_extra_trip", tb + 0.00315, tb + 0.00316))
+            rt.append(("TpuLoadedExecutable::ExecuteLaunch",
+                       tb + 0.0019, tb + 0.002))
+        d0 += busy + 0.003
+    return tr.Trace({0: {tr.OPS_LINE: ops, tr.MODULES_LINE: modules}},
+                    {"python3": sorted(py, key=lambda e: e[1]),
+                     "main/1": rt})
+
+
+def test_the_sweep_metrics_are_the_six():
+    assert sorted(SWEEP_METRICS) == sorted(SWEEP_FORMS)
+
+
+@pytest.mark.parametrize("name", sorted(SWEEP_FORMS))
+def test_a_sweep_metric_is_a_metric_file_in_all_but_place(name):
+    """The keys of a metric file, the expert dispatch's layer, the eight
+    routed cells (those whose cell file reports ``held_expert_tokens.*``),
+    a reducer of ``reducers/sweep.py`` (the router's: the accepted
+    ``scope_ms_per_step`` on a scope the program opens), a ``what`` that
+    names what it reads; a name the contract does not have yet."""
+    _bench_on_path()
+    from deepspeed_tpu.telemetry import scopes
+    from lib import reducers
+    from reducers import sweep
+    spec = SWEEP_METRICS[name]
+    unit, source, reducer, reads = SWEEP_FORMS[name]
+    assert set(spec) == {"layer", "unit", "better", "source", "moves",
+                         "cells", "what", "reducer"}
+    assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", name)
+    assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", spec["unit"])
+    assert name not in {m["name"] for m in CONTRACT["per_layer"]}
+    assert (spec["layer"], spec["unit"], spec["better"], spec["source"],
+            spec["moves"]) == ("expert dispatch", unit, "lower", source,
+                               "train_tokens_per_s")
+    assert spec["layer"] in {m["layer"] for m in CONTRACT["per_layer"]}
+    routed = sorted(
+        w["name"] for w in CONTRACT["workloads"]
+        if any(m.startswith("held_expert_tokens.") for m in _load(
+            BENCH / "cells" / f"{w['name']}.json")["per_layer"]))
+    assert sorted(spec["cells"]) == routed and len(routed) == 8
+    assert spec["reducer"]["name"] == reducer
+    found = reducers.find(reducer)
+    args = spec["reducer"]["args"]
+    if name == "router_ms.routed":
+        assert args == {"pattern": r"ds\.moe_router\b",
+                        "module": "^jit_train_step"}
+        assert "ds.moe_router" in scopes.KIND_SCOPES
+    else:
+        assert found is getattr(sweep, reducer)
+        assert args == (GAP_ARGS if source == "device_trace" else {})
+    assert spec["what"] and "\n" not in spec["what"]
+    for text in reads:
+        assert text in spec["what"], text
+    counted = {n for n, _ in sweep.COUNTERS.values()}
+    assert set(re.findall(r"ds_moe_\w+", spec["what"])) <= counted
+
+
+def test_the_sweep_counters_read_a_snapshot_and_a_live_registry():
+    """The four counted readings from the recorder's own output: three
+    steps of five calls, one of them with six trips."""
+    _bench_on_path()
+    from deepspeed_tpu import telemetry
+    from lib import reducers
+    read = lambda ctx: {  # noqa: E731
+        name: reducers.find(form[2])(ctx, {})
+        for name, form in SWEEP_FORMS.items() if form[1] == "program_counter"}
+    want = {"moe_sweep_trips.routed": pytest.approx(16 / 15),
+            "moe_extra_trip_steps.routed": pytest.approx(100 / 3),
+            "moe_tile_pad_share.routed": pytest.approx(
+                100 * (1 - 14080 / (80 * 256))),
+            "moe_dead_tile_share.routed": pytest.approx(
+                100 * (1 - 240 / 304))}
+    assert read({"registry_snapshot": _sweep_snapshot()}) == want
+    # a parent from before the counters, a registry without them, none
+    old = {"ds_moe_held_rows_total", "ds_moe_held_calls_total"}
+    for ctx in ({"registry_snapshot": _sweep_snapshot(old)},
+                {"registry_snapshot": {}}, {"registry_snapshot": None}):
+        assert set(read(ctx).values()) == {None}
+    telemetry.shutdown()
+    assert set(read({}).values()) == {None}       # no live registry
+    telemetry.configure()
+    try:
+        live = telemetry.get_registry()
+        for name, metric in _sweep_snapshot().items():
+            for v in metric["values"]:
+                getattr(live, metric["type"])(name).inc(
+                    v["value"], **v["labels"])
+        assert read({}) == want
+    finally:
+        telemetry.shutdown()
+
+
+@pytest.mark.parametrize("events, want", [
+    ((2,), 6.0), ((), None), ((0,), None), ((2, 4), 3.0),
+    ((1, 2, 3, 4), None)],
+    ids=["one_long_step", "no_event", "speaks_of_a_run_before_the_trace",
+         "a_long_and_a_plain_step", "every_step"])
+def test_an_extra_trip_event_is_paired_with_the_run_before(events, want):
+    """The event lies one step behind: the one in step 2's boundary speaks
+    of step 1's run, the long one (with step 3's the median of the two).
+    No event, an event that speaks of a run the trace does not hold, or
+    one for every step (nothing to compare with) read nothing."""
+    _bench_on_path()
+    from lib import reducers
+    from reducers import sweep
+    ctx = {"trace": _sweep_trace(events)}
+    got = reducers.find("extra_trip_cost_ms")(ctx, GAP_ARGS)
+    assert got == (want if want is None else pytest.approx(want, abs=1e-6))
+    rows = sweep.traced_steps(ctx, GAP_ARGS)
+    assert len(rows) == 4
+    spoken = {i - 1 for i in events if 1 <= i <= 4}
+    assert [r["extra_trip"] for r in rows] == [i in spoken for i in range(4)]
+    assert [r["device_ms"] for r in rows] == pytest.approx(
+        [97.0, 103.0, 97.0, 97.0])
+    assert reducers.find("extra_trip_cost_ms")({"trace": None},
+                                               GAP_ARGS) is None
+
+
+def test_a_run_under_way_when_the_trace_began_is_no_traced_step():
+    """A profiler started by hand inside the window catches the tail of a
+    run (here step 0's, whose host events it did not see): that run is
+    left out, the event of step 2's boundary still finds step 1's."""
+    _bench_on_path()
+    from lib import reducers
+    from reducers import sweep
+    ctx = {"trace": _sweep_trace((2,), from_step=1)}
+    assert reducers.find("extra_trip_cost_ms")(
+        ctx, GAP_ARGS) == pytest.approx(6.0, abs=1e-6)
+    rows = sweep.traced_steps(ctx, GAP_ARGS)
+    assert [(round(r["device_ms"]), r["extra_trip"]) for r in rows] == [
+        (103, True), (97, False), (97, False)]
+
+
+def test_sweep_split_reads_a_traced_runs_directory(tmp_path, monkeypatch):
+    """The by-hand reader on a directory laid out as a traced run leaves
+    it (the recorded trace of a program from before the counters and the
+    snapshot of one that has them): the six names, the counted four from
+    the snapshot, the block padding of a cell that has it beside the
+    tiles', the program's own extra-trip events with the step each speaks
+    of; the recorded trace holds no such event, so no cost."""
+    _bench_on_path()
+    import shutil
+    import sys
+    from lib import tracer
+    cell = "train-conv-s8k-1chip"
+    run = tmp_path / cell / "plugins" / "profile" / "t"
+    run.mkdir(parents=True)
+    shutil.copy(SCOPED_TRACE, run)
+    snap = _sweep_snapshot()
+    snap["ds_moe_held_blocks_total"] = {"values": [
+        {"labels": {}, "value": 120.0}]}
+    snap["ds_moe_held_block_rows"] = {"values": [
+        {"labels": {}, "value": 640.0}]}
+    (tmp_path / cell / f"{cell}.metrics.json").write_text(json.dumps(snap))
+    (tmp_path / cell / f"{cell}.op_scopes.json").write_text("{}")
+    span = lambda name, ts, **args: {  # noqa: E731
+        "name": name, "ph": "X", "ts": ts, "dur": 1.0, "args": args}
+    (tmp_path / cell / f"{cell}.trace.json").write_text(json.dumps({
+        "traceEvents": [span("train_batch", 10.0, step=7),
+                        span("moe_extra_trip", 20.0, trips=6, calls=5),
+                        span("train_batch", 30.0, step=8)]}))
+    monkeypatch.setattr(tracer, "TRACE_ROOT", tmp_path)
+    sys.path.insert(0, str(BENCH / "tests"))
+    try:
+        import sweep_split
+    finally:
+        sys.path.pop(0)
+    out = sweep_split.sweep_split(cell)
+    assert set(out["metrics"]) == set(SWEEP_METRICS)
+    assert out["metrics"]["moe_sweep_trips.routed"] == pytest.approx(16 / 15)
+    assert out["metrics"]["moe_extra_trip_cost_ms.routed"] is None
+    assert out["metrics"]["router_ms.routed"] is None
+    assert out["counters"]["extra"] == 1 and out["counters"]["steps"] == 3
+    assert out["block_pad_share"] == {"moe_pad_share.routed": pytest.approx(
+        100 * (1 - 42240 / (120 * 640)))}
+    assert out["extra_trip_steps"] == [{"step": 6, "trips": 6, "calls": 5}]
+    assert [r["extra_trip"] for r in out["steps"]] == [False] * len(
+        out["steps"]) and out["steps"]
+    json.dumps(out)

@@ -13,11 +13,14 @@ experts; its compile for the chip is in ``tests/test_zero_layout.py``."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.moe.sharded_moe import held_chunk, held_experts_ffn
 from deepspeed_tpu.ops.pallas import grouped_matmul
 
@@ -67,14 +70,26 @@ def _inputs(load, d, f, dtype, seed=0):
 
 
 def _all(fn, *static):
-    """(out, rows computed, dx, d weights, the three dW) in one program."""
+    """(out, rows computed, dx, d weights, the three dW) in one program;
+    behind them the sweep's own count, where ``fn`` returns one."""
     def run(x, idx, w, experts, ct):
-        (out, done), back = jax.vjp(
+        (out, counts), back = jax.vjp(
             lambda x, w, e: fn(x, idx, w, e, 0, BLOCK, *static),
             x, w, experts)
-        dx, dw, de = back((ct, jnp.zeros((), jax.dtypes.float0)))
-        return out, done, dx, dw, de["w_gate"], de["w_up"], de["w_down"]
+        dx, dw, de = back((ct, jax.tree.map(
+            lambda c: np.zeros(c.shape, jax.dtypes.float0), counts)))
+        swept = dict(counts) if isinstance(counts, dict) else {"done": counts}
+        return (out, swept.pop("done"), dx, dw, de["w_gate"], de["w_up"],
+                de["w_down"], swept)
     return jax.jit(run)
+
+
+@functools.cache
+def _loop(load, width):
+    """The block loop's results on ``_inputs(load, 128, width, float32)``:
+    one program a (load, width) a file, whoever asks."""
+    return _all(held_reference.held_experts_ffn)(
+        *_inputs(load, 128, width, jnp.float32))
 
 
 def _err(got, want):
@@ -95,14 +110,14 @@ def test_kernel_pair_matches_the_block_loop(load, width):
     is the rows routed to the held experts, whatever the skew."""
     args = _inputs(load, 128, width, jnp.float32)
     got = _all(held_experts_ffn, True)(*args)
-    want = _all(held_reference.held_experts_ffn)(*args)
+    want = _loop(load, width)
     rows = int(np.sum(np.asarray(args[1]) < HELD))
     assert int(got[1]) == int(want[1]) == rows
     for name, g, w in zip(NAMES, got, want):
         assert g.shape == w.shape and g.dtype == w.dtype, name
         assert _err(g, w) < 2e-5, (name, _err(g, w))
     if load == "absent_only":
-        assert not any(np.asarray(g).any() for g in got), load
+        assert not any(np.asarray(g).any() for g in got[:7]), load
 
 
 @pytest.mark.parametrize("width", [384, 256], ids=["f384", "f256"])
@@ -145,10 +160,71 @@ def test_any_chunk_gives_what_the_loop_gives(load, chunk):
     24 (one chunk, part empty, whatever the load)."""
     args = _inputs(load, 128, 256, jnp.float32)
     got = _all(held_experts_ffn, True, chunk)(*args)
-    want = _all(held_reference.held_experts_ffn)(*args)
+    want = _loop(load, 256)
     assert int(got[1]) == int(want[1])
     for name, g, w in zip(NAMES, got, want):
         assert _err(g, w) < 2e-5, (name, _err(g, w))
+
+
+# ---- the sweep counts itself (ISSUE 68) ------------------------------------
+# rows a chunk: the shape's own (what a balanced router sends the four held
+# experts and a tile each: 24 tiles), and half the one expert's 20 tiles
+CHUNK = 24 * TILE
+SWEEPS = {"balanced": (CHUNK, 1), "an_expert_with_none": (CHUNK, 1),
+          "one_expert": (12 * TILE, 2)}
+
+
+def _forward(load, chunk):
+    """(out, the sweep's counts as ints, the routing) of the forward."""
+    x, idx, w, experts, _ = _inputs(load, 128, 128, jnp.float32)
+    out, counts = jax.jit(lambda x, w, e: held_experts_ffn(
+        x, idx, w, e, 0, BLOCK, True, chunk))(x, w, experts)
+    return (np.asarray(out), {k: int(v) for k, v in counts.items()},
+            np.asarray(idx))
+
+
+@pytest.mark.parametrize("load", sorted(SWEEPS))
+def test_the_sweep_counts_its_trips_and_its_tiles(load):
+    """``trips``, ``tiles`` and ``swept`` of the forward sweep: a balanced
+    load and one with an expert that is sent nothing fit the shape's chunk
+    (one trip, and the chunk's tiles past the live ones are swept and not
+    live: no tile of the absent expert among the live); a share sent more
+    than a chunk holds takes a second trip and gives what one trip of a
+    chunk that holds it all gives."""
+    assert held_chunk(TOKENS, TOP_K, EXPERTS, HELD, BLOCK) == CHUNK
+    chunk, trips = SWEEPS[load]
+    out, got, idx = _forward(load, chunk)
+    sent = np.bincount(idx.ravel(), minlength=EXPERTS)[:HELD]
+    live = int(np.sum(-(-sent // TILE)))
+    assert got == {"done": int(sent.sum()), "trips": trips, "tiles": live,
+                   "swept": trips * chunk // TILE, "tile": TILE}
+    assert got["tiles"] <= got["swept"]
+    if load == "an_expert_with_none":
+        assert sent[2] == 0 and got["tiles"] < got["swept"]
+    if trips == 2:
+        assert chunk // TILE < live <= 2 * chunk // TILE
+        whole, once, _ = _forward(load, CHUNK)
+        assert once["trips"] == 1 and once["tiles"] == live
+        assert _err(out, whole) < 1e-6
+
+
+def test_the_trips_returned_are_the_bound_the_loop_is_given(monkeypatch):
+    """One expression, not two: the ``trips`` the forward hands back IS
+    the value ``lax.fori_loop`` got as its upper bound."""
+    x, idx, w, experts, _ = _inputs("balanced", 128, 128, jnp.float32)
+    bounds, loop = [], sharded_moe.lax.fori_loop
+    monkeypatch.setattr(sharded_moe.lax, "fori_loop", lambda lo, hi, *a: (
+        bounds.append(hi), loop(lo, hi, *a))[1])
+
+    def probe(x, w, e):
+        out, counts = sharded_moe._held_forward(x, idx, w, e, 0, BLOCK,
+                                                CHUNK, "swiglu")
+        assert any(hi is counts["trips"] for hi in bounds)
+        assert counts["swept"].dtype == counts["trips"].dtype == jnp.int32
+        return out
+
+    jax.eval_shape(probe, x, w, experts)
+    assert bounds
 
 
 def test_the_chunk_rule_by_shape(monkeypatch):
@@ -249,7 +325,7 @@ def test_the_column_cut_gives_what_the_whole_expert_gives(
     chunks, so that a carried ``dW`` is copied in by columns too."""
     from deepspeed_tpu.ops.pallas import _common
     args = _inputs(load, 128, 512, jnp.float32)
-    want = _all(held_reference.held_experts_ffn)(*args)
+    want = _loop(load, 512)
     monkeypatch.setattr(grouped_matmul, "_VMEM_MAX", 17 << 20)
     assert grouped_matmul.backward_geometry(128, 512, TILE, 4)[0] == 4
     _common._TRACED.clear()

@@ -173,9 +173,9 @@ def test_no_token_is_dropped_under_a_skewed_router(skew):
     xt = x.reshape(-1, D)
     idx, w, _, _ = sigmoid_top_k(xt @ params["router"], bias, K,
                                  scaling=2.446)
-    out, done = held_experts_ffn(xt, idx, w, held, 0, 16)
+    out, swept = held_experts_ffn(xt, idx, w, held, 0, 16)
     want_rows = int(jnp.sum(idx < 8))
-    assert int(done) == want_rows
+    assert int(swept["done"]) == want_rows
     assert want_rows == {"all_to_one_held_expert": xt.shape[0] * 8,
                          "none_held": 0}.get(skew, want_rows)
     dense = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True)

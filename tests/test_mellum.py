@@ -188,9 +188,9 @@ def test_no_row_is_dropped_under_a_skewed_softmax_router(skew):
              "none_held": jnp.where(jnp.arange(E) < 16, -30.0, 0.0)}[skew]
     idx, w, _, _ = softmax_top_k(xt @ params["router"] + plant, K)
     idx = idx.astype(jnp.int32)
-    out, done = held_experts_ffn(xt, idx, w, held, 0, 16)
+    out, swept = held_experts_ffn(xt, idx, w, held, 0, 16)
     want_rows = int(jnp.sum(idx < 16))
-    assert int(done) == want_rows
+    assert int(swept["done"]) == want_rows
     if skew == "all_to_one_held_expert":
         assert int(jnp.sum(idx == 3)) == xt.shape[0]
     if skew == "none_held":
@@ -203,7 +203,8 @@ def test_no_row_is_dropped_under_a_skewed_softmax_router(skew):
     # the sweep's own trip count is the blocks the layer counts from the load
     ends = _held_layout(idx, w, 0, 16, 16)[3]
     sent = jnp.bincount(idx.reshape(-1), length=E)[:16]
-    assert int(ends[-1]) == int(jnp.sum((sent + 15) // 16))
+    assert int(ends[-1]) == int(jnp.sum((sent + 15) // 16)) \
+        == int(swept["tiles"])
     if skew == "all_to_one_held_expert":
         assert int(ends[-1]) >= xt.shape[0] // 16
 
